@@ -10,7 +10,6 @@ Public API parity with the reference (``deepspeed/__init__.py``):
 
 __version__ = "0.1.0"
 
-from .utils import jax_compat  # noqa: F401  (must precede any jax-using submodule)
 from . import comm  # noqa: F401
 from .comm.comm import init_distributed  # noqa: F401
 from .runtime import zero  # noqa: F401  (ds.zero.Init / GatheredParameters parity)
@@ -69,7 +68,17 @@ def initialize(args=None,
         from .comm.mesh import mesh_from_mpu
         mesh = mesh_from_mpu(mpu)
 
-    ds_config = config if isinstance(config, DeepSpeedConfig) else DeepSpeedConfig(config, mpu=mpu)
+    if isinstance(config, DeepSpeedConfig):
+        ds_config = config
+    else:
+        # an explicit mesh decides the data-parallel degree the batch triad is
+        # checked against — not jax.device_count(): a one-device mesh on a
+        # four-chip host trains with dp=1
+        dp = None
+        if mesh is not None:
+            from .comm.mesh import BATCH_AXES, axis_size
+            dp = axis_size(mesh, *BATCH_AXES)
+        ds_config = DeepSpeedConfig(config, mpu=mpu, dp_world_size=dp)
     from .runtime.pipe.engine import PipelineEngine
     from .runtime.pipe.module import PipelineModule
     if isinstance(model, PipelineModule):

@@ -173,11 +173,8 @@ def in_manual_mesh() -> bool:
     """True inside a shard_map body: GSPMD-level sharding constraints are
     meaningless/illegal there, and shard_map-wrapping kernels must not
     re-wrap."""
-    try:
-        from jax.sharding import get_abstract_mesh
-        return bool(get_abstract_mesh()._any_axis_manual)
-    except Exception:
-        return False
+    from jax.sharding import get_abstract_mesh
+    return bool(get_abstract_mesh().manual_axes)
 
 
 def axis_size(mesh: Mesh, *axes: str) -> int:

@@ -71,7 +71,7 @@ class RaggedInferenceEngineConfig:
     enable_prefix_cache: bool = True
     # pure-decode rounds fused into ONE compiled program (the reference's
     # CUDA-graphs analog): dispatch/host overhead amortizes K×, which
-    # dominates decode at small models or over tunneled chips.  Sequences
+    # dominates decode at small models.  Sequences
     # hitting EOS mid-block have their surplus tokens discarded host-side.
     decode_steps_per_dispatch: int = 8
     # unroll the layer loop in the decode trunk (llama-family twin only;
@@ -563,13 +563,13 @@ class InferenceEngineV2:
             keys += [("verify", b, width) for b in batches]
         return keys
 
-    def _aot_compile(self, key):
-        """``lower(...).compile()`` one program key against abstract
-        params/cache (the ``compile_aot_serving`` machinery, aimed at the
-        LIVE engine's shapes): nothing executes, no engine state moves —
-        unlike ``warm_verify``'s all-padding dispatches — and the
-        returned Compiled is call-compatible with the lazily jitted
-        version because both come from the same builder."""
+    def _aot_lower(self, key):
+        """Lower one program key against abstract params/cache (the
+        ``compile_aot_serving`` machinery, aimed at the LIVE engine's
+        shapes): nothing executes, no engine state moves — unlike
+        ``warm_verify``'s all-padding dispatches.  The Lowered's text is
+        what an integrity check reads to see which kernels the step
+        really contains (``chip_smoke.py``)."""
         sds = jax.ShapeDtypeStruct
         kvcfg = self.econfig.kv
         params_abs = jax.tree.map(lambda x: sds(x.shape, x.dtype), self.params)
@@ -595,10 +595,16 @@ class InferenceEngineV2:
             jitted = self._build_step_jit()
             args = (params_abs, cache_abs) + batch_args(b, c) + (rng_abs, )
         if self.mesh is None:
-            return jitted.lower(*args).compile()
+            return jitted.lower(*args)
         from ...comm.mesh import trace_mesh
         with self.mesh, trace_mesh(self.mesh):
-            return jitted.lower(*args).compile()
+            return jitted.lower(*args)
+
+    def _aot_compile(self, key):
+        """Compile one program key ahead of time; the returned Compiled is
+        call-compatible with the lazily jitted version because both come
+        from the same builder."""
+        return self._aot_lower(key).compile()
 
     def warm_all(self) -> Dict[str, object]:
         """AOT-compile the full reachable step set (``step_shape_set``)

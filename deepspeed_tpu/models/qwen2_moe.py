@@ -112,7 +112,7 @@ def _moe_intermediate_constraint(t):
     weights.  Leaving GSPMD to resolve the chain instead mixes the weights'
     expert-axis sharding into the activations and falls back to
     "Involuntary full rematerialization" — replicating a full [B,S,NE,E]
-    tensor per MoE layer (MULTICHIP_r03.json tail)."""
+    tensor per MoE layer (the warning `__graft_entry__.dryrun_multichip` fails on)."""
     from ..comm.mesh import BATCH_AXES, get_global_mesh, has_global_mesh
     from .llama import _skip_constraint
     if not has_global_mesh() or _skip_constraint(t):

@@ -69,10 +69,7 @@ def xla_cost_analysis(fn, *args, **kwargs):
     ``{'flops': .., 'bytes accessed': .., ...}`` (exact, post-fusion)."""
     lowered = jax.jit(fn).lower(*args, **kwargs)
     compiled = lowered.compile()
-    ca = compiled.cost_analysis()
-    if isinstance(ca, (list, tuple)):  # older jax returns [dict]
-        ca = ca[0] if ca else {}
-    return dict(ca or {})
+    return dict(compiled.cost_analysis() or {})
 
 
 # ------------------------------------------------------------------ profiler

@@ -467,11 +467,8 @@ class DeepSpeedConfig:
     def _resolve_dp_world_size(self):
         if self._dp_world_size_hint is not None:
             return self._dp_world_size_hint
-        try:
-            import jax
-            world = jax.device_count()
-        except Exception:
-            world = 1
+        import jax
+        world = jax.device_count()
         denom = (self.pipeline.stages * self.tensor_parallel_config.autotp_size * self.sequence_parallel_size)
         return max(1, world // max(1, denom))
 

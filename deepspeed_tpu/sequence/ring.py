@@ -39,7 +39,7 @@ _NEG_INF = -1e30
 def _match_vma(like):
     """Return a fn casting an unvarying array to the varying-manual-axes set
     of ``like`` (shard_map vma typing; no-op outside shard_map)."""
-    axes = getattr(jax.typeof(like), "vma", None) if hasattr(jax, "typeof") else None
+    axes = jax.typeof(like).vma
     if not axes:
         return lambda x: x
     return lambda x: jax.lax.pcast(x, tuple(axes), to="varying")

@@ -64,7 +64,10 @@ def run(attention_impl, seq, batch, steps=3, windows=3):
 
 def main():
     sys.path.insert(0, os.path.dirname(os.path.abspath(__file__)))
-    from bench import peak_flops_per_chip
+    from bench import peak_flops_per_chip, require_tpu
+    from deepspeed_tpu.utils import compile_cache
+    require_tpu()
+    compile_cache.enable()
 
     seq, batch = 32768, 1
     tps, n_params, cfg, loss = run("flash", seq, batch)

@@ -681,6 +681,9 @@ def main():
 
     if args.dryrun:
         os.environ.setdefault("JAX_PLATFORMS", "cpu")
+    else:
+        from deepspeed_tpu.utils import compile_cache
+        compile_cache.enable()
 
     from deepspeed_tpu.serving import VirtualClock, WallClock
 
