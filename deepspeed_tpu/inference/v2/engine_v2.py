@@ -119,6 +119,15 @@ def _make_step_fn(model, qparams, greedy: bool, temperature: float):
     return step
 
 
+def _named(fn, label: str):
+    """Name a step function after its program key (``step:b16:c128`` ->
+    ``ds_step_b16_c128``) before ``jax.jit``, so the device trace's
+    ``XLA Modules`` line reads ``jit_ds_step_b16_c128`` and tells a mixed
+    step from a one-token step."""
+    fn.__name__ = fn.__qualname__ = "ds_" + label.replace(":", "_")
+    return fn
+
+
 def _serving_shardings(model, cfg, kvcfg, kv_dtype, mesh):
     """TP shardings shared by the live engine (_setup_tp) and the AOT budget
     path: params via the logical-axis rules (zero_stage=0), the scanned KV
@@ -162,7 +171,8 @@ def compile_aot_serving(cfg, mesh, engine_config: RaggedInferenceEngineConfig = 
     model = build_cache_model(cfg, kvcfg.page_size)
     abs_params, cache_abs, param_sh, cache_sh, r = _serving_shardings(
         model, cfg, kvcfg, eng_cfg.kv_dtype, mesh)
-    step = _make_step_fn(model, None, eng_cfg.greedy, eng_cfg.temperature)
+    step = _named(_make_step_fn(model, None, eng_cfg.greedy, eng_cfg.temperature),
+                  InferenceEngineV2._key_label((batch, chunk)))
     jitted = jax.jit(step, donate_argnums=(1, ),
                      in_shardings=(param_sh, cache_sh, r, r, r, r, r),
                      out_shardings=(r, cache_sh))
@@ -440,13 +450,16 @@ class InferenceEngineV2:
         with self.mesh, trace_mesh(self.mesh):
             return fn(*args)
 
-    def _build_step_jit(self):
+    def _build_step_jit(self, batch: int, chunk: int):
         """The jitted single/mixed step program — ONE builder shared by
         the lazy per-shape cache and the AOT ``warm_all`` path, so the
-        two can never trace different computations for the same key."""
+        two can never trace different computations for the same key
+        (batch and chunk only name the program: the shapes come with the
+        arguments)."""
         step = _make_step_fn(self.model, self._qparams, self.econfig.greedy,
                              self.econfig.temperature)
-        return jax.jit(step, donate_argnums=(1, ), **self._jit_kwargs())
+        return jax.jit(_named(step, self._key_label((batch, chunk))),
+                       donate_argnums=(1, ), **self._jit_kwargs())
 
     def _build_multi_jit(self, batch: int, k: int):
         """The fused k-round decode program (shapes close over batch/k)."""
@@ -471,9 +484,10 @@ class InferenceEngineV2:
             cache, _, out = jax.lax.fori_loop(0, k, body, (cache, tokens0, out0))
             return out, cache
 
-        return jax.jit(mstep, donate_argnums=(1, ), **self._jit_kwargs())
+        return jax.jit(_named(mstep, self._key_label(("multi", batch, k))),
+                       donate_argnums=(1, ), **self._jit_kwargs())
 
-    def _build_verify_jit(self):
+    def _build_verify_jit(self, batch: int, width: int):
         """The speculative verify program (argmax at EVERY position)."""
         def vstep(params, cache, tokens, start_pos, block_tables, chunk_lens):
             if self._qparams is not None:
@@ -487,14 +501,15 @@ class InferenceEngineV2:
             r = self._repl_sh
             kwargs = dict(in_shardings=(self._param_sh, self._cache_sh, r, r, r, r),
                           out_shardings=(r, self._cache_sh))
-        return jax.jit(vstep, donate_argnums=(1, ), **kwargs)
+        return jax.jit(_named(vstep, self._key_label(("verify", batch, width))),
+                       donate_argnums=(1, ), **kwargs)
 
     def _compiled_step(self, batch: int, chunk: int):
         key = (batch, chunk)
         if key not in self._step_fns:
             logger.info(f"InferenceEngineV2: compiling step program batch={batch} chunk={chunk}")
-            self._step_fns[key] = self._build_step_jit()
-            self._note_compile(f"step:b{batch}:c{chunk}")
+            self._step_fns[key] = self._build_step_jit(batch, chunk)
+            self._note_compile(self._key_label(key))
         return self._step_fns[key]
 
     def _compiled_multi_step(self, batch: int, k: int):
@@ -502,7 +517,7 @@ class InferenceEngineV2:
         if key not in self._step_fns:
             logger.info(f"InferenceEngineV2: compiling multi-decode program batch={batch} k={k}")
             self._step_fns[key] = self._build_multi_jit(batch, k)
-            self._note_compile(f"multi:b{batch}:k{k}")
+            self._note_compile(self._key_label(key))
         return self._step_fns[key]
 
     def _compiled_verify(self, batch: int, width: int):
@@ -518,8 +533,8 @@ class InferenceEngineV2:
         if key not in self._step_fns:
             logger.info(f"InferenceEngineV2: compiling verify program batch={batch} "
                         f"width={width}")
-            self._step_fns[key] = self._build_verify_jit()
-            self._note_compile(f"verify:b{batch}:w{width}")
+            self._step_fns[key] = self._build_verify_jit(batch, width)
+            self._note_compile(self._key_label(key))
         return self._step_fns[key]
 
     # ------------------------------------------------------------- AOT set
@@ -588,11 +603,11 @@ class InferenceEngineV2:
                 batch_args(b, 1)[1:] + (rng_abs, )
         elif key[0] == "verify":
             _, b, w = key
-            jitted = self._build_verify_jit()
+            jitted = self._build_verify_jit(b, w)
             args = (params_abs, cache_abs) + batch_args(b, w)
         else:
             b, c = key
-            jitted = self._build_step_jit()
+            jitted = self._build_step_jit(b, c)
             args = (params_abs, cache_abs) + batch_args(b, c) + (rng_abs, )
         if self.mesh is None:
             return jitted.lower(*args)
@@ -731,7 +746,9 @@ class InferenceEngineV2:
                 anat.mark("verify_plan")
             fn = self._compiled_verify(batch, width)
             if anat.enabled:
-                anat.note_shape("spec_verify", batch, width)
+                # tokens_real (accepted + 1 a row) is known at the fold
+                anat.note_program(self._key_label(("verify", batch, width)), "spec_verify",
+                                  rows_decode=len(seqs), slots=batch * width)
             _fi.check("engine.verify_step")  # chaos site: device loss mid-verify
             argmax, self.cache = self._invoke(fn, self.params, self.cache,
                                               jnp.asarray(rb.tokens), jnp.asarray(rb.start_pos),
@@ -811,6 +828,11 @@ class InferenceEngineV2:
             self.spec_stats.rollback_pages += freed
             self.last_spec_round[s.uid] = (len(d), a, freed)
         if anat.enabled:
+            # real: accepted + 1 a live row (last_spec_round holds this round
+            # only); discarded: rejected drafts and rows flushed in flight
+            n_real = sum(a + 1 for _, a, _ in self.last_spec_round.values())
+            anat.note_tokens(sum(len(v) for v in out.values()),
+                             sum(1 + len(d) for d in drafts) - n_real, real=n_real)
             anat.mark("sample_accept")
         return out
 
@@ -833,7 +855,9 @@ class InferenceEngineV2:
         self.rng, sub = jax.random.split(self.rng)
         fn = self._compiled_multi_step(batch, k)
         if anat.enabled:
-            anat.note_shape("multi_decode", batch, k)
+            anat.note_program(self._key_label(("multi", batch, k)), "multi_decode",
+                              rows_decode=len(seqs), tokens_real=len(seqs) * k,
+                              slots=batch * k)
         toks, self.cache = self._invoke(fn, self.params, self.cache, jnp.asarray(rb.tokens[:, 0]),
                                         jnp.asarray(rb.start_pos), jnp.asarray(rb.block_tables),
                                         jnp.asarray(rb.chunk_lens), sub)
@@ -875,6 +899,9 @@ class InferenceEngineV2:
             self.state.note_progress(s)
             out[s.uid] = list(s.generated[before:])
         if anat.enabled:
+            # the overshoot past a row's EOS or limit, and whole rows flushed in flight
+            n_out = sum(len(v) for v in out.values())
+            anat.note_tokens(n_out, len(inf.seqs) * k - n_out)
             anat.mark("sample_accept")
         return out
 
@@ -999,7 +1026,9 @@ class InferenceEngineV2:
         if anat.enabled:
             path = ("mixed" if plan.prefill and plan.decode
                     else "prefill" if plan.prefill else "decode")
-            anat.note_shape(path, batch, chunk)
+            anat.note_program(self._key_label((batch, chunk)), path,
+                              rows_decode=len(plan.decode), rows_prefill=len(plan.prefill),
+                              tokens_real=sum(n for _, n in work), slots=batch * chunk)
         next_tok, self.cache = self._invoke(fn, self.params, self.cache, jnp.asarray(rb.tokens),
                                             jnp.asarray(rb.start_pos), jnp.asarray(rb.block_tables),
                                             jnp.asarray(rb.chunk_lens), sub)
@@ -1034,6 +1063,7 @@ class InferenceEngineV2:
                     (eos is not None and tok == eos):
                 seq.done = True
         if anat.enabled:
+            anat.note_tokens(len(out))
             anat.mark("sample_accept")
         return out
 

@@ -255,6 +255,7 @@ def _flash_fwd2(q, k, v, *, h, hk, causal, block_q, block_k, interpret, emit_lse
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
+        name="ds_flash_fwd",
     )(tab, q, k, v)
     return (out[0], out[1]) if emit_lse else (out[0], None)
 
@@ -379,6 +380,7 @@ def _flash_bwd2(q, k, v, o, lse, do, *, h, hk, causal, block_q, block_k, interpr
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
+        name="ds_flash_dq",
     )(tab_r, q, k, v, o, do, lse)
 
     tab_c = _tri_table(nq, nk, bq, bk, causal, transpose=True, q_offset=q_offset)
@@ -402,6 +404,7 @@ def _flash_bwd2(q, k, v, o, lse, do, *, h, hk, causal, block_q, block_k, interpr
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
+        name="ds_flash_dkv",
     )(tab_c, q, k, v, o, do, lse)
     return dq, dk, dv
 

@@ -178,6 +178,7 @@ def paged_attention_pallas(q, pages, block_table, start_pos, chunk_lens, page_si
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
+        name="ds_paged_attention",
     )(block_table, start_pos, qg, pages)
 
     out = out.reshape(b, n_kv, rep, c, d).reshape(b, h, c, d).transpose(0, 2, 1, 3)

@@ -40,6 +40,7 @@ from ..ops.lamb import fused_lamb
 from ..ops.lion import fused_lion
 from ..ops.onebit import onebit_adam, onebit_lamb, zero_one_adam
 from ..utils.logging import log_dist, logger
+from ..utils.nvtx import profiler_range
 from ..utils.timer import (BACKWARD_GLOBAL_TIMER, FORWARD_GLOBAL_TIMER, STEP_GLOBAL_TIMER, TRAIN_BATCH_TIMER,
                            NoopTimer, SynchronizedWallClockTimer, ThroughputTimer)
 from .config import DeepSpeedConfig
@@ -1004,7 +1005,11 @@ class DeepSpeedEngine:
                                 in_specs=(state_specs, batch_specs),
                                 out_specs=(state_specs, metric_specs),
                                 check_vma=False)
-        self._train_step_fn = jax.jit(step_fn,
+
+        def ds_train_step(state, b):   # the name the device trace's XLA Modules line shows
+            return step_fn(state, b)
+
+        self._train_step_fn = jax.jit(ds_train_step,
                                       in_shardings=(self.state_shardings, batch_sh),
                                       out_shardings=(self.state_shardings, metrics_sh),
                                       donate_argnums=(0, ))
@@ -1051,13 +1056,13 @@ class DeepSpeedEngine:
             # the per-group update programs too
             self._nvme_opt._update_fns.clear()
 
-        def grad_step(state, b):
+        def ds_grad_step(state, b):
             grads, loss = self._grads_for_batch(state, b)
             norm2 = sum(jnp.sum(jnp.square(g.astype(jnp.float32) * inv))
                         for g in jax.tree.leaves(grads))
             return grads, loss, jnp.sqrt(norm2)
 
-        self._train_step_fn = jax.jit(grad_step, in_shardings=(self.state_shardings, batch_sh))
+        self._train_step_fn = jax.jit(ds_grad_step, in_shardings=(self.state_shardings, batch_sh))
         self._batch_shardings = batch_sh
 
         def unsupported(*a, **k):
@@ -1148,18 +1153,20 @@ class DeepSpeedEngine:
         batch_sh = self._batch_sharding_tree(batch)
         repl = NamedSharding(self.mesh, P())
 
-        def train_step(state, b):
+        # the step functions carry the names the device trace's ``XLA
+        # Modules`` line shows: jit_ds_train_step, jit_ds_accum, jit_ds_apply
+        def ds_train_step(state, b):
             grads, loss = self._grads_for_batch(state, b)
             return self._apply_grads(state, grads, loss)
 
         metrics_sh = StepMetrics(*([repl] * 5))
-        self._train_step_fn = jax.jit(train_step,
+        self._train_step_fn = jax.jit(ds_train_step,
                                       in_shardings=(self.state_shardings, batch_sh),
                                       out_shardings=(self.state_shardings, metrics_sh),
                                       donate_argnums=(0, ))
         self._batch_shardings = batch_sh
 
-        def accum(state, b):
+        def ds_accum(state, b):
             # one micro-batch per call — NO gas re-split here: the imperative
             # forward/backward/step path calls backward() once per micro-batch
             # and step() divides the summed grads by gas
@@ -1173,8 +1180,11 @@ class DeepSpeedEngine:
             return grads, loss
 
         micro_batch_sh = self._batch_sharding_tree(batch)
-        self._accum_fn = jax.jit(accum, in_shardings=(self.state_shardings, micro_batch_sh))
-        self._apply_step_fn = jax.jit(self._apply_grads,
+        def ds_apply(state, grads, loss):
+            return self._apply_grads(state, grads, loss)
+
+        self._accum_fn = jax.jit(ds_accum, in_shardings=(self.state_shardings, micro_batch_sh))
+        self._apply_step_fn = jax.jit(ds_apply,
                                       in_shardings=(self.state_shardings, None, repl),
                                       out_shardings=(self.state_shardings, metrics_sh),
                                       donate_argnums=(0, ))
@@ -1302,13 +1312,19 @@ class DeepSpeedEngine:
             "engine/step", track="engine",
             attrs={"global_step": self.global_steps} if self.tracer.enabled else None)
         try:
-            with mesh_lib.trace_mesh(self.mesh):  # first call traces model code
+            # in a running jax.profiler trace: one ds.train_step range a step
+            # on the host plane (an inactive TraceMe otherwise)
+            with jax.profiler.StepTraceAnnotation("ds.train_step", step_num=self.global_steps), \
+                    mesh_lib.trace_mesh(self.mesh):  # first call traces model code
                 if getattr(self, "_nvme_opt", None) is not None:
                     self.state, metrics = self._nvme_train_step(batch)
                 else:
                     with self.tracer.span("engine/fused_step",
                                           parent=self._step_span, track="engine"):
-                        self.state, metrics = self._train_step_fn(self.state, batch)
+                        # the jitted call places the batch itself: a device_put of
+                        # its own, to have a span for it, cost 0.1% of the step
+                        with profiler_range("ds.dispatch"):
+                            self.state, metrics = self._train_step_fn(self.state, batch)
         finally:
             self.tracer.end(self._step_span)
             self._step_span = None

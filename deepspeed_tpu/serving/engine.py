@@ -106,15 +106,13 @@ class ServingEngine:
         self._uids = itertools.count(max(engine.state.seqs.keys(), default=-1) + 1)
         self._events_step = 0
         self._t0 = self.clock.now()
-        # step-anatomy fold cursors (telemetry/step_anatomy.py): compiles
-        # already bridged into metrics/events, steps already mirrored into
-        # the flight-recorder ring.  The compile cursor starts at the
+        # step-anatomy fold cursor (telemetry/step_anatomy.py): compiles
+        # already bridged into metrics/events.  It starts at the
         # recorder's CURRENT log length so pre-frontend warm-up compiles
         # (harnesses warm before building the frontend) are not re-counted
         # as serving-time recompiles.
         self._compiles_seen = len(getattr(engine, "anatomy",
                                           NULL_ANATOMY).compiles)
-        self._anat_steps_seen = 0
         # EWMA of clock-seconds per tick-with-work (load_stats input for the
         # fleet router's least-loaded policy); None until the first step runs
         self._ewma_step_s: Optional[float] = None
@@ -365,20 +363,24 @@ class ServingEngine:
         """The strictly serial host→device step loop.
 
         With a step-anatomy recorder on the engine, the tick opens the
-        step window BEFORE the admission/preflight work (``step_begin``
-        is idempotent — the engine's own call then no-ops) and attributes
-        planning up to the engine call as the ``schedule`` segment; on
-        clock-charged steps (VirtualClock / fleet clock views) the
-        charged cost is forwarded as the step's device seconds.  Ticks
-        that run no step leave the window open — their host work folds
-        into the step that eventually runs, which is exactly the loop tax
-        the anatomy exists to expose."""
+        step window BEFORE the admission/preflight work and HOLDS it
+        (``step_begin(hold=True)``: the engine's own begin and end then
+        no-op) until the tokens are delivered, so the whole tick lies in
+        one step: ``admit`` (expiry + admission), ``schedule`` (the
+        KV-pressure preflight and plan), the engine's own segments, and
+        ``deliver``; on clock-charged steps (VirtualClock / fleet clock
+        views) the charged cost is forwarded as the step's device
+        seconds.  Ticks that run no step leave the window open — their
+        host work folds into the step that eventually runs, which is
+        exactly the loop tax the anatomy exists to expose."""
         anat = getattr(self.engine, "anatomy", NULL_ANATOMY)
         if anat.enabled:
-            anat.step_begin()
+            anat.step_begin(hold=True)
         now = self.clock.now()
         self._expire(now)
         self._admit(now)
+        if anat.enabled:
+            anat.mark("admit")
         if not self._active:
             return {}
         evicted, plan = self.kvp.resolve()
@@ -397,22 +399,28 @@ class ServingEngine:
         if self.config.step_cost is not None:
             cost = self.config.step_cost(plan.planned_tokens)
         t_step = self.clock.now()
-        out = self.engine.step(plan)
-        # clock-domain step seconds: clocks that account the cost themselves
-        # (VirtualClock, ReplicaClockView) return it; WallClock returns None
-        # and the real elapsed time is measured
-        charged = self.clock.on_step(cost)
-        dt = charged if charged is not None else self.clock.now() - t_step
-        self._ewma_step_s = dt if self._ewma_step_s is None \
-            else 0.8 * self._ewma_step_s + 0.2 * dt
-        if anat.enabled:
-            if charged is not None:
-                anat.charge_last_step(charged)
-            self._fold_anatomy(anat)
-        # fold BEFORE _deliver: finishing a request flushes its engine
-        # sequence, which pops its last_spec_round entry
-        self._record_spec_rounds()
-        self._deliver(out, self.clock.now())
+        try:
+            out = self.engine.step(plan)
+            # clock-domain step seconds: clocks that account the cost themselves
+            # (VirtualClock, ReplicaClockView) return it; WallClock returns None
+            # and the real elapsed time is measured
+            charged = self.clock.on_step(cost)
+            dt = charged if charged is not None else self.clock.now() - t_step
+            self._ewma_step_s = dt if self._ewma_step_s is None \
+                else 0.8 * self._ewma_step_s + 0.2 * dt
+            if anat.enabled:
+                if charged is not None:
+                    anat.charge_last_step(charged)
+                self._fold_compiles(anat)
+            # fold BEFORE _deliver: finishing a request flushes its engine
+            # sequence, which pops its last_spec_round entry
+            self._record_spec_rounds()
+            self._deliver(out, self.clock.now())
+            if anat.enabled:
+                anat.mark("deliver")
+        finally:
+            if anat.enabled:   # a failed step closes its window too
+                anat.step_end(release=True)
         return out
 
     def _tick_pipelined(self) -> Dict[int, List[int]]:
@@ -424,8 +432,9 @@ class ServingEngine:
 
         1. **overlap window** — deadline expiry and admission run while
            last tick's dispatch is still in flight; with a recorder
-           attached the stretch lands in the open step's ``overlap``
-           segment (loop tax hidden under device time).  A sequence
+           attached the caller's loop since the last tick lands in the
+           open step's ``overlap`` segment and this stretch in ``admit``
+           (both loop tax hidden under device time).  A sequence
            flushed here while in flight is skipped whole at the fold
            (object-identity guards in ``complete_step``) — its computed
            tokens are discarded, never half-applied.
@@ -440,13 +449,17 @@ class ServingEngine:
            g+1's charge, so delivery/finish times equal the serial
            loop's (sum of costs through step g).  Runs in a ``finally``:
            a g+1 dispatch failure must never lose g's delivered tokens.
+           With a recorder it is step g+1's ``deliver`` segment (no
+           window is open when nothing was dispatched: host gap).
         """
         anat = getattr(self.engine, "anatomy", NULL_ANATOMY)
+        if anat.enabled:
+            anat.mark("overlap")   # marks are no-ops when no step window is open
         now = self.clock.now()
         self._expire(now)
         self._admit(now)
         if anat.enabled:
-            anat.mark("overlap")   # no-op when no step window is open
+            anat.mark("admit")
         out: Dict[int, List[int]] = {}
         if self._inflight is not None:
             inf, charged, t_dispatch = self._inflight
@@ -457,7 +470,7 @@ class ServingEngine:
             self._ewma_step_s = dt if self._ewma_step_s is None \
                 else 0.8 * self._ewma_step_s + 0.2 * dt
             if anat.enabled:
-                self._fold_anatomy(anat)
+                self._fold_compiles(anat)
             # fold BEFORE the next dispatch (it clears last_spec_round)
             # and BEFORE _deliver (finishing a request flushes its engine
             # sequence, which pops its entry)
@@ -496,16 +509,18 @@ class ServingEngine:
                     self._inflight = (inf, charged, t_dispatch)
         finally:
             self._deliver(out, t_deliver)
+            if anat.enabled:
+                anat.mark("deliver")
         return out
 
-    def _fold_anatomy(self, anat) -> None:
-        """Bridge the engine's step-anatomy state into the serving
+    def _fold_compiles(self, anat) -> None:
+        """Bridge the recorder's compile tracker into the serving
         telemetry surfaces: new JIT cache misses become ``engine/
         recompiles`` counter increments (steady-state ones additionally
         the ``engine/recompile_steady_state`` counter + event — the AOT
-        regression signal, loud by design), and the just-closed step is
-        mirrored as one bounded ``anatomy/step`` span on this frontend's
-        flight-recorder track."""
+        regression signal, loud by design).  The steps themselves are
+        drawn in the profiler's trace (``StepAnatomy(annotate=...)``) and
+        tabled by ``to_doc()``; nothing copies them anywhere else."""
         compiles = anat.compiles
         if len(compiles) > self._compiles_seen:
             for c in list(compiles)[self._compiles_seen:]:
@@ -522,26 +537,6 @@ class ServingEngine:
                     self._emit([("engine/recompile_steady_state", 1.0,
                                  self._next_event_step())])
             self._compiles_seen = len(compiles)
-        if anat.total_steps > self._anat_steps_seen:
-            unseen = anat.total_steps - self._anat_steps_seen
-            self._anat_steps_seen = anat.total_steps
-            recorder = self.recorder if self.recorder is not None \
-                else getattr(self.tracer, "recorder", None)
-            if recorder is not None:
-                # mirror EVERY unseen closed step, not just the newest —
-                # a chaos-failed step closes its record but skips that
-                # tick's fold, and its anatomy is exactly what a
-                # crash-scoped dump needs (deque eviction bounds the tail)
-                steps = anat.steps
-                for rec in list(steps)[-min(unseen, len(steps)):]:
-                    recorder.span(
-                        "anatomy/step", f"anatomy/{self.trace_track}",
-                        rec.end_ts - rec.wall_s, rec.end_ts,
-                        attrs={"shape": rec.shape_key,
-                               "host_gap_s": round(rec.host_gap_s, 9),
-                               "host_s": round(rec.host_s(), 9),
-                               "device_s": round(rec.device_s, 9),
-                               "compiles": rec.compiles})
 
     def export_kv_gauges(self) -> None:
         """Publish the engine's KV-arena occupancy onto the metrics

@@ -267,14 +267,6 @@ EVENTS = {
                                       "a step program compiled AFTER the "
                                       "warm-up boundary — the AOT "
                                       "serving-step regression guard"),
-    "anatomy/step": ("span", "serving/engine.py",
-                     "flight-recorder span: one engine step's anatomy "
-                     "(attrs: shape, host/device/gap seconds, compiles) "
-                     "on the anatomy/<frontend> track"),
-    "anatomy/device": ("span", "telemetry/step_anatomy.py",
-                       "device-compute child of an emit_spans "
-                       "anatomy/step (host segments ride as "
-                       "anatomy/<segment> via the DYNAMIC family)"),
     # ---- engine-step tracer spans (runtime/engine.py set_telemetry)
     "engine/step": ("span", "runtime/engine.py",
                     "one train_batch trace root on the engine track"),
@@ -420,14 +412,12 @@ DYNAMIC = [
             "(tenant tallies sum to the fleet's pages in use — the "
             "per-tenant KV-quota input), exported once per fleet round"},
     {"prefix": "anatomy/", "template": "anatomy/<name>",
-     "kind": "gauge+span+track", "source": "serving/fleet/router.py "
-     "(+serving/engine.py, telemetry/step_anatomy.py)",
-     "expansions": ["anatomy/host_gap_fraction/<rid> (gauge)",
-                    "anatomy/<frontend> (flight-recorder track of "
-                    "anatomy/step spans, e.g. anatomy/replica0)"],
-     "doc": "step-anatomy surfaces: per-replica host-gap-fraction gauges "
-            "once per fleet round + per-step recorder tracks "
-            "(docs/OBSERVABILITY.md 'Step anatomy')"},
+     "kind": "gauge", "source": "serving/fleet/router.py",
+     "expansions": ["anatomy/host_gap_fraction/<rid> (gauge)"],
+     "doc": "step-anatomy surface: per-replica host-gap-fraction gauges "
+            "once per fleet round (docs/OBSERVABILITY.md 'Step anatomy'; "
+            "the steps themselves are ds.step ranges in the profiler's "
+            "trace, not events of this registry)"},
 ]
 
 BEGIN_MARK = ("<!-- BEGIN EVENT TABLE (generated from "

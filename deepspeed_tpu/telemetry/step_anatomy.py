@@ -7,6 +7,9 @@ to kill.  This module decomposes EVERY engine step into:
 * named **host segments**, measured as disjoint cursor intervals on the
   recorder's clock —
 
+    ``admit``          the serving frontend's deadline expiry and
+                       admission (``_expire`` + ``_admit``) at a tick's
+                       start
     ``schedule``       step planning (``SplitFuseScheduler.plan`` /
                        the serving frontend's KV-pressure preflight)
     ``draft_plan``     speculative draft planning (``_plan_drafts``)
@@ -21,23 +24,39 @@ to kill.  This module decomposes EVERY engine step into:
                        enqueue)
     ``sample_accept``  host-side token fold (argmax accept loop, EOS/
                        limit checks, rollback truncation)
-    ``overlap``        host work for step g+1 executed while step g was
-                       still in flight on device (the async double-
-                       buffered tick's scheduling/admission/delivery
-                       window — loop tax HIDDEN under device time)
+    ``deliver``        the serving frontend handing the step's tokens to
+                       their requests (``_deliver``: stop checks,
+                       finishing, flushing)
+    ``overlap``        the caller's loop between two pipelined ticks,
+                       run while the previous dispatch was still in
+                       flight (loop tax HIDDEN under device time; in the
+                       async double-buffered tick ``admit`` and
+                       ``deliver`` run in flight too)
     ``bookkeeping``    everything else inside the step window (prefix-
                        cache publish, descriptor updates, the residual
                        between the last mark and step end)
 
-* **device compute** — the blocking materialization of the dispatch's
-  outputs on a real clock, or the explicitly charged virtual step cost
-  (``charge_last_step``) under ``VirtualClock``/``ReplicaClockView``;
+* ``device_s`` — on a real clock the HOST'S WAIT at the blocking
+  readback of the dispatch's outputs (the ``ds.device_wait`` range): an
+  upper bound on what the device still had to do when the host got
+  there, never the device's busy time, which only the profiler's device
+  trace gives.  Under ``VirtualClock``/``ReplicaClockView`` it is the
+  explicitly charged step cost (``charge_last_step``);
 
 * the **host gap** — clock time between the previous step's end and this
-  step's begin: the serving loop's admission/deadline/delivery work, the
-  per-tick Python re-entry the AOT item wants amortized away.  Idle
-  waits (``note_idle``) are excluded — idle is absent load, not loop
-  tax — and the following step is flagged ``after_idle``.
+  step's begin.  Under a serving frontend both ends lie at a ``tick()``'s
+  edges, so the gap is what the CALLER did between two ticks (submitting
+  requests, its own loop).  Idle waits (``note_idle``) are excluded —
+  idle is absent load, not loop tax — and the following step is flagged
+  ``after_idle``.
+
+* **counts** of what the step carried, noted where the engine packs the
+  batch and folds the tokens: the program's key (``step:b16:c128``,
+  ``multi:b16:k8``), ``rows_decode``/``rows_prefill`` (sequences),
+  ``tokens_real`` (token positions computed for a live sequence),
+  ``slots`` (positions the program computed, padding included),
+  ``tokens_out`` (tokens that reached a sequence) and
+  ``tokens_discarded`` (overshoot of the fused rung, rejected drafts).
 
 The decomposition TILES by construction: every component is a
 non-negative clock difference (or an explicit charge), and
@@ -47,6 +66,17 @@ non-negative clock difference (or an explicit charge), and
 exactly, per step.  ``scripts/step_anatomy.py`` re-verifies the tiling
 from the committed per-step table within 1e-6 (exit 1 on mismatch) —
 the same trust-but-re-verify stance as ``why_slow.py``'s cause tiling.
+
+With an ``annotate`` factory (``utils/nvtx.py::profiler_range``) every
+step is also written into the host plane of a running ``jax.profiler``
+trace, on the device trace's clock: one ``ds.step`` range from
+``step_begin`` to ``step_end`` whose metadata are the step's index, key
+and counts, and one instant ``ds.mark.<segment>`` at every cursor mark
+(``ds.mark.device_wait`` for the readback).  The recorder is a cursor — a
+segment's name is known when it ENDS — so a reader rebuilds segment
+``ds.<segment>`` as the interval from the previous mark (or the step's
+begin) to the mark; what lies between the last mark and the range's end
+is ``bookkeeping``.  The module itself stays free of jax.
 
 A **compile tracker** rides along: every JIT cache miss the engine
 reports (``note_compile``) is tagged warm-up or — after
@@ -66,54 +96,62 @@ from typing import Dict, List, Optional
 
 from .trace import PerfClock
 
-__all__ = ["HOST_SEGMENTS", "StepAnatomy", "NullStepAnatomy", "NULL_ANATOMY",
+__all__ = ["HOST_SEGMENTS", "COUNTS", "StepAnatomy", "NullStepAnatomy", "NULL_ANATOMY",
            "StepRecord", "CompileRecord"]
 
 #: the closed host-segment vocabulary; every step exports all of them
 #: (zero-filled) so the per-step table has one fixed shape
-HOST_SEGMENTS = ("schedule", "draft_plan", "verify_plan", "aot_compile",
-                 "compile_wait", "dispatch", "sample_accept", "overlap",
-                 "bookkeeping", "promote_wait")
+HOST_SEGMENTS = ("admit", "schedule", "draft_plan", "verify_plan",
+                 "aot_compile", "compile_wait", "dispatch", "sample_accept",
+                 "deliver", "overlap", "bookkeeping", "promote_wait")
+
+#: what a step carried; zero until the engine notes them
+COUNTS = ("rows_decode", "rows_prefill", "tokens_real", "slots", "tokens_out",
+          "tokens_discarded")
+
+#: names of the instant profiler events, built once (a mark allocates no string)
+_MARK_NAMES = {s: "ds.mark." + s for s in HOST_SEGMENTS + ("device_wait", )}
 
 
 class StepRecord:
     """One recorded engine step (mutable only via the recorder)."""
 
-    __slots__ = ("index", "path", "batch", "chunk", "segments", "device_s",
-                 "host_gap_s", "wall_s", "after_idle", "compiles", "end_ts")
+    __slots__ = ("index", "key", "path", "segments", "device_s", "host_gap_s",
+                 "wall_s", "after_idle", "compiles", "end_ts") + COUNTS
 
     def __init__(self, index: int):
         self.index = index
+        self.key: Optional[str] = None       # the program: step:b16:c128 | multi:b16:k8 | verify:b16:w5
         self.path: Optional[str] = None      # decode|prefill|mixed|spec_verify|multi_decode
-        self.batch: Optional[int] = None     # bucketed batch of the dispatch
-        self.chunk: Optional[int] = None     # chunk width / verify width / fused k
         self.segments: Dict[str, float] = {s: 0.0 for s in HOST_SEGMENTS}
-        self.device_s = 0.0
+        self.device_s = 0.0                  # real clock: the host's wait at the readback
         self.host_gap_s = 0.0
         self.wall_s = 0.0
         self.after_idle = False
         self.compiles = 0                    # JIT cache misses THIS step paid for
         self.end_ts = 0.0                    # recorder-clock time at step end
-
-    @property
-    def shape_key(self) -> str:
-        return f"{self.path}:b{self.batch}:c{self.chunk}"
+        self.rows_decode = self.rows_prefill = 0
+        self.tokens_real = self.slots = 0
+        self.tokens_out = self.tokens_discarded = 0
 
     def host_s(self) -> float:
         return sum(self.segments.values())
 
+    def counts(self) -> Dict[str, int]:
+        return {c: getattr(self, c) for c in COUNTS}
+
     def to_row(self) -> dict:
-        """Deterministic export row (9-dp rounding, sorted segment keys)."""
+        """Deterministic export row (9-dp rounding, fixed key order)."""
         return {
             "index": self.index,
+            "key": self.key,
             "path": self.path,
-            "batch": self.batch,
-            "chunk": self.chunk,
-            "shape": self.shape_key,
+            **self.counts(),
             "segments": {s: round(self.segments[s], 9) for s in HOST_SEGMENTS},
             "device_s": round(self.device_s, 9),
             "host_gap_s": round(self.host_gap_s, 9),
             "wall_s": round(self.wall_s, 9),
+            "end_ts": round(self.end_ts, 9),
             "after_idle": self.after_idle,
             "compiles": self.compiles,
         }
@@ -149,11 +187,14 @@ class StepAnatomy:
     default).  ``max_steps`` bounds the per-step table (deque; evictions
     counted in ``dropped_steps``); lifetime totals keep accumulating past
     the cap, so the host-gap-fraction gauges never lie about the window
-    they cover being the whole run."""
+    they cover being the whole run.  ``annotate``: an optional factory of
+    profiler ranges, ``annotate(name)`` giving a context manager with
+    ``set_metadata(**kw)`` (``utils/nvtx.py::profiler_range``); with it
+    every step and mark is also written into a running profiler trace."""
 
     enabled = True
 
-    def __init__(self, clock=None, max_steps: int = 4096):
+    def __init__(self, clock=None, max_steps: int = 4096, annotate=None):
         if max_steps < 1:
             raise ValueError(f"max_steps must be >= 1, got {max_steps}")
         self.clock = clock if clock is not None else PerfClock()
@@ -172,20 +213,28 @@ class StepAnatomy:
         self._last_end: Optional[float] = None
         self._after_idle = False
         self._cur: Optional[StepRecord] = None
+        self._held = False      # a frontend owns the window's close
         self._gap0 = 0.0        # inter-step gap captured at step_begin
         self._t = 0.0           # segment cursor
+        self._annotate = annotate
+        self._range = None      # the open step's ``ds.step`` profiler range
 
     # ------------------------------------------------------------- lifecycle
 
-    def step_begin(self) -> None:
+    def step_begin(self, hold: bool = False) -> None:
         """Open a step window.  Idempotent while a step is open: the
         serving frontend opens the window before its admission/preflight
         work and the engine's own ``step_begin`` then no-ops, so the two
-        layers share one step without coordination."""
+        layers share one step without coordination.  ``hold=True`` (the
+        serial serving tick) keeps the window open through the engine's
+        ``step_end`` until ``step_end(release=True)``, so what the
+        frontend does after the fold (``deliver``) lies inside the step."""
         if self._cur is not None:
+            self._held = self._held or hold
             return
         t = self.clock.now()
         self._cur = StepRecord(self.total_steps)
+        self._held = hold
         if self._last_end is not None:
             self._gap0 = t - self._last_end
             if self._gap0 < 0:   # clock-domain mixup must not corrupt tiling
@@ -195,42 +244,59 @@ class StepAnatomy:
         self._cur.after_idle = self._after_idle
         self._after_idle = False
         self._t = t
+        if self._annotate is not None:
+            self._range = self._annotate("ds.step")
+            self._range.__enter__()
+
+    def _advance(self, name: str) -> float:
+        """Move the cursor to now (and, with a factory, put the instant
+        ``ds.mark.<name>`` into the profile); the seconds it moved by."""
+        t = self.clock.now()
+        if self._annotate is not None:
+            with self._annotate(_MARK_NAMES[name]):
+                pass
+        dt = t - self._t
+        self._t = t
+        return dt if dt > 0 else 0.0
 
     def mark(self, segment: str) -> None:
         """Attribute the cursor interval ``[last mark, now]`` to
         ``segment`` and advance the cursor.  Outside an open step (a
         frontend early-return path) the call is a no-op."""
-        cur = self._cur
-        if cur is None:
-            return
-        t = self.clock.now()
-        dt = t - self._t
-        if dt > 0:
-            cur.segments[segment] = cur.segments.get(segment, 0.0) + dt
-        self._t = t
+        if self._cur is not None:
+            self._cur.segments[segment] += self._advance(segment)
 
     def device_mark(self) -> None:
-        """Attribute the cursor interval to device compute (the blocking
-        output materialization on a real clock)."""
-        cur = self._cur
-        if cur is None:
-            return
-        t = self.clock.now()
-        dt = t - self._t
-        if dt > 0:
-            cur.device_s += dt
-        self._t = t
+        """Attribute the cursor interval to ``device_s``: on a real clock
+        the host's wait at the blocking output materialization."""
+        if self._cur is not None:
+            self._cur.device_s += self._advance("device_wait")
 
-    def note_shape(self, path: str, batch: int, chunk: int) -> None:
-        """Tag the open step with its dispatch shape — the per-(bucket,
-        batch-shape) attribution key.  A step that never dispatches
+    def note_program(self, key: str, path: str, rows_decode: int = 0,
+                     rows_prefill: int = 0, tokens_real: int = 0,
+                     slots: int = 0) -> None:
+        """Tag the open step with the program it dispatches (``key``, as
+        ``InferenceEngineV2._key_label`` prints it: the attribution key)
+        and what the packed batch carries.  A step that never dispatches
         (empty plan) keeps ``path=None`` and is DISCARDED at step_end:
         its host time folds into the next real step's host gap, which is
         exactly what that time is (loop tax without device work)."""
-        if self._cur is not None:
-            self._cur.path = path
-            self._cur.batch = int(batch)
-            self._cur.chunk = int(chunk)
+        cur = self._cur
+        if cur is not None:
+            cur.key, cur.path = key, path
+            cur.rows_decode, cur.rows_prefill = int(rows_decode), int(rows_prefill)
+            cur.tokens_real, cur.slots = int(tokens_real), int(slots)
+
+    def note_tokens(self, out: int, discarded: int = 0, real: int = 0) -> None:
+        """What the fold did with the step's tokens: ``out`` reached a
+        sequence, ``discarded`` were computed and thrown away; ``real``
+        adds positions whose use is known only now (a verify round's
+        accepted + 1 a row)."""
+        cur = self._cur
+        if cur is not None:
+            cur.tokens_out += int(out)
+            cur.tokens_discarded += int(discarded)
+            cur.tokens_real += int(real)
 
     def note_compile(self, key: str, aot: bool = False) -> None:
         """One compile event (the engine's ``_step_fns`` grew an entry).
@@ -262,20 +328,28 @@ class StepAnatomy:
             self._last_end = None
         self._after_idle = True
 
-    def step_end(self) -> Optional[StepRecord]:
+    def step_end(self, release: bool = False) -> Optional[StepRecord]:
         """Close the step window: the residual cursor interval becomes
         ``bookkeeping``, the inter-step gap becomes ``host_gap_s``, and
         ``wall_s`` is the exact component sum (the tiling invariant).
-        Returns the closed record, or None when the step never dispatched
-        (discarded — see :meth:`note_shape`)."""
+        A window a frontend holds (``step_begin(hold=True)``) closes only
+        with ``release=True``.  Returns the closed record, or None when
+        the window stays open or the step never dispatched (discarded —
+        see :meth:`note_program`)."""
         cur = self._cur
-        if cur is None:
+        if cur is None or (self._held and not release):
             return None
         t = self.clock.now()
         tail = t - self._t
         if tail > 0:
             cur.segments["bookkeeping"] += tail
         self._cur = None
+        self._held = False
+        rng, self._range = self._range, None
+        if rng is not None:
+            if cur.path is not None:
+                rng.set_metadata(index=cur.index, key=cur.key, **cur.counts())
+            rng.__exit__(None, None, None)
         if cur.path is None:
             # planned-but-empty step: keep the gap origin where it was so
             # this window folds into the next real step's host gap
@@ -288,17 +362,24 @@ class StepAnatomy:
         return cur
 
     def charge_last_step(self, dt: float) -> Optional[StepRecord]:
-        """Post-hoc device charge for clock-driven frontends: a
-        ``VirtualClock``/``ReplicaClockView`` accounts the step cost via
-        ``clock.on_step`` AFTER the engine step returned, so the serving
-        loop forwards the charged seconds here.  The last record's device
-        and wall grow by ``dt`` and the gap origin re-anchors at the
-        clock's current reading (a VirtualClock just advanced by the
-        charge; a deferred ReplicaClockView has not, and its round
-        advance shows up in the next step's host gap — the round-
-        quantization the fleet simulator actually imposes)."""
+        """Device charge for clock-driven frontends: a ``VirtualClock``/
+        ``ReplicaClockView`` accounts the step cost via ``clock.on_step``
+        AFTER the engine step returned, so the serving loop forwards the
+        charged seconds here.  They go to the step that last dispatched:
+        the open one (a held window: its cursor snaps to the clock, so
+        the charged advance lands in no segment), else the last closed
+        record, whose device and wall grow by ``dt`` while the gap origin
+        re-anchors at the clock's current reading (a VirtualClock just
+        advanced by the charge; a deferred ReplicaClockView has not, and
+        its round advance shows up in the next step's host gap — the
+        round-quantization the fleet simulator actually imposes)."""
         if not dt >= 0:
             raise ValueError(f"step charge cannot be negative (dt={dt})")
+        cur = self._cur
+        if cur is not None and cur.path is not None:
+            cur.device_s += dt
+            self._t = self.clock.now()
+            return cur
         if not self.steps:
             return None
         rec = self.steps[-1]
@@ -334,6 +415,10 @@ class StepAnatomy:
         self._last_end = None
         self._after_idle = False
         self._cur = None
+        self._held = False
+        rng, self._range = self._range, None
+        if rng is not None:
+            rng.__exit__(None, None, None)
 
     # --------------------------------------------------------------- intake
 
@@ -361,16 +446,17 @@ class StepAnatomy:
         return self.total_host_gap_s / self.total_wall_s
 
     def by_shape(self) -> Dict[str, dict]:
-        """Per-(path, batch, chunk) aggregation over the RETAINED steps
-        (the deque window; ``dropped_steps`` tells the reader when that
-        window is not the whole run).  Deterministic key order."""
+        """Per-program-key aggregation over the RETAINED steps (the deque
+        window; ``dropped_steps`` tells the reader when that window is
+        not the whole run).  Deterministic key order."""
         out: Dict[str, dict] = {}
         for rec in self.steps:
-            agg = out.get(rec.shape_key)
+            agg = out.get(rec.key)
             if agg is None:
-                agg = out[rec.shape_key] = {
+                agg = out[rec.key] = {
                     "steps": 0, "wall_s": 0.0, "host_s": 0.0,
                     "device_s": 0.0, "host_gap_s": 0.0, "compiles": 0,
+                    **{c: 0 for c in COUNTS},
                     "segments": {s: 0.0 for s in HOST_SEGMENTS}}
             agg["steps"] += 1
             agg["wall_s"] += rec.wall_s
@@ -378,12 +464,14 @@ class StepAnatomy:
             agg["device_s"] += rec.device_s
             agg["host_gap_s"] += rec.host_gap_s
             agg["compiles"] += rec.compiles
+            for c in COUNTS:
+                agg[c] += getattr(rec, c)
             for s in HOST_SEGMENTS:
                 agg["segments"][s] += rec.segments[s]
         for key in sorted(out):
             agg = out[key]
             wall = agg["wall_s"]
-            rounded = {
+            out[key] = {
                 "steps": agg["steps"],
                 "wall_s": round(wall, 9),
                 "host_s": round(agg["host_s"], 9),
@@ -392,10 +480,10 @@ class StepAnatomy:
                 "host_gap_fraction": round(agg["host_gap_s"] / wall, 6)
                 if wall > 0 else None,
                 "compiles": agg["compiles"],
+                **{c: agg[c] for c in COUNTS},
                 "segments": {s: round(agg["segments"][s], 9)
                              for s in HOST_SEGMENTS},
             }
-            out[key] = rounded
         return {k: out[k] for k in sorted(out)}
 
     def summary(self) -> dict:
@@ -417,58 +505,17 @@ class StepAnatomy:
     def to_doc(self) -> dict:
         """The full deterministic export (what ``bench_serving.py
         --anatomy`` commits and ``scripts/step_anatomy.py`` re-verifies):
-        per-step table, compile log, per-shape fold, summary.  Pure data,
-        9-dp rounding, sorted keys downstream.  Schema 2 = the r20
-        segment vocabulary (``aot_compile``/``overlap``) plus the
-        compile log's ``aot`` flag."""
+        per-step table, compile log, per-program fold, summary.  Pure
+        data, 9-dp rounding, sorted keys downstream.  Schema 3 = the
+        program key as a step's identity, the counts, and the ``admit``
+        and ``deliver`` segments."""
         return {
-            "schema": 2,
+            "schema": 3,
             "summary": self.summary(),
             "by_shape": self.by_shape(),
             "steps": [rec.to_row() for rec in self.steps],
             "compiles": [c.to_row() for c in self.compiles],
         }
-
-    # ------------------------------------------------------------ span lift
-
-    def emit_spans(self, tracer, trace_id: Optional[int] = None,
-                   track: str = "anatomy") -> int:
-        """Lift the retained per-step records into tracer spans: one
-        ``anatomy/step`` parent per step with its components laid
-        end-to-end inside the window.  Naming contract: only
-        ``host_gap`` and ``compile_wait`` — the two step-anatomy entries
-        in the REQUEST-phase taxonomy (``trace_report.PHASES``,
-        ``why_slow.CAUSES``) — emit as ``phase/<name>``; the plain host
-        segments and device compute emit as ``anatomy/<name>``, which
-        the request folds ignore by design.  So anatomy spans sharing a
-        trace file with request traces never surface as ``unknown:<p>``:
-        they either fold by name or are skipped, never half-parsed.
-        Returns spans emitted; no-op (0) on a disabled tracer."""
-        if not getattr(tracer, "enabled", False):
-            return 0
-        tid = trace_id if trace_id is not None else tracer.new_trace_id()
-        n = 0
-        for rec in self.steps:
-            t0 = rec.end_ts - rec.wall_s
-            parent = tracer.add_span(
-                "anatomy/step", tid, t0, rec.end_ts, track=track,
-                attrs={"shape": rec.shape_key, "compiles": rec.compiles,
-                       "after_idle": rec.after_idle})
-            n += 1
-            t = t0
-            parts = [("phase/host_gap", rec.host_gap_s)]
-            parts += [("phase/compile_wait" if s == "compile_wait"
-                       else f"anatomy/{s}", rec.segments[s])
-                      for s in HOST_SEGMENTS]
-            parts.append(("anatomy/device", rec.device_s))
-            for name, dur in parts:
-                if dur <= 0:
-                    continue
-                tracer.add_span(name, tid, t, t + dur,
-                                parent_id=parent.span_id, track=track)
-                t += dur
-                n += 1
-        return n
 
 
 class NullStepAnatomy:
@@ -484,7 +531,7 @@ class NullStepAnatomy:
     steady_state_recompiles = 0
     steady = False
 
-    def step_begin(self) -> None:
+    def step_begin(self, hold=False) -> None:
         pass
 
     def mark(self, segment) -> None:
@@ -493,7 +540,11 @@ class NullStepAnatomy:
     def device_mark(self) -> None:
         pass
 
-    def note_shape(self, path, batch, chunk) -> None:
+    def note_program(self, key, path, rows_decode=0, rows_prefill=0,
+                     tokens_real=0, slots=0) -> None:
+        pass
+
+    def note_tokens(self, out, discarded=0, real=0) -> None:
         pass
 
     def note_compile(self, key, aot=False) -> None:
@@ -502,7 +553,7 @@ class NullStepAnatomy:
     def note_idle(self) -> None:
         pass
 
-    def step_end(self) -> None:
+    def step_end(self, release=False) -> None:
         return None
 
     def charge_last_step(self, dt) -> None:
@@ -528,11 +579,8 @@ class NullStepAnatomy:
         return {}
 
     def to_doc(self) -> dict:
-        return {"schema": 2, "summary": {}, "by_shape": {}, "steps": [],
+        return {"schema": 3, "summary": {}, "by_shape": {}, "steps": [],
                 "compiles": []}
-
-    def emit_spans(self, tracer, trace_id=None, track="anatomy") -> int:
-        return 0
 
 
 NULL_ANATOMY = NullStepAnatomy()

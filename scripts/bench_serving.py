@@ -207,7 +207,7 @@ def run_spec_pair(make_engine, clock_factory, arrivals, rate, max_queue_depth,
 def run_anatomy_leg(make_engine, clock_factory, arrivals, rate,
                     max_queue_depth, dryrun, out_path):
     """Step-anatomy receipt (docs/OBSERVABILITY.md "Step anatomy"),
-    schema v2: the SAME workload served twice — the strictly serial tick
+    schema v3: the SAME workload served twice — the strictly serial tick
     loop and the async double-buffered one (``async_dispatch=True``) —
     each leg AOT-warmed (``warm_all``: compile set closed up front),
     declared steady, reset, then measured.  Commits
@@ -224,10 +224,11 @@ def run_anatomy_leg(make_engine, clock_factory, arrivals, rate,
       no step may pay a JIT compile — the AOT regression guard;
     * a **wall-clock comparison**: the same two modes on a ``WallClock``
       burst (all-at-once arrivals, so steps run back-to-back), where the
-      pipelined host-gap fraction must land STRICTLY below serial at
-      equal completions — the Python loop tax measurably hidden under
-      device time.  Real timings vary run to run; the ordering is the
-      receipt.  Under ``--dryrun``'s VirtualClock the primary legs' host
+      pipelined ``overlap`` share of wall time must land STRICTLY above
+      the serial loop's (0: it runs no host work under a dispatch in
+      flight) at equal completions — the Python loop tax measurably
+      hidden under device time.  Real timings vary run to run; the
+      ordering is the receipt.  Under ``--dryrun``'s VirtualClock the primary legs' host
       segments and gaps are 0 BY CONSTRUCTION, so they pin the shape
       census, parity, tiling and the recompile guard instead;
     * byte-identical regeneration of the virtual legs (each runs twice).
@@ -317,8 +318,8 @@ def run_anatomy_leg(make_engine, clock_factory, arrivals, rate,
             for i in done_both)
 
     # wall-clock after-leg: the same two modes on a WallClock burst.  All
-    # arrivals land at t=0 so the loop never idles — every inter-step gap
-    # is loop tax, which is exactly what the pipelined mode must hide.
+    # arrivals land at t=0 so the loop never idles — the caller's loop
+    # between two ticks is loop tax, which the pipelined mode must hide.
     # Retried up to 3x before the strict assert: one noisy scheduler
     # stall on a shared box must not fail artifact regeneration.
     burst = [dict(a, arrival_ts=0.0, deadline=None)
@@ -329,27 +330,27 @@ def run_anatomy_leg(make_engine, clock_factory, arrivals, rate,
             False, make_clock=WallClock, runs=burst, queue_depth=256))
         _, _, w_pipe_sum, w_pipe_out, _ = (w_pipe := one_run(
             True, make_clock=WallClock, runs=burst, queue_depth=256))
-        g_ser = sa.fold(w_ser[0])["totals"]["host_gap_fraction"] or 0.0
-        g_pipe = sa.fold(w_pipe[0])["totals"]["host_gap_fraction"] or 0.0
+        g_ser = sa.fold(w_ser[0])["totals"]["overlap_fraction"] or 0.0
+        g_pipe = sa.fold(w_pipe[0])["totals"]["overlap_fraction"] or 0.0
         wall = {
-            "serial_host_gap_fraction": round(g_ser, 6),
-            "pipelined_host_gap_fraction": round(g_pipe, 6),
+            "serial_overlap_fraction": round(g_ser, 6),
+            "pipelined_overlap_fraction": round(g_pipe, 6),
             "serial_completed": w_ser_sum["completed"],
             "pipelined_completed": w_pipe_sum["completed"],
             "serial_goodput_rps": w_ser_sum["goodput_rps"],
             "pipelined_goodput_rps": w_pipe_sum["goodput_rps"],
             "n_requests": len(burst),
             "note": "wall-clock timings vary across runs; the receipt is "
-                    "the ordering (pipelined strictly below serial) at "
+                    "the ordering (pipelined strictly above serial) at "
                     "equal completions",
         }
-        if g_pipe < g_ser and \
+        if g_pipe > g_ser and \
                 w_ser_sum["completed"] == w_pipe_sum["completed"] and \
                 w_ser_out == w_pipe_out:
             break
-    assert wall["pipelined_host_gap_fraction"] \
-        < wall["serial_host_gap_fraction"], (
-        "pipelined wall-clock host_gap_fraction not strictly below serial: "
+    assert wall["pipelined_overlap_fraction"] \
+        > wall["serial_overlap_fraction"], (
+        "pipelined wall-clock overlap fraction not strictly above serial: "
         + str(wall))
     assert w_ser_out == w_pipe_out, \
         "wall-clock legs diverged on token streams"
@@ -359,7 +360,7 @@ def run_anatomy_leg(make_engine, clock_factory, arrivals, rate,
         "metric": "host_gap_fraction",
         "value": pipe_report["totals"]["host_gap_fraction"],
         "unit": "fraction_of_wall",
-        "schema_version": 2,
+        "schema_version": 3,
         "workload": {"n_requests": len(arrivals), "arrival_rate": rate,
                      "dryrun": bool(dryrun), "virtual_clock": bool(dryrun),
                      "deadlines": False},
@@ -373,8 +374,8 @@ def run_anatomy_leg(make_engine, clock_factory, arrivals, rate,
           f"pipelined={pipe_report['n_steps']} parity={parity} "
           f"steady_recompiles="
           f"{[legs[n]['steady_state_recompiles'] for n in ('serial', 'pipelined')]} "
-          f"wall_gap serial={wall['serial_host_gap_fraction']} "
-          f"pipelined={wall['pipelined_host_gap_fraction']} "
+          f"wall_overlap serial={wall['serial_overlap_fraction']} "
+          f"pipelined={wall['pipelined_overlap_fraction']} "
           f"repeat_identical={identical}", flush=True)
     from deepspeed_tpu.resilience.atomic_io import atomic_write_json
     atomic_write_json(out_path, rec, indent=1)

@@ -551,9 +551,9 @@ def _validate_attribution(v):
     return None
 
 
-_ANATOMY_SEGMENTS = ("schedule", "draft_plan", "verify_plan", "aot_compile",
-                     "compile_wait", "dispatch", "sample_accept", "overlap",
-                     "bookkeeping", "promote_wait")
+_ANATOMY_SEGMENTS = ("admit", "schedule", "draft_plan", "verify_plan",
+                     "aot_compile", "compile_wait", "dispatch", "sample_accept",
+                     "deliver", "overlap", "bookkeeping", "promote_wait")
 
 
 def _validate_anatomy_leg(leg, name):
@@ -590,7 +590,7 @@ def _validate_anatomy_leg(leg, name):
                                           + sum(segs[s] for s in _ANATOMY_SEGMENTS)
                                           + row.get("device_s", 0.0))
         if abs(resid) > 1e-6 + pad:
-            return (f"legs.{name}.anatomy.steps[{i}] ({row.get('shape')}): "
+            return (f"legs.{name}.anatomy.steps[{i}] ({row.get('key')}): "
                     f"components do not tile wall_s (residual {resid:g})")
     # the compile log must agree with the declared counter; deliberate AOT
     # warm-up compiles (aot=true) are never steady-state entries
@@ -633,15 +633,19 @@ def _gap_fraction(leg):
 def _validate_step_anatomy(v):
     """The step-anatomy receipt (bench_serving.py run_anatomy_leg ->
     BENCH_STEP_ANATOMY.json, scripts/step_anatomy.py, docs/OBSERVABILITY.md
-    "Step anatomy"), schema v2: the SAME workload served twice — the
+    "Step anatomy"), schema v3: the SAME workload served twice — the
     strictly serial tick loop and the async double-buffered one — each leg
     re-verified for tiling and ZERO steady-state recompiles (the AOT step
     set must be closed in BOTH modes), greedy token streams byte-identical
     between the legs (per request, asserted by the producer and declared
     here), pipelined host-gap fraction no worse than serial, and — when a
-    wall-clock comparison section is present — pipelined host-gap fraction
-    STRICTLY below serial at equal goodput (the loop tax the async
-    dispatch exists to hide under device time)."""
+    wall-clock comparison section is present — a pipelined ``overlap``
+    share of wall time STRICTLY above the serial loop's (which is 0: it
+    never runs host work under a dispatch in flight) at equal goodput:
+    the loop tax the async dispatch exists to hide under device time.
+    (Up to schema v2 the receipt compared host-gap fractions; since the
+    tick's ``admit`` and ``deliver`` are segments of the step the gap is
+    the caller's loop in both modes and orders nothing.)"""
     if not isinstance(v, dict):
         return f"expected step-anatomy object, got {type(v).__name__}"
     for k in ("metric", "value", "unit", "schema_version", "workload",
@@ -649,8 +653,8 @@ def _validate_step_anatomy(v):
               "wall"):
         if k not in v:
             return f"missing step-anatomy key {k!r}"
-    if v["schema_version"] != 2:
-        return f"schema_version {v['schema_version']} != 2"
+    if v["schema_version"] != 3:
+        return f"schema_version {v['schema_version']} != 3"
     if v["greedy_parity"] is not True:
         return ("greedy_parity is not true — the pipelined loop's token "
                 "streams diverged from the serial loop's")
@@ -677,21 +681,21 @@ def _validate_step_anatomy(v):
     wall = v["wall"]
     if wall is not None:
         # the wall-clock after-leg: real timings, so numbers vary across
-        # runs — but the ordering is the receipt.  Strictly below, at
+        # runs — but the ordering is the receipt.  Strictly above, at
         # equal goodput (same completion counts): hiding host work under
         # device time by shedding load would not be a win.
         if not isinstance(wall, dict):
             return f"wall: expected object or null, got {type(wall).__name__}"
-        for k in ("serial_host_gap_fraction", "pipelined_host_gap_fraction",
+        for k in ("serial_overlap_fraction", "pipelined_overlap_fraction",
                   "serial_completed", "pipelined_completed"):
             if not isinstance(wall.get(k), (int, float)) \
                     or isinstance(wall.get(k), bool):
                 return f"wall.{k} is not a number ({wall.get(k)!r})"
-        if not wall["pipelined_host_gap_fraction"] \
-                < wall["serial_host_gap_fraction"]:
-            return (f"wall-clock pipelined host_gap_fraction "
-                    f"{wall['pipelined_host_gap_fraction']} not strictly "
-                    f"below serial {wall['serial_host_gap_fraction']}")
+        if not wall["pipelined_overlap_fraction"] \
+                > wall["serial_overlap_fraction"]:
+            return (f"wall-clock pipelined overlap fraction "
+                    f"{wall['pipelined_overlap_fraction']} not strictly "
+                    f"above serial {wall['serial_overlap_fraction']}")
         if wall["pipelined_completed"] != wall["serial_completed"]:
             return (f"wall-clock legs completed different request counts "
                     f"(serial {wall['serial_completed']} vs pipelined "

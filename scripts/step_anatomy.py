@@ -2,7 +2,7 @@
 """step_anatomy — verify and fold a per-step engine anatomy table.
 
 Input: a step-anatomy document — either the raw
-``StepAnatomy.to_doc()`` export (``{"schema": 1, "steps": [...],
+``StepAnatomy.to_doc()`` export (``{"schema": 3, "steps": [...],
 "compiles": [...]}``) or a committed ``BENCH_STEP_ANATOMY.json`` receipt
 (the same document nested under its ``"anatomy"`` key).
 
@@ -21,11 +21,14 @@ The report does two things, in this order:
    declared ``steady_state_recompiles`` must equal the number of
    ``steady`` entries in the committed compile list.
 
-2. **Fold the anatomy.**  Per (path, batch, chunk) shape: step count,
-   wall/host/device/host-gap seconds, the host-gap fraction (the Python
-   step-loop tax the ROADMAP's AOT serving-step item must shrink), and
-   per-segment totals; plus the overall fractions and the compile
-   summary (warm-up vs steady-state).
+2. **Fold the anatomy.**  Per program key (``step:b16:c128``,
+   ``multi:b16:k8``): step count, wall/host/device/host-gap seconds, the
+   host-gap fraction (what the caller did between two ticks), what the
+   steps carried (rows, real tokens, slots, tokens out and discarded),
+   and per-segment totals; plus the overall fractions (``overlap_fraction``:
+   the share of wall time the pipelined tick hid under a dispatch in
+   flight, 0 in the serial loop) and the compile summary (warm-up vs
+   steady-state).
 
 Output: one deterministic JSON document (sorted keys, no timestamps);
 ``--json`` prints compact bytes byte-identical across repeat runs on the
@@ -39,9 +42,13 @@ import sys
 
 #: must mirror telemetry/step_anatomy.py HOST_SEGMENTS — the fixed
 #: per-step segment vocabulary (a committed row missing one is drift)
-HOST_SEGMENTS = ("schedule", "draft_plan", "verify_plan", "aot_compile",
-                 "compile_wait", "dispatch", "sample_accept", "overlap",
-                 "bookkeeping", "promote_wait")
+HOST_SEGMENTS = ("admit", "schedule", "draft_plan", "verify_plan",
+                 "aot_compile", "compile_wait", "dispatch", "sample_accept",
+                 "deliver", "overlap", "bookkeeping", "promote_wait")
+
+#: must mirror telemetry/step_anatomy.py COUNTS — what a step carried
+COUNTS = ("rows_decode", "rows_prefill", "tokens_real", "slots", "tokens_out",
+          "tokens_discarded")
 
 
 def _anatomy_of(doc):
@@ -70,7 +77,8 @@ def fold(doc, tol=1e-6):
 
     mismatches = []
     by_shape = {}
-    tot = {"wall_s": 0.0, "host_s": 0.0, "device_s": 0.0, "host_gap_s": 0.0}
+    tot = {"wall_s": 0.0, "host_s": 0.0, "device_s": 0.0, "host_gap_s": 0.0,
+           **{c: 0 for c in COUNTS}}
     seg_tot = {s: 0.0 for s in HOST_SEGMENTS}
     for i, row in enumerate(steps):
         segs = row.get("segments") or {}
@@ -90,14 +98,12 @@ def fold(doc, tol=1e-6):
         pad = 0.5e-9 * (len(HOST_SEGMENTS) + 3)
         if abs(residual) > tol + pad:
             mismatches.append({"index": row.get("index", i),
-                               "shape": row.get("shape"),
+                               "key": row.get("key"),
                                "residual": round(residual, 12)})
             continue
-        key = row.get("shape") or (f"{row.get('path')}:b{row.get('batch')}"
-                                   f":c{row.get('chunk')}")
-        agg = by_shape.setdefault(key, {
+        agg = by_shape.setdefault(row.get("key"), {
             "steps": 0, "wall_s": 0.0, "host_s": 0.0, "device_s": 0.0,
-            "host_gap_s": 0.0, "compiles": 0,
+            "host_gap_s": 0.0, "compiles": 0, **{c: 0 for c in COUNTS},
             "segments": {s: 0.0 for s in HOST_SEGMENTS}})
         agg["steps"] += 1
         agg["wall_s"] += wall
@@ -105,6 +111,9 @@ def fold(doc, tol=1e-6):
         agg["device_s"] += dev
         agg["host_gap_s"] += gap
         agg["compiles"] += row.get("compiles", 0)
+        for c in COUNTS:
+            agg[c] += row.get(c, 0)
+            tot[c] += row.get(c, 0)
         for s in HOST_SEGMENTS:
             agg["segments"][s] += segs[s]
         tot["wall_s"] += wall
@@ -127,6 +136,7 @@ def fold(doc, tol=1e-6):
             "host_gap_fraction": round(agg["host_gap_s"] / wall, 6)
             if wall > 0 else None,
             "compiles": agg["compiles"],
+            **{c: agg[c] for c in COUNTS},
             "segments": {s: round(agg["segments"][s], 9)
                          for s in HOST_SEGMENTS},
         }
@@ -153,6 +163,9 @@ def fold(doc, tol=1e-6):
             if wall > 0 else None,
             "device_fraction": round(tot["device_s"] / wall, 6)
             if wall > 0 else None,
+            "overlap_fraction": round(seg_tot["overlap"] / wall, 6)
+            if wall > 0 else None,
+            **{c: tot[c] for c in COUNTS},
             "segments": {s: round(seg_tot[s], 9) for s in HOST_SEGMENTS},
         },
         "by_shape": shapes,

@@ -44,9 +44,9 @@ sys.path.insert(0, os.path.join(os.path.dirname(os.path.abspath(__file__)), ".."
 from deepspeed_tpu.serving.metrics import percentile_summary  # noqa: E402
 
 #: ``host_gap`` / ``compile_wait`` are the step-anatomy phases
-#: (telemetry/step_anatomy.py, ``StepAnatomy.emit_spans``): per-step
-#: host-side loop tax and JIT compile pauses lifted into the trace —
-#: named here so anatomy spans fold instead of breaking the tiling
+#: (telemetry/step_anatomy.py): per-step host-side loop tax and JIT
+#: compile pauses — named here so a trace that carries them as
+#: ``phase/<name>`` spans folds instead of breaking the tiling
 #: ``parked``/``promote`` are the kv-tier phases (serving/kvtier):
 #: host-demoted idle windows and the unhidden slice of the h2d promote
 #: transfer a resume pays (telemetry/spans.py carves them out of

@@ -21,8 +21,13 @@ def test_groups_facade():
     # (the autouse _reset_global_mesh fixture restores the mesh afterwards)
 
 
-def test_nvtx_shim():
-    from deepspeed_tpu.utils.nvtx import instrument_w_nvtx, range_pop, range_push
+def test_nvtx_shim(tmp_path):
+    import glob
+
+    from jax.profiler import ProfileData
+
+    from deepspeed_tpu.utils import nvtx
+    from deepspeed_tpu.utils.nvtx import instrument_w_nvtx, profiler_range, range_pop, range_push
 
     @instrument_w_nvtx
     def f(x):
@@ -32,6 +37,23 @@ def test_nvtx_shim():
     assert f(21) == 42
     range_pop()
     range_pop()  # extra pop is a no-op
+
+    # the wrapped function runs under a jax.profiler.TraceAnnotation (the
+    # host-side analogue of an NVTX range), not under a named_scope
+    assert isinstance(profiler_range("x"), jax.profiler.TraceAnnotation)
+    assert "named_scope" not in open(nvtx.__file__).read().split('"""', 2)[2]
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        assert f(4) == 8
+        with profiler_range("ds.step") as rng:
+            rng.set_metadata(key="step:b4:c1", slots=4)
+    finally:
+        jax.profiler.stop_trace()
+    data = ProfileData.from_file(glob.glob(str(tmp_path / "**" / "*.xplane.pb"), recursive=True)[0])
+    host = {ev.name: dict(ev.stats) for plane in data.planes if plane.name.startswith("/host:")
+            for line in plane.lines for ev in line.events}
+    assert f.__qualname__ in host
+    assert host["ds.step"] == {"key": "step:b4:c1", "slots": 4}
 
 
 def test_legacy_transformer_layer_pre_and_post_ln():

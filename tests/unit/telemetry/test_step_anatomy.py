@@ -4,9 +4,11 @@ construction, host gaps measure inter-step loop tax and exclude idle,
 the compile tracker tags warm-up vs steady-state recompiles (the AOT
 regression guard), the disabled path allocates nothing, the report CLI
 exits 1 on a planted tiling mismatch and prints byte-identical --json,
-and the new ``host_gap``/``compile_wait`` phases fold in
+the ``host_gap``/``compile_wait`` phases fold in
 ``trace_report.py``/``why_slow.py`` instead of surfacing as
-``unknown:<p>``."""
+``unknown:<p>``, the counts of a step against numbers worked by hand,
+``admit`` and ``deliver`` as segments of both ticks, and the profiler
+ranges an ``annotate`` factory sees."""
 
 import importlib.util
 import json
@@ -56,7 +58,7 @@ def test_segments_device_and_gap_tile_wall():
     anat.device_mark()
     clock.advance(0.05)
     anat.mark("sample_accept")
-    anat.note_shape("decode", 4, 1)
+    anat.note_program("step:b4:c1", "decode")
     clock.advance(0.03)            # unmarked residual -> bookkeeping
     rec = anat.step_end()
     assert rec is not None
@@ -68,14 +70,14 @@ def test_segments_device_and_gap_tile_wall():
     assert row["segments"]["bookkeeping"] == pytest.approx(0.03)
     assert row["host_gap_s"] == 0.0            # first step: no predecessor
     assert _tiles(row) and row["wall_s"] == pytest.approx(0.88)
-    assert row["shape"] == "decode:b4:c1"
+    assert row["key"] == "step:b4:c1" and row["path"] == "decode"
 
     # second step: the inter-step window becomes its host gap
     clock.advance(0.3)
     anat.step_begin()
     clock.advance(0.4)
     anat.device_mark()
-    anat.note_shape("decode", 4, 1)
+    anat.note_program("step:b4:c1", "decode")
     rec2 = anat.step_end()
     row2 = rec2.to_row()
     assert row2["host_gap_s"] == pytest.approx(0.3)
@@ -89,7 +91,7 @@ def test_idle_excluded_and_flagged():
     anat.step_begin()
     clock.advance(0.1)
     anat.device_mark()
-    anat.note_shape("decode", 4, 1)
+    anat.note_program("step:b4:c1", "decode")
     anat.step_end()
     clock.advance(5.0)             # arrival gap: the loop idled
     anat.note_idle()
@@ -97,7 +99,7 @@ def test_idle_excluded_and_flagged():
     anat.step_begin()
     clock.advance(0.1)
     anat.device_mark()
-    anat.note_shape("decode", 4, 1)
+    anat.note_program("step:b4:c1", "decode")
     row = anat.step_end().to_row()
     # the 5s idle is excluded; note_idle also reset the gap origin, so the
     # 0.2s of post-idle host work is excluded too (flagged instead)
@@ -112,7 +114,7 @@ def test_idle_excluded_and_flagged():
     anat.mark("schedule")
     clock.advance(0.1)
     anat.device_mark()
-    anat.note_shape("decode", 4, 1)
+    anat.note_program("step:b4:c1", "decode")
     row = anat.step_end().to_row()
     assert row["segments"]["schedule"] == pytest.approx(0.3)
     assert _tiles(row)
@@ -124,7 +126,7 @@ def test_empty_step_discarded_folds_into_next_gap():
     anat.step_begin()
     clock.advance(0.1)
     anat.device_mark()
-    anat.note_shape("decode", 4, 1)
+    anat.note_program("step:b4:c1", "decode")
     anat.step_end()
     # a planned-but-empty step (no dispatch): discarded, not recorded
     anat.step_begin()
@@ -135,7 +137,7 @@ def test_empty_step_discarded_folds_into_next_gap():
     anat.step_begin()
     clock.advance(0.05)
     anat.device_mark()
-    anat.note_shape("decode", 4, 1)
+    anat.note_program("step:b4:c1", "decode")
     row = anat.step_end().to_row()
     assert row["host_gap_s"] == pytest.approx(0.25)
     assert _tiles(row)
@@ -150,7 +152,7 @@ def test_step_begin_idempotent_shared_between_frontend_and_engine():
     anat.step_begin()              # the engine's own call must no-op
     clock.advance(0.1)
     anat.device_mark()
-    anat.note_shape("prefill", 8, 32)
+    anat.note_program("step:b8:c32", "prefill")
     row = anat.step_end().to_row()
     assert row["segments"]["schedule"] == pytest.approx(0.2)
     assert row["device_s"] == pytest.approx(0.1)
@@ -161,7 +163,7 @@ def test_charge_last_step_virtual_clock_contract():
     clock = VirtualClock()
     anat = StepAnatomy(clock=clock)
     anat.step_begin()
-    anat.note_shape("decode", 4, 1)
+    anat.note_program("step:b4:c1", "decode")
     anat.step_end()                # virtual: zero-width so far
     clock.advance(1.5)             # clock.on_step charged the cost
     rec = anat.charge_last_step(1.5)
@@ -171,7 +173,7 @@ def test_charge_last_step_virtual_clock_contract():
     # the gap origin re-anchored at the charged clock: the next step
     # starts gap-free
     anat.step_begin()
-    anat.note_shape("decode", 4, 1)
+    anat.note_program("step:b4:c1", "decode")
     anat.step_end()
     clock.advance(1.0)
     row2 = anat.charge_last_step(1.0).to_row()
@@ -187,7 +189,7 @@ def test_retention_bound_and_lifetime_totals():
         anat.step_begin()
         clock.advance(1.0)
         anat.device_mark()
-        anat.note_shape("decode", 4, 1)
+        anat.note_program("step:b4:c1", "decode")
         anat.step_end()
     assert len(anat.steps) == 4 and anat.dropped_steps == 3
     assert anat.total_steps == 7
@@ -206,7 +208,7 @@ def test_compile_tracker_warmup_vs_steady_and_reset():
     assert len(anat.compiles) == 2  # compile log survives the reset
     anat.step_begin()
     anat.note_compile("step:b8:c32")
-    anat.note_shape("mixed", 8, 32)
+    anat.note_program("step:b8:c32", "mixed")
     anat.step_end()
     assert anat.steady_state_recompiles == 1
     rows = [c.to_row() for c in anat.compiles]
@@ -219,7 +221,7 @@ def test_null_anatomy_allocates_nothing():
         for _ in range(n):
             NULL_ANATOMY.step_begin()
             NULL_ANATOMY.mark("schedule")
-            NULL_ANATOMY.note_shape("decode", 4, 1)
+            NULL_ANATOMY.note_program("step:b4:c1", "decode")
             NULL_ANATOMY.device_mark()
             NULL_ANATOMY.note_compile("k")
             NULL_ANATOMY.step_end()
@@ -257,7 +259,8 @@ def _sample_doc():
         anat.mark("dispatch")
         clock.advance(0.5)
         anat.device_mark()
-        anat.note_shape("decode" if i % 2 else "prefill", 4, 1 if i % 2 else 32)
+        anat.note_program("step:b4:c1" if i % 2 else "step:b4:c32",
+                          "decode" if i % 2 else "prefill")
         anat.step_end()
         clock.advance(0.05)        # inter-step loop tax -> next host gap
     return anat.to_doc()
@@ -269,7 +272,7 @@ def test_report_folds_and_verifies():
     report = sa.fold(doc)
     assert report["verification"]["mismatches"] == 0
     assert report["n_steps"] == 5
-    assert set(report["by_shape"]) == {"decode:b4:c1", "prefill:b4:c32"}
+    assert set(report["by_shape"]) == {"step:b4:c1", "step:b4:c32"}
     for agg in report["by_shape"].values():
         assert 0.0 <= agg["host_gap_fraction"] <= 1.0
     assert report["compiles"] == {"total": 1, "warmup": 1, "steady_state": 0,
@@ -310,11 +313,11 @@ def test_cli_byte_identical_and_sabotage_exit1(tmp_path):
 
 
 def test_schema_validator_catches_anatomy_drift(tmp_path):
-    """BENCH_STEP_ANATOMY.json (schema v2, serial + pipelined legs) is
+    """BENCH_STEP_ANATOMY.json (schema v3, serial + pipelined legs) is
     schema-enforced: the committed artifact passes; a planted tiling
     break, steady recompile, parity break, determinism flag, or a wall
-    comparison where pipelining did not strictly shrink the host gap
-    fails."""
+    comparison where pipelining hid no more host work than the serial
+    loop fails."""
     spec = importlib.util.spec_from_file_location(
         "check_bench_schema", os.path.join(REPO_ROOT, "scripts",
                                            "check_bench_schema.py"))
@@ -344,8 +347,8 @@ def test_schema_validator_catches_anatomy_drift(tmp_path):
     bad["greedy_parity"] = False
     assert any("greedy" in e for e in errors_for(bad))
     bad = json.loads(json.dumps(good))
-    bad["wall"]["pipelined_host_gap_fraction"] = \
-        bad["wall"]["serial_host_gap_fraction"]
+    bad["wall"]["pipelined_overlap_fraction"] = \
+        bad["wall"]["serial_overlap_fraction"]
     assert any("strictly" in e for e in errors_for(bad))
     # an AOT warm-up compile mislabeled as a steady-state recompile
     bad = json.loads(json.dumps(good))
@@ -404,40 +407,126 @@ def test_trace_report_knows_anatomy_phases():
     assert cp["compile_wait"]["total_s"] == pytest.approx(0.5)
 
 
-def test_emit_spans_fold_clean_in_reports():
-    """The recorder's own span lift produces phase names both report
-    tools fold without unknowns (anatomy traces carry no request root,
-    so the request folds simply skip them — but the phases must parse)."""
+class _FakeRange:
+    """What ``utils/nvtx.py::profiler_range`` gives, recorded: open and
+    close order, and the metadata set before the close."""
+
+    def __init__(self, log, name):
+        self.log, self.name, self.meta = log, name, {}
+
+    def __enter__(self):
+        self.log.append(("open", self.name, None))
+        return self
+
+    def __exit__(self, *exc):
+        self.log.append(("close", self.name, dict(self.meta)))
+        return False
+
+    def set_metadata(self, **kw):
+        self.meta.update(kw)
+
+
+def test_annotate_factory_sees_step_and_marks_nested_with_counts():
+    """With an ``annotate`` factory the recorder writes one ``ds.step``
+    range a step, opened at step_begin and closed at step_end with the
+    index, key and counts as metadata, and inside it one instant
+    ``ds.mark.<segment>`` at every mark, in order (a reader rebuilds the
+    segments as the intervals between marks).  A step that never
+    dispatched closes its range without metadata."""
     clock = VirtualClock()
-    anat = StepAnatomy(clock=clock)
+    log = []
+    anat = StepAnatomy(clock=clock, annotate=lambda name: _FakeRange(log, name))
     anat.step_begin()
     clock.advance(0.2)
-    anat.mark("compile_wait")
+    anat.mark("admit")
+    anat.mark("schedule")
+    anat.note_program("multi:b4:k8", "multi_decode", rows_decode=3,
+                      tokens_real=24, slots=32)
+    anat.mark("dispatch")
     clock.advance(0.8)
     anat.device_mark()
-    anat.note_shape("decode", 4, 1)
+    anat.note_tokens(20, 4)
+    anat.mark("sample_accept")
     anat.step_end()
-    tracer = Tracer(clock=clock)
-    n = anat.emit_spans(tracer, track="anatomy")
-    assert n >= 3
-    names = {s.name for s in tracer.spans}
-    assert "anatomy/step" in names and "phase/compile_wait" in names
-    # children tile the parent window exactly
-    parent = next(s for s in tracer.spans if s.name == "anatomy/step")
-    kids = [s for s in tracer.spans if s.parent_id == parent.span_id]
-    assert sum(k.end_ts - k.start_ts for k in kids) == \
-        pytest.approx(parent.end_ts - parent.start_ts)
+    events = [(kind, name) for kind, name, _ in log]
+    assert events == [
+        ("open", "ds.step"),
+        ("open", "ds.mark.admit"), ("close", "ds.mark.admit"),
+        ("open", "ds.mark.schedule"), ("close", "ds.mark.schedule"),
+        ("open", "ds.mark.dispatch"), ("close", "ds.mark.dispatch"),
+        ("open", "ds.mark.device_wait"), ("close", "ds.mark.device_wait"),
+        ("open", "ds.mark.sample_accept"), ("close", "ds.mark.sample_accept"),
+        ("close", "ds.step")]
+    assert log[-1][2] == {"index": 0, "key": "multi:b4:k8", "rows_decode": 3,
+                          "rows_prefill": 0, "tokens_real": 24, "slots": 32,
+                          "tokens_out": 20, "tokens_discarded": 4}
+    # the counts ride the row and the per-program fold too
+    row = anat.last_step.to_row()
+    assert (row["tokens_real"], row["slots"], row["tokens_out"],
+            row["tokens_discarded"]) == (24, 32, 20, 4)
+    assert anat.by_shape()["multi:b4:k8"]["slots"] == 32
+    # an empty step: a range, no metadata, no record
+    del log[:]
+    anat.step_begin()
+    assert anat.step_end() is None
+    assert log == [("open", "ds.step", None), ("close", "ds.step", {})]
 
 
-def test_recorder_ring_gets_anatomy_track():
-    """ServingEngine mirrors closed steps onto the flight recorder's
-    ``anatomy/<track>`` ring (here driven directly via the recorder API
-    the frontend uses)."""
+def test_null_anatomy_never_calls_the_factory(tiny_serving):
+    """The disabled path builds no range: an engine without a recorder
+    serves with ``NULL_ANATOMY``, which has no factory to call (the
+    tracemalloc test above pins that it allocates nothing either)."""
+    eng = tiny_serving()
+    assert eng.anatomy is NULL_ANATOMY and not hasattr(NULL_ANATOMY, "_annotate")
+    NULL_ANATOMY.step_begin(hold=True)
+    NULL_ANATOMY.note_program("step:b4:c1", "decode", tokens_real=1, slots=4)
+    NULL_ANATOMY.note_tokens(1, 0, real=1)
+    assert NULL_ANATOMY.step_end(release=True) is None
+    assert eng.generate([[1, 2, 3]], max_new_tokens=2) and NULL_ANATOMY.total_steps == 0
+
+
+def test_held_window_closes_only_on_release():
+    """The serial serving tick holds the window: the engine's own
+    step_end no-ops, a clock charge lands on the OPEN step, and what the
+    frontend marks afterwards (``deliver``) lies inside the step."""
     clock = VirtualClock()
+    anat = StepAnatomy(clock=clock)
+    anat.step_begin(hold=True)
+    clock.advance(0.1)
+    anat.mark("admit")
+    anat.step_begin()                      # the engine's begin: no-op
+    anat.note_program("step:b4:c1", "decode", rows_decode=1, tokens_real=1, slots=4)
+    assert anat.step_end() is None         # the engine's end: held
+    clock.advance(1.0)                     # clock.on_step charged the cost
+    assert anat.charge_last_step(1.0).index == 0
+    clock.advance(0.05)
+    anat.mark("deliver")
+    row = anat.step_end(release=True).to_row()
+    assert row["segments"]["admit"] == pytest.approx(0.1)
+    assert row["device_s"] == pytest.approx(1.0)
+    assert row["segments"]["deliver"] == pytest.approx(0.05)
+    assert _tiles(row) and row["wall_s"] == pytest.approx(1.15)
+    assert row["end_ts"] == pytest.approx(1.15)
+
+
+def test_flight_recorder_holds_no_copy_of_the_steps(tiny_serving):
+    """One representation: the steps are tabled by ``to_doc()`` (and
+    drawn in the profiler's trace); ``ServingEngine`` mirrors none of
+    them onto the flight recorder or the tracer."""
+    from deepspeed_tpu.serving import ServingEngine
+
+    eng = tiny_serving()
+    clock = VirtualClock()
+    anat = eng.set_anatomy(StepAnatomy(clock=clock))
+    tracer = Tracer(clock=clock)
     rec = FlightRecorder(clock=clock, max_per_track=8)
-    rec.span("anatomy/step", "anatomy/replica0", 0.0, 1.0,
-             attrs={"shape": "decode:b4:c1"})
-    assert [s.name for s in rec.track("anatomy/replica0")] == ["anatomy/step"]
+    serve = ServingEngine(eng, clock=clock, tracer=tracer, recorder=rec)
+    reqs = serve.run([{"arrival_ts": 0.0, "prompt": [1, 2, 3], "max_new_tokens": 3}])
+    assert reqs[0].state.value == "done" and anat.total_steps > 0
+    assert not [t for t in rec.summary()["tracks"] if t.startswith("anatomy/")]
+    assert not [sp for sp in tracer.spans if sp.name.startswith("anatomy/")]
+    assert not hasattr(anat, "emit_spans")
+    assert anat.last_step.to_row()["key"].startswith("step:b")
 
 
 # ----------------------------- serving-engine integration (tiny model)
@@ -462,13 +551,13 @@ def tiny_serving():
     params = LlamaForCausalLM(cfg).init(jax.random.PRNGKey(0),
                                         jnp.zeros((1, 8), jnp.int32))
 
-    def make():
+    def make(k=1):
         kv = PagedKVConfig(num_pages=40, page_size=4, max_pages_per_seq=16)
         sched = SchedulerConfig(token_budget=64, max_seqs=4, prefill_chunk=8,
                                 decode_bucket=2)
         return build_engine(cfg, params, RaggedInferenceEngineConfig(
             kv=kv, scheduler=sched, kv_dtype=jnp.float32,
-            decode_steps_per_dispatch=1, max_new_tokens=6))
+            decode_steps_per_dispatch=k, max_new_tokens=6))
     return make
 
 
@@ -507,10 +596,8 @@ def test_serving_anatomy_tiles_and_guards_recompiles(tiny_serving):
     assert metrics.counter("engine/recompiles").value == \
         len(anat.compiles) - warm_compiles
     assert sum(r["compiles"] for r in doc["steps"]) >= 1
-    # EVERY closed step mirrored onto the flight-recorder anatomy track
-    # (not just the newest per fold — crash-scoped dumps need them all)
-    assert len(recorder.track("anatomy/serving")) == \
-        min(anat.total_steps, recorder.max_per_track)
+    # one representation: no copy of the steps on the flight recorder
+    assert recorder.track("anatomy/serving") == []
     # kv gauges export
     serve.export_kv_gauges()
     assert 0.0 <= metrics.gauge("kv/page_occupancy").value <= 1.0
@@ -525,3 +612,162 @@ def test_engine_anatomy_disabled_by_default(tiny_serving):
     assert eng.anatomy.total_steps == 0
     eng.set_anatomy(None)
     assert eng.anatomy is NULL_ANATOMY
+
+
+def _counts(rec):
+    return (rec.key, rec.path, rec.rows_decode, rec.rows_prefill,
+            rec.tokens_real, rec.slots, rec.tokens_out, rec.tokens_discarded)
+
+
+def test_counts_of_single_and_mixed_steps_by_hand(tiny_serving):
+    """Prompts of 3 and 12 tokens, chunk 8, batch bucket 2, 3 tokens
+    each.  Step 0 prefills 3 + 8 positions in 2 x 8 slots and the short
+    prompt's first token comes out; step 1 is mixed (one decode row, the
+    long prompt's last 4 positions) and gives two tokens; steps 2 and 3
+    decode the two rows, one of which ends after step 2."""
+    eng = tiny_serving()
+    anat = eng.set_anatomy(StepAnatomy(clock=VirtualClock()))
+    outs = eng.generate([[1, 2, 3], list(range(1, 13))], max_new_tokens=3)
+    got = [_counts(r) for r in anat.steps]
+    assert got == [
+        ("step:b2:c8", "prefill", 0, 2, 11, 16, 1, 0),
+        ("step:b2:c8", "mixed", 1, 1, 5, 16, 2, 0),
+        ("step:b2:c1", "decode", 2, 0, 2, 2, 2, 0),
+        ("step:b2:c1", "decode", 1, 0, 1, 2, 1, 0)]
+    assert sum(r.tokens_out for r in anat.steps) == sum(len(o) for o in outs) == 6
+    fold = anat.by_shape()
+    assert fold["step:b2:c8"]["tokens_real"] == 16 and fold["step:b2:c8"]["slots"] == 32
+    assert all(r.tokens_real <= r.slots for r in anat.steps)
+
+
+def test_counts_of_a_fused_dispatch_with_overshoot(tiny_serving):
+    """k = 4 fused decode steps, 6 tokens asked: the prefill gives the
+    first, one fused dispatch four more, and the last dispatch runs the
+    full rung of 4 for the one token still missing: 3 discarded."""
+    eng = tiny_serving(k=4)
+    anat = eng.set_anatomy(StepAnatomy(clock=VirtualClock()))
+    outs = eng.generate([[1, 2, 3]], max_new_tokens=6)
+    got = [_counts(r) for r in anat.steps]
+    assert got == [
+        ("step:b2:c8", "prefill", 0, 1, 3, 16, 1, 0),
+        ("multi:b2:k4", "multi_decode", 1, 0, 4, 8, 4, 0),
+        ("multi:b2:k4", "multi_decode", 1, 0, 4, 8, 1, 3)]
+    assert sum(r.tokens_out for r in anat.steps) == len(outs[0]) == 6
+    assert sum(r.tokens_discarded for r in anat.steps) == 3
+
+
+class _TickingClock:
+    """A real-clock stand-in that is deterministic: every reading is one
+    millisecond after the last, ``on_step`` charges nothing (as
+    ``WallClock``), so every host segment between two readings is > 0."""
+
+    def __init__(self):
+        self.t = 0.0
+
+    def now(self):
+        self.t += 1e-3
+        return self.t
+
+    def wait_until(self, ts):
+        self.t = max(self.t, ts)
+
+    def on_step(self, cost):
+        return None
+
+
+@pytest.mark.parametrize("async_dispatch", [False, True], ids=["serial", "pipelined"])
+def test_admit_and_deliver_are_segments_in_both_ticks(tiny_serving, async_dispatch):
+    """The tick's expiry + admission and its delivery are named segments
+    of the step in the serial and in the pipelined tick, the tiling stays
+    exact, and ``overlap`` (the caller's loop under a dispatch in flight)
+    exists only in the pipelined one."""
+    from deepspeed_tpu.serving import ServingConfig, ServingEngine
+
+    eng = tiny_serving()
+    clock = _TickingClock()
+    anat = eng.set_anatomy(StepAnatomy(clock=clock))
+    serve = ServingEngine(eng, clock=clock,
+                          config=ServingConfig(async_dispatch=async_dispatch))
+    reqs = serve.run([{"arrival_ts": 0.0, "prompt": [1 + i, 2, 3, 4, 5],
+                       "max_new_tokens": 4} for i in range(3)])
+    assert all(r.state.value == "done" for r in reqs)
+    rows = [r.to_row() for r in anat.steps]
+    assert rows and all(_tiles(r, tol=1e-8) for r in rows)
+    assert sum(r["segments"]["admit"] for r in rows) > 0
+    assert sum(r["segments"]["deliver"] for r in rows) > 0
+    assert (sum(r["segments"]["overlap"] for r in rows) > 0) == async_dispatch
+    if not async_dispatch:
+        # the whole tick lies in the step: admit first, deliver last, so
+        # the gap is one clock reading of the caller's loop per tick
+        assert all(r["segments"]["admit"] > 0 and r["segments"]["deliver"] > 0
+                   for r in rows)
+        assert max(r["host_gap_s"] for r in rows[1:]) < 0.01
+    assert sum(r["tokens_out"] for r in rows) == sum(len(r.tokens) for r in reqs)
+
+
+# ------------------------------- names the device trace reads (XLA Modules, kernels)
+
+
+def test_step_programs_carry_their_key_as_a_name(tiny_serving):
+    """Each builder names its function after the program key before
+    ``jax.jit``, so the lowered module (what the device trace's ``XLA
+    Modules`` line shows) is ``jit_ds_step_b2_c8`` and not ``jit_step``."""
+    from deepspeed_tpu.inference.v2.engine_v2 import _named
+
+    eng = tiny_serving(k=4)
+    assert {eng._key_label(k) for k in eng.step_shape_set()} >= {
+        "step:b2:c1", "step:b2:c8", "multi:b2:k4"}
+    assert "jit_ds_step_b2_c8" in eng._aot_lower((2, 8)).as_text()[:400]
+    assert "jit_ds_step_b2_c1" in eng._aot_lower((2, 1)).as_text()[:400]
+    assert "jit_ds_multi_b2_k4" in eng._aot_lower(("multi", 2, 4)).as_text()[:400]
+    assert eng._build_verify_jit(2, 5).__name__ == "ds_verify_b2_w5"
+    assert eng._compiled_step(4, 8).__name__ == "ds_step_b4_c8"
+    assert _named(lambda x: x, "multi:b16:k8").__name__ == "ds_multi_b16_k8"
+
+
+def test_training_step_functions_carry_ds_names():
+    import jax
+    import numpy as np
+
+    import deepspeed_tpu as ds
+    from deepspeed_tpu.models.llama import PRESETS, LlamaForCausalLM
+
+    eng, _, _, _ = ds.initialize(model=LlamaForCausalLM(PRESETS["tiny"]), config={
+        "train_batch_size": 8, "optimizer": {"type": "Adam", "params": {"lr": 1e-3}},
+        "zero_optimization": {"stage": 1}})
+    ids = np.random.default_rng(0).integers(0, 256, (8, 16), dtype=np.int32)
+    batch = {"input_ids": ids, "labels": ids}
+    eng._ensure_ready(batch)
+    assert eng._train_step_fn.__name__ == "ds_train_step"
+    assert eng._accum_fn.__name__ == "ds_accum"
+    assert eng._apply_step_fn.__name__ == "ds_apply"
+    assert "jit_ds_train_step" in eng._train_step_fn.lower(eng.state, batch).as_text()[:400]
+    # train_batch runs under ds.train_step / ds.dispatch
+    # ranges: inactive TraceMes without a profile, and the loss comes out
+    assert np.isfinite(float(jax.device_get(eng.train_batch(batch=batch))))
+
+
+def test_pallas_kernels_carry_ds_names():
+    """Every ``pl.pallas_call`` in the paged and flash kernels passes
+    ``name=``: read from the jaxpr (the CPU traces what the TPU lowers)."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    from deepspeed_tpu.ops.flash_attention import flash_attention
+    from deepspeed_tpu.ops.paged_attention import paged_attention_pallas
+
+    rng = np.random.default_rng(0)
+    q = jnp.asarray(rng.normal(size=(1, 128, 4, 64)), jnp.float32)
+    loss = lambda q: flash_attention(q, q, q, causal=True, block_q=32, block_k=32,
+                                     interpret=True).sum()
+    text = str(jax.make_jaxpr(jax.grad(loss))(q))
+    assert all(n in text for n in ("ds_flash_fwd", "ds_flash_dq", "ds_flash_dkv"))
+
+    pages = jnp.zeros((5, 8, 2, 2, 32), jnp.float32)
+    qd = jnp.asarray(rng.normal(size=(2, 1, 4, 32)), jnp.float32)
+    bt = jnp.asarray([[1, 2], [3, 4]], jnp.int32)
+    sp, cl = jnp.asarray([3, 9], jnp.int32), jnp.asarray([1, 1], jnp.int32)
+    text = str(jax.make_jaxpr(lambda q, p: paged_attention_pallas(
+        q, p, bt, sp, cl, 8, interpret=True))(qd, pages))
+    assert "ds_paged_attention" in text
