@@ -268,6 +268,9 @@ class InferenceEngineV2:
                 model = build_cache_model(cfg, kvcfg.page_size)
         self.cfg = cfg
         self.model = model
+        # experts a token is routed to (0: no expert layer), for the step
+        # records' expert_rows
+        self._experts_per_tok = int(getattr(cfg, "num_experts_per_tok", 0) or 0)
         # weight-only-quantized checkpoints: int8 stays in HBM, dequant is
         # traced into the step program (ref: inference/quantization kernels)
         if isinstance(params, QuantizedParams):
@@ -832,7 +835,8 @@ class InferenceEngineV2:
             # only); discarded: rejected drafts and rows flushed in flight
             n_real = sum(a + 1 for _, a, _ in self.last_spec_round.values())
             anat.note_tokens(sum(len(v) for v in out.values()),
-                             sum(1 + len(d) for d in drafts) - n_real, real=n_real)
+                             sum(1 + len(d) for d in drafts) - n_real, real=n_real,
+                             expert_rows=n_real * self._experts_per_tok)
             anat.mark("sample_accept")
         return out
 
@@ -857,7 +861,7 @@ class InferenceEngineV2:
         if anat.enabled:
             anat.note_program(self._key_label(("multi", batch, k)), "multi_decode",
                               rows_decode=len(seqs), tokens_real=len(seqs) * k,
-                              slots=batch * k)
+                              slots=batch * k, expert_rows=len(seqs) * k * self._experts_per_tok)
         toks, self.cache = self._invoke(fn, self.params, self.cache, jnp.asarray(rb.tokens[:, 0]),
                                         jnp.asarray(rb.start_pos), jnp.asarray(rb.block_tables),
                                         jnp.asarray(rb.chunk_lens), sub)
@@ -1026,9 +1030,11 @@ class InferenceEngineV2:
         if anat.enabled:
             path = ("mixed" if plan.prefill and plan.decode
                     else "prefill" if plan.prefill else "decode")
+            tokens_real = sum(n for _, n in work)
             anat.note_program(self._key_label((batch, chunk)), path,
                               rows_decode=len(plan.decode), rows_prefill=len(plan.prefill),
-                              tokens_real=sum(n for _, n in work), slots=batch * chunk)
+                              tokens_real=tokens_real, slots=batch * chunk,
+                              expert_rows=tokens_real * self._experts_per_tok)
         next_tok, self.cache = self._invoke(fn, self.params, self.cache, jnp.asarray(rb.tokens),
                                             jnp.asarray(rb.start_pos), jnp.asarray(rb.block_tables),
                                             jnp.asarray(rb.chunk_lens), sub)

@@ -30,13 +30,16 @@ class MixtralBlockCache(nn.Module):
     scanned: bool = False
 
     @nn.compact
-    def __call__(self, carry, layer_pages, positions=None, block_table=None, start_pos=None, chunk_lens=None):
+    def __call__(self, carry, layer_pages, positions=None, block_table=None, start_pos=None, chunk_lens=None,
+                 stacked_banks=None, layer=None):
         cfg = self.cfg
         x = carry
         attn_out, layer_pages = LlamaAttentionCache(cfg.as_llama(), self.page_size, name="self_attn")(
             RMSNorm(cfg.rms_norm_eps, cfg.dtype, cfg.param_dtype, name="input_layernorm")(x), positions,
             layer_pages, block_table, start_pos, chunk_lens)
         h = x + attn_out
+        # a chunk's padding goes to no expert (the mask the KV write uses)
+        token_mask = None if chunk_lens is None else jnp.arange(x.shape[1])[None, :] < chunk_lens[:, None]
         moe_out, _l_aux, _counts = MoE(hidden_size=cfg.hidden_size,
                                        num_experts=cfg.num_local_experts,
                                        intermediate_size=cfg.intermediate_size,
@@ -49,7 +52,9 @@ class MixtralBlockCache(nn.Module):
                                        param_dtype=cfg.param_dtype,
                                        name="block_sparse_moe")(
                                            RMSNorm(cfg.rms_norm_eps, cfg.dtype, cfg.param_dtype,
-                                                   name="post_attention_layernorm")(h), train=False)
+                                                   name="post_attention_layernorm")(h), train=False,
+                                           token_mask=token_mask,
+                                           stacked_banks=None if stacked_banks is None else (stacked_banks, layer))
         out = h + moe_out
         return out, layer_pages
 
@@ -59,6 +64,19 @@ class MixtralForCausalLMWithCache(nn.Module):
     tokens, start_pos, block_table, cache)`` → (logits, new_cache)."""
     cfg: MixtralConfig
     page_size: int = 16
+
+    def _stacked_banks(self):
+        """The blocks' expert banks as the scan holds them, [L, E, ...], for the
+        blocks to read in place: the scan hands a block its slice of them,
+        and the dropless path's grouped product, a custom call on the TPU,
+        would copy that slice every layer (three banks, 2.8 GB at Mixtral's
+        widths).  None where there is nothing to read yet (``init``) or the
+        banks would first have to be cast to the compute dtype."""
+        experts = self.variables.get("params", {}).get("layers", {}).get("block_sparse_moe", {}).get("experts")
+        if experts is None:
+            return None
+        banks = tuple(nn.meta.unbox(experts[name]) for name in ("w_gate", "w_up", "w_down"))
+        return banks if all(w.dtype == self.cfg.dtype for w in banks) else None
 
     @nn.compact
     def __call__(self, input_ids, start_pos, block_table, cache, chunk_lens=None):
@@ -74,12 +92,13 @@ class MixtralForCausalLMWithCache(nn.Module):
         blocks = nn.scan(MixtralBlockCache,
                          variable_axes={"params": 0},
                          split_rngs={"params": True},
-                         in_axes=(0, nn.broadcast, nn.broadcast, nn.broadcast, nn.broadcast),
+                         in_axes=(0, nn.broadcast, nn.broadcast, nn.broadcast, nn.broadcast, nn.broadcast, 0),
                          out_axes=0,
                          length=cfg.num_hidden_layers,
                          metadata_params={nn.PARTITION_NAME: LAYERS})
         x, cache = blocks(cfg, self.page_size, scanned=True,
-                          name="layers")(x, cache, positions, block_table, start_pos, chunk_lens)
+                          name="layers")(x, cache, positions, block_table, start_pos, chunk_lens,
+                                         self._stacked_banks(), jnp.arange(cfg.num_hidden_layers))
         x = RMSNorm(cfg.rms_norm_eps, cfg.dtype, cfg.param_dtype, name="norm")(x)
         logits = nn.DenseGeneral(features=cfg.vocab_size,
                                  use_bias=False,

@@ -1,18 +1,41 @@
-"""Mixture-of-Experts core: gating + dispatch/combine.
+"""Mixture-of-Experts core: gating, and the two ways from tokens to experts.
 
 Reference: ``deepspeed/moe/sharded_moe.py`` — ``TopKGate:449`` (top1/top2/topk
 gating at ``:183,290,374``), ``MOELayer:533`` with all-to-all dispatch
 (``_AllToAll:96``) to local ``Experts``.
 
-TPU-native realisation (GShard-style, compiler-scheduled): tokens are grouped
-by their data shard ([G, S, d], G sharded over the batch axes); gating
-produces per-group dispatch/combine tensors; the dispatch einsum produces
-[G, E, C, d] which we resharding-constrain from group-sharded to
-expert-sharded — GSPMD lowers that to the same all-to-all the reference
-issues explicitly, riding ICI.  Capacity/drop semantics follow the
-reference: ``capacity = ceil(k * S / E * capacity_factor)``, clamped to
-``min_capacity``, tokens beyond capacity dropped (or kept when
-``drop_tokens=False`` → capacity = S).
+Tokens are grouped by their data shard ([G, S, d], G sharded over the batch
+axes), and a group is routed by one of two paths.  ``MoE.__call__`` picks
+between them from the layer's ``drop_tokens`` flag and the mesh it can see,
+and there is no other knob:
+
+* **Capacity dispatch** (``top1_gating`` / ``topk_gating`` →
+  ``dispatch_combine``), GShard-style and compiler-scheduled: gating makes
+  per-group dispatch/combine tensors [S, E, C], the dispatch einsum makes
+  [G, E, C, d], which is resharding-constrained from group-sharded to
+  expert-sharded — GSPMD lowers that to the all-to-all the reference issues
+  explicitly.  ``capacity = ceil(k * S / E * capacity_factor)``, clamped to
+  ``min_capacity``; tokens beyond it are dropped.  Taken when
+  ``drop_tokens=True`` (dropping is a different result, so a path of its
+  own), and when ``drop_tokens=False`` on an ``expert`` mesh axis larger
+  than 1, with capacity = S so that nothing is dropped: every expert then
+  multiplies S rows, E/k times what the routing needs, but the exchange
+  stays the one GSPMD knows (a ragged all-to-all is not built yet).
+
+* **Dropless sorted dispatch** (``dropless_moe``), taken when
+  ``drop_tokens=False`` and the experts are on one shard (every served
+  Mixtral; training when asked): the [S, k] choices are flattened and
+  stable-sorted by expert id, the rows gathered, and the three products of
+  the bank run over the ragged groups (``jax.lax.ragged_dot``), so the
+  experts multiply k rows a live token and no [S, E, C] tensor exists.
+  Rows a token mask removes (the padding of a serving step) sort behind the
+  last group, are in no group, cost no product and come out as exact zeros.
+  Up to ``DENSE_UP_TO_TOKENS`` tokens a group (a decode step) every expert
+  multiplies every row instead: there the weights' read is the whole cost
+  either way, and the sort and the grouped kernel's fixed cost are not paid.
+  A scanned trunk may hand the layer the banks of all its layers, still
+  stacked, and the layer's index (``layer``): the grouped product is a
+  custom call on the TPU and would copy the slice the scan gives it.
 """
 
 from typing import Optional, Tuple
@@ -21,7 +44,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from ..comm.mesh import BATCH_AXES, EXPERT_AXIS, get_global_mesh
+from ..comm.mesh import BATCH_AXES, EXPERT_AXIS, axis_size, get_global_mesh, get_trace_mesh, in_manual_mesh
 
 
 def _capacity(num_tokens: int, num_experts: int, capacity_factor: float, min_capacity: int, k: int) -> int:
@@ -137,3 +160,118 @@ def dispatch_combine(x_grouped, combine, dispatch, expert_fn):
         expert_out = jax.lax.with_sharding_constraint(expert_out, ep_sh)
     out = jnp.einsum("gsec,gecd->gsd", combine.astype(expert_out.dtype), expert_out)
     return out
+
+
+#: Tokens up to which the dropless path lets every expert multiply every row.
+#: An expert's product over r rows does 2*r*d*f operations on the 2*d*f bytes
+#: of its bf16 weights, r operations a byte, so it is bound by reading the
+#: weights while r is under the chip's operations a byte (197e12 / 819e9 = 240
+#: on a v5e).  Up to there the dense form costs the weight read the routed
+#: rows would cost too, without the sort, the gathers and the grouped
+#: kernel's fixed cost (a decode step's 16 tokens: PERF.md section 6, PR 25).
+DENSE_UP_TO_TOKENS = 256
+
+
+def _experts_grouped(x, top_vals, expert, group_sizes, bank, layer):
+    """Sort the [S, k] choices by expert, multiply by ragged groups, un-sort,
+    weight and add.  Dead rows (expert id E) sort behind the last group."""
+    s, k = expert.shape
+    e = group_sizes.shape[0]
+    if layer is not None:
+        # the banks of all L layers, read in place as L*E groups of which this
+        # layer's E have rows: the grouped product is a custom call on the
+        # TPU, and a custom call reads no slice of a stack without a copy
+        n = bank[0].shape[0] * e
+        bank = tuple(w.reshape((n, ) + w.shape[2:]) for w in bank)
+        group_sizes = jax.lax.dynamic_update_slice(jnp.zeros((n, ), jnp.int32), group_sizes, (layer * e, ))
+    w_gate, w_up, w_down = bank
+    # flat row i is choice i % k of token i // k
+    order = jnp.argsort(expert.reshape(s * k), stable=True)
+    rows = jnp.take(x, order // k, axis=0)  # [S*k, d], sorted by expert
+    h = jax.nn.silu(jax.lax.ragged_dot(rows, w_gate, group_sizes)) * jax.lax.ragged_dot(rows, w_up, group_sizes)
+    y = jax.lax.ragged_dot(h, w_down, group_sizes).astype(jnp.float32)
+    # what ragged_dot leaves beyond the groups' sum is not defined on the TPU
+    y = jnp.where((jnp.arange(s * k) < jnp.sum(group_sizes))[:, None], y, 0.0)
+    back = jnp.zeros((s * k, ), order.dtype).at[order].set(jnp.arange(s * k, dtype=order.dtype))
+    y = jnp.take(y, back, axis=0).reshape(s, k, -1)
+    return jnp.sum(y * top_vals[:, :, None], axis=1)
+
+
+def _experts_dense(x, top_vals, expert, group_sizes, bank, layer):
+    """Every expert multiplies every row; a row's k choices are picked by
+    the weights [S, E], zero elsewhere and on dead rows (expert id E)."""
+    e = group_sizes.shape[0]
+    if layer is not None:  # an einsum reads its slice of the stack in place
+        bank = tuple(jax.lax.dynamic_index_in_dim(w, layer, 0, keepdims=False) for w in bank)
+    w_gate, w_up, w_down = bank
+    h = jax.nn.silu(jnp.einsum("sd,edf->esf", x, w_gate)) * jnp.einsum("sd,edf->esf", x, w_up)
+    y = jnp.einsum("esf,efd->esd", h, w_down).astype(jnp.float32)
+    weights = jnp.sum(_one_hot(expert, e) * top_vals[:, :, None], axis=1)
+    return jnp.einsum("se,esd->sd", weights, y)
+
+
+def dropless_moe(x, logits, bank, k: int, token_mask=None, noise=None, layer=None):
+    """Route one group with no capacity: every live token through its k
+    highest experts.
+
+    x: [S, d] in the compute dtype; logits: [S, E] float32; bank: the
+    experts' (w_gate, w_up [E, d, f], w_down [E, f, d]) in the compute
+    dtype — or, with ``layer`` (an index, traced or not), the banks of a
+    whole scanned trunk [L, E, ...], of which layer ``layer``'s are read in
+    place; token_mask: [S] bool or None — rows that carry no token go to no
+    expert and come out as zeros; noise: [S, E] or None, added to the logits
+    for the choice only (``top1_gating``'s RSample).  Gate values as the
+    capacity path has them: softmax in float32, renormalised over the k when
+    k > 1; a token's k outputs are weighted and added in float32.
+    Returns (out [S, d] float32, l_aux, exp_counts [E] int32).
+    """
+    s, e = logits.shape
+    gates = jax.nn.softmax(logits, axis=-1)
+    if noise is None:
+        top_vals, top_idx = jax.lax.top_k(gates, k)  # [S, k]
+    else:
+        _, top_idx = jax.lax.top_k(logits + noise, k)
+        top_vals = jnp.take_along_axis(gates, top_idx, axis=-1)
+    if k > 1:
+        top_vals = top_vals / jnp.maximum(jnp.sum(top_vals, axis=-1, keepdims=True), 1e-9)
+    live = jnp.ones((s, ), bool) if token_mask is None else token_mask
+
+    # aux load-balancing loss on the top-1 mask (ref: l_aux = E * sum(me * ce))
+    mask1 = _one_hot(top_idx[:, 0], e) * live[:, None]
+    l_aux = jnp.sum(jnp.mean(gates, axis=0) * jnp.mean(mask1, axis=0)) * e
+    expert = jnp.where(live[:, None], top_idx, e)  # [S, k]; a dead row goes to expert id E: to none
+    group_sizes = jnp.bincount(expert.reshape(-1), length=e + 1)[:e].astype(jnp.int32)
+
+    experts = _experts_dense if s <= DENSE_UP_TO_TOKENS else _experts_grouped
+    return experts(x, top_vals, expert, group_sizes, bank, layer), l_aux, group_sizes
+
+
+def dropless_dispatch(x, logits, bank, k: int, token_mask=None, noise=None, layer=None):
+    """``dropless_moe`` over a batch [B, S, ...]: one group a data shard.
+
+    Without capacity a token's output does not depend on its group, so the
+    groups are there for the sort alone: across the data axes it would gather
+    every token.  Under a governing mesh (``trace_mesh``) whose batch axes
+    divide B the batch is routed shard by shard inside ``shard_map``; else
+    (one device, a tensor-only mesh, already inside a manual mesh) as one
+    group.  ``l_aux`` is the mean over the shards, ``exp_counts`` their sum.
+    """
+    from jax.sharding import PartitionSpec as P
+
+    def one_group(x, logits, token_mask, noise, bank, layer):
+        flat = lambda a: None if a is None else a.reshape((-1, ) + a.shape[2:])
+        out, l_aux, counts = dropless_moe(flat(x), flat(logits), bank, k, flat(token_mask), flat(noise), layer)
+        return out.reshape(x.shape[:2] + out.shape[1:]), l_aux, counts
+
+    mesh = get_trace_mesh()
+    batch_axes = () if mesh is None or in_manual_mesh() else tuple(a for a in BATCH_AXES if mesh.shape.get(a, 1) > 1)
+    if not batch_axes or x.shape[0] % axis_size(mesh, *batch_axes):
+        return one_group(x, logits, token_mask, noise, bank, layer)
+
+    def one_shard(*args):
+        out, l_aux, counts = one_group(*args)
+        return out, jax.lax.pmean(l_aux, batch_axes), jax.lax.psum(counts, batch_axes)
+
+    rows = P(batch_axes)
+    return jax.shard_map(one_shard, mesh=mesh, in_specs=(rows, rows, rows, rows, P(), P()), out_specs=(rows, P(), P()),
+                         axis_names=set(batch_axes), check_vma=False)(x, logits, token_mask, noise, bank, layer)

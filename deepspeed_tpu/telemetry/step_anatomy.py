@@ -55,8 +55,10 @@ to kill.  This module decomposes EVERY engine step into:
   ``multi:b16:k8``), ``rows_decode``/``rows_prefill`` (sequences),
   ``tokens_real`` (token positions computed for a live sequence),
   ``slots`` (positions the program computed, padding included),
-  ``tokens_out`` (tokens that reached a sequence) and
-  ``tokens_discarded`` (overshoot of the fused rung, rejected drafts).
+  ``tokens_out`` (tokens that reached a sequence),
+  ``tokens_discarded`` (overshoot of the fused rung, rejected drafts) and
+  ``expert_rows`` (rows a layer's routed experts multiplied:
+  ``tokens_real`` x experts a token; 0 for a model with no expert layer).
 
 The decomposition TILES by construction: every component is a
 non-negative clock difference (or an explicit charge), and
@@ -107,7 +109,7 @@ HOST_SEGMENTS = ("admit", "schedule", "draft_plan", "verify_plan",
 
 #: what a step carried; zero until the engine notes them
 COUNTS = ("rows_decode", "rows_prefill", "tokens_real", "slots", "tokens_out",
-          "tokens_discarded")
+          "tokens_discarded", "expert_rows")
 
 #: names of the instant profiler events, built once (a mark allocates no string)
 _MARK_NAMES = {s: "ds.mark." + s for s in HOST_SEGMENTS + ("device_wait", )}
@@ -133,6 +135,7 @@ class StepRecord:
         self.rows_decode = self.rows_prefill = 0
         self.tokens_real = self.slots = 0
         self.tokens_out = self.tokens_discarded = 0
+        self.expert_rows = 0
 
     def host_s(self) -> float:
         return sum(self.segments.values())
@@ -274,7 +277,7 @@ class StepAnatomy:
 
     def note_program(self, key: str, path: str, rows_decode: int = 0,
                      rows_prefill: int = 0, tokens_real: int = 0,
-                     slots: int = 0) -> None:
+                     slots: int = 0, expert_rows: int = 0) -> None:
         """Tag the open step with the program it dispatches (``key``, as
         ``InferenceEngineV2._key_label`` prints it: the attribution key)
         and what the packed batch carries.  A step that never dispatches
@@ -286,17 +289,20 @@ class StepAnatomy:
             cur.key, cur.path = key, path
             cur.rows_decode, cur.rows_prefill = int(rows_decode), int(rows_prefill)
             cur.tokens_real, cur.slots = int(tokens_real), int(slots)
+            cur.expert_rows = int(expert_rows)
 
-    def note_tokens(self, out: int, discarded: int = 0, real: int = 0) -> None:
+    def note_tokens(self, out: int, discarded: int = 0, real: int = 0,
+                    expert_rows: int = 0) -> None:
         """What the fold did with the step's tokens: ``out`` reached a
         sequence, ``discarded`` were computed and thrown away; ``real``
         adds positions whose use is known only now (a verify round's
-        accepted + 1 a row)."""
+        accepted + 1 a row), ``expert_rows`` their rows through the experts."""
         cur = self._cur
         if cur is not None:
             cur.tokens_out += int(out)
             cur.tokens_discarded += int(discarded)
             cur.tokens_real += int(real)
+            cur.expert_rows += int(expert_rows)
 
     def note_compile(self, key: str, aot: bool = False) -> None:
         """One compile event (the engine's ``_step_fns`` grew an entry).
@@ -541,10 +547,10 @@ class NullStepAnatomy:
         pass
 
     def note_program(self, key, path, rows_decode=0, rows_prefill=0,
-                     tokens_real=0, slots=0) -> None:
+                     tokens_real=0, slots=0, expert_rows=0) -> None:
         pass
 
-    def note_tokens(self, out, discarded=0, real=0) -> None:
+    def note_tokens(self, out, discarded=0, real=0, expert_rows=0) -> None:
         pass
 
     def note_compile(self, key, aot=False) -> None:

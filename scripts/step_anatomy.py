@@ -48,7 +48,7 @@ HOST_SEGMENTS = ("admit", "schedule", "draft_plan", "verify_plan",
 
 #: must mirror telemetry/step_anatomy.py COUNTS — what a step carried
 COUNTS = ("rows_decode", "rows_prefill", "tokens_real", "slots", "tokens_out",
-          "tokens_discarded")
+          "tokens_discarded", "expert_rows")
 
 
 def _anatomy_of(doc):

@@ -134,3 +134,170 @@ def test_tp_ep_mesh_matches_single_device():
     g0 = jax.jit(jax.grad(loss))(params, x)
     for a, b in zip(jax.tree.leaves(g1), jax.tree.leaves(g0)):
         np.testing.assert_allclose(np.asarray(a), np.asarray(b), atol=3e-5, rtol=2e-4)
+
+
+# ------------------------------------------------------------- dropless path
+
+
+def _per_token_reference(x, logits, bank, k, idx, mask=None):
+    """The dropless layer as its definition reads: a loop over tokens, each
+    through its k highest experts in float32 (k == 1 keeps the gate value as
+    the weight, as ``top1_gating`` does; k > 1 renormalises over the k).
+    ``idx`` [S, k] are the concrete choices, so the loop also differentiates."""
+    w_gate, w_up, w_down = bank
+    gates = jax.nn.softmax(logits.astype(jnp.float32), axis=-1)
+    rows = []
+    for t in range(x.shape[0]):
+        y = jnp.zeros((w_down.shape[-1], ), jnp.float32)
+        if mask is None or bool(mask[t]):
+            vals = jnp.stack([gates[t, int(e)] for e in idx[t]])
+            if k > 1:
+                vals = vals / vals.sum()
+            for j, e in enumerate(int(e) for e in idx[t]):
+                y = y + vals[j] * ((jax.nn.silu(x[t] @ w_gate[e]) * (x[t] @ w_up[e])) @ w_down[e])
+        rows.append(y)
+    return jnp.stack(rows)
+
+
+def _dropless_case(name):
+    """(x [S, d], logits [S, E], k, mask or None) of one named case."""
+    rng = np.random.default_rng(7)
+    s, e, d, k, mask = 24, 4, 16, 2, None
+    if name == "single_token":
+        s = 1
+    logits = rng.normal(size=(s, e)).astype(np.float32)
+    if name == "k1":
+        k = 1
+    elif name == "empty_expert":
+        logits[:, 2] = -30.0  # expert 2 is nobody's choice
+    elif name == "one_expert":
+        logits[:, 1] = 30.0  # everybody's first choice: a capacity of k*S/E would drop
+    elif name == "masked_third":
+        mask = np.arange(s) % 3 != 1
+    x = rng.normal(size=(s, d)).astype(np.float32)
+    return jnp.asarray(x), jnp.asarray(logits), k, mask
+
+
+@pytest.mark.parametrize("form", ["grouped", "dense"])
+@pytest.mark.parametrize("name", ["k1", "k2", "empty_expert", "one_expert", "masked_third", "single_token"])
+def test_dropless_matches_per_token_loop(name, form, monkeypatch):
+    """Both forms of the dropless path (sorted dispatch + grouped products;
+    every expert over every row, for few tokens) == the per-token top-k loop,
+    to 1e-5 in float32: no token dropped whatever the load, an expert with no
+    token multiplies nothing, a masked row is exactly zero and counted for
+    no expert."""
+    from deepspeed_tpu.moe import sharded_moe
+    x, logits, k, mask = _dropless_case(name)
+    s, e = logits.shape
+    monkeypatch.setattr(sharded_moe, "DENSE_UP_TO_TOKENS", s if form == "dense" else 0)
+    rng = np.random.default_rng(11)
+    bank = tuple(jnp.asarray(rng.normal(size=shape).astype(np.float32) * 0.3)
+                 for shape in ((e, 16, 32), (e, 16, 32), (e, 32, 16)))
+    idx = np.argsort(-np.asarray(logits), axis=-1, kind="stable")[:, :k]
+    want = _per_token_reference(x, logits, bank, k, idx, mask)
+    out, l_aux, counts = jax.jit(
+        lambda *a: sharded_moe.dropless_moe(*a, k, None if mask is None else jnp.asarray(mask)))(x, logits, bank)
+    np.testing.assert_allclose(np.asarray(out), np.asarray(want), atol=1e-5, rtol=1e-5)
+    live = np.ones(s, bool) if mask is None else mask
+    np.testing.assert_array_equal(np.asarray(counts), np.bincount(idx[live].ravel(), minlength=e))
+    assert int(counts.sum()) == k * int(live.sum())
+    assert np.isfinite(float(l_aux))
+    if name == "empty_expert":
+        assert int(counts[2]) == 0
+    if name == "one_expert":
+        assert int(counts[1]) == s > k * s // e
+    if mask is not None:
+        assert (np.asarray(out)[~mask] == 0.0).all()
+
+
+@pytest.fixture(params=["grouped", "dense"])
+def dropless_form(request, monkeypatch):
+    """Run a test of the whole layer under each form of the dropless path."""
+    from deepspeed_tpu.moe import sharded_moe
+    monkeypatch.setattr(sharded_moe, "DENSE_UP_TO_TOKENS", 1 << 30 if request.param == "dense" else 0)
+    return request.param
+
+
+def test_dropless_reads_its_layer_in_a_stack_of_banks(dropless_form):
+    """With ``layer`` the banks are a scanned trunk's, [L, E, ...], read in
+    place: the same result as with that layer's own slice of them."""
+    from deepspeed_tpu.moe.sharded_moe import dropless_moe
+    x, logits, k, _ = _dropless_case("k2")
+    mask = jnp.arange(x.shape[0]) % 4 != 3
+    rng = np.random.default_rng(13)
+    stack = tuple(jnp.asarray(rng.normal(size=shape).astype(np.float32) * 0.3)
+                  for shape in ((3, 4, 16, 32), (3, 4, 16, 32), (3, 4, 32, 16)))
+    for layer in (0, 2):
+        want, _, want_counts = dropless_moe(x, logits, tuple(w[layer] for w in stack), k, mask)
+        got, _, counts = jax.jit(lambda *a: dropless_moe(*a[:3], k, mask, None, a[3]))(x, logits, stack, jnp.int32(layer))
+        np.testing.assert_allclose(np.asarray(got), np.asarray(want), atol=1e-6, rtol=1e-6)
+        np.testing.assert_array_equal(np.asarray(counts), np.asarray(want_counts))
+
+
+def _dropless_layer_loss(layer, params, x):
+    out, l_aux, _ = layer.apply(params, x)
+    return jnp.mean(out**2) + 0.01 * l_aux
+
+
+def test_dropless_training_value_and_gradient_match_reference(dropless_form):
+    """Training with ``drop_tokens=False``: the layer's loss and its gradient
+    (router and bank) equal the per-token loop's, l_aux included."""
+    from flax import linen as nn
+    set_global_mesh(create_mesh(MeshSpec(), devices=jax.devices()[:1]))
+    layer = MoE(hidden_size=16, num_experts=4, intermediate_size=32, k=2, drop_tokens=False, dtype=jnp.float32)
+    x = jnp.asarray(np.random.default_rng(3).normal(size=(3, 8, 16)), jnp.float32)
+    params = nn.meta.unbox(layer.init(jax.random.PRNGKey(0), x))
+    xf = x.reshape(-1, 16)
+    idx = np.argsort(-np.asarray(xf @ params["params"]["gate"]["kernel"]), axis=-1, kind="stable")[:, :2]
+
+    def reference_loss(p):
+        p = p["params"]
+        logits = xf @ p["gate"]["kernel"]
+        out = _per_token_reference(xf, logits, (p["experts"]["w_gate"], p["experts"]["w_up"], p["experts"]["w_down"]),
+                                   2, idx)
+        gates = jax.nn.softmax(logits, axis=-1)
+        l_aux = jnp.sum(jnp.mean(gates, axis=0) * jnp.mean(jax.nn.one_hot(idx[:, 0], 4), axis=0)) * 4
+        return jnp.mean(out**2) + 0.01 * l_aux
+
+    loss, grads = jax.jit(jax.value_and_grad(lambda p: _dropless_layer_loss(layer, p, x)))(params)
+    want, want_grads = jax.value_and_grad(reference_loss)(params)
+    np.testing.assert_allclose(float(loss), float(want), rtol=1e-5)
+    for a, b in zip(jax.tree.leaves(grads), jax.tree.leaves(want_grads)):
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b), atol=1e-6, rtol=1e-4)
+
+
+def test_dropless_data_shards_route_their_own_tokens(dropless_form):
+    """Under a governing mesh with a data axis the batch is routed shard by
+    shard (``shard_map``): same outputs, counts and gradients as one group on
+    one device, and the expert-mesh path (capacity for every token) agrees."""
+    from jax.sharding import NamedSharding, PartitionSpec as P
+    from deepspeed_tpu.comm.mesh import trace_mesh
+    layer = MoE(hidden_size=16, num_experts=4, intermediate_size=32, k=2, drop_tokens=False, dtype=jnp.float32)
+    x = jnp.asarray(np.random.default_rng(5).normal(size=(4, 8, 16)), jnp.float32)
+    mask = jnp.arange(8)[None, :] < jnp.asarray([8, 3, 0, 5])[:, None]
+    set_global_mesh(create_mesh(MeshSpec(), devices=jax.devices()[:1]))
+    params = layer.init(jax.random.PRNGKey(0), x)
+    fwd = lambda p, x, m: layer.apply(p, x, token_mask=m)
+    loss = lambda p, x: jnp.mean(layer.apply(p, x)[0]**2)
+    gold, _, gold_counts = jax.jit(fwd)(params, x, mask)
+    gold_grads = jax.jit(jax.grad(loss))(params, x)
+
+    mesh = create_mesh(MeshSpec(data=2, tensor=2), devices=jax.devices()[:4])
+    set_global_mesh(mesh)
+    xs = jax.device_put(x, NamedSharding(mesh, P(("data", "expert"), None, None)))
+    with trace_mesh(mesh):
+        out, _, counts = jax.jit(fwd)(params, xs, mask)
+        grads = jax.jit(jax.grad(loss))(params, xs)
+    np.testing.assert_allclose(np.asarray(out), np.asarray(gold), atol=1e-6)
+    np.testing.assert_array_equal(np.asarray(counts), np.asarray(gold_counts))
+    for a, b in zip(jax.tree.leaves(grads), jax.tree.leaves(gold_grads)):
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b), atol=1e-6, rtol=1e-4)
+
+    # an expert mesh axis keeps the capacity dispatch, with room for every token
+    set_global_mesh(create_mesh(MeshSpec(expert=2), devices=jax.devices()[:2]))
+    ep_out, _, ep_counts = jax.jit(lambda p, x: layer.apply(p, x))(params, x)
+    full, _, full_counts = jax.jit(lambda p, x: layer.apply(p, x, token_mask=jnp.ones((4, 8), bool)))(params, x)
+    set_global_mesh(create_mesh(MeshSpec(), devices=jax.devices()[:1]))
+    one, _, one_counts = jax.jit(lambda p, x: layer.apply(p, x))(params, x)
+    np.testing.assert_allclose(np.asarray(ep_out), np.asarray(one), atol=2e-6)
+    np.testing.assert_array_equal(np.asarray(ep_counts), np.asarray(one_counts))

@@ -441,7 +441,7 @@ def test_annotate_factory_sees_step_and_marks_nested_with_counts():
     anat.mark("admit")
     anat.mark("schedule")
     anat.note_program("multi:b4:k8", "multi_decode", rows_decode=3,
-                      tokens_real=24, slots=32)
+                      tokens_real=24, slots=32, expert_rows=48)
     anat.mark("dispatch")
     clock.advance(0.8)
     anat.device_mark()
@@ -459,7 +459,8 @@ def test_annotate_factory_sees_step_and_marks_nested_with_counts():
         ("close", "ds.step")]
     assert log[-1][2] == {"index": 0, "key": "multi:b4:k8", "rows_decode": 3,
                           "rows_prefill": 0, "tokens_real": 24, "slots": 32,
-                          "tokens_out": 20, "tokens_discarded": 4}
+                          "tokens_out": 20, "tokens_discarded": 4,
+                          "expert_rows": 48}
     # the counts ride the row and the per-program fold too
     row = anat.last_step.to_row()
     assert (row["tokens_real"], row["slots"], row["tokens_out"],
@@ -638,6 +639,8 @@ def test_counts_of_single_and_mixed_steps_by_hand(tiny_serving):
     fold = anat.by_shape()
     assert fold["step:b2:c8"]["tokens_real"] == 16 and fold["step:b2:c8"]["slots"] == 32
     assert all(r.tokens_real <= r.slots for r in anat.steps)
+    # a model with no expert layer sends no row through experts
+    assert all(r.expert_rows == 0 for r in anat.steps)
 
 
 def test_counts_of_a_fused_dispatch_with_overshoot(tiny_serving):
