@@ -56,6 +56,13 @@ def build_cache_model(cfg, page_size: int):
     return LlamaForCausalLMWithCache(cfg, page_size=page_size)
 
 
+def _table_width(cfg, kvcfg: PagedKVConfig) -> int:
+    """Columns of a block-table row in ``cfg``'s step programs: what the
+    geometry of its pages needs for ``kvcfg``'s token capacity."""
+    from ...models.cache_zoo import cache_geometry
+    return cache_geometry(cfg, kvcfg.page_size).table_width(kvcfg.max_pages_per_seq * kvcfg.page_size)
+
+
 @dataclasses.dataclass(frozen=True)
 class RaggedInferenceEngineConfig:
     """ref: inference/v2/config_v2.py RaggedInferenceEngineConfig."""
@@ -141,7 +148,7 @@ def _serving_shardings(model, cfg, kvcfg, kv_dtype, mesh):
     cache_abs = jax.eval_shape(lambda: init_kv_cache(cfg, kvcfg, dtype=kv_dtype))
     toks1 = jnp.zeros((1, 1), jnp.int32)
     one = jnp.zeros((1, ), jnp.int32)
-    bt1 = jnp.zeros((1, kvcfg.max_pages_per_seq), jnp.int32)
+    bt1 = jnp.zeros((1, _table_width(cfg, kvcfg)), jnp.int32)
     abs_vars = jax.eval_shape(
         lambda: model.init(jax.random.PRNGKey(0), toks1, one, bt1, cache_abs,
                            jnp.ones((1, ), jnp.int32)))
@@ -179,7 +186,7 @@ def compile_aot_serving(cfg, mesh, engine_config: RaggedInferenceEngineConfig = 
     sds = jax.ShapeDtypeStruct
     args = (abs_params, cache_abs,
             sds((batch, chunk), jnp.int32), sds((batch, ), jnp.int32),
-            sds((batch, kvcfg.max_pages_per_seq), jnp.int32), sds((batch, ), jnp.int32),
+            sds((batch, _table_width(cfg, kvcfg)), jnp.int32), sds((batch, ), jnp.int32),
             jax.eval_shape(lambda: jax.random.PRNGKey(0)))
     with mesh, trace_mesh(mesh):
         compiled = jitted.lower(*args).compile()
@@ -210,7 +217,12 @@ class InFlightStep:
 
 
 class InferenceEngineV2:
-    """Continuous-batching engine over a paged-KV Llama model."""
+    """Continuous-batching engine over a model whose per-sequence state lives
+    in pages of one arena: keys and values of every token for the
+    softmax-attention families, a ring of exact rows plus summary rows for
+    chunked linear attention.  What a page holds, and how many pages ``n``
+    tokens need, is the geometry's (``self.kv.geometry``); the engine only
+    hands the model's step programs the block-table rows."""
 
     def __init__(self, cfg: LlamaConfig, params, engine_config: RaggedInferenceEngineConfig = None,
                  rng: Optional[jax.Array] = None, mesh=None):
@@ -279,8 +291,14 @@ class InferenceEngineV2:
         else:
             self._qparams = None
             self.params = params
+        from ...models.cache_zoo import cache_geometry
         self.kv = BlockedKVCache(kvcfg.num_pages, kvcfg.page_size, kvcfg.max_pages_per_seq,
-                                 enable_prefix_cache=self.econfig.enable_prefix_cache)
+                                 enable_prefix_cache=self.econfig.enable_prefix_cache,
+                                 geometry=cache_geometry(cfg, kvcfg.page_size))
+        if self.econfig.spec is not None and not self.kv.geometry.pages_immutable:
+            # a verify chunk may cross a window and its rollback cannot be undone
+            raise NotImplementedError(f"speculative decoding over {type(self.kv.geometry).__name__} "
+                                      "(pages rewritten in place) is not implemented")
         self.state = StateManager(self.kv, max_batch=self.econfig.scheduler.max_seqs)
         self.scheduler = SplitFuseScheduler(self.econfig.scheduler)
         cache = init_kv_cache(cfg, kvcfg, dtype=self.econfig.kv_dtype)
@@ -589,14 +607,13 @@ class InferenceEngineV2:
         what an integrity check reads to see which kernels the step
         really contains (``chip_smoke.py``)."""
         sds = jax.ShapeDtypeStruct
-        kvcfg = self.econfig.kv
         params_abs = jax.tree.map(lambda x: sds(x.shape, x.dtype), self.params)
         cache_abs = jax.tree.map(lambda x: sds(x.shape, x.dtype), self.cache)
         rng_abs = sds(self.rng.shape, self.rng.dtype)
 
         def batch_args(b, w):
             return (sds((b, w), jnp.int32), sds((b, ), jnp.int32),
-                    sds((b, kvcfg.max_pages_per_seq), jnp.int32),
+                    sds((b, self.kv.table_width), jnp.int32),
                     sds((b, ), jnp.int32))
 
         if key[0] == "multi":
@@ -687,7 +704,7 @@ class InferenceEngineV2:
             zeros = jnp.zeros((b, ), jnp.int32)
             _, self.cache = self._invoke(
                 fn, self.params, self.cache, jnp.zeros((b, width), jnp.int32),
-                zeros, jnp.zeros((b, self.kv.max_pages_per_seq), jnp.int32), zeros)
+                zeros, jnp.zeros((b, self.kv.table_width), jnp.int32), zeros)
 
     def _plan_drafts(self, seqs) -> List[List[int]]:
         """Draft up to ``max_draft`` tokens per decode row, then shrink
@@ -704,7 +721,7 @@ class InferenceEngineV2:
         spec = self.econfig.spec
         sched = self.econfig.scheduler
         width = min(spec.max_draft, sched.spec_verify_tokens or spec.max_draft)
-        cap = min(self.kv.max_pages_per_seq * self.kv.page_size,
+        cap = min(self.kv.max_tokens_per_seq,
                   getattr(self.cfg, "max_position_embeddings", None) or (1 << 30))
         drafts: List[List[int]] = []
         for s in seqs:
@@ -861,7 +878,8 @@ class InferenceEngineV2:
         if anat.enabled:
             anat.note_program(self._key_label(("multi", batch, k)), "multi_decode",
                               rows_decode=len(seqs), tokens_real=len(seqs) * k,
-                              slots=batch * k, expert_rows=len(seqs) * k * self._experts_per_tok)
+                              slots=batch * k, expert_rows=len(seqs) * k * self._experts_per_tok,
+                              cache_counts=self._cache_counts([(s, k) for s in seqs]))
         toks, self.cache = self._invoke(fn, self.params, self.cache, jnp.asarray(rb.tokens[:, 0]),
                                         jnp.asarray(rb.start_pos), jnp.asarray(rb.block_tables),
                                         jnp.asarray(rb.chunk_lens), sub)
@@ -908,6 +926,11 @@ class InferenceEngineV2:
             anat.note_tokens(n_out, len(inf.seqs) * k - n_out)
             anat.mark("sample_accept")
         return out
+
+    def _cache_counts(self, work) -> tuple:
+        """The geometry's ``step_counts`` summed over a step's (seq, tokens) rows."""
+        counts = [self.kv.geometry.step_counts(s.seen_tokens, n) for s, n in work]
+        return tuple(sum(c) for c in zip(*counts))
 
     def _bucket_batch(self, n: int) -> int:
         q = self.econfig.scheduler.decode_bucket
@@ -1008,7 +1031,7 @@ class InferenceEngineV2:
             # when the page arena, per-seq page capacity, or the position
             # table can't take the full block.
             max_pos = getattr(self.cfg, "max_position_embeddings", None) or (1 << 30)
-            seq_room = min(min(self.kv.max_pages_per_seq * self.kv.page_size, max_pos) -
+            seq_room = min(min(self.kv.max_tokens_per_seq, max_pos) -
                            len(s.tokens) for s in plan.decode)
             k = k_cfg
             while k > 1 and (seq_room < k or sum(self.kv.pages_needed(s, k) for s in plan.decode)
@@ -1034,7 +1057,8 @@ class InferenceEngineV2:
             anat.note_program(self._key_label((batch, chunk)), path,
                               rows_decode=len(plan.decode), rows_prefill=len(plan.prefill),
                               tokens_real=tokens_real, slots=batch * chunk,
-                              expert_rows=tokens_real * self._experts_per_tok)
+                              expert_rows=tokens_real * self._experts_per_tok,
+                              cache_counts=self._cache_counts(work))
         next_tok, self.cache = self._invoke(fn, self.params, self.cache, jnp.asarray(rb.tokens),
                                             jnp.asarray(rb.start_pos), jnp.asarray(rb.block_tables),
                                             jnp.asarray(rb.chunk_lens), sub)

@@ -1,0 +1,119 @@
+"""Cache geometry: the one owner of "which pages does a sequence of ``n``
+tokens hold, and where do they sit in its block-table row".
+
+The arena (``models/llama_cache.init_kv_cache``) is a pool of pages of one
+shape; what a page *holds* is the model's business.  Every host-side place
+that used to divide a token count by the page size (``BlockedKVCache``,
+``StateManager.truncate``, ``AdmissionController``, the engine's caps, the
+KV snapshot paths) asks the geometry instead, and ``SplitFuseScheduler``
+asks it where a prefill chunk must end.
+
+A sequence's ``pages`` list is always in *order of need*: entry ``i`` is
+the ``i``-th page the sequence came to need as it grew, so growing appends
+and rewinding pops the tail whatever the layout.  ``slots(n)`` is the index
+(a slice or an array) of the block-table row's columns that the first ``n``
+entries fill.
+
+Stdlib + numpy only: the admission controller and the scheduler import it.
+"""
+
+import numpy as np
+
+
+class LinearGeometry:
+    """Page ``i`` of a sequence holds the keys and values of tokens
+    ``page_size * i .. page_size * i + page_size - 1`` for ever: what every
+    softmax-attention twin uses."""
+
+    #: a full page never changes again, so sequences with a common prefix
+    #: may share it (the prefix cache) and a rewind is always possible
+    pages_immutable = True
+
+    def __init__(self, page_size: int):
+        self.page_size = int(page_size)
+
+    def pages_for(self, n_tokens: int) -> int:
+        return -(-int(n_tokens) // self.page_size)
+
+    def table_width(self, max_tokens: int) -> int:
+        """Columns a block-table row needs for a sequence of ``max_tokens``."""
+        return self.pages_for(max_tokens)
+
+    def slots(self, n_pages: int):
+        return slice(0, n_pages)
+
+    def rewind_floor(self, seen_tokens: int) -> int:
+        """The shortest history ``truncate`` may still rewind to."""
+        return 0
+
+    def chunk_limit(self, start: int, n_tokens: int) -> int:
+        """How many of ``n_tokens`` one chunk starting at ``start`` may carry."""
+        return n_tokens
+
+    def step_counts(self, start: int, n_tokens: int) -> tuple:
+        """What feeding tokens ``start .. start + n_tokens - 1`` does to a
+        cache of two kinds of page, for the step records:
+        (``summary_rows_written``, ``ring_wraps``, ``attn_rows_visible``).
+        Nothing here has summaries or a ring."""
+        return 0, 0, 0
+
+
+class RingSummaryGeometry:
+    """Two kinds of page a sequence, for chunked linear attention with
+    ``chunk_size == page_size``: a *ring* of ``window / page_size`` pages of
+    exact keys and values (the current window, overwritten in place when the
+    next window starts) and one *summary* row a chunk, ``page_size`` rows to
+    a page, growing for ever.  The block-table row is ``[ring | summary
+    pages]``: the ring first, so a row built for the linear layout (the
+    benchmark's check) reads as a valid one."""
+
+    pages_immutable = False
+
+    def __init__(self, page_size: int, window: int):
+        if window % (page_size * page_size):
+            # a window's summary rows must fill whole summary pages
+            raise ValueError(f"window {window} is no multiple of page_size^2 = {page_size * page_size}")
+        self.page_size = int(page_size)
+        self.window = int(window)
+        self.ring = window // page_size
+        self._summary_span = page_size * page_size   # tokens one summary page covers
+        n_sum = window // self._summary_span
+        need = np.concatenate([np.arange(self.ring) * page_size, np.arange(n_sum) * self._summary_span])
+        col = np.concatenate([np.arange(self.ring), self.ring + np.arange(n_sum)])
+        self._first_window = col[np.argsort(need, kind="stable")]   # columns of the first window's pages
+
+    def pages_for(self, n_tokens: int) -> int:
+        n = int(n_tokens)
+        return min(-(-n // self.page_size), self.ring) + -(-n // self._summary_span)
+
+    def table_width(self, max_tokens: int) -> int:
+        return self.ring + -(-int(max_tokens) // self._summary_span)
+
+    def slots(self, n_pages: int):
+        """Order of need: ring page ``r`` from token ``page_size * r`` on,
+        summary page ``s`` from token ``page_size^2 * s`` on, the ring page
+        first where both start at one token.  Once the ring is whole only
+        summary pages follow, each in the column of its own index."""
+        head = self._first_window[:n_pages]
+        return head if n_pages <= head.size else np.concatenate([head, np.arange(head.size, n_pages)])
+
+    def rewind_floor(self, seen_tokens: int) -> int:
+        """The start of the window the last seen token lies in: the ring
+        still holds every exact row from there on, and a summary row is
+        written again when its chunk completes again.  Rows of the window
+        before it are gone."""
+        return (max(int(seen_tokens), 1) - 1) // self.window * self.window
+
+    def chunk_limit(self, start: int, n_tokens: int) -> int:
+        """A chunk ends where its window ends: inside a chunk every query
+        sees the same summary rows, so the twin can hand the paged kernel one
+        start position a row."""
+        return min(n_tokens, self.window - start % self.window)
+
+    def step_counts(self, start: int, n_tokens: int) -> tuple:
+        """(chunks that complete, tokens that start a window after the first,
+        ring rows plus summary rows the queries can see, summed over them)."""
+        t = np.arange(start, start + n_tokens)
+        visible = t % self.window + 1 + t // self.window * (self.window // self.page_size)
+        return ((start + n_tokens) // self.page_size - start // self.page_size,
+                int(np.count_nonzero((t % self.window == 0) & (t > 0))), int(visible.sum()))

@@ -20,6 +20,8 @@ from typing import Dict, List, Optional, Sequence, Tuple
 
 import numpy as np
 
+from .geometry import LinearGeometry
+
 
 class BlockedAllocator:
     """Refcounted free-list allocator over KV pages (ref:
@@ -369,21 +371,34 @@ class PrefixCacheManager:
 
 class BlockedKVCache:
     """Geometry + allocator pairing (ref: kv_cache.py:40).  The device
-    arena itself lives in the engine (a donated jax array)."""
+    arena itself lives in the engine (a donated jax array).
+
+    ``geometry`` (``geometry.py``) owns how many pages ``n`` tokens hold and
+    which block-table column each sits in; ``max_pages_per_seq`` keeps its
+    meaning as a sequence's token capacity in pages of the linear layout
+    (``max_tokens_per_seq = max_pages_per_seq x page_size``), from which the
+    geometry derives the pages a sequence can really come to hold and the
+    width of a block-table row."""
 
     def __init__(self, num_pages: int, page_size: int, max_pages_per_seq: int,
-                 enable_prefix_cache: bool = True):
+                 enable_prefix_cache: bool = True, geometry=None):
         self.num_pages = num_pages
         self.page_size = page_size
-        self.max_pages_per_seq = max_pages_per_seq
+        self.geometry = geometry if geometry is not None else LinearGeometry(page_size)
+        self.max_tokens_per_seq = max_pages_per_seq * page_size
+        self.max_pages_per_seq = self.geometry.pages_for(self.max_tokens_per_seq)
+        self.table_width = self.geometry.table_width(self.max_tokens_per_seq)
         self.allocator = BlockedAllocator(num_pages)
+        if enable_prefix_cache and not self.geometry.pages_immutable:
+            # the hash-to-page map would hand out a page that its owner rewrites
+            raise ValueError(f"{type(self.geometry).__name__} rewrites pages in place: "
+                             "enable_prefix_cache must be off")
         self.prefix_cache = (PrefixCacheManager(self.allocator, page_size)
                              if enable_prefix_cache else None)
 
     def pages_needed(self, seq: SequenceDescriptor, new_tokens: int) -> int:
         total = len(seq.tokens) if new_tokens == 0 else seq.seen_tokens + new_tokens
-        needed = -(-total // self.page_size)  # ceil
-        return max(0, needed - len(seq.pages))
+        return max(0, self.geometry.pages_for(total) - len(seq.pages))
 
     def ensure_capacity(self, seq: SequenceDescriptor, new_tokens: int) -> None:
         n = self.pages_needed(seq, new_tokens)
@@ -559,10 +574,19 @@ class StateManager:
         clamped boundary inside the retained trailing page are never
         attended (the kernels mask at ``start_pos``) and are overwritten
         by the next step's writes at those positions.  Returns pages
-        freed."""
+        freed.
+
+        A geometry that rewrites pages in place can rewind only as far as
+        its ``rewind_floor``; below it the rows are gone and this raises
+        rather than serve from a corrupt cache.  A finished sequence is
+        exempt: it is never stepped again (the fused rung's overshoot past a
+        row's limit may have run into the next window)."""
+        floor = self.kv.geometry.rewind_floor(seq.seen_tokens)
+        if int(n_tokens) < floor and not seq.done:
+            raise RuntimeError(f"sequence {seq.uid}: cannot rewind from {seq.seen_tokens} to {int(n_tokens)} "
+                               f"tokens, the cache holds exact rows from token {floor} on only")
         seq.seen_tokens = min(seq.seen_tokens, int(n_tokens))
-        keep = -(-int(n_tokens) // self.kv.page_size)   # ceil
-        return self.kv.release_tail(seq, keep)
+        return self.kv.release_tail(seq, self.kv.geometry.pages_for(n_tokens))
 
     def flush(self, uid: int) -> None:
         """Release a sequence's KV + state (ref: engine_v2.py flush)."""
@@ -592,7 +616,7 @@ class StateManager:
         assert len(work) <= b, f"{len(work)} work items exceed batch capacity {b}"
         tokens = np.zeros((b, chunk), np.int32)
         start_pos = np.zeros((b, ), np.int32)
-        block_tables = np.zeros((b, self.kv.max_pages_per_seq), np.int32)
+        block_tables = np.zeros((b, self.kv.table_width), np.int32)
         chunk_lens = np.zeros((b, ), np.int32)
         uids = [-1] * b
         for i, (seq, n) in enumerate(work):
@@ -600,7 +624,7 @@ class StateManager:
             sl = seq.tokens[seq.seen_tokens:seq.seen_tokens + n]
             tokens[i, :len(sl)] = sl
             start_pos[i] = seq.seen_tokens
-            block_tables[i, :len(seq.pages)] = seq.pages
+            block_tables[i, self.kv.geometry.slots(len(seq.pages))] = seq.pages
             chunk_lens[i] = n
             uids[i] = seq.uid
         return RaggedBatch(tokens=tokens, start_pos=start_pos, block_tables=block_tables,

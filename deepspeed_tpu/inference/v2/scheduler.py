@@ -89,7 +89,8 @@ class SplitFuseScheduler:
         for seq in prefills:
             if budget <= 0 or len(plan_prefill) + len(decodes) >= cfg.max_seqs:
                 break
-            n = min(seq.remaining_prefill, cfg.prefill_chunk, budget)
+            n = manager.kv.geometry.chunk_limit(seq.seen_tokens,
+                                                min(seq.remaining_prefill, cfg.prefill_chunk, budget))
             if n <= 0:
                 # defensive: unreachable under the current filters (prefills
                 # all have remaining_prefill >= 1, budget > 0 checked above)

@@ -15,9 +15,12 @@ import jax
 import jax.numpy as jnp
 from flax import linen as nn
 
+from ..inference.v2.geometry import LinearGeometry, RingSummaryGeometry
 from .llama import EMBED, HEAD_DIM, HEADS, KV_HEADS, LAYERS, MLP, VOCAB, RMSNorm, _logical, apply_rope, \
     rotary_embedding
 from .llama_cache import paged_attention_core
+from .evabyte import EvaByteConfig
+from .evabyte_cache import EvaByteForCausalLMWithCache
 from .falcon import FalconConfig
 from .opt import OPTConfig
 from .phi import PhiConfig, apply_partial_rope
@@ -385,4 +388,20 @@ CACHE_MODEL_REGISTRY = {
     OPTConfig: OPTForCausalLMWithCache,
     PhiConfig: PhiForCausalLMWithCache,
     Qwen2MoeConfig: Qwen2MoeForCausalLMWithCache,
+    EvaByteConfig: EvaByteForCausalLMWithCache,
 }
+
+#: what a page holds, for the families whose pages are not "16 tokens' keys
+#: and values for ever" (inference/v2/geometry.py); every other family's
+#: geometry is linear
+CACHE_GEOMETRY_REGISTRY = {
+    EvaByteConfig: lambda cfg, page_size: RingSummaryGeometry(page_size, cfg.window_size),
+}
+
+
+def cache_geometry(cfg, page_size: int):
+    """The geometry of ``cfg``'s pages in an arena of ``page_size``-row pages."""
+    for cfg_cls, make in CACHE_GEOMETRY_REGISTRY.items():
+        if isinstance(cfg, cfg_cls):
+            return make(cfg, page_size)
+    return LinearGeometry(page_size)

@@ -181,6 +181,7 @@ class RMSNorm(nn.Module):
     eps: float = 1e-5
     dtype: Any = jnp.bfloat16
     param_dtype: Any = jnp.float32
+    unit_offset: bool = False  # the scale is 1 + weight (EvaByte's norm_add_unit_offset)
 
     @nn.compact
     def __call__(self, x):
@@ -189,7 +190,8 @@ class RMSNorm(nn.Module):
         x32 = x.astype(jnp.float32)
         var = jnp.mean(jnp.square(x32), axis=-1, keepdims=True)
         normed = x32 * jax.lax.rsqrt(var + self.eps)
-        return (normed * scale.astype(jnp.float32)).astype(self.dtype)
+        scale = scale.astype(jnp.float32) + 1.0 if self.unit_offset else scale.astype(jnp.float32)
+        return (normed * scale).astype(self.dtype)
 
 
 def rotary_embedding(positions, head_dim, theta):

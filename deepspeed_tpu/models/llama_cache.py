@@ -43,7 +43,11 @@ def init_kv_cache(cfg, kv: PagedKVConfig, dtype=jnp.bfloat16):
     """Allocate the paged arena: [L, P, page, 2, n_kv, hd].  Page 0 is the
     reserved null page (block tables point unused slots at it).  Works for
     any model-family config (falcon names its kv-head count differently;
-    MHA models have none)."""
+    MHA models have none).  A page is ``page`` rows of one key and one value
+    a head; which rows a twin keeps there is its own business: the keys and
+    values of 16 consecutive tokens (every softmax-attention twin), or, for
+    chunked linear attention, either 16 exact tokens of the current window
+    or 16 chunk summaries (``models/evabyte_cache.py``)."""
     head_dim = cfg.hidden_size // cfg.num_attention_heads
     n_kv = getattr(cfg, "num_key_value_heads", None) or getattr(cfg, "num_kv_heads", None) \
         or cfg.num_attention_heads
@@ -51,13 +55,16 @@ def init_kv_cache(cfg, kv: PagedKVConfig, dtype=jnp.bfloat16):
                      dtype)
 
 
-def _write_pages(pages, k_new, v_new, block_table, start_pos, page_size, chunk_lens=None):
+def _write_pages(pages, k_new, v_new, block_table, start_pos, page_size, chunk_lens=None, layer=None):
     """Scatter a chunk's K/V into the arena pages.
 
     pages: [P, page, 2, n_kv, hd] (one layer)   k/v_new: [B, C, n_kv, hd]
     block_table: [B, max_pages]  start_pos: [B]  chunk_lens: [B] or None —
     positions at/after a row's chunk_len are padding; their writes are
-    redirected to the reserved null page 0.
+    redirected to the reserved null page 0.  With ``layer`` (a traced index)
+    ``pages`` is the whole arena [L, P, page, 2, n_kv, hd] and the rows go
+    into that layer of it: a twin that carries the arena through its layer
+    loop updates it in place instead of stacking a second one.
     """
     b, c = k_new.shape[0], k_new.shape[1]
     positions = start_pos[:, None] + jnp.arange(c)[None, :]          # [B, C]
@@ -76,7 +83,8 @@ def _write_pages(pages, k_new, v_new, block_table, start_pos, page_size, chunk_l
         kv_chunk = jnp.where(valid[:, :, None, None, None], kv_chunk, 0)
     slot_idx = positions % page_size                                  # [B, C]
     flat_kv = kv_chunk.reshape((-1, ) + kv_chunk.shape[2:])           # [B*C, 2, n_kv, hd]
-    return pages.at[page_idx.reshape(-1), slot_idx.reshape(-1)].set(flat_kv)
+    where = (page_idx.reshape(-1), slot_idx.reshape(-1))
+    return pages.at[where if layer is None else (layer, ) + where].set(flat_kv)
 
 
 def paged_attention(q, pages, block_table, start_pos, chunk_lens, page_size, sliding_window=0,

@@ -122,11 +122,14 @@ def _paged_sharded(q, pages, block_table, start_pos, chunk_lens, page_size, inte
 
 
 def paged_attention_pallas(q, pages, block_table, start_pos, chunk_lens, page_size,
-                           *, interpret: Optional[bool] = None):
+                           *, layer=None, interpret: Optional[bool] = None):
     """Drop-in twin of ``models/llama_cache.paged_attention`` (jnp golden).
 
     q: [B, C, H, D]; pages: [P, page, 2, n_kv, D] (chunk K/V already
     written); block_table: [B, max_pages]; start_pos/chunk_lens: [B].
+    With ``layer`` (a traced index) ``pages`` is the whole arena
+    [L, P, page, 2, n_kv, D] and the kernel reads that layer's pages where
+    they lie: no layer of the arena is sliced out first.
     """
     from ..comm.mesh import get_trace_mesh, in_manual_mesh
     if interpret is None:
@@ -136,10 +139,12 @@ def paged_attention_pallas(q, pages, block_table, start_pos, chunk_lens, page_si
     if isinstance(q, jax.core.Tracer) and not in_manual_mesh():
         mesh = get_trace_mesh()
         if mesh is not None and mesh.size > 1:
+            if layer is not None:
+                raise NotImplementedError("the whole-arena form of the paged kernel is single-device")
             return _paged_sharded(q, pages, block_table, start_pos, chunk_lens, page_size,
                                   interpret, mesh)
     b, c, h, d = q.shape
-    n_kv = pages.shape[3]
+    n_kv = pages.shape[-2]
     max_pages = block_table.shape[1]
     rep = h // n_kv
     scale = 1.0 / (d**0.5)
@@ -150,26 +155,36 @@ def paged_attention_pallas(q, pages, block_table, start_pos, chunk_lens, page_si
     grid = (b, max_pages)
     kernel = functools.partial(_paged_kernel, page_size=page_size, max_pages=max_pages,
                                chunk=c, scale=scale, n_kv=n_kv)
+
+    def page_of(b, j, bt, sp):
+        # j is CLAMPED to the row's last needed page: past it the index map
+        # repeats the same page and Mosaic's pipeline skips the refetch —
+        # pages beyond the true sequence length cost no DMA (they were still
+        # copied pre-r4 even though pl.when skipped their compute)
+        return bt[b, jnp.minimum(j, (sp[b] + c - 1) // page_size)]
+
+    # one whole page: trailing dims (page, 2, n_kv, d) are the full array
+    # dims → always tile-legal
+    if layer is None:
+        prefetch = (block_table, start_pos)
+        page_spec = pl.BlockSpec((1, page_size, 2, n_kv, d),
+                                 lambda b, j, bt, sp: (page_of(b, j, bt, sp), 0, 0, 0, 0))
+    else:
+        prefetch = (block_table, start_pos, jnp.reshape(layer, (1, )).astype(jnp.int32))
+        page_spec = pl.BlockSpec((None, 1, page_size, 2, n_kv, d),
+                                 lambda b, j, bt, sp, ly: (ly[0], page_of(b, j, bt, sp), 0, 0, 0, 0))
+    kernel_fn = kernel if layer is None else (lambda bt, sp, ly, *refs: kernel(bt, sp, *refs))
     out = pl.pallas_call(
-        kernel,
+        kernel_fn,
         grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=2,
+            num_scalar_prefetch=len(prefetch),
             grid=grid,
             in_specs=[
                 # q stays resident across the page sweep (index map constant in j)
-                pl.BlockSpec((1, n_kv, rep * c, d), lambda b, j, bt, sp: (b, 0, 0, 0)),
-                # one whole page: trailing dims (page, 2, n_kv, d) are the full
-                # array dims → always tile-legal.  j is CLAMPED to the row's
-                # last needed page: past it the index map repeats the same
-                # page and Mosaic's pipeline skips the refetch — pages beyond
-                # the true sequence length cost no DMA (they were still
-                # copied pre-r4 even though pl.when skipped their compute)
-                pl.BlockSpec((1, page_size, 2, n_kv, d),
-                             lambda b, j, bt, sp:
-                             (bt[b, jnp.minimum(j, (sp[b] + c - 1) // page_size)],
-                              0, 0, 0, 0)),
+                pl.BlockSpec((1, n_kv, rep * c, d), lambda b, j, *_: (b, 0, 0, 0)),
+                page_spec,
             ],
-            out_specs=pl.BlockSpec((1, n_kv, rep * c, d), lambda b, j, bt, sp: (b, 0, 0, 0)),
+            out_specs=pl.BlockSpec((1, n_kv, rep * c, d), lambda b, j, *_: (b, 0, 0, 0)),
             scratch_shapes=([pltpu.VMEM((rep * c, 1), jnp.float32)] * n_kv +
                             [pltpu.VMEM((rep * c, 1), jnp.float32)] * n_kv +
                             [pltpu.VMEM((rep * c, d), jnp.float32)] * n_kv),
@@ -179,7 +194,7 @@ def paged_attention_pallas(q, pages, block_table, start_pos, chunk_lens, page_si
             dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
         name="ds_paged_attention",
-    )(block_table, start_pos, qg, pages)
+    )(*prefetch, qg, pages)
 
     out = out.reshape(b, n_kv, rep, c, d).reshape(b, h, c, d).transpose(0, 2, 1, 3)
     if chunk_lens is not None:

@@ -47,7 +47,7 @@ class AdmissionController:
         """Admit into the QUEUE?  Returns (ok, reject_reason)."""
         kv = self.engine.kv
         total_tokens = len(req.prompt) + req.max_new_tokens
-        if total_tokens > kv.max_pages_per_seq * kv.page_size:
+        if total_tokens > kv.max_tokens_per_seq:
             return False, "exceeds_max_pages_per_seq"
         max_pos = getattr(self.engine.cfg, "max_position_embeddings", None)
         if max_pos is not None and total_tokens > max_pos:
@@ -56,7 +56,7 @@ class AdmissionController:
         # this request even running alone — including the start-time headroom
         # can_start will demand, so everything QUEUED is eventually STARTABLE
         # (a queued-but-never-startable head would block the queue forever)
-        if -(-total_tokens // kv.page_size) + self.config.kv_headroom_pages \
+        if kv.geometry.pages_for(total_tokens) + self.config.kv_headroom_pages \
                 > kv.num_pages - 1:
             return False, "exceeds_kv_arena"
         if self.config.max_queue_depth > 0 and queue_depth >= self.config.max_queue_depth:
@@ -88,8 +88,8 @@ class AdmissionController:
         can ever use and deadlock at the head of the queue).  Prefix-cache
         hits only reduce this, so it is a safe bound."""
         kv = self.engine.kv
-        final = -(-(len(req.prompt) + req.max_new_tokens) // kv.page_size)
-        return min(-(-len(req.engine_tokens()) // kv.page_size) + 1, final)
+        pages_for = kv.geometry.pages_for
+        return min(pages_for(len(req.engine_tokens())) + 1, pages_for(len(req.prompt) + req.max_new_tokens))
 
     def can_start(self, req: ServingRequest, reserved_pages: int = 0) -> bool:
         """Hand ``req`` to the engine now?  May evict cache-only prefix

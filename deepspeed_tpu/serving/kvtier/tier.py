@@ -384,7 +384,7 @@ class TieredKVManager:
             return None
         try:
             _fi.check("kv.demote")   # chaos site: failed d2h demotion
-            n_pages = -(-seq.seen_tokens // kv.page_size)
+            n_pages = kv.geometry.pages_for(seq.seen_tokens)
             block = kv.export_pages(arena, list(seq.pages[:n_pages]))
         except _FATAL:
             raise

@@ -153,7 +153,7 @@ class KVExporter:
         # pages covering [0, seen_tokens): the trailing partial page is
         # exported whole — positions past ``seen_tokens`` inside it are
         # never attended on the importer either (kernels mask at start_pos)
-        n_pages = -(-seq.seen_tokens // kv.page_size)
+        n_pages = kv.geometry.pages_for(seq.seen_tokens)
         self._pages = list(seq.pages[:n_pages])
         self._next = 0
         self.snapshot = KVSnapshot(
@@ -240,7 +240,7 @@ def import_snapshot(engine, uid: int, tokens: Sequence[int],
     if uid in engine.state.seqs:
         raise KVImportError(f"uid {uid} already live on the target engine")
     n = snapshot.n_pages
-    if n != -(-snapshot.seen_tokens // kv.page_size):
+    if n != kv.geometry.pages_for(snapshot.seen_tokens):
         raise KVImportError(f"snapshot pages ({n}) do not cover its seen "
                             f"boundary ({snapshot.seen_tokens})")
     if n > kv.max_pages_per_seq:

@@ -56,9 +56,14 @@ to kill.  This module decomposes EVERY engine step into:
   ``tokens_real`` (token positions computed for a live sequence),
   ``slots`` (positions the program computed, padding included),
   ``tokens_out`` (tokens that reached a sequence),
-  ``tokens_discarded`` (overshoot of the fused rung, rejected drafts) and
+  ``tokens_discarded`` (overshoot of the fused rung, rejected drafts),
   ``expert_rows`` (rows a layer's routed experts multiplied:
-  ``tokens_real`` x experts a token; 0 for a model with no expert layer).
+  ``tokens_real`` x experts a token; 0 for a model with no expert layer)
+  and, for a cache of exact and summary pages (chunked linear attention; 0
+  under the linear geometry), ``summary_rows_written`` (chunks that
+  completed in the step), ``ring_wraps`` (token rows that started a window
+  after the first) and ``attn_rows_visible`` (ring rows plus summary rows a
+  query could see, summed over the step's token rows, one layer).
 
 The decomposition TILES by construction: every component is a
 non-negative clock difference (or an explicit charge), and
@@ -109,7 +114,8 @@ HOST_SEGMENTS = ("admit", "schedule", "draft_plan", "verify_plan",
 
 #: what a step carried; zero until the engine notes them
 COUNTS = ("rows_decode", "rows_prefill", "tokens_real", "slots", "tokens_out",
-          "tokens_discarded", "expert_rows")
+          "tokens_discarded", "expert_rows", "summary_rows_written", "ring_wraps",
+          "attn_rows_visible")
 
 #: names of the instant profiler events, built once (a mark allocates no string)
 _MARK_NAMES = {s: "ds.mark." + s for s in HOST_SEGMENTS + ("device_wait", )}
@@ -136,6 +142,7 @@ class StepRecord:
         self.tokens_real = self.slots = 0
         self.tokens_out = self.tokens_discarded = 0
         self.expert_rows = 0
+        self.summary_rows_written = self.ring_wraps = self.attn_rows_visible = 0
 
     def host_s(self) -> float:
         return sum(self.segments.values())
@@ -277,10 +284,12 @@ class StepAnatomy:
 
     def note_program(self, key: str, path: str, rows_decode: int = 0,
                      rows_prefill: int = 0, tokens_real: int = 0,
-                     slots: int = 0, expert_rows: int = 0) -> None:
+                     slots: int = 0, expert_rows: int = 0,
+                     cache_counts: tuple = (0, 0, 0)) -> None:
         """Tag the open step with the program it dispatches (``key``, as
         ``InferenceEngineV2._key_label`` prints it: the attribution key)
-        and what the packed batch carries.  A step that never dispatches
+        and what the packed batch carries (``cache_counts``: the geometry's
+        ``step_counts`` summed over the rows).  A step that never dispatches
         (empty plan) keeps ``path=None`` and is DISCARDED at step_end:
         its host time folds into the next real step's host gap, which is
         exactly what that time is (loop tax without device work)."""
@@ -290,6 +299,7 @@ class StepAnatomy:
             cur.rows_decode, cur.rows_prefill = int(rows_decode), int(rows_prefill)
             cur.tokens_real, cur.slots = int(tokens_real), int(slots)
             cur.expert_rows = int(expert_rows)
+            cur.summary_rows_written, cur.ring_wraps, cur.attn_rows_visible = (int(c) for c in cache_counts)
 
     def note_tokens(self, out: int, discarded: int = 0, real: int = 0,
                     expert_rows: int = 0) -> None:
@@ -547,7 +557,7 @@ class NullStepAnatomy:
         pass
 
     def note_program(self, key, path, rows_decode=0, rows_prefill=0,
-                     tokens_real=0, slots=0, expert_rows=0) -> None:
+                     tokens_real=0, slots=0, expert_rows=0, cache_counts=(0, 0, 0)) -> None:
         pass
 
     def note_tokens(self, out, discarded=0, real=0, expert_rows=0) -> None:

@@ -460,7 +460,8 @@ def test_annotate_factory_sees_step_and_marks_nested_with_counts():
     assert log[-1][2] == {"index": 0, "key": "multi:b4:k8", "rows_decode": 3,
                           "rows_prefill": 0, "tokens_real": 24, "slots": 32,
                           "tokens_out": 20, "tokens_discarded": 4,
-                          "expert_rows": 48}
+                          "expert_rows": 48, "summary_rows_written": 0, "ring_wraps": 0,
+                          "attn_rows_visible": 0}
     # the counts ride the row and the per-program fold too
     row = anat.last_step.to_row()
     assert (row["tokens_real"], row["slots"], row["tokens_out"],
