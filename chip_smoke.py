@@ -366,7 +366,7 @@ def leg_serve(leg, tp, rehearse, serve1_tokens):
     keys = eng.step_shape_set()
     warm_s = time.monotonic() - t0
     lowered_missing = [eng._key_label(k) for k in keys
-                       if "_paged_kernel" not in eng._aot_lower(k).as_text()]
+                       if "ds_paged_attention" not in eng._aot_lower(k).as_text()]
     mosaic_missing = [eng._key_label(k) for k in keys
                       if "tpu_custom_call" not in eng._step_fns[k].as_text()]
     _, _ = jax.random.split(eng.rng)  # the serving loop's only eager ops: compile them now
@@ -438,7 +438,7 @@ def leg_serve(leg, tp, rehearse, serve1_tokens):
                      "prompt_tokens": [len(p) for p in prompts],
                      "new_tokens_each": shape["new_tokens"]},
         "compiles_in_window": compiles_in_window,
-        "kernels_in_hlo": {"_paged_kernel": not lowered_missing,
+        "kernels_in_hlo": {"ds_paged_attention": not lowered_missing,
                            "tpu_custom_call": not mosaic_missing},
         "check": {"requests": picked, "positions": int(sum(len(g) for g in gaps)),
                   "max_abs_logit_err_paged_vs_reference": [round(e, 4) for e in logit_err],
@@ -515,7 +515,7 @@ def leg_train(leg, n_dev, rehearse):
     lowered_text = lowered.as_text()
     compiled_text = lowered.compile().as_text()
     hlo_check_s = time.monotonic() - t1
-    kernels = {k: k in lowered_text for k in ("_fwd2_kernel", "_dq2_kernel", "_dkv2_kernel")}
+    kernels = {k: k in lowered_text for k in ("ds_flash_fwd", "ds_flash_dq", "ds_flash_dkv")}
 
     state = engine.state
     n_params = _n_params(state.params)

@@ -126,6 +126,36 @@ def _make_step_fn(model, qparams, greedy: bool, temperature: float):
     return step
 
 
+def _make_multi_fn(model, qparams, greedy: bool, temperature: float, batch: int, k: int):
+    """The fused decode program: ``k`` one-token rounds in ONE dispatch, each
+    round's sampled token the next round's input.  The arena is the loop's
+    carry (and, in the scanned trunks, the carry of the layer loop inside it).
+    Pure, like ``_make_step_fn``, and shared with the AOT path the same way."""
+
+    def mstep(params, cache, tokens0, start_pos, block_tables, chunk_lens, rng):
+        if qparams is not None:
+            params = {"params": qparams.dequantize(params["params"])}
+
+        def body(i, carry):
+            cache, toks, out = carry
+            logits, cache = model.apply(params, toks[:, None], start_pos + i,
+                                        block_tables, cache, chunk_lens)
+            row_logits = logits[:, 0]
+            if greedy:
+                nxt = jnp.argmax(row_logits, axis=-1).astype(jnp.int32)
+            else:
+                nxt = jax.random.categorical(
+                    jax.random.fold_in(rng, i),
+                    row_logits / temperature, axis=-1).astype(jnp.int32)
+            return (cache, nxt, out.at[:, i].set(nxt))
+
+        out0 = jnp.zeros((batch, k), jnp.int32)
+        cache, _, out = jax.lax.fori_loop(0, k, body, (cache, tokens0, out0))
+        return out, cache
+
+    return mstep
+
+
 def _named(fn, label: str):
     """Name a step function after its program key (``step:b16:c128`` ->
     ``ds_step_b16_c128``) before ``jax.jit``, so the device trace's
@@ -159,8 +189,10 @@ def _serving_shardings(model, cfg, kvcfg, kv_dtype, mesh):
 
 
 def compile_aot_serving(cfg, mesh, engine_config: RaggedInferenceEngineConfig = None,
-                        batch: int = 8, chunk: int = 1):
-    """AOT-compile the TP-sharded serving step against an offline topology.
+                        batch: int = 8, chunk: int = 1, fused_steps: int = 0):
+    """AOT-compile the TP-sharded serving step against an offline topology:
+    the step program of ``chunk`` tokens a row or, with ``fused_steps`` k > 1,
+    the fused decode program of k one-token rounds (``multi:b<batch>:k<k>``).
 
     No weights are ever allocated — params/cache lower as ShapeDtypeStructs —
     so this proves a serving config (e.g. Llama-3-8B at TP8 on v5p) fits
@@ -178,14 +210,20 @@ def compile_aot_serving(cfg, mesh, engine_config: RaggedInferenceEngineConfig = 
     model = build_cache_model(cfg, kvcfg.page_size)
     abs_params, cache_abs, param_sh, cache_sh, r = _serving_shardings(
         model, cfg, kvcfg, eng_cfg.kv_dtype, mesh)
-    step = _named(_make_step_fn(model, None, eng_cfg.greedy, eng_cfg.temperature),
-                  InferenceEngineV2._key_label((batch, chunk)))
+    if fused_steps > 1:
+        tokens_shape = (batch, )
+        step = _named(_make_multi_fn(model, None, eng_cfg.greedy, eng_cfg.temperature, batch, fused_steps),
+                      InferenceEngineV2._key_label(("multi", batch, fused_steps)))
+    else:
+        tokens_shape = (batch, chunk)
+        step = _named(_make_step_fn(model, None, eng_cfg.greedy, eng_cfg.temperature),
+                      InferenceEngineV2._key_label((batch, chunk)))
     jitted = jax.jit(step, donate_argnums=(1, ),
                      in_shardings=(param_sh, cache_sh, r, r, r, r, r),
                      out_shardings=(r, cache_sh))
     sds = jax.ShapeDtypeStruct
     args = (abs_params, cache_abs,
-            sds((batch, chunk), jnp.int32), sds((batch, ), jnp.int32),
+            sds(tokens_shape, jnp.int32), sds((batch, ), jnp.int32),
             sds((batch, _table_width(cfg, kvcfg)), jnp.int32), sds((batch, ), jnp.int32),
             jax.eval_shape(lambda: jax.random.PRNGKey(0)))
     with mesh, trace_mesh(mesh):
@@ -484,27 +522,8 @@ class InferenceEngineV2:
 
     def _build_multi_jit(self, batch: int, k: int):
         """The fused k-round decode program (shapes close over batch/k)."""
-        def mstep(params, cache, tokens0, start_pos, block_tables, chunk_lens, rng):
-            if self._qparams is not None:
-                params = {"params": self._qparams.dequantize(params["params"])}
-
-            def body(i, carry):
-                cache, toks, out = carry
-                logits, cache = self.model.apply(params, toks[:, None], start_pos + i,
-                                                 block_tables, cache, chunk_lens)
-                row_logits = logits[:, 0]
-                if self.econfig.greedy:
-                    nxt = jnp.argmax(row_logits, axis=-1).astype(jnp.int32)
-                else:
-                    nxt = jax.random.categorical(
-                        jax.random.fold_in(rng, i),
-                        row_logits / self.econfig.temperature, axis=-1).astype(jnp.int32)
-                return (cache, nxt, out.at[:, i].set(nxt))
-
-            out0 = jnp.zeros((batch, k), jnp.int32)
-            cache, _, out = jax.lax.fori_loop(0, k, body, (cache, tokens0, out0))
-            return out, cache
-
+        mstep = _make_multi_fn(self.model, self._qparams, self.econfig.greedy,
+                               self.econfig.temperature, batch, k)
         return jax.jit(_named(mstep, self._key_label(("multi", batch, k))),
                        donate_argnums=(1, ), **self._jit_kwargs())
 

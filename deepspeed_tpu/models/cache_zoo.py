@@ -16,9 +16,8 @@ import jax.numpy as jnp
 from flax import linen as nn
 
 from ..inference.v2.geometry import LinearGeometry, RingSummaryGeometry
-from .llama import EMBED, HEAD_DIM, HEADS, KV_HEADS, LAYERS, MLP, VOCAB, RMSNorm, _logical, apply_rope, \
-    rotary_embedding
-from .llama_cache import paged_attention_core
+from .llama import EMBED, HEAD_DIM, HEADS, KV_HEADS, MLP, VOCAB, RMSNorm, _logical, apply_rope, rotary_embedding
+from .llama_cache import paged_attention_core, scan_blocks
 from .evabyte import EvaByteConfig
 from .evabyte_cache import EvaByteForCausalLMWithCache
 from .falcon import FalconConfig
@@ -35,7 +34,7 @@ class FalconAttentionCache(nn.Module):
     page_size: int = 16
 
     @nn.compact
-    def __call__(self, x, positions, pages, block_table, start_pos, chunk_lens=None):
+    def __call__(self, x, positions, pages, block_table, start_pos, chunk_lens=None, layer=None):
         cfg = self.cfg
         H, KV = cfg.num_attention_heads, cfg.num_kv_heads
         D = cfg.hidden_size // H
@@ -57,7 +56,7 @@ class FalconAttentionCache(nn.Module):
             q = apply_rope(q, cos, sin)
             k = apply_rope(k, cos, sin)
         out, pages = paged_attention_core(q, k, v, pages, block_table, start_pos, chunk_lens, self.page_size,
-                                          attention_impl=cfg.attention_impl, alibi_slopes=slopes)
+                                          attention_impl=cfg.attention_impl, alibi_slopes=slopes, layer=layer)
         out = nn.DenseGeneral(features=cfg.hidden_size, axis=(-2, -1), use_bias=cfg.bias,
                               dtype=cfg.dtype, param_dtype=cfg.param_dtype,
                               kernel_init=_logical(nn.initializers.lecun_normal(), (HEADS, HEAD_DIM, EMBED)),
@@ -68,12 +67,11 @@ class FalconAttentionCache(nn.Module):
 class FalconBlockCache(nn.Module):
     cfg: FalconConfig
     page_size: int = 16
-    scanned: bool = False
 
     @nn.compact
-    def __call__(self, carry, layer_pages, positions=None, block_table=None, start_pos=None, chunk_lens=None):
+    def __call__(self, carry, layer, positions, block_table, start_pos, chunk_lens=None):
         cfg = self.cfg
-        x = carry
+        x, pages = carry
         ln = partial(nn.LayerNorm, epsilon=cfg.layer_norm_epsilon, dtype=cfg.dtype,
                      param_dtype=cfg.param_dtype)
 
@@ -89,10 +87,10 @@ class FalconBlockCache(nn.Module):
         if not cfg.parallel_attn:
             # falcon-rw sequential residual: ln1 → attn → add; ln2 → mlp → add
             attn_in = ln(name="input_layernorm")(x)
-            attn_out, layer_pages = FalconAttentionCache(cfg, self.page_size, name="self_attention")(
-                attn_in, positions, layer_pages, block_table, start_pos, chunk_lens)
+            attn_out, pages = FalconAttentionCache(cfg, self.page_size, name="self_attention")(
+                attn_in, positions, pages, block_table, start_pos, chunk_lens, layer)
             h = x + attn_out
-            return h + mlp(ln(name="post_attention_layernorm")(h)), layer_pages
+            return (h + mlp(ln(name="post_attention_layernorm")(h)), pages), None
 
         if cfg.num_ln_in_parallel_attn == 2:
             attn_in = ln(name="ln_attn")(x)
@@ -100,9 +98,9 @@ class FalconBlockCache(nn.Module):
         else:
             attn_in = ln(name="input_layernorm")(x)
             mlp_in = attn_in
-        attn_out, layer_pages = FalconAttentionCache(cfg, self.page_size, name="self_attention")(
-            attn_in, positions, layer_pages, block_table, start_pos, chunk_lens)
-        return x + attn_out + mlp(mlp_in), layer_pages
+        attn_out, pages = FalconAttentionCache(cfg, self.page_size, name="self_attention")(
+            attn_in, positions, pages, block_table, start_pos, chunk_lens, layer)
+        return (x + attn_out + mlp(mlp_in), pages), None
 
 
 class FalconForCausalLMWithCache(nn.Module):
@@ -117,12 +115,8 @@ class FalconForCausalLMWithCache(nn.Module):
                          embedding_init=_logical(nn.initializers.normal(0.02), (VOCAB, EMBED)),
                          name="word_embeddings")
         x = embed(input_ids)
-        blocks = nn.scan(FalconBlockCache, variable_axes={"params": 0}, split_rngs={"params": True},
-                         in_axes=(0, nn.broadcast, nn.broadcast, nn.broadcast, nn.broadcast),
-                         out_axes=0, length=cfg.num_hidden_layers,
-                         metadata_params={nn.PARTITION_NAME: LAYERS})
-        x, cache = blocks(cfg, self.page_size, scanned=True,
-                          name="h")(x, cache, positions, block_table, start_pos, chunk_lens)
+        (x, cache), _ = scan_blocks(FalconBlockCache, cfg.num_hidden_layers)(cfg, self.page_size, name="h")(
+            (x, cache), jnp.arange(cfg.num_hidden_layers), positions, block_table, start_pos, chunk_lens)
         x = nn.LayerNorm(epsilon=cfg.layer_norm_epsilon, dtype=cfg.dtype, param_dtype=cfg.param_dtype,
                          name="ln_f")(x)
         if cfg.tie_word_embeddings:
@@ -142,7 +136,7 @@ class OPTAttentionCache(nn.Module):
     page_size: int = 16
 
     @nn.compact
-    def __call__(self, x, pages, block_table, start_pos, chunk_lens=None):
+    def __call__(self, x, pages, block_table, start_pos, chunk_lens=None, layer=None):
         cfg = self.cfg
         H = cfg.num_attention_heads
         D = cfg.hidden_size // H
@@ -154,7 +148,7 @@ class OPTAttentionCache(nn.Module):
         v = dense(features=(H, D), kernel_init=_logical(nn.initializers.lecun_normal(), (EMBED, KV_HEADS, HEAD_DIM)),
                   name="v_proj")(x)
         out, pages = paged_attention_core(q, k, v, pages, block_table, start_pos, chunk_lens, self.page_size,
-                                          attention_impl=cfg.attention_impl)
+                                          attention_impl=cfg.attention_impl, layer=layer)
         out = nn.DenseGeneral(features=cfg.hidden_size, axis=(-2, -1), use_bias=True,
                               dtype=cfg.dtype, param_dtype=cfg.param_dtype,
                               kernel_init=_logical(nn.initializers.lecun_normal(), (HEADS, HEAD_DIM, EMBED)),
@@ -165,16 +159,15 @@ class OPTAttentionCache(nn.Module):
 class OPTBlockCache(nn.Module):
     cfg: OPTConfig
     page_size: int = 16
-    scanned: bool = False
 
     @nn.compact
-    def __call__(self, carry, layer_pages, positions=None, block_table=None, start_pos=None, chunk_lens=None):
+    def __call__(self, carry, layer, positions, block_table, start_pos, chunk_lens=None):
         cfg = self.cfg
-        x = carry
+        x, pages = carry
         ln = partial(nn.LayerNorm, epsilon=1e-5, dtype=cfg.dtype, param_dtype=cfg.param_dtype)
         a_in = ln(name="self_attn_layer_norm")(x) if cfg.do_layer_norm_before else x
-        a, layer_pages = OPTAttentionCache(cfg, self.page_size, name="self_attn")(
-            a_in, layer_pages, block_table, start_pos, chunk_lens)
+        a, pages = OPTAttentionCache(cfg, self.page_size, name="self_attn")(
+            a_in, pages, block_table, start_pos, chunk_lens, layer)
         h = x + a
         if not cfg.do_layer_norm_before:
             h = ln(name="self_attn_layer_norm")(h)
@@ -187,7 +180,7 @@ class OPTBlockCache(nn.Module):
         out = h + m
         if not cfg.do_layer_norm_before:
             out = ln(name="final_layer_norm")(out)
-        return out, layer_pages
+        return (out, pages), None
 
 
 class OPTForCausalLMWithCache(nn.Module):
@@ -213,12 +206,8 @@ class OPTForCausalLMWithCache(nn.Module):
             x = nn.Dense(cfg.hidden_size, use_bias=False, dtype=cfg.dtype,
                          param_dtype=cfg.param_dtype, name="project_in")(x)
         x = x + pos_embed(safe_pos + 2)
-        blocks = nn.scan(OPTBlockCache, variable_axes={"params": 0}, split_rngs={"params": True},
-                         in_axes=(0, nn.broadcast, nn.broadcast, nn.broadcast, nn.broadcast),
-                         out_axes=0, length=cfg.num_hidden_layers,
-                         metadata_params={nn.PARTITION_NAME: LAYERS})
-        x, cache = blocks(cfg, self.page_size, scanned=True,
-                          name="layers")(x, cache, positions, block_table, start_pos, chunk_lens)
+        (x, cache), _ = scan_blocks(OPTBlockCache, cfg.num_hidden_layers)(cfg, self.page_size, name="layers")(
+            (x, cache), jnp.arange(cfg.num_hidden_layers), positions, block_table, start_pos, chunk_lens)
         if cfg.do_layer_norm_before:
             x = nn.LayerNorm(epsilon=1e-5, dtype=cfg.dtype, param_dtype=cfg.param_dtype,
                              name="final_layer_norm")(x)
@@ -242,7 +231,7 @@ class PhiAttentionCache(nn.Module):
     page_size: int = 16
 
     @nn.compact
-    def __call__(self, x, positions, pages, block_table, start_pos, chunk_lens=None):
+    def __call__(self, x, positions, pages, block_table, start_pos, chunk_lens=None, layer=None):
         cfg = self.cfg
         H, KV = cfg.num_attention_heads, cfg.num_key_value_heads
         D = cfg.hidden_size // H
@@ -263,7 +252,7 @@ class PhiAttentionCache(nn.Module):
         q = apply_partial_rope(q, cos, sin, rot_dim)
         k = apply_partial_rope(k, cos, sin, rot_dim)
         out, pages = paged_attention_core(q, k, v, pages, block_table, start_pos, chunk_lens, self.page_size,
-                                          attention_impl=cfg.attention_impl)
+                                          attention_impl=cfg.attention_impl, layer=layer)
         out = nn.DenseGeneral(features=cfg.hidden_size, axis=(-2, -1), use_bias=True,
                               dtype=cfg.dtype, param_dtype=cfg.param_dtype,
                               kernel_init=_logical(nn.initializers.lecun_normal(), (HEADS, HEAD_DIM, EMBED)),
@@ -274,22 +263,21 @@ class PhiAttentionCache(nn.Module):
 class PhiBlockCache(nn.Module):
     cfg: PhiConfig
     page_size: int = 16
-    scanned: bool = False
 
     @nn.compact
-    def __call__(self, carry, layer_pages, positions=None, block_table=None, start_pos=None, chunk_lens=None):
+    def __call__(self, carry, layer, positions, block_table, start_pos, chunk_lens=None):
         cfg = self.cfg
-        x = carry
+        x, pages = carry
         h = nn.LayerNorm(epsilon=cfg.layer_norm_eps, dtype=cfg.dtype, param_dtype=cfg.param_dtype,
                          name="input_layernorm")(x)
-        attn_out, layer_pages = PhiAttentionCache(cfg, self.page_size, name="self_attn")(
-            h, positions, layer_pages, block_table, start_pos, chunk_lens)
+        attn_out, pages = PhiAttentionCache(cfg, self.page_size, name="self_attn")(
+            h, positions, pages, block_table, start_pos, chunk_lens, layer)
         m = nn.Dense(cfg.intermediate_size, use_bias=True, dtype=cfg.dtype, param_dtype=cfg.param_dtype,
                      kernel_init=_logical(nn.initializers.lecun_normal(), (EMBED, MLP)), name="fc1")(h)
         m = jax.nn.gelu(m, approximate=True)
         mlp_out = nn.Dense(cfg.hidden_size, use_bias=True, dtype=cfg.dtype, param_dtype=cfg.param_dtype,
                            kernel_init=_logical(nn.initializers.lecun_normal(), (MLP, EMBED)), name="fc2")(m)
-        return x + attn_out + mlp_out, layer_pages
+        return (x + attn_out + mlp_out, pages), None
 
 
 class PhiForCausalLMWithCache(nn.Module):
@@ -304,12 +292,8 @@ class PhiForCausalLMWithCache(nn.Module):
                          embedding_init=_logical(nn.initializers.normal(0.02), (VOCAB, EMBED)),
                          name="embed_tokens")
         x = embed(input_ids)
-        blocks = nn.scan(PhiBlockCache, variable_axes={"params": 0}, split_rngs={"params": True},
-                         in_axes=(0, nn.broadcast, nn.broadcast, nn.broadcast, nn.broadcast),
-                         out_axes=0, length=cfg.num_hidden_layers,
-                         metadata_params={nn.PARTITION_NAME: LAYERS})
-        x, cache = blocks(cfg, self.page_size, scanned=True,
-                          name="layers")(x, cache, positions, block_table, start_pos, chunk_lens)
+        (x, cache), _ = scan_blocks(PhiBlockCache, cfg.num_hidden_layers)(cfg, self.page_size, name="layers")(
+            (x, cache), jnp.arange(cfg.num_hidden_layers), positions, block_table, start_pos, chunk_lens)
         x = nn.LayerNorm(epsilon=cfg.layer_norm_eps, dtype=cfg.dtype, param_dtype=cfg.param_dtype,
                          name="final_layernorm")(x)
         logits = nn.Dense(cfg.vocab_size, use_bias=True, dtype=cfg.dtype, param_dtype=cfg.param_dtype,
@@ -324,23 +308,22 @@ class PhiForCausalLMWithCache(nn.Module):
 class Qwen2MoeBlockCache(nn.Module):
     cfg: Qwen2MoeConfig
     page_size: int = 16
-    scanned: bool = False
     sparse: bool = True   # mixed stacks: dense SwiGLU for mlp_only/off-step layers
 
     @nn.compact
-    def __call__(self, carry, layer_pages, positions=None, block_table=None, start_pos=None, chunk_lens=None):
+    def __call__(self, carry, layer, positions, block_table, start_pos, chunk_lens=None):
         from .llama_cache import LlamaAttentionCache
         from .qwen2_moe import Qwen2MoeDenseMLP
         cfg = self.cfg
-        x = carry
-        attn_out, layer_pages = LlamaAttentionCache(cfg.as_llama(), self.page_size, name="self_attn")(
+        x, pages = carry
+        attn_out, pages = LlamaAttentionCache(cfg.as_llama(), self.page_size, name="self_attn")(
             RMSNorm(cfg.rms_norm_eps, cfg.dtype, cfg.param_dtype, name="input_layernorm")(x), positions,
-            layer_pages, block_table, start_pos, chunk_lens)
+            pages, block_table, start_pos, chunk_lens, layer)
         h = x + attn_out
         mlp = Qwen2MoeSparseMLP(cfg, name="mlp") if self.sparse else Qwen2MoeDenseMLP(cfg, name="mlp")
         out = h + mlp(
             RMSNorm(cfg.rms_norm_eps, cfg.dtype, cfg.param_dtype, name="post_attention_layernorm")(h))
-        return out, layer_pages
+        return (out, pages), None
 
 
 class Qwen2MoeForCausalLMWithCache(nn.Module):
@@ -358,21 +341,16 @@ class Qwen2MoeForCausalLMWithCache(nn.Module):
         if cfg.mixed_stack:
             # dense/sparse layers can't share one scanned body — unroll with
             # per-layer dispatch, mirroring the training model's layers_{i}
-            # naming so converted checkpoints apply unchanged
-            new_pages = []
+            # naming so converted checkpoints apply unchanged; the arena stays
+            # whole here too, each block naming its layer in it
             for i in range(cfg.num_hidden_layers):
-                x, pages_i = Qwen2MoeBlockCache(cfg, self.page_size, sparse=cfg.layer_is_sparse(i),
-                                                name=f"layers_{i}")(x, cache[i], positions,
-                                                                    block_table, start_pos, chunk_lens)
-                new_pages.append(pages_i)
-            cache = jnp.stack(new_pages)
+                (x, cache), _ = Qwen2MoeBlockCache(cfg, self.page_size, sparse=cfg.layer_is_sparse(i),
+                                                   name=f"layers_{i}")((x, cache), i, positions, block_table,
+                                                                       start_pos, chunk_lens)
         else:
-            blocks = nn.scan(Qwen2MoeBlockCache, variable_axes={"params": 0}, split_rngs={"params": True},
-                             in_axes=(0, nn.broadcast, nn.broadcast, nn.broadcast, nn.broadcast),
-                             out_axes=0, length=cfg.num_hidden_layers,
-                             metadata_params={nn.PARTITION_NAME: LAYERS})
-            x, cache = blocks(cfg, self.page_size, scanned=True,
-                              name="layers")(x, cache, positions, block_table, start_pos, chunk_lens)
+            (x, cache), _ = scan_blocks(Qwen2MoeBlockCache, cfg.num_hidden_layers)(
+                cfg, self.page_size, name="layers")((x, cache), jnp.arange(cfg.num_hidden_layers), positions,
+                                                    block_table, start_pos, chunk_lens)
         x = RMSNorm(cfg.rms_norm_eps, cfg.dtype, cfg.param_dtype, name="norm")(x)
         if cfg.tie_word_embeddings:
             return embed.attend(x), cache

@@ -41,8 +41,8 @@ import jax.numpy as jnp
 from flax import linen as nn
 
 from .evabyte import EvaByteConfig, EvaByteHead, EvaProjections, eva_embed, eva_norm, summarise_chunks
-from .llama import LAYERS, LlamaMLP
-from .llama_cache import _write_pages, paged_attention
+from .llama import LlamaMLP
+from .llama_cache import _write_pages, paged_attention, scan_blocks
 
 
 def _summarise_completed(arena, layer, block_table, start_pos, chunk_lens, width, phi, mu, page_size, ring):
@@ -121,9 +121,6 @@ class EvaByteForCausalLMWithCache(nn.Module):
             chunk_lens = jnp.full(start_pos.shape, input_ids.shape[1], jnp.int32)
         positions = start_pos[:, None] + jnp.arange(input_ids.shape[1])[None, :]
         x = eva_embed(cfg)(input_ids).astype(jnp.float32)
-        blocks = nn.scan(EvaByteBlockCache, variable_axes={"params": 0}, split_rngs={"params": True},
-                         in_axes=(0, nn.broadcast, nn.broadcast, nn.broadcast, nn.broadcast),
-                         length=cfg.num_hidden_layers, metadata_params={nn.PARTITION_NAME: LAYERS})
-        (x, cache), _ = blocks(cfg, self.page_size, name="layers")(
+        (x, cache), _ = scan_blocks(EvaByteBlockCache, cfg.num_hidden_layers)(cfg, self.page_size, name="layers")(
             (x, cache), jnp.arange(cfg.num_hidden_layers), positions, block_table, start_pos, chunk_lens)
         return EvaByteHead(cfg, name="lm_head")(eva_norm(cfg, "norm", jnp.float32)(x), 1)[..., 0, :], cache

@@ -6,8 +6,22 @@ and v2 FastGen uses a blocked KV cache with blocked-flash kernels
 (``deepspeed/inference/v2/ragged/kv_cache.py:40 BlockedKVCache``,
 ``inference/v2/kernels/ragged_ops``).  TPU-native realisation: the cache is
 an explicit JAX array arena of fixed-size pages, functionally threaded
-through the forward pass (donated between steps so XLA updates it in
-place); attention gathers a sequence's pages via its block table.
+through the forward pass; attention gathers a sequence's pages via its
+block table.
+
+What updates the arena in place.  The engine donates the arena to every step
+program, but donation only gives the result somewhere to land: whether the
+step touches the rows it writes or moves the whole arena hangs on how the
+trunk hands it to the layers.  The scanned trunks carry it whole,
+[L, P, page, 2, n_kv, hd], beside the activations, and every write and read
+names its layer (``layer=``): the loop's carry is one buffer from the step's
+argument to its result, a write is a scatter of the chunk's rows into it and
+the kernel reads a layer's pages where they lie.  (A scan that takes a
+layer's pages in and hands them out slices a layer out, stacks a second arena
+and copies it back: three moves of the whole arena a model step, a third of
+served Mixtral's busy time on the chip: PERF.md, PR 27.)  The unrolled trunk
+(``scan_layers=False``) takes a tuple of per-layer arenas, donated leaf by
+leaf, and a block there is handed its own layer's pages and no index.
 
 Param-tree compatibility: module/submodule names mirror LlamaForCausalLM
 exactly (embed_tokens, model/layers/{self_attn/{q,k,v,o}_proj,
@@ -61,10 +75,15 @@ def _write_pages(pages, k_new, v_new, block_table, start_pos, page_size, chunk_l
     pages: [P, page, 2, n_kv, hd] (one layer)   k/v_new: [B, C, n_kv, hd]
     block_table: [B, max_pages]  start_pos: [B]  chunk_lens: [B] or None —
     positions at/after a row's chunk_len are padding; their writes are
-    redirected to the reserved null page 0.  With ``layer`` (a traced index)
-    ``pages`` is the whole arena [L, P, page, 2, n_kv, hd] and the rows go
-    into that layer of it: a twin that carries the arena through its layer
-    loop updates it in place instead of stacking a second one.
+    redirected to the reserved null page 0.  With ``layer`` (an index, traced
+    in a scanned trunk) ``pages`` is the whole arena [L, P, page, 2, n_kv, hd]
+    and the rows go into that layer of it.  This is the form that updates in
+    place: the arena is the layer loop's carry, so the scatter's operand and
+    result are one buffer and only the chunk's B*C rows move.  Without
+    ``layer`` the scatter is into one layer's pages, in place only where that
+    layer is a buffer of its own (the unrolled trunk's tuple); a layer sliced
+    out of a stacked arena by a scan is a copy, and so is the stack it goes
+    back into.
     """
     b, c = k_new.shape[0], k_new.shape[1]
     positions = start_pos[:, None] + jnp.arange(c)[None, :]          # [B, C]
@@ -134,21 +153,23 @@ def paged_attention(q, pages, block_table, start_pos, chunk_lens, page_size, sli
 
 
 def paged_attention_core(q, k, v, pages, block_table, start_pos, chunk_lens, page_size,
-                         attention_impl="reference", sliding_window=0, alibi_slopes=None):
+                         attention_impl="reference", sliding_window=0, alibi_slopes=None, layer=None):
     """Shared paged-KV attention core for every model family's cache twin:
     write this chunk's K/V into the arena, then attend the chunk's queries
     against (history + chunk).  q/k/v are post-projection, post-RoPE
-    [B, C, N(H|KV), D].  Returns (out [B, C, H, D], new_pages)."""
+    [B, C, N(H|KV), D].  ``pages`` is one layer's pages, or with ``layer``
+    the whole arena (``_write_pages``).  Returns (out [B, C, H, D], new_pages)."""
     pages = _write_pages(pages, k.astype(pages.dtype), v.astype(pages.dtype), block_table,
-                         start_pos, page_size, chunk_lens)
+                         start_pos, page_size, chunk_lens, layer=layer)
     if attention_impl == "flash" and not sliding_window and alibi_slopes is None:
         from ..ops.paged_attention import paged_attention_pallas
-        out = paged_attention_pallas(q, pages, block_table, start_pos, chunk_lens, page_size)
+        out = paged_attention_pallas(q, pages, block_table, start_pos, chunk_lens, page_size, layer=layer)
     else:
         # window masks / alibi bias decode through the jnp path (in-kernel
-        # variants land with the kernel)
-        out = paged_attention(q, pages, block_table, start_pos, chunk_lens, page_size,
-                              sliding_window=sliding_window, alibi_slopes=alibi_slopes)
+        # variants land with the kernel); out of the whole arena it reads a
+        # layer's slice, 1/L of an arena
+        out = paged_attention(q, pages if layer is None else pages[layer], block_table, start_pos, chunk_lens,
+                              page_size, sliding_window=sliding_window, alibi_slopes=alibi_slopes)
     return out, pages
 
 
@@ -178,7 +199,7 @@ class LlamaAttentionCache(nn.Module):
     page_size: int = 16
 
     @nn.compact
-    def __call__(self, x, positions, pages, block_table, start_pos, chunk_lens=None):
+    def __call__(self, x, positions, pages, block_table, start_pos, chunk_lens=None, layer=None):
         cfg = self.cfg
         head_dim = cfg.hidden_size // cfg.num_attention_heads
         from functools import partial
@@ -198,7 +219,7 @@ class LlamaAttentionCache(nn.Module):
         k = apply_rope(k, cos, sin)
         out, pages = paged_attention_core(q, k, v, pages, block_table, start_pos, chunk_lens,
                                           self.page_size, attention_impl=cfg.attention_impl,
-                                          sliding_window=cfg.sliding_window)
+                                          sliding_window=cfg.sliding_window, layer=layer)
         out = nn.DenseGeneral(features=cfg.hidden_size,
                               axis=(-2, -1),
                               use_bias=False,
@@ -210,23 +231,37 @@ class LlamaAttentionCache(nn.Module):
 
 
 class LlamaBlockCache(nn.Module):
+    """One block in the shape of a scan's body: ``(carry, layer, ...) ->
+    (carry, None)`` with ``carry = (x, pages)``.  A scanned trunk carries the
+    whole arena and scans over the layers' indices; the unrolled trunk hands a
+    block its own layer's pages and ``layer=None``.  Every softmax twin's
+    block has this form."""
     cfg: LlamaConfig
     page_size: int = 16
-    scanned: bool = False
 
     @nn.compact
-    def __call__(self, carry, layer_pages, positions=None, block_table=None, start_pos=None, chunk_lens=None):
+    def __call__(self, carry, layer, positions, block_table, start_pos, chunk_lens=None):
         cfg = self.cfg
-        x = carry
-        attn_out, layer_pages = LlamaAttentionCache(cfg, self.page_size, name="self_attn")(
-            RMSNorm(cfg.rms_norm_eps, cfg.dtype, cfg.param_dtype, name="input_layernorm")(x), positions, layer_pages,
-            block_table, start_pos, chunk_lens)
+        x, pages = carry
+        attn_out, pages = LlamaAttentionCache(cfg, self.page_size, name="self_attn")(
+            RMSNorm(cfg.rms_norm_eps, cfg.dtype, cfg.param_dtype, name="input_layernorm")(x), positions, pages,
+            block_table, start_pos, chunk_lens, layer)
         h = x + attn_out
         out = h + LlamaMLP(cfg, name="mlp")(
             RMSNorm(cfg.rms_norm_eps, cfg.dtype, cfg.param_dtype, name="post_attention_layernorm")(h))
-        if self.scanned:
-            return out, layer_pages
-        return out, layer_pages
+        return (out, pages), None
+
+
+def scan_blocks(block_cls, num_layers, n_broadcast=4):
+    """``nn.scan`` of a serving block over the layers' indices, the arena in
+    the carry: ``blocks(...)((x, arena), jnp.arange(L), *broadcast)`` ->
+    ``((x, arena), None)``."""
+    return nn.scan(block_cls,
+                   variable_axes={"params": 0},
+                   split_rngs={"params": True},
+                   in_axes=(0, ) + (nn.broadcast, ) * n_broadcast,
+                   length=num_layers,
+                   metadata_params={nn.PARTITION_NAME: LAYERS})
 
 
 class LlamaForCausalLMWithCache(nn.Module):
@@ -258,26 +293,22 @@ class LlamaForCausalLMWithCache(nn.Module):
                     # unrolled serving trunk (params layout model/layers_i/*,
                     # see unstack_layer_params): straight-line code drops the
                     # scan's while/dynamic-slice bookkeeping — measured ~22ms
-                    # of 123ms per 8 fused decode rounds at B32 (r4).  The
-                    # cache arrives as a TUPLE of per-layer arenas (donated
-                    # leaf-wise); an [L, ...] array would force a whole-arena
-                    # dynamic-update per layer
+                    # of 123ms per 8 fused decode rounds at B32 (r4, against
+                    # the scan that stacked the arena and moved all of it
+                    # every step: true of that scan only, the one below
+                    # carries the arena and writes it in place).  The cache
+                    # arrives as a TUPLE of per-layer arenas (donated
+                    # leaf-wise), each block handed its own
                     new_pages = []
                     for i in range(self.cfg.num_hidden_layers):
-                        x, pages_i = LlamaBlockCache(self.cfg, self.page_size,
-                                                     name=f"layers_{i}")(
-                            x, cache[i], positions, block_table, start_pos, chunk_lens)
+                        (x, pages_i), _ = LlamaBlockCache(self.cfg, self.page_size, name=f"layers_{i}")(
+                            (x, cache[i]), None, positions, block_table, start_pos, chunk_lens)
                         new_pages.append(pages_i)
                     return x, tuple(new_pages)
-                blocks = nn.scan(LlamaBlockCache,
-                                 variable_axes={"params": 0},
-                                 split_rngs={"params": True},
-                                 in_axes=(0, nn.broadcast, nn.broadcast, nn.broadcast, nn.broadcast),
-                                 out_axes=0,
-                                 length=self.cfg.num_hidden_layers,
-                                 metadata_params={nn.PARTITION_NAME: LAYERS})
-                x, cache = blocks(self.cfg, self.page_size, scanned=True,
-                                  name="layers")(x, cache, positions, block_table, start_pos, chunk_lens)
+                (x, cache), _ = scan_blocks(LlamaBlockCache, self.cfg.num_hidden_layers)(
+                    self.cfg, self.page_size, name="layers")(
+                        (x, cache), jnp.arange(self.cfg.num_hidden_layers), positions, block_table, start_pos,
+                        chunk_lens)
                 return x, cache
 
         x, cache = _Trunk(cfg, self.page_size, name="model")(x, cache, positions, block_table, start_pos, chunk_lens)

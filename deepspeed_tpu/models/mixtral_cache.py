@@ -19,24 +19,22 @@ import jax.numpy as jnp
 from flax import linen as nn
 
 from ..moe.layer import MoE
-from .llama import EMBED, LAYERS, VOCAB, RMSNorm, _logical
-from .llama_cache import LlamaAttentionCache
+from .llama import EMBED, VOCAB, RMSNorm, _logical
+from .llama_cache import LlamaAttentionCache, scan_blocks
 from .mixtral import MixtralConfig
 
 
 class MixtralBlockCache(nn.Module):
     cfg: MixtralConfig
     page_size: int = 16
-    scanned: bool = False
 
     @nn.compact
-    def __call__(self, carry, layer_pages, positions=None, block_table=None, start_pos=None, chunk_lens=None,
-                 stacked_banks=None, layer=None):
+    def __call__(self, carry, layer, positions, block_table, start_pos, chunk_lens=None, stacked_banks=None):
         cfg = self.cfg
-        x = carry
-        attn_out, layer_pages = LlamaAttentionCache(cfg.as_llama(), self.page_size, name="self_attn")(
+        x, pages = carry
+        attn_out, pages = LlamaAttentionCache(cfg.as_llama(), self.page_size, name="self_attn")(
             RMSNorm(cfg.rms_norm_eps, cfg.dtype, cfg.param_dtype, name="input_layernorm")(x), positions,
-            layer_pages, block_table, start_pos, chunk_lens)
+            pages, block_table, start_pos, chunk_lens, layer)
         h = x + attn_out
         # a chunk's padding goes to no expert (the mask the KV write uses)
         token_mask = None if chunk_lens is None else jnp.arange(x.shape[1])[None, :] < chunk_lens[:, None]
@@ -55,8 +53,7 @@ class MixtralBlockCache(nn.Module):
                                                    name="post_attention_layernorm")(h), train=False,
                                            token_mask=token_mask,
                                            stacked_banks=None if stacked_banks is None else (stacked_banks, layer))
-        out = h + moe_out
-        return out, layer_pages
+        return (h + moe_out, pages), None
 
 
 class MixtralForCausalLMWithCache(nn.Module):
@@ -89,16 +86,11 @@ class MixtralForCausalLMWithCache(nn.Module):
                          embedding_init=_logical(nn.initializers.normal(0.02), (VOCAB, EMBED)),
                          name="embed_tokens")
         x = embed(input_ids)
-        blocks = nn.scan(MixtralBlockCache,
-                         variable_axes={"params": 0},
-                         split_rngs={"params": True},
-                         in_axes=(0, nn.broadcast, nn.broadcast, nn.broadcast, nn.broadcast, nn.broadcast, 0),
-                         out_axes=0,
-                         length=cfg.num_hidden_layers,
-                         metadata_params={nn.PARTITION_NAME: LAYERS})
-        x, cache = blocks(cfg, self.page_size, scanned=True,
-                          name="layers")(x, cache, positions, block_table, start_pos, chunk_lens,
-                                         self._stacked_banks(), jnp.arange(cfg.num_hidden_layers))
+        # the arena rides in the carry and the blocks name their layer in it,
+        # as they do in the stacked expert banks (models/llama_cache.py)
+        (x, cache), _ = scan_blocks(MixtralBlockCache, cfg.num_hidden_layers, n_broadcast=5)(
+            cfg, self.page_size, name="layers")((x, cache), jnp.arange(cfg.num_hidden_layers), positions,
+                                                block_table, start_pos, chunk_lens, self._stacked_banks())
         x = RMSNorm(cfg.rms_norm_eps, cfg.dtype, cfg.param_dtype, name="norm")(x)
         logits = nn.DenseGeneral(features=cfg.vocab_size,
                                  use_bias=False,

@@ -83,42 +83,43 @@ def _paged_kernel(bt_ref, sp_ref, q_ref, pg_ref, o_ref, *scr, page_size, max_pag
             o_ref[0, hh] = (accs[hh][:] / jnp.maximum(ls[hh][:], 1e-30)).astype(o_ref.dtype)
 
 
-def _paged_sharded(q, pages, block_table, start_pos, chunk_lens, page_size, interpret, mesh):
+def _paged_sharded(q, pages, block_table, start_pos, chunk_lens, page_size, interpret, mesh, layer=None):
     """Run the paged kernel inside shard_map over the governing (trace) mesh.
 
     Mosaic custom calls cannot be auto-partitioned by GSPMD — the TP-sharded
     serving engine (inference/v2) traces this under a tensor-axis mesh, so the
     kernel wraps itself the way ``flash_attention._flash_sharded`` does.
-    Attention is head-local: q shards on H, the page arena on its n_kv dim,
-    block tables/positions replicate, and no collective is needed inside —
-    the o_proj allreduce after it is GSPMD's to insert.  A tensor degree that
-    does not divide n_kv replicates (correct, just not distributed)."""
+    Attention is head-local: q shards on H, the page arena on its n_kv dim
+    (one layer's pages or, with ``layer``, the whole arena under one more
+    leading dimension), block tables, positions and the layer's index
+    replicate, and no collective is needed inside — the o_proj allreduce after
+    it is GSPMD's to insert.  A tensor degree that does not divide n_kv
+    replicates (correct, just not distributed)."""
     from jax.sharding import PartitionSpec as P
 
     from ..comm.mesh import TENSOR_AXIS
-    h, n_kv = q.shape[2], pages.shape[3]
+    h, n_kv = q.shape[2], pages.shape[-2]
     tp = mesh.shape.get(TENSOR_AXIS, 1)
     head_axes = (TENSOR_AXIS, ) if tp > 1 and n_kv % tp == 0 and h % tp == 0 else ()
     qspec = P(None, None, head_axes or None, None)
-    pspec = P(None, None, None, head_axes or None, None)
-    if chunk_lens is None:
-        fn = jax.shard_map(
-            lambda q_, pg_, bt_, sp_: paged_attention_pallas(
-                q_, pg_, bt_, sp_, None, page_size, interpret=interpret),
-            mesh=mesh,
-            in_specs=(qspec, pspec, P(None, None), P(None)),
-            out_specs=qspec,
-            check_vma=False)
-        return fn(q, pages, block_table, start_pos)
+    pspec = P(*(None, ) * (pages.ndim - 2), head_axes or None, None)
+    optional = {"chunk_lens": (chunk_lens, P(None)),
+                "layer": (None if layer is None else jnp.asarray(layer, jnp.int32), P())}
+    given = {name: arg_spec for name, arg_spec in optional.items() if arg_spec[0] is not None}
+
+    def local(q_, pg_, bt_, sp_, *rest):
+        kw = dict(zip(given, rest))
+        return paged_attention_pallas(q_, pg_, bt_, sp_, kw.get("chunk_lens"), page_size,
+                                      layer=kw.get("layer"), interpret=interpret)
+
     fn = jax.shard_map(
-        lambda q_, pg_, bt_, sp_, cl_: paged_attention_pallas(
-            q_, pg_, bt_, sp_, cl_, page_size, interpret=interpret),
+        local,
         mesh=mesh,
-        in_specs=(qspec, pspec, P(None, None), P(None), P(None)),
+        in_specs=(qspec, pspec, P(None, None), P(None), *(spec for _, spec in given.values())),
         out_specs=qspec,
         # pallas_call out_shapes carry no varying-mesh-axes annotation
         check_vma=False)
-    return fn(q, pages, block_table, start_pos, chunk_lens)
+    return fn(q, pages, block_table, start_pos, *(arg for arg, _ in given.values()))
 
 
 def paged_attention_pallas(q, pages, block_table, start_pos, chunk_lens, page_size,
@@ -127,9 +128,9 @@ def paged_attention_pallas(q, pages, block_table, start_pos, chunk_lens, page_si
 
     q: [B, C, H, D]; pages: [P, page, 2, n_kv, D] (chunk K/V already
     written); block_table: [B, max_pages]; start_pos/chunk_lens: [B].
-    With ``layer`` (a traced index) ``pages`` is the whole arena
-    [L, P, page, 2, n_kv, D] and the kernel reads that layer's pages where
-    they lie: no layer of the arena is sliced out first.
+    With ``layer`` (an index, traced in a scanned trunk) ``pages`` is the
+    whole arena [L, P, page, 2, n_kv, D] and the kernel reads that layer's
+    pages where they lie: no layer of the arena is sliced out first.
     """
     from ..comm.mesh import get_trace_mesh, in_manual_mesh
     if interpret is None:
@@ -139,10 +140,8 @@ def paged_attention_pallas(q, pages, block_table, start_pos, chunk_lens, page_si
     if isinstance(q, jax.core.Tracer) and not in_manual_mesh():
         mesh = get_trace_mesh()
         if mesh is not None and mesh.size > 1:
-            if layer is not None:
-                raise NotImplementedError("the whole-arena form of the paged kernel is single-device")
             return _paged_sharded(q, pages, block_table, start_pos, chunk_lens, page_size,
-                                  interpret, mesh)
+                                  interpret, mesh, layer)
     b, c, h, d = q.shape
     n_kv = pages.shape[-2]
     max_pages = block_table.shape[1]

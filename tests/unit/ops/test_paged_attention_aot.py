@@ -1,7 +1,9 @@
 """The paged kernel compiled for the chip, without the chip, at the shapes the
 benchmark's serving cells give it: what interpret mode cannot refuse (tiling,
 scoped VMEM, 32 key heads unrolled in one grid step) the chip's compiler
-does, here, in a second or two a shape.  Nothing runs and no time is read.
+does, here, in a second or two a shape.  And a small Mixtral twin's step
+programs, to read the compiler's buffer assignment for a second arena.
+Nothing runs and no time is read.
 
 The topology is described inside a fixture (never at import: every xdist
 worker imports this file, only the one that runs it may load the TPU's
@@ -18,18 +20,32 @@ from deepspeed_tpu.ops.paged_attention import paged_attention_pallas
 
 
 @pytest.fixture(scope="module")
-def one_chip():
+def described_chip():
     os.environ.setdefault("TPU_LOG_DIR", "disabled")
     from jax.experimental import topologies
-    from jax.sharding import SingleDeviceSharding
     try:
-        topo = topologies.get_topology_desc(platform="tpu", topology_name="v5e:2x2")
+        return topologies.get_topology_desc(platform="tpu", topology_name="v5e:2x2").devices[0]
     except Exception as e:
         pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
-    return SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.fixture(scope="module")
+def one_chip(described_chip):
+    from jax.sharding import SingleDeviceSharding
+    return SingleDeviceSharding(described_chip)
+
+
+@pytest.fixture
+def no_compile_cache():
+    """A described device's compile cannot be read back."""
+    cache_was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    yield
+    jax.config.update("jax_enable_compilation_cache", cache_was)
 
 
 def _compile(one_chip, chunk, n_q, n_kv, table_width, layers=None):
+    """The kernel alone; under the ``no_compile_cache`` fixture."""
     sds = lambda shape, dtype: jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)  # noqa: E731
     arena = (64, 16, 2, n_kv, 128) if layers is None else (layers, 64, 16, 2, n_kv, 128)
     args = [sds((16, chunk, n_q, 128), jnp.bfloat16), sds(arena, jnp.bfloat16), sds((16, table_width), jnp.int32),
@@ -40,22 +56,58 @@ def _compile(one_chip, chunk, n_q, n_kv, table_width, layers=None):
 
     if layers is not None:
         args.append(sds((), jnp.int32))
-    cache_was = jax.config.jax_enable_compilation_cache
-    jax.config.update("jax_enable_compilation_cache", False)   # a described device's compile cannot be read back
-    try:
-        return jax.jit(call).lower(*args).compile()
-    finally:
-        jax.config.update("jax_enable_compilation_cache", cache_was)
+    return jax.jit(call).lower(*args).compile()
 
 
 @pytest.mark.parametrize("chunk", [128, 1])
-def test_grouped_heads_one_layer_of_pages(one_chip, chunk):
-    """Mixtral's shape: 32 query heads over 8 key heads, a layer's pages."""
+def test_grouped_heads_one_layer_of_pages(one_chip, no_compile_cache, chunk):
+    """Mixtral's heads, 32 query heads over 8 key heads, out of one layer's
+    pages: the form the unrolled trunk still gives the kernel."""
     assert "tpu_custom_call" in _compile(one_chip, chunk, 32, 8, 770).as_text()
 
 
 @pytest.mark.parametrize("chunk", [128, 1])
-def test_ungrouped_heads_out_of_the_whole_arena(one_chip, chunk):
+def test_grouped_heads_out_of_the_whole_arena(one_chip, no_compile_cache, chunk):
+    """Mixtral's shape as its scanned twin gives it: 32 query heads over 8
+    key heads, the layer named by an index into an arena of 3."""
+    assert "tpu_custom_call" in _compile(one_chip, chunk, 32, 8, 770, layers=3).as_text()
+
+
+@pytest.mark.parametrize("chunk", [128, 1])
+def test_ungrouped_heads_out_of_the_whole_arena(one_chip, no_compile_cache, chunk):
     """EvaByte's shape: 32 key heads, no grouping, the layer named by an
     index into the whole arena, a table of 248 virtual pages."""
     assert "tpu_custom_call" in _compile(one_chip, chunk, 32, 32, 248, layers=8).as_text()
+
+
+@pytest.mark.parametrize("program", ["step_c128", "step_c1", "fused_2"])
+def test_scanned_twin_holds_no_second_arena(described_chip, no_compile_cache, program):
+    """A small Mixtral twin's donated step programs, compiled for one chip
+    with an arena that dwarfs everything else in them (101 MB beside 25 MB of
+    weights and a few MB of activations): the compiler's temporaries stay under
+    half an arena, in the mixed step, the one-token step and the fused decode
+    program (a ``fori_loop`` of 2 around the layer scan, the arena the carry
+    of both).  On the parent of PR 27 all three fail, with 101 to 102 MB of
+    temporaries: the scan took a layer's pages in and stacked them on the way
+    out (at the benchmark's size, 4.09 GB beside a 2.15 GB arena)."""
+    from deepspeed_tpu.comm.mesh import MeshSpec, create_mesh
+    from deepspeed_tpu.inference.v2 import RaggedInferenceEngineConfig
+    from deepspeed_tpu.inference.v2.engine_v2 import compile_aot_serving
+    from deepspeed_tpu.inference.v2.scheduler import SchedulerConfig
+    from deepspeed_tpu.models.llama_cache import PagedKVConfig
+    from deepspeed_tpu.models.mixtral import MixtralConfig
+    cfg = MixtralConfig(vocab_size=512, hidden_size=512, intermediate_size=512, num_hidden_layers=3,
+                        num_attention_heads=4, num_key_value_heads=2, num_local_experts=4, num_experts_per_tok=2,
+                        max_position_embeddings=4096, drop_tokens=False, dtype=jnp.bfloat16,
+                        param_dtype=jnp.bfloat16, attention_impl="flash", scan_layers=True, remat=False)
+    kv = PagedKVConfig(num_pages=2048, page_size=16, max_pages_per_seq=64)
+    arena_bytes = cfg.num_hidden_layers * kv.num_pages * kv.page_size * 2 * cfg.num_key_value_heads * 128 * 2
+    econf = RaggedInferenceEngineConfig(kv=kv, scheduler=SchedulerConfig(token_budget=1024, max_seqs=8,
+                                                                         prefill_chunk=128, decode_bucket=8))
+    mesh = create_mesh(MeshSpec(), devices=[described_chip])
+    how = {"step_c128": dict(chunk=128), "step_c1": dict(chunk=1), "fused_2": dict(fused_steps=2)}[program]
+    compiled, _ = compile_aot_serving(cfg, mesh, econf, batch=8, **how)
+    mem = compiled.memory_analysis()
+    assert mem.alias_size_in_bytes >= arena_bytes, "the donated arena is not the result's buffer"
+    assert mem.temp_size_in_bytes < arena_bytes // 2, (mem.temp_size_in_bytes, arena_bytes)
+    assert "tpu_custom_call" in compiled.as_text()
