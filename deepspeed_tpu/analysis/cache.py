@@ -1,7 +1,7 @@
 """dslint incremental cache: findings keyed on content hashes.
 
 A warm full-repo run costs one sha256 sweep (~tens of ms) instead of a
-parse + 9-checker walk of every file (~seconds).  Correctness stance:
+parse + 8-checker walk of every file (~seconds).  Correctness stance:
 several checkers are **cross-file** (event/fault-site registries, the
 call graph, the state-machine tables, doc sync), so a single changed
 file can move findings in *other* files — the cache therefore replays a
@@ -16,7 +16,7 @@ stored run only when EVERY input matches:
 Anything else is a full re-run that refreshes the store.  Replayed
 output is byte-identical to the live run's ``--json`` (asserted in
 tier-1): findings are stored per file plus a cross-file remainder
-(docs/BENCH artifacts) and re-sorted through the same ``Finding`` path.
+(docs artifacts) and re-sorted through the same ``Finding`` path.
 
 Persistence is ``.dslint_cache/cache.json`` under the repo root,
 published with the same temp + fsync + atomic-rename discipline as
@@ -85,9 +85,8 @@ class DslintCache:
     def file_hashes(self, files: Sequence[str]) -> List[Tuple[str, str]]:
         """(root-relative path, sha256) per file, sorted by rel path —
         the per-file half of the scan key.  The non-``.py`` artifacts the
-        finish-phase checkers read (committed root ``*.json`` benches,
-        ``docs/*.md`` generated tables) are folded in too: a hand-edited
-        STATE_MACHINES.md or a corrupted BENCH_*.json must be a cache
+        finish-phase checkers read (``docs/*.md`` generated tables) are
+        folded in too: a hand-edited STATE_MACHINES.md must be a cache
         MISS, or the drift-as-finding contract dies in the warm path."""
         seen = {}
         for path in list(files) + self._artifact_files():
@@ -98,21 +97,16 @@ class DslintCache:
 
     def _artifact_files(self) -> List[str]:
         out = []
-        # committed bench artifacts (bench-schema reads them), generated
-        # doc tables (event-registry/state-machine drift checks), and the
-        # delegated validator sources under scripts/ (bench-schema
-        # imports check_bench_schema.py even when `scripts` is not among
-        # the scanned paths) — same stance as analysis_sources_hash:
-        # editing any input re-runs everything
-        for dirname, suffix in ((".", ".json"), ("docs", ".md"),
-                                ("scripts", ".py")):
-            d = os.path.join(self.root, dirname)
-            try:
-                for fn in sorted(os.listdir(d)):
-                    if fn.endswith(suffix):
-                        out.append(os.path.join(d, fn))
-            except OSError:
-                pass
+        # generated doc tables (event-registry/state-machine drift
+        # checks) — same stance as analysis_sources_hash: editing any
+        # input re-runs everything
+        d = os.path.join(self.root, "docs")
+        try:
+            for fn in sorted(os.listdir(d)):
+                if fn.endswith(".md"):
+                    out.append(os.path.join(d, fn))
+        except OSError:
+            pass
         # the event registry is loaded from run.root by its checker even
         # when the scan paths don't cover it (partial invocations)
         reg = os.path.join(self.root, "deepspeed_tpu", "telemetry",

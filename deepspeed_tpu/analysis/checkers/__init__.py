@@ -2,7 +2,6 @@
 here, then list it in ``ALL`` (docs/ANALYSIS.md walks through an example)."""
 
 from .atomic_write import AtomicWriteChecker
-from .bench_schema import BenchSchemaChecker
 from .crash_transparency import CrashTransparencyChecker
 from .crash_transparency_interproc import CrashTransparencyInterprocChecker
 from .determinism import DeterminismChecker
@@ -18,7 +17,6 @@ ALL = (
     FaultSiteChecker,
     EventRegistryChecker,
     AtomicWriteChecker,
-    BenchSchemaChecker,
     KVLifetimeChecker,
     StateMachineChecker,
 )

@@ -20,10 +20,7 @@ SENSITIVE_GLOBS = [
     "deepspeed_tpu/runtime/checkpoint_engine.py",
     "deepspeed_tpu/runtime/swap_tensor/*.py",
     "deepspeed_tpu/resilience/*.py",
-    "scripts/bench_*.py",
     "scripts/aot_membudget.py",
-    "bench.py",
-    "bench_inference.py",
 ]
 
 LEGACY_MARKER = "atomic-ok"

@@ -58,11 +58,8 @@ def build_hf_engine(path: str,
     # policies but no paged cache twin — the reference serves them through
     # v1 kernel injection (module_inject/containers); here they route to the
     # v1 jitted-forward engine behind a generate()-compatible surface
-    from ...models.llama import LlamaConfig
     from ...models.cache_zoo import CACHE_MODEL_REGISTRY
-    from ...models.mixtral import MixtralConfig
-    twin_cfgs = (LlamaConfig, MixtralConfig, *CACHE_MODEL_REGISTRY.keys())
-    if not isinstance(cfg, twin_cfgs):
+    if not isinstance(cfg, tuple(CACHE_MODEL_REGISTRY)):
         import deepspeed_tpu as ds
         from .model_implementations.policies import policy_for
         if quantization_mode is not None:

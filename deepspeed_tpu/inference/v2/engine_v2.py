@@ -27,8 +27,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from ...models.llama import LlamaConfig
-from ...models.llama_cache import LlamaForCausalLMWithCache, PagedKVConfig, init_kv_cache
+from ...models.llama_cache import PagedKVConfig, init_kv_cache, stack_layer_params
 from ...telemetry.step_anatomy import NULL_ANATOMY
 from ...utils.logging import logger
 from .ragged import BlockedKVCache, RaggedBatch, StateManager
@@ -40,20 +39,8 @@ def build_cache_model(cfg, page_size: int):
     """Per-arch paged-cache model dispatch (the reference's
     model_implementations registry role, ref: inference/v2/engine_factory.py
     arch switch)."""
-    from ...models.mixtral import MixtralConfig
-    if isinstance(cfg, MixtralConfig):
-        from ...models.mixtral_cache import MixtralForCausalLMWithCache
-        if cfg.drop_tokens:
-            # serving must be dropless: capacity drops would silently zero
-            # routed tokens and diverge from HF (the reference FastGen moe
-            # gating has no capacity limit at inference)
-            cfg = cfg.__class__(**{**cfg.__dict__, "drop_tokens": False})
-        return MixtralForCausalLMWithCache(cfg, page_size=page_size)
-    from ...models.cache_zoo import CACHE_MODEL_REGISTRY
-    for cfg_cls, model_cls in CACHE_MODEL_REGISTRY.items():
-        if isinstance(cfg, cfg_cls):
-            return model_cls(cfg, page_size=page_size)
-    return LlamaForCausalLMWithCache(cfg, page_size=page_size)
+    from ...models.cache_zoo import cache_twin
+    return cache_twin(cfg).model(cfg, page_size=page_size)
 
 
 def _table_width(cfg, kvcfg: PagedKVConfig) -> int:
@@ -81,13 +68,6 @@ class RaggedInferenceEngineConfig:
     # dominates decode at small models.  Sequences
     # hitting EOS mid-block have their surplus tokens discarded host-side.
     decode_steps_per_dispatch: int = 8
-    # unroll the layer loop in the decode trunk (llama-family twin only;
-    # other families and quantized checkpoints keep the scanned layout with
-    # a warning): straight-line code drops the scan's while/dus bookkeeping
-    # at tiny decode shapes; scan-stacked checkpoints are converted at
-    # engine init (models/llama_cache.unstack_layer_params — no data
-    # movement)
-    unroll_layers: bool = False
     # TP-sharded serving (ref: inference/v2/engine_v2.py:118 honors
     # tensor_parallel.tp_size; model_implementations/sharding/qkv.py et al.).
     # Weights shard via the logical-axis rules (module_inject/tp_rules.py),
@@ -262,7 +242,7 @@ class InferenceEngineV2:
     tokens need, is the geometry's (``self.kv.geometry``); the engine only
     hands the model's step programs the block-table rows."""
 
-    def __init__(self, cfg: LlamaConfig, params, engine_config: RaggedInferenceEngineConfig = None,
+    def __init__(self, cfg, params, engine_config: RaggedInferenceEngineConfig = None,
                  rng: Optional[jax.Array] = None, mesh=None):
         self.econfig = engine_config or RaggedInferenceEngineConfig()
         # speculative decoding: greedy-only (the accept rule is an argmax
@@ -291,33 +271,20 @@ class InferenceEngineV2:
         kvcfg = self.econfig.kv
         from ..quantization import QuantizedParams
         self.mesh = self._resolve_mesh(mesh)
-        if self.mesh is not None:
-            if isinstance(params, QuantizedParams):
-                raise NotImplementedError(
-                    "TP-sharded serving of weight-only-quantized checkpoints is not "
-                    "implemented (int8 blocks would need per-shard scale re-layout)")
-            if self.econfig.unroll_layers:
-                logger.warning("tensor_parallel: the unrolled decode trunk is single-device; "
-                               "keeping the scanned layout")
-                self.econfig = dataclasses.replace(self.econfig, unroll_layers=False)
-        model = build_cache_model(cfg, kvcfg.page_size)
-        if self.econfig.unroll_layers and getattr(cfg, "scan_layers", False):
-            # only the llama-family twin implements the unrolled trunk; other
-            # families' twins are scan-only and would fail with a converted
-            # param tree / tupled cache
-            if not isinstance(model, LlamaForCausalLMWithCache):
-                logger.warning(f"unroll_layers: {type(model).__name__} has no unrolled "
-                               "trunk; keeping the scanned layout")
-            elif isinstance(params, QuantizedParams):
-                logger.warning("unroll_layers: quantized checkpoints keep the scanned "
-                               "layout (per-layer dequant conversion not implemented)")
-            else:
-                cfg = dataclasses.replace(cfg, scan_layers=False)
-                from ...models.llama_cache import unstack_layer_params
-                params = unstack_layer_params(params, cfg.num_hidden_layers)
-                model = build_cache_model(cfg, kvcfg.page_size)
+        if self.mesh is not None and isinstance(params, QuantizedParams):
+            raise NotImplementedError(
+                "TP-sharded serving of weight-only-quantized checkpoints is not "
+                "implemented (int8 blocks would need per-shard scale re-layout)")
         self.cfg = cfg
-        self.model = model
+        self.model = build_cache_model(cfg, kvcfg.page_size)
+        if not getattr(cfg, "scan_layers", True) and not getattr(cfg, "mixed_stack", False):
+            # a tree trained with scan_layers=False names its layers
+            # model/layers_<i>; every twin scans one stacked model/layers but
+            # the mixed dense/sparse stack, whose layers differ in shape
+            if isinstance(params, QuantizedParams):
+                raise NotImplementedError("a quantized scan_layers=False tree cannot be stacked: "
+                                          "stack it (stack_layer_params), then quantize")
+            params = stack_layer_params(params, cfg.num_hidden_layers)
         # experts a token is routed to (0: no expert layer), for the step
         # records' expert_rows
         self._experts_per_tok = int(getattr(cfg, "num_experts_per_tok", 0) or 0)
@@ -339,12 +306,7 @@ class InferenceEngineV2:
                                       "(pages rewritten in place) is not implemented")
         self.state = StateManager(self.kv, max_batch=self.econfig.scheduler.max_seqs)
         self.scheduler = SplitFuseScheduler(self.econfig.scheduler)
-        cache = init_kv_cache(cfg, kvcfg, dtype=self.econfig.kv_dtype)
-        if not getattr(cfg, "scan_layers", True):
-            # unrolled trunk: per-layer arena tuple (donated leaf-wise; a
-            # stacked arena would cost a whole-arena dus per layer per round)
-            cache = tuple(cache[i] for i in range(cfg.num_hidden_layers))
-        self.cache = cache
+        self.cache = init_kv_cache(cfg, kvcfg, dtype=self.econfig.kv_dtype)
         self.rng = rng if rng is not None else jax.random.PRNGKey(0)
         self._max_new: Dict[int, int] = {}
         self._step_fns: Dict[Tuple[int, int], callable] = {}
@@ -413,13 +375,6 @@ class InferenceEngineV2:
         from ...comm.mesh import TENSOR_AXIS
         mesh = self.mesh
         tp = mesh.shape.get(TENSOR_AXIS, 1)
-        if not isinstance(self.cache, jax.Array):
-            # scan_layers=False builds a per-layer arena TUPLE for leaf-wise
-            # donation — a single-device decode optimization; the TP path is
-            # scanned-only (same stance as the unroll_layers guard in init)
-            raise NotImplementedError(
-                "TP-sharded serving requires scan_layers=True (the per-layer "
-                "unrolled arena tuple is a single-device layout)")
         n_kv = self.cache.shape[-2]
         heads = self.cfg.num_attention_heads
         if tp > 1 and (n_kv % tp or heads % tp):
@@ -1143,7 +1098,7 @@ class InferenceEngineV2:
         return outs
 
 
-def build_engine(cfg: LlamaConfig, params, engine_config: RaggedInferenceEngineConfig = None,
+def build_engine(cfg, params, engine_config: RaggedInferenceEngineConfig = None,
                  mesh=None):
     """Factory (ref: inference/v2/engine_factory.py:69 build_hf_engine —
     there it loads an HF checkpoint; here weights come from the training

@@ -9,18 +9,23 @@ whose attention goes through the shared ``paged_attention_core``
 program for prefill / continuation / decode.
 """
 
+import dataclasses
 from functools import partial
+from typing import Callable
 
 import jax
 import jax.numpy as jnp
 from flax import linen as nn
 
 from ..inference.v2.geometry import LinearGeometry, RingSummaryGeometry
-from .llama import EMBED, HEAD_DIM, HEADS, KV_HEADS, MLP, VOCAB, RMSNorm, _logical, apply_rope, rotary_embedding
-from .llama_cache import paged_attention_core, scan_blocks
+from .llama import (EMBED, HEAD_DIM, HEADS, KV_HEADS, MLP, VOCAB, LlamaConfig, RMSNorm, _logical, apply_rope,
+                    rotary_embedding)
+from .llama_cache import LlamaForCausalLMWithCache, paged_attention_core, scan_blocks
 from .evabyte import EvaByteConfig
 from .evabyte_cache import EvaByteForCausalLMWithCache
 from .falcon import FalconConfig
+from .mixtral import MixtralConfig
+from .mixtral_cache import MixtralForCausalLMWithCache
 from .opt import OPTConfig
 from .phi import PhiConfig, apply_partial_rope
 from .qwen2_moe import Qwen2MoeConfig, Qwen2MoeSparseMLP
@@ -361,25 +366,46 @@ class Qwen2MoeForCausalLMWithCache(nn.Module):
         return logits, cache
 
 
+@dataclasses.dataclass(frozen=True)
+class CacheTwin:
+    """What serves a configuration: ``model(cfg, page_size=)`` builds its
+    paged-cache twin, ``geometry(cfg, page_size)`` says what a page of its
+    arena holds (inference/v2/geometry.py)."""
+    model: Callable
+    geometry: Callable = lambda cfg, page_size: LinearGeometry(page_size)
+
+
+def _dropless_mixtral(cfg, page_size):
+    if cfg.drop_tokens:
+        # serving must be dropless: capacity drops would silently zero
+        # routed tokens and diverge from HF (the reference FastGen moe
+        # gating has no capacity limit at inference)
+        cfg = cfg.__class__(**{**cfg.__dict__, "drop_tokens": False})
+    return MixtralForCausalLMWithCache(cfg, page_size=page_size)
+
+
+#: the one place that says which twin and which geometry serve a configuration
 CACHE_MODEL_REGISTRY = {
-    FalconConfig: FalconForCausalLMWithCache,
-    OPTConfig: OPTForCausalLMWithCache,
-    PhiConfig: PhiForCausalLMWithCache,
-    Qwen2MoeConfig: Qwen2MoeForCausalLMWithCache,
-    EvaByteConfig: EvaByteForCausalLMWithCache,
+    LlamaConfig: CacheTwin(LlamaForCausalLMWithCache),
+    MixtralConfig: CacheTwin(_dropless_mixtral),
+    FalconConfig: CacheTwin(FalconForCausalLMWithCache),
+    OPTConfig: CacheTwin(OPTForCausalLMWithCache),
+    PhiConfig: CacheTwin(PhiForCausalLMWithCache),
+    Qwen2MoeConfig: CacheTwin(Qwen2MoeForCausalLMWithCache),
+    EvaByteConfig: CacheTwin(EvaByteForCausalLMWithCache,
+                             lambda cfg, page_size: RingSummaryGeometry(page_size, cfg.window_size)),
 }
 
-#: what a page holds, for the families whose pages are not "16 tokens' keys
-#: and values for ever" (inference/v2/geometry.py); every other family's
-#: geometry is linear
-CACHE_GEOMETRY_REGISTRY = {
-    EvaByteConfig: lambda cfg, page_size: RingSummaryGeometry(page_size, cfg.window_size),
-}
+
+def cache_twin(cfg) -> CacheTwin:
+    """The registry's entry for ``cfg``."""
+    for cfg_cls, twin in CACHE_MODEL_REGISTRY.items():
+        if isinstance(cfg, cfg_cls):
+            return twin
+    raise TypeError(f"{type(cfg).__name__} has no paged-cache twin: models/cache_zoo.CACHE_MODEL_REGISTRY "
+                    f"serves {', '.join(c.__name__ for c in CACHE_MODEL_REGISTRY)}")
 
 
 def cache_geometry(cfg, page_size: int):
     """The geometry of ``cfg``'s pages in an arena of ``page_size``-row pages."""
-    for cfg_cls, make in CACHE_GEOMETRY_REGISTRY.items():
-        if isinstance(cfg, cfg_cls):
-            return make(cfg, page_size)
-    return LinearGeometry(page_size)
+    return cache_twin(cfg).geometry(cfg, page_size)

@@ -19,9 +19,7 @@ argument to its result, a write is a scatter of the chunk's rows into it and
 the kernel reads a layer's pages where they lie.  (A scan that takes a
 layer's pages in and hands them out slices a layer out, stacks a second arena
 and copies it back: three moves of the whole arena a model step, a third of
-served Mixtral's busy time on the chip: PERF.md, PR 27.)  The unrolled trunk
-(``scan_layers=False``) takes a tuple of per-layer arenas, donated leaf by
-leaf, and a block there is handed its own layer's pages and no index.
+served Mixtral's busy time on the chip: PERF.md, PR 27.)
 
 Param-tree compatibility: module/submodule names mirror LlamaForCausalLM
 exactly (embed_tokens, model/layers/{self_attn/{q,k,v,o}_proj,
@@ -173,23 +171,25 @@ def paged_attention_core(q, k, v, pages, block_table, start_pos, chunk_lens, pag
     return out, pages
 
 
-def unstack_layer_params(variables, num_layers):
-    """Convert scan-stacked params (``model/layers/*`` leaves ``[L, ...]``)
-    to the unrolled layout (``model/layers_{i}/*``).
-
-    The training↔serving layout converter: checkpoints trained with
-    ``scan_layers=True`` (the training default) serve through the unrolled
-    decode trunk without re-export (r3 verdict: scan-only cache twins
-    blocked ``scan_layers=False`` serving).  No data movement — each
-    unrolled leaf is a view-slice of the stacked leaf."""
+def stack_layer_params(variables, num_layers):
+    """Convert unrolled params (``model/layers_{i}/*``, what a model trained
+    with ``scan_layers=False`` holds) to the scan-stacked layout the serving
+    twins take (``model/layers/*``, leaves ``[L, ...]``).  A tree that is
+    stacked already comes back as it is."""
     had_wrapper = isinstance(variables, dict) and "params" in variables
     p = dict(variables["params"]) if had_wrapper else dict(variables)
     m = dict(p.get("model", {}))
-    if "layers" not in m:
-        return variables  # already unrolled (or a foreign tree) — no-op
-    stacked = m.pop("layers")
-    for i in range(num_layers):
-        m[f"layers_{i}"] = jax.tree.map(lambda x, i=i: x[i], stacked)
+    if "layers_0" not in m:
+        return variables
+    boxed = lambda x: isinstance(x, nn.meta.AxisMetadata)
+
+    def stack(*xs):
+        if not boxed(xs[0]):
+            return jnp.stack(xs)
+        # what nn.scan does to a partitioned leaf: the layers' axis joins its names
+        return xs[0].replace_boxed(jnp.stack([x.value for x in xs])).add_axis(0, {nn.PARTITION_NAME: LAYERS})
+
+    m["layers"] = jax.tree.map(stack, *(m.pop(f"layers_{i}") for i in range(num_layers)), is_leaf=boxed)
     p["model"] = m
     return {"params": p} if had_wrapper else p
 
@@ -232,10 +232,9 @@ class LlamaAttentionCache(nn.Module):
 
 class LlamaBlockCache(nn.Module):
     """One block in the shape of a scan's body: ``(carry, layer, ...) ->
-    (carry, None)`` with ``carry = (x, pages)``.  A scanned trunk carries the
-    whole arena and scans over the layers' indices; the unrolled trunk hands a
-    block its own layer's pages and ``layer=None``.  Every softmax twin's
-    block has this form."""
+    (carry, None)`` with ``carry = (x, pages)``.  The trunk carries the whole
+    arena and scans over the layers' indices.  Every softmax twin's block has
+    this form."""
     cfg: LlamaConfig
     page_size: int = 16
 
@@ -289,22 +288,6 @@ class LlamaForCausalLMWithCache(nn.Module):
 
             @nn.compact
             def __call__(self, x, cache, positions, block_table, start_pos, chunk_lens):
-                if not self.cfg.scan_layers:
-                    # unrolled serving trunk (params layout model/layers_i/*,
-                    # see unstack_layer_params): straight-line code drops the
-                    # scan's while/dynamic-slice bookkeeping — measured ~22ms
-                    # of 123ms per 8 fused decode rounds at B32 (r4, against
-                    # the scan that stacked the arena and moved all of it
-                    # every step: true of that scan only, the one below
-                    # carries the arena and writes it in place).  The cache
-                    # arrives as a TUPLE of per-layer arenas (donated
-                    # leaf-wise), each block handed its own
-                    new_pages = []
-                    for i in range(self.cfg.num_hidden_layers):
-                        (x, pages_i), _ = LlamaBlockCache(self.cfg, self.page_size, name=f"layers_{i}")(
-                            (x, cache[i]), None, positions, block_table, start_pos, chunk_lens)
-                        new_pages.append(pages_i)
-                    return x, tuple(new_pages)
                 (x, cache), _ = scan_blocks(LlamaBlockCache, self.cfg.num_hidden_layers)(
                     self.cfg, self.page_size, name="layers")(
                         (x, cache), jnp.arange(self.cfg.num_hidden_layers), positions, block_table, start_pos,

@@ -36,7 +36,7 @@ update program), the overlap here is measured, not asserted:
 events every step, ``step(..., serialize=True)`` runs a fenced probe sweep
 attributing per-group upload/compute/download seconds, and
 ``overlap_report()`` combines them into the overlap fraction and the
-transfer-/compute-bound floor emitted to ``BENCH_SCALE.json``.
+transfer-/compute-bound floor.
 
 Interface-compatible with ``PipelinedNVMeOptimizer`` so the engine's
 ``_nvme_train_step`` orchestration (fwd/bwd program + grouped update loop)

@@ -15,8 +15,7 @@ plus two kinds of timing sweeps —
   write-backs ready), yielding the achieved wall time and per-group
   compute-completion timestamps.
 
-``report()`` combines the two into the artifact fields
-(``BENCH_SCALE.json`` host-streamed leg, docs/PERF.md):
+``report()`` combines the two into these fields:
 
   serialized_s     = Σ(upload + compute + download)      -- no-overlap cost
   transfer_s       = Σ(upload + download)

@@ -53,7 +53,7 @@ def _chunk_partials(q, k_chunk, v_chunk, q_pos, k_pos, scale, causal):
     The matmuls keep their STORAGE dtype operands with f32 accumulation —
     bf16 inputs run the MXU at full rate; the r4 version upcast q AND k to
     f32 first, running both einsums at ~1/8 MXU throughput, which is most
-    of why FPDT measured 3.95x slower than flash at 32k (BENCH_LONGCTX r4).
+    of why FPDT measured 3.95x slower than flash at 32k (r4, docs/PERF.md).
     The softmax bookkeeping (max/exp/log) stays f32."""
     nh, nkv = q.shape[2], k_chunk.shape[2]
     if nkv != nh:

@@ -842,9 +842,9 @@ class ServingEngine:
           retry here).
 
         Returns None when the request is in neither window (not active,
-        already paused, finished, or too early in prefill) or the engine's
-        cache layout is not exportable — the router just skips it."""
-        from .kvtransfer import KVExporter, KVImportError
+        already paused, finished, or too early in prefill) — the router
+        just skips it."""
+        from .kvtransfer import KVExporter
         req = self._active.get(uid)
         if req is None or req.state not in (RequestState.PREFILL,
                                             RequestState.DECODE):
@@ -862,13 +862,6 @@ class ServingEngine:
         try:
             exporter = KVExporter(self.engine, uid, chunk_pages=chunk_pages,
                                   source=source)
-        except KVImportError as e:
-            # structurally unexportable on THIS engine (e.g. the
-            # unroll_layers per-layer tuple cache layout): not a migratable
-            # request, not an error — the caller keeps serving it here
-            seq.paused = False
-            logger.debug(f"begin_migration({uid}): not exportable ({e})")
-            return None
         except Exception:
             seq.paused = False
             raise

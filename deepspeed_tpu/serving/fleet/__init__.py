@@ -12,8 +12,7 @@ migration — ``serving/kvtransfer``), a fleet-global
 :class:`PrefixDirectory` replicas publish their prefix-chain digests
 into, and a deterministic :class:`FleetSimulator` that
 replays arrivals plus a scripted fault schedule bit-reproducibly on CPU
-(``scripts/bench_router.py`` is the load harness; the seeded workload
-generators live in :mod:`.sim`).
+(the seeded workload generators live in :mod:`.sim`).
 """
 
 from .autoscale import (RUNGS, AutoscaleConfig, Autoscaler, OverloadConfig,

@@ -45,13 +45,13 @@ BROWNS OUT in explicit, auditable rungs rather than falling over —
 
 and steps back DOWN the same rungs symmetrically as pressure clears.
 Every move emits a ``fleet/overload_step_up``/``_step_down`` event and is
-recorded with per-rung occupancy time, so a bench can assert that every
+recorded with per-rung occupancy time, so a test can assert that every
 rung entered was also exited.
 
 Determinism: decisions are pure functions of clock time and fleet state,
 probed through the ``autoscaler.decide`` fault-injection site — the same
 flash crowd replays the same decision sequence byte-for-byte on every
-run and machine (the ``BENCH_ROUTER.json`` ``autoscale`` receipt).
+run and machine.
 """
 
 import dataclasses

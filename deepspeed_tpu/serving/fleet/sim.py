@@ -3,8 +3,7 @@
 Drives a :class:`~.router.Router` + :class:`~.pool.ReplicaPool` on the
 pool's ONE shared clock in discrete *rounds* that model the fleet's
 replicas stepping concurrently (``VirtualClock``: deterministic CPU
-simulation; ``WallClock``: the same loop with real time — bench wall mode
-reuses it rather than re-implementing the round structure):
+simulation; ``WallClock``: the same loop with real time):
 
   1. apply due schedule events (kill / recover / drain / restart);
   2. submit due arrivals, time out expired pending work, dispatch;
@@ -19,7 +18,7 @@ reuses it rather than re-implementing the round structure):
 Everything is seeded/ordered deterministically (sorted replica order,
 list-ordered arrivals and schedule, greedy decode), so the same inputs
 produce bit-identical outputs on every run and machine — the property the
-``bench_router.py --dryrun`` artifact and the chaos tests pin.
+determinism and chaos tests pin.
 
 Token timestamps within a round are stamped at round START (the shared
 clock advances only at step 4); latencies are therefore quantized to
@@ -261,7 +260,7 @@ def session_arrivals(seed: int, n_sessions: int, vocab: int,
                          "tool_tokens": [...]}, ...]}, ...]}
 
     ``rate``: Poisson session-start rate; None starts every session at
-    t=0 (the resident-capacity shape ``bench_serving --kv-tier`` uses).
+    t=0 (the resident-capacity shape).
     ``stall_prob``: per-turn probability of ONE mid-generation tool
     stall at a seeded token offset; ``stall_at`` instead fires a stall
     at each of the given FIXED offsets in every turn (the deterministic

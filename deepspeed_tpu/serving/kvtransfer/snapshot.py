@@ -142,10 +142,6 @@ class KVExporter:
         seq = engine.state.seqs[uid]
         kv = engine.kv
         arena = engine.cache
-        if not hasattr(arena, "shape") or len(arena.shape) != 6:
-            raise KVImportError(
-                "KV export supports the scanned single-arena layout only "
-                "(unroll_layers builds a per-layer tuple)")
         self.engine = engine
         self.uid = uid
         self.chunk_pages = int(chunk_pages)
@@ -197,12 +193,8 @@ class KVExporter:
 
 def _validate_arena(snapshot: "KVSnapshot", kv, arena) -> None:
     """The importability gate BOTH import paths (migration sequence,
-    prefix adoption) share: scanned single-arena layout, matching page
-    geometry and dtype.  One rule — a future layout change cannot diverge
-    the two paths."""
-    if not hasattr(arena, "shape") or len(arena.shape) != 6:
-        raise KVImportError("KV import supports the scanned single-arena "
-                            "layout only (unroll_layers builds a tuple)")
+    prefix adoption) share: matching page geometry and dtype.  One rule —
+    a future layout change cannot diverge the two paths."""
     if snapshot.page_size != kv.page_size:
         raise KVImportError(f"page_size mismatch: snapshot {snapshot.page_size} "
                             f"vs engine {kv.page_size}")

@@ -4,8 +4,7 @@ engine or a fleet.
 Two drivers share the Session bookkeeping and the tool-stall ladder:
 
 * :class:`SessionManager` — closed-loop over ONE
-  :class:`~..engine.ServingEngine` (the ``bench_serving.py --kv-tier``
-  workload driver and the unit-test harness).  Tool stalls park the
+  :class:`~..engine.ServingEngine` (the unit-test harness).  Tool stalls park the
   request through the engine's host KV tier (``serve.park(uid,
   phase="tool_stall")``), prefetch a lead interval before the seeded
   tool result lands, then resume — the r22 prefetch-hidden contract.

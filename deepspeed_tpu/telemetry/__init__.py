@@ -15,7 +15,7 @@ and fleet dispatch (the client trace_id survives replica failover).
 """
 
 from .export import (load_chrome_trace, spans_to_jsonl, to_chrome_trace,
-                     write_chrome_trace, write_jsonl)
+                     validate_chrome_trace, write_chrome_trace, write_jsonl)
 from .flight_recorder import FlightRecorder
 from .metrics import (Counter, Gauge, Histogram, HistogramWindow,
                       MetricsRegistry)
@@ -28,7 +28,7 @@ from .trace import (NULL_SPAN, NULL_TRACER, NullTracer, PerfClock, Span,
 
 __all__ = [
     "load_chrome_trace", "spans_to_jsonl", "to_chrome_trace",
-    "write_chrome_trace", "write_jsonl",
+    "validate_chrome_trace", "write_chrome_trace", "write_jsonl",
     "FlightRecorder",
     "Counter", "Gauge", "Histogram", "HistogramWindow", "MetricsRegistry",
     "BurnRateConfig", "SLOBurnMonitor",

@@ -21,7 +21,7 @@ windows).  Everything is driven by the caller's clock: under
 ``VirtualClock`` the alert timeline — ``slo/alert_fired/<tenant>`` /
 ``slo/alert_cleared/<tenant>`` events, the :attr:`alerts` audit log, and
 the flight-recorder ``ctrl/slo/<tenant>`` interval track — is
-bit-reproducible across runs (the ``BENCH_ROUTER_ATTRIB.json`` receipt).
+bit-reproducible across runs.
 """
 
 import dataclasses

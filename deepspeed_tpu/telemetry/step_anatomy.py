@@ -519,8 +519,8 @@ class StepAnatomy:
         }
 
     def to_doc(self) -> dict:
-        """The full deterministic export (what ``bench_serving.py
-        --anatomy`` commits and ``scripts/step_anatomy.py`` re-verifies):
+        """The full deterministic export (what ``scripts/step_anatomy.py``
+        verifies and folds):
         per-step table, compile log, per-program fold, summary.  Pure
         data, 9-dp rounding, sorted keys downstream.  Schema 3 = the
         program key as a step's identity, the counts, and the ``admit``

@@ -1,9 +1,7 @@
 """Persistent XLA compile cache, placed from outside.
 
 Every entry point that compiles on the chip (``chip_smoke.py`` children,
-``bench.py``, ``bench_inference.py``, the live modes of
-``scripts/bench_{scale,longctx,serving}.py``) calls :func:`enable` once,
-before its first compile.
+``benchmark/run.py``) calls :func:`enable` once, before its first compile.
 
 Where the cache lives is the operator's decision, not the program's:
 

@@ -2,10 +2,10 @@
 """dslint — the unified static-analysis pass (r11 tentpole).
 
 Runs every registered checker (determinism, crash-transparency,
-fault-sites, event-registry, atomic-write, bench-schema) in one AST walk
-per file and exits non-zero on any unsuppressed finding.  Deterministic:
-two identical runs produce byte-identical output (``--json`` asserted in
-tier-1, tests/unit/test_dslint.py).
+fault-sites, event-registry, atomic-write, kv-lifetime, state-machine) in
+one AST walk per file and exits non-zero on any unsuppressed finding.
+Deterministic: two identical runs produce byte-identical output (``--json``
+asserted in tier-1, tests/unit/test_dslint.py).
 
     python scripts/dslint.py deepspeed_tpu scripts            # the tier-1 run
     python scripts/dslint.py --json deepspeed_tpu scripts

@@ -1,10 +1,8 @@
 #!/usr/bin/env python
 """step_anatomy — verify and fold a per-step engine anatomy table.
 
-Input: a step-anatomy document — either the raw
-``StepAnatomy.to_doc()`` export (``{"schema": 3, "steps": [...],
-"compiles": [...]}``) or a committed ``BENCH_STEP_ANATOMY.json`` receipt
-(the same document nested under its ``"anatomy"`` key).
+Input: a step-anatomy document — the ``StepAnatomy.to_doc()`` export
+(``{"schema": 3, "steps": [...], "compiles": [...]}``).
 
 The report does two things, in this order:
 
@@ -52,25 +50,8 @@ COUNTS = ("rows_decode", "rows_prefill", "tokens_real", "slots", "tokens_out",
           "attn_rows_visible")
 
 
-def _anatomy_of(doc):
-    """Accept a raw recorder doc or a bench receipt wrapping one.  A
-    schema-v2 receipt carries TWO legs (serial / pipelined); the fold
-    reads the pipelined one — the headline the receipt's ``value`` quotes
-    (fold a specific leg by passing its ``anatomy`` sub-document)."""
-    if isinstance(doc, dict):
-        legs = doc.get("legs")
-        if isinstance(legs, dict):
-            leg = legs.get("pipelined") or legs.get("serial") or {}
-            if isinstance(leg.get("anatomy"), dict):
-                return leg["anatomy"]
-        if isinstance(doc.get("anatomy"), dict):
-            return doc["anatomy"]
-    return doc
-
-
-def fold(doc, tol=1e-6):
+def fold(anatomy, tol=1e-6):
     """Pure-function core (unit-tested; main() is the CLI shell)."""
-    anatomy = _anatomy_of(doc)
     steps = anatomy.get("steps")
     if not isinstance(steps, list):
         raise ValueError("not a step-anatomy document: no 'steps' table")
@@ -149,7 +130,7 @@ def fold(doc, tol=1e-6):
         mismatches.append({
             "error": f"summary declares {declared} steady-state "
                      f"recompile(s) but the compile log records "
-                     f"{len(steady)} — the receipt disagrees with itself"})
+                     f"{len(steady)} — the document disagrees with itself"})
 
     wall = tot["wall_s"]
     return {
@@ -190,8 +171,7 @@ def fold(doc, tol=1e-6):
 
 def main():
     ap = argparse.ArgumentParser(description=__doc__)
-    ap.add_argument("doc", help="StepAnatomy.to_doc() export or a "
-                                "BENCH_STEP_ANATOMY.json receipt")
+    ap.add_argument("doc", help="StepAnatomy.to_doc() export")
     ap.add_argument("--tol", type=float, default=1e-6,
                     help="max |wall - (gap + segments + device)| per step")
     ap.add_argument("--json", action="store_true", dest="as_json",

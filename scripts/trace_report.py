@@ -1,9 +1,8 @@
 #!/usr/bin/env python
 """Fold a telemetry Chrome trace into a critical-path breakdown.
 
-Input: a trace exported by ``deepspeed_tpu.telemetry.write_chrome_trace``
-(e.g. ``scripts/bench_router.py --dryrun --trace`` →
-``BENCH_ROUTER_TRACE.json``).  For every request trace (root span named
+Input: a trace exported by ``deepspeed_tpu.telemetry.write_chrome_trace``.
+For every request trace (root span named
 ``request``) the phase child spans — ``pending`` (router queue /
 failover re-dispatch wait), ``queued`` (replica admission queue, incl.
 preemption requeue and submit backoff), ``prefill``, ``decode``,
@@ -128,8 +127,8 @@ def fold(doc: dict, tol: float = 1e-6) -> dict:
         breakdown[p] = {
             "total_s": round(tp, 9),
             "fraction": round(tp / total, 6) if total else None,
-            # same method as the BENCH_*.json percentile fields
-            # (serving/metrics.py) — the two artifacts must agree
+            # same method as serving/metrics.py's percentile fields —
+            # the two must agree
             "per_request": percentile_summary(
                 [r["phases"].get(p, 0.0) for r in requests]),
         }

@@ -5,7 +5,7 @@
 ``why_slow`` answers the question an operator actually asks: **"why was
 THIS request slow — and what explains the p99?"**  It folds every request
 trace in a Chrome trace (``deepspeed_tpu.telemetry.write_chrome_trace``
-output — a ``--trace`` bench artifact or a flight-recorder dump) into a
+output — a tracer's export or a flight-recorder dump) into a
 named-cause breakdown of its end-to-end latency:
 
     queue_wait       router-queue (``phase/pending``) + replica admission
@@ -14,8 +14,8 @@ named-cause breakdown of its end-to-end latency:
     partition_delay  pending/queued time overlapping a declared
                      degradation window (a control-plane partition, a
                      flash crowd) — the trace's ``otherData`` carries
-                     ``degradation_t0``/``degradation_t1`` (benches stamp
-                     it; ``--window t0:t1`` overrides)
+                     ``degradation_t0``/``degradation_t1`` (the exporter's
+                     ``meta``; ``--window t0:t1`` overrides)
     prefill          prompt processing (incl. recompute-on-resume)
     decode           token generation
     migration_pause  paused for chunked KV export (``phase/migrating``)
@@ -43,12 +43,11 @@ though the cap costs tokens, not seconds.
 The tail receipt: ``ttft_gap`` compares the p99 TTFT request against the
 p50 one (nearest-rank over DONE requests, TTFT-clipped causes) and
 reports what fraction of the p99−p50 gap the SLOWDOWN causes (everything
-except baseline prefill/decode compute) explain — the
-``BENCH_ROUTER_ATTRIB.json`` acceptance bar is >= 0.8.
+except baseline prefill/decode compute) explain.
 
 Output is one deterministic JSON document (sorted keys, no timestamps):
 ``--json`` prints compact bytes that are identical across repeat runs on
-the same trace — itself pinned by the bench artifact.
+the same trace.
 
 Deliberately stdlib-only (no package import): the CLI starts in
 milliseconds and runs anywhere the trace file does.

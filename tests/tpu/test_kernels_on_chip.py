@@ -62,7 +62,7 @@ def test_flash_fwd_bwd_bf16_vs_golden():
 
 
 def _model_step_time(attention_impl, remat_policy, steps=10):
-    """Bench-shaped training step time (6 of bench.py's 12 layers to halve
+    """Bench-shaped training step time (6 of the 125M preset's 12 layers to halve
     compile time; the attention cost per layer is identical).  Isolated
     single-op timings proved unreliable in BOTH directions (scan/pallas
     interaction, XLA DCE of untaken grads), so the floor is asserted on the

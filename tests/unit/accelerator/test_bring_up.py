@@ -39,17 +39,6 @@ def test_compile_cache_defaults_to_checkout(monkeypatch, config_updates):
     assert [v for k, v in config_updates.items() if k.endswith("cache_dir")] == [want]
 
 
-def test_peak_table_raises_on_unknown_device_kind(monkeypatch):
-    import bench
-
-    class Unknown:
-        platform, device_kind = "tpu", "TPU v99"
-
-    monkeypatch.setattr(bench.jax, "devices", lambda: [Unknown()])
-    with pytest.raises(RuntimeError, match="TPU v99"):
-        bench.peak_flops_per_chip()
-
-
 def test_get_accelerator_reraises_device_errors(monkeypatch):
     from deepspeed_tpu.accelerator import real_accelerator
 

@@ -1,6 +1,8 @@
 """FastGen-v2 engine tests (ref: tests/unit/inference/v2 — ragged batching,
 scheduler, engine generate correctness vs the cache-free reference path)."""
 
+import dataclasses
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -26,17 +28,17 @@ def trained_params():
     return model.init(jax.random.PRNGKey(0), ids)
 
 
-def _engine(trained_params, **overrides):
+def _engine(trained_params, cfg=CFG, **overrides):
     kv = PagedKVConfig(num_pages=64, page_size=8, max_pages_per_seq=8)
     sched = SchedulerConfig(token_budget=64, max_seqs=8, prefill_chunk=8, decode_bucket=4)
     eng_cfg = RaggedInferenceEngineConfig(kv=kv, scheduler=sched, kv_dtype=jnp.float32,
                                           **overrides)
-    return build_engine(CFG, trained_params, eng_cfg)
+    return build_engine(cfg, trained_params, eng_cfg)
 
 
-def _reference_greedy(params, prompt, n_new):
+def _reference_greedy(params, prompt, n_new, model=None):
     """Cache-free greedy decode via the training model (golden)."""
-    model = LlamaForCausalLM(CFG)
+    model = model or LlamaForCausalLM(CFG)
     ids = jnp.asarray([prompt], jnp.int32)
     for _ in range(n_new):
         logits = model.apply(params, ids)
@@ -54,13 +56,10 @@ def test_generate_matches_cachefree_reference(trained_params):
         assert got == expected, (got, expected)
 
 
-def test_unrolled_trunk_and_overshoot_match_reference(trained_params):
-    """r4 serving path: unrolled layer trunk (scan-stacked checkpoint
-    converted via unstack_layer_params) + fused-decode OVERSHOOT (k rung
-    larger than tokens remaining; surplus discarded host-side) must produce
-    exactly the reference greedy tokens."""
-    eng = _engine(trained_params, unroll_layers=True, decode_steps_per_dispatch=4)
-    assert not eng.cfg.scan_layers and isinstance(eng.cache, tuple)
+def test_fused_decode_overshoot_matches_reference(trained_params):
+    """Fused-decode OVERSHOOT (k rung larger than tokens remaining; surplus
+    discarded host-side) must produce exactly the reference greedy tokens."""
+    eng = _engine(trained_params, decode_steps_per_dispatch=4)
     prompts = [[5, 9, 2, 7, 1], [3, 3, 8]]
     # prefill emits token 1; the remaining 5 take a k=4 rung plus a second
     # rung that OVERSHOOTS by 3 — those surplus tokens must be discarded
@@ -69,6 +68,44 @@ def test_unrolled_trunk_and_overshoot_match_reference(trained_params):
     for prompt, got in zip(prompts, outs):
         expected = _reference_greedy(trained_params, prompt, 6)
         assert got == expected, (got, expected)
+
+
+def test_unscanned_checkpoint_served_through_the_scanned_twin():
+    """A tree trained with ``scan_layers=False`` (``model/layers_<i>``) is
+    stacked once at engine init: one arena, the reference's tokens."""
+    cfg = dataclasses.replace(CFG, scan_layers=False)
+    model = LlamaForCausalLM(cfg)
+    params = model.init(jax.random.PRNGKey(1), jnp.zeros((1, 8), jnp.int32))
+    assert "layers_0" in params["params"]["model"]
+    eng = _engine(params, cfg)
+    assert eng.cache.ndim == 6
+    prompt = [5, 9, 2, 7, 1]
+    assert eng.generate([prompt], max_new_tokens=6) == [_reference_greedy(params, prompt, 6, model)]
+
+
+def test_mixed_dense_sparse_stack_served_in_the_one_arena():
+    """The mixed Qwen2-MoE stack (layers differ in shape, so its twin loops
+    over ``layers_<i>``) names its layer in the whole arena like every other
+    twin: served by the engine it gives the training model's greedy tokens."""
+    from deepspeed_tpu.inference.v2.engine_v2 import build_cache_model
+    from deepspeed_tpu.models.llama_cache import init_kv_cache
+    from deepspeed_tpu.models.qwen2_moe import Qwen2MoeConfig, Qwen2MoeForCausalLM
+    cfg = Qwen2MoeConfig(vocab_size=128, hidden_size=64, intermediate_size=128, moe_intermediate_size=32,
+                         shared_expert_intermediate_size=64, num_hidden_layers=2, num_attention_heads=4,
+                         num_key_value_heads=2, num_experts=4, num_experts_per_tok=2, mlp_only_layers=(0, ),
+                         max_position_embeddings=128, rope_theta=1e4, dtype=jnp.float32, scan_layers=False,
+                         remat=False)
+    assert cfg.mixed_stack
+    kv = PagedKVConfig(num_pages=32, page_size=8, max_pages_per_seq=4)
+    one = jnp.zeros((1, ), jnp.int32)
+    params = build_cache_model(cfg, kv.page_size).init(
+        jax.random.PRNGKey(2), jnp.zeros((1, 1), jnp.int32), one, jnp.zeros((1, kv.max_pages_per_seq), jnp.int32),
+        init_kv_cache(cfg, kv, dtype=jnp.float32), one)
+    sched = SchedulerConfig(token_budget=32, max_seqs=4, prefill_chunk=8, decode_bucket=4)
+    eng = InferenceEngineV2(cfg, params, RaggedInferenceEngineConfig(kv=kv, scheduler=sched, kv_dtype=jnp.float32))
+    prompt = [5, 9, 2, 7, 1]
+    assert eng.generate([prompt], max_new_tokens=5) == [_reference_greedy(params, prompt, 5, Qwen2MoeForCausalLM(cfg))]
+    assert eng.cache.ndim == 6
 
 
 def test_long_prompt_splitfuse_chunking(trained_params):

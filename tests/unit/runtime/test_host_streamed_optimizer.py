@@ -1,5 +1,5 @@
 """Host-streamed grouped optimizer (r5 — the tier that broke the 792M
-single-chip ceiling: 1.62B trained on a 16 GB v5e, BENCH_SCALE.json).
+single-chip ceiling: 1.62B trained on a 16 GB v5e, docs/PERF.md r5).
 
 ref: deepspeed/runtime/zero/stage_1_and_2.py CPU offload + cpu_adam —
 fp32 master/moments out of device memory, touched in bounded pieces.

@@ -7,7 +7,7 @@ staged buffers are donated exactly once into the fused-Adam program, the
 engine issues the first uploads during the BACKWARD, and a serialized
 probe sweep attributes per-group upload/compute/download seconds that
 ``overlap_report`` folds into an overlap fraction with a transfer-/
-compute-bound floor (the ``BENCH_SCALE.json`` artifact fields).
+compute-bound floor.
 
 Everything here runs on the CPU backend: the dispatch structure, donation
 discipline, event ordering and instrumentation math are identical — only

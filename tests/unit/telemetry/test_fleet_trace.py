@@ -2,8 +2,8 @@
 byte-identical Chrome traces; the client trace_id survives replica
 failover (the resumed attempt links to the dead replica's span); the
 trace_report critical-path fold verifies span sums against the TTFT/TPOT
-accounting; and the bench-schema trace validator accepts the real
-artifact while catching the drift classes it exists for."""
+accounting; and the exporter's trace validator accepts a real trace
+while catching the drift classes it exists for."""
 
 import importlib.util
 import json
@@ -20,7 +20,8 @@ from deepspeed_tpu.models.llama_cache import PagedKVConfig
 from deepspeed_tpu.serving import VirtualClock
 from deepspeed_tpu.serving.fleet import (FleetSimulator, FleetState, ReplicaPool,
                                          Router, RoundRobinPolicy)
-from deepspeed_tpu.telemetry import Tracer, to_chrome_trace, write_chrome_trace
+from deepspeed_tpu.telemetry import (Tracer, to_chrome_trace, validate_chrome_trace,
+                                     write_chrome_trace)
 
 REPO_ROOT = os.path.abspath(os.path.join(os.path.dirname(__file__), "..", "..", ".."))
 
@@ -307,16 +308,15 @@ def test_trace_report_flags_unaccounted_time(trained_params):
 # ---------------------------------------------------------- schema checker
 
 
-def test_schema_validator_accepts_real_trace_and_catches_drift(trained_params, tmp_path):
-    checker = _script("check_bench_schema.py")
+def test_schema_validator_accepts_real_trace_and_catches_drift(trained_params):
     _, tracer, _ = _run_fleet(trained_params, schedule=[(2.0, "kill", 1)])
     doc = to_chrome_trace(tracer.spans, dropped_spans=tracer.dropped_spans)
-    assert checker._validate_trace(doc) is None
+    assert validate_chrome_trace(doc) is None
 
     def broken(mutate):
         d = json.loads(json.dumps(doc))
         mutate(d)
-        return checker._validate_trace(d)
+        return validate_chrome_trace(d)
 
     # span whose parent does not exist
     def orphan(d):
@@ -347,12 +347,4 @@ def test_schema_validator_accepts_real_trace_and_catches_drift(trained_params, t
     assert "bad dur" in broken(neg_dur)
 
     # not a trace at all
-    assert checker._validate_trace({"hello": 1}) is not None
-
-    # end-to-end: validate_all picks the trace schema up by filename
-    p = tmp_path / "BENCH_ROUTER_TRACE.json"
-    p.write_text(json.dumps(doc))
-    assert not checker.validate_all(str(tmp_path))
-    p.write_text(json.dumps({"traceEvents": "nope"}))
-    errs = checker.validate_all(str(tmp_path))
-    assert errs and "traceEvents" in errs[0]
+    assert validate_chrome_trace({"hello": 1}) is not None

@@ -449,7 +449,7 @@ def test_recorder_without_tracer_still_records_replica_fence(trained_params):
     assert router.summary()["control_plane"]["lease_expirations"] == 1
     fences = recorder.track("ctrl/replica0")
     assert [s.name for s in fences] == ["ctrl/fence"], recorder.summary()
-    assert sorted(fences[0].attrs) == ["active", "queued"]
+    assert sorted(fences[0].attrs) == ["active", "parked", "queued"]
     # ...and a replacement engine (the recover()/restart() path) inherits
     # the attachment like it inherits the tracer
     pool._attach_engine(0)

@@ -1,6 +1,6 @@
 """Tier-1 guard: durability-sensitive writers go through the atomic-write
-helper (r7 tentpole; same wiring pattern as test_bench_schema.py).  A bare
-``open(path, "w")`` on a checkpoint or benchmark-artifact path tears under
+helper (r7 tentpole).  A bare
+``open(path, "w")`` on a checkpoint or committed-artifact path tears under
 a crash — scripts/check_atomic_writes.py forbids it outside
 resilience/atomic_io.py, and this test runs the checker over the repo plus
 proves the checker still catches the violation classes it exists for."""

@@ -3,7 +3,7 @@ each checker demonstrably catches its violation class (fixture pairs under
 tests/unit/analysis/fixtures/), suppressions demand a reason, and the JSON
 output is byte-identical across runs.
 
-Same pattern as test_bench_schema.py / the old test_atomic_writes.py: the
+Same pattern as the old test_atomic_writes.py: the
 CLI module is loaded by path, so this also covers the standalone import
 trick (dslint never imports jax — that is what keeps the full-repo run
 inside its 5 s budget)."""
@@ -44,7 +44,7 @@ def _by_checker(findings, name):
 def test_repo_is_lint_clean():
     runner = _run(["deepspeed_tpu", "scripts"], root=REPO_ROOT)
     assert not runner.findings, "\n".join(f.human() for f in runner.findings)
-    # the five AST checkers plus bench-schema really ran
+    # the checkers really ran
     assert runner.files, "nothing scanned?"
     assert runner.suppressed_count > 0, \
         "the repo carries documented suppressions; zero honored means the " \
@@ -197,15 +197,6 @@ def test_atomic_write_checker_fixtures():
     assert any("open" in x.message for x in bad)
     assert any("savez" in x.message for x in bad)
     assert len(bad) == 2
-
-
-def test_bench_schema_checker_fixtures():
-    bad = _by_checker(_findings("bench_bad", checkers=["bench-schema"]),
-                      "bench-schema")
-    assert bad, "malformed BENCH_r99.json not caught"
-    clean = _by_checker(_findings("bench_clean", checkers=["bench-schema"]),
-                        "bench-schema")
-    assert clean == [], [x.human() for x in clean]
 
 
 def test_kv_lifetime_checker_fixtures():
