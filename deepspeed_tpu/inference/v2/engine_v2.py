@@ -27,7 +27,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from ...models.llama_cache import PagedKVConfig, init_kv_cache, stack_layer_params
+from ...models.llama_cache import PagedKVConfig, init_kv_cache, reads_through_kernel, stack_layer_params
+from ...ops.paged_attention import walk_block
 from ...telemetry.step_anatomy import NULL_ANATOMY
 from ...utils.logging import logger
 from .ragged import BlockedKVCache, RaggedBatch, StateManager
@@ -853,7 +854,7 @@ class InferenceEngineV2:
             anat.note_program(self._key_label(("multi", batch, k)), "multi_decode",
                               rows_decode=len(seqs), tokens_real=len(seqs) * k,
                               slots=batch * k, expert_rows=len(seqs) * k * self._experts_per_tok,
-                              cache_counts=self._cache_counts([(s, k) for s in seqs]))
+                              cache_counts=self._cache_counts([(s, k) for s in seqs], calls=k))
         toks, self.cache = self._invoke(fn, self.params, self.cache, jnp.asarray(rb.tokens[:, 0]),
                                         jnp.asarray(rb.start_pos), jnp.asarray(rb.block_tables),
                                         jnp.asarray(rb.chunk_lens), sub)
@@ -901,9 +902,27 @@ class InferenceEngineV2:
             anat.mark("sample_accept")
         return out
 
-    def _cache_counts(self, work) -> tuple:
-        """The geometry's ``step_counts`` summed over a step's (seq, tokens) rows."""
-        counts = [self.kv.geometry.step_counts(s.seen_tokens, n) for s, n in work]
+    def _walk_rows(self) -> int:
+        """Key rows a block of the paged kernel's walk holds, as the kernel
+        chooses it for this engine's pages (a tensor-parallel shard's key
+        heads); 0 where the twin's attention does not read through it."""
+        cfg = self.cfg
+        if not reads_through_kernel(getattr(cfg, "attention_impl", None), getattr(cfg, "sliding_window", 0),
+                                    getattr(cfg, "alibi", False)):
+            return 0
+        from ...comm.mesh import TENSOR_AXIS
+        *_, n_kv, d = self.cache.shape
+        tp = 1 if self.mesh is None else self.mesh.shape.get(TENSOR_AXIS, 1)
+        return self.kv.page_size * walk_block(self.kv.page_size, self.kv.table_width, n_kv // tp, d,
+                                              self.cache.dtype.itemsize)
+
+    def _cache_counts(self, work, calls: int = 1) -> tuple:
+        """The geometry's ``step_counts`` summed over a step's (seq, tokens)
+        rows, each row's tokens going through the paged kernel in ``calls``
+        calls, by blocks of the rows its walk takes at a step (``walked`` is
+        0 where no kernel walks)."""
+        block_rows = self._walk_rows()
+        counts = [self.kv.geometry.step_counts(s.seen_tokens, n, block_rows, calls) for s, n in work]
         return tuple(sum(c) for c in zip(*counts))
 
     def _bucket_batch(self, n: int) -> int:

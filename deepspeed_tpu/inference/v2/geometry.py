@@ -20,6 +20,18 @@ Stdlib + numpy only: the admission controller and the scheduler import it.
 import numpy as np
 
 
+def _rows_walked(seen, block_rows, calls):
+    """Key rows the paged kernel's walk covers for consecutive tokens that
+    see ``seen[i] + 1`` rows each and go through it in ``calls`` calls of
+    equal length: every token of a call walks whole blocks up to the call's
+    last token's last row.  ``block_rows`` 0: no kernel walks."""
+    if not seen.size or not block_rows:
+        return 0
+    a_call = -(-seen.size // calls)
+    last = np.minimum((np.arange(seen.size) // a_call + 1) * a_call, seen.size) - 1     # its call's last token
+    return int((-(-(seen[last] + 1) // block_rows) * block_rows).sum())
+
+
 class LinearGeometry:
     """Page ``i`` of a sequence holds the keys and values of tokens
     ``page_size * i .. page_size * i + page_size - 1`` for ever: what every
@@ -50,12 +62,17 @@ class LinearGeometry:
         """How many of ``n_tokens`` one chunk starting at ``start`` may carry."""
         return n_tokens
 
-    def step_counts(self, start: int, n_tokens: int) -> tuple:
-        """What feeding tokens ``start .. start + n_tokens - 1`` does to a
-        cache of two kinds of page, for the step records:
-        (``summary_rows_written``, ``ring_wraps``, ``attn_rows_visible``).
-        Nothing here has summaries or a ring."""
-        return 0, 0, 0
+    def step_counts(self, start: int, n_tokens: int, block_rows: int = 0, calls: int = 1) -> tuple:
+        """What feeding tokens ``start .. start + n_tokens - 1`` does to the
+        cache, for the step records: (``summary_rows_written``,
+        ``ring_wraps``, ``attn_rows_visible``, ``attn_rows_walked``).  Nothing
+        here has summaries or a ring; token ``t`` sees rows ``0 .. t``.  The
+        tokens go through the paged kernel in ``calls`` calls of equal length
+        (one chunk, or the fused rung's one token a step), and a call walks
+        whole blocks of ``block_rows`` key rows up to its last token's last
+        visible row, for every one of its tokens."""
+        t = np.arange(start, start + n_tokens)
+        return 0, 0, int((t + 1).sum()), _rows_walked(t, block_rows, calls)
 
 
 class RingSummaryGeometry:
@@ -110,10 +127,13 @@ class RingSummaryGeometry:
         start position a row."""
         return min(n_tokens, self.window - start % self.window)
 
-    def step_counts(self, start: int, n_tokens: int) -> tuple:
+    def step_counts(self, start: int, n_tokens: int, block_rows: int = 0, calls: int = 1) -> tuple:
         """(chunks that complete, tokens that start a window after the first,
-        ring rows plus summary rows the queries can see, summed over them)."""
+        ring rows plus summary rows the queries can see, summed over them,
+        and the rows the kernel's walk covers for them: as the linear
+        geometry's, over the rows of the kernel's view, summaries first)."""
         t = np.arange(start, start + n_tokens)
-        visible = t % self.window + 1 + t // self.window * (self.window // self.page_size)
+        seen = t % self.window + t // self.window * (self.window // self.page_size)   # rows below the query's own
         return ((start + n_tokens) // self.page_size - start // self.page_size,
-                int(np.count_nonzero((t % self.window == 0) & (t > 0))), int(visible.sum()))
+                int(np.count_nonzero((t % self.window == 0) & (t > 0))), int((seen + 1).sum()),
+                _rows_walked(seen, block_rows, calls))

@@ -42,7 +42,7 @@ from flax import linen as nn
 
 from .evabyte import EvaByteConfig, EvaByteHead, EvaProjections, eva_embed, eva_norm, summarise_chunks
 from .llama import LlamaMLP
-from .llama_cache import _write_pages, paged_attention, scan_blocks
+from .llama_cache import _write_pages, paged_attention, reads_through_kernel, scan_blocks
 
 
 def _summarise_completed(arena, layer, block_table, start_pos, chunk_lens, width, phi, mu, page_size, ring):
@@ -96,7 +96,7 @@ class EvaByteBlockCache(nn.Module):
                                          attn.adaptive_phi, attn.adaptive_mu_k, page, ring)
         view, vstart = _kernel_view(block_table, start_pos, page, ring, cfg.window_size,
                                     -(-cfg.max_position_embeddings // cfg.window_size))
-        if cfg.attention_impl == "flash":
+        if reads_through_kernel(cfg.attention_impl):
             from ..ops.paged_attention import paged_attention_pallas
             o = paged_attention_pallas(q, arena, view, vstart, chunk_lens, page, layer=layer)
         else:

@@ -150,6 +150,13 @@ def paged_attention(q, pages, block_table, start_pos, chunk_lens, page_size, sli
     return out
 
 
+def reads_through_kernel(attention_impl, sliding_window=0, alibi=False) -> bool:
+    """Whether a twin's attention reads its pages through ``ds_paged_attention``
+    (window masks and alibi bias go through the jnp form: in-kernel variants
+    land with the kernel).  The engine asks too, for its step records."""
+    return attention_impl == "flash" and not sliding_window and not alibi
+
+
 def paged_attention_core(q, k, v, pages, block_table, start_pos, chunk_lens, page_size,
                          attention_impl="reference", sliding_window=0, alibi_slopes=None, layer=None):
     """Shared paged-KV attention core for every model family's cache twin:
@@ -159,13 +166,11 @@ def paged_attention_core(q, k, v, pages, block_table, start_pos, chunk_lens, pag
     the whole arena (``_write_pages``).  Returns (out [B, C, H, D], new_pages)."""
     pages = _write_pages(pages, k.astype(pages.dtype), v.astype(pages.dtype), block_table,
                          start_pos, page_size, chunk_lens, layer=layer)
-    if attention_impl == "flash" and not sliding_window and alibi_slopes is None:
+    if reads_through_kernel(attention_impl, sliding_window, alibi_slopes is not None):
         from ..ops.paged_attention import paged_attention_pallas
         out = paged_attention_pallas(q, pages, block_table, start_pos, chunk_lens, page_size, layer=layer)
     else:
-        # window masks / alibi bias decode through the jnp path (in-kernel
-        # variants land with the kernel); out of the whole arena it reads a
-        # layer's slice, 1/L of an arena
+        # out of the whole arena the jnp form reads a layer's slice, 1/L of an arena
         out = paged_attention(q, pages if layer is None else pages[layer], block_table, start_pos, chunk_lens,
                               page_size, sliding_window=sliding_window, alibi_slopes=alibi_slopes)
     return out, pages

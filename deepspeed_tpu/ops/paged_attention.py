@@ -1,29 +1,52 @@
-"""Pallas TPU paged (blocked-KV) decode attention.
+"""Pallas TPU paged (blocked-KV) attention.
 
 TPU-native equivalent of the reference FastGen's blocked-flash/linear-KV
 attention kernels (ref: deepspeed/inference/v2/kernels/ragged_ops —
 ``blocked_flash``, ``linear_blocked_kv_rotary``; KV geometry from
-``inference/v2/ragged/kv_cache.py``).  The kernel attends a (small) chunk of
-queries per sequence against that sequence's paged KV history, gathering
-pages from the shared arena through the block table.
+``inference/v2/ragged/kv_cache.py``).  The kernel attends a chunk of queries
+per sequence against that sequence's paged KV history, gathering pages from
+the shared arena through the block table.
 
-Implementation notes:
-  * the block table and start positions ride in scalar-prefetch SMEM
-    (``PrefetchScalarGridSpec``) so each grid step's page DMA address is
-    computed from ``block_table[b, j]`` — the Pallas analog of the
-    reference's atom-builder indirection (ragged/csrc/fast_host_buffer.cpp).
-  * grid = (batch, pages); the page dimension is "arbitrary" (sequential)
-    and carries the online-softmax state in VMEM scratch.  Each grid step
-    DMAs one WHOLE page — [page, 2, n_kv, D], whose trailing block dims are
-    the full array dims and therefore always tile-legal — and loops the kv
-    heads in-kernel with per-head scratch.  (A per-head grid with a
-    [page, 1, 1, D] block is rejected by the TPU tiling rules: the
-    second-minor block dim 1 is neither 8-aligned nor the full n_kv dim.)
-  * GQA: queries are laid out group-major ([B, n_kv, rep·C, D]) so each
-    head iteration contracts its whole query group against the page.
-  * pages whose first key is beyond the chunk's last visible position are
-    skipped (`pl.when`), so decode cost scales with the sequence's true
-    length, not max_pages — SplitFuse's "decode is O(context)" property.
+The kernel's work follows what the step carries, not what the table could
+hold:
+  * a row walks its own history in *blocks* of ``walk_block`` pages, up to
+    the row's last visible key, ``start + chunk_len - 1``: a row with no
+    token does nothing and writes zeros, and a decode row riding in a chunk
+    program walks its own context once.  The block table, start positions,
+    chunk lengths and the layer's index ride in scalar-prefetch SMEM.  The
+    arena's layout is untouched and a page, ``[page, 2, n_kv, D]``, is still
+    one DMA.  How a block's pages reach VMEM follows from the page's shape
+    (``_copies_pages``), because the chip's compiler allows a kernel's own
+    DMA only out of whole tiles:
+      - a page of whole tiles with heads of 128 lanes (every cell's): the
+        arena stays in HBM (``pl.ANY``), grid = (batch, 1), and the kernel
+        copies a block's pages (512 key rows where the table and a VMEM
+        budget allow, 128 at least) into a double-buffered scratch; the walk
+        is a ``fori_loop`` that ends with the row, so the table's width costs
+        nothing.  One head's keys (or values) are every ``2 * n_kv``-th row
+        of the block's ``[keys * 2 * n_kv, D]`` view: a strided load, on
+        32-bit words for bfloat16 (two heads share a sublane; a bfloat16 is
+        the upper half of a float32), and the heads are a ``fori_loop``.
+        Where a key's rows would put every sublane of such a load into one
+        VMEM bank (a stride of an even number of 8-row tiles: 16, 32, 64 key
+        heads) the scratch pads the heads to an odd number of tiles; the DMA
+        writes the real ones.
+      - a page the tiling pads (heads narrower than 128 lanes; 1, 3, 6, 12
+        ... key heads) or heads wider than 128 lanes: the pipeline brings the
+        block, one ``BlockSpec`` a page (a whole page is tile-legal in every
+        layout), grid = (batch, blocks of 128 key rows the table holds); a
+        step past the row's last block is skipped and its pages, clamped to
+        the row's last one, are not fetched again.  A head's rows are a plain
+        load a page, its index static: the heads are unrolled.
+    Either way a block's tail past the row's last page repeats that page and
+    is left to the position mask.
+  * queries are laid out position-major ([B, n_kv, C*rep, D], row =
+    c * rep + r), so the rows that carry a token are the first
+    ``chunk_len * rep``; they are taken in tiles and a tile wholly past them
+    is neither multiplied nor read: its output is zero.
+  * per head, tile and block one ``[tile, D] x [D, keys]`` and one
+    ``[tile, keys] x [keys, D]``: bfloat16 operands into the MXU, float32
+    scores, online-softmax state and accumulator in VMEM scratch.
 """
 
 import functools
@@ -36,51 +59,203 @@ from jax.experimental.pallas import tpu as pltpu
 
 DEFAULT_MASK_VALUE = -0.7 * float(jnp.finfo(jnp.float32).max)
 
+#: key rows one step of the walk takes where the kernel copies the pages
+#: itself, the table and the budget allowing (on the chip a step's fixed
+#: costs, the softmax state read and written and the MXU filled and drained,
+#: are half the time at 128 and an eighth at 512)
+_BLOCK_KEYS = 512
+#: ... and at least, and where the pipeline brings them: the MXU's width
+_MIN_BLOCK_KEYS = 128
+#: VMEM the two slots of the block's scratch may take (EvaByte's 32 heads,
+#: padded to 40, in blocks of 512 rows: all of it)
+_BLOCK_BYTES = 20 << 20
+#: query rows a tile holds at most
+_TILE_ROWS = 128
 
-def _paged_kernel(bt_ref, sp_ref, q_ref, pg_ref, o_ref, *scr, page_size, max_pages, chunk,
-                  scale, n_kv):
-    b = pl.program_id(0)
-    j = pl.program_id(1)
-    ms, ls, accs = scr[:n_kv], scr[n_kv:2 * n_kv], scr[2 * n_kv:]
 
-    @pl.when(j == 0)
+def _copies_pages(n_kv: int, d: int, itemsize: int) -> bool:
+    """Whether the kernel copies a block's pages out of the arena itself.  The
+    chip's compiler allows a DMA only out of whole tiles, and tiles a page's
+    ``[n_kv, D]`` rows by 128 lanes and by 8 sublanes, or by the power of two
+    that holds fewer heads, or by one 32-bit word's worth of them (2 for
+    bfloat16) at least; and a strided load takes rows of 128 lanes only."""
+    tile_rows = min(8, max(4 // itemsize, 1 << (n_kv - 1).bit_length()))
+    return d == 128 and n_kv % tile_rows == 0
+
+
+def _padded_heads(n_kv: int, itemsize: int) -> int:
+    """Heads the scratch lays a key's rows out for.  A head's rows are taken
+    with a sublane stride of one key's rows, ``2 * n_kv * itemsize / 4``
+    32-bit sublanes; where that is an even number of 8-sublane tiles all
+    eight sublanes of a load fall into one bank of VMEM (measured on a v5e:
+    six cycles a load at a stride of 4 tiles, one at 1, 3 or 5).  One tile
+    more makes it odd."""
+    sublanes = 2 * n_kv * itemsize // 4
+    return n_kv + (16 // itemsize if sublanes % 16 == 0 else 0)
+
+
+def walk_block(page_size: int, table_width: int, n_kv: int, d: int, itemsize: int) -> int:
+    """Pages the kernel's walk takes at one step (a block), for a table and a
+    page of these shapes: 512 key rows where it copies the pages itself,
+    fewer where the two slots of its scratch would pass their budget (never
+    fewer than 128 key rows); 128 key rows where the pipeline brings them;
+    never more than the table holds."""
+    pages = -(-_MIN_BLOCK_KEYS // page_size)
+    if _copies_pages(n_kv, d, itemsize):
+        page_bytes = page_size * 2 * _padded_heads(n_kv, itemsize) * d * itemsize
+        pages = max(min(-(-_BLOCK_KEYS // page_size), _BLOCK_BYTES // (2 * page_bytes)), pages)
+    return max(1, min(pages, table_width))
+
+
+def _head_rows(block, kv, h):
+    """One head's keys (``kv`` 0) or values (1) out of a block, as
+    ``[pages * page, D]``.  Out of the scratch the kernel copied its pages
+    into, ``[pages, page, 2, heads (padded), 128]``: every ``2 * heads``-th
+    row of the block's ``[keys * 2 * heads, 128]`` view.  Out of the pages
+    the pipeline brought, a list of ``[page, 2, heads, D]``: a load a page."""
+    if isinstance(block, list):
+        return jnp.concatenate([page[:, kv, h, :] for page in block], axis=0)
+    ppb, page, _, n_pad, d = block.shape
+    n_keys, e = ppb * page, kv * n_pad + h
+    rows_ref = block.reshape(n_keys * 2 * n_pad, d)
+    if rows_ref.dtype.itemsize == 4:
+        return rows_ref[pl.ds(e, n_keys, stride=2 * n_pad), :]
+    if rows_ref.dtype != jnp.bfloat16:
+        raise NotImplementedError(f"paged kernel over a {rows_ref.dtype} arena")
+    # two bfloat16 rows share a 32-bit sublane: load the pairs, keep the
+    # half; a bfloat16 is the upper half of the float32 of the same value
+    words = rows_ref.bitcast(jnp.uint32)[pl.ds(e // 2, n_keys, stride=n_pad), :]
+    half = (words >> jnp.asarray(16 * (e % 2), jnp.uint32)) << 16
+    return pltpu.bitcast(half, jnp.float32).astype(jnp.bfloat16)
+
+
+def _last_page(start, n_tok, page_size, width):
+    """The table column of a row's last visible key (0 for a row with no
+    token, whose walk is empty)."""
+    return jnp.clip((start + n_tok - 1) // page_size, 0, width - 1)
+
+
+def _paged_kernel(bt_ref, sp_ref, cl_ref, ly_ref, q_ref, *refs, page_size, ppb, copies, rep, tile, scale):
+    """``refs``: where the kernel ``copies`` its pages, the arena in HBM, the
+    output, the scratch ``[2, pages a block, page, 2, n_kv (padded), D]`` and
+    its two DMA semaphores (the row's one grid step walks all its blocks);
+    else the block's ``ppb`` pages as the pipeline brought them and the
+    output (grid step ``g`` is block ``g``).  Then the softmax state."""
+    b, g = pl.program_id(0), pl.program_id(1)
+    if copies:
+        arena_ref, o_ref, buf, sem, m_ref, l_ref, acc_ref = refs
+    else:
+        fed, (o_ref, m_ref, l_ref, acc_ref) = list(refs[:ppb]), refs[ppb:]
+    _, n_kv, padded, d = q_ref.shape
+    block = ppb * page_size
+    n_tiles = padded // tile
+    start, n_tok = sp_ref[b], cl_ref[b]
+    last_page = _last_page(start, n_tok, page_size, bt_ref.shape[1])      # of the row's last visible key
+    n_blocks = jnp.where(n_tok > 0, last_page // ppb + 1, 0)
+    live_tiles = (n_tok * rep + tile - 1) // tile      # tiles with a row that carries a token
+
+    def rows_of(t):
+        return slice(None) if n_tiles == 1 else pl.ds(pl.multiple_of(t * tile, tile), tile)
+
+    @pl.when(g == 0)
     def _init():
-        for hh in range(n_kv):
-            ms[hh][:] = jnp.full_like(ms[hh], -jnp.inf)
-            ls[hh][:] = jnp.zeros_like(ls[hh])
-            accs[hh][:] = jnp.zeros_like(accs[hh])
+        def init(t, _):
+            r = rows_of(t)
+            m_ref[:, r] = jnp.full((n_kv, tile, 1), -jnp.inf, jnp.float32)
+            l_ref[:, r] = jnp.zeros((n_kv, tile, 1), jnp.float32)
+            acc_ref[:, r] = jnp.zeros((n_kv, tile, d), jnp.float32)
 
-    start = sp_ref[b]
-    # last visible key position of this chunk is start + chunk - 1
-    @pl.when(j * page_size <= start + chunk - 1)
-    def _compute():
-        for hh in range(n_kv):
-            # bf16 operands straight into the MXU, f32 accumulation
-            q = q_ref[0, hh]             # [repC, D]
-            k = pg_ref[0, :, 0, hh]      # [page, D]
-            v = pg_ref[0, :, 1, hh]      # [page, D]
-            rep_c = q.shape[0]
-            s = jax.lax.dot_general(q, k, (((1, ), (1, )), ((), ())),
-                                    preferred_element_type=jnp.float32) * scale  # [repC, page]
-            # row r of the group-major q block is chunk position r % chunk
-            row_c = jax.lax.broadcasted_iota(jnp.int32, (rep_c, page_size), 0) % chunk
-            kpos = j * page_size + jax.lax.broadcasted_iota(jnp.int32, (rep_c, page_size), 1)
-            s = jnp.where(kpos <= start + row_c, s, DEFAULT_MASK_VALUE)
-            m_prev = ms[hh][:]
-            l_prev = ls[hh][:]
-            m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
-            p = jnp.exp(s - m_new)
-            alpha = jnp.exp(m_prev - m_new)
-            ls[hh][:] = alpha * l_prev + jnp.sum(p, axis=1, keepdims=True)
-            accs[hh][:] = accs[hh][:] * alpha + jax.lax.dot_general(
-                p.astype(v.dtype), v, (((1, ), (0, )), ((), ())),
-                preferred_element_type=jnp.float32)
-            ms[hh][:] = m_new
+        jax.lax.fori_loop(0, live_tiles, init, None)
 
-    @pl.when(j == max_pages - 1)
-    def _finalize():
-        for hh in range(n_kv):
-            o_ref[0, hh] = (accs[hh][:] / jnp.maximum(ls[hh][:], 1e-30)).astype(o_ref.dtype)
+    def block_step(j, pages):
+        """Block ``j`` of the row, its pages in VMEM, into the softmax state."""
+        key = jax.lax.broadcasted_iota(jnp.int32, (tile, block), 1)
+        row = jax.lax.broadcasted_iota(jnp.int32, (tile, 1), 0)
+
+        def head(h, _):
+            k = _head_rows(pages, 0, h)                                  # [block, D]
+            v = _head_rows(pages, 1, h)
+
+            def q_tile(t, _):
+                r = rows_of(t)
+                # bf16 operands straight into the MXU, f32 accumulation
+                s = jax.lax.dot_general(q_ref[0, h, r, :], k, (((1, ), (1, )), ((), ())),
+                                        preferred_element_type=jnp.float32) * scale   # [tile, block]
+                # row i of the position-major q block is chunk position i // rep;
+                # the last key it may see, counted from this block's first
+                sees = start - j * block + (t * tile + row) // rep
+                s = jnp.where(key <= sees, s, DEFAULT_MASK_VALUE)
+                m_prev = m_ref[h, r]
+                m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
+                p = jnp.exp(s - m_new)
+                alpha = jnp.exp(m_prev - m_new)
+                l_ref[h, r] = alpha * l_ref[h, r] + jnp.sum(p, axis=1, keepdims=True)
+                acc_ref[h, r] = acc_ref[h, r] * alpha + jax.lax.dot_general(
+                    p.astype(v.dtype), v, (((1, ), (0, )), ((), ())), preferred_element_type=jnp.float32)
+                m_ref[h, r] = m_new
+
+            jax.lax.fori_loop(0, live_tiles, q_tile, None)
+
+        if copies:
+            jax.lax.fori_loop(0, n_kv, head, None)
+        else:
+            # a plain load wants the head's index static
+            for h in range(n_kv):
+                head(h, None)
+
+    if copies:
+        n_pad = buf.shape[4]
+
+        def page_copy(blk, i, slot):
+            # past the row's last page the block repeats it; the mask hides it
+            page = bt_ref[b, jnp.minimum(blk * ppb + i, last_page)]
+            dst = buf.at[slot, i] if n_pad == n_kv else buf.at[slot, i, :, :, pl.ds(0, n_kv), :]
+            return pltpu.make_async_copy(arena_ref.at[ly_ref[0], page], dst, sem.at[slot])
+
+        def fetch(blk, slot):
+            for i in range(ppb):
+                page_copy(blk, i, slot).start()
+
+        @pl.when(n_blocks > 0)
+        def _first():
+            fetch(0, 0)
+
+        def walk(j, _):
+            slot = j % 2
+
+            @pl.when(j + 1 < n_blocks)
+            def _next():
+                fetch(j + 1, 1 - slot)
+
+            for i in range(ppb):
+                page_copy(j, i, slot).wait()
+            block_step(j, buf.at[slot])
+
+        jax.lax.fori_loop(0, n_blocks, walk, None)
+    else:
+        @pl.when(g < n_blocks)
+        def _fed():
+            block_step(g, fed)
+
+    @pl.when(g == pl.num_programs(1) - 1)
+    def _finish():
+        def finish(t, _):
+            r = rows_of(t)
+            carries = (t * tile + jax.lax.broadcasted_iota(jnp.int32, (tile, d), 0)) // rep < n_tok
+
+            @pl.when(t < live_tiles)
+            def _live():
+                def head(h, _):
+                    out = acc_ref[h, r] / jnp.maximum(l_ref[h, r], 1e-30)
+                    o_ref[0, h, r, :] = jnp.where(carries, out, 0).astype(o_ref.dtype)
+
+                jax.lax.fori_loop(0, n_kv, head, None)
+
+            @pl.when(t >= live_tiles)
+            def _dead():
+                o_ref[0, :, r, :] = jnp.zeros((n_kv, tile, d), o_ref.dtype)
+
+        jax.lax.fori_loop(0, n_tiles, finish, None)
 
 
 def _paged_sharded(q, pages, block_table, start_pos, chunk_lens, page_size, interpret, mesh, layer=None):
@@ -127,10 +302,12 @@ def paged_attention_pallas(q, pages, block_table, start_pos, chunk_lens, page_si
     """Drop-in twin of ``models/llama_cache.paged_attention`` (jnp golden).
 
     q: [B, C, H, D]; pages: [P, page, 2, n_kv, D] (chunk K/V already
-    written); block_table: [B, max_pages]; start_pos/chunk_lens: [B].
-    With ``layer`` (an index, traced in a scanned trunk) ``pages`` is the
-    whole arena [L, P, page, 2, n_kv, D] and the kernel reads that layer's
-    pages where they lie: no layer of the arena is sliced out first.
+    written); block_table: [B, max_pages]; start_pos/chunk_lens: [B]
+    (``chunk_lens`` None: every row carries its whole chunk).  With ``layer``
+    (an index, traced in a scanned trunk) ``pages`` is the whole arena
+    [L, P, page, 2, n_kv, D] and the kernel reads that layer's pages where
+    they lie: no layer of the arena is sliced out first.  Query rows at and
+    past a row's ``chunk_lens`` come out exactly zero.
     """
     from ..comm.mesh import get_trace_mesh, in_manual_mesh
     if interpret is None:
@@ -144,59 +321,73 @@ def paged_attention_pallas(q, pages, block_table, start_pos, chunk_lens, page_si
                                   interpret, mesh, layer)
     b, c, h, d = q.shape
     n_kv = pages.shape[-2]
-    max_pages = block_table.shape[1]
     rep = h // n_kv
-    scale = 1.0 / (d**0.5)
-
-    # group-major query layout: [B, n_kv, rep*C, D], row = r*C + c
-    qg = q.transpose(0, 2, 1, 3).reshape(b, n_kv, rep, c, d).reshape(b, n_kv, rep * c, d)
-
-    grid = (b, max_pages)
-    kernel = functools.partial(_paged_kernel, page_size=page_size, max_pages=max_pages,
-                               chunk=c, scale=scale, n_kv=n_kv)
-
-    def page_of(b, j, bt, sp):
-        # j is CLAMPED to the row's last needed page: past it the index map
-        # repeats the same page and Mosaic's pipeline skips the refetch —
-        # pages beyond the true sequence length cost no DMA (they were still
-        # copied pre-r4 even though pl.when skipped their compute)
-        return bt[b, jnp.minimum(j, (sp[b] + c - 1) // page_size)]
-
-    # one whole page: trailing dims (page, 2, n_kv, d) are the full array
-    # dims → always tile-legal
     if layer is None:
-        prefetch = (block_table, start_pos)
-        page_spec = pl.BlockSpec((1, page_size, 2, n_kv, d),
-                                 lambda b, j, bt, sp: (page_of(b, j, bt, sp), 0, 0, 0, 0))
+        pages, layer = pages[None], 0          # one layer's pages are an arena of one layer
+    if chunk_lens is None:
+        chunk_lens = jnp.full((b, ), c, jnp.int32)
+    # the block, how its pages arrive, the tile and the scratch follow from
+    # the shapes: a chunk of up to _TILE_ROWS query rows a key head is one
+    # tile; a longer one is cut into tiles of that many, padded with rows no
+    # chunk position owns
+    itemsize = pages.dtype.itemsize
+    width = block_table.shape[1]
+    copies = _copies_pages(n_kv, d, itemsize)
+    ppb = walk_block(page_size, width, n_kv, d, itemsize)
+    rows = c * rep
+    tile = min(rows, _TILE_ROWS)
+    padded = -(-rows // tile) * tile
+
+    # position-major query layout: [B, n_kv, C*rep, D], row = c*rep + r
+    qg = q.reshape(b, c, n_kv, rep, d).transpose(0, 2, 1, 3, 4).reshape(b, n_kv, rows, d)
+    if padded != rows:
+        qg = jnp.pad(qg, ((0, 0), (0, 0), (0, padded - rows), (0, 0)))
+
+    row_block = pl.BlockSpec((1, n_kv, padded, d), lambda b, g, *_: (b, 0, 0, 0))
+    state = [pltpu.VMEM((n_kv, padded, 1), jnp.float32), pltpu.VMEM((n_kv, padded, 1), jnp.float32),
+             pltpu.VMEM((n_kv, padded, d), jnp.float32)]
+    lanes = lambda n: -(-n // 128) * 128  # noqa: E731
+    if copies:
+        n_pad = _padded_heads(n_kv, itemsize)
+        steps, arena_specs, arenas = 1, [pl.BlockSpec(memory_space=pl.ANY)], [pages]
+        scratch = [pltpu.VMEM((2, ppb, page_size, 2, n_pad, d), pages.dtype), pltpu.SemaphoreType.DMA((2, ))]
     else:
-        prefetch = (block_table, start_pos, jnp.reshape(layer, (1, )).astype(jnp.int32))
-        page_spec = pl.BlockSpec((None, 1, page_size, 2, n_kv, d),
-                                 lambda b, j, bt, sp, ly: (ly[0], page_of(b, j, bt, sp), 0, 0, 0, 0))
-    kernel_fn = kernel if layer is None else (lambda bt, sp, ly, *refs: kernel(bt, sp, *refs))
+        def page_spec(i):
+            def index(b, g, bt, sp, cl, ly):
+                # clamped to the row's last page: past it the same page again,
+                # which the pipeline does not fetch twice
+                column = jnp.minimum(g * ppb + i, _last_page(sp[b], cl[b], page_size, width))
+                return ly[0], bt[b, column], 0, 0, 0, 0
+
+            return pl.BlockSpec((None, None, page_size, 2, n_kv, d), index)
+
+        n_pad = -(-n_kv // 8) * 8               # as the tiling lays a page out
+        steps, arena_specs, arenas = -(-width // ppb), [page_spec(i) for i in range(ppb)], [pages] * ppb
+        scratch = []
+    q_block = n_kv * padded * lanes(d) * q.dtype.itemsize
+    vmem = n_kv * padded * (lanes(d) + 2 * 128) * 4 + 2 * ppb * page_size * 2 * n_pad * lanes(d) * itemsize
+    kernel = functools.partial(_paged_kernel, page_size=page_size, ppb=ppb, copies=copies, rep=rep, tile=tile,
+                               scale=1.0 / (d**0.5))
     out = pl.pallas_call(
-        kernel_fn,
+        kernel,
         grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=len(prefetch),
-            grid=grid,
-            in_specs=[
-                # q stays resident across the page sweep (index map constant in j)
-                pl.BlockSpec((1, n_kv, rep * c, d), lambda b, j, *_: (b, 0, 0, 0)),
-                page_spec,
-            ],
-            out_specs=pl.BlockSpec((1, n_kv, rep * c, d), lambda b, j, *_: (b, 0, 0, 0)),
-            scratch_shapes=([pltpu.VMEM((rep * c, 1), jnp.float32)] * n_kv +
-                            [pltpu.VMEM((rep * c, 1), jnp.float32)] * n_kv +
-                            [pltpu.VMEM((rep * c, d), jnp.float32)] * n_kv),
+            num_scalar_prefetch=4,
+            grid=(b, steps),
+            in_specs=[row_block, *arena_specs],
+            out_specs=row_block,
+            scratch_shapes=scratch + state,
         ),
-        out_shape=jax.ShapeDtypeStruct((b, n_kv, rep * c, d), q.dtype),
+        out_shape=jax.ShapeDtypeStruct((b, n_kv, padded, d), q.dtype),
         compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "arbitrary")),
+            dimension_semantics=("parallel", "arbitrary"),
+            # the row's query and output blocks twice (the pipeline's two
+            # buffers), the block's pages twice (the scratch's two slots or
+            # the pipeline's), the softmax state, and room for the body's
+            # temporaries
+            vmem_limit_bytes=4 * q_block + vmem + (16 << 20)),
         interpret=interpret,
         name="ds_paged_attention",
-    )(*prefetch, qg, pages)
+    )(block_table, start_pos.astype(jnp.int32), chunk_lens.astype(jnp.int32),
+      jnp.reshape(layer, (1, )).astype(jnp.int32), qg, *arenas)
 
-    out = out.reshape(b, n_kv, rep, c, d).reshape(b, h, c, d).transpose(0, 2, 1, 3)
-    if chunk_lens is not None:
-        valid = jnp.arange(c)[None, :] < chunk_lens[:, None]
-        out = jnp.where(valid[..., None, None], out, 0)
-    return out
+    return out[:, :, :rows].reshape(b, n_kv, c, rep, d).transpose(0, 2, 1, 3, 4).reshape(b, c, h, d)

@@ -61,9 +61,13 @@ to kill.  This module decomposes EVERY engine step into:
   ``tokens_real`` x experts a token; 0 for a model with no expert layer)
   and, for a cache of exact and summary pages (chunked linear attention; 0
   under the linear geometry), ``summary_rows_written`` (chunks that
-  completed in the step), ``ring_wraps`` (token rows that started a window
-  after the first) and ``attn_rows_visible`` (ring rows plus summary rows a
-  query could see, summed over the step's token rows, one layer).
+  completed in the step) and ``ring_wraps`` (token rows that started a
+  window after the first); under either geometry ``attn_rows_visible`` (key
+  rows a query could see: ring rows plus summary rows, or its whole history;
+  summed over the step's token rows, one layer) and ``attn_rows_walked``
+  (key rows the paged kernel's walk covered for them: whole blocks up to
+  the last row its call could see, 0 where the attention does not read
+  through the kernel; ``walked / visible`` is the kernel's tightness).
 
 The decomposition TILES by construction: every component is a
 non-negative clock difference (or an explicit charge), and
@@ -115,7 +119,7 @@ HOST_SEGMENTS = ("admit", "schedule", "draft_plan", "verify_plan",
 #: what a step carried; zero until the engine notes them
 COUNTS = ("rows_decode", "rows_prefill", "tokens_real", "slots", "tokens_out",
           "tokens_discarded", "expert_rows", "summary_rows_written", "ring_wraps",
-          "attn_rows_visible")
+          "attn_rows_visible", "attn_rows_walked")
 
 #: names of the instant profiler events, built once (a mark allocates no string)
 _MARK_NAMES = {s: "ds.mark." + s for s in HOST_SEGMENTS + ("device_wait", )}
@@ -142,7 +146,8 @@ class StepRecord:
         self.tokens_real = self.slots = 0
         self.tokens_out = self.tokens_discarded = 0
         self.expert_rows = 0
-        self.summary_rows_written = self.ring_wraps = self.attn_rows_visible = 0
+        self.summary_rows_written = self.ring_wraps = 0
+        self.attn_rows_visible = self.attn_rows_walked = 0
 
     def host_s(self) -> float:
         return sum(self.segments.values())
@@ -285,7 +290,7 @@ class StepAnatomy:
     def note_program(self, key: str, path: str, rows_decode: int = 0,
                      rows_prefill: int = 0, tokens_real: int = 0,
                      slots: int = 0, expert_rows: int = 0,
-                     cache_counts: tuple = (0, 0, 0)) -> None:
+                     cache_counts: tuple = (0, 0, 0, 0)) -> None:
         """Tag the open step with the program it dispatches (``key``, as
         ``InferenceEngineV2._key_label`` prints it: the attribution key)
         and what the packed batch carries (``cache_counts``: the geometry's
@@ -299,7 +304,8 @@ class StepAnatomy:
             cur.rows_decode, cur.rows_prefill = int(rows_decode), int(rows_prefill)
             cur.tokens_real, cur.slots = int(tokens_real), int(slots)
             cur.expert_rows = int(expert_rows)
-            cur.summary_rows_written, cur.ring_wraps, cur.attn_rows_visible = (int(c) for c in cache_counts)
+            (cur.summary_rows_written, cur.ring_wraps, cur.attn_rows_visible,
+             cur.attn_rows_walked) = (int(c) for c in cache_counts)
 
     def note_tokens(self, out: int, discarded: int = 0, real: int = 0,
                     expert_rows: int = 0) -> None:

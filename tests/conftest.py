@@ -108,8 +108,6 @@ SLOW_TESTS = {
     "monitor/test_monitor.py::test_engine_writes_monitor_events",
     "ops/test_flash_attention.py::test_flash_backward_kernel_grads",
     "ops/test_flash_attention.py::test_flash_gradients_match_reference",
-    "ops/test_paged_attention.py::test_pallas_decode_single_token",
-    "ops/test_paged_attention.py::test_pallas_matches_jnp_golden",
     "ops/test_sparse_attention.py::test_pallas_bwd_sparse_layout_and_no_dense_intermediate",
     "ops/test_sparse_attention.py::test_pallas_kernel_gradients_via_bwd_kernels",
     "profiling/test_flops_profiler.py::test_profiler_with_engine",

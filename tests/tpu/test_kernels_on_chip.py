@@ -168,12 +168,18 @@ def test_flash_gqa_native_llama3_shape_on_chip():
 # geometry — Llama-3-8B heads (32q/8kv, d=128, rep 4), page 16, prefill
 # chunk 128 and decode chunk 1 — whole and at the TP=4 share (8q/2kv).
 # Start positions are deliberately not multiples of the page or the chunk.
+# The last three are pages the chip's tiling pads, which the pipeline brings
+# (ops/paged_attention._copies_pages): Falcon-7B's one key head of 64 lanes
+# at a chunk and at one token, and a Mixtral shard of tensor_parallel=8.
 PAGED_GEOMETRIES = [
     (4, 8, 4, 64, 8, (0, 5, 13)),
     (128, 32, 8, 128, 16, (0, 200, 1337)),
     (1, 32, 8, 128, 16, (0, 200, 1337)),
     (128, 8, 2, 128, 16, (0, 200, 1337)),
     (1, 8, 2, 128, 16, (0, 200, 1337)),
+    (128, 71, 1, 64, 16, (0, 200, 1337)),
+    (1, 71, 1, 64, 16, (0, 200, 1337)),
+    (1, 4, 1, 128, 16, (0, 200, 1337)),
 ]
 
 
@@ -210,8 +216,10 @@ def test_paged_kernel_bf16_on_chip(c, h, n_kv, d, page_size, starts):
 
     gold = jax.jit(lambda q, pages: paged_attention(
         q.astype(jnp.float32), pages.astype(jnp.float32), bt, sp, cl, page_size))(q, pages)
-    got = jax.jit(lambda q, pages: paged_attention_pallas(
-        q, pages, bt, sp, cl, page_size, interpret=False))(q, pages)
+    kernel = jax.jit(lambda q, pages: paged_attention_pallas(q, pages, bt, sp, cl, page_size, interpret=False))
+    # the kernel by its name: no other form of the same attention stands in
+    assert "ds_paged_attention" in kernel.lower(q, pages).as_text()
+    got = kernel(q, pages)
     assert bool(jnp.isfinite(got.astype(jnp.float32)).all())
     err = float(jnp.max(jnp.abs(got.astype(jnp.float32) - gold)))
     # bf16 probabilities and outputs against an f32 golden: 2^-8 relative

@@ -31,6 +31,7 @@ from deepspeed_tpu.models.evabyte import EvaByteConfig, EvaByteForCausalLM
 from deepspeed_tpu.models.evabyte_cache import EvaByteForCausalLMWithCache
 from deepspeed_tpu.models.llama import PRESETS
 from deepspeed_tpu.models.llama_cache import PagedKVConfig, init_kv_cache
+from deepspeed_tpu.ops.paged_attention import walk_block
 
 sys.path.insert(0, os.path.join(os.path.dirname(__file__), "..", "..", "..", "benchmark"))
 from refs import evabyte as ref  # noqa: E402
@@ -169,13 +170,13 @@ def test_plan_never_makes_a_chunk_that_crosses_a_window():
 # ------------------------------------------------------------------ (c) the engine
 
 
-def _engine(params, **over):
+def _engine(params, cfg=CFG, **over):
     econf = dict(kv=PagedKVConfig(num_pages=128, page_size=PAGE, max_pages_per_seq=40),
                  scheduler=SchedulerConfig(token_budget=96, max_seqs=4, prefill_chunk=48, decode_bucket=4),
                  max_new_tokens=48, enable_prefix_cache=False, decode_steps_per_dispatch=8,
                  kv_dtype=jnp.float32)
     econf.update(over)
-    return InferenceEngineV2(CFG, params, RaggedInferenceEngineConfig(**econf))
+    return InferenceEngineV2(cfg, params, RaggedInferenceEngineConfig(**econf))
 
 
 def _greedy(params, prompt, n):
@@ -209,7 +210,7 @@ def test_engine_generate_matches_full_sequence_model_over_two_windows(params, id
 
 def test_step_records_count_summaries_wraps_and_visible_rows(params, ids):
     from deepspeed_tpu.telemetry import StepAnatomy
-    eng = _engine(params)
+    eng = _engine(params, dataclasses.replace(CFG, attention_impl="flash"))
     anat = eng.set_anatomy(StepAnatomy())
     eng.generate([ids[:300].tolist()], max_new_tokens=20)
     rows = [r.to_row() for r in anat.steps]
@@ -218,8 +219,16 @@ def test_step_records_count_summaries_wraps_and_visible_rows(params, ids):
     assert sum(r["ring_wraps"] for r in rows) == 1          # token 256
     t = np.arange(fed)
     assert sum(r["attn_rows_visible"] for r in rows) == int((t % WINDOW + 1 + t // WINDOW * (WINDOW // PAGE)).sum())
-    # the linear geometry counts nothing
-    assert LinearGeometry(PAGE).step_counts(250, 48) == (0, 0, 0)
+    # the walk covers what a query sees, in whole blocks (heads of 16 lanes: the pipeline brings blocks of 128 rows):
+    # never less, and less than a block a token more than the row its call's last token sees (a call is a chunk of up
+    # to 48 tokens, or one token of the fused rung)
+    block = walk_block(PAGE, eng.kv.table_width, 4, 16, 4) * PAGE
+    assert block == 128
+    for r in rows:
+        slack = r["attn_rows_walked"] - r["attn_rows_visible"]
+        assert 0 <= slack < r["tokens_real"] * (block + 48), r
+    # the linear geometry has no summaries and no ring
+    assert LinearGeometry(PAGE).step_counts(250, 48)[:2] == (0, 0)
 
 
 # ---------------------------------------------------------------- (d) the geometry

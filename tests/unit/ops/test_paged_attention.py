@@ -48,13 +48,116 @@ def _setup(b=3, c=4, h=8, n_kv=4, d=32, page_size=8, max_pages=6, seed=0):
     return q, pages, bt, sp, cl, page_size
 
 
-@pytest.mark.parametrize("gqa", [False, True])
-def test_pallas_matches_jnp_golden(gqa):
-    q, pages, bt, sp, cl, ps = _setup(h=8, n_kv=4 if gqa else 8)
-    expected = paged_attention(q, pages, bt, sp, cl, ps)
-    got = jax.jit(lambda q, pages: paged_attention_pallas(q, pages, bt, sp, cl, ps,
+def _rows(rows, c, h, n_kv, d=128, page_size=8, width=None, layers=None, seed=0):
+    """An arena (of ``layers`` layers, or one layer's pages) that holds the
+    history of ``rows`` = [(start, chunk_len), ...] and each row's chunk,
+    written as the twins write it; the table ``width`` columns wide.  Heads
+    of 128 lanes, as every cell's, unless ``d`` says otherwise."""
+    rng = np.random.default_rng(seed)
+    b = len(rows)
+    start = np.array([s for s, _ in rows], np.int32)
+    lens = np.array([n for _, n in rows], np.int32)
+    need = [-(-(s + c) // page_size) for s in start]
+    width = width or max(need)
+    table = np.zeros((b, width), np.int32)
+    nxt = 1
+    for i in range(b):
+        table[i, :need[i]] = np.arange(nxt, nxt + need[i])
+        nxt += need[i]
+    pages = np.zeros((nxt, page_size, 2, n_kv, d), np.float32)
+    for i in range(b):
+        hist = rng.normal(size=(start[i], 2, n_kv, d)).astype(np.float32)
+        for t in range(start[i]):
+            pages[table[i, t // page_size], t % page_size] = hist[t]
+    q = jnp.asarray(rng.normal(size=(b, c, h, d)), jnp.float32)
+    k_new, v_new = (jnp.asarray(rng.normal(size=(b, c, n_kv, d)), jnp.float32) for _ in range(2))
+    table, start, lens = jnp.asarray(table), jnp.asarray(start), jnp.asarray(lens)
+    pages = _write_pages(jnp.asarray(pages), k_new, v_new, table, start, page_size, lens)
+    layer = None
+    if layers:
+        # the other layers hold other rows: a read of the wrong layer shows
+        layer = layers - 2
+        pages = jnp.stack([pages if i == layer else jnp.asarray(rng.normal(size=pages.shape), jnp.float32)
+                           for i in range(layers)])
+    return q, pages, table, start, lens, page_size, layer
+
+
+def _eva_view():
+    """EvaByte's pages as the kernel sees them (``_kernel_view``): 32 key
+    heads of 128 lanes, no grouping (the scratch pads such heads and takes a
+    head's rows by strided load), rows whose chunk starts in the third window,
+    one of them a decode row, the layer named in the whole arena."""
+    from deepspeed_tpu.models.evabyte_cache import _kernel_view
+    page, window = 8, 256
+    ring = window // page                                   # ring pages; summary pages a window: ring // page
+    rng = np.random.default_rng(3)
+    start = np.array([2 * window + 40, 2 * window + 201, 2 * window], np.int32)
+    lens = np.array([16, 1, 0], np.int32)
+    width = ring + 3 * (ring // page)
+    table = 1 + np.arange(3 * width, dtype=np.int32).reshape(3, width)
+    arena = jnp.asarray(rng.normal(size=(2, 1 + 3 * width, page, 2, 32, 128)), jnp.float32)
+    view, vstart = _kernel_view(jnp.asarray(table), jnp.asarray(start), page, ring, window, 4)
+    q = jnp.asarray(rng.normal(size=(3, 16, 32, 128)), jnp.float32)
+    return q, arena, view, vstart, jnp.asarray(lens), page, 1
+
+
+CASES = {
+    # Heads of 128 lanes in whole tiles (every cell's): the kernel copies a block's pages itself, 64 of them (512 rows),
+    # and takes a head's rows by strided load.
+    # the three rows of old: a prefill from nothing, a chunk short of one token, a decode row deep in a chunk program
+    "mha": lambda: _rows([(0, 4), (5, 3), (13, 1)], c=4, h=8, n_kv=8),
+    "gqa": lambda: _rows([(0, 4), (5, 3), (13, 1)], c=4, h=8, n_kv=4),
+    # a table of 150 columns under blocks of 64 pages: two whole blocks and a tail of 22, rows that end in each
+    "table_no_multiple_of_the_block": lambda: _rows([(1100, 4), (520, 4), (250, 2), (513, 1)], c=4, h=4, n_kv=2, width=150),
+    # a mixed step: a chunk of 32 next to a decode row deep in its context (its second block) and a row with nothing
+    "decode_row_in_a_chunk_of_32": lambda: _rows([(64, 32), (700, 1), (0, 0), (37, 17)], c=32, h=8, n_kv=4),
+    # query rows beyond one tile (32 positions x 8 heads a key head = 256 rows): a decode row multiplies the first only
+    "more_than_one_query_tile": lambda: _rows([(10, 32), (90, 1), (40, 20), (0, 0)], c=32, h=16, n_kv=2),
+    "two_key_heads_a_tensor_parallel_shard": lambda: _rows([(0, 8), (77, 1), (30, 5)], c=8, h=8, n_kv=2),
+    "heads_of_128_lanes": lambda: _rows([(0, 4), (5, 3), (530, 1)], c=4, h=8, n_kv=4),
+    "layer_named_in_the_whole_arena": lambda: _rows([(0, 4), (5, 3), (540, 1)], c=4, h=8, n_kv=4, layers=3),
+    "evabyte_view_32_key_heads_third_window": _eva_view,
+    # one key head: whole tiles in float32; in bfloat16 half a 32-bit sublane, a page the tiling pads (see below)
+    "one_key_head": lambda: _rows([(3, 4), (21, 1), (300, 2)], c=4, h=4, n_kv=1),
+    # Pages the chip's tiling pads, or heads no strided load takes: the pipeline brings a block's pages, 16 of them
+    # (128 rows), and a head's rows are a load a page.
+    "heads_of_32_lanes": lambda: _rows([(0, 4), (5, 3), (13, 1)], c=4, h=8, n_kv=4, d=32),
+    # 150 columns under blocks of 16 pages: nine whole blocks and a tail of 6; a row with nothing, the layer named
+    "heads_of_32_lanes_table_no_multiple_of_the_block": lambda: _rows(
+        [(1100, 4), (520, 4), (0, 0), (513, 1)], c=4, h=4, n_kv=2, d=32, width=150, layers=3),
+    "heads_of_64_lanes_decode_row_in_a_chunk_of_32": lambda: _rows(
+        [(64, 32), (700, 1), (0, 0), (37, 17)], c=32, h=16, n_kv=2, d=64),
+    "three_key_heads": lambda: _rows([(0, 4), (5, 3), (140, 1)], c=4, h=6, n_kv=3),
+    "heads_of_256_lanes": lambda: _rows([(0, 4), (130, 1)], c=4, h=4, n_kv=2, d=256),
+}
+
+
+def test_the_cases_take_both_ways_a_block_arrives():
+    """Which way is a matter of the page's shape alone: the cases above are
+    on both sides of it, in both types."""
+    from deepspeed_tpu.ops.paged_attention import _copies_pages
+    ways = {(case, size): _copies_pages(*make()[1].shape[-2:], size) for case, make in CASES.items() for size in (4, 2)}
+    assert sum(ways.values()) >= 18 and sum(not w for w in ways.values()) >= 10
+    assert ways["one_key_head", 4] and not ways["one_key_head", 2]
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16], ids=["float32", "bfloat16"])
+@pytest.mark.parametrize("case", list(CASES))
+def test_pallas_matches_jnp_golden(case, dtype):
+    """The kernel in interpret mode against the jnp golden: same values where
+    a row carries a token, exactly zero where it does not.  In bfloat16 (two
+    heads a 32-bit sublane: the kernel's other way to take a head's rows out
+    of a page) against the golden over the same rounded operands."""
+    q, pages, table, start, lens, page_size, layer = CASES[case]()
+    q, pages = q.astype(dtype), pages.astype(dtype)
+    as32 = lambda x: x.astype(jnp.float32)  # noqa: E731
+    expected = paged_attention(as32(q), as32(pages if layer is None else pages[layer]), table, start, lens, page_size)
+    got = jax.jit(lambda q, pages: paged_attention_pallas(q, pages, table, start, lens, page_size, layer=layer,
                                                           interpret=True))(q, pages)
-    np.testing.assert_allclose(np.asarray(got), np.asarray(expected), atol=2e-5)
+    assert got.dtype == dtype
+    np.testing.assert_allclose(np.asarray(as32(got)), np.asarray(expected), atol=2e-5 if dtype == jnp.float32 else 3e-2)
+    past = np.arange(q.shape[1])[None, :] >= np.asarray(lens)[:, None]
+    np.testing.assert_array_equal(np.asarray(as32(got))[past], 0)
 
 
 def test_pallas_decode_single_token():

@@ -1,7 +1,8 @@
 """The paged kernel compiled for the chip, without the chip, at the shapes the
 benchmark's serving cells give it: what interpret mode cannot refuse (tiling,
-scoped VMEM, 32 key heads unrolled in one grid step) the chip's compiler
-does, here, in a second or two a shape.  And a small Mixtral twin's step
+the VMEM a block of 512 key rows and a row's softmax state need, strided
+loads of a head's rows, DMAs out of an arena left in HBM, which pages such a
+DMA may take) the chip's compiler does, here, in a second or two a shape.  And a small Mixtral twin's step
 programs, to read the compiler's buffer assignment for a second arena.
 Nothing runs and no time is read.
 
@@ -16,7 +17,8 @@ import jax
 import jax.numpy as jnp
 import pytest
 
-from deepspeed_tpu.ops.paged_attention import paged_attention_pallas
+from deepspeed_tpu.ops.paged_attention import (_BLOCK_BYTES, _copies_pages, _padded_heads, paged_attention_pallas,
+                                                walk_block)
 
 
 @pytest.fixture(scope="module")
@@ -44,11 +46,12 @@ def no_compile_cache():
     jax.config.update("jax_enable_compilation_cache", cache_was)
 
 
-def _compile(one_chip, chunk, n_q, n_kv, table_width, layers=None):
-    """The kernel alone; under the ``no_compile_cache`` fixture."""
+def _compile(one_chip, chunk, n_q, n_kv, table_width, layers=None, d=128, dtype=jnp.bfloat16):
+    """The kernel alone, lowered and compiled: the program's text.  Under the
+    ``no_compile_cache`` fixture."""
     sds = lambda shape, dtype: jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)  # noqa: E731
-    arena = (64, 16, 2, n_kv, 128) if layers is None else (layers, 64, 16, 2, n_kv, 128)
-    args = [sds((16, chunk, n_q, 128), jnp.bfloat16), sds(arena, jnp.bfloat16), sds((16, table_width), jnp.int32),
+    arena = (64, 16, 2, n_kv, d) if layers is None else (layers, 64, 16, 2, n_kv, d)
+    args = [sds((16, chunk, n_q, d), dtype), sds(arena, dtype), sds((16, table_width), jnp.int32),
             sds((16, ), jnp.int32), sds((16, ), jnp.int32)]
 
     def call(q, pages, table, start, lens, layer=None):
@@ -56,28 +59,83 @@ def _compile(one_chip, chunk, n_q, n_kv, table_width, layers=None):
 
     if layers is not None:
         args.append(sds((), jnp.int32))
-    return jax.jit(call).lower(*args).compile()
+    lowered = jax.jit(call).lower(*args)
+    # the kernel by its name, not another form of the same attention
+    assert "ds_paged_attention" in lowered.as_text()
+    return lowered.compile().as_text()
 
 
 @pytest.mark.parametrize("chunk", [128, 1])
 def test_grouped_heads_one_layer_of_pages(one_chip, no_compile_cache, chunk):
     """Mixtral's heads, 32 query heads over 8 key heads, out of one layer's
     pages: the form the unrolled trunk still gives the kernel."""
-    assert "tpu_custom_call" in _compile(one_chip, chunk, 32, 8, 770).as_text()
+    assert "tpu_custom_call" in _compile(one_chip, chunk, 32, 8, 770)
+
+
+@pytest.mark.parametrize("table_width", [776, 282])
+@pytest.mark.parametrize("chunk", [128, 1])
+def test_grouped_heads_out_of_the_whole_arena(one_chip, no_compile_cache, chunk, table_width):
+    """Mixtral's shape as its scanned twin gives it: 32 query heads over 8
+    key heads, the layer named by an index into an arena of 3, at the table
+    widths of the document cell (776) and the chat cell (282), neither a
+    multiple of the walk's block of 32 pages."""
+    assert "tpu_custom_call" in _compile(one_chip, chunk, 32, 8, table_width, layers=3)
 
 
 @pytest.mark.parametrize("chunk", [128, 1])
-def test_grouped_heads_out_of_the_whole_arena(one_chip, no_compile_cache, chunk):
-    """Mixtral's shape as its scanned twin gives it: 32 query heads over 8
-    key heads, the layer named by an index into an arena of 3."""
-    assert "tpu_custom_call" in _compile(one_chip, chunk, 32, 8, 770, layers=3).as_text()
+def test_two_key_heads_a_tensor_parallel_shard(one_chip, no_compile_cache, chunk):
+    """What a shard of ``tensor_parallel=4`` gives the kernel: 8 query heads
+    over 2 key heads, a page of two rows a token and a half."""
+    assert "tpu_custom_call" in _compile(one_chip, chunk, 8, 2, 776, layers=3)
 
 
 @pytest.mark.parametrize("chunk", [128, 1])
 def test_ungrouped_heads_out_of_the_whole_arena(one_chip, no_compile_cache, chunk):
     """EvaByte's shape: 32 key heads, no grouping, the layer named by an
     index into the whole arena, a table of 248 virtual pages."""
-    assert "tpu_custom_call" in _compile(one_chip, chunk, 32, 32, 248, layers=8).as_text()
+    assert "tpu_custom_call" in _compile(one_chip, chunk, 32, 32, 248, layers=8)
+
+
+#: pages the chip's tiling pads, or heads no strided load takes: (query heads, key heads, lanes, dtype)
+PIPELINED = {
+    "falcon_7b_one_key_head_of_64_lanes": (71, 1, 64, jnp.bfloat16),
+    "opt_125m_12_heads_of_64_lanes": (12, 12, 64, jnp.bfloat16),
+    "phi_2_32_heads_of_80_lanes": (32, 32, 80, jnp.bfloat16),
+    "a_mixtral_shard_of_tensor_parallel_8": (4, 1, 128, jnp.bfloat16),
+    "six_key_heads": (12, 6, 128, jnp.bfloat16),
+    "three_key_heads_float32": (3, 3, 128, jnp.float32),
+    "heads_of_256_lanes": (8, 8, 256, jnp.bfloat16),
+}
+
+
+@pytest.mark.parametrize("chunk", [128, 1])
+@pytest.mark.parametrize("model", list(PIPELINED))
+def test_pages_the_kernel_cannot_copy_itself(one_chip, no_compile_cache, model, chunk):
+    """The chip's compiler refuses a kernel's own DMA out of a page its
+    tiling pads ("Slice shape along dimension 5 must be aligned to tiling
+    (128), but is 64"): for such a model the pipeline brings the pages, one
+    ``BlockSpec`` each, and the same kernel compiles."""
+    n_q, n_kv, d, dtype = PIPELINED[model]
+    assert not _copies_pages(n_kv, d, jnp.dtype(dtype).itemsize)
+    assert "tpu_custom_call" in _compile(one_chip, chunk, n_q, n_kv, 282, layers=3, d=d, dtype=dtype)
+
+
+@pytest.mark.parametrize("n_kv, dtype", [(2, jnp.bfloat16), (4, jnp.bfloat16), (16, jnp.bfloat16), (24, jnp.bfloat16),
+                                         (64, jnp.bfloat16), (1, jnp.float32), (2, jnp.float32), (24, jnp.float32)])
+def test_pages_the_kernel_copies_itself(one_chip, no_compile_cache, n_kv, dtype):
+    """Every count of key heads ``_copies_pages`` takes for whole tiles: the
+    compiler accepts the DMA out of the arena and the strided load."""
+    assert _copies_pages(n_kv, 128, jnp.dtype(dtype).itemsize)
+    assert "tpu_custom_call" in _compile(one_chip, 1, n_kv, n_kv, 100, layers=3, dtype=dtype)
+
+
+@pytest.mark.parametrize("n_kv, width", [(8, 776), (8, 282), (2, 776), (32, 248), (64, 100)])
+def test_the_blocks_scratch_stays_in_its_budget(n_kv, width):
+    """The two slots of the scratch, with the heads it pads, at the cells'
+    shapes: within the budget the block was chosen under (a count, no compile)."""
+    ppb = walk_block(16, width, n_kv, 128, 2)
+    assert ppb * 16 >= 128
+    assert 2 * ppb * 16 * 2 * _padded_heads(n_kv, 2) * 128 * 2 <= _BLOCK_BYTES
 
 
 @pytest.mark.parametrize("program", ["step_c128", "step_c1", "fused_2"])
