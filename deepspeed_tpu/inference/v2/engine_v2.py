@@ -27,7 +27,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from ...models.llama_cache import PagedKVConfig, init_kv_cache, reads_through_kernel, stack_layer_params
+from ...models.llama_cache import PagedKVConfig, reads_through_kernel, stack_layer_params
 from ...ops.paged_attention import walk_block
 from ...telemetry.step_anatomy import NULL_ANATOMY
 from ...utils.logging import logger
@@ -42,6 +42,16 @@ def build_cache_model(cfg, page_size: int):
     arch switch)."""
     from ...models.cache_zoo import cache_twin
     return cache_twin(cfg).model(cfg, page_size=page_size)
+
+
+def _init_cache(cfg, econfig):
+    """What the engine keeps as ``cache``, made by the configuration's twin:
+    the arena of pages, and where its geometry has state slots one a
+    sequence the scheduler may run and the scratch slot 0 beside them, each
+    sized for the scheduler's prefill chunk."""
+    from ...models.cache_zoo import cache_twin
+    return cache_twin(cfg).init_cache(cfg, econfig.kv, econfig.kv_dtype, econfig.scheduler.max_seqs + 1,
+                                      econfig.scheduler.prefill_chunk)
 
 
 def _table_width(cfg, kvcfg: PagedKVConfig) -> int:
@@ -94,10 +104,10 @@ def _make_step_fn(model, qparams, greedy: bool, temperature: float):
     def step(params, cache, tokens, start_pos, block_tables, chunk_lens, rng):
         if qparams is not None:
             params = {"params": qparams.dequantize(params["params"])}
-        logits, cache = model.apply(params, tokens, start_pos, block_tables, cache, chunk_lens)
-        # logits of each row's LAST real token
-        last = jnp.maximum(chunk_lens - 1, 0)
-        row_logits = jnp.take_along_axis(logits, last[:, None, None], axis=1)[:, 0]   # [B, V]
+        # logits of each row's LAST real token alone: the twin takes those rows
+        # out before its final norm and head (models/llama_cache.sampled_rows)
+        logits, cache = model.apply(params, tokens, start_pos, block_tables, cache, chunk_lens, True)
+        row_logits = logits[:, 0]                                                      # [B, V]
         if greedy:
             next_tok = jnp.argmax(row_logits, axis=-1)
         else:
@@ -146,7 +156,7 @@ def _named(fn, label: str):
     return fn
 
 
-def _serving_shardings(model, cfg, kvcfg, kv_dtype, mesh):
+def _serving_shardings(model, cfg, econfig, mesh):
     """TP shardings shared by the live engine (_setup_tp) and the AOT budget
     path: params via the logical-axis rules (zero_stage=0), the scanned KV
     arena [L, P, page, 2, n_kv, hd] over its kv-heads dim, host-side batch
@@ -156,16 +166,23 @@ def _serving_shardings(model, cfg, kvcfg, kv_dtype, mesh):
 
     from ...comm.mesh import TENSOR_AXIS
     from ...module_inject.tp_rules import param_shardings
-    cache_abs = jax.eval_shape(lambda: init_kv_cache(cfg, kvcfg, dtype=kv_dtype))
+    from ...models.cache_zoo import cache_geometry
+    kvcfg = econfig.kv
+    cache_abs = jax.eval_shape(lambda: _init_cache(cfg, econfig))
     toks1 = jnp.zeros((1, 1), jnp.int32)
     one = jnp.zeros((1, ), jnp.int32)
     bt1 = jnp.zeros((1, _table_width(cfg, kvcfg)), jnp.int32)
     abs_vars = jax.eval_shape(
-        lambda: model.init(jax.random.PRNGKey(0), toks1, one, bt1, cache_abs,
-                           jnp.ones((1, ), jnp.int32)))
+        lambda cache: model.init(jax.random.PRNGKey(0), toks1, one, bt1, cache, jnp.ones((1, ), jnp.int32)), cache_abs)
     param_sh = param_shardings(abs_vars, mesh, zero_stage=0)
-    cache_sh = NamedSharding(mesh, P(None, None, None, None, TENSOR_AXIS, None))
     repl = NamedSharding(mesh, P())
+    if cache_geometry(cfg, kvcfg.page_size).state_slots:
+        if mesh.shape.get(TENSOR_AXIS, 1) > 1:
+            raise NotImplementedError("tensor-parallel serving of a model with state slots: the slots' arrays "
+                                      "(rings, recurrent states) have no sharding rule yet")
+        cache_sh = jax.tree.map(lambda _: repl, cache_abs)
+    else:
+        cache_sh = NamedSharding(mesh, P(None, None, None, None, TENSOR_AXIS, None))
     return abs_vars, cache_abs, param_sh, cache_sh, repl
 
 
@@ -189,8 +206,7 @@ def compile_aot_serving(cfg, mesh, engine_config: RaggedInferenceEngineConfig = 
     eng_cfg = engine_config or RaggedInferenceEngineConfig()
     kvcfg = eng_cfg.kv
     model = build_cache_model(cfg, kvcfg.page_size)
-    abs_params, cache_abs, param_sh, cache_sh, r = _serving_shardings(
-        model, cfg, kvcfg, eng_cfg.kv_dtype, mesh)
+    abs_params, cache_abs, param_sh, cache_sh, r = _serving_shardings(model, cfg, eng_cfg, mesh)
     if fused_steps > 1:
         tokens_shape = (batch, )
         step = _named(_make_multi_fn(model, None, eng_cfg.greedy, eng_cfg.temperature, batch, fused_steps),
@@ -239,9 +255,13 @@ class InferenceEngineV2:
     """Continuous-batching engine over a model whose per-sequence state lives
     in pages of one arena: keys and values of every token for the
     softmax-attention families, a ring of exact rows plus summary rows for
-    chunked linear attention.  What a page holds, and how many pages ``n``
-    tokens need, is the geometry's (``self.kv.geometry``); the engine only
-    hands the model's step programs the block-table rows."""
+    chunked linear attention; and, where the geometry says so, in one state
+    slot a sequence beside the pages (rings of window layers, recurrent
+    states).  What a page holds, how many pages ``n`` tokens need and whether
+    there is a slot is the geometry's (``self.kv.geometry``); ``self.cache``
+    is whatever the configuration's twin makes to hold it (one array of
+    pages, or pages and slots: ``cache_zoo.CacheTwin.init_cache``); the
+    engine only hands the model's step programs the block-table rows."""
 
     def __init__(self, cfg, params, engine_config: RaggedInferenceEngineConfig = None,
                  rng: Optional[jax.Array] = None, mesh=None):
@@ -298,16 +318,20 @@ class InferenceEngineV2:
             self._qparams = None
             self.params = params
         from ...models.cache_zoo import cache_geometry
+        geometry = cache_geometry(cfg, kvcfg.page_size)
+        if self.econfig.spec is not None and geometry.state_slots:
+            raise NotImplementedError(f"speculative decoding over {type(geometry).__name__}: a rejected draft has "
+                                      "already advanced the slot's recurrent state, which cannot be rewound")
         self.kv = BlockedKVCache(kvcfg.num_pages, kvcfg.page_size, kvcfg.max_pages_per_seq,
-                                 enable_prefix_cache=self.econfig.enable_prefix_cache,
-                                 geometry=cache_geometry(cfg, kvcfg.page_size))
+                                 enable_prefix_cache=self.econfig.enable_prefix_cache, geometry=geometry,
+                                 state_slots=self.econfig.scheduler.max_seqs + 1)
         if self.econfig.spec is not None and not self.kv.geometry.pages_immutable:
             # a verify chunk may cross a window and its rollback cannot be undone
             raise NotImplementedError(f"speculative decoding over {type(self.kv.geometry).__name__} "
                                       "(pages rewritten in place) is not implemented")
         self.state = StateManager(self.kv, max_batch=self.econfig.scheduler.max_seqs)
         self.scheduler = SplitFuseScheduler(self.econfig.scheduler)
-        self.cache = init_kv_cache(cfg, kvcfg, dtype=self.econfig.kv_dtype)
+        self.cache = _init_cache(cfg, self.econfig)
         self.rng = rng if rng is not None else jax.random.PRNGKey(0)
         self._max_new: Dict[int, int] = {}
         self._step_fns: Dict[Tuple[int, int], callable] = {}
@@ -376,13 +400,13 @@ class InferenceEngineV2:
         from ...comm.mesh import TENSOR_AXIS
         mesh = self.mesh
         tp = mesh.shape.get(TENSOR_AXIS, 1)
-        n_kv = self.cache.shape[-2]
+        n_kv = self._pages().shape[-2]
         heads = self.cfg.num_attention_heads
         if tp > 1 and (n_kv % tp or heads % tp):
             raise ValueError(f"tensor_parallel={tp} must divide num_key_value_heads={n_kv} "
                              f"and num_attention_heads={heads}")
         _, _, self._param_sh, self._cache_sh, self._repl_sh = _serving_shardings(
-            self.model, self.cfg, self.econfig.kv, self.econfig.kv_dtype, mesh)
+            self.model, self.cfg, self.econfig, mesh)
         self.params = jax.device_put(self.params, self._param_sh)
         self.cache = jax.device_put(self.cache, self._cache_sh)
         logger.info(f"InferenceEngineV2: TP-sharded serving over tensor={tp} "
@@ -854,7 +878,8 @@ class InferenceEngineV2:
             anat.note_program(self._key_label(("multi", batch, k)), "multi_decode",
                               rows_decode=len(seqs), tokens_real=len(seqs) * k,
                               slots=batch * k, expert_rows=len(seqs) * k * self._experts_per_tok,
-                              cache_counts=self._cache_counts([(s, k) for s in seqs], calls=k))
+                              cache_counts=self._cache_counts([(s, k) for s in seqs], calls=k),
+                              state_counts=self._state_counts([(s, k) for s in seqs]))
         toks, self.cache = self._invoke(fn, self.params, self.cache, jnp.asarray(rb.tokens[:, 0]),
                                         jnp.asarray(rb.start_pos), jnp.asarray(rb.block_tables),
                                         jnp.asarray(rb.chunk_lens), sub)
@@ -902,19 +927,24 @@ class InferenceEngineV2:
             anat.mark("sample_accept")
         return out
 
+    def _pages(self):
+        """The arena of pages in ``self.cache``, which the paged kernel reads."""
+        from ...models.cache_zoo import cache_twin
+        return cache_twin(self.cfg).pages(self.cache)
+
     def _walk_rows(self) -> int:
         """Key rows a block of the paged kernel's walk holds, as the kernel
         chooses it for this engine's pages (a tensor-parallel shard's key
         heads); 0 where the twin's attention does not read through it."""
         cfg = self.cfg
-        if not reads_through_kernel(getattr(cfg, "attention_impl", None), getattr(cfg, "sliding_window", 0),
-                                    getattr(cfg, "alibi", False)):
+        if not reads_through_kernel(getattr(cfg, "attention_impl", None), getattr(cfg, "alibi", False)):
             return 0
         from ...comm.mesh import TENSOR_AXIS
-        *_, n_kv, d = self.cache.shape
+        pages = self._pages()
+        *_, n_kv, d = pages.shape
         tp = 1 if self.mesh is None else self.mesh.shape.get(TENSOR_AXIS, 1)
         return self.kv.page_size * walk_block(self.kv.page_size, self.kv.table_width, n_kv // tp, d,
-                                              self.cache.dtype.itemsize)
+                                              pages.dtype.itemsize)
 
     def _cache_counts(self, work, calls: int = 1) -> tuple:
         """The geometry's ``step_counts`` summed over a step's (seq, tokens)
@@ -924,6 +954,19 @@ class InferenceEngineV2:
         block_rows = self._walk_rows()
         counts = [self.kv.geometry.step_counts(s.seen_tokens, n, block_rows, calls) for s, n in work]
         return tuple(sum(c) for c in zip(*counts))
+
+    def _state_counts(self, work) -> dict:
+        """The step records' counts of a geometry with state slots (none
+        without): the geometry's ``state_counts`` summed over the step's
+        rows, and the slots that sequences hold."""
+        geometry = self.kv.geometry
+        if not geometry.state_slots:
+            return {}
+        total = {"state_slots_live": len(self.state.seqs)}
+        for s, n in work:
+            for name, count in geometry.state_counts(s.seen_tokens, n).items():
+                total[name] = total.get(name, 0) + count
+        return total
 
     def _bucket_batch(self, n: int) -> int:
         q = self.econfig.scheduler.decode_bucket
@@ -1051,7 +1094,7 @@ class InferenceEngineV2:
                               rows_decode=len(plan.decode), rows_prefill=len(plan.prefill),
                               tokens_real=tokens_real, slots=batch * chunk,
                               expert_rows=tokens_real * self._experts_per_tok,
-                              cache_counts=self._cache_counts(work))
+                              cache_counts=self._cache_counts(work), state_counts=self._state_counts(work))
         next_tok, self.cache = self._invoke(fn, self.params, self.cache, jnp.asarray(rb.tokens),
                                             jnp.asarray(rb.start_pos), jnp.asarray(rb.block_tables),
                                             jnp.asarray(rb.chunk_lens), sub)

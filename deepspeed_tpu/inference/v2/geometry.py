@@ -40,6 +40,8 @@ class LinearGeometry:
     #: a full page never changes again, so sequences with a common prefix
     #: may share it (the prefix cache) and a rewind is always possible
     pages_immutable = True
+    #: whether a sequence also holds a state slot (``SlotPagesGeometry``)
+    state_slots = False
 
     def __init__(self, page_size: int):
         self.page_size = int(page_size)
@@ -51,12 +53,21 @@ class LinearGeometry:
         """Columns a block-table row needs for a sequence of ``max_tokens``."""
         return self.pages_for(max_tokens)
 
+    def token_capacity(self, max_tokens: int) -> int:
+        """Tokens a sequence may hold where its row was sized for ``max_tokens``."""
+        return int(max_tokens)
+
     def slots(self, n_pages: int):
         return slice(0, n_pages)
 
     def rewind_floor(self, seen_tokens: int) -> int:
         """The shortest history ``truncate`` may still rewind to."""
         return 0
+
+    def state_counts(self, start: int, n_tokens: int) -> dict:
+        """Further named counts of the step records (``telemetry/step_anatomy.COUNTS``)
+        that feeding tokens ``start .. start + n_tokens - 1`` adds to; none here."""
+        return {}
 
     def chunk_limit(self, start: int, n_tokens: int) -> int:
         """How many of ``n_tokens`` one chunk starting at ``start`` may carry."""
@@ -85,6 +96,9 @@ class RingSummaryGeometry:
     benchmark's check) reads as a valid one."""
 
     pages_immutable = False
+    state_slots = False
+    token_capacity = LinearGeometry.token_capacity
+    state_counts = LinearGeometry.state_counts
 
     def __init__(self, page_size: int, window: int):
         if window % (page_size * page_size):
@@ -137,3 +151,38 @@ class RingSummaryGeometry:
         return ((start + n_tokens) // self.page_size - start // self.page_size,
                 int(np.count_nonzero((t % self.window == 0) & (t > 0))), int((seen + 1).sum()),
                 _rows_walked(seen, block_rows, calls))
+
+
+class SlotPagesGeometry(LinearGeometry):
+    """Pages for the one layer whose keys and values grow with the sequence,
+    laid out as the linear geometry's, plus one **state slot** a sequence for
+    everything of fixed size: the window layers' rings and the recurrent
+    layers' states (``models/phi4flash_cache.py``).  The slot's index rides
+    in the last column of the block-table row, so a row sized for ``n``
+    tokens holds a page less; slot 0 is scratch, as page 0 is the null page,
+    so a row built for the linear layout alone reads as a valid one.  The
+    slot is allocated with the sequence and released with it
+    (``ragged.StateManager``)."""
+
+    #: the pages never change, but a slot's state belongs to one sequence
+    #: and is not kept by position: nothing of it can be shared or rewound to
+    pages_immutable = False
+    state_slots = True
+
+    def __init__(self, page_size: int, window: int):
+        super().__init__(page_size)
+        self.window = int(window)
+
+    def token_capacity(self, max_tokens: int) -> int:
+        return (self.table_width(max_tokens) - 1) * self.page_size
+
+    def rewind_floor(self, seen_tokens: int) -> int:
+        """A recurrent state cannot be rewound at all."""
+        return int(seen_tokens)
+
+    def state_counts(self, start: int, n_tokens: int) -> dict:
+        """``ssm_rows``: token rows the scan advanced; ``window_rows_visible``:
+        key rows a window layer's queries could see, ``min(t + 1, window)``
+        summed over them (one layer each)."""
+        t = np.arange(start, start + n_tokens)
+        return {"ssm_rows": int(n_tokens), "window_rows_visible": int(np.minimum(t + 1, self.window).sum())}

@@ -31,9 +31,10 @@ class BlockedAllocator:
     :class:`PrefixCacheManager`; it returns to the free list only when the
     last reference drops."""
 
-    def __init__(self, num_pages: int):
+    def __init__(self, num_pages: int, what: str = "KV cache"):
         assert num_pages >= 2
         self.num_pages = num_pages
+        self.what = what
         self._free: List[int] = list(range(1, num_pages))
         self._rc = np.zeros(num_pages, np.int32)
 
@@ -43,7 +44,7 @@ class BlockedAllocator:
 
     def allocate(self, n: int) -> List[int]:
         if n > len(self._free):
-            raise RuntimeError(f"KV cache exhausted: need {n} pages, have {len(self._free)}")
+            raise RuntimeError(f"{self.what} exhausted: need {n} pages, have {len(self._free)}")
         pages, self._free = self._free[:n], self._free[n:]
         self._rc[pages] = 1
         return pages
@@ -97,6 +98,7 @@ class SequenceDescriptor:
     uid: int
     tokens: List[int]                      # full token history (prompt + generated)
     pages: List[int] = dataclasses.field(default_factory=list)
+    slot: int = 0                          # its state slot (0: the geometry has none)
     seen_tokens: int = 0                   # tokens whose KV is in cache
     generated: List[int] = dataclasses.field(default_factory=list)
     done: bool = False
@@ -381,14 +383,19 @@ class BlockedKVCache:
     width of a block-table row."""
 
     def __init__(self, num_pages: int, page_size: int, max_pages_per_seq: int,
-                 enable_prefix_cache: bool = True, geometry=None):
+                 enable_prefix_cache: bool = True, geometry=None, state_slots: int = 0):
         self.num_pages = num_pages
         self.page_size = page_size
         self.geometry = geometry if geometry is not None else LinearGeometry(page_size)
-        self.max_tokens_per_seq = max_pages_per_seq * page_size
+        self.table_width = self.geometry.table_width(max_pages_per_seq * page_size)
+        self.max_tokens_per_seq = self.geometry.token_capacity(max_pages_per_seq * page_size)
         self.max_pages_per_seq = self.geometry.pages_for(self.max_tokens_per_seq)
-        self.table_width = self.geometry.table_width(self.max_tokens_per_seq)
         self.allocator = BlockedAllocator(num_pages)
+        #: ``state_slots`` slots of per-sequence state beside the pages (slot
+        #: 0 is scratch), where the geometry has them; else None
+        self.slot_allocator = BlockedAllocator(state_slots, "state slots") if self.geometry.state_slots else None
+        if enable_prefix_cache:
+            self.refuse_state_slots("the prefix cache")
         if enable_prefix_cache and not self.geometry.pages_immutable:
             # the hash-to-page map would hand out a page that its owner rewrites
             raise ValueError(f"{type(self.geometry).__name__} rewrites pages in place: "
@@ -412,6 +419,15 @@ class BlockedKVCache:
     def release(self, seq: SequenceDescriptor) -> None:
         self.allocator.free(seq.pages)
         seq.pages = []
+        if seq.slot:
+            self.slot_allocator.free([seq.slot])
+            seq.slot = 0
+
+    def refuse_state_slots(self, what: str) -> None:
+        if self.geometry.state_slots:
+            raise NotImplementedError(f"{what} over {type(self.geometry).__name__}: a sequence's pages are half of "
+                                      "its state; its slot (rings, recurrent states) would have to be kept or "
+                                      "travel as a second block")
 
     def export_pages(self, arena, pages: Sequence[int]) -> np.ndarray:
         """Stage the KV blocks of ``pages`` device→host (the serving analog
@@ -421,6 +437,7 @@ class BlockedKVCache:
         returned block is ``[L, len(pages), page, 2, n_kv, hd]``.  Page ids
         are validated against the arena geometry — exporting the reserved
         null page (0) or an out-of-range id is a caller bug, not data."""
+        self.refuse_state_slots("export_pages")
         idx = np.asarray(list(pages), np.int64)
         if idx.size and not ((idx > 0) & (idx < self.num_pages)).all():
             raise ValueError(f"export_pages: page ids out of range: {idx.tolist()}")
@@ -436,6 +453,7 @@ class BlockedKVCache:
         must match the arena's per-page geometry and dtype exactly; a
         mismatched snapshot is rejected here rather than silently cast
         (KV bytes from a different geometry are garbage, not data)."""
+        self.refuse_state_slots("import_pages")
         idx = np.asarray(list(pages), np.int64)
         if idx.size and not ((idx > 0) & (idx < self.num_pages)).all():
             raise ValueError(f"import_pages: page ids out of range: {idx.tolist()}")
@@ -547,6 +565,10 @@ class StateManager:
     def get_or_create(self, uid: int, tokens: Optional[Sequence[int]] = None) -> SequenceDescriptor:
         if uid not in self.seqs:
             seq = SequenceDescriptor(uid=uid, tokens=list(tokens or []))
+            if self.kv.slot_allocator is not None:
+                # with the sequence, released with it (flush, preempt); the
+                # admission controller counts free slots, so none is a caller's bug
+                seq.slot = self.kv.slot_allocator.allocate(1)[0]
             pc = self.kv.prefix_cache
             if pc is not None and seq.tokens:
                 # reuse cached KV pages for the shared prompt prefix: the
@@ -589,13 +611,14 @@ class StateManager:
         return self.kv.release_tail(seq, self.kv.geometry.pages_for(n_tokens))
 
     def flush(self, uid: int) -> None:
-        """Release a sequence's KV + state (ref: engine_v2.py flush)."""
+        """Release a sequence's KV pages and state slot (ref: engine_v2.py flush)."""
         seq = self.seqs.pop(uid, None)
         if seq is not None:
             self.kv.release(seq)
 
     def preempt(self, uid: int) -> SequenceDescriptor:
-        """KV-pressure eviction: release ``uid``'s pages and drop its state,
+        """KV-pressure eviction: release ``uid``'s pages (and its state slot:
+        a resumed sequence is prefilled again from its tokens) and drop its state,
         returning the descriptor so the serving frontend can requeue the
         request with its generated tokens preserved.  Full pages the
         sequence published to the prefix cache keep the cache's refcount and
@@ -625,6 +648,8 @@ class StateManager:
             tokens[i, :len(sl)] = sl
             start_pos[i] = seq.seen_tokens
             block_tables[i, self.kv.geometry.slots(len(seq.pages))] = seq.pages
+            if seq.slot:
+                block_tables[i, -1] = seq.slot     # the row's last column (geometry.SlotPagesGeometry)
             chunk_lens[i] = n
             uids[i] = seq.uid
         return RaggedBatch(tokens=tokens, start_pos=start_pos, block_tables=block_tables,

@@ -17,10 +17,11 @@ import jax
 import jax.numpy as jnp
 from flax import linen as nn
 
-from ..inference.v2.geometry import LinearGeometry, RingSummaryGeometry
+from ..inference.v2.geometry import LinearGeometry, RingSummaryGeometry, SlotPagesGeometry
 from .llama import (EMBED, HEAD_DIM, HEADS, KV_HEADS, MLP, VOCAB, LlamaConfig, RMSNorm, _logical, apply_rope,
                     rotary_embedding)
-from .llama_cache import LlamaForCausalLMWithCache, paged_attention_core, scan_blocks
+from .llama_cache import (LlamaForCausalLMWithCache, init_kv_cache, paged_attention_core, sampled_rows,
+                          scan_blocks)
 from .evabyte import EvaByteConfig
 from .evabyte_cache import EvaByteForCausalLMWithCache
 from .falcon import FalconConfig
@@ -28,6 +29,9 @@ from .mixtral import MixtralConfig
 from .mixtral_cache import MixtralForCausalLMWithCache
 from .opt import OPTConfig
 from .phi import PhiConfig, apply_partial_rope
+from .phi4flash import Phi4FlashConfig
+from .phi4flash_cache import Phi4FlashForCausalLMWithCache
+from .phi4flash_cache import init_cache as init_phi4flash_cache
 from .qwen2_moe import Qwen2MoeConfig, Qwen2MoeSparseMLP
 
 
@@ -113,7 +117,7 @@ class FalconForCausalLMWithCache(nn.Module):
     page_size: int = 16
 
     @nn.compact
-    def __call__(self, input_ids, start_pos, block_table, cache, chunk_lens=None):
+    def __call__(self, input_ids, start_pos, block_table, cache, chunk_lens=None, last_only=False):
         cfg = self.cfg
         positions = start_pos[:, None] + jnp.arange(input_ids.shape[1])[None, :]
         embed = nn.Embed(cfg.vocab_size, cfg.hidden_size, dtype=cfg.dtype, param_dtype=cfg.param_dtype,
@@ -122,6 +126,7 @@ class FalconForCausalLMWithCache(nn.Module):
         x = embed(input_ids)
         (x, cache), _ = scan_blocks(FalconBlockCache, cfg.num_hidden_layers)(cfg, self.page_size, name="h")(
             (x, cache), jnp.arange(cfg.num_hidden_layers), positions, block_table, start_pos, chunk_lens)
+        x = sampled_rows(x, chunk_lens, last_only)
         x = nn.LayerNorm(epsilon=cfg.layer_norm_epsilon, dtype=cfg.dtype, param_dtype=cfg.param_dtype,
                          name="ln_f")(x)
         if cfg.tie_word_embeddings:
@@ -193,7 +198,7 @@ class OPTForCausalLMWithCache(nn.Module):
     page_size: int = 16
 
     @nn.compact
-    def __call__(self, input_ids, start_pos, block_table, cache, chunk_lens=None):
+    def __call__(self, input_ids, start_pos, block_table, cache, chunk_lens=None, last_only=False):
         cfg = self.cfg
         positions = start_pos[:, None] + jnp.arange(input_ids.shape[1])[None, :]
         proj_dim = cfg.word_embed_proj_dim or cfg.hidden_size
@@ -213,6 +218,7 @@ class OPTForCausalLMWithCache(nn.Module):
         x = x + pos_embed(safe_pos + 2)
         (x, cache), _ = scan_blocks(OPTBlockCache, cfg.num_hidden_layers)(cfg, self.page_size, name="layers")(
             (x, cache), jnp.arange(cfg.num_hidden_layers), positions, block_table, start_pos, chunk_lens)
+        x = sampled_rows(x, chunk_lens, last_only)
         if cfg.do_layer_norm_before:
             x = nn.LayerNorm(epsilon=1e-5, dtype=cfg.dtype, param_dtype=cfg.param_dtype,
                              name="final_layer_norm")(x)
@@ -290,7 +296,7 @@ class PhiForCausalLMWithCache(nn.Module):
     page_size: int = 16
 
     @nn.compact
-    def __call__(self, input_ids, start_pos, block_table, cache, chunk_lens=None):
+    def __call__(self, input_ids, start_pos, block_table, cache, chunk_lens=None, last_only=False):
         cfg = self.cfg
         positions = start_pos[:, None] + jnp.arange(input_ids.shape[1])[None, :]
         embed = nn.Embed(cfg.vocab_size, cfg.hidden_size, dtype=cfg.dtype, param_dtype=cfg.param_dtype,
@@ -299,6 +305,7 @@ class PhiForCausalLMWithCache(nn.Module):
         x = embed(input_ids)
         (x, cache), _ = scan_blocks(PhiBlockCache, cfg.num_hidden_layers)(cfg, self.page_size, name="layers")(
             (x, cache), jnp.arange(cfg.num_hidden_layers), positions, block_table, start_pos, chunk_lens)
+        x = sampled_rows(x, chunk_lens, last_only)
         x = nn.LayerNorm(epsilon=cfg.layer_norm_eps, dtype=cfg.dtype, param_dtype=cfg.param_dtype,
                          name="final_layernorm")(x)
         logits = nn.Dense(cfg.vocab_size, use_bias=True, dtype=cfg.dtype, param_dtype=cfg.param_dtype,
@@ -336,7 +343,7 @@ class Qwen2MoeForCausalLMWithCache(nn.Module):
     page_size: int = 16
 
     @nn.compact
-    def __call__(self, input_ids, start_pos, block_table, cache, chunk_lens=None):
+    def __call__(self, input_ids, start_pos, block_table, cache, chunk_lens=None, last_only=False):
         cfg = self.cfg
         positions = start_pos[:, None] + jnp.arange(input_ids.shape[1])[None, :]
         embed = nn.Embed(cfg.vocab_size, cfg.hidden_size, dtype=cfg.dtype, param_dtype=cfg.param_dtype,
@@ -356,6 +363,7 @@ class Qwen2MoeForCausalLMWithCache(nn.Module):
             (x, cache), _ = scan_blocks(Qwen2MoeBlockCache, cfg.num_hidden_layers)(
                 cfg, self.page_size, name="layers")((x, cache), jnp.arange(cfg.num_hidden_layers), positions,
                                                     block_table, start_pos, chunk_lens)
+        x = sampled_rows(x, chunk_lens, last_only)
         x = RMSNorm(cfg.rms_norm_eps, cfg.dtype, cfg.param_dtype, name="norm")(x)
         if cfg.tie_word_embeddings:
             return embed.attend(x), cache
@@ -370,9 +378,15 @@ class Qwen2MoeForCausalLMWithCache(nn.Module):
 class CacheTwin:
     """What serves a configuration: ``model(cfg, page_size=)`` builds its
     paged-cache twin, ``geometry(cfg, page_size)`` says what a page of its
-    arena holds (inference/v2/geometry.py)."""
+    arena holds and whether a sequence holds a state slot besides
+    (inference/v2/geometry.py), ``init_cache(cfg, kv, dtype, n_slots, chunk)`` makes
+    what the engine keeps as ``eng.cache`` and hands the twin: the one arena
+    of pages [L, P, page, 2, n_kv, hd], or pages and state slots together,
+    of which ``pages(cache)`` is the arena the paged kernel reads."""
     model: Callable
     geometry: Callable = lambda cfg, page_size: LinearGeometry(page_size)
+    init_cache: Callable = lambda cfg, kv, dtype, n_slots, chunk: init_kv_cache(cfg, kv, dtype=dtype)
+    pages: Callable = lambda cache: cache
 
 
 def _dropless_mixtral(cfg, page_size):
@@ -394,6 +408,9 @@ CACHE_MODEL_REGISTRY = {
     Qwen2MoeConfig: CacheTwin(Qwen2MoeForCausalLMWithCache),
     EvaByteConfig: CacheTwin(EvaByteForCausalLMWithCache,
                              lambda cfg, page_size: RingSummaryGeometry(page_size, cfg.window_size)),
+    Phi4FlashConfig: CacheTwin(Phi4FlashForCausalLMWithCache,
+                               lambda cfg, page_size: SlotPagesGeometry(page_size, cfg.sliding_window),
+                               init_phi4flash_cache, lambda cache: cache["pages"]),
 }
 
 

@@ -42,7 +42,7 @@ from flax import linen as nn
 
 from .evabyte import EvaByteConfig, EvaByteHead, EvaProjections, eva_embed, eva_norm, summarise_chunks
 from .llama import LlamaMLP
-from .llama_cache import _write_pages, paged_attention, reads_through_kernel, scan_blocks
+from .llama_cache import _write_pages, paged_attention, reads_through_kernel, sampled_rows, scan_blocks
 
 
 def _summarise_completed(arena, layer, block_table, start_pos, chunk_lens, width, phi, mu, page_size, ring):
@@ -113,7 +113,7 @@ class EvaByteForCausalLMWithCache(nn.Module):
     page_size: int = 16
 
     @nn.compact
-    def __call__(self, input_ids, start_pos, block_table, cache, chunk_lens=None):
+    def __call__(self, input_ids, start_pos, block_table, cache, chunk_lens=None, last_only=False):
         cfg = self.cfg
         if self.page_size != cfg.chunk_size:
             raise ValueError(f"EvaByte's chunk is its page: page_size {self.page_size} != chunk_size {cfg.chunk_size}")
@@ -123,4 +123,5 @@ class EvaByteForCausalLMWithCache(nn.Module):
         x = eva_embed(cfg)(input_ids).astype(jnp.float32)
         (x, cache), _ = scan_blocks(EvaByteBlockCache, cfg.num_hidden_layers)(cfg, self.page_size, name="layers")(
             (x, cache), jnp.arange(cfg.num_hidden_layers), positions, block_table, start_pos, chunk_lens)
+        x = sampled_rows(x, chunk_lens, last_only)
         return EvaByteHead(cfg, name="lm_head")(eva_norm(cfg, "norm", jnp.float32)(x), 1)[..., 0, :], cache

@@ -105,7 +105,7 @@ def _write_pages(pages, k_new, v_new, block_table, start_pos, page_size, chunk_l
 
 
 def paged_attention(q, pages, block_table, start_pos, chunk_lens, page_size, sliding_window=0,
-                    alibi_slopes=None):
+                    alibi_slopes=None, scale=None):
     """Attention of a chunk's queries against (history + chunk) keys.
 
     q: [B, C, H, hd] (RoPE applied); pages: [P, page, 2, n_kv, hd] with the
@@ -129,7 +129,7 @@ def paged_attention(q, pages, block_table, start_pos, chunk_lens, page_size, sli
         rep = h // n_kv
         k = jnp.repeat(k, rep, axis=2)
         v = jnp.repeat(v, rep, axis=2)
-    scale = 1.0 / jnp.sqrt(d).astype(jnp.float32)
+    scale = 1.0 / jnp.sqrt(d).astype(jnp.float32) if scale is None else jnp.float32(scale)
     logits = jnp.einsum("bcnd,bknd->bnck", q.astype(jnp.float32), k.astype(jnp.float32)) * scale
     qpos = start_pos[:, None] + jnp.arange(c)[None, :]                # [B, C]
     kpos = jnp.arange(max_pages * page_size)[None, :]                 # [1, S_kv]
@@ -150,11 +150,12 @@ def paged_attention(q, pages, block_table, start_pos, chunk_lens, page_size, sli
     return out
 
 
-def reads_through_kernel(attention_impl, sliding_window=0, alibi=False) -> bool:
+def reads_through_kernel(attention_impl, alibi=False) -> bool:
     """Whether a twin's attention reads its pages through ``ds_paged_attention``
-    (window masks and alibi bias go through the jnp form: in-kernel variants
-    land with the kernel).  The engine asks too, for its step records."""
-    return attention_impl == "flash" and not sliding_window and not alibi
+    (a window does: the kernel takes a first visible row as well as a last;
+    alibi bias goes through the jnp form).  The engine asks too, for its
+    step records."""
+    return attention_impl == "flash" and not alibi
 
 
 def paged_attention_core(q, k, v, pages, block_table, start_pos, chunk_lens, page_size,
@@ -166,14 +167,29 @@ def paged_attention_core(q, k, v, pages, block_table, start_pos, chunk_lens, pag
     the whole arena (``_write_pages``).  Returns (out [B, C, H, D], new_pages)."""
     pages = _write_pages(pages, k.astype(pages.dtype), v.astype(pages.dtype), block_table,
                          start_pos, page_size, chunk_lens, layer=layer)
-    if reads_through_kernel(attention_impl, sliding_window, alibi_slopes is not None):
+    if reads_through_kernel(attention_impl, alibi_slopes is not None):
         from ..ops.paged_attention import paged_attention_pallas
-        out = paged_attention_pallas(q, pages, block_table, start_pos, chunk_lens, page_size, layer=layer)
+        out = paged_attention_pallas(q, pages, block_table, start_pos, chunk_lens, page_size, layer=layer,
+                                     window=sliding_window)
     else:
         # out of the whole arena the jnp form reads a layer's slice, 1/L of an arena
         out = paged_attention(q, pages if layer is None else pages[layer], block_table, start_pos, chunk_lens,
                               page_size, sliding_window=sliding_window, alibi_slopes=alibi_slopes)
     return out, pages
+
+
+def sampled_rows(x, chunk_lens, last_only):
+    """What of a trunk's output [B, C, H] goes on to the final norm and the
+    head: with ``last_only`` each row's last real token alone, [B, 1, H].  The
+    engine's step programs sample from nothing else, and a head over every
+    slot of a mixed step is its largest product and buffer where the
+    vocabulary is large; the logits of every position are for who compares
+    them (the verify program, the benchmark's check, the tests)."""
+    if not last_only:
+        return x
+    if chunk_lens is None:
+        return x[:, -1:]
+    return jnp.take_along_axis(x, jnp.maximum(chunk_lens - 1, 0)[:, None, None], axis=1)
 
 
 def stack_layer_params(variables, num_layers):
@@ -270,12 +286,14 @@ def scan_blocks(block_cls, num_layers, n_broadcast=4):
 
 class LlamaForCausalLMWithCache(nn.Module):
     """Chunked forward with paged KV.  ``apply(variables, tokens, start_pos,
-    block_table, cache)`` → (logits, new_cache)."""
+    block_table, cache, chunk_lens, last_only)`` → (logits, new_cache); the
+    logits of every position, or with ``last_only`` of each row's last real
+    token ([B, 1, V]: ``sampled_rows``).  Every twin's contract."""
     cfg: LlamaConfig
     page_size: int = 16
 
     @nn.compact
-    def __call__(self, input_ids, start_pos, block_table, cache, chunk_lens=None):
+    def __call__(self, input_ids, start_pos, block_table, cache, chunk_lens=None, last_only=False):
         cfg = self.cfg
         positions = start_pos[:, None] + jnp.arange(input_ids.shape[1])[None, :]
         embed = nn.Embed(num_embeddings=cfg.vocab_size,
@@ -300,6 +318,7 @@ class LlamaForCausalLMWithCache(nn.Module):
                 return x, cache
 
         x, cache = _Trunk(cfg, self.page_size, name="model")(x, cache, positions, block_table, start_pos, chunk_lens)
+        x = sampled_rows(x, chunk_lens, last_only)
         x = RMSNorm(cfg.rms_norm_eps, cfg.dtype, cfg.param_dtype, name="norm")(x)
         if cfg.tie_word_embeddings:
             logits = embed.attend(x)

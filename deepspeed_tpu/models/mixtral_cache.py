@@ -20,7 +20,7 @@ from flax import linen as nn
 
 from ..moe.layer import MoE
 from .llama import EMBED, VOCAB, RMSNorm, _logical
-from .llama_cache import LlamaAttentionCache, scan_blocks
+from .llama_cache import LlamaAttentionCache, sampled_rows, scan_blocks
 from .mixtral import MixtralConfig
 
 
@@ -76,7 +76,7 @@ class MixtralForCausalLMWithCache(nn.Module):
         return banks if all(w.dtype == self.cfg.dtype for w in banks) else None
 
     @nn.compact
-    def __call__(self, input_ids, start_pos, block_table, cache, chunk_lens=None):
+    def __call__(self, input_ids, start_pos, block_table, cache, chunk_lens=None, last_only=False):
         cfg = self.cfg
         positions = start_pos[:, None] + jnp.arange(input_ids.shape[1])[None, :]
         embed = nn.Embed(num_embeddings=cfg.vocab_size,
@@ -91,6 +91,7 @@ class MixtralForCausalLMWithCache(nn.Module):
         (x, cache), _ = scan_blocks(MixtralBlockCache, cfg.num_hidden_layers, n_broadcast=5)(
             cfg, self.page_size, name="layers")((x, cache), jnp.arange(cfg.num_hidden_layers), positions,
                                                 block_table, start_pos, chunk_lens, self._stacked_banks())
+        x = sampled_rows(x, chunk_lens, last_only)
         x = RMSNorm(cfg.rms_norm_eps, cfg.dtype, cfg.param_dtype, name="norm")(x)
         logits = nn.DenseGeneral(features=cfg.vocab_size,
                                  use_bias=False,

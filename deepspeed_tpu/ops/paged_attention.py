@@ -135,7 +135,13 @@ def _last_page(start, n_tok, page_size, width):
     return jnp.clip((start + n_tok - 1) // page_size, 0, width - 1)
 
 
-def _paged_kernel(bt_ref, sp_ref, cl_ref, ly_ref, q_ref, *refs, page_size, ppb, copies, rep, tile, scale):
+def _first_block(start, window, block):
+    """The first block of a row's walk: with a ``window`` the one that holds
+    the first key the row's first query may see, else block 0."""
+    return jnp.maximum(start - window + 1, 0) // block if window else 0
+
+
+def _paged_kernel(bt_ref, sp_ref, cl_ref, ly_ref, q_ref, *refs, page_size, ppb, copies, rep, tile, scale, window):
     """``refs``: where the kernel ``copies`` its pages, the arena in HBM, the
     output, the scratch ``[2, pages a block, page, 2, n_kv (padded), D]`` and
     its two DMA semaphores (the row's one grid step walks all its blocks);
@@ -152,6 +158,7 @@ def _paged_kernel(bt_ref, sp_ref, cl_ref, ly_ref, q_ref, *refs, page_size, ppb, 
     start, n_tok = sp_ref[b], cl_ref[b]
     last_page = _last_page(start, n_tok, page_size, bt_ref.shape[1])      # of the row's last visible key
     n_blocks = jnp.where(n_tok > 0, last_page // ppb + 1, 0)
+    first_block = _first_block(start, window, block)
     live_tiles = (n_tok * rep + tile - 1) // tile      # tiles with a row that carries a token
 
     def rows_of(t):
@@ -184,7 +191,14 @@ def _paged_kernel(bt_ref, sp_ref, cl_ref, ly_ref, q_ref, *refs, page_size, ppb, 
                 # row i of the position-major q block is chunk position i // rep;
                 # the last key it may see, counted from this block's first
                 sees = start - j * block + (t * tile + row) // rep
-                s = jnp.where(key <= sees, s, DEFAULT_MASK_VALUE)
+                seen = key <= sees
+                if window:
+                    # ... and the first: a query sees ``window`` keys, its own
+                    # among them.  A tile whose keys of this block all lie
+                    # before it takes the finite mask value as its maximum,
+                    # which the first real score wipes out (alpha = 0)
+                    seen = seen & (key > sees - window)
+                s = jnp.where(seen, s, DEFAULT_MASK_VALUE)
                 m_prev = m_ref[h, r]
                 m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
                 p = jnp.exp(s - m_new)
@@ -218,7 +232,7 @@ def _paged_kernel(bt_ref, sp_ref, cl_ref, ly_ref, q_ref, *refs, page_size, ppb, 
 
         @pl.when(n_blocks > 0)
         def _first():
-            fetch(0, 0)
+            fetch(first_block, first_block % 2)
 
         def walk(j, _):
             slot = j % 2
@@ -231,9 +245,9 @@ def _paged_kernel(bt_ref, sp_ref, cl_ref, ly_ref, q_ref, *refs, page_size, ppb, 
                 page_copy(j, i, slot).wait()
             block_step(j, buf.at[slot])
 
-        jax.lax.fori_loop(0, n_blocks, walk, None)
+        jax.lax.fori_loop(first_block, n_blocks, walk, None)
     else:
-        @pl.when(g < n_blocks)
+        @pl.when((g >= first_block) & (g < n_blocks))
         def _fed():
             block_step(g, fed)
 
@@ -258,7 +272,8 @@ def _paged_kernel(bt_ref, sp_ref, cl_ref, ly_ref, q_ref, *refs, page_size, ppb, 
         jax.lax.fori_loop(0, n_tiles, finish, None)
 
 
-def _paged_sharded(q, pages, block_table, start_pos, chunk_lens, page_size, interpret, mesh, layer=None):
+def _paged_sharded(q, pages, block_table, start_pos, chunk_lens, page_size, interpret, mesh, layer=None,
+                   window=0, scale=None):
     """Run the paged kernel inside shard_map over the governing (trace) mesh.
 
     Mosaic custom calls cannot be auto-partitioned by GSPMD — the TP-sharded
@@ -285,7 +300,7 @@ def _paged_sharded(q, pages, block_table, start_pos, chunk_lens, page_size, inte
     def local(q_, pg_, bt_, sp_, *rest):
         kw = dict(zip(given, rest))
         return paged_attention_pallas(q_, pg_, bt_, sp_, kw.get("chunk_lens"), page_size,
-                                      layer=kw.get("layer"), interpret=interpret)
+                                      layer=kw.get("layer"), window=window, scale=scale, interpret=interpret)
 
     fn = jax.shard_map(
         local,
@@ -298,7 +313,8 @@ def _paged_sharded(q, pages, block_table, start_pos, chunk_lens, page_size, inte
 
 
 def paged_attention_pallas(q, pages, block_table, start_pos, chunk_lens, page_size,
-                           *, layer=None, interpret: Optional[bool] = None):
+                           *, layer=None, window: int = 0, scale: Optional[float] = None,
+                           interpret: Optional[bool] = None):
     """Drop-in twin of ``models/llama_cache.paged_attention`` (jnp golden).
 
     q: [B, C, H, D]; pages: [P, page, 2, n_kv, D] (chunk K/V already
@@ -307,7 +323,10 @@ def paged_attention_pallas(q, pages, block_table, start_pos, chunk_lens, page_si
     (an index, traced in a scanned trunk) ``pages`` is the whole arena
     [L, P, page, 2, n_kv, D] and the kernel reads that layer's pages where
     they lie: no layer of the arena is sliced out first.  Query rows at and
-    past a row's ``chunk_lens`` come out exactly zero.
+    past a row's ``chunk_lens`` come out exactly zero.  With ``window`` (a
+    static count) the query at position ``t`` sees keys ``t - window + 1 ..
+    t`` and the walk starts at the block that holds the row's first visible
+    key; ``scale`` multiplies the scores in place of ``1 / sqrt(D)``.
     """
     from ..comm.mesh import get_trace_mesh, in_manual_mesh
     if interpret is None:
@@ -318,7 +337,7 @@ def paged_attention_pallas(q, pages, block_table, start_pos, chunk_lens, page_si
         mesh = get_trace_mesh()
         if mesh is not None and mesh.size > 1:
             return _paged_sharded(q, pages, block_table, start_pos, chunk_lens, page_size,
-                                  interpret, mesh, layer)
+                                  interpret, mesh, layer, window, scale)
     b, c, h, d = q.shape
     n_kv = pages.shape[-2]
     rep = h // n_kv
@@ -356,7 +375,9 @@ def paged_attention_pallas(q, pages, block_table, start_pos, chunk_lens, page_si
             def index(b, g, bt, sp, cl, ly):
                 # clamped to the row's last page: past it the same page again,
                 # which the pipeline does not fetch twice
-                column = jnp.minimum(g * ppb + i, _last_page(sp[b], cl[b], page_size, width))
+                # ... and before its first block that block's, fetched once
+                first = _first_block(sp[b], window, ppb * page_size)
+                column = jnp.minimum(jnp.maximum(g, first) * ppb + i, _last_page(sp[b], cl[b], page_size, width))
                 return ly[0], bt[b, column], 0, 0, 0, 0
 
             return pl.BlockSpec((None, None, page_size, 2, n_kv, d), index)
@@ -367,7 +388,7 @@ def paged_attention_pallas(q, pages, block_table, start_pos, chunk_lens, page_si
     q_block = n_kv * padded * lanes(d) * q.dtype.itemsize
     vmem = n_kv * padded * (lanes(d) + 2 * 128) * 4 + 2 * ppb * page_size * 2 * n_pad * lanes(d) * itemsize
     kernel = functools.partial(_paged_kernel, page_size=page_size, ppb=ppb, copies=copies, rep=rep, tile=tile,
-                               scale=1.0 / (d**0.5))
+                               scale=1.0 / (d**0.5) if scale is None else float(scale), window=int(window or 0))
     out = pl.pallas_call(
         kernel,
         grid_spec=pltpu.PrefetchScalarGridSpec(

@@ -106,6 +106,10 @@ class AdmissionController:
         if len(self.engine.state.seqs) >= self.engine.state.max_batch:
             return False
         kv = self.engine.kv
+        if kv.slot_allocator is not None and kv.slot_allocator.free_pages < 1:
+            # a sequence holds a state slot from its start to its flush or
+            # preemption: with none free the request waits in the queue
+            return False
         need = self._start_pages(req) + self.config.kv_headroom_pages + reserved_pages
         shortfall = need - kv.allocator.free_pages
         if shortfall > 0 and kv.prefix_cache is not None \

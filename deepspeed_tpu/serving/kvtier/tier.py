@@ -313,6 +313,7 @@ class TieredKVManager:
 
     def __init__(self, engine, config: Optional[TierConfig] = None,
                  metrics=None):
+        engine.kv.refuse_state_slots("HostKVTier")
         self.engine = engine          # the InferenceEngineV2
         self.config = config or TierConfig()
         self.metrics = metrics

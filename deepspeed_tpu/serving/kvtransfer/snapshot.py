@@ -141,6 +141,7 @@ class KVExporter:
             raise ValueError(f"chunk_pages must be >= 1, got {chunk_pages}")
         seq = engine.state.seqs[uid]
         kv = engine.kv
+        kv.refuse_state_slots("KVSnapshot export")
         arena = engine.cache
         self.engine = engine
         self.uid = uid
@@ -224,6 +225,7 @@ def import_snapshot(engine, uid: int, tokens: Sequence[int],
     _fi.check("kv.import")   # chaos site: crash/device-loss mid-import
     snapshot.verify()
     kv = engine.kv
+    kv.refuse_state_slots("KVSnapshot import")
     arena = engine.cache
     _validate_arena(snapshot, kv, arena)
     if list(snapshot.tokens) != [int(t) for t in tokens]:

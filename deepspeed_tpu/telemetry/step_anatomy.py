@@ -119,7 +119,7 @@ HOST_SEGMENTS = ("admit", "schedule", "draft_plan", "verify_plan",
 #: what a step carried; zero until the engine notes them
 COUNTS = ("rows_decode", "rows_prefill", "tokens_real", "slots", "tokens_out",
           "tokens_discarded", "expert_rows", "summary_rows_written", "ring_wraps",
-          "attn_rows_visible", "attn_rows_walked")
+          "attn_rows_visible", "attn_rows_walked", "state_slots_live", "ssm_rows", "window_rows_visible")
 
 #: names of the instant profiler events, built once (a mark allocates no string)
 _MARK_NAMES = {s: "ds.mark." + s for s in HOST_SEGMENTS + ("device_wait", )}
@@ -148,6 +148,7 @@ class StepRecord:
         self.expert_rows = 0
         self.summary_rows_written = self.ring_wraps = 0
         self.attn_rows_visible = self.attn_rows_walked = 0
+        self.state_slots_live = self.ssm_rows = self.window_rows_visible = 0
 
     def host_s(self) -> float:
         return sum(self.segments.values())
@@ -290,7 +291,7 @@ class StepAnatomy:
     def note_program(self, key: str, path: str, rows_decode: int = 0,
                      rows_prefill: int = 0, tokens_real: int = 0,
                      slots: int = 0, expert_rows: int = 0,
-                     cache_counts: tuple = (0, 0, 0, 0)) -> None:
+                     cache_counts: tuple = (0, 0, 0, 0), state_counts: Optional[dict] = None) -> None:
         """Tag the open step with the program it dispatches (``key``, as
         ``InferenceEngineV2._key_label`` prints it: the attribution key)
         and what the packed batch carries (``cache_counts``: the geometry's
@@ -306,6 +307,8 @@ class StepAnatomy:
             cur.expert_rows = int(expert_rows)
             (cur.summary_rows_written, cur.ring_wraps, cur.attn_rows_visible,
              cur.attn_rows_walked) = (int(c) for c in cache_counts)
+            for name, count in (state_counts or {}).items():
+                setattr(cur, name, int(count))
 
     def note_tokens(self, out: int, discarded: int = 0, real: int = 0,
                     expert_rows: int = 0) -> None:
@@ -563,7 +566,7 @@ class NullStepAnatomy:
         pass
 
     def note_program(self, key, path, rows_decode=0, rows_prefill=0,
-                     tokens_real=0, slots=0, expert_rows=0, cache_counts=(0, 0, 0)) -> None:
+                     tokens_real=0, slots=0, expert_rows=0, cache_counts=(0, 0, 0), state_counts=None) -> None:
         pass
 
     def note_tokens(self, out, discarded=0, real=0, expert_rows=0) -> None:

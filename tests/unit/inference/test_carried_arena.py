@@ -123,6 +123,35 @@ def test_alibi_and_window_read_a_layers_slice_of_the_carried_arena(family, impl)
     _check(family, impl, "rows_finish_apart")
 
 
+@pytest.mark.parametrize("family", sorted(FAMILIES))
+def test_head_over_the_sampled_rows_equals_the_all_position_logits(family):
+    """The engine's step programs ask the twin for each row's last real token
+    alone (``last_only``: the rows are gathered before the final norm and the
+    head); whoever compares logits asks for every position.  One answer, and
+    the same arena, at every step of a ragged schedule."""
+    cfg, full_cls, _, _, _ = FAMILIES[family]
+    set_global_mesh(create_mesh(MeshSpec(), devices=jax.devices()[:1]))
+    tokens = np.random.default_rng(0).integers(1, cfg.vocab_size, (ROWS, LENGTH), dtype=np.int32)
+    params = nn.meta.unbox(full_cls(cfg).init(jax.random.PRNGKey(0), jnp.zeros((1, 8), jnp.int32)))
+    twin = jax.jit(build_cache_model(cfg, KV.page_size).apply, static_argnums=6)
+    arena = init_kv_cache(cfg, KV, jnp.float32)
+    tables = jnp.asarray(1 + np.arange(ROWS * KV.max_pages_per_seq, dtype=np.int32).reshape(ROWS, -1))
+    start = np.zeros((ROWS, ), np.int32)
+    for width, lens in SCHEDULES["rows_finish_apart"]:
+        lens = np.asarray(lens, np.int32)
+        ids = np.zeros((ROWS, width), np.int32)
+        for r in range(ROWS):
+            ids[r, :lens[r]] = tokens[r, start[r]:start[r] + lens[r]]
+        batch = (jnp.asarray(ids), jnp.asarray(start), tables, arena, jnp.asarray(lens))
+        every, after = twin(params, *batch, False)
+        last, after_last = twin(params, *batch, True)
+        assert last.shape == (ROWS, 1, cfg.vocab_size)
+        for r in np.flatnonzero(lens):
+            np.testing.assert_allclose(last[r, 0], every[r, lens[r] - 1], atol=1e-6, rtol=1e-6)
+        np.testing.assert_array_equal(after, after_last)
+        arena, start = after, start + lens
+
+
 @pytest.mark.parametrize("traced", [True, False], ids=["traced_index", "static_index"])
 def test_kernel_reads_a_layer_of_the_whole_arena_under_a_tensor_mesh(traced):
     """``_paged_sharded(layer=)``: the arena sharded over its key heads under

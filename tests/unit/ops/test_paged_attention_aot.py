@@ -46,16 +46,16 @@ def no_compile_cache():
     jax.config.update("jax_enable_compilation_cache", cache_was)
 
 
-def _compile(one_chip, chunk, n_q, n_kv, table_width, layers=None, d=128, dtype=jnp.bfloat16):
+def _compile(one_chip, chunk, n_q, n_kv, table_width, layers=None, d=128, dtype=jnp.bfloat16, batch=16, **bounds):
     """The kernel alone, lowered and compiled: the program's text.  Under the
     ``no_compile_cache`` fixture."""
     sds = lambda shape, dtype: jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)  # noqa: E731
     arena = (64, 16, 2, n_kv, d) if layers is None else (layers, 64, 16, 2, n_kv, d)
-    args = [sds((16, chunk, n_q, d), dtype), sds(arena, dtype), sds((16, table_width), jnp.int32),
-            sds((16, ), jnp.int32), sds((16, ), jnp.int32)]
+    args = [sds((batch, chunk, n_q, d), dtype), sds(arena, dtype), sds((batch, table_width), jnp.int32),
+            sds((batch, ), jnp.int32), sds((batch, ), jnp.int32)]
 
     def call(q, pages, table, start, lens, layer=None):
-        return paged_attention_pallas(q, pages, table, start, lens, 16, layer=layer, interpret=False)
+        return paged_attention_pallas(q, pages, table, start, lens, 16, layer=layer, interpret=False, **bounds)
 
     if layers is not None:
         args.append(sds((), jnp.int32))
@@ -94,6 +94,19 @@ def test_ungrouped_heads_out_of_the_whole_arena(one_chip, no_compile_cache, chun
     """EvaByte's shape: 32 key heads, no grouping, the layer named by an
     index into the whole arena, a table of 248 virtual pages."""
     assert "tpu_custom_call" in _compile(one_chip, chunk, 32, 32, 248, layers=8)
+
+
+@pytest.mark.parametrize("table_width, window", [(41, 512), (193, 0)])
+@pytest.mark.parametrize("chunk", [128, 1])
+def test_two_key_pairs_a_row_with_the_windows_bound(one_chip, no_compile_cache, chunk, table_width, window):
+    """Phi-4-mini-flash's shapes (``models/phi4flash_cache.py``): 32 sequences
+    x 5 groups of key pairs are 160 kernel rows of 8 query heads over 2 key
+    heads of 128 lanes, the scores scaled by 1/8; a window layer's ring is a
+    table of 41 pages read under the window's two bounds (the walk's first
+    block a traced number), the shared pages a table of 193 under one."""
+    assert _copies_pages(2, 128, 2)
+    assert "tpu_custom_call" in _compile(one_chip, chunk, 8, 2, table_width, layers=8, batch=160, window=window,
+                                         scale=0.125)
 
 
 #: pages the chip's tiling pads, or heads no strided load takes: (query heads, key heads, lanes, dtype)
