@@ -6,12 +6,16 @@ softmax-over-all-experts gating → top-k (optionally renormalized), experts
 are gated-SiLU MLPs at ``moe_intermediate_size``, plus a dense shared
 expert scaled by sigmoid(shared_expert_gate(x)).
 
-The expert mixture here is the exact dense formulation (every expert's
-output weighted by its routing weight, zeros for non-selected) — bit-exact
-with HF's gather-based compute and MXU-friendly via stacked-expert einsums.
-For large expert counts sharded over the mesh's expert axis, use
-deepspeed_tpu.moe.MoE (all-to-all dispatch with capacity) — this model
-targets checkpoint parity and fine-tuning.
+The routed experts go through ``moe.sharded_moe.dropless_dispatch``, the
+path served Mixtral takes: no capacity, no token dropped, a token's k
+outputs weighted and added in float32 (HF's gather-based math).  Above
+``DENSE_UP_TO_TOKENS`` tokens a data shard (a training step) the [S, k]
+choices are sorted by expert and the bank multiplies the routed rows,
+k a token, forward and backward; up to it (a decode step, a short forward)
+every expert multiplies every row, which costs the same read of the weights.
+The bank [NE, ...] is whole on every data shard (ZeRO-3 gathers it a layer,
+an ``expert`` mesh axis too): for expert counts that need the experts kept
+apart over the mesh, use deepspeed_tpu.moe.MoE (capacity dispatch).
 """
 
 from dataclasses import dataclass
@@ -19,11 +23,12 @@ from typing import Any
 
 import jax
 import jax.numpy as jnp
-import numpy as np
 from flax import linen as nn
 
-from .llama import EMBED, LAYERS, MLP, VOCAB, LlamaAttention, LlamaConfig, RMSNorm, _logical
+from .llama import (EMBED, LAYERS, MLP, VOCAB, LlamaAttention, LlamaConfig, RMSNorm, _logical,
+                    activation_constraint)
 from ..axes import EXPERT_EMBED, EXPERT_MLP, EXPERTS
+from ..moe.sharded_moe import dropless_dispatch
 
 
 @dataclass(frozen=True)
@@ -101,32 +106,6 @@ class Qwen2MoeConfig:
         return cfg
 
 
-def _moe_intermediate_constraint(t):
-    """Pin a [B, S, N_experts, *] dense-mixture intermediate to batch
-    sharding over the full (data×expert) group, experts LOCAL.
-
-    This model computes every expert for every token (exact HF math — see
-    the module docstring; capacity-based EP dispatch lives in moe.MoE), so
-    the FLOP-consistent layout is token-parallel over all DP axes with the
-    expert-stacked weights gathered per layer, exactly like ZeRO-3 dense
-    weights.  Leaving GSPMD to resolve the chain instead mixes the weights'
-    expert-axis sharding into the activations and falls back to
-    "Involuntary full rematerialization" — replicating a full [B,S,NE,E]
-    tensor per MoE layer (the warning `__graft_entry__.dryrun_multichip` fails on)."""
-    from ..comm.mesh import BATCH_AXES, get_global_mesh, has_global_mesh
-    from .llama import _skip_constraint
-    if not has_global_mesh() or _skip_constraint(t):
-        return t
-    mesh = get_global_mesh()
-    nb = int(np.prod([mesh.shape.get(a, 1) for a in BATCH_AXES]))
-    if nb == 1 or t.shape[0] % nb:
-        return t
-    from jax.sharding import NamedSharding, PartitionSpec
-    spec = [None] * t.ndim
-    spec[0] = BATCH_AXES
-    return jax.lax.with_sharding_constraint(t, NamedSharding(mesh, PartitionSpec(*spec)))
-
-
 class Qwen2MoeSparseMLP(nn.Module):
     cfg: Qwen2MoeConfig
 
@@ -138,13 +117,6 @@ class Qwen2MoeSparseMLP(nn.Module):
 
         gate_logits = nn.Dense(NE, use_bias=False, dtype=jnp.float32, param_dtype=cfg.param_dtype,
                                name="gate")(x.astype(jnp.float32))         # [B,S,NE]
-        probs = jax.nn.softmax(gate_logits, axis=-1)
-        topv, topi = jax.lax.top_k(probs, cfg.num_experts_per_tok)
-        if cfg.norm_topk_prob:
-            topv = topv / (topv.sum(-1, keepdims=True) + 1e-20)
-        # dense routing weights: zeros except selected experts
-        onehot = jax.nn.one_hot(topi, NE, dtype=probs.dtype)   # [B,S,K,NE]
-        weights = (onehot * topv[..., None]).sum(-2)           # [B,S,NE]
 
         # EXPERT_EMBED/EXPERT_MLP exclude the expert mesh axis from the ZeRO
         # dims — the 'expert' axis is already consumed by the EXPERTS dim
@@ -155,13 +127,16 @@ class Qwen2MoeSparseMLP(nn.Module):
                           (NE, E, M), cfg.param_dtype)
         w_down = self.param("w_down", _logical(nn.initializers.lecun_normal(), (EXPERTS, EXPERT_MLP, EXPERT_EMBED)),
                             (NE, M, E), cfg.param_dtype)
-        # dense mixture: every expert evaluated, weighted-summed (exact HF math)
-        h = _moe_intermediate_constraint(jnp.einsum("bse,nem->bsnm", x.astype(dt), w_gate.astype(dt)))
-        u = _moe_intermediate_constraint(jnp.einsum("bse,nem->bsnm", x.astype(dt), w_up.astype(dt)))
-        act = nn.silu(h) * u
-        y = _moe_intermediate_constraint(jnp.einsum("bsnm,nme->bsne", act, w_down.astype(dt)))
-        out = jnp.einsum("bsne,bsn->bse", y.astype(jnp.float32), weights)
-        from .llama import activation_constraint
+        # softmax over all experts, top-k, the k outputs weighted and added in
+        # float32: HF's math, with the experts multiplying the routed rows only
+        with jax.named_scope("ds_moe_grouped"):
+            out, _, exp_counts = dropless_dispatch(x.astype(dt), gate_logits,
+                                                   (w_gate.astype(dt), w_up.astype(dt), w_down.astype(dt)),
+                                                   cfg.num_experts_per_tok, normalize=cfg.norm_topk_prob)
+        # rows each expert multiplied: their sum is tokens x k on the grouped
+        # path, max over mean its load imbalance (a no-op unless the caller
+        # makes "intermediates" mutable)
+        self.sow("intermediates", "exp_counts", exp_counts)
         out = activation_constraint(out)
 
         # shared expert with sigmoid gate (HF: shared_expert_gate Linear(E,1))
@@ -240,7 +215,7 @@ class Qwen2MoeForCausalLM(nn.Module):
         if cfg.remat:
             block_cls = nn.remat(Qwen2MoeBlock, prevent_cse=not cfg.scan_layers)
         if cfg.scan_layers:
-            blocks = nn.scan(block_cls, variable_axes={"params": 0}, split_rngs={"params": True},
+            blocks = nn.scan(block_cls, variable_axes={"params": 0, "intermediates": 0}, split_rngs={"params": True},
                              in_axes=(nn.broadcast, nn.broadcast), length=cfg.num_hidden_layers,
                              metadata_params={nn.PARTITION_NAME: LAYERS})
             x, _ = blocks(cfg, scanned=True, name="layers")(x, positions, segment_ids)

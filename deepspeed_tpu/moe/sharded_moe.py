@@ -24,7 +24,11 @@ and there is no other knob:
 
 * **Dropless sorted dispatch** (``dropless_moe``), taken when
   ``drop_tokens=False`` and the experts are on one shard (every served
-  Mixtral; training when asked): the [S, k] choices are flattened and
+  Mixtral, its training twin when asked) and always by
+  ``models/qwen2_moe.Qwen2MoeSparseMLP``, training and served, whose bank
+  ZeRO-3 or an ``expert`` axis gathers whole a layer (the published model
+  drops no token, and does not renormalise its k gate values:
+  ``normalize``): the [S, k] choices are flattened and
   stable-sorted by expert id, the rows gathered, and the three products of
   the bank run over the ragged groups (``jax.lax.ragged_dot``), so the
   experts multiply k rows a live token and no [S, E, C] tensor exists.
@@ -210,7 +214,7 @@ def _experts_dense(x, top_vals, expert, group_sizes, bank, layer):
     return jnp.einsum("se,esd->sd", weights, y)
 
 
-def dropless_moe(x, logits, bank, k: int, token_mask=None, noise=None, layer=None):
+def dropless_moe(x, logits, bank, k: int, token_mask=None, noise=None, layer=None, normalize: bool = True):
     """Route one group with no capacity: every live token through its k
     highest experts.
 
@@ -222,7 +226,8 @@ def dropless_moe(x, logits, bank, k: int, token_mask=None, noise=None, layer=Non
     expert and come out as zeros; noise: [S, E] or None, added to the logits
     for the choice only (``top1_gating``'s RSample).  Gate values as the
     capacity path has them: softmax in float32, renormalised over the k when
-    k > 1; a token's k outputs are weighted and added in float32.
+    k > 1 unless the model publishes that it does not (``normalize``, Qwen2-MoE's
+    ``norm_topk_prob``); a token's k outputs are weighted and added in float32.
     Returns (out [S, d] float32, l_aux, exp_counts [E] int32).
     """
     s, e = logits.shape
@@ -232,7 +237,7 @@ def dropless_moe(x, logits, bank, k: int, token_mask=None, noise=None, layer=Non
     else:
         _, top_idx = jax.lax.top_k(logits + noise, k)
         top_vals = jnp.take_along_axis(gates, top_idx, axis=-1)
-    if k > 1:
+    if normalize and k > 1:
         top_vals = top_vals / jnp.maximum(jnp.sum(top_vals, axis=-1, keepdims=True), 1e-9)
     live = jnp.ones((s, ), bool) if token_mask is None else token_mask
 
@@ -246,7 +251,7 @@ def dropless_moe(x, logits, bank, k: int, token_mask=None, noise=None, layer=Non
     return experts(x, top_vals, expert, group_sizes, bank, layer), l_aux, group_sizes
 
 
-def dropless_dispatch(x, logits, bank, k: int, token_mask=None, noise=None, layer=None):
+def dropless_dispatch(x, logits, bank, k: int, token_mask=None, noise=None, layer=None, normalize: bool = True):
     """``dropless_moe`` over a batch [B, S, ...]: one group a data shard.
 
     Without capacity a token's output does not depend on its group, so the
@@ -255,12 +260,18 @@ def dropless_dispatch(x, logits, bank, k: int, token_mask=None, noise=None, laye
     divide B the batch is routed shard by shard inside ``shard_map``; else
     (one device, a tensor-only mesh, already inside a manual mesh) as one
     group.  ``l_aux`` is the mean over the shards, ``exp_counts`` their sum.
+
+    The bank enters replicated: where ZeRO-3 partitions it that is the
+    all-gather a dense product would need too, of the compute dtype the
+    caller cast it to, and its cotangent, a psum inside the manual region and
+    a slice outside, leaves a TPU as one fused reduce-scatter.
     """
     from jax.sharding import PartitionSpec as P
 
     def one_group(x, logits, token_mask, noise, bank, layer):
         flat = lambda a: None if a is None else a.reshape((-1, ) + a.shape[2:])
-        out, l_aux, counts = dropless_moe(flat(x), flat(logits), bank, k, flat(token_mask), flat(noise), layer)
+        out, l_aux, counts = dropless_moe(flat(x), flat(logits), bank, k, flat(token_mask), flat(noise), layer,
+                                          normalize)
         return out.reshape(x.shape[:2] + out.shape[1:]), l_aux, counts
 
     mesh = get_trace_mesh()
@@ -273,5 +284,9 @@ def dropless_dispatch(x, logits, bank, k: int, token_mask=None, noise=None, laye
         return out, jax.lax.pmean(l_aux, batch_axes), jax.lax.psum(counts, batch_axes)
 
     rows = P(batch_axes)
+    # manual over the whole mesh where its other axes are trivial: under a
+    # partly manual mesh XLA's CPU compiler aborts on the bfloat16 psum that
+    # the replicated bank's cotangent is
+    manual = mesh.axis_names if mesh.size == axis_size(mesh, *batch_axes) else batch_axes
     return jax.shard_map(one_shard, mesh=mesh, in_specs=(rows, rows, rows, rows, P(), P()), out_specs=(rows, P(), P()),
-                         axis_names=set(batch_axes), check_vma=False)(x, logits, token_mask, noise, bank, layer)
+                         axis_names=set(manual), check_vma=False)(x, logits, token_mask, noise, bank, layer)

@@ -350,3 +350,69 @@ def test_flash_q_offset_staged_equals_full_on_chip():
         b = np.asarray(b, np.float32)
         denom = max(1.0, np.abs(b).max())
         assert np.abs(a - b).max() / denom < 2e-2, f"d{n} staged-vs-full mismatch"
+
+
+# ----------------------------------------------------------------- grouped experts
+
+
+def _dense_mixture(cfg, p, x):
+    """``Qwen2MoeSparseMLP`` as it stood until PR 31: every expert multiplies
+    every token ([B, S, NE, *] intermediates) and the routing weights zero all
+    but k of the results.  Kept here as the grouped product's reference."""
+    import jax
+    import jax.numpy as jnp
+    dt, f32 = cfg.dtype, jnp.float32
+    probs = jax.nn.softmax(x.astype(f32) @ p["gate"]["kernel"], axis=-1)
+    topv, topi = jax.lax.top_k(probs, cfg.num_experts_per_tok)
+    weights = (jax.nn.one_hot(topi, cfg.num_experts, dtype=f32) * topv[..., None]).sum(-2)
+    h = jnp.einsum("bse,nem->bsnm", x, p["w_gate"].astype(dt))
+    u = jnp.einsum("bse,nem->bsnm", x, p["w_up"].astype(dt))
+    y = jnp.einsum("bsnm,nme->bsne", jax.nn.silu(h) * u, p["w_down"].astype(dt))
+    out = jnp.einsum("bsne,bsn->bse", y.astype(f32), weights)
+    shared = (jax.nn.silu(x @ p["shared_gate_proj"]["kernel"].astype(dt)) *
+              (x @ p["shared_up_proj"]["kernel"].astype(dt))) @ p["shared_down_proj"]["kernel"].astype(dt)
+    gate = jax.nn.sigmoid(x.astype(f32) @ p["shared_expert_gate"]["kernel"])
+    return (out + gate * shared.astype(f32)).astype(x.dtype)
+
+
+def test_qwen2_moe_grouped_experts_match_dense_mixture_at_cell_widths():
+    """The train cell's expert layer at its own widths (60 experts of 1408
+    over hidden 2048, 4 a token, 4,096 tokens a chip, bf16): output and
+    gradients of the grouped product against the dense mixture it replaced."""
+    import jax
+    import jax.numpy as jnp
+    from flax import linen as nn
+    from deepspeed_tpu.models.qwen2_moe import Qwen2MoeConfig, Qwen2MoeSparseMLP
+
+    cfg = Qwen2MoeConfig(num_hidden_layers=1)  # the published widths are the defaults
+    assert (cfg.hidden_size, cfg.num_experts, cfg.moe_intermediate_size, cfg.num_experts_per_tok) == (2048, 60, 1408, 4)
+    layer = Qwen2MoeSparseMLP(cfg)
+    kx, kr, kp = jax.random.split(jax.random.PRNGKey(31), 3)
+    x = jax.random.normal(kx, (2, 2048, cfg.hidden_size), jnp.bfloat16)
+    r = jax.random.normal(kr, x.shape, jnp.float32)
+    params = nn.meta.unbox(jax.jit(layer.init)(kp, x))["params"]
+
+    def run(fn):
+        f = jax.jit(jax.value_and_grad(lambda p: jnp.sum(fn(p, x).astype(jnp.float32) * r)))
+        out = jax.jit(fn)(params, x)
+        loss, grads = jax.block_until_ready(f(params))
+        t0 = time.perf_counter()
+        for _ in range(5):
+            loss, grads = f(params)
+        jax.block_until_ready(grads)
+        return out, grads, (time.perf_counter() - t0) / 5
+
+    got, got_grads, got_s = run(lambda p, x: layer.apply({"params": p}, x))
+    want, want_grads, want_s = run(lambda p, x: _dense_mixture(cfg, p, x))
+    print(f"\nqwen2_moe layer fwd+bwd at 4096 tokens: grouped {got_s * 1e3:.2f} ms, dense mixture {want_s * 1e3:.2f} ms")
+
+    rel = lambda a, b: float(jnp.linalg.norm((a.astype(jnp.float32) - b.astype(jnp.float32)).ravel()) /
+                             jnp.linalg.norm(b.astype(jnp.float32).ravel()))
+    assert rel(got, want) < 1e-2, f"output deviates from the dense mixture by {rel(got, want)}"
+    for name in ("w_gate", "w_up", "w_down"):
+        err = rel(got_grads[name], want_grads[name])
+        print(f"d{name}: {err:.2e}")
+        assert np.isfinite(np.asarray(got_grads[name], np.float32)).all(), f"d{name} is not finite"
+        assert err < 2e-2, f"d{name} deviates from the dense mixture's by {err}"
+    err = rel(got_grads["gate"]["kernel"], want_grads["gate"]["kernel"])
+    assert err < 5e-2, f"the router's gradient deviates by {err}"
