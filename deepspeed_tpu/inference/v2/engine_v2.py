@@ -879,7 +879,7 @@ class InferenceEngineV2:
                               rows_decode=len(seqs), tokens_real=len(seqs) * k,
                               slots=batch * k, expert_rows=len(seqs) * k * self._experts_per_tok,
                               cache_counts=self._cache_counts([(s, k) for s in seqs], calls=k),
-                              state_counts=self._state_counts([(s, k) for s in seqs]))
+                              state_counts=self._state_counts([(s, k) for s in seqs], calls=k))
         toks, self.cache = self._invoke(fn, self.params, self.cache, jnp.asarray(rb.tokens[:, 0]),
                                         jnp.asarray(rb.start_pos), jnp.asarray(rb.block_tables),
                                         jnp.asarray(rb.chunk_lens), sub)
@@ -955,7 +955,7 @@ class InferenceEngineV2:
         counts = [self.kv.geometry.step_counts(s.seen_tokens, n, block_rows, calls) for s, n in work]
         return tuple(sum(c) for c in zip(*counts))
 
-    def _state_counts(self, work) -> dict:
+    def _state_counts(self, work, calls: int = 1) -> dict:
         """The step records' counts of a geometry with state slots (none
         without): the geometry's ``state_counts`` summed over the step's
         rows, and the slots that sequences hold."""
@@ -964,7 +964,7 @@ class InferenceEngineV2:
             return {}
         total = {"state_slots_live": len(self.state.seqs)}
         for s, n in work:
-            for name, count in geometry.state_counts(s.seen_tokens, n).items():
+            for name, count in geometry.state_counts(s.seen_tokens, n, calls).items():
                 total[name] = total.get(name, 0) + count
         return total
 

@@ -64,9 +64,10 @@ class LinearGeometry:
         """The shortest history ``truncate`` may still rewind to."""
         return 0
 
-    def state_counts(self, start: int, n_tokens: int) -> dict:
+    def state_counts(self, start: int, n_tokens: int, calls: int = 1) -> dict:
         """Further named counts of the step records (``telemetry/step_anatomy.COUNTS``)
-        that feeding tokens ``start .. start + n_tokens - 1`` adds to; none here."""
+        that feeding tokens ``start .. start + n_tokens - 1`` in ``calls`` calls
+        of equal length adds to; none here."""
         return {}
 
     def chunk_limit(self, start: int, n_tokens: int) -> int:
@@ -154,24 +155,28 @@ class RingSummaryGeometry:
 
 
 class SlotPagesGeometry(LinearGeometry):
-    """Pages for the one layer whose keys and values grow with the sequence,
-    laid out as the linear geometry's, plus one **state slot** a sequence for
-    everything of fixed size: the window layers' rings and the recurrent
-    layers' states (``models/phi4flash_cache.py``).  The slot's index rides
-    in the last column of the block-table row, so a row sized for ``n``
-    tokens holds a page less; slot 0 is scratch, as page 0 is the null page,
-    so a row built for the linear layout alone reads as a valid one.  The
-    slot is allocated with the sequence and released with it
-    (``ragged.StateManager``)."""
+    """Pages for the layers whose keys and values grow with the sequence (one
+    layer's in ``models/phi4flash_cache.py``, every attention layer's under
+    one block table in ``models/granite_hybrid_cache.py``), laid out as the
+    linear geometry's, plus one **state slot** a sequence for everything of
+    fixed size: the recurrent layers' states and, with a ``window``, the
+    window layers' rings.  The slot's index rides in the last column of the
+    block-table row, so a row sized for ``n`` tokens holds a page less; slot
+    0 is scratch, as page 0 is the null page, so a row built for the linear
+    layout alone reads as a valid one.  The slot is allocated with the
+    sequence and released with it (``ragged.StateManager``).
+    ``state_bytes``: what one sequence's recurrent states take, every layer,
+    where the step records are to count the bytes a step moves of them."""
 
     #: the pages never change, but a slot's state belongs to one sequence
     #: and is not kept by position: nothing of it can be shared or rewound to
     pages_immutable = False
     state_slots = True
 
-    def __init__(self, page_size: int, window: int):
+    def __init__(self, page_size: int, window: int = None, state_bytes: int = 0):
         super().__init__(page_size)
-        self.window = int(window)
+        self.window = None if window is None else int(window)
+        self.state_bytes = int(state_bytes)
 
     def token_capacity(self, max_tokens: int) -> int:
         return (self.table_width(max_tokens) - 1) * self.page_size
@@ -180,9 +185,16 @@ class SlotPagesGeometry(LinearGeometry):
         """A recurrent state cannot be rewound at all."""
         return int(seen_tokens)
 
-    def state_counts(self, start: int, n_tokens: int) -> dict:
-        """``ssm_rows``: token rows the scan advanced; ``window_rows_visible``:
-        key rows a window layer's queries could see, ``min(t + 1, window)``
-        summed over them (one layer each)."""
-        t = np.arange(start, start + n_tokens)
-        return {"ssm_rows": int(n_tokens), "window_rows_visible": int(np.minimum(t + 1, self.window).sum())}
+    def state_counts(self, start: int, n_tokens: int, calls: int = 1) -> dict:
+        """``ssm_rows``: token rows the recurrence advanced; with a window,
+        ``window_rows_visible``: key rows a window layer's queries could see,
+        ``min(t + 1, window)`` summed over them (one layer each); with
+        ``state_bytes``, ``ssd_state_bytes``: the row's states read and
+        written once in each of the ``calls`` calls its tokens go through."""
+        counts = {"ssm_rows": int(n_tokens)}
+        if self.window is not None:
+            t = np.arange(start, start + n_tokens)
+            counts["window_rows_visible"] = int(np.minimum(t + 1, self.window).sum())
+        if self.state_bytes:
+            counts["ssd_state_bytes"] = 2 * self.state_bytes * int(calls)
+        return counts

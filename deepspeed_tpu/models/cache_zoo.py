@@ -25,6 +25,9 @@ from .llama_cache import (LlamaForCausalLMWithCache, init_kv_cache, paged_attent
 from .evabyte import EvaByteConfig
 from .evabyte_cache import EvaByteForCausalLMWithCache
 from .falcon import FalconConfig
+from .granite_hybrid import GraniteHybridConfig
+from .granite_hybrid_cache import GraniteHybridForCausalLMWithCache, slot_state_bytes
+from .granite_hybrid_cache import init_cache as init_granite_hybrid_cache
 from .mixtral import MixtralConfig
 from .mixtral_cache import MixtralForCausalLMWithCache
 from .opt import OPTConfig
@@ -381,8 +384,9 @@ class CacheTwin:
     arena holds and whether a sequence holds a state slot besides
     (inference/v2/geometry.py), ``init_cache(cfg, kv, dtype, n_slots, chunk)`` makes
     what the engine keeps as ``eng.cache`` and hands the twin: the one arena
-    of pages [L, P, page, 2, n_kv, hd], or pages and state slots together,
-    of which ``pages(cache)`` is the arena the paged kernel reads."""
+    of pages [L, P, page, 2, n_kv, hd], or pages (of the layers whose keys
+    and values grow: one, or several under one block table) and state slots
+    together, of which ``pages(cache)`` is the arena the paged kernel reads."""
     model: Callable
     geometry: Callable = lambda cfg, page_size: LinearGeometry(page_size)
     init_cache: Callable = lambda cfg, kv, dtype, n_slots, chunk: init_kv_cache(cfg, kv, dtype=dtype)
@@ -411,6 +415,10 @@ CACHE_MODEL_REGISTRY = {
     Phi4FlashConfig: CacheTwin(Phi4FlashForCausalLMWithCache,
                                lambda cfg, page_size: SlotPagesGeometry(page_size, cfg.sliding_window),
                                init_phi4flash_cache, lambda cache: cache["pages"]),
+    GraniteHybridConfig: CacheTwin(GraniteHybridForCausalLMWithCache,
+                                   lambda cfg, page_size: SlotPagesGeometry(page_size,
+                                                                            state_bytes=slot_state_bytes(cfg)),
+                                   init_granite_hybrid_cache, lambda cache: cache["pages"]),
 }
 
 

@@ -119,7 +119,8 @@ HOST_SEGMENTS = ("admit", "schedule", "draft_plan", "verify_plan",
 #: what a step carried; zero until the engine notes them
 COUNTS = ("rows_decode", "rows_prefill", "tokens_real", "slots", "tokens_out",
           "tokens_discarded", "expert_rows", "summary_rows_written", "ring_wraps",
-          "attn_rows_visible", "attn_rows_walked", "state_slots_live", "ssm_rows", "window_rows_visible")
+          "attn_rows_visible", "attn_rows_walked", "state_slots_live", "ssm_rows", "window_rows_visible",
+          "ssd_state_bytes")
 
 #: names of the instant profiler events, built once (a mark allocates no string)
 _MARK_NAMES = {s: "ds.mark." + s for s in HOST_SEGMENTS + ("device_wait", )}
@@ -148,7 +149,7 @@ class StepRecord:
         self.expert_rows = 0
         self.summary_rows_written = self.ring_wraps = 0
         self.attn_rows_visible = self.attn_rows_walked = 0
-        self.state_slots_live = self.ssm_rows = self.window_rows_visible = 0
+        self.state_slots_live = self.ssm_rows = self.window_rows_visible = self.ssd_state_bytes = 0
 
     def host_s(self) -> float:
         return sum(self.segments.values())

@@ -63,6 +63,32 @@ def test_counts_of_a_step(start, n):
     assert LinearGeometry(PAGE).state_counts(start, n) == {}
 
 
+@pytest.mark.parametrize("start, n, calls", [(0, 128, 1), (2500, 8, 8), (2500, 1, 1)])
+def test_counts_of_a_step_without_a_window_and_with_state_bytes(start, n, calls):
+    """Slots that hold recurrent states and no ring (``models/granite_hybrid_cache.py``):
+    no window count, and the states' bytes a step moves, in and out, once a
+    call the row's tokens go through (a chunk: one; a fused dispatch: its rounds)."""
+    state = 36 * 2_097_152
+    g = SlotPagesGeometry(PAGE, state_bytes=state)
+    assert g.window is None and g.state_slots and not g.pages_immutable
+    assert g.state_counts(start, n, calls) == {"ssm_rows": n, "ssd_state_bytes": 2 * state * calls}
+    assert g.table_width(258 * PAGE) == 258 and g.token_capacity(258 * PAGE) == 257 * PAGE
+    assert g.rewind_floor(700) == 700 and g.chunk_limit(300, 128) == 128
+    assert "ssd_state_bytes" not in _geometry().state_counts(start, n, calls)      # rings and Mamba-1 states: not counted
+    assert LinearGeometry(PAGE).state_counts(start, n, calls) == {}
+
+
+def test_a_slot_comes_and_goes_the_same_without_a_window():
+    kv = BlockedKVCache(64, PAGE, 10, enable_prefix_cache=False, geometry=SlotPagesGeometry(PAGE), state_slots=3)
+    state = StateManager(kv, max_batch=8)
+    seq = state.get_or_create(1, [5] * 20)
+    assert seq.slot in (1, 2) and kv.slot_allocator.free_pages == 1
+    state.flush(1)
+    assert kv.slot_allocator.free_pages == 2
+    with pytest.raises(NotImplementedError, match="prefix cache over SlotPagesGeometry"):
+        BlockedKVCache(64, PAGE, 10, enable_prefix_cache=True, geometry=SlotPagesGeometry(PAGE), state_slots=3)
+
+
 # ------------------------------------------------------- the slot's life in StateManager
 
 
