@@ -28,6 +28,9 @@ import jax.numpy as jnp
 import numpy as np
 
 from ...models.llama_cache import PagedKVConfig, reads_through_kernel, stack_layer_params
+from ...comm.mesh import trace_mesh
+from ...moe import sharded_moe
+from ...ops.grouped_matmul import takes_kernel
 from ...ops.paged_attention import walk_block
 from ...telemetry.step_anatomy import NULL_ANATOMY
 from ...utils.logging import logger
@@ -306,9 +309,12 @@ class InferenceEngineV2:
                 raise NotImplementedError("a quantized scan_layers=False tree cannot be stacked: "
                                           "stack it (stack_layer_params), then quantize")
             params = stack_layer_params(params, cfg.num_hidden_layers)
-        # experts a token is routed to (0: no expert layer), for the step
-        # records' expert_rows
+        # experts a token is routed to (0: no expert layer), and whether the
+        # grouped product of a step this engine traces is the kernel ds_gmm,
+        # for the step records' expert_rows and expert_rows_kernel
         self._experts_per_tok = int(getattr(cfg, "num_experts_per_tok", 0) or 0)
+        with trace_mesh(self.mesh):
+            self._experts_kernel = takes_kernel()
         # weight-only-quantized checkpoints: int8 stays in HBM, dequant is
         # traced into the step program (ref: inference/quantization kernels)
         if isinstance(params, QuantizedParams):
@@ -359,6 +365,16 @@ class InferenceEngineV2:
         (warm-up vs steady-state — the AOT regression guard)."""
         self._fresh_compile = True
         self.anatomy.note_compile(key)
+
+    def _expert_rows(self, tokens: int, group: int) -> Dict[str, int]:
+        """The step records' expert counts: the rows the routed experts
+        multiplied for ``tokens`` real tokens, and how many of them went
+        through the kernel ``ds_gmm``: all, where a model step of ``group``
+        slots takes the sorted form (``moe/sharded_moe.dropless_moe``) and its
+        products the kernel (``ops/grouped_matmul.takes_kernel``), else none."""
+        rows = tokens * self._experts_per_tok
+        return {"expert_rows": rows,
+                "expert_rows_kernel": rows if self._experts_kernel and group > sharded_moe.DENSE_UP_TO_TOKENS else 0}
 
     # ------------------------------------------------------------------ TP
 
@@ -852,7 +868,7 @@ class InferenceEngineV2:
             n_real = sum(a + 1 for _, a, _ in self.last_spec_round.values())
             anat.note_tokens(sum(len(v) for v in out.values()),
                              sum(1 + len(d) for d in drafts) - n_real, real=n_real,
-                             expert_rows=n_real * self._experts_per_tok)
+                             **self._expert_rows(n_real, argmax.size))
             anat.mark("sample_accept")
         return out
 
@@ -877,7 +893,7 @@ class InferenceEngineV2:
         if anat.enabled:
             anat.note_program(self._key_label(("multi", batch, k)), "multi_decode",
                               rows_decode=len(seqs), tokens_real=len(seqs) * k,
-                              slots=batch * k, expert_rows=len(seqs) * k * self._experts_per_tok,
+                              slots=batch * k, **self._expert_rows(len(seqs) * k, batch),
                               cache_counts=self._cache_counts([(s, k) for s in seqs], calls=k),
                               state_counts=self._state_counts([(s, k) for s in seqs], calls=k))
         toks, self.cache = self._invoke(fn, self.params, self.cache, jnp.asarray(rb.tokens[:, 0]),
@@ -1093,7 +1109,7 @@ class InferenceEngineV2:
             anat.note_program(self._key_label((batch, chunk)), path,
                               rows_decode=len(plan.decode), rows_prefill=len(plan.prefill),
                               tokens_real=tokens_real, slots=batch * chunk,
-                              expert_rows=tokens_real * self._experts_per_tok,
+                              **self._expert_rows(tokens_real, batch * chunk),
                               cache_counts=self._cache_counts(work), state_counts=self._state_counts(work))
         next_tok, self.cache = self._invoke(fn, self.params, self.cache, jnp.asarray(rb.tokens),
                                             jnp.asarray(rb.start_pos), jnp.asarray(rb.block_tables),

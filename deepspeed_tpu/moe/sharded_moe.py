@@ -30,8 +30,11 @@ and there is no other knob:
   drops no token, and does not renormalise its k gate values:
   ``normalize``): the [S, k] choices are flattened and
   stable-sorted by expert id, the rows gathered, and the three products of
-  the bank run over the ragged groups (``jax.lax.ragged_dot``), so the
-  experts multiply k rows a live token and no [S, E, C] tensor exists.
+  the bank run over the ragged groups (``ops/grouped_matmul.grouped_matmul``:
+  the Pallas kernel ``ds_gmm`` on a TPU where the call is one device's, forward
+  and backward, ``jax.lax.ragged_dot`` on the CPU and under a mesh the
+  compiler partitions), so the experts multiply k rows a live token and no
+  [S, E, C] tensor exists.
   Rows a token mask removes (the padding of a serving step) sort behind the
   last group, are in no group, cost no product and come out as exact zeros.
   Up to ``DENSE_UP_TO_TOKENS`` tokens a group (a decode step) every expert
@@ -49,6 +52,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from ..comm.mesh import BATCH_AXES, EXPERT_AXIS, axis_size, get_global_mesh, get_trace_mesh, in_manual_mesh
+from ..ops.grouped_matmul import grouped_matmul
 
 
 def _capacity(num_tokens: int, num_experts: int, capacity_factor: float, min_capacity: int, k: int) -> int:
@@ -192,9 +196,9 @@ def _experts_grouped(x, top_vals, expert, group_sizes, bank, layer):
     # flat row i is choice i % k of token i // k
     order = jnp.argsort(expert.reshape(s * k), stable=True)
     rows = jnp.take(x, order // k, axis=0)  # [S*k, d], sorted by expert
-    h = jax.nn.silu(jax.lax.ragged_dot(rows, w_gate, group_sizes)) * jax.lax.ragged_dot(rows, w_up, group_sizes)
-    y = jax.lax.ragged_dot(h, w_down, group_sizes).astype(jnp.float32)
-    # what ragged_dot leaves beyond the groups' sum is not defined on the TPU
+    h = jax.nn.silu(grouped_matmul(rows, w_gate, group_sizes)) * grouped_matmul(rows, w_up, group_sizes)
+    y = grouped_matmul(h, w_down, group_sizes).astype(jnp.float32)
+    # what the grouped product leaves beyond the groups' sum is not defined on the TPU
     y = jnp.where((jnp.arange(s * k) < jnp.sum(group_sizes))[:, None], y, 0.0)
     back = jnp.zeros((s * k, ), order.dtype).at[order].set(jnp.arange(s * k, dtype=order.dtype))
     y = jnp.take(y, back, axis=0).reshape(s, k, -1)

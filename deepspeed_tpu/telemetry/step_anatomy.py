@@ -58,7 +58,11 @@ to kill.  This module decomposes EVERY engine step into:
   ``tokens_out`` (tokens that reached a sequence),
   ``tokens_discarded`` (overshoot of the fused rung, rejected drafts),
   ``expert_rows`` (rows a layer's routed experts multiplied:
-  ``tokens_real`` x experts a token; 0 for a model with no expert layer)
+  ``tokens_real`` x experts a token; 0 for a model with no expert layer),
+  ``expert_rows_kernel`` (those of them that went through the grouped
+  kernel ``ds_gmm``: all of a mixed step's on a TPU of one device, none of a
+  decode step's, where every expert multiplies every row, and none where the
+  product is ``jax.lax.ragged_dot``)
   and, for a cache of exact and summary pages (chunked linear attention; 0
   under the linear geometry), ``summary_rows_written`` (chunks that
   completed in the step) and ``ring_wraps`` (token rows that started a
@@ -118,7 +122,7 @@ HOST_SEGMENTS = ("admit", "schedule", "draft_plan", "verify_plan",
 
 #: what a step carried; zero until the engine notes them
 COUNTS = ("rows_decode", "rows_prefill", "tokens_real", "slots", "tokens_out",
-          "tokens_discarded", "expert_rows", "summary_rows_written", "ring_wraps",
+          "tokens_discarded", "expert_rows", "expert_rows_kernel", "summary_rows_written", "ring_wraps",
           "attn_rows_visible", "attn_rows_walked", "state_slots_live", "ssm_rows", "window_rows_visible",
           "ssd_state_bytes")
 
@@ -146,7 +150,7 @@ class StepRecord:
         self.rows_decode = self.rows_prefill = 0
         self.tokens_real = self.slots = 0
         self.tokens_out = self.tokens_discarded = 0
-        self.expert_rows = 0
+        self.expert_rows = self.expert_rows_kernel = 0
         self.summary_rows_written = self.ring_wraps = 0
         self.attn_rows_visible = self.attn_rows_walked = 0
         self.state_slots_live = self.ssm_rows = self.window_rows_visible = self.ssd_state_bytes = 0
@@ -291,7 +295,7 @@ class StepAnatomy:
 
     def note_program(self, key: str, path: str, rows_decode: int = 0,
                      rows_prefill: int = 0, tokens_real: int = 0,
-                     slots: int = 0, expert_rows: int = 0,
+                     slots: int = 0, expert_rows: int = 0, expert_rows_kernel: int = 0,
                      cache_counts: tuple = (0, 0, 0, 0), state_counts: Optional[dict] = None) -> None:
         """Tag the open step with the program it dispatches (``key``, as
         ``InferenceEngineV2._key_label`` prints it: the attribution key)
@@ -305,24 +309,26 @@ class StepAnatomy:
             cur.key, cur.path = key, path
             cur.rows_decode, cur.rows_prefill = int(rows_decode), int(rows_prefill)
             cur.tokens_real, cur.slots = int(tokens_real), int(slots)
-            cur.expert_rows = int(expert_rows)
+            cur.expert_rows, cur.expert_rows_kernel = int(expert_rows), int(expert_rows_kernel)
             (cur.summary_rows_written, cur.ring_wraps, cur.attn_rows_visible,
              cur.attn_rows_walked) = (int(c) for c in cache_counts)
             for name, count in (state_counts or {}).items():
                 setattr(cur, name, int(count))
 
     def note_tokens(self, out: int, discarded: int = 0, real: int = 0,
-                    expert_rows: int = 0) -> None:
+                    expert_rows: int = 0, expert_rows_kernel: int = 0) -> None:
         """What the fold did with the step's tokens: ``out`` reached a
         sequence, ``discarded`` were computed and thrown away; ``real``
         adds positions whose use is known only now (a verify round's
-        accepted + 1 a row), ``expert_rows`` their rows through the experts."""
+        accepted + 1 a row), ``expert_rows`` their rows through the experts
+        and ``expert_rows_kernel`` those of them through ``ds_gmm``."""
         cur = self._cur
         if cur is not None:
             cur.tokens_out += int(out)
             cur.tokens_discarded += int(discarded)
             cur.tokens_real += int(real)
             cur.expert_rows += int(expert_rows)
+            cur.expert_rows_kernel += int(expert_rows_kernel)
 
     def note_compile(self, key: str, aot: bool = False) -> None:
         """One compile event (the engine's ``_step_fns`` grew an entry).
@@ -567,10 +573,11 @@ class NullStepAnatomy:
         pass
 
     def note_program(self, key, path, rows_decode=0, rows_prefill=0,
-                     tokens_real=0, slots=0, expert_rows=0, cache_counts=(0, 0, 0), state_counts=None) -> None:
+                     tokens_real=0, slots=0, expert_rows=0, expert_rows_kernel=0, cache_counts=(0, 0, 0),
+                     state_counts=None) -> None:
         pass
 
-    def note_tokens(self, out, discarded=0, real=0, expert_rows=0) -> None:
+    def note_tokens(self, out, discarded=0, real=0, expert_rows=0, expert_rows_kernel=0) -> None:
         pass
 
     def note_compile(self, key, aot=False) -> None:

@@ -416,3 +416,119 @@ def test_qwen2_moe_grouped_experts_match_dense_mixture_at_cell_widths():
         assert err < 2e-2, f"d{name} deviates from the dense mixture's by {err}"
     err = rel(got_grads["gate"]["kernel"], want_grads["gate"]["kernel"])
     assert err < 5e-2, f"the router's gradient deviates by {err}"
+
+
+# ------------------------------------------------------- grouped product
+
+
+#: the grouped product's operands in the benchmark's cells: (rows, contraction, columns, groups, the first group
+#: with rows, how many have them, rows in all); Mixtral's bank is the stack of 3 layers of which the second is read
+GROUPED_ON_CHIP = {
+    "mixtral_gate_and_up_doc_load": (4096, 4096, 14336, 24, 8, 8, 340),
+    "mixtral_down_chat_load": (4096, 14336, 4096, 24, 8, 8, 290),
+    "mixtral_gate_and_up_full_step": (4096, 4096, 14336, 24, 16, 8, 4096),
+    "qwen15_moe_gate_and_up": (16384, 2048, 1408, 60, 0, 60, 16384),
+    "qwen15_moe_down": (16384, 1408, 2048, 60, 0, 60, 16384),
+}
+
+
+def _grouped_operands(name):
+    import jax
+    import jax.numpy as jnp
+    m, k, n, g, first, some, live = GROUPED_ON_CHIP[name]
+    sizes = np.zeros(g, np.int32)
+    sizes[first:first + some] = np.random.default_rng(m + live).multinomial(live, np.ones(some) / some)
+    kl, kr, kw = jax.random.split(jax.random.PRNGKey(live), 3)
+    lhs = jax.random.normal(kl, (m, k), jnp.bfloat16)
+    rhs = jax.random.normal(kr, (g, k, n), jnp.bfloat16) * k**-0.5
+    weight = jax.random.normal(kw, (m, n), jnp.bfloat16) * (jnp.arange(m) < live)[:, None]
+    return lhs, rhs, jnp.asarray(sizes), live, weight
+
+
+def _ms(fn, *args, n=10):
+    import jax
+    out = jax.block_until_ready(fn(*args))
+    t0 = time.perf_counter()
+    for _ in range(n):
+        out = fn(*args)
+    jax.block_until_ready(out)
+    return out, (time.perf_counter() - t0) / n * 1e3
+
+
+def _rel(a, b):
+    a, b = np.asarray(a, np.float32), np.asarray(b, np.float32)
+    return float(np.linalg.norm(a - b) / max(np.linalg.norm(b), 1e-30))
+
+
+@pytest.mark.parametrize("name", list(GROUPED_ON_CHIP))
+def test_grouped_kernel_matches_ragged_dot_on_chip(name):
+    """``ds_gmm`` at a cell's operands against ``jax.lax.ragged_dot`` on the
+    same chip, over the groups' rows: both multiply bfloat16 and add in
+    float32, so they differ by the order of the sum."""
+    import jax
+    from deepspeed_tpu.ops.grouped_matmul import grouped_matmul, takes_kernel
+    assert takes_kernel()
+    lhs, rhs, sizes, live, _ = _grouped_operands(name)
+    kernel = jax.jit(grouped_matmul)
+    assert "ds_gmm" in kernel.lower(lhs, rhs, sizes).as_text()
+    got, got_ms = _ms(kernel, lhs, rhs, sizes)
+    want, want_ms = _ms(jax.jit(jax.lax.ragged_dot), lhs, rhs, sizes)
+    print(f"\n{name}: ds_gmm {got_ms:.3f} ms, ragged_dot {want_ms:.3f} ms")
+    assert got.dtype == want.dtype and got.shape == want.shape
+    assert np.isfinite(np.asarray(got[:live], np.float32)).all()
+    assert _rel(got[:live], want[:live]) < 4e-3
+
+
+@pytest.mark.parametrize("name", ["qwen15_moe_gate_and_up", "qwen15_moe_down"])
+def test_grouped_kernel_gradients_match_ragged_dots_on_chip(name):
+    """The ``custom_vjp`` at the train cell's operands, under
+    ``jax.checkpoint`` as the train step has it: the input gradient (``ds_gmm``
+    with the bank read transposed) and the weight gradient (``ds_tgmm``)
+    against ``ragged_dot``'s own."""
+    import jax
+    import jax.numpy as jnp
+    from deepspeed_tpu.ops.grouped_matmul import grouped_matmul
+    lhs, rhs, sizes, _, weight = _grouped_operands(name)
+
+    def grads(product):
+        loss = lambda a, b, s, w: jnp.sum((jax.checkpoint(product)(a, b, s) * w).astype(jnp.float32))  # noqa: E731
+        return jax.jit(jax.grad(loss, argnums=(0, 1)))
+
+    kernel = grads(grouped_matmul)
+    text = kernel.lower(lhs, rhs, sizes, weight).as_text()
+    assert "ds_gmm" in text and "ds_tgmm" in text and "ragged_dot" not in text
+    got, got_ms = _ms(kernel, lhs, rhs, sizes, weight)
+    want, want_ms = _ms(grads(jax.lax.ragged_dot), lhs, rhs, sizes, weight)
+    print(f"\n{name}: forward again and both gradients {got_ms:.3f} ms, ragged_dot's {want_ms:.3f} ms")
+    for g, w, which in zip(got, want, ("input", "bank")):
+        assert g.dtype == w.dtype and np.isfinite(np.asarray(g, np.float32)).all(), which
+        assert _rel(g, w) < 4e-3, (which, _rel(g, w))
+
+
+def test_rows_in_no_group_do_not_reach_the_experts_output_on_chip():
+    """A serving step's padding at Mixtral's widths (2 of 8 experts a token,
+    2,048 slots of which 170 carry a token): the padded rows hold NaN, sort
+    behind the last group and come out exact zeros; the live rows equal what
+    the same tokens give with zeros for padding; with a stack of banks and a
+    layer's index the same."""
+    import jax
+    import jax.numpy as jnp
+    from deepspeed_tpu.moe.sharded_moe import dropless_moe
+    d, f, e, s = 4096, 14336, 8, 2048
+    ks = jax.random.split(jax.random.PRNGKey(33), 5)
+    x = jax.random.normal(ks[0], (s, d), jnp.bfloat16)
+    logits = jax.random.normal(ks[1], (s, e), jnp.float32)
+    stack = tuple(jax.random.normal(k, (2, e) + shape, jnp.bfloat16) * 0.02
+                  for k, shape in zip(ks[2:], ((d, f), (d, f), (f, d))))
+    mask = jnp.arange(s) % 12 == 5
+    # the banks are arguments: a closed-over constant of 1.9 GB would be written into the program's text
+    run = jax.jit(lambda x, stack, layer: dropless_moe(x, logits, stack, 2, mask, None, layer))
+    assert "ds_gmm" in run.lower(x, stack, 1).as_text()
+    clean, _, counts = run(jnp.where(mask[:, None], x, 0), stack, 1)
+    dirty, _, _ = run(jnp.where(mask[:, None], x, jnp.nan), stack, 1)
+    assert int(counts.sum()) == 2 * int(mask.sum())
+    assert np.isfinite(np.asarray(dirty)).all()
+    assert not np.asarray(dirty)[~np.asarray(mask)].any()
+    np.testing.assert_array_equal(np.asarray(dirty), np.asarray(clean))
+    other, _, _ = run(x, stack, 0)
+    assert _rel(other[np.asarray(mask)], clean[np.asarray(mask)]) > 0.5  # another layer's banks: the index is read

@@ -112,3 +112,33 @@ def test_step_records_count_expert_rows(served):
     assert len(anat.steps) >= 3 and {r.path for r in anat.steps} >= {"prefill", "multi_decode"}
     assert all(r.expert_rows == r.tokens_real * CFG.num_experts_per_tok > 0 for r in anat.steps)
     assert all("expert_rows" in r.to_row() for r in anat.steps)
+
+
+@pytest.mark.parametrize("kernel_path", [True, False], ids=["one_tpu_device", "cpu_or_gspmd"])
+def test_step_records_count_the_rows_through_the_grouped_kernel(served, kernel_path, monkeypatch):
+    """``expert_rows_kernel``: where the grouped product is the kernel
+    ``ds_gmm`` (one TPU device; said here, the CPU's own answer is no) all of
+    a step's ``expert_rows`` if its slots take the sorted form, none if every
+    expert multiplies every row (a decode step, each round of a fused
+    dispatch); where the product is ``ragged_dot``, none at all."""
+    from deepspeed_tpu.inference.v2 import RaggedInferenceEngineConfig, build_engine, engine_v2
+    from deepspeed_tpu.inference.v2.scheduler import SchedulerConfig
+    from deepspeed_tpu.moe import sharded_moe
+    from deepspeed_tpu.serving import VirtualClock
+    from deepspeed_tpu.telemetry.step_anatomy import StepAnatomy
+    params, _ = served
+    if kernel_path:
+        monkeypatch.setattr(engine_v2, "takes_kernel", lambda: True)
+    monkeypatch.setattr(sharded_moe, "DENSE_UP_TO_TOKENS", 4)  # a decode bucket's rows stay dense, a chunk's slots do not
+    sched = SchedulerConfig(token_budget=64, max_seqs=4, prefill_chunk=CHUNK, decode_bucket=2)
+    eng = build_engine(CFG, params, RaggedInferenceEngineConfig(
+        kv=PagedKVConfig(num_pages=40, page_size=4, max_pages_per_seq=16), scheduler=sched,
+        kv_dtype=jnp.float32, decode_steps_per_dispatch=4, max_new_tokens=6))
+    anat = eng.set_anatomy(StepAnatomy(clock=VirtualClock()))
+    eng.generate([ROW, list(range(1, 13))], max_new_tokens=6)
+    chunked = [r for r in anat.steps if r.key.endswith(f":c{CHUNK}")]
+    decode = [r for r in anat.steps if r not in chunked]
+    assert chunked and decode and all(r.expert_rows > 0 for r in anat.steps)
+    assert all(r.expert_rows_kernel == (r.expert_rows if kernel_path else 0) for r in chunked)
+    assert all(r.expert_rows_kernel == 0 for r in decode)
+    assert all("expert_rows_kernel" in r.to_row() for r in anat.steps)

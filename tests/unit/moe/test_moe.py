@@ -301,3 +301,71 @@ def test_dropless_data_shards_route_their_own_tokens(dropless_form):
     one, _, one_counts = jax.jit(lambda p, x: layer.apply(p, x))(params, x)
     np.testing.assert_allclose(np.asarray(ep_out), np.asarray(one), atol=2e-6)
     np.testing.assert_array_equal(np.asarray(ep_counts), np.asarray(one_counts))
+
+
+# ------------------------------------------------- the grouped product's kernel
+
+
+@pytest.mark.parametrize("name", ["k2", "empty_expert", "one_expert", "masked_third"])
+def test_dropless_through_the_grouped_kernel(name, monkeypatch):
+    """The sorted form with its three products forced through ``ds_gmm``
+    (interpret mode; on the CPU the path is ``ragged_dot``): the per-token
+    loop's outputs, ``exp_counts`` unchanged, masked rows exact zeros, and
+    the same gradient for inputs and bank as through ``ragged_dot``."""
+    import functools
+    from deepspeed_tpu.moe import sharded_moe
+    from deepspeed_tpu.ops.grouped_matmul import grouped_matmul
+    x, logits, k, mask = _dropless_case(name)
+    s, e = logits.shape
+    monkeypatch.setattr(sharded_moe, "DENSE_UP_TO_TOKENS", 0)
+    rng = np.random.default_rng(11)
+    bank = tuple(jnp.asarray(rng.normal(size=shape).astype(np.float32) * 0.3)
+                 for shape in ((e, 16, 32), (e, 16, 32), (e, 32, 16)))
+    token_mask = None if mask is None else jnp.asarray(mask)
+    run = lambda x, bank: sharded_moe.dropless_moe(x, logits, bank, k, token_mask)  # noqa: E731
+    loss = lambda x, bank: jnp.sum(run(x, bank)[0]**2)  # noqa: E731
+    want, _, want_counts = run(x, bank)
+    want_grads = jax.grad(loss, argnums=(0, 1))(x, bank)
+
+    monkeypatch.setattr(sharded_moe, "grouped_matmul", functools.partial(grouped_matmul, interpret=True))
+    assert "pallas_call" in str(jax.make_jaxpr(run)(x, bank))
+    if mask is not None:  # what a masked row holds reaches no product
+        x = jnp.where(token_mask[:, None], x, jnp.nan)
+    out, _, counts = jax.jit(run)(x, bank)
+    idx = np.argsort(-np.asarray(logits), axis=-1, kind="stable")[:, :k]
+    np.testing.assert_allclose(np.asarray(out), np.asarray(_per_token_reference(x, logits, bank, k, idx, mask)),
+                               atol=1e-5, rtol=1e-5)
+    np.testing.assert_allclose(np.asarray(out), np.asarray(want), atol=1e-6, rtol=1e-6)
+    np.testing.assert_array_equal(np.asarray(counts), np.asarray(want_counts))
+    if mask is not None:
+        assert (np.asarray(out)[~mask] == 0.0).all()
+    for a, b in zip(jax.tree.leaves(jax.grad(loss, argnums=(0, 1))(x, bank)), jax.tree.leaves(want_grads)):
+        assert np.isfinite(np.asarray(a)).all()
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b), atol=1e-5, rtol=1e-4)
+
+
+@pytest.mark.parametrize("mesh_axes,kernel", [(None, True), (dict(data=1), True), (dict(data=4), True),
+                                              (dict(data=2, tensor=2), False), (dict(tensor=2), False)],
+                         ids=["no_mesh", "one_device", "fully_manual", "partly_manual", "gspmd_only"])
+def test_grouped_product_takes_the_kernel_only_where_one_device_runs_it(mesh_axes, kernel, monkeypatch):
+    """The path rule, with the platform said to be a TPU (nothing is lowered):
+    the kernel with no governing mesh, on one device and inside a
+    ``shard_map`` that is manual over every axis; ``ragged_dot`` where a
+    ``tensor`` axis is left to the compiler, inside ``shard_map`` or not."""
+    from deepspeed_tpu.comm.mesh import trace_mesh
+    from deepspeed_tpu.moe import sharded_moe
+    from deepspeed_tpu.ops import grouped_matmul as gm
+    monkeypatch.setattr(gm, "_traced_for_tpu", lambda: True)
+    monkeypatch.setattr(sharded_moe, "DENSE_UP_TO_TOKENS", 0)
+    mesh = None
+    if mesh_axes is not None:
+        mesh = create_mesh(MeshSpec(**mesh_axes), devices=jax.devices()[:int(np.prod(list(mesh_axes.values())))])
+    rng = np.random.default_rng(17)
+    x = jnp.asarray(rng.normal(size=(4, 8, 16)), jnp.float32)
+    logits = jnp.asarray(rng.normal(size=(4, 8, 4)), jnp.float32)
+    bank = tuple(jnp.asarray(rng.normal(size=shape), jnp.float32) for shape in ((4, 16, 32), (4, 16, 32), (4, 32, 16)))
+    with trace_mesh(mesh):
+        text = str(jax.make_jaxpr(lambda *a: sharded_moe.dropless_dispatch(*a, 2))(x, logits, bank))
+    assert ("pallas_call" in text) == kernel
+    assert ("ragged_dot" in text) != kernel
+    assert ("shard_map" in text) == (mesh_axes is not None and mesh_axes.get("data", 1) > 1)

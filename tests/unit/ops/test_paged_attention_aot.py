@@ -182,3 +182,33 @@ def test_scanned_twin_holds_no_second_arena(described_chip, no_compile_cache, pr
     assert mem.alias_size_in_bytes >= arena_bytes, "the donated arena is not the result's buffer"
     assert mem.temp_size_in_bytes < arena_bytes // 2, (mem.temp_size_in_bytes, arena_bytes)
     assert "tpu_custom_call" in compiled.as_text()
+    # the mixed step's 1,024 slots take the sorted form and its products the grouped kernel; a decode step's 8 do not
+    assert ("ds_gmm" in compiled.as_text()) == (program == "step_c128")
+
+
+#: the grouped product's operands in the benchmark's cells: (rows, contraction, columns, groups)
+GROUPED = {
+    "mixtral_gate_and_up_in_a_stack_of_3_layers": (4096, 4096, 14336, 24),
+    "mixtral_down_in_a_stack_of_3_layers": (4096, 14336, 4096, 24),
+    "qwen15_moe_gate_and_up": (16384, 2048, 1408, 60),
+    "qwen15_moe_down": (16384, 1408, 2048, 60),
+}
+
+
+@pytest.mark.parametrize("backward", [False, True], ids=["forward", "gradients"])
+@pytest.mark.parametrize("operands", list(GROUPED))
+def test_grouped_product_at_the_cells_operands(one_chip, no_compile_cache, operands, backward):
+    """``ds_gmm`` forward, and with ``ds_tgmm`` the gradients of both
+    operands, at the tiles ``ops/grouped_matmul.py`` picks for the serving
+    cells' banks (16 MiB of a bank a grid step, 52 MiB of VMEM asked for) and
+    the train cell's (a group's whole bank a step, an accumulator of 11.5 MiB):
+    what fits is the compiler's to say."""
+    from deepspeed_tpu.ops.grouped_matmul import grouped_matmul
+    m, k, n, g = GROUPED[operands]
+    sds = lambda shape, dtype=jnp.bfloat16: jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)  # noqa: E731
+    product = lambda a, b, s: grouped_matmul(a, b, s, interpret=False)  # noqa: E731
+    if backward:
+        product = jax.grad(lambda a, b, s: jnp.sum(grouped_matmul(a, b, s, interpret=False).astype(jnp.float32)), (0, 1))
+    lowered = jax.jit(product).lower(sds((m, k)), sds((g, k, n)), sds((g, ), jnp.int32))
+    assert ("ds_gmm" in lowered.as_text()) and (("ds_tgmm" in lowered.as_text()) == backward)
+    assert "tpu_custom_call" in lowered.compile().as_text()

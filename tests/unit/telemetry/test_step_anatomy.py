@@ -392,11 +392,11 @@ def test_annotate_factory_sees_step_and_marks_nested_with_counts():
     anat.mark("admit")
     anat.mark("schedule")
     anat.note_program("multi:b4:k8", "multi_decode", rows_decode=3,
-                      tokens_real=24, slots=32, expert_rows=48)
+                      tokens_real=24, slots=32, expert_rows=48, expert_rows_kernel=40)
     anat.mark("dispatch")
     clock.advance(0.8)
     anat.device_mark()
-    anat.note_tokens(20, 4)
+    anat.note_tokens(20, 4, expert_rows=2, expert_rows_kernel=2)
     anat.mark("sample_accept")
     anat.step_end()
     events = [(kind, name) for kind, name, _ in log]
@@ -411,7 +411,7 @@ def test_annotate_factory_sees_step_and_marks_nested_with_counts():
     assert log[-1][2] == {"index": 0, "key": "multi:b4:k8", "rows_decode": 3,
                           "rows_prefill": 0, "tokens_real": 24, "slots": 32,
                           "tokens_out": 20, "tokens_discarded": 4,
-                          "expert_rows": 48, "summary_rows_written": 0, "ring_wraps": 0,
+                          "expert_rows": 50, "expert_rows_kernel": 42, "summary_rows_written": 0, "ring_wraps": 0,
                           "attn_rows_visible": 0, "attn_rows_walked": 0, "state_slots_live": 0,
                           "ssm_rows": 0, "window_rows_visible": 0, "ssd_state_bytes": 0}
     # the counts ride the row and the per-program fold too
@@ -592,8 +592,8 @@ def test_counts_of_single_and_mixed_steps_by_hand(tiny_serving):
     fold = anat.by_shape()
     assert fold["step:b2:c8"]["tokens_real"] == 16 and fold["step:b2:c8"]["slots"] == 32
     assert all(r.tokens_real <= r.slots for r in anat.steps)
-    # a model with no expert layer sends no row through experts
-    assert all(r.expert_rows == 0 for r in anat.steps)
+    # a model with no expert layer sends no row through experts, or their kernel
+    assert all(r.expert_rows == 0 and r.expert_rows_kernel == 0 for r in anat.steps)
 
 
 def test_counts_of_a_fused_dispatch_with_overshoot(tiny_serving):
