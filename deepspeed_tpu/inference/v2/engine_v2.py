@@ -32,8 +32,9 @@ from ...comm.mesh import trace_mesh
 from ...moe import sharded_moe
 from ...ops.grouped_matmul import takes_kernel
 from ...ops.paged_attention import walk_block
-from ...telemetry.step_anatomy import NULL_ANATOMY
+from ...telemetry.step_anatomy import NULL_ANATOMY, StepAnatomy
 from ...utils.logging import logger
+from ...utils.nvtx import profiler_range
 from .ragged import BlockedKVCache, RaggedBatch, StateManager
 from .scheduler import SchedulerConfig, SplitFuseScheduler, StepPlan
 from .spec import SpecConfig, SpecStats, make_drafter
@@ -341,9 +342,13 @@ class InferenceEngineV2:
         self.rng = rng if rng is not None else jax.random.PRNGKey(0)
         self._max_new: Dict[int, int] = {}
         self._step_fns: Dict[Tuple[int, int], callable] = {}
-        # per-step anatomy (telemetry/step_anatomy.py): NULL by default —
-        # one attribute read + one predicate per hook when disabled
-        self.anatomy = NULL_ANATOMY
+        # per-step anatomy (telemetry/step_anatomy.py): every engine records
+        # its steps (a ring of the last 8,192, drawn into a running profile
+        # too); set_anatomy(None) switches to the NULL recorder, one
+        # attribute read and one predicate a hook.  A serving frontend moves
+        # the engine's own recorder, and no other, onto its clock.
+        self.anatomy = StepAnatomy(max_steps=8192, annotate=profiler_range)
+        self.anatomy_is_default = True
         self._fresh_compile = False
         self._param_sh = self._cache_sh = self._repl_sh = None
         if self.mesh is not None:
@@ -351,11 +356,12 @@ class InferenceEngineV2:
 
     def set_anatomy(self, anatomy):
         """Attach a :class:`~...telemetry.step_anatomy.StepAnatomy`
-        recorder (None restores the allocation-free NULL recorder).  The
-        recorder's clock should be the serving clock when a frontend
-        drives this engine, so host-gap windows and device charges live
-        in one time domain."""
+        recorder in place of the engine's own (None: the allocation-free
+        NULL recorder, recording off).  The recorder's clock should be the
+        serving clock when a frontend drives this engine, so host-gap
+        windows and device charges live in one time domain."""
         self.anatomy = anatomy if anatomy is not None else NULL_ANATOMY
+        self.anatomy_is_default = False
         return self.anatomy
 
     def _note_compile(self, key: str) -> None:
@@ -697,6 +703,8 @@ class InferenceEngineV2:
             # inside an open step window the compile time is attributed
             # explicitly; outside one, mark() is a no-op by design
             anat.mark("aot_compile")
+        # the step set is compiled: a compile from here on is a steady-state recompile
+        anat.mark_steady()
         return {"compiled": compiled, "cached": cached, "fallback": fallback,
                 "keys": [self._key_label(k) for k in keys]}
 
@@ -892,14 +900,15 @@ class InferenceEngineV2:
         fn = self._compiled_multi_step(batch, k)
         if anat.enabled:
             anat.note_program(self._key_label(("multi", batch, k)), "multi_decode",
-                              rows_decode=len(seqs), tokens_real=len(seqs) * k,
-                              slots=batch * k, **self._expert_rows(len(seqs) * k, batch),
-                              cache_counts=self._cache_counts([(s, k) for s in seqs], calls=k),
-                              state_counts=self._state_counts([(s, k) for s in seqs], calls=k))
+                              rows_decode=len(seqs), tokens_real=len(seqs) * k, slots=batch * k)
         toks, self.cache = self._invoke(fn, self.params, self.cache, jnp.asarray(rb.tokens[:, 0]),
                                         jnp.asarray(rb.start_pos), jnp.asarray(rb.block_tables),
                                         jnp.asarray(rb.chunk_lens), sub)
         if anat.enabled:
+            # the passes over the rows run with the program already enqueued
+            anat.note_counts(**self._expert_rows(len(seqs) * k, batch),
+                             cache_counts=self._cache_counts([(s, k) for s in seqs], calls=k),
+                             state_counts=self._state_counts([(s, k) for s in seqs], calls=k))
             anat.mark("compile_wait" if self._fresh_compile else "dispatch")
         inf = InFlightStep("multi")
         inf.tokens = toks
@@ -973,12 +982,11 @@ class InferenceEngineV2:
 
     def _state_counts(self, work, calls: int = 1) -> dict:
         """The step records' counts of a geometry with state slots (none
-        without): the geometry's ``state_counts`` summed over the step's
-        rows, and the slots that sequences hold."""
+        without): the geometry's ``state_counts`` summed over the step's rows."""
         geometry = self.kv.geometry
         if not geometry.state_slots:
             return {}
-        total = {"state_slots_live": len(self.state.seqs)}
+        total = {}
         for s, n in work:
             for name, count in geometry.state_counts(s.seen_tokens, n, calls).items():
                 total[name] = total.get(name, 0) + count
@@ -1108,13 +1116,14 @@ class InferenceEngineV2:
             tokens_real = sum(n for _, n in work)
             anat.note_program(self._key_label((batch, chunk)), path,
                               rows_decode=len(plan.decode), rows_prefill=len(plan.prefill),
-                              tokens_real=tokens_real, slots=batch * chunk,
-                              **self._expert_rows(tokens_real, batch * chunk),
-                              cache_counts=self._cache_counts(work), state_counts=self._state_counts(work))
+                              tokens_real=tokens_real, slots=batch * chunk)
         next_tok, self.cache = self._invoke(fn, self.params, self.cache, jnp.asarray(rb.tokens),
                                             jnp.asarray(rb.start_pos), jnp.asarray(rb.block_tables),
                                             jnp.asarray(rb.chunk_lens), sub)
         if anat.enabled:
+            # the passes over the rows run with the program already enqueued
+            anat.note_counts(**self._expert_rows(tokens_real, batch * chunk),
+                             cache_counts=self._cache_counts(work), state_counts=self._state_counts(work))
             anat.mark("compile_wait" if self._fresh_compile else "dispatch")
         inf = InFlightStep("single")
         inf.tokens = next_tok

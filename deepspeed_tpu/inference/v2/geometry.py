@@ -76,15 +76,14 @@ class LinearGeometry:
 
     def step_counts(self, start: int, n_tokens: int, block_rows: int = 0, calls: int = 1) -> tuple:
         """What feeding tokens ``start .. start + n_tokens - 1`` does to the
-        cache, for the step records: (``summary_rows_written``,
-        ``ring_wraps``, ``attn_rows_visible``, ``attn_rows_walked``).  Nothing
-        here has summaries or a ring; token ``t`` sees rows ``0 .. t``.  The
+        cache, for the step records: (``attn_rows_visible``,
+        ``attn_rows_walked``).  Token ``t`` sees rows ``0 .. t``.  The
         tokens go through the paged kernel in ``calls`` calls of equal length
         (one chunk, or the fused rung's one token a step), and a call walks
         whole blocks of ``block_rows`` key rows up to its last token's last
         visible row, for every one of its tokens."""
         t = np.arange(start, start + n_tokens)
-        return 0, 0, int((t + 1).sum()), _rows_walked(t, block_rows, calls)
+        return int((t + 1).sum()), _rows_walked(t, block_rows, calls)
 
 
 class RingSummaryGeometry:
@@ -143,15 +142,12 @@ class RingSummaryGeometry:
         return min(n_tokens, self.window - start % self.window)
 
     def step_counts(self, start: int, n_tokens: int, block_rows: int = 0, calls: int = 1) -> tuple:
-        """(chunks that complete, tokens that start a window after the first,
-        ring rows plus summary rows the queries can see, summed over them,
-        and the rows the kernel's walk covers for them: as the linear
+        """(ring rows plus summary rows the queries can see, summed over
+        them, and the rows the kernel's walk covers for them: as the linear
         geometry's, over the rows of the kernel's view, summaries first)."""
         t = np.arange(start, start + n_tokens)
         seen = t % self.window + t // self.window * (self.window // self.page_size)   # rows below the query's own
-        return ((start + n_tokens) // self.page_size - start // self.page_size,
-                int(np.count_nonzero((t % self.window == 0) & (t > 0))), int((seen + 1).sum()),
-                _rows_walked(seen, block_rows, calls))
+        return int((seen + 1).sum()), _rows_walked(seen, block_rows, calls)
 
 
 class SlotPagesGeometry(LinearGeometry):

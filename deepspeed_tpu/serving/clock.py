@@ -63,6 +63,9 @@ class WallClock:
     """Monotonic wall time (zeroed at construction so timestamps are small
     and comparable with VirtualClock-based configs)."""
 
+    #: seconds of real time: a step recorder on it also reads CPU and collector time
+    real_time = True
+
     def __init__(self):
         self._t0 = time.monotonic()
 

@@ -106,13 +106,18 @@ class ServingEngine:
         self._uids = itertools.count(max(engine.state.seqs.keys(), default=-1) + 1)
         self._events_step = 0
         self._t0 = self.clock.now()
-        # step-anatomy fold cursor (telemetry/step_anatomy.py): compiles
-        # already bridged into metrics/events.  It starts at the
-        # recorder's CURRENT log length so pre-frontend warm-up compiles
-        # (harnesses warm before building the frontend) are not re-counted
-        # as serving-time recompiles.
-        self._compiles_seen = len(getattr(engine, "anatomy",
-                                          NULL_ANATOMY).compiles)
+        # the engine's own step recorder (telemetry/step_anatomy.py) moves
+        # onto this frontend's clock, so a step's end_ts lies on the clock
+        # the caller times its ticks on; a recorder somebody brought
+        # (set_anatomy) keeps the clock it was made with
+        anat = getattr(engine, "anatomy", NULL_ANATOMY)
+        if getattr(engine, "anatomy_is_default", False):
+            anat.rebind(self.clock)
+        # step-anatomy fold cursor: compiles already bridged into
+        # metrics/events.  It starts at the recorder's CURRENT log length
+        # so pre-frontend warm-up compiles (harnesses warm before building
+        # the frontend) are not re-counted as serving-time recompiles.
+        self._compiles_seen = len(anat.compiles)
         # EWMA of clock-seconds per tick-with-work (load_stats input for the
         # fleet router's least-loaded policy); None until the first step runs
         self._ewma_step_s: Optional[float] = None

@@ -22,7 +22,7 @@ from .metrics import (Counter, Gauge, Histogram, HistogramWindow,
 from .slo import BurnRateConfig, SLOBurnMonitor
 from .spans import PHASE_OF_STATE, emit_attempt_spans, phase_intervals
 from .step_anatomy import (HOST_SEGMENTS, NULL_ANATOMY, NullStepAnatomy,
-                           StepAnatomy)
+                           StepAnatomy, recorders)
 from .trace import (NULL_SPAN, NULL_TRACER, NullTracer, PerfClock, Span,
                     Tracer)
 
@@ -34,5 +34,6 @@ __all__ = [
     "BurnRateConfig", "SLOBurnMonitor",
     "PHASE_OF_STATE", "emit_attempt_spans", "phase_intervals",
     "HOST_SEGMENTS", "NULL_ANATOMY", "NullStepAnatomy", "StepAnatomy",
+    "recorders",
     "NULL_SPAN", "NULL_TRACER", "NullTracer", "PerfClock", "Span", "Tracer",
 ]

@@ -63,15 +63,24 @@ to kill.  This module decomposes EVERY engine step into:
   kernel ``ds_gmm``: all of a mixed step's on a TPU of one device, none of a
   decode step's, where every expert multiplies every row, and none where the
   product is ``jax.lax.ragged_dot``)
-  and, for a cache of exact and summary pages (chunked linear attention; 0
-  under the linear geometry), ``summary_rows_written`` (chunks that
-  completed in the step) and ``ring_wraps`` (token rows that started a
-  window after the first); under either geometry ``attn_rows_visible`` (key
-  rows a query could see: ring rows plus summary rows, or its whole history;
-  summed over the step's token rows, one layer) and ``attn_rows_walked``
-  (key rows the paged kernel's walk covered for them: whole blocks up to
-  the last row its call could see, 0 where the attention does not read
-  through the kernel; ``walked / visible`` is the kernel's tightness).
+  and, under every cache geometry, ``attn_rows_visible`` (key rows a query
+  could see: ring rows plus summary rows, or its whole history; summed over
+  the step's token rows, one layer) and ``attn_rows_walked`` (key rows the
+  paged kernel's walk covered for them: whole blocks up to the last row its
+  call could see, 0 where the attention does not read through the kernel;
+  ``visible / walked`` is the kernel's tightness).  The engine notes the
+  program's key and its rows before it enqueues the program and the counts
+  that take a pass over the rows (``note_counts``) after, under the device's
+  busy time.
+
+* on a real clock (``PerfClock``, ``WallClock``: ``real_time = True``), two
+  readings that say what held a slow step, neither a part of the tiling:
+  ``cpu_s`` (``time.thread_time()`` from ``step_begin`` to ``step_end``: CPU
+  the serving thread burned; ``wall_s - host_gap_s - device_s - cpu_s`` is
+  time it was blocked or descheduled) and ``gc_s`` (seconds inside Python's
+  collector during the step, from one ``gc.callbacks`` hook the first such
+  recorder installs).  Both stay 0.0 under a virtual clock, so two runs of
+  one seed still export the same bytes.
 
 The decomposition TILES by construction: every component is a
 non-negative clock difference (or an explicit charge), and
@@ -93,6 +102,20 @@ segment's name is known when it ENDS — so a reader rebuilds segment
 begin) to the mark; what lies between the last mark and the range's end
 is ``bookkeeping``.  The module itself stays free of jax.
 
+A step that **falls behind** says so.  When a step closes, its own time
+(``wall_s - host_gap_s``) is compared with the median of the last 64 closed
+steps of the same program key; with at least 16 of them, a step over 4 x
+that median and at least 0.25 s over it is *slow*: its row goes into
+``slow_steps`` (a ring of 256) and one ``ds.slow_step`` line goes to the
+program's logger, one a second at most, with the gap before it, the wait at
+the readback, the two largest host segments, ``cpu_s``, ``gc_s`` and what
+the step carried.  ``host_gap_s`` is printed and left out of the rule: a
+caller's idle waits lie there too.
+
+Every recorder is reachable in its process: :func:`recorders` gives the
+live ones, weakly held, oldest first (what the benchmark's readers of the
+step records call, and what a server's debug endpoint would).
+
 A **compile tracker** rides along: every JIT cache miss the engine
 reports (``note_compile``) is tagged warm-up or — after
 :meth:`mark_steady` — an *unexpected steady-state recompile*, the
@@ -106,13 +129,18 @@ Deliberately stdlib-only (no jax import): the engine imports it at
 module scope and ``scripts/step_anatomy.py`` stays standalone.
 """
 
+import gc
+import statistics
+import time
+import weakref
 from collections import deque
 from typing import Dict, List, Optional
 
+from ..utils.logging import logger
 from .trace import PerfClock
 
 __all__ = ["HOST_SEGMENTS", "COUNTS", "StepAnatomy", "NullStepAnatomy", "NULL_ANATOMY",
-           "StepRecord", "CompileRecord"]
+           "StepRecord", "CompileRecord", "recorders"]
 
 #: the closed host-segment vocabulary; every step exports all of them
 #: (zero-filled) so the per-step table has one fixed shape
@@ -122,19 +150,47 @@ HOST_SEGMENTS = ("admit", "schedule", "draft_plan", "verify_plan",
 
 #: what a step carried; zero until the engine notes them
 COUNTS = ("rows_decode", "rows_prefill", "tokens_real", "slots", "tokens_out",
-          "tokens_discarded", "expert_rows", "expert_rows_kernel", "summary_rows_written", "ring_wraps",
-          "attn_rows_visible", "attn_rows_walked", "state_slots_live", "ssm_rows", "window_rows_visible",
-          "ssd_state_bytes")
+          "tokens_discarded", "expert_rows", "expert_rows_kernel", "attn_rows_visible", "attn_rows_walked",
+          "ssm_rows", "window_rows_visible", "ssd_state_bytes")
 
 #: names of the instant profiler events, built once (a mark allocates no string)
 _MARK_NAMES = {s: "ds.mark." + s for s in HOST_SEGMENTS + ("device_wait", )}
+
+#: the slow-step rule: a step's own time against the median of the last
+#: SLOW_HISTORY closed steps of its key, once SLOW_MIN_STEPS of them are there
+SLOW_HISTORY, SLOW_MIN_STEPS = 64, 16
+SLOW_FACTOR, SLOW_OVER_S = 4.0, 0.25
+SLOW_RING = 256
+SLOW_LOG_EVERY_S = 1.0
+
+_RECORDERS: List[weakref.ref] = []
+
+
+def recorders() -> list:
+    """The live recorders of this process, oldest first.  Weakly held: a
+    recorder goes with its engine (or whoever else made it), and its
+    reference leaves the list with it."""
+    return [rec for ref in list(_RECORDERS) if (rec := ref()) is not None]
+
+
+_gc_total_s = 0.0   # seconds inside Python's collector since the hook went in
+_gc_t0 = None
+
+
+def _gc_hook(phase, info):
+    global _gc_total_s, _gc_t0
+    if phase == "start":
+        _gc_t0 = time.perf_counter()  # dslint-ok(determinism): the collector runs in real time; only a recorder on a real clock reads the sum
+    elif _gc_t0 is not None:
+        _gc_total_s += time.perf_counter() - _gc_t0  # dslint-ok(determinism): as above
+        _gc_t0 = None
 
 
 class StepRecord:
     """One recorded engine step (mutable only via the recorder)."""
 
     __slots__ = ("index", "key", "path", "segments", "device_s", "host_gap_s",
-                 "wall_s", "after_idle", "compiles", "end_ts") + COUNTS
+                 "wall_s", "after_idle", "compiles", "end_ts", "cpu_s", "gc_s") + COUNTS
 
     def __init__(self, index: int):
         self.index = index
@@ -147,16 +203,21 @@ class StepRecord:
         self.after_idle = False
         self.compiles = 0                    # JIT cache misses THIS step paid for
         self.end_ts = 0.0                    # recorder-clock time at step end
+        self.cpu_s = 0.0                     # real clock: CPU the stepping thread burned in the step
+        self.gc_s = 0.0                      # real clock: seconds inside Python's collector in the step
         self.rows_decode = self.rows_prefill = 0
         self.tokens_real = self.slots = 0
         self.tokens_out = self.tokens_discarded = 0
         self.expert_rows = self.expert_rows_kernel = 0
-        self.summary_rows_written = self.ring_wraps = 0
         self.attn_rows_visible = self.attn_rows_walked = 0
-        self.state_slots_live = self.ssm_rows = self.window_rows_visible = self.ssd_state_bytes = 0
+        self.ssm_rows = self.window_rows_visible = self.ssd_state_bytes = 0
 
     def host_s(self) -> float:
         return sum(self.segments.values())
+
+    def own_s(self) -> float:
+        """The step without the gap before it: host segments and the wait at the readback."""
+        return self.wall_s - self.host_gap_s
 
     def counts(self) -> Dict[str, int]:
         return {c: getattr(self, c) for c in COUNTS}
@@ -173,6 +234,8 @@ class StepRecord:
             "host_gap_s": round(self.host_gap_s, 9),
             "wall_s": round(self.wall_s, 9),
             "end_ts": round(self.end_ts, 9),
+            "cpu_s": round(self.cpu_s, 9),
+            "gc_s": round(self.gc_s, 9),
             "after_idle": self.after_idle,
             "compiles": self.compiles,
         }
@@ -218,9 +281,12 @@ class StepAnatomy:
     def __init__(self, clock=None, max_steps: int = 4096, annotate=None):
         if max_steps < 1:
             raise ValueError(f"max_steps must be >= 1, got {max_steps}")
-        self.clock = clock if clock is not None else PerfClock()
         self.steps = deque(maxlen=int(max_steps))
         self.dropped_steps = 0
+        #: rows of the steps the slow-step rule caught, with ``own_s`` and ``median_s``
+        self.slow_steps = deque(maxlen=SLOW_RING)
+        self._own_by_key: Dict[str, deque] = {}
+        self._slow_logged: Optional[float] = None   # clock time of the last ds.slow_step line
         self.compiles: List[CompileRecord] = []
         self.steady_state_recompiles = 0
         #: monotonic count of CLOSED steps (deque eviction never rewinds it)
@@ -239,6 +305,27 @@ class StepAnatomy:
         self._t = 0.0           # segment cursor
         self._annotate = annotate
         self._range = None      # the open step's ``ds.step`` profiler range
+        self._cpu0 = self._gc0 = 0.0
+        self._bind(clock if clock is not None else PerfClock())
+        _RECORDERS.append(weakref.ref(self, _RECORDERS.remove))
+
+    def _bind(self, clock) -> None:
+        self.clock = clock
+        # cpu_s and gc_s are read on a clock of real time only
+        self._real = bool(getattr(clock, "real_time", False))
+        if self._real and _gc_hook not in gc.callbacks:
+            gc.callbacks.append(_gc_hook)
+
+    def rebind(self, clock) -> None:
+        """Move the recorder onto another clock (the serving frontend's, so
+        ``end_ts`` lies on the clock its caller times the ticks on).  Refused
+        while a step is open; the gap origin resets, since the two clocks
+        share no zero."""
+        if self._cur is not None:
+            raise RuntimeError("StepAnatomy.rebind: a step is open")
+        self._bind(clock)
+        self._last_end = None
+        self._slow_logged = None
 
     # ------------------------------------------------------------- lifecycle
 
@@ -265,6 +352,8 @@ class StepAnatomy:
         self._cur.after_idle = self._after_idle
         self._after_idle = False
         self._t = t
+        if self._real:
+            self._cpu0, self._gc0 = time.thread_time(), _gc_total_s
         if self._annotate is not None:
             self._range = self._annotate("ds.step")
             self._range.__enter__()
@@ -294,24 +383,30 @@ class StepAnatomy:
             self._cur.device_s += self._advance("device_wait")
 
     def note_program(self, key: str, path: str, rows_decode: int = 0,
-                     rows_prefill: int = 0, tokens_real: int = 0,
-                     slots: int = 0, expert_rows: int = 0, expert_rows_kernel: int = 0,
-                     cache_counts: tuple = (0, 0, 0, 0), state_counts: Optional[dict] = None) -> None:
+                     rows_prefill: int = 0, tokens_real: int = 0, slots: int = 0) -> None:
         """Tag the open step with the program it dispatches (``key``, as
         ``InferenceEngineV2._key_label`` prints it: the attribution key)
-        and what the packed batch carries (``cache_counts``: the geometry's
-        ``step_counts`` summed over the rows).  A step that never dispatches
-        (empty plan) keeps ``path=None`` and is DISCARDED at step_end:
-        its host time folds into the next real step's host gap, which is
-        exactly what that time is (loop tax without device work)."""
+        and the rows and positions of the packed batch.  A step that never
+        dispatches (empty plan) keeps ``path=None`` and is DISCARDED at
+        step_end: its host time folds into the next real step's host gap,
+        which is exactly what that time is (loop tax without device work)."""
         cur = self._cur
         if cur is not None:
             cur.key, cur.path = key, path
             cur.rows_decode, cur.rows_prefill = int(rows_decode), int(rows_prefill)
             cur.tokens_real, cur.slots = int(tokens_real), int(slots)
+
+    def note_counts(self, expert_rows: int = 0, expert_rows_kernel: int = 0,
+                    cache_counts: tuple = (0, 0), state_counts: Optional[dict] = None) -> None:
+        """What the packed batch carries beyond its rows, noted once the
+        program is enqueued (the passes over the rows then run under the
+        device's busy time): the rows through the experts, ``cache_counts``
+        (the geometry's ``step_counts`` summed over the rows: visible,
+        walked) and ``state_counts`` (its ``state_counts``, by name)."""
+        cur = self._cur
+        if cur is not None:
             cur.expert_rows, cur.expert_rows_kernel = int(expert_rows), int(expert_rows_kernel)
-            (cur.summary_rows_written, cur.ring_wraps, cur.attn_rows_visible,
-             cur.attn_rows_walked) = (int(c) for c in cache_counts)
+            cur.attn_rows_visible, cur.attn_rows_walked = (int(c) for c in cache_counts)
             for name, count in (state_counts or {}).items():
                 setattr(cur, name, int(count))
 
@@ -389,9 +484,39 @@ class StepAnatomy:
         cur.host_gap_s = self._gap0
         cur.wall_s = cur.host_gap_s + cur.host_s() + cur.device_s
         cur.end_ts = t
+        if self._real:
+            cur.cpu_s, cur.gc_s = time.thread_time() - self._cpu0, _gc_total_s - self._gc0
         self._last_end = t
         self._retain(cur)
+        self._judge(cur)
         return cur
+
+    def _judge(self, rec: StepRecord) -> None:
+        """The slow-step rule (module docstring): compare the closed step's
+        own time with the median of its key's last steps, then add it to them."""
+        own = rec.own_s()
+        history = self._own_by_key.get(rec.key)
+        if history is None:
+            history = self._own_by_key[rec.key] = deque(maxlen=SLOW_HISTORY)
+        if own >= SLOW_OVER_S and len(history) >= SLOW_MIN_STEPS:   # the median only where it can matter
+            median = statistics.median(history)
+            if own > SLOW_FACTOR * median and own - median >= SLOW_OVER_S:
+                self._slow(rec, own, median)
+        history.append(own)
+
+    def _slow(self, rec: StepRecord, own: float, median: float) -> None:
+        self.slow_steps.append({**rec.to_row(), "own_s": round(own, 9), "median_s": round(median, 9)})
+        if self._slow_logged is not None and 0 <= rec.end_ts - self._slow_logged < SLOW_LOG_EVERY_S:
+            return
+        self._slow_logged = rec.end_ts
+        top = sorted(HOST_SEGMENTS, key=lambda s: -rec.segments[s])[:2]
+        logger.warning(
+            f"ds.slow_step index={rec.index} key={rec.key} own_s={own:.6f} median_s={median:.6f} "
+            f"host_gap_s={rec.host_gap_s:.6f} device_wait_s={rec.device_s:.6f} "
+            + " ".join(f"{s}={rec.segments[s]:.6f}" for s in top) +
+            f" cpu_s={rec.cpu_s:.6f} gc_s={rec.gc_s:.6f} compiles={rec.compiles} "
+            f"rows_decode={rec.rows_decode} rows_prefill={rec.rows_prefill} "
+            f"tokens_real={rec.tokens_real} slots={rec.slots}")
 
     def charge_last_step(self, dt: float) -> Optional[StepRecord]:
         """Device charge for clock-driven frontends: a ``VirtualClock``/
@@ -440,6 +565,8 @@ class StepAnatomy:
         measured host-gap fractions; warm-up COMPILES must stay on the
         record, they are what 'steady state' is defined against)."""
         self.steps.clear()
+        self.slow_steps.clear()
+        self._own_by_key.clear()
         self.dropped_steps = 0
         self.total_steps = 0
         self.total_wall_s = self.total_host_s = 0.0
@@ -523,6 +650,7 @@ class StepAnatomy:
             "steps": self.total_steps,
             "retained_steps": len(self.steps),
             "dropped_steps": self.dropped_steps,
+            "slow_steps": len(self.slow_steps),
             "wall_s": round(self.total_wall_s, 9),
             "host_s": round(self.total_host_s, 9),
             "device_s": round(self.total_device_s, 9),
@@ -572,9 +700,10 @@ class NullStepAnatomy:
     def device_mark(self) -> None:
         pass
 
-    def note_program(self, key, path, rows_decode=0, rows_prefill=0,
-                     tokens_real=0, slots=0, expert_rows=0, expert_rows_kernel=0, cache_counts=(0, 0, 0),
-                     state_counts=None) -> None:
+    def note_program(self, key, path, rows_decode=0, rows_prefill=0, tokens_real=0, slots=0) -> None:
+        pass
+
+    def note_counts(self, expert_rows=0, expert_rows_kernel=0, cache_counts=(0, 0), state_counts=None) -> None:
         pass
 
     def note_tokens(self, out, discarded=0, real=0, expert_rows=0, expert_rows_kernel=0) -> None:

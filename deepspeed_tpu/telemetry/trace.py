@@ -40,6 +40,9 @@ class PerfClock:
     """Default tracer clock: ``time.perf_counter`` zeroed at construction
     (matches WallClock's small-comparable-timestamps convention)."""
 
+    #: seconds of real time: a step recorder on it also reads CPU and collector time
+    real_time = True
+
     def __init__(self):
         self._t0 = time.perf_counter()
 

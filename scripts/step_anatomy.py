@@ -46,9 +46,8 @@ HOST_SEGMENTS = ("admit", "schedule", "draft_plan", "verify_plan",
 
 #: must mirror telemetry/step_anatomy.py COUNTS — what a step carried
 COUNTS = ("rows_decode", "rows_prefill", "tokens_real", "slots", "tokens_out",
-          "tokens_discarded", "expert_rows", "expert_rows_kernel", "summary_rows_written", "ring_wraps",
-          "attn_rows_visible", "attn_rows_walked", "state_slots_live", "ssm_rows", "window_rows_visible",
-          "ssd_state_bytes")
+          "tokens_discarded", "expert_rows", "expert_rows_kernel", "attn_rows_visible", "attn_rows_walked",
+          "ssm_rows", "window_rows_visible", "ssd_state_bytes")
 
 
 def fold(anatomy, tol=1e-6):

@@ -44,7 +44,7 @@ HAND_WORKED = {
 @pytest.mark.parametrize("case", list(HAND_WORKED))
 def test_rows_visible_and_walked_by_hand(case):
     geometry, args, (visible, walked) = HAND_WORKED[case]
-    assert geometry.step_counts(*args)[2:] == (visible, walked)
+    assert geometry.step_counts(*args) == (visible, walked)
 
 
 @pytest.mark.parametrize("attention_impl", ["flash", "reference"])
@@ -80,3 +80,40 @@ def test_a_linear_engines_records_fill_both_counts(attention_impl):
             assert 0 <= slack < r["tokens_real"] * (block + 32), r
         else:
             assert r["attn_rows_walked"] == 0 < r["attn_rows_visible"], r
+
+
+@pytest.mark.parametrize("k", [1, 4])
+def test_the_counts_are_noted_after_the_enqueue_and_read_the_same(k):
+    """The program's key and rows are on the open step before ``_invoke``
+    enqueues it; the passes over the rows (``_cache_counts``) run after, and
+    give what they would have given before: nothing between the two moves a
+    sequence's ``seen_tokens``.  Both dispatch sites (``k``: the single step
+    and the fused rung)."""
+    cfg = LlamaConfig(vocab_size=128, hidden_size=64, intermediate_size=128, num_hidden_layers=2,
+                      num_attention_heads=4, num_key_value_heads=2, max_position_embeddings=512,
+                      dtype=jnp.float32, scan_layers=True, remat=False, attention_impl="flash")
+    params = nn.meta.unbox(LlamaForCausalLM(cfg).init(jax.random.PRNGKey(0), jnp.zeros((1, 8), jnp.int32)))
+    eng = InferenceEngineV2(cfg, params, RaggedInferenceEngineConfig(
+        kv=PagedKVConfig(num_pages=64, page_size=8, max_pages_per_seq=24),
+        scheduler=SchedulerConfig(token_budget=64, max_seqs=4, prefill_chunk=32, decode_bucket=4),
+        max_new_tokens=8, enable_prefix_cache=False, decode_steps_per_dispatch=k, kv_dtype=jnp.float32))
+    anat, invoke, counts, events = eng.anatomy, eng._invoke, eng._cache_counts, []
+
+    def spy_invoke(fn, *args):
+        cur = anat._cur
+        assert cur.key is not None and cur.slots > 0 and cur.attn_rows_visible == 0 == cur.attn_rows_walked
+        events.append(("invoke", {uid: s.seen_tokens for uid, s in eng.state.seqs.items()}))
+        return invoke(fn, *args)
+
+    def spy_counts(work, calls=1):
+        got = counts(work, calls)
+        events.append(("counts", {s.uid: s.seen_tokens for s, _ in work}, got))
+        return got
+
+    eng._invoke, eng._cache_counts = spy_invoke, spy_counts
+    eng.generate([np.arange(1, 71).tolist(), np.arange(1, 21).tolist()], max_new_tokens=8)
+    assert [e[0] for e in events] == ["invoke", "counts"] * len(anat.steps) and len(anat.steps) >= 4
+    for (_, at_enqueue), (_, at_count, got), rec in zip(events[::2], events[1::2], anat.steps):
+        assert all(at_enqueue[uid] == seen for uid, seen in at_count.items())      # as they stood before the enqueue
+        assert got == (rec.attn_rows_visible, rec.attn_rows_walked) and 0 < got[0] <= got[1]
+    assert any(r.key.startswith("multi:") for r in anat.steps) == (k > 1)

@@ -456,7 +456,6 @@ def test_step_records_count_the_state_bytes_a_step_moves(params, ids):
     fed = sum(r["tokens_real"] for r in rows)
     assert sum(r["ssm_rows"] for r in rows) == fed and all(r["window_rows_visible"] == 0 for r in rows)
     assert sum(r["attn_rows_visible"] for r in rows) == int((np.arange(fed) + 1).sum())
-    assert all(r["state_slots_live"] == 1 for r in rows)
     state = slot_state_bytes(CFG)
     assert state == 4 * 6 * 16 * 32 * 32
     for r in rows:      # a chunk step moves the row's states once each way, a fused dispatch of k rounds k times

@@ -337,7 +337,6 @@ def test_step_records_count_slots_scan_rows_and_window_rows(params, ids):
     assert sum(r["ssm_rows"] for r in rows) == fed
     assert sum(r["window_rows_visible"] for r in rows) == int(np.minimum(t + 1, WINDOW).sum())
     assert sum(r["attn_rows_visible"] for r in rows) == int((t + 1).sum())
-    assert all(r["state_slots_live"] == 1 for r in rows)
 
 
 # ------------------------------------------------------- (d) what is refused, in words

@@ -143,6 +143,7 @@ def test_disabled_tracer_serving_loop_allocates_nothing_telemetric(trained_param
     import os
     serve = _serve(trained_params)          # NULL_TRACER default
     assert not serve.tracer.enabled
+    serve.engine.set_anatomy(None)          # and the step recorder off: its ring keeps a record a step by design
 
     def round_trip(tag):
         serve.submit([5, 9, 2, tag % 100 + 1], max_new_tokens=4)

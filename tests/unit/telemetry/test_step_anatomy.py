@@ -391,8 +391,8 @@ def test_annotate_factory_sees_step_and_marks_nested_with_counts():
     clock.advance(0.2)
     anat.mark("admit")
     anat.mark("schedule")
-    anat.note_program("multi:b4:k8", "multi_decode", rows_decode=3,
-                      tokens_real=24, slots=32, expert_rows=48, expert_rows_kernel=40)
+    anat.note_program("multi:b4:k8", "multi_decode", rows_decode=3, tokens_real=24, slots=32)
+    anat.note_counts(expert_rows=48, expert_rows_kernel=40)   # as the engine does: once the program is enqueued
     anat.mark("dispatch")
     clock.advance(0.8)
     anat.device_mark()
@@ -411,8 +411,8 @@ def test_annotate_factory_sees_step_and_marks_nested_with_counts():
     assert log[-1][2] == {"index": 0, "key": "multi:b4:k8", "rows_decode": 3,
                           "rows_prefill": 0, "tokens_real": 24, "slots": 32,
                           "tokens_out": 20, "tokens_discarded": 4,
-                          "expert_rows": 50, "expert_rows_kernel": 42, "summary_rows_written": 0, "ring_wraps": 0,
-                          "attn_rows_visible": 0, "attn_rows_walked": 0, "state_slots_live": 0,
+                          "expert_rows": 50, "expert_rows_kernel": 42,
+                          "attn_rows_visible": 0, "attn_rows_walked": 0,
                           "ssm_rows": 0, "window_rows_visible": 0, "ssd_state_bytes": 0}
     # the counts ride the row and the per-program fold too
     row = anat.last_step.to_row()
@@ -427,10 +427,12 @@ def test_annotate_factory_sees_step_and_marks_nested_with_counts():
 
 
 def test_null_anatomy_never_calls_the_factory(tiny_serving):
-    """The disabled path builds no range: an engine without a recorder
-    serves with ``NULL_ANATOMY``, which has no factory to call (the
-    tracemalloc test above pins that it allocates nothing either)."""
+    """The disabled path builds no range: an engine whose recorder was
+    switched off serves with ``NULL_ANATOMY``, which has no factory to call
+    (the tracemalloc test above pins that it allocates nothing either)."""
     eng = tiny_serving()
+    assert eng.anatomy.enabled and eng.anatomy._annotate is not None    # the engine's own recorder draws ranges
+    eng.set_anatomy(None)
     assert eng.anatomy is NULL_ANATOMY and not hasattr(NULL_ANATOMY, "_annotate")
     NULL_ANATOMY.step_begin(hold=True)
     NULL_ANATOMY.note_program("step:b4:c1", "decode", tokens_real=1, slots=4)
@@ -486,35 +488,6 @@ def test_flight_recorder_holds_no_copy_of_the_steps(tiny_serving):
 # ----------------------------- serving-engine integration (tiny model)
 
 
-@pytest.fixture(scope="module")
-def tiny_serving():
-    import jax
-    import jax.numpy as jnp
-
-    from deepspeed_tpu.inference.v2 import (RaggedInferenceEngineConfig,
-                                            build_engine)
-    from deepspeed_tpu.inference.v2.scheduler import SchedulerConfig
-    from deepspeed_tpu.models.llama import LlamaConfig, LlamaForCausalLM
-    from deepspeed_tpu.models.llama_cache import PagedKVConfig
-
-    cfg = LlamaConfig(vocab_size=64, hidden_size=32, intermediate_size=64,
-                      num_hidden_layers=2, num_attention_heads=4,
-                      num_key_value_heads=2, max_position_embeddings=128,
-                      rope_theta=1e4, dtype=jnp.float32, scan_layers=True,
-                      remat=False)
-    params = LlamaForCausalLM(cfg).init(jax.random.PRNGKey(0),
-                                        jnp.zeros((1, 8), jnp.int32))
-
-    def make(k=1):
-        kv = PagedKVConfig(num_pages=40, page_size=4, max_pages_per_seq=16)
-        sched = SchedulerConfig(token_budget=64, max_seqs=4, prefill_chunk=8,
-                                decode_bucket=2)
-        return build_engine(cfg, params, RaggedInferenceEngineConfig(
-            kv=kv, scheduler=sched, kv_dtype=jnp.float32,
-            decode_steps_per_dispatch=k, max_new_tokens=6))
-    return make
-
-
 def test_serving_anatomy_tiles_and_guards_recompiles(tiny_serving):
     from deepspeed_tpu.serving import (AdmissionConfig, ServingConfig,
                                        ServingEngine, VirtualClock)
@@ -559,13 +532,15 @@ def test_serving_anatomy_tiles_and_guards_recompiles(tiny_serving):
     assert occ["in_use"] + occ["free"] == occ["usable"]
 
 
-def test_engine_anatomy_disabled_by_default(tiny_serving):
+def test_engine_records_by_default_and_none_switches_it_off(tiny_serving):
     eng = tiny_serving()
-    assert eng.anatomy is NULL_ANATOMY
+    own = eng.anatomy
+    assert isinstance(own, StepAnatomy) and own.steps.maxlen == 8192 and eng.anatomy_is_default
     eng.generate([[1, 2, 3]], max_new_tokens=2)
-    assert eng.anatomy.total_steps == 0
-    eng.set_anatomy(None)
-    assert eng.anatomy is NULL_ANATOMY
+    assert own.total_steps >= 2 and all(_tiles(r.to_row()) for r in own.steps)
+    assert eng.set_anatomy(None) is NULL_ANATOMY and eng.anatomy is NULL_ANATOMY and not eng.anatomy_is_default
+    eng.generate([[1, 2, 3]], max_new_tokens=2)
+    assert NULL_ANATOMY.total_steps == 0 and own.total_steps == len(own.steps)   # the old recorder saw no more
 
 
 def _counts(rec):
