@@ -11,9 +11,13 @@ build_hf_engine``.  The serving loop composes:
   paged_attention[_pallas] — the blocked-KV attention kernel
 
 TPU specifics vs the reference:
-  * ONE compiled step program per (batch-bucket, chunk-bucket) pair — the
-    scheduler quantises both, so steady-state serving reuses 2–4 programs
-    instead of the reference's per-shape CUDA kernel launches.
+  * ONE compiled step program per list of row groups ``((rows, width), ...)``:
+    the decode bucket at one slot a row, and in a mixed step a prefill group
+    of a few rows (a ladder of three) at a chunk a row beside it, where the
+    twin's blocks take more than one group (``takes_row_groups``), else the
+    one rectangle of all rows at the chunk — the scheduler quantises both, so
+    steady-state serving reuses a handful of programs instead of the
+    reference's per-shape CUDA kernel launches.
   * the KV arena is donated through the jitted step, so XLA updates pages
     in place (the reference's global InferenceContext arena, inference_context.h).
   * sampling is greedy or categorical on-device; logits for each row are
@@ -99,19 +103,27 @@ class RaggedInferenceEngineConfig:
     spec: Optional[SpecConfig] = None
 
 
-def _make_step_fn(model, qparams, greedy: bool, temperature: float):
+def _make_step_fn(model, qparams, greedy: bool, temperature: float, groups):
     """The unified SplitFuse step program: one chunked forward serving
     prefill, continuation and decode, then per-row last-token sampling.
-    Pure in (params, cache, batch arrays) so both the live engine and the
-    AOT serving-budget path (compile_aot_serving) jit the same function."""
+    ``groups`` is the step's static list of row groups ``(rows, width)``; the
+    tokens are their one flat axis, the other batch arrays one entry a row
+    (models/llama_cache.py "Row groups").  Pure in (params, cache, batch
+    arrays) so both the live engine and the AOT serving-budget path
+    (compile_aot_serving) jit the same function."""
+    groups = tuple(groups)
 
     def step(params, cache, tokens, start_pos, block_tables, chunk_lens, rng):
         if qparams is not None:
             params = {"params": qparams.dequantize(params["params"])}
         # logits of each row's LAST real token alone: the twin takes those rows
         # out before its final norm and head (models/llama_cache.sampled_rows)
-        logits, cache = model.apply(params, tokens, start_pos, block_tables, cache, chunk_lens, True)
-        row_logits = logits[:, 0]                                                      # [B, V]
+        if len(groups) == 1:  # the rectangle of every twin's contract
+            logits, cache = model.apply(params, tokens.reshape(groups[0]), start_pos, block_tables, cache,
+                                        chunk_lens, True)
+        else:  # to a twin that takes them (``takes_row_groups``): flat, with their list
+            logits, cache = model.apply(params, tokens, start_pos, block_tables, cache, chunk_lens, True, groups)
+        row_logits = logits[:, 0]                                                      # [R, V]
         if greedy:
             next_tok = jnp.argmax(row_logits, axis=-1)
         else:
@@ -152,10 +164,10 @@ def _make_multi_fn(model, qparams, greedy: bool, temperature: float, batch: int,
 
 
 def _named(fn, label: str):
-    """Name a step function after its program key (``step:b16:c128`` ->
-    ``ds_step_b16_c128``) before ``jax.jit``, so the device trace's
-    ``XLA Modules`` line reads ``jit_ds_step_b16_c128`` and tells a mixed
-    step from a one-token step."""
+    """Name a step function after its program key (``step:b16:c1:b1:c128`` ->
+    ``ds_step_b16_c1_b1_c128``) before ``jax.jit``, so the device trace's
+    ``XLA Modules`` line reads ``jit_ds_step_b16_c1_b1_c128`` and tells a
+    mixed step from a one-token step."""
     fn.__name__ = fn.__qualname__ = "ds_" + label.replace(":", "_")
     return fn
 
@@ -191,10 +203,12 @@ def _serving_shardings(model, cfg, econfig, mesh):
 
 
 def compile_aot_serving(cfg, mesh, engine_config: RaggedInferenceEngineConfig = None,
-                        batch: int = 8, chunk: int = 1, fused_steps: int = 0):
+                        batch: int = 8, chunk: int = 1, fused_steps: int = 0, groups=None):
     """AOT-compile the TP-sharded serving step against an offline topology:
-    the step program of ``chunk`` tokens a row or, with ``fused_steps`` k > 1,
-    the fused decode program of k one-token rounds (``multi:b<batch>:k<k>``).
+    the step program of ``chunk`` tokens a row (or of the row ``groups``
+    given: a mixed step's ``((16, 1), (1, 128))``) or, with ``fused_steps``
+    k > 1, the fused decode program of k one-token rounds
+    (``multi:b<batch>:k<k>``).
 
     No weights are ever allocated — params/cache lower as ShapeDtypeStructs —
     so this proves a serving config (e.g. Llama-3-8B at TP8 on v5p) fits
@@ -216,9 +230,11 @@ def compile_aot_serving(cfg, mesh, engine_config: RaggedInferenceEngineConfig = 
         step = _named(_make_multi_fn(model, None, eng_cfg.greedy, eng_cfg.temperature, batch, fused_steps),
                       InferenceEngineV2._key_label(("multi", batch, fused_steps)))
     else:
-        tokens_shape = (batch, chunk)
-        step = _named(_make_step_fn(model, None, eng_cfg.greedy, eng_cfg.temperature),
-                      InferenceEngineV2._key_label((batch, chunk)))
+        groups = tuple(groups or ((batch, chunk), ))
+        batch = sum(rows for rows, _ in groups)
+        tokens_shape = (sum(rows * width for rows, width in groups), )
+        step = _named(_make_step_fn(model, None, eng_cfg.greedy, eng_cfg.temperature, groups),
+                      InferenceEngineV2._key_label(groups))
     jitted = jax.jit(step, donate_argnums=(1, ),
                      in_shardings=(param_sh, cache_sh, r, r, r, r, r),
                      out_shardings=(r, cache_sh))
@@ -341,7 +357,11 @@ class InferenceEngineV2:
         self.cache = _init_cache(cfg, self.econfig)
         self.rng = rng if rng is not None else jax.random.PRNGKey(0)
         self._max_new: Dict[int, int] = {}
-        self._step_fns: Dict[Tuple[int, int], callable] = {}
+        #: program key -> program: a step's row groups ``((rows, width), ...)``,
+        #: ``("multi", batch, k)`` or ``("verify", batch, width)``
+        self._step_fns: Dict[tuple, callable] = {}
+        #: whether the twin's blocks take a step of more than one row group
+        self._row_groups = bool(getattr(self.model, "takes_row_groups", False))
         # per-step anatomy (telemetry/step_anatomy.py): every engine records
         # its steps (a ring of the last 8,192, drawn into a running profile
         # too); set_anatomy(None) switches to the NULL recorder, one
@@ -511,15 +531,14 @@ class InferenceEngineV2:
         with self.mesh, trace_mesh(self.mesh):
             return fn(*args)
 
-    def _build_step_jit(self, batch: int, chunk: int):
-        """The jitted single/mixed step program — ONE builder shared by
-        the lazy per-shape cache and the AOT ``warm_all`` path, so the
-        two can never trace different computations for the same key
-        (batch and chunk only name the program: the shapes come with the
-        arguments)."""
+    def _build_step_jit(self, groups: tuple):
+        """The jitted single/mixed step program of the row ``groups`` — ONE
+        builder shared by the lazy per-shape cache and the AOT ``warm_all``
+        path, so the two can never trace different computations for the
+        same key."""
         step = _make_step_fn(self.model, self._qparams, self.econfig.greedy,
-                             self.econfig.temperature)
-        return jax.jit(_named(step, self._key_label((batch, chunk))),
+                             self.econfig.temperature, groups)
+        return jax.jit(_named(step, self._key_label(groups)),
                        donate_argnums=(1, ), **self._jit_kwargs())
 
     def _build_multi_jit(self, batch: int, k: int):
@@ -546,13 +565,12 @@ class InferenceEngineV2:
         return jax.jit(_named(vstep, self._key_label(("verify", batch, width))),
                        donate_argnums=(1, ), **kwargs)
 
-    def _compiled_step(self, batch: int, chunk: int):
-        key = (batch, chunk)
-        if key not in self._step_fns:
-            logger.info(f"InferenceEngineV2: compiling step program batch={batch} chunk={chunk}")
-            self._step_fns[key] = self._build_step_jit(batch, chunk)
-            self._note_compile(self._key_label(key))
-        return self._step_fns[key]
+    def _compiled_step(self, groups: tuple):
+        if groups not in self._step_fns:
+            logger.info(f"InferenceEngineV2: compiling step program of row groups {groups}")
+            self._step_fns[groups] = self._build_step_jit(groups)
+            self._note_compile(self._key_label(groups))
+        return self._step_fns[groups]
 
     def _compiled_multi_step(self, batch: int, k: int):
         key = ("multi", batch, k)
@@ -587,16 +605,21 @@ class InferenceEngineV2:
             return f"multi:b{key[1]}:k{key[2]}"
         if key[0] == "verify":
             return f"verify:b{key[1]}:w{key[2]}"
-        return f"step:b{key[0]}:c{key[1]}"
+        # a step's row groups: ((16, 128), ) -> step:b16:c128, a mixed step's
+        # ((16, 1), (1, 128)) -> step:b16:c1:b1:c128 (no "_": _named turns ":" into it)
+        return "step:" + ":".join(f"b{rows}:c{width}" for rows, width in key)
 
     def step_shape_set(self) -> List[tuple]:
         """Enumerate every program key steady-state serving can reach,
         straight from the scheduler's bucket table: batch buckets are the
-        ``decode_bucket`` multiples up to ``max_seqs``; chunk buckets are
-        {1, prefill_chunk} (the only two ``_dispatch_single`` produces);
-        the fused-decode rung adds its halving ladder (k_cfg, k_cfg/2,
-        ..., 2 — exactly the pressure fallbacks ``_dispatch_inner``
-        walks); a drafter adds one verify width (``max_draft + 1``).
+        ``decode_bucket`` multiples up to ``max_seqs``; a step is the decode
+        bucket at one token a row and, with a prefill row in it, what
+        ``_step_groups`` makes of the plan (the decode bucket beside each
+        rung of ``_prefill_rungs``, or the rectangle of a bucket of rows at
+        ``prefill_chunk``); the fused-decode rung adds its halving ladder
+        (k_cfg, k_cfg/2, ..., 2 — exactly the pressure fallbacks
+        ``_dispatch_inner`` walks); a drafter adds one verify width
+        (``max_draft + 1``).
         This closure is what makes ``warm_all`` a guarantee rather than a
         heuristic: a steady-state dispatch outside this set would be an
         engine bug, and the ``engine/recompile_steady_state`` guard would
@@ -605,8 +628,11 @@ class InferenceEngineV2:
         q = sched.decode_bucket
         maxb = self.state.max_batch
         batches = sorted({min(maxb, m * q) for m in range(1, -(-maxb // q) + 1)})
-        keys: List[tuple] = [(b, c) for b in batches
-                             for c in sorted({1, sched.prefill_chunk})]
+        keys: List[tuple] = [((b, 1), ) for b in batches]
+        if sched.prefill_chunk > 1 and self._row_groups:
+            keys += [((b, 1), (p, sched.prefill_chunk)) for b in batches for p in self._prefill_rungs()]
+        elif sched.prefill_chunk > 1:
+            keys += [((b, sched.prefill_chunk), ) for b in batches]
         k_cfg = self.econfig.decode_steps_per_dispatch
         if k_cfg > 1:
             ks = set()
@@ -647,9 +673,9 @@ class InferenceEngineV2:
             jitted = self._build_verify_jit(b, w)
             args = (params_abs, cache_abs) + batch_args(b, w)
         else:
-            b, c = key
-            jitted = self._build_step_jit(b, c)
-            args = (params_abs, cache_abs) + batch_args(b, c) + (rng_abs, )
+            jitted = self._build_step_jit(key)
+            args = (params_abs, cache_abs, sds((sum(rows * width for rows, width in key), ), jnp.int32)) + \
+                batch_args(sum(rows for rows, _ in key), 1)[1:] + (rng_abs, )
         if self.mesh is None:
             return jitted.lower(*args)
         from ...comm.mesh import trace_mesh
@@ -996,6 +1022,34 @@ class InferenceEngineV2:
         q = self.econfig.scheduler.decode_bucket
         return min(self.state.max_batch, -(-n // q) * q)
 
+    def _prefill_rungs(self) -> Tuple[int, ...]:
+        """Rows a mixed step's prefill group may have: the smallest rung that
+        holds the plan's prefill rows is taken.  One row is a rung of its own
+        (long prompts arrive one at a time: the usual mixed step holds one
+        prefilling prompt), ``max_seqs`` is what a plan can hold at most, and
+        four between them takes a burst of arrivals without the full
+        rectangle's slots (PERF.md section 6, PR 35).  Each rung is a program
+        for ``warm_all`` to compile."""
+        return tuple(sorted({min(p, self.state.max_batch) for p in (1, 4, self.state.max_batch)}))
+
+    def _step_groups(self, plan: StepPlan):
+        """A single step's layout: its row groups as ``pack_groups`` takes them,
+        [(work, rows, width)].  Rows of one token each are one group at one
+        token a row.  With a wider prefill row in the plan, a twin whose blocks
+        take more than one group gets the decode bucket at one token a row
+        (all padding where nothing decodes: dead slots that save a program)
+        and the prefill rows in a group of their own at the chunk; any other
+        gets the one rectangle, every row at the chunk."""
+        decode = [(s, 1) for s in plan.decode]
+        work = decode + list(plan.prefill)
+        chunk = self.econfig.scheduler.prefill_chunk
+        if all(n == 1 for _, n in work):  # a prompt's last token is a row of one token too
+            return [(work, self._bucket_batch(len(work)), 1)]
+        if not self._row_groups:
+            return [(work, self._bucket_batch(len(work)), chunk)]
+        rows = next(p for p in self._prefill_rungs() if p >= len(plan.prefill))
+        return [(decode, self._bucket_batch(max(len(decode), 1)), 1), (list(plan.prefill), rows, chunk)]
+
     def step(self, plan: Optional[StepPlan] = None) -> Dict[int, List[int]]:
         """Run one scheduled step; returns {uid: [new tokens]} for
         sequences that produced tokens this call — one token per uid on
@@ -1102,27 +1156,25 @@ class InferenceEngineV2:
         work: List = [(s, 1) for s in plan.decode] + list(plan.prefill)
         if not work:
             return None
-        chunk = max(n for _, n in work)
-        # chunk buckets: 1 (pure decode) or the prefill quantum
-        chunk = 1 if chunk == 1 else self.econfig.scheduler.prefill_chunk
-        batch = self._bucket_batch(len(work))
-        rb: RaggedBatch = self.state.pack(work, chunk, pad_to=batch)
+        packed = self._step_groups(plan)
+        groups = tuple((rows, width) for _, rows, width in packed)
+        rb: RaggedBatch = self.state.pack_groups(packed)
 
         self.rng, sub = jax.random.split(self.rng)
-        fn = self._compiled_step(batch, chunk)
+        fn = self._compiled_step(groups)
         if anat.enabled:
             path = ("mixed" if plan.prefill and plan.decode
                     else "prefill" if plan.prefill else "decode")
-            tokens_real = sum(n for _, n in work)
-            anat.note_program(self._key_label((batch, chunk)), path,
+            tokens_real = plan.planned_tokens
+            anat.note_program(self._key_label(groups), path,
                               rows_decode=len(plan.decode), rows_prefill=len(plan.prefill),
-                              tokens_real=tokens_real, slots=batch * chunk)
+                              tokens_real=tokens_real, slots=rb.tokens.size)
         next_tok, self.cache = self._invoke(fn, self.params, self.cache, jnp.asarray(rb.tokens),
                                             jnp.asarray(rb.start_pos), jnp.asarray(rb.block_tables),
                                             jnp.asarray(rb.chunk_lens), sub)
         if anat.enabled:
             # the passes over the rows run with the program already enqueued
-            anat.note_counts(**self._expert_rows(tokens_real, batch * chunk),
+            anat.note_counts(**self._expert_rows(tokens_real, rb.tokens.size),
                              cache_counts=self._cache_counts(work), state_counts=self._state_counts(work))
             anat.mark("compile_wait" if self._fresh_compile else "dispatch")
         inf = InFlightStep("single")

@@ -542,8 +542,9 @@ class BlockedKVCache:
 @dataclasses.dataclass
 class RaggedBatch:
     """One step's packed device inputs (ref: RaggedBatchWrapper) — fixed
-    max shapes so the compiled program is reused across steps."""
-    tokens: np.ndarray        # [B, C] int32 (padded)
+    max shapes so the compiled program is reused across steps.  The rows of a
+    step's row groups are concatenated, B rows in all."""
+    tokens: np.ndarray        # [B, C] int32 (padded); [T] flat where packed by groups
     start_pos: np.ndarray     # [B] int32 — context length before this chunk
     block_tables: np.ndarray  # [B, max_pages] int32 (null page 0 padded)
     chunk_lens: np.ndarray    # [B] int32 — real tokens this step (0 = padding row)
@@ -551,7 +552,7 @@ class RaggedBatch:
 
     @property
     def batch(self) -> int:
-        return self.tokens.shape[0]
+        return len(self.uids)
 
 
 class StateManager:
@@ -630,27 +631,44 @@ class StateManager:
 
     def pack(self, work: List[Tuple[SequenceDescriptor, int]], chunk: int,
              pad_to: Optional[int] = None) -> RaggedBatch:
-        """Pack (seq, n_tokens) work items into fixed [B, chunk] buffers.
+        """Pack (seq, n_tokens) work items into fixed [B, chunk] buffers: the
+        one-group case of ``pack_groups``, its tokens as the rectangle.
 
         B is padded to ``pad_to`` (default ``max_batch``) so the compiled
         step program keeps ONE shape across scheduler decisions — padding
         rows have uid -1, chunk_len 0, and an all-null block table."""
         b = pad_to if pad_to is not None else self.max_batch
-        assert len(work) <= b, f"{len(work)} work items exceed batch capacity {b}"
-        tokens = np.zeros((b, chunk), np.int32)
-        start_pos = np.zeros((b, ), np.int32)
-        block_tables = np.zeros((b, self.kv.table_width), np.int32)
-        chunk_lens = np.zeros((b, ), np.int32)
-        uids = [-1] * b
-        for i, (seq, n) in enumerate(work):
-            self.kv.ensure_capacity(seq, n)
-            sl = seq.tokens[seq.seen_tokens:seq.seen_tokens + n]
-            tokens[i, :len(sl)] = sl
-            start_pos[i] = seq.seen_tokens
-            block_tables[i, self.kv.geometry.slots(len(seq.pages))] = seq.pages
-            if seq.slot:
-                block_tables[i, -1] = seq.slot     # the row's last column (geometry.SlotPagesGeometry)
-            chunk_lens[i] = n
-            uids[i] = seq.uid
+        rb = self.pack_groups([(work, b, chunk)])
+        rb.tokens = rb.tokens.reshape(b, chunk)
+        return rb
+
+    def pack_groups(self, groups: List[Tuple[List[Tuple[SequenceDescriptor, int]], int, int]]) -> RaggedBatch:
+        """Pack a step's row groups, each (work, rows, width): the tokens on
+        one flat axis of ``sum(rows x width)`` slots, group after group and a
+        row's ``width`` slots together; ``start_pos``, the block tables,
+        ``chunk_lens`` and ``uids`` one entry a row, the groups' rows
+        concatenated.  A group's work fills its first rows; the rest are
+        padding rows as in ``pack``."""
+        n_rows = sum(rows for _, rows, _ in groups)
+        tokens = np.zeros((sum(rows * width for _, rows, width in groups), ), np.int32)
+        start_pos = np.zeros((n_rows, ), np.int32)
+        block_tables = np.zeros((n_rows, self.kv.table_width), np.int32)
+        chunk_lens = np.zeros((n_rows, ), np.int32)
+        uids = [-1] * n_rows
+        r0 = t0 = 0
+        for work, rows, width in groups:
+            assert len(work) <= rows, f"{len(work)} work items exceed batch capacity {rows}"
+            for i, (seq, n) in enumerate(work, start=r0):
+                self.kv.ensure_capacity(seq, n)
+                sl = seq.tokens[seq.seen_tokens:seq.seen_tokens + n]
+                at = t0 + (i - r0) * width
+                tokens[at:at + len(sl)] = sl
+                start_pos[i] = seq.seen_tokens
+                block_tables[i, self.kv.geometry.slots(len(seq.pages))] = seq.pages
+                if seq.slot:
+                    block_tables[i, -1] = seq.slot     # the row's last column (geometry.SlotPagesGeometry)
+                chunk_lens[i] = n
+                uids[i] = seq.uid
+            r0, t0 = r0 + rows, t0 + rows * width
         return RaggedBatch(tokens=tokens, start_pos=start_pos, block_tables=block_tables,
                            chunk_lens=chunk_lens, uids=uids)

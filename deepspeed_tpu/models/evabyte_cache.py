@@ -36,13 +36,16 @@ scan that takes a layer's pages in and hands them out stacks a second arena,
 which an arena of half the chip's memory has no room for.)
 """
 
+from typing import Optional, Tuple
+
 import jax
 import jax.numpy as jnp
 from flax import linen as nn
 
 from .evabyte import EvaByteConfig, EvaByteHead, EvaProjections, eva_embed, eva_norm, summarise_chunks
 from .llama import LlamaMLP
-from .llama_cache import _write_pages, paged_attention, reads_through_kernel, sampled_rows, scan_blocks
+from .llama_cache import (_write_pages, flat_positions, flat_step, logits_as, over_row_groups, paged_attention,
+                          reads_through_kernel, sampled_rows, scan_blocks)
 
 
 def _summarise_completed(arena, layer, block_table, start_pos, chunk_lens, width, phi, mu, page_size, ring):
@@ -79,8 +82,12 @@ def _kernel_view(block_table, start_pos, page_size, ring, window, max_windows):
 
 
 class EvaByteBlockCache(nn.Module):
+    """``x`` is the flat axis [T, hidden] of ``groups`` (models/llama_cache.py):
+    projections, rope and the MLP run there; the ring's writes, the summaries,
+    the kernel's view and the attention are a row's, group by group."""
     cfg: EvaByteConfig
     page_size: int = 16
+    groups: Optional[Tuple[Tuple[int, int], ...]] = None
 
     @nn.compact
     def __call__(self, carry, layer, positions, block_table, start_pos, chunk_lens):
@@ -89,18 +96,21 @@ class EvaByteBlockCache(nn.Module):
         ring = cfg.window_size // page
         attn = EvaProjections(cfg, name="self_attn")
         q, k, v = attn.qkv(eva_norm(cfg, "input_layernorm")(x), positions)
-        arena = _write_pages(arena, k.astype(arena.dtype), v.astype(arena.dtype), block_table[:, :ring],
-                             start_pos % cfg.window_size, page, chunk_lens, layer=layer)
-        with jax.named_scope("ds_eva_summarise"):
-            arena = _summarise_completed(arena, layer, block_table, start_pos, chunk_lens, x.shape[1],
-                                         attn.adaptive_phi, attn.adaptive_mu_k, page, ring)
-        view, vstart = _kernel_view(block_table, start_pos, page, ring, cfg.window_size,
-                                    -(-cfg.max_position_embeddings // cfg.window_size))
-        if reads_through_kernel(cfg.attention_impl):
-            from ..ops.paged_attention import paged_attention_pallas
-            o = paged_attention_pallas(q, arena, view, vstart, chunk_lens, page, layer=layer)
-        else:
-            o = paged_attention(q, arena[layer], view, vstart, chunk_lens, page)
+
+        def attend(arena, q, k, v, block_table, start_pos, chunk_lens):
+            arena = _write_pages(arena, k.astype(arena.dtype), v.astype(arena.dtype), block_table[:, :ring],
+                                 start_pos % cfg.window_size, page, chunk_lens, layer=layer)
+            with jax.named_scope("ds_eva_summarise"):
+                arena = _summarise_completed(arena, layer, block_table, start_pos, chunk_lens, q.shape[1],
+                                             attn.adaptive_phi, attn.adaptive_mu_k, page, ring)
+            view, vstart = _kernel_view(block_table, start_pos, page, ring, cfg.window_size,
+                                        -(-cfg.max_position_embeddings // cfg.window_size))
+            if reads_through_kernel(cfg.attention_impl):
+                from ..ops.paged_attention import paged_attention_pallas
+                return paged_attention_pallas(q, arena, view, vstart, chunk_lens, page, layer=layer), arena
+            return paged_attention(q, arena[layer], view, vstart, chunk_lens, page), arena
+
+        o, arena = over_row_groups(self.groups, attend, arena, (q, k, v), (block_table, start_pos, chunk_lens))
         x = x + attn.o_proj(o.astype(cfg.dtype)).astype(x.dtype)
         x = x + LlamaMLP(cfg, name="mlp")(eva_norm(cfg, "post_attention_layernorm")(x)).astype(x.dtype)
         return (x, arena), None
@@ -108,20 +118,24 @@ class EvaByteBlockCache(nn.Module):
 
 class EvaByteForCausalLMWithCache(nn.Module):
     """``apply(variables, tokens, start_pos, block_table, cache, chunk_lens)``
-    -> (head 0's logits [B, C, vocab_size] in float32, new cache)."""
+    -> (head 0's logits [B, C, vocab_size] in float32, new cache); a rectangle
+    of tokens or, with ``groups``, the flat axis of several
+    (``LlamaForCausalLMWithCache``)."""
     cfg: EvaByteConfig
     page_size: int = 16
+    takes_row_groups = True
 
     @nn.compact
-    def __call__(self, input_ids, start_pos, block_table, cache, chunk_lens=None, last_only=False):
+    def __call__(self, input_ids, start_pos, block_table, cache, chunk_lens=None, last_only=False, groups=None):
         cfg = self.cfg
         if self.page_size != cfg.chunk_size:
             raise ValueError(f"EvaByte's chunk is its page: page_size {self.page_size} != chunk_size {cfg.chunk_size}")
-        if chunk_lens is None:
-            chunk_lens = jnp.full(start_pos.shape, input_ids.shape[1], jnp.int32)
-        positions = start_pos[:, None] + jnp.arange(input_ids.shape[1])[None, :]
-        x = eva_embed(cfg)(input_ids).astype(jnp.float32)
-        (x, cache), _ = scan_blocks(EvaByteBlockCache, cfg.num_hidden_layers)(cfg, self.page_size, name="layers")(
-            (x, cache), jnp.arange(cfg.num_hidden_layers), positions, block_table, start_pos, chunk_lens)
-        x = sampled_rows(x, chunk_lens, last_only)
-        return EvaByteHead(cfg, name="lm_head")(eva_norm(cfg, "norm", jnp.float32)(x), 1)[..., 0, :], cache
+        tokens, groups, chunk_lens = flat_step(input_ids, chunk_lens, groups)
+        positions = flat_positions(groups, start_pos)
+        x = eva_embed(cfg)(tokens).astype(jnp.float32)
+        (x, cache), _ = scan_blocks(EvaByteBlockCache, cfg.num_hidden_layers)(
+            cfg, self.page_size, groups, name="layers")((x, cache), jnp.arange(cfg.num_hidden_layers), positions,
+                                                        block_table, start_pos, chunk_lens)
+        x = sampled_rows(x, chunk_lens, last_only, groups)
+        logits = EvaByteHead(cfg, name="lm_head")(eva_norm(cfg, "norm", jnp.float32)(x), 1)[..., 0, :]
+        return logits_as(logits, input_ids, last_only), cache

@@ -198,15 +198,15 @@ def rotary_embedding(positions, head_dim, theta):
     """RoPE tables; fp32 for precision (ref kernel: csrc/transformer/inference
     rotary — here a pure-jnp pair that XLA fuses into the attention matmuls)."""
     inv_freq = 1.0 / (theta**(jnp.arange(0, head_dim, 2, dtype=jnp.float32) / head_dim))
-    angles = positions.astype(jnp.float32)[..., None] * inv_freq  # [B, S, D/2]
+    angles = positions.astype(jnp.float32)[..., None] * inv_freq  # [B, S, D/2] ([T, D/2] of flat positions)
     return jnp.cos(angles), jnp.sin(angles)
 
 
 def apply_rope(x, cos, sin):
-    # x: [B, S, N, D]
+    # x: [B, S, N, D], or [T, N, D] with the tables of flat positions [T, D/2]
     x1, x2 = jnp.split(x.astype(jnp.float32), 2, axis=-1)
-    cos = cos[:, :, None, :]
-    sin = sin[:, :, None, :]
+    cos = cos[..., None, :]
+    sin = sin[..., None, :]
     out = jnp.concatenate([x1 * cos - x2 * sin, x2 * cos + x1 * sin], axis=-1)
     return out.astype(x.dtype)
 

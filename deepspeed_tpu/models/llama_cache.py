@@ -30,6 +30,20 @@ One program serves prefill chunks, continuation chunks and decode (C=1) —
 the Dynamic-SplitFuse property that all phases are the same computation at
 different chunk sizes (ref: blogs/deepspeed-fastgen — SplitFuse; here it
 falls out of the unified chunked forward).
+
+Row groups.  A step's tokens are one flat axis of ``T = sum(rows x width)``
+slots: a short static list of groups ``(rows, width)``, one after another.
+``start_pos``, the block tables and ``chunk_lens`` stay one entry a row, the
+groups' rows concatenated.  A rectangle ``[B, C]`` is the one group
+``((B, C),)``; a mixed step is the decode rows at one slot each beside the
+prefill rows at a chunk each, ``((16, 1), (1, 128))``: 144 slots where the
+rectangle has 2,048.  Whatever is a function of a token alone (embedding,
+norms, projections, rope, the MLP or the experts, the residual) runs on the
+flat axis, ``[T, hidden]``; what needs a row's sequence (the page writes, the
+paged attention) runs group by group at the group's own rectangle
+(``over_row_groups``).  A twin whose blocks are written so says
+``takes_row_groups = True``, and the engine hands it a mixed step in two
+groups; any other gets rectangles only.
 """
 
 import dataclasses
@@ -37,6 +51,7 @@ from typing import Any, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from flax import linen as nn
 
 from .llama import (EMBED, HEAD_DIM, HEADS, KV_HEADS, LAYERS, MLP, VOCAB, LlamaConfig, LlamaMLP, RMSNorm, _logical,
@@ -178,18 +193,87 @@ def paged_attention_core(q, k, v, pages, block_table, start_pos, chunk_lens, pag
     return out, pages
 
 
-def sampled_rows(x, chunk_lens, last_only):
-    """What of a trunk's output [B, C, H] goes on to the final norm and the
-    head: with ``last_only`` each row's last real token alone, [B, 1, H].  The
-    engine's step programs sample from nothing else, and a head over every
-    slot of a mixed step is its largest product and buffer where the
-    vocabulary is large; the logits of every position are for who compares
-    them (the verify program, the benchmark's check, the tests)."""
+def flat_step(input_ids, chunk_lens, groups):
+    """A twin's first argument as (flat tokens [T], its groups, chunk_lens
+    [R]): a rectangle ``[B, C]`` is one group, flat tokens ``[T]`` come with
+    their ``groups``; rows without ``chunk_lens`` carry a token in every slot."""
+    if groups is None:
+        groups = (tuple(input_ids.shape), )
+    groups = tuple((int(rows), int(width)) for rows, width in groups)
+    if sum(rows * width for rows, width in groups) != input_ids.size:
+        raise ValueError(f"row groups {groups} do not hold tokens of shape {input_ids.shape}")
+    if chunk_lens is None:
+        chunk_lens = jnp.asarray(np.repeat([w for _, w in groups], [r for r, _ in groups]), jnp.int32)
+    return input_ids.reshape(-1), groups, chunk_lens
+
+
+def spread_rows(groups, per_row):
+    """[R, ...] -> [T, ...]: a row's entry in every slot of its chunk."""
+    out, r0 = [], 0
+    for rows, width in groups:
+        out.append(jnp.repeat(per_row[r0:r0 + rows], width, axis=0, total_repeat_length=rows * width))
+        r0 += rows
+    return jnp.concatenate(out)
+
+
+def slot_in_chunk(groups):
+    """[T], static: each slot's index in its row's chunk."""
+    return np.concatenate([np.tile(np.arange(width, dtype=np.int32), rows) for rows, width in groups])
+
+
+def flat_positions(groups, start_pos):
+    """[T]: each slot's position in its row's sequence."""
+    return spread_rows(groups, start_pos) + slot_in_chunk(groups)
+
+
+def live_slots(groups, chunk_lens):
+    """[T] bool: whether the slot carries a token (a chunk's first ``chunk_lens``)."""
+    return slot_in_chunk(groups) < spread_rows(groups, chunk_lens)
+
+
+def over_row_groups(groups, attend, arena, flat, per_row):
+    """What needs a row's sequence, group by group: ``attend(arena, *rect,
+    *rows) -> (out [rows, width, ...], arena)`` is the code of one rectangle;
+    it gets each of ``flat`` ([T, ...]) as the group's ``[rows, width, ...]``
+    and each of ``per_row`` ([R, ...]) as the group's rows, the arena is
+    threaded through the groups, and the results come back flat [T, ...]."""
+    out, t0, r0 = [], 0, 0
+    for rows, width in groups:
+        n = rows * width
+        rect = [a[t0:t0 + n].reshape((rows, width) + a.shape[1:]) for a in flat]
+        o, arena = attend(arena, *rect, *(a[r0:r0 + rows] for a in per_row))
+        out.append(o.reshape((n, ) + o.shape[2:]))
+        t0, r0 = t0 + n, r0 + rows
+    return (out[0] if len(out) == 1 else jnp.concatenate(out)), arena
+
+
+def sampled_rows(x, chunk_lens, last_only, groups=None):
+    """What of a trunk's output goes on to the final norm and the head: with
+    ``last_only`` each row's last real token alone, [R, 1, H].  The engine's
+    step programs sample from nothing else, and a head over every slot of a
+    mixed step is its largest product and buffer where the vocabulary is
+    large; the logits of every position are for who compares them (the verify
+    program, the benchmark's check, the tests).  ``x`` is a rectangle
+    [B, C, H], or with ``groups`` the flat axis [T, H], of which a row's last
+    real token is that of its chunk in its group."""
     if not last_only:
         return x
+    if groups is not None:
+        first, t0 = [], 0
+        for rows, width in groups:
+            first.append(t0 + width * np.arange(rows, dtype=np.int32))
+            t0 += rows * width
+        return x[np.concatenate(first) + jnp.maximum(chunk_lens - 1, 0)][:, None]
     if chunk_lens is None:
         return x[:, -1:]
     return jnp.take_along_axis(x, jnp.maximum(chunk_lens - 1, 0)[:, None, None], axis=1)
+
+
+def logits_as(logits, input_ids, last_only):
+    """The logits of every position in the shape the tokens came in
+    ([B, C, V] of a rectangle, [T, V] of flat tokens); ``last_only``'s
+    [R, 1, V] as they are."""
+    return logits if last_only else logits.reshape(input_ids.shape + logits.shape[-1:])
 
 
 def stack_layer_params(variables, num_layers):
@@ -216,8 +300,12 @@ def stack_layer_params(variables, num_layers):
 
 
 class LlamaAttentionCache(nn.Module):
+    """``x`` and ``positions`` are a rectangle, [B, C, hidden] and [B, C], or
+    with ``groups`` the flat axis, [T, hidden] and [T]: the projections and
+    rope run there, and the page writes and the attention group by group."""
     cfg: LlamaConfig
     page_size: int = 16
+    groups: Optional[Tuple[Tuple[int, int], ...]] = None
 
     @nn.compact
     def __call__(self, x, positions, pages, block_table, start_pos, chunk_lens=None, layer=None):
@@ -238,9 +326,16 @@ class LlamaAttentionCache(nn.Module):
         cos, sin = rotary_embedding(positions, head_dim, cfg.rope_theta)
         q = apply_rope(q, cos, sin)
         k = apply_rope(k, cos, sin)
-        out, pages = paged_attention_core(q, k, v, pages, block_table, start_pos, chunk_lens,
-                                          self.page_size, attention_impl=cfg.attention_impl,
-                                          sliding_window=cfg.sliding_window, layer=layer)
+
+        def attend(pages, q, k, v, block_table, start_pos, chunk_lens):
+            return paged_attention_core(q, k, v, pages, block_table, start_pos, chunk_lens, self.page_size,
+                                        attention_impl=cfg.attention_impl, sliding_window=cfg.sliding_window,
+                                        layer=layer)
+
+        if self.groups is None:
+            out, pages = attend(pages, q, k, v, block_table, start_pos, chunk_lens)
+        else:
+            out, pages = over_row_groups(self.groups, attend, pages, (q, k, v), (block_table, start_pos, chunk_lens))
         out = nn.DenseGeneral(features=cfg.hidden_size,
                               axis=(-2, -1),
                               use_bias=False,
@@ -255,15 +350,16 @@ class LlamaBlockCache(nn.Module):
     """One block in the shape of a scan's body: ``(carry, layer, ...) ->
     (carry, None)`` with ``carry = (x, pages)``.  The trunk carries the whole
     arena and scans over the layers' indices.  Every softmax twin's block has
-    this form."""
+    this form.  ``x`` is the flat axis [T, hidden] of ``groups``."""
     cfg: LlamaConfig
     page_size: int = 16
+    groups: Optional[Tuple[Tuple[int, int], ...]] = None
 
     @nn.compact
     def __call__(self, carry, layer, positions, block_table, start_pos, chunk_lens=None):
         cfg = self.cfg
         x, pages = carry
-        attn_out, pages = LlamaAttentionCache(cfg, self.page_size, name="self_attn")(
+        attn_out, pages = LlamaAttentionCache(cfg, self.page_size, self.groups, name="self_attn")(
             RMSNorm(cfg.rms_norm_eps, cfg.dtype, cfg.param_dtype, name="input_layernorm")(x), positions, pages,
             block_table, start_pos, chunk_lens, layer)
         h = x + attn_out
@@ -288,21 +384,25 @@ class LlamaForCausalLMWithCache(nn.Module):
     """Chunked forward with paged KV.  ``apply(variables, tokens, start_pos,
     block_table, cache, chunk_lens, last_only)`` → (logits, new_cache); the
     logits of every position, or with ``last_only`` of each row's last real
-    token ([B, 1, V]: ``sampled_rows``).  Every twin's contract."""
+    token ([B, 1, V]: ``sampled_rows``).  Every twin's contract.  ``tokens``
+    is a rectangle [B, C], or with ``groups`` (static) the flat axis [T] of
+    several, and the logits of every position have its shape."""
     cfg: LlamaConfig
     page_size: int = 16
+    takes_row_groups = True
 
     @nn.compact
-    def __call__(self, input_ids, start_pos, block_table, cache, chunk_lens=None, last_only=False):
+    def __call__(self, input_ids, start_pos, block_table, cache, chunk_lens=None, last_only=False, groups=None):
         cfg = self.cfg
-        positions = start_pos[:, None] + jnp.arange(input_ids.shape[1])[None, :]
+        tokens, groups, chunk_lens = flat_step(input_ids, chunk_lens, groups)
+        positions = flat_positions(groups, start_pos)
         embed = nn.Embed(num_embeddings=cfg.vocab_size,
                          features=cfg.hidden_size,
                          dtype=cfg.dtype,
                          param_dtype=cfg.param_dtype,
                          embedding_init=_logical(nn.initializers.normal(0.02), (VOCAB, EMBED)),
                          name="embed_tokens")
-        x = embed(input_ids)
+        x = embed(tokens)
 
         class _Trunk(nn.Module):
             """Named 'model' to match LlamaForCausalLM's param tree."""
@@ -312,13 +412,13 @@ class LlamaForCausalLMWithCache(nn.Module):
             @nn.compact
             def __call__(self, x, cache, positions, block_table, start_pos, chunk_lens):
                 (x, cache), _ = scan_blocks(LlamaBlockCache, self.cfg.num_hidden_layers)(
-                    self.cfg, self.page_size, name="layers")(
+                    self.cfg, self.page_size, groups, name="layers")(
                         (x, cache), jnp.arange(self.cfg.num_hidden_layers), positions, block_table, start_pos,
                         chunk_lens)
                 return x, cache
 
         x, cache = _Trunk(cfg, self.page_size, name="model")(x, cache, positions, block_table, start_pos, chunk_lens)
-        x = sampled_rows(x, chunk_lens, last_only)
+        x = sampled_rows(x, chunk_lens, last_only, groups)
         x = RMSNorm(cfg.rms_norm_eps, cfg.dtype, cfg.param_dtype, name="norm")(x)
         if cfg.tie_word_embeddings:
             logits = embed.attend(x)
@@ -329,4 +429,4 @@ class LlamaForCausalLMWithCache(nn.Module):
                                      param_dtype=cfg.param_dtype,
                                      kernel_init=_logical(nn.initializers.lecun_normal(), (EMBED, VOCAB)),
                                      name="lm_head")(x)
-        return logits, cache
+        return logits_as(logits, input_ids, last_only), cache

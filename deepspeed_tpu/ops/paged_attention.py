@@ -338,6 +338,18 @@ def paged_attention_pallas(q, pages, block_table, start_pos, chunk_lens, page_si
         if mesh is not None and mesh.size > 1:
             return _paged_sharded(q, pages, block_table, start_pos, chunk_lens, page_size,
                                   interpret, mesh, layer, window, scale)
+    return _paged_call(q, pages, block_table, start_pos, chunk_lens, layer, page_size=page_size,
+                       window=int(window or 0), scale=None if scale is None else float(scale), interpret=interpret)
+
+
+@functools.partial(jax.jit, static_argnames=("page_size", "window", "scale", "interpret"))
+def _paged_call(q, pages, block_table, start_pos, chunk_lens, layer, *, page_size, window, scale, interpret):
+    """The kernel's call at one set of shapes.  Jitted for its trace's sake:
+    inside a step program's trace the jaxpr of a jitted function is looked up
+    by its arguments' shapes, so the kernel's body (a walk of unrolled page
+    copies: half a second of Python a trace) is traced once a shape and
+    process, not twice a program that calls it (a scanned trunk traces its
+    block twice), and a serving engine's programs share the decode shape."""
     b, c, h, d = q.shape
     n_kv = pages.shape[-2]
     rep = h // n_kv
@@ -388,7 +400,7 @@ def paged_attention_pallas(q, pages, block_table, start_pos, chunk_lens, page_si
     q_block = n_kv * padded * lanes(d) * q.dtype.itemsize
     vmem = n_kv * padded * (lanes(d) + 2 * 128) * 4 + 2 * ppb * page_size * 2 * n_pad * lanes(d) * itemsize
     kernel = functools.partial(_paged_kernel, page_size=page_size, ppb=ppb, copies=copies, rep=rep, tile=tile,
-                               scale=1.0 / (d**0.5) if scale is None else float(scale), window=int(window or 0))
+                               scale=1.0 / (d**0.5) if scale is None else scale, window=window)
     out = pl.pallas_call(
         kernel,
         grid_spec=pltpu.PrefetchScalarGridSpec(

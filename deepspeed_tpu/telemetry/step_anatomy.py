@@ -51,8 +51,9 @@ to kill.  This module decomposes EVERY engine step into:
   ``after_idle``.
 
 * **counts** of what the step carried, noted where the engine packs the
-  batch and folds the tokens: the program's key (``step:b16:c128``,
-  ``multi:b16:k8``), ``rows_decode``/``rows_prefill`` (sequences),
+  batch and folds the tokens: the program's key (``step:b16:c1:b1:c128``:
+  a step's row groups, rows and width each; ``multi:b16:k8``),
+  ``rows_decode``/``rows_prefill`` (sequences),
   ``tokens_real`` (token positions computed for a live sequence),
   ``slots`` (positions the program computed, padding included),
   ``tokens_out`` (tokens that reached a sequence),
@@ -194,7 +195,7 @@ class StepRecord:
 
     def __init__(self, index: int):
         self.index = index
-        self.key: Optional[str] = None       # the program: step:b16:c128 | multi:b16:k8 | verify:b16:w5
+        self.key: Optional[str] = None       # the program: step:b16:c1:b1:c128 | multi:b16:k8 | verify:b16:w5
         self.path: Optional[str] = None      # decode|prefill|mixed|spec_verify|multi_decode
         self.segments: Dict[str, float] = {s: 0.0 for s in HOST_SEGMENTS}
         self.device_s = 0.0                  # real clock: the host's wait at the readback

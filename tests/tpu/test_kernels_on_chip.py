@@ -532,3 +532,101 @@ def test_rows_in_no_group_do_not_reach_the_experts_output_on_chip():
     np.testing.assert_array_equal(np.asarray(dirty), np.asarray(clean))
     other, _, _ = run(x, stack, 0)
     assert _rel(other[np.asarray(mask)], clean[np.asarray(mask)]) > 0.5  # another layer's banks: the index is read
+
+
+# ------------------------------------------------- a mixed step in row groups
+
+
+def _load_cell(config, traffic):
+    import json
+    bench = os.path.join(os.path.dirname(__file__), "..", "..", "benchmark")
+    with open(os.path.join(bench, "configs", config + ".json")) as f:
+        cfg = json.load(f)
+    with open(os.path.join(bench, "traffic", traffic + ".json")) as f:
+        return cfg, json.load(f)
+
+
+@pytest.mark.parametrize("config,traffic", [("mixtral-8x7b-serve-1chip", "long_prompt_short_answer"),
+                                            ("evabyte-6.5b-serve-1chip", "bytes_doc")])
+def test_two_group_step_matches_the_rectangle_at_cell_widths_on_chip(config, traffic):
+    """One mixed plan at a doc cell's widths, weights and depth (eight rows
+    decoding at contexts a request of the cell reaches, one prompt's chunk of
+    100 tokens behind 2,048): the two-group program ``((16, 1), (1, 128))``
+    against the rectangle ``((16, 128), )`` the engine ran before and the
+    benchmark's check still feeds.  Logits of every live row within the
+    cell's own check limit (the 90th percentile, as the check holds its
+    positions: a router near a tie picks another expert under any rounding),
+    the prompt's pages the same, and the time of each program a step,
+    printed."""
+    _two_groups_against_the_rectangle(config, *_load_cell(config, traffic))
+
+
+def _two_groups_against_the_rectangle(config, cfg, traffic):
+    import jax
+    import jax.numpy as jnp
+    sys.path.insert(0, os.path.join(os.path.dirname(__file__), "..", "..", "benchmark"))
+    import harness
+    from kinds import serve_open_loop
+
+    from deepspeed_tpu.inference.v2.engine_v2 import _table_width, build_cache_model
+    from deepspeed_tpu.models.cache_zoo import cache_geometry, cache_twin
+
+    econf = serve_open_loop.engine_config(cfg, traffic)
+    pcfg = harness.program_config(cfg)
+    _, params = harness.seeded_params(cfg, pcfg, 35, jax.devices()[:1])
+    page, chunk, width = econf.kv.page_size, econf.scheduler.prefill_chunk, _table_width(pcfg, econf.kv)
+    twin = build_cache_model(pcfg, page)
+    assert twin.takes_row_groups
+    # eight rows decoding and one prompt's chunk: (context, tokens in the step, the row's pages)
+    longest = (width - 2) * page if cache_geometry(pcfg, page).pages_immutable else 24000
+    live = [(int(300 + (longest - 300) * i / 7), 1) for i in range(8)] + [(16 * chunk, chunk - 28)]
+    live = [(ctx, n, 1 + i * width + np.arange(width)) for i, (ctx, n) in enumerate(live)]  # every column its own page
+    kv = econf.kv.__class__(num_pages=1 + len(live) * width, page_size=page, max_pages_per_seq=econf.kv.max_pages_per_seq)
+    shape = jax.eval_shape(lambda: cache_twin(pcfg).init_cache(pcfg, kv, econf.kv_dtype, 17, chunk))
+    # a context that is not blank, the same for both programs; the null page zero
+    fresh = jax.jit(lambda: (0.5 * jax.random.normal(jax.random.PRNGKey(1), shape.shape, shape.dtype)).at[:, 0].set(0))
+    ids = np.random.default_rng(35).integers(1, cfg["vocab_size"], (len(live), chunk))
+    prompt_pages = live[-1][2][:17 * chunk // page + 1]
+
+    def run(groups, at):
+        """``at``: for each of ``live`` its row among the groups' concatenated rows."""
+        rows = sum(r for r, _ in groups)
+        first, t0 = [], 0
+        for n_rows, w in groups:
+            first += [t0 + w * i for i in range(n_rows)]
+            t0 += n_rows * w
+        toks, start = np.zeros((t0, ), np.int32), np.zeros((rows, ), np.int32)
+        tables, lens = np.zeros((rows, width), np.int32), np.zeros((rows, ), np.int32)
+        for i, (ctx, n, pages) in enumerate(live):
+            toks[first[at[i]]:first[at[i]] + n] = ids[i, :n]
+            start[at[i]], lens[at[i]], tables[at[i]] = ctx, n, pages
+        toks, start, tables, lens = map(jnp.asarray, (toks, start, tables, lens))
+        if len(groups) == 1:
+            fn = jax.jit(lambda p, c: twin.apply(p, toks.reshape(groups[0]), start, tables, c, lens, True),
+                         donate_argnums=1)
+        else:
+            fn = jax.jit(lambda p, c: twin.apply(p, toks, start, tables, c, lens, True, groups), donate_argnums=1)
+        logits, arena = fn(params, fresh())
+        logits = np.asarray(logits[np.asarray(at), 0], np.float32)
+        written = np.asarray(arena[:, prompt_pages], np.float32)
+        # once more before the clock starts: the arena a program hands back is placed as the
+        # program's own results are, and the first call on it is a second trace of the program
+        _, arena = fn(params, arena)
+        jax.block_until_ready(arena)
+        t0 = time.perf_counter()
+        for _ in range(10):
+            out, arena = fn(params, arena)
+        jax.block_until_ready(out)
+        return logits, written, (time.perf_counter() - t0) / 10 * 1e3
+
+    want, pages_want, rect_ms = run(((16, chunk), ), list(range(9)))               # the plan's nine rows first
+    got, pages_got, groups_ms = run(((16, 1), (1, chunk)), list(range(8)) + [16])  # the prompt in a group of its own
+    errs = np.asarray([_rel(g, w) for g, w in zip(got, want)])
+    limit = min(cfg["check"]["limits"].values())
+    print(f"\n{config}: rectangle ((16, {chunk}), ) {rect_ms:.2f} ms a step, two groups ((16, 1), (1, {chunk})) "
+          f"{groups_ms:.2f} ms; logits two groups against rectangle, ||d|| / ||ref|| over the live rows: "
+          f"p50 {np.median(errs):.5f} p90 {np.percentile(errs, 90):.5f} max {errs.max():.5f} (limit {limit})")
+    assert np.isfinite(got).all()
+    assert np.percentile(errs, 90) <= limit, errs
+    assert _rel(pages_got, pages_want) <= limit
+    assert groups_ms < rect_ms

@@ -66,9 +66,12 @@ def test_warm_all_closes_the_step_set(trained_params):
     res = eng.warm_all()
     assert res["fallback"] == 0 and res["cached"] == 0
     assert res["compiled"] == len(res["keys"]) == len(eng.step_shape_set())
-    # decode_bucket rungs x {1, prefill_chunk} + one verify width
+    # decode_bucket rungs alone and beside each prefill rung (1, 4, max_seqs
+    # rows at prefill_chunk) + one verify width
     assert set(res["keys"]) == {
-        "step:b4:c1", "step:b4:c8", "step:b8:c1", "step:b8:c8",
+        "step:b4:c1", "step:b8:c1",
+        "step:b4:c1:b1:c8", "step:b4:c1:b4:c8", "step:b4:c1:b8:c8",
+        "step:b8:c1:b1:c8", "step:b8:c1:b4:c8", "step:b8:c1:b8:c8",
         "verify:b4:w5", "verify:b8:w5"}
     assert all(c.aot for c in anat.compiles)
     anat.mark_steady()

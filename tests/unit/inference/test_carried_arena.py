@@ -34,13 +34,14 @@ FALCON_ALIBI = FalconConfig(vocab_size=128, hidden_size=64, num_hidden_layers=3,
                             num_kv_heads=4, alibi=True, parallel_attn=False, bias=True,
                             max_position_embeddings=128, dtype=jnp.float32, remat=False)
 
-#: name -> (config, full-sequence model, block, where the stacked blocks and the embedding lie in the tree)
+#: name -> (config, full-sequence model, block, where the stacked blocks and the embedding lie in the tree,
+#: whether the block takes the flat axis of row groups: here the rectangle as its one group)
 FAMILIES = {
-    "llama": (LLAMA, LlamaForCausalLM, LlamaBlockCache, ("model", "layers"), "embed_tokens"),
-    "mixtral": (MIXTRAL, MixtralForCausalLM, MixtralBlockCache, ("layers", ), "embed_tokens"),
+    "llama": (LLAMA, LlamaForCausalLM, LlamaBlockCache, ("model", "layers"), "embed_tokens", True),
+    "mixtral": (MIXTRAL, MixtralForCausalLM, MixtralBlockCache, ("layers", ), "embed_tokens", True),
     "mistral_window": (dataclasses.replace(LLAMA, sliding_window=6), LlamaForCausalLM, LlamaBlockCache,
-                       ("model", "layers"), "embed_tokens"),
-    "falcon_alibi": (FALCON_ALIBI, FalconForCausalLM, FalconBlockCache, ("h", ), "word_embeddings"),
+                       ("model", "layers"), "embed_tokens", True),
+    "falcon_alibi": (FALCON_ALIBI, FalconForCausalLM, FalconBlockCache, ("h", ), "word_embeddings", False),
 }
 
 #: (chunk width, a row's real tokens in it) a step: ragged prefill chunks, then one-token steps
@@ -64,7 +65,7 @@ def _check(family, impl, schedule):
     """Run ``schedule`` through the twin and, beside it, through the per-layer
     form; hold every step's logits to the full-sequence model's and its arena
     to the per-layer form's."""
-    cfg, full_cls, block_cls, layers_at, embed_at = FAMILIES[family]
+    cfg, full_cls, block_cls, layers_at, embed_at, flat = FAMILIES[family]
     cfg = dataclasses.replace(cfg, attention_impl=impl)
     page = KV.page_size
     set_global_mesh(create_mesh(MeshSpec(), devices=jax.devices()[:1]))
@@ -75,8 +76,11 @@ def _check(family, impl, schedule):
     layers = params["params"]
     for key in layers_at:
         layers = layers[key]
-    block = block_cls(cfg, page)
-    one_layer = jax.jit(lambda w, x, pages, *batch: block.apply({"params": w}, (x, pages), None, *batch)[0])
+
+    def one_layer(width):
+        block = block_cls(cfg, page, ((ROWS, width), )) if flat else block_cls(cfg, page)
+        return jax.jit(lambda w, x, pages, *batch: block.apply({"params": w}, (x, pages), None, *batch)[0])
+
     twin = jax.jit(build_cache_model(cfg, page).apply)
     # an arena that is not blank, so that "untouched" says something; the null page is zero
     arena = jax.random.normal(jax.random.PRNGKey(1), init_kv_cache(cfg, KV, jnp.float32).shape).at[:, 0].set(0)
@@ -89,8 +93,10 @@ def _check(family, impl, schedule):
             ids[r, :lens[r]] = tokens[r, start[r]:start[r] + lens[r]]
         positions = start[:, None] + np.arange(width)[None, :]
         x = params["params"][embed_at]["embedding"][jnp.asarray(ids)]
-        by_layer = np.asarray(_per_layer_arena(one_layer, layers, x, arena, jnp.asarray(positions), jnp.asarray(tables),
-                                               jnp.asarray(start), jnp.asarray(lens)))
+        if flat:
+            x, positions = x.reshape(ROWS * width, -1), positions.reshape(-1)
+        by_layer = np.asarray(_per_layer_arena(one_layer(width), layers, x, arena, jnp.asarray(positions),
+                                               jnp.asarray(tables), jnp.asarray(start), jnp.asarray(lens)))
         logits, after = twin(params, jnp.asarray(ids), jnp.asarray(start), jnp.asarray(tables), arena,
                              jnp.asarray(lens))
         logits, before, after = np.asarray(logits), np.asarray(arena), np.asarray(after)
@@ -129,7 +135,7 @@ def test_head_over_the_sampled_rows_equals_the_all_position_logits(family):
     alone (``last_only``: the rows are gathered before the final norm and the
     head); whoever compares logits asks for every position.  One answer, and
     the same arena, at every step of a ragged schedule."""
-    cfg, full_cls, _, _, _ = FAMILIES[family]
+    cfg, full_cls, *_ = FAMILIES[family]
     set_global_mesh(create_mesh(MeshSpec(), devices=jax.devices()[:1]))
     tokens = np.random.default_rng(0).integers(1, cfg.vocab_size, (ROWS, LENGTH), dtype=np.int32)
     params = nn.meta.unbox(full_cls(cfg).init(jax.random.PRNGKey(0), jnp.zeros((1, 8), jnp.int32)))

@@ -1,0 +1,232 @@
+"""The engine hands a mixed step to a twin that takes row groups in two groups
+(``engine_v2._step_groups``): the decode bucket at one slot a row beside a
+prefill group of a rung of rows at the chunk.  Held here: the token streams
+(against a row-at-a-time reference that knows no batching), the closure of
+``step_shape_set`` over every plan the scheduler can make under the benchmark
+cells' scheduler configurations, no compile after ``warm_all``, and what the
+step record of a two-group step holds.
+"""
+
+import dataclasses
+import json
+import os
+import sys
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+from flax import linen as nn
+
+from deepspeed_tpu.comm.mesh import MeshSpec, create_mesh, set_global_mesh
+from deepspeed_tpu.inference.v2 import RaggedInferenceEngineConfig, build_engine
+from deepspeed_tpu.inference.v2.engine_v2 import build_cache_model
+from deepspeed_tpu.inference.v2.ragged import SequenceDescriptor
+from deepspeed_tpu.inference.v2.scheduler import SchedulerConfig
+from deepspeed_tpu.models.evabyte import EvaByteConfig
+from deepspeed_tpu.models.falcon import FalconConfig
+from deepspeed_tpu.models.llama import LlamaConfig
+from deepspeed_tpu.models.llama_cache import PagedKVConfig, init_kv_cache
+from deepspeed_tpu.models.mixtral import PRESETS as MIXTRAL_PRESETS
+from deepspeed_tpu.serving import RequestState, ServingConfig, ServingEngine, VirtualClock
+from deepspeed_tpu.telemetry import StepAnatomy
+
+BENCHMARK = os.path.join(os.path.dirname(__file__), "..", "..", "..", "benchmark")
+sys.path.insert(0, BENCHMARK)
+import step_trace  # noqa: E402
+
+PAGE, CHUNK = 16, 16
+CONFIGS = {
+    "llama": LlamaConfig(vocab_size=128, hidden_size=64, intermediate_size=128, num_hidden_layers=2,
+                         num_attention_heads=4, num_key_value_heads=2, max_position_embeddings=512,
+                         rope_theta=1e4, dtype=jnp.float32, scan_layers=True, remat=False),
+    "mixtral": dataclasses.replace(MIXTRAL_PRESETS["tiny"], dtype=jnp.float32, remat=False, drop_tokens=False,
+                                   num_hidden_layers=2, max_position_embeddings=512),
+    "evabyte": EvaByteConfig(hidden_size=64, intermediate_size=128, num_hidden_layers=2, num_attention_heads=4,
+                             num_key_value_heads=4, max_position_embeddings=2048, window_size=256,
+                             chunk_size=PAGE, dtype=jnp.float32, param_dtype=jnp.float32),
+}
+#: a twin whose blocks take rectangles only
+FALCON = FalconConfig(vocab_size=128, hidden_size=64, num_hidden_layers=2, num_attention_heads=4, num_kv_heads=4,
+                      alibi=False, parallel_attn=True, bias=False, max_position_embeddings=512, dtype=jnp.float32,
+                      remat=False)
+KV = PagedKVConfig(num_pages=160, page_size=PAGE, max_pages_per_seq=24)
+SCHED = SchedulerConfig(token_budget=40, max_seqs=8, prefill_chunk=CHUNK, decode_bucket=4)
+NEW = 6
+
+
+def _params(cfg):
+    set_global_mesh(create_mesh(MeshSpec(), devices=jax.devices()[:1]))
+    twin = build_cache_model(cfg, PAGE)
+    cache = init_kv_cache(cfg, KV, jnp.float32)
+    table = jnp.zeros((1, KV.max_pages_per_seq), jnp.int32)
+    one = jnp.zeros((1, ), jnp.int32)
+    return twin, nn.meta.unbox(twin.init(jax.random.PRNGKey(0), jnp.zeros((1, 1), jnp.int32), one, table, cache,
+                                         jnp.ones((1, ), jnp.int32)))
+
+
+def _engine(cfg, params, k=1, sched=SCHED, kv=KV):
+    return build_engine(cfg, params, RaggedInferenceEngineConfig(
+        kv=kv, scheduler=sched, kv_dtype=jnp.float32, decode_steps_per_dispatch=k, max_new_tokens=NEW,
+        enable_prefix_cache=False))
+
+
+def _prompts(cfg, seed=3):
+    """Mixed traffic: prompts of less than a chunk, of a chunk and a bit and
+    of several chunks, so that under a budget of two and a half chunks some
+    rows decode while others are still prefilling, one or several at a time."""
+    rng = np.random.default_rng(seed)
+    return [rng.integers(1, cfg.vocab_size, n).tolist() for n in (5, 70, 17, 3, 41, 16, 9, 33)]
+
+
+def _row_at_a_time(cfg, twin, params, prompts, new=NEW):
+    """Greedy streams from the twin fed one sequence at a time as rectangles
+    of one row: the prompt in chunks, then one token a step."""
+    table = jnp.asarray(1 + np.arange(KV.max_pages_per_seq, dtype=np.int32)[None])
+    step = jax.jit(lambda c, t, s, n: twin.apply(params, t, s, table, c, n, True))
+    out = []
+    for prompt in prompts:
+        cache, pos, toks = init_kv_cache(cfg, KV, jnp.float32), 0, list(prompt)
+        while len(toks) < len(prompt) + new:
+            n = min(CHUNK, len(prompt) - pos) if pos < len(prompt) else 1
+            ids = np.zeros((1, CHUNK if n > 1 or pos < len(prompt) else 1), np.int32)
+            ids[0, :n] = toks[pos:pos + n]
+            logits, cache = step(cache, jnp.asarray(ids), jnp.asarray([pos], jnp.int32), jnp.asarray([n], jnp.int32))
+            pos += n
+            if pos >= len(prompt):
+                toks.append(int(jnp.argmax(logits[0, 0])))
+        out.append(toks[len(prompt):])
+    return out
+
+
+@pytest.fixture(scope="module", params=sorted(CONFIGS))
+def family(request):
+    cfg = CONFIGS[request.param]
+    twin, params = _params(cfg)
+    prompts = _prompts(cfg)
+    return cfg, params, prompts, _row_at_a_time(cfg, twin, params, prompts)
+
+
+def _two_group_rows(anat):
+    return [r for r in (s.to_row() for s in anat.steps) if r["key"].count(":b") == 2]
+
+
+def test_generate_emits_the_row_at_a_time_streams(family):
+    cfg, params, prompts, want = family
+    eng = _engine(cfg, params)
+    assert eng.generate(prompts) == want
+    rows = _two_group_rows(eng.anatomy)
+    assert rows and any(r["rows_decode"] and r["rows_prefill"] for r in rows), "no mixed step ran in two groups"
+
+
+@pytest.mark.parametrize("async_dispatch", [False, True])
+def test_both_serving_ticks_emit_the_row_at_a_time_streams_and_compile_nothing(family, async_dispatch):
+    cfg, params, prompts, want = family
+    eng = _engine(cfg, params, k=4)
+    clock = VirtualClock()
+    anat = eng.set_anatomy(StepAnatomy(clock=clock))
+    warm = eng.warm_all()
+    assert warm["fallback"] == 0 and warm["compiled"] == len(eng.step_shape_set())
+    programs = set(eng._step_fns)
+    serve = ServingEngine(eng, clock=clock, config=ServingConfig(async_dispatch=async_dispatch))
+    # arrivals a few ticks apart: later prompts prefill beside rows that decode
+    reqs = serve.run([dict(prompt=p, max_new_tokens=NEW, arrival_ts=0.02 * i) for i, p in enumerate(prompts)])
+    assert all(r.state is RequestState.DONE for r in reqs)
+    assert [r.tokens for r in reqs] == want
+    assert set(eng._step_fns) == programs and anat.steady_state_recompiles == 0
+    assert sum(r.compiles for r in anat.steps) == 0
+    assert _two_group_rows(anat)
+
+
+def test_the_step_record_of_a_two_group_step(family):
+    cfg, params, prompts, _ = family
+    eng = _engine(cfg, params)
+    eng.put([0, 1], [prompts[0], prompts[3]])
+    while not all(s.in_decode for s in eng.state.seqs.values()):
+        eng.step()
+    eng.put([2], [prompts[1]])                      # 70 tokens: chunks of 16 beside two decoding rows
+    plan = eng.scheduler.plan(eng.state)
+    assert len(plan.decode) == 2 and [n for _, n in plan.prefill] == [CHUNK]
+    eng.step(plan)
+    row = eng.anatomy.last_step.to_row()
+    assert row["key"] == "step:b4:c1:b1:c16" and row["path"] == "mixed"
+    assert row["slots"] == 4 + 1 * CHUNK and row["tokens_real"] == plan.planned_tokens == 2 + CHUNK
+    assert (row["rows_decode"], row["rows_prefill"]) == (2, 1)
+    # the key survives _named -> the lowered module's name -> benchmark/step_trace.program_key
+    module = eng._aot_lower(((4, 1), (1, CHUNK))).as_text()[:400]
+    assert "jit_ds_step_b4_c1_b1_c16" in module
+    assert step_trace.program_key("jit_ds_step_b4_c1_b1_c16(1234)") == row["key"]
+    for _ in range(7):                               # the prompt's other four chunks, then one-token steps
+        eng.step()
+    rows = [s.to_row() for s in eng.anatomy.steps]
+    mixed = sum(1 for r in rows if r["key"] != "step:b4:c1")
+    assert step_trace.mixed_step_share(rows) == pytest.approx(mixed / len(rows)) and 3 <= mixed < len(rows)
+    assert step_trace.slot_fill_share([row]) == pytest.approx((2 + CHUNK) / (4 + CHUNK))
+
+
+def test_a_twin_that_does_not_take_row_groups_keeps_the_rectangle():
+    _, params = _params(FALCON)
+    eng = _engine(FALCON, params)
+    assert not eng._row_groups and build_cache_model(CONFIGS["llama"], PAGE).takes_row_groups
+    keys = {eng._key_label(k) for k in eng.step_shape_set()}
+    assert keys == {"step:b4:c1", "step:b8:c1", "step:b4:c16", "step:b8:c16"}
+    prompts = _prompts(FALCON)
+    outs = eng.generate(prompts[:3])
+    assert all(len(o) == NEW for o in outs)
+    assert {s.to_row()["key"] for s in eng.anatomy.steps} <= keys
+
+
+# ------------------------------------------------ every plan has its program
+
+
+def _cell_schedulers():
+    out = {}
+    for name in sorted(os.listdir(os.path.join(BENCHMARK, "configs"))):
+        with open(os.path.join(BENCHMARK, "configs", name)) as f:
+            engine = json.load(f).get("engine")
+        if engine and "scheduler" in engine:   # the serving cells' configurations
+            out[name[:-len(".json")]] = SchedulerConfig(**engine["scheduler"])
+    return out
+
+
+CELL_SCHEDULERS = _cell_schedulers()
+
+
+@pytest.mark.parametrize("twin", ["row_groups", "rectangle"])
+@pytest.mark.parametrize("cell", sorted(CELL_SCHEDULERS))
+def test_every_plan_of_a_cells_scheduler_maps_to_a_key_of_the_step_set(cell, twin):
+    """Random populations of decoding and prefilling sequences, up to more
+    than the scheduler admits: whatever ``plan`` returns, ``_step_groups``
+    names a program of ``step_shape_set`` and holds every row of the plan."""
+    sched = CELL_SCHEDULERS[cell]
+    cfg = CONFIGS["llama"] if twin == "row_groups" else FALCON
+    _, params = _params(cfg)
+    eng = _engine(cfg, params, k=8, sched=sched, kv=dataclasses.replace(KV, num_pages=32))
+    keys = set(eng.step_shape_set())
+    assert len([k for k in keys if not isinstance(k[0], str)]) <= 4
+    rng = np.random.default_rng(0)
+    seen = set()
+    for trial in range(300):
+        eng.state.seqs.clear()
+        n_decode, n_prefill = rng.integers(0, sched.max_seqs + 3), rng.integers(0, sched.max_seqs + 3)
+        if trial % 3 == 0:
+            n_prefill = min(n_prefill, 2)        # the usual population: few prompts arrive at once
+        for uid in range(n_decode + n_prefill):
+            length = int(rng.integers(1, 4 * sched.prefill_chunk))
+            seq = SequenceDescriptor(uid=uid, tokens=[1] * length)
+            seq.seen_tokens = length - 1 if uid < n_decode else int(rng.integers(0, length))
+            if uid < n_decode:
+                seq.generated = [1]
+            eng.state.seqs[uid] = seq
+        plan = eng.scheduler.plan(eng.state)
+        if not plan.decode and not plan.prefill:
+            continue
+        packed = eng._step_groups(plan)
+        groups = tuple((rows, width) for _, rows, width in packed)
+        assert groups in keys, (groups, len(plan.decode), plan.prefill)
+        assert all(len(work) <= rows and all(n <= width for _, n in work) for work, rows, width in packed)
+        assert sum(len(work) for work, _, _ in packed) == len(plan.decode) + len(plan.prefill)
+        assert sum(n for work, _, _ in packed for _, n in work) == plan.planned_tokens
+        seen.add(groups)
+    single = {k for k in keys if not isinstance(k[0], str)}
+    assert seen == single, f"programs no plan reached: {single - seen}"

@@ -46,14 +46,14 @@ def test_nvtx_shim(tmp_path):
     try:
         assert f(4) == 8
         with profiler_range("ds.step") as rng:
-            rng.set_metadata(key="step:b4:c1", slots=4)
+            rng.set_metadata(key="step:b4:c1:b1:c8", slots=12)
     finally:
         jax.profiler.stop_trace()
     data = ProfileData.from_file(glob.glob(str(tmp_path / "**" / "*.xplane.pb"), recursive=True)[0])
     host = {ev.name: dict(ev.stats) for plane in data.planes if plane.name.startswith("/host:")
             for line in plane.lines for ev in line.events}
     assert f.__qualname__ in host
-    assert host["ds.step"] == {"key": "step:b4:c1", "slots": 4}
+    assert host["ds.step"] == {"key": "step:b4:c1:b1:c8", "slots": 12}
 
 
 def test_legacy_transformer_layer_pre_and_post_ln():

@@ -549,23 +549,25 @@ def _counts(rec):
 
 
 def test_counts_of_single_and_mixed_steps_by_hand(tiny_serving):
-    """Prompts of 3 and 12 tokens, chunk 8, batch bucket 2, 3 tokens
-    each.  Step 0 prefills 3 + 8 positions in 2 x 8 slots and the short
-    prompt's first token comes out; step 1 is mixed (one decode row, the
-    long prompt's last 4 positions) and gives two tokens; steps 2 and 3
-    decode the two rows, one of which ends after step 2."""
+    """Prompts of 3 and 12 tokens, chunk 8, batch bucket 2, prefill rungs
+    of 1 and 4 rows, 3 tokens each.  Step 0 prefills 3 + 8 positions in a
+    prefill group of 4 x 8 slots beside the decode bucket's 2 dead slots
+    and the short prompt's first token comes out; step 1 is mixed (one
+    decode row of the bucket's 2 slots, the long prompt's last 4 positions
+    in a group of 1 x 8) and gives two tokens; steps 2 and 3 decode the
+    two rows, one of which ends after step 2."""
     eng = tiny_serving()
     anat = eng.set_anatomy(StepAnatomy(clock=VirtualClock()))
     outs = eng.generate([[1, 2, 3], list(range(1, 13))], max_new_tokens=3)
     got = [_counts(r) for r in anat.steps]
     assert got == [
-        ("step:b2:c8", "prefill", 0, 2, 11, 16, 1, 0),
-        ("step:b2:c8", "mixed", 1, 1, 5, 16, 2, 0),
+        ("step:b2:c1:b4:c8", "prefill", 0, 2, 11, 34, 1, 0),
+        ("step:b2:c1:b1:c8", "mixed", 1, 1, 5, 10, 2, 0),
         ("step:b2:c1", "decode", 2, 0, 2, 2, 2, 0),
         ("step:b2:c1", "decode", 1, 0, 1, 2, 1, 0)]
     assert sum(r.tokens_out for r in anat.steps) == sum(len(o) for o in outs) == 6
     fold = anat.by_shape()
-    assert fold["step:b2:c8"]["tokens_real"] == 16 and fold["step:b2:c8"]["slots"] == 32
+    assert fold["step:b2:c1:b1:c8"]["tokens_real"] == 5 and fold["step:b2:c1:b1:c8"]["slots"] == 10
     assert all(r.tokens_real <= r.slots for r in anat.steps)
     # a model with no expert layer sends no row through experts, or their kernel
     assert all(r.expert_rows == 0 and r.expert_rows_kernel == 0 for r in anat.steps)
@@ -580,7 +582,7 @@ def test_counts_of_a_fused_dispatch_with_overshoot(tiny_serving):
     outs = eng.generate([[1, 2, 3]], max_new_tokens=6)
     got = [_counts(r) for r in anat.steps]
     assert got == [
-        ("step:b2:c8", "prefill", 0, 1, 3, 16, 1, 0),
+        ("step:b2:c1:b1:c8", "prefill", 0, 1, 3, 10, 1, 0),
         ("multi:b2:k4", "multi_decode", 1, 0, 4, 8, 4, 0),
         ("multi:b2:k4", "multi_decode", 1, 0, 4, 8, 1, 3)]
     assert sum(r.tokens_out for r in anat.steps) == len(outs[0]) == 6
@@ -642,17 +644,17 @@ def test_admit_and_deliver_are_segments_in_both_ticks(tiny_serving, async_dispat
 def test_step_programs_carry_their_key_as_a_name(tiny_serving):
     """Each builder names its function after the program key before
     ``jax.jit``, so the lowered module (what the device trace's ``XLA
-    Modules`` line shows) is ``jit_ds_step_b2_c8`` and not ``jit_step``."""
+    Modules`` line shows) is ``jit_ds_step_b2_c1_b1_c8`` and not ``jit_step``."""
     from deepspeed_tpu.inference.v2.engine_v2 import _named
 
     eng = tiny_serving(k=4)
     assert {eng._key_label(k) for k in eng.step_shape_set()} >= {
-        "step:b2:c1", "step:b2:c8", "multi:b2:k4"}
-    assert "jit_ds_step_b2_c8" in eng._aot_lower((2, 8)).as_text()[:400]
-    assert "jit_ds_step_b2_c1" in eng._aot_lower((2, 1)).as_text()[:400]
+        "step:b2:c1", "step:b2:c1:b1:c8", "step:b2:c1:b4:c8", "multi:b2:k4"}
+    assert "jit_ds_step_b2_c1_b1_c8" in eng._aot_lower(((2, 1), (1, 8))).as_text()[:400]
+    assert "jit_ds_step_b2_c1" in eng._aot_lower(((2, 1), )).as_text()[:400]
     assert "jit_ds_multi_b2_k4" in eng._aot_lower(("multi", 2, 4)).as_text()[:400]
     assert eng._build_verify_jit(2, 5).__name__ == "ds_verify_b2_w5"
-    assert eng._compiled_step(4, 8).__name__ == "ds_step_b4_c8"
+    assert eng._compiled_step(((4, 8), )).__name__ == "ds_step_b4_c8"
     assert _named(lambda x: x, "multi:b16:k8").__name__ == "ds_multi_b16_k8"
 
 
