@@ -39,6 +39,7 @@ from ...ops.paged_attention import walk_block
 from ...telemetry.step_anatomy import NULL_ANATOMY, StepAnatomy
 from ...utils.logging import logger
 from ...utils.nvtx import profiler_range
+from .geometry import LinearGeometry
 from .ragged import BlockedKVCache, RaggedBatch, StateManager
 from .scheduler import SchedulerConfig, SplitFuseScheduler, StepPlan
 from .spec import SpecConfig, SpecStats, make_drafter
@@ -182,7 +183,7 @@ def _serving_shardings(model, cfg, econfig, mesh):
 
     from ...comm.mesh import TENSOR_AXIS
     from ...module_inject.tp_rules import param_shardings
-    from ...models.cache_zoo import cache_geometry
+    from ...models.cache_zoo import cache_geometry, cache_twin
     kvcfg = econfig.kv
     cache_abs = jax.eval_shape(lambda: _init_cache(cfg, econfig))
     toks1 = jnp.zeros((1, 1), jnp.int32)
@@ -197,6 +198,12 @@ def _serving_shardings(model, cfg, econfig, mesh):
             raise NotImplementedError("tensor-parallel serving of a model with state slots: the slots' arrays "
                                       "(rings, recurrent states) have no sharding rule yet")
         cache_sh = jax.tree.map(lambda _: repl, cache_abs)
+    elif cache_twin(cfg).walk_rows is not None:
+        # pages of a twin's own shape under a kernel of its own (latent pages [L, P, page, W])
+        if mesh.shape.get(TENSOR_AXIS, 1) > 1:
+            raise NotImplementedError("tensor-parallel serving of latent pages: a row is every head's, and no "
+                                      "head-sharded call of ds_mla_absorbed is built yet")
+        cache_sh = repl
     else:
         cache_sh = NamedSharding(mesh, P(None, None, None, None, TENSOR_AXIS, None))
     return abs_vars, cache_abs, param_sh, cache_sh, repl
@@ -990,6 +997,10 @@ class InferenceEngineV2:
         cfg = self.cfg
         if not reads_through_kernel(getattr(cfg, "attention_impl", None), getattr(cfg, "alibi", False)):
             return 0
+        from ...models.cache_zoo import cache_twin
+        own_walk = cache_twin(cfg).walk_rows
+        if own_walk is not None:  # a kernel of the twin's own over pages of another shape
+            return own_walk(self.kv.page_size, self.kv.table_width)
         from ...comm.mesh import TENSOR_AXIS
         pages = self._pages()
         *_, n_kv, d = pages.shape
@@ -1007,10 +1018,10 @@ class InferenceEngineV2:
         return tuple(sum(c) for c in zip(*counts))
 
     def _state_counts(self, work, calls: int = 1) -> dict:
-        """The step records' counts of a geometry with state slots (none
-        without): the geometry's ``state_counts`` summed over the step's rows."""
+        """The step records' named counts of a geometry that has some (state
+        slots, latent pages): its ``state_counts`` summed over the step's rows."""
         geometry = self.kv.geometry
-        if not geometry.state_slots:
+        if type(geometry).state_counts is LinearGeometry.state_counts:  # none to add
             return {}
         total = {}
         for s, n in work:

@@ -36,6 +36,9 @@ from .phi4flash import Phi4FlashConfig
 from .phi4flash_cache import Phi4FlashForCausalLMWithCache
 from .phi4flash_cache import init_cache as init_phi4flash_cache
 from .qwen2_moe import Qwen2MoeConfig, Qwen2MoeSparseMLP
+from .xing4 import Xing4Config
+from .xing4_cache import LatentPagesGeometry, Xing4ForCausalLMWithCache
+from .xing4_cache import init_cache as init_xing4_cache, walk_rows as xing4_walk_rows
 
 
 # ------------------------------------------------------------------- falcon
@@ -386,11 +389,15 @@ class CacheTwin:
     what the engine keeps as ``eng.cache`` and hands the twin: the one arena
     of pages [L, P, page, 2, n_kv, hd], or pages (of the layers whose keys
     and values grow: one, or several under one block table) and state slots
-    together, of which ``pages(cache)`` is the arena the paged kernel reads."""
+    together, of which ``pages(cache)`` is the arena the paged kernel reads.
+    ``walk_rows(page_size, table_width)``: key rows a block of the walk holds
+    where the twin's pages go through a kernel of its own (latent pages,
+    ``ops/mla_attention.py``); None: ``ds_paged_attention``'s, from the arena's shape."""
     model: Callable
     geometry: Callable = lambda cfg, page_size: LinearGeometry(page_size)
     init_cache: Callable = lambda cfg, kv, dtype, n_slots, chunk: init_kv_cache(cfg, kv, dtype=dtype)
     pages: Callable = lambda cache: cache
+    walk_rows: Callable = None
 
 
 def _dropless_mixtral(cfg, page_size):
@@ -419,6 +426,8 @@ CACHE_MODEL_REGISTRY = {
                                    lambda cfg, page_size: SlotPagesGeometry(page_size,
                                                                             state_bytes=slot_state_bytes(cfg)),
                                    init_granite_hybrid_cache, lambda cache: cache["pages"]),
+    Xing4Config: CacheTwin(Xing4ForCausalLMWithCache, lambda cfg, page_size: LatentPagesGeometry(page_size),
+                           init_xing4_cache, walk_rows=xing4_walk_rows),
 }
 
 

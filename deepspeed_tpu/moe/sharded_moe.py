@@ -218,7 +218,8 @@ def _experts_dense(x, top_vals, expert, group_sizes, bank, layer):
     return jnp.einsum("se,esd->sd", weights, y)
 
 
-def dropless_moe(x, logits, bank, k: int, token_mask=None, noise=None, layer=None, normalize: bool = True):
+def dropless_moe(x, logits, bank, k: int, token_mask=None, noise=None, layer=None, normalize: bool = True,
+                 scoring: str = "softmax", select_bias=None, route_scale: float = 1.0):
     """Route one group with no capacity: every live token through its k
     highest experts.
 
@@ -228,21 +229,43 @@ def dropless_moe(x, logits, bank, k: int, token_mask=None, noise=None, layer=Non
     whole scanned trunk [L, E, ...], of which layer ``layer``'s are read in
     place; token_mask: [S] bool or None — rows that carry no token go to no
     expert and come out as zeros; noise: [S, E] or None, added to the logits
-    for the choice only (``top1_gating``'s RSample).  Gate values as the
-    capacity path has them: softmax in float32, renormalised over the k when
-    k > 1 unless the model publishes that it does not (``normalize``, Qwen2-MoE's
-    ``norm_topk_prob``); a token's k outputs are weighted and added in float32.
-    Returns (out [S, d] float32, l_aux, exp_counts [E] int32).
+    for the choice only (``top1_gating``'s RSample).
+
+    Three router forms, all in float32, chosen by what the model publishes:
+
+    * softmax over all experts, the k largest, renormalised over the k
+      (``scoring="softmax"``, the default; Mixtral, and the capacity path's
+      gate values) -- or not renormalised, where the model publishes
+      ``norm_topk_prob: false`` (``normalize=False``: Qwen2-MoE);
+    * sigmoid scores, one an expert (``scoring="sigmoid"``: the published
+      ``scoring_func``), the k largest, ``w = s / (sum + 1e-20)`` over the k
+      where ``norm_topk_prob`` is true;
+    * either with a learned **selection bias** [E] added to the scores for
+      the choice alone (``select_bias``: ``topk_method: "noaux_tc"``'s
+      ``e_score_correction_bias``): the weights are the unbiased scores of
+      the experts so chosen.
+
+    ``route_scale`` (``routed_scaling_factor``) multiplies the k weights
+    after the renormalisation.  A token's k outputs are weighted and added in
+    float32.  Returns (out [S, d] float32, l_aux, exp_counts [E] int32).
     """
     s, e = logits.shape
-    gates = jax.nn.softmax(logits, axis=-1)
-    if noise is None:
+    if scoring not in ("softmax", "sigmoid"):
+        raise ValueError(f"unknown router scoring {scoring!r}: softmax or sigmoid")
+    gates = jax.nn.softmax(logits, axis=-1) if scoring == "softmax" else jax.nn.sigmoid(logits)
+    if noise is None and select_bias is None:
         top_vals, top_idx = jax.lax.top_k(gates, k)  # [S, k]
     else:
-        _, top_idx = jax.lax.top_k(logits + noise, k)
+        choice = gates if noise is None else logits + noise
+        if select_bias is not None:
+            choice = choice + select_bias.astype(jnp.float32)
+        _, top_idx = jax.lax.top_k(choice, k)
         top_vals = jnp.take_along_axis(gates, top_idx, axis=-1)
     if normalize and k > 1:
-        top_vals = top_vals / jnp.maximum(jnp.sum(top_vals, axis=-1, keepdims=True), 1e-9)
+        total = jnp.sum(top_vals, axis=-1, keepdims=True)
+        top_vals = top_vals / (jnp.maximum(total, 1e-9) if scoring == "softmax" else total + 1e-20)
+    if route_scale != 1.0:
+        top_vals = top_vals * route_scale
     live = jnp.ones((s, ), bool) if token_mask is None else token_mask
 
     # aux load-balancing loss on the top-1 mask (ref: l_aux = E * sum(me * ce))
@@ -255,7 +278,8 @@ def dropless_moe(x, logits, bank, k: int, token_mask=None, noise=None, layer=Non
     return experts(x, top_vals, expert, group_sizes, bank, layer), l_aux, group_sizes
 
 
-def dropless_dispatch(x, logits, bank, k: int, token_mask=None, noise=None, layer=None, normalize: bool = True):
+def dropless_dispatch(x, logits, bank, k: int, token_mask=None, noise=None, layer=None, normalize: bool = True,
+                      scoring: str = "softmax", select_bias=None, route_scale: float = 1.0):
     """``dropless_moe`` over a batch [B, S, ...]: one group a data shard.
 
     Without capacity a token's output does not depend on its group, so the
@@ -268,14 +292,16 @@ def dropless_dispatch(x, logits, bank, k: int, token_mask=None, noise=None, laye
     The bank enters replicated: where ZeRO-3 partitions it that is the
     all-gather a dense product would need too, of the compute dtype the
     caller cast it to, and its cotangent, a psum inside the manual region and
-    a slice outside, leaves a TPU as one fused reduce-scatter.
+    a slice outside, leaves a TPU as one fused reduce-scatter.  ``scoring``,
+    ``select_bias`` (replicated, closed over) and ``route_scale`` are
+    ``dropless_moe``'s router forms.
     """
     from jax.sharding import PartitionSpec as P
 
     def one_group(x, logits, token_mask, noise, bank, layer):
         flat = lambda a: None if a is None else a.reshape((-1, ) + a.shape[2:])
         out, l_aux, counts = dropless_moe(flat(x), flat(logits), bank, k, flat(token_mask), flat(noise), layer,
-                                          normalize)
+                                          normalize, scoring, select_bias, route_scale)
         return out.reshape(x.shape[:2] + out.shape[1:]), l_aux, counts
 
     mesh = get_trace_mesh()

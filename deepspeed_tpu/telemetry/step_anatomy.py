@@ -152,7 +152,7 @@ HOST_SEGMENTS = ("admit", "schedule", "draft_plan", "verify_plan",
 #: what a step carried; zero until the engine notes them
 COUNTS = ("rows_decode", "rows_prefill", "tokens_real", "slots", "tokens_out",
           "tokens_discarded", "expert_rows", "expert_rows_kernel", "attn_rows_visible", "attn_rows_walked",
-          "ssm_rows", "window_rows_visible", "ssd_state_bytes")
+          "ssm_rows", "window_rows_visible", "ssd_state_bytes", "mla_rows_read")
 
 #: names of the instant profiler events, built once (a mark allocates no string)
 _MARK_NAMES = {s: "ds.mark." + s for s in HOST_SEGMENTS + ("device_wait", )}
@@ -211,7 +211,7 @@ class StepRecord:
         self.tokens_out = self.tokens_discarded = 0
         self.expert_rows = self.expert_rows_kernel = 0
         self.attn_rows_visible = self.attn_rows_walked = 0
-        self.ssm_rows = self.window_rows_visible = self.ssd_state_bytes = 0
+        self.ssm_rows = self.window_rows_visible = self.ssd_state_bytes = self.mla_rows_read = 0
 
     def host_s(self) -> float:
         return sum(self.segments.values())

@@ -47,7 +47,7 @@ HOST_SEGMENTS = ("admit", "schedule", "draft_plan", "verify_plan",
 #: must mirror telemetry/step_anatomy.py COUNTS — what a step carried
 COUNTS = ("rows_decode", "rows_prefill", "tokens_real", "slots", "tokens_out",
           "tokens_discarded", "expert_rows", "expert_rows_kernel", "attn_rows_visible", "attn_rows_walked",
-          "ssm_rows", "window_rows_visible", "ssd_state_bytes")
+          "ssm_rows", "window_rows_visible", "ssd_state_bytes", "mla_rows_read")
 
 
 def fold(anatomy, tol=1e-6):
