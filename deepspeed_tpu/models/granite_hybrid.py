@@ -235,7 +235,9 @@ class Mamba2Mixer(nn.Module):
     """The projections, the convolution and the gated norm of a Mamba-2
     layer; how the recurrence runs between ``project`` and ``finish`` (whole
     sequence here, through the slot arena in the serving twin) is the
-    caller's."""
+    caller's.  ``project`` is ``in_project``, a function of a token alone,
+    and ``convolve``, which needs a row's sequence: the serving twin runs the
+    first on its flat token axis and the second a row group at a time."""
     cfg: GraniteHybridConfig
 
     def setup(self):
@@ -252,37 +254,55 @@ class Mamba2Mixer(nn.Module):
         self.norm = _Weight(cfg.d_inner, cfg.param_dtype, name="norm")
         self.out_proj = _dense(cfg, cfg.hidden_size, "out_proj")
 
-    def project(self, u, tail, chunk_lens):
-        """``u`` [B, C, hidden], ``tail`` [B, d_conv - 1, conv_dim] (the last
-        inputs of the convolution, zeros at a sequence's start) -> (``z`` [B,
-        C, d_inner], ``x`` [B, C, H, P], ``B``, ``C`` [B, C, N], ``dt`` [B, C,
-        H] float32 and 0 past ``chunk_lens``, the new tail)."""
+    def in_project(self, u, live):
+        """``u`` [..., hidden], ``live`` [...] (whether the position carries a
+        token) -> (the gate ``z`` [..., d_inner], the convolution's input
+        [..., conv_dim], ``dt`` [..., H] float32 and 0 where not ``live``)."""
         cfg = self.cfg
-        d, n, k = cfg.d_inner, cfg.mamba_d_state, cfg.mamba_d_conv
-        c = u.shape[1]
-        z, xbc, dt = jnp.split(self.in_proj(u), [d, d + cfg.conv_dim], axis=-1)
+        z, xbc, dt = jnp.split(self.in_proj(u), [cfg.d_inner, cfg.d_inner + cfg.conv_dim], axis=-1)
+        dt = jax.nn.softplus(dt.astype(jnp.float32) + self.dt_bias.astype(jnp.float32))
+        return z, xbc, jnp.where(live[..., None], dt, 0.0)
+
+    def convolve(self, xbc, tail, chunk_lens):
+        """``xbc`` [B, C, conv_dim] as ``in_project`` gave it, ``tail`` [B,
+        d_conv - 1, conv_dim] (the last inputs of the convolution, zeros at a
+        sequence's start) -> (the convolved and activated ``xbc``, the new
+        tail)."""
+        cfg = self.cfg
+        c, k = xbc.shape[1], cfg.mamba_d_conv
         seen = jnp.concatenate([tail.astype(xbc.dtype), xbc], axis=1)                    # [B, k-1+C, conv_dim]
         conv = sum(seen[:, j:j + c].astype(jnp.float32) * self.conv_kernel[j].astype(jnp.float32) for j in range(k))
         if cfg.mamba_conv_bias:
             conv = conv + self.conv_bias.astype(jnp.float32)
-        xbc = nn.silu(conv).astype(cfg.dtype)
         # the inputs before the row's next position: rows n .. n + k - 2 of ``seen``
         tail = jnp.take_along_axis(seen, (chunk_lens[:, None] + jnp.arange(k - 1)[None, :])[:, :, None], axis=1)
-        x, b_mat, c_mat = jnp.split(xbc, [d, d + n], axis=-1)
-        dt = jax.nn.softplus(dt.astype(jnp.float32) + self.dt_bias.astype(jnp.float32))
-        dt = jnp.where(jnp.arange(c)[None, :, None] < chunk_lens[:, None, None], dt, 0.0)
-        return z, x.reshape(x.shape[:2] + (cfg.mamba_n_heads, cfg.mamba_d_head)), b_mat, c_mat, dt, tail
+        return nn.silu(conv).astype(cfg.dtype), tail
+
+    def x_b_c(self, xbc):
+        """The convolved ``xbc`` [..., conv_dim] -> ``x`` [..., H, P], ``B``, ``C`` [..., N]."""
+        cfg = self.cfg
+        x, b_mat, c_mat = jnp.split(xbc, [cfg.d_inner, cfg.d_inner + cfg.mamba_d_state], axis=-1)
+        return x.reshape(x.shape[:-1] + (cfg.mamba_n_heads, cfg.mamba_d_head)), b_mat, c_mat
+
+    def project(self, u, tail, chunk_lens):
+        """A rectangle through all three: ``u`` [B, C, hidden], ``tail`` [B,
+        d_conv - 1, conv_dim] -> (``z`` [B, C, d_inner], ``x`` [B, C, H, P],
+        ``B``, ``C`` [B, C, N], ``dt`` [B, C, H] float32 and 0 past
+        ``chunk_lens``, the new tail)."""
+        z, xbc, dt = self.in_project(u, jnp.arange(u.shape[1])[None, :] < chunk_lens[:, None])
+        xbc, tail = self.convolve(xbc, tail, chunk_lens)
+        return (z, ) + self.x_b_c(xbc) + (dt, tail)
 
     def neg_a(self):
         return -jnp.exp(self.A_log.astype(jnp.float32))
 
     def finish(self, y, x, z):
-        """``y`` [B, C, H, P] float32 (the recurrence's output without the skip
-        term), ``x`` as ``project`` gave it, ``z`` the gate -> [B, C, hidden]."""
+        """``y`` [..., H, P] float32 (the recurrence's output without the skip
+        term), ``x`` as ``x_b_c`` gave it, ``z`` the gate -> [..., hidden]."""
         cfg = self.cfg
         with jax.named_scope("ds_gated_norm"):
             y = y + self.D.astype(jnp.float32)[:, None] * x.astype(jnp.float32)
-            y = y.reshape(y.shape[:2] + (cfg.d_inner, )) * nn.silu(z.astype(jnp.float32))
+            y = y.reshape(y.shape[:-2] + (cfg.d_inner, )) * nn.silu(z.astype(jnp.float32))
             y = y * jax.lax.rsqrt(jnp.mean(jnp.square(y), axis=-1, keepdims=True) + cfg.rms_norm_eps)
             y = (y * self.norm().astype(jnp.float32)).astype(cfg.dtype)
         return self.out_proj(y)
@@ -312,14 +332,14 @@ class GraniteAttention(nn.Module):
         self.o_proj = _dense(cfg, cfg.hidden_size, "o_proj")
 
     def qkv(self, x):
-        """[B, C, H, d], [B, C, H_kv, d], [B, C, H_kv, d]."""
+        """``x`` [..., hidden] -> [..., H, d], [..., H_kv, d], [..., H_kv, d]."""
         cfg = self.cfg
-        heads = lambda t, n: t.reshape(t.shape[:2] + (n, cfg.head_dim))  # noqa: E731
+        heads = lambda t, n: t.reshape(t.shape[:-1] + (n, cfg.head_dim))  # noqa: E731
         return (heads(self.q_proj(x), cfg.num_attention_heads), heads(self.k_proj(x), cfg.num_key_value_heads),
                 heads(self.v_proj(x), cfg.num_key_value_heads))
 
     def out(self, a):
-        return self.o_proj(a.reshape(a.shape[:2] + (-1, )).astype(self.cfg.dtype))
+        return self.o_proj(a.reshape(a.shape[:-2] + (-1, )).astype(self.cfg.dtype))
 
 
 # -------------------------------------------------------------------- layers
@@ -374,8 +394,8 @@ class _WholePeriod(nn.Module):
 
 
 def scaled_logits(cfg, embed, x):
-    """``x E^T / logits_scaling`` in float32."""
-    logits = jnp.einsum("bch,vh->bcv", x, embed.embedding.astype(x.dtype), preferred_element_type=jnp.float32)
+    """``x E^T / logits_scaling`` in float32; ``x`` [..., hidden]."""
+    logits = jnp.einsum("...h,vh->...v", x, embed.embedding.astype(x.dtype), preferred_element_type=jnp.float32)
     return logits / cfg.logits_scaling
 
 

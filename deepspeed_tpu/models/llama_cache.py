@@ -43,7 +43,15 @@ flat axis, ``[T, hidden]``; what needs a row's sequence (the page writes, the
 paged attention) runs group by group at the group's own rectangle
 (``over_row_groups``).  A twin whose blocks are written so says
 ``takes_row_groups = True``, and the engine hands it a mixed step in two
-groups; any other gets rectangles only.
+groups; any other gets rectangles only.  The twins of this file, of
+``mixtral_cache.py``, ``evabyte_cache.py``, ``xing4_cache.py``,
+``phi4flash_cache.py`` and ``granite_hybrid_cache.py`` take groups; the other
+families of ``cache_zoo.py`` do not.  A twin that holds a state slot a
+sequence (the last two) also runs group by group what reads or writes the
+slot: the causal convolution with the slot's tail, the recurrence with the
+slot's state (in the form the group's width asks for: one position a row, or
+a chunk), a window layer's ring; its ``arena`` is the dict of its cache's
+arrays, threaded through the groups whole as a single arena is.
 """
 
 import dataclasses

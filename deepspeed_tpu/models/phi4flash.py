@@ -191,34 +191,60 @@ class MambaMixer(nn.Module):
     ``state`` [B, N, D] float32 and ``tail`` [B, d_conv - 1, D], the last
     inputs of the convolution, are what a sequence carries between calls
     (zeros at its start); ``y`` is the scan's output before the gate, the
-    memory of the middle layer."""
+    memory of the middle layer.  The call is five parts in a row, of which
+    ``in_project``, ``scan_inputs`` and ``gate_out`` are functions of a token
+    alone and ``convolve`` and ``scan`` need a row's sequence: the serving
+    twin runs the former on its flat token axis and the latter a row group at
+    a time."""
     cfg: Phi4FlashConfig
 
-    @nn.compact
-    def __call__(self, x, state, tail, chunk_lens):
+    def setup(self):
         cfg = self.cfg
         d, n, k = cfg.d_inner, cfg.d_state, cfg.d_conv
-        b, c, _ = x.shape
-        u, z = jnp.split(_dense(cfg, 2 * d, "in_proj")(x), 2, axis=-1)
-        conv_w = self.param("conv_kernel", nn.initializers.lecun_normal(), (k, d), cfg.param_dtype)
-        conv_b = self.param("conv_bias", nn.initializers.zeros_init(), (d, ), cfg.param_dtype)
-        a_log = self.param("A_log", lambda *_: jnp.log(jnp.broadcast_to(jnp.arange(1.0, n + 1), (d, n))
-                                                       ).astype(cfg.param_dtype))
-        d_skip = self.param("D", nn.initializers.ones_init(), (d, ), cfg.param_dtype)
+        self.in_proj = _dense(cfg, 2 * d, "in_proj")
+        self.conv_kernel = self.param("conv_kernel", nn.initializers.lecun_normal(), (k, d), cfg.param_dtype)
+        self.conv_bias = self.param("conv_bias", nn.initializers.zeros_init(), (d, ), cfg.param_dtype)
+        self.A_log = self.param("A_log", lambda *_: jnp.log(jnp.broadcast_to(jnp.arange(1.0, n + 1), (d, n))
+                                                            ).astype(cfg.param_dtype))
+        self.D = self.param("D", nn.initializers.ones_init(), (d, ), cfg.param_dtype)
+        self.x_proj = _dense(cfg, cfg.rank + 2 * n, "x_proj")
+        self.dt_proj = _dense(cfg, d, "dt_proj", use_bias=True)
+        self.out_proj = _dense(cfg, cfg.hidden_size, "out_proj")
 
+    def in_project(self, x):
+        """``x`` [..., hidden] -> (the convolution's input ``u``, the gate ``z``), each [..., D]."""
+        return jnp.split(self.in_proj(x), 2, axis=-1)
+
+    def convolve(self, u, tail, chunk_lens):
+        """``u`` [B, C, D], ``tail`` [B, d_conv - 1, D] -> (the convolved and activated ``u``, the new tail)."""
+        c, k = u.shape[1], self.cfg.d_conv
         seen = jnp.concatenate([tail.astype(u.dtype), u], axis=1)                        # [B, k-1+C, D]
-        conv = sum(seen[:, j:j + c].astype(jnp.float32) * conv_w[j].astype(jnp.float32) for j in range(k))
-        u = nn.silu(conv + conv_b.astype(jnp.float32)).astype(cfg.dtype)
+        conv = sum(seen[:, j:j + c].astype(jnp.float32) * self.conv_kernel[j].astype(jnp.float32) for j in range(k))
         # the inputs before the row's next position: rows n .. n + k - 2 of ``seen``
         tail = jnp.take_along_axis(seen, (chunk_lens[:, None] + jnp.arange(k - 1)[None, :])[:, :, None], axis=1)
+        return nn.silu(conv + self.conv_bias.astype(jnp.float32)).astype(self.cfg.dtype), tail
 
-        dt_r, b_mat, c_mat = jnp.split(_dense(cfg, cfg.rank + 2 * n, "x_proj")(u), [cfg.rank, cfg.rank + n], axis=-1)
-        dt = jax.nn.softplus(_dense(cfg, d, "dt_proj", use_bias=True)(dt_r).astype(jnp.float32))
-        valid = jnp.arange(c)[None, :] < chunk_lens[:, None]
-        y, state = ssm_scan(u.astype(jnp.float32), dt, -jnp.exp(a_log.astype(jnp.float32)), b_mat, c_mat, d_skip,
-                            state, valid)
-        out = _dense(cfg, cfg.hidden_size, "out_proj")((y * nn.silu(z.astype(jnp.float32))).astype(cfg.dtype))
-        return out, y, state, tail
+    def scan_inputs(self, u):
+        """The convolved ``u`` [..., D] -> (``dt`` [..., D] float32, ``B``, ``C`` [..., N])."""
+        cfg = self.cfg
+        dt_r, b_mat, c_mat = jnp.split(self.x_proj(u), [cfg.rank, cfg.rank + cfg.d_state], axis=-1)
+        return jax.nn.softplus(self.dt_proj(dt_r).astype(jnp.float32)), b_mat, c_mat
+
+    def scan(self, u, dt, b_mat, c_mat, state, chunk_lens):
+        """``ssm_scan`` of a rectangle [B, C, ...] from ``state``: (``y`` [B, C, D] float32, the new state)."""
+        valid = jnp.arange(u.shape[1])[None, :] < chunk_lens[:, None]
+        return ssm_scan(u.astype(jnp.float32), dt, -jnp.exp(self.A_log.astype(jnp.float32)), b_mat, c_mat, self.D,
+                        state, valid)
+
+    def gate_out(self, y, z):
+        """``y`` [..., D] float32 under the gate ``z`` -> [..., hidden]."""
+        return self.out_proj((y * nn.silu(z.astype(jnp.float32))).astype(self.cfg.dtype))
+
+    def __call__(self, x, state, tail, chunk_lens):
+        u, z = self.in_project(x)
+        u, tail = self.convolve(u, tail, chunk_lens)
+        y, state = self.scan(u, *self.scan_inputs(u), state, chunk_lens)
+        return self.gate_out(y, z), y, state, tail
 
     def fresh(self, batch):
         """(state, tail) of a sequence's start."""
@@ -281,34 +307,34 @@ class DiffAttention(nn.Module):
         return 1.0 / (self.cfg.head_dim**0.5)
 
     def queries(self, x):
-        """[B, C, H, 2d]: head ``2i`` is ``[q1_i | 0]``, head ``2i + 1`` is ``[0 | q2_i]``."""
+        """``x`` [..., hidden] -> [..., H, 2d]: head ``2i`` is ``[q1_i | 0]``, head ``2i + 1`` is ``[0 | q2_i]``."""
         cfg = self.cfg
         d = cfg.head_dim
-        q = self.q_proj(x).reshape(x.shape[:2] + (cfg.num_attention_heads // 2, 2, d))
+        q = self.q_proj(x).reshape(x.shape[:-1] + (cfg.num_attention_heads // 2, 2, d))
         zero = jnp.zeros_like(q[..., 0, :])
         q = jnp.stack([jnp.concatenate([q[..., 0, :], zero], -1), jnp.concatenate([zero, q[..., 1, :]], -1)], axis=-2)
-        return q.reshape(x.shape[:2] + (cfg.num_attention_heads, 2 * d))
+        return q.reshape(x.shape[:-1] + (cfg.num_attention_heads, 2 * d))
 
     def keys_values(self, x):
-        """Each [B, C, H_kv / 2, 2d]: pair ``j`` is ``[k1_j | k2_j]``, ``[v1_j | v2_j]``."""
+        """Each [..., H_kv / 2, 2d]: pair ``j`` is ``[k1_j | k2_j]``, ``[v1_j | v2_j]``."""
         cfg = self.cfg
-        shape = x.shape[:2] + (cfg.num_key_value_heads // 2, 2 * cfg.head_dim)
+        shape = x.shape[:-1] + (cfg.num_key_value_heads // 2, 2 * cfg.head_dim)
         return self.k_proj(x).reshape(shape), self.v_proj(x).reshape(shape)
 
     def combine(self, a, layer):
-        """``a`` [B, C, H, 2d], heads ``2i`` and ``2i + 1`` the two softmaxes'
-        outputs of pair ``i`` -> the layer's output [B, C, hidden]."""
+        """``a`` [..., H, 2d], heads ``2i`` and ``2i + 1`` the two softmaxes'
+        outputs of pair ``i`` -> the layer's output [..., hidden]."""
         cfg = self.cfg
         with jax.named_scope("ds_diff_combine"):
             f32 = lambda x: x.astype(jnp.float32)  # noqa: E731
             l0 = lambda_init(layer)
             lam = jnp.exp(jnp.sum(f32(self.lambda_q1) * f32(self.lambda_k1))) - \
                 jnp.exp(jnp.sum(f32(self.lambda_q2) * f32(self.lambda_k2))) + l0
-            a = f32(a).reshape(a.shape[:2] + (cfg.num_attention_heads // 2, 2, a.shape[-1]))
+            a = f32(a).reshape(a.shape[:-2] + (cfg.num_attention_heads // 2, 2, a.shape[-1]))
             diff = a[..., 0, :] - lam * a[..., 1, :]
             diff = diff * jax.lax.rsqrt(jnp.mean(jnp.square(diff), axis=-1, keepdims=True) + cfg.layer_norm_eps)
             out = (1.0 - l0) * diff * f32(self.sub_norm())
-            return self.o_proj(out.reshape(a.shape[:2] + (-1, )).astype(cfg.dtype))
+            return self.o_proj(out.reshape(a.shape[:-3] + (-1, )).astype(cfg.dtype))
 
 
 # -------------------------------------------------------------------- layers
@@ -387,8 +413,8 @@ def embed_tokens(cfg):
 
 
 def tied_logits(embed, x):
-    """``x E^T`` in float32."""
-    return jnp.einsum("bch,vh->bcv", x, embed.embedding.astype(x.dtype), preferred_element_type=jnp.float32)
+    """``x E^T`` in float32; ``x`` [..., hidden]."""
+    return jnp.einsum("...h,vh->...v", x, embed.embedding.astype(x.dtype), preferred_element_type=jnp.float32)
 
 
 class Phi4FlashForCausalLM(nn.Module):

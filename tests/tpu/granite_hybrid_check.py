@@ -27,6 +27,10 @@ for _p in (os.path.join(ROOT, "benchmark"), ROOT):
     if _p not in sys.path:
         sys.path.insert(0, _p)
 
+#: per array of the cache, the first index of its second axis past the null page or the scratch slot
+#: (what ``row_groups_check.readings`` compares from)
+REAL_FROM = {"pages": 1, "ssm": 1, "conv": 1}
+
 #: what is taken out of the reference's forward pass -> what marks, in a parameter's path, the leaves it zeroes
 KINDS = {
     "state": "['in_proj']",        # the columns of B and C alone (see ``without``): y = D x, no recurrence

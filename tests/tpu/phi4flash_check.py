@@ -41,6 +41,16 @@ KINDS = {
 }
 
 
+def real_from(config: dict) -> dict:
+    """Per array of the cache, the first index of its second axis that is
+    past the null page (a page of tokens is ``page_groups`` device pages) or
+    the scratch slot: what ``row_groups_check.readings`` compares from."""
+    import harness
+    from deepspeed_tpu.models.phi4flash_cache import page_groups
+    n = page_groups(harness.program_config(config))
+    return {"pages": n, "ring": n, "ssm": 1, "conv": 1}
+
+
 def _l0(layer):
     return 0.8 - 0.6 * np.exp(-0.3 * np.asarray(layer, np.float64))
 
@@ -121,7 +131,13 @@ def readings(config: dict, traffic: dict, seed: int, rows: list) -> dict:
       reference without that kind and the whole reference;
     * ``last_only``: the largest such distance between the head over each
       row's last real token alone and the all-position logits there, every
-      step, at the batch of this check;
+      step, at the batch of this check; ``last_only_rows`` holds it row by
+      row, and ``last_only_exact`` is the same reading with both programs
+      compiled under ``xla_allow_excess_precision=false``: the two heads are
+      two programs of one step, the compiler is free to leave out a rounding
+      to bfloat16 in one and not in the other, and a row whose trunk then
+      differs in the last bit of one element differs, layers later, in all
+      of them (PERF.md section 6, PR 38, "After the review");
     * ``bucket``: the largest distance from the reference of the same head
       with the rows spread over a batch of the scheduler's decode bucket, the
       shape of the engine's step programs, at every step that ends on a
@@ -175,12 +191,21 @@ def readings(config: dict, traffic: dict, seed: int, rows: list) -> dict:
 
     step = jax.jit(apply, static_argnums=6, donate_argnums=1)
     peek = jax.jit(apply, static_argnums=6)                                   # the cache stays as it was
+    exact_programs = {}
+
+    def exact(last, *args):
+        """``peek`` compiled with the compiler's excess precision off, a program a (width, ``last``)."""
+        key = (args[0].shape, last)
+        if key not in exact_programs:
+            exact_programs[key] = peek.lower(eng.params, eng.cache, *args, last).compile(
+                compiler_options={"xla_allow_excess_precision": False})
+        return exact_programs[key](eng.params, eng.cache, *args)[0]
 
     def worst(a, b):
         return float(jnp.max(plain.rel_l2(a, b)))
 
     pos, got = [0] * n, [[] for _ in rows]
-    out = {"last_only": 0.0, "steps": 0}
+    out = {"last_only_rows": [], "last_only_exact": 0.0, "steps": 0}
     wide_rows = []
     while any(pos[i] < len(toks[i]) for i in range(n)):
         lens = [min(chunk, p - pos[i]) if pos[i] < p else int(pos[i] < p + d) for i, (p, d, _, _) in enumerate(rows)]
@@ -193,9 +218,11 @@ def readings(config: dict, traffic: dict, seed: int, rows: list) -> dict:
             wide = peek(eng.params, eng.cache, *bucket(width, pos, lens), True)[0]
             wide_rows += [(i, pos[i] + lens[i] - 1, wide[bucket_rows[i], 0]) for i in ends]
             del wide
+        pick = lambda every: jnp.stack([every[i, lens[i] - 1] for i in live])      # noqa: E731
+        out["last_only_exact"] = max(out["last_only_exact"],
+                                     worst(exact(True, *args)[jnp.asarray(live), 0], pick(exact(False, *args))))
         logits, eng.cache = step(eng.params, eng.cache, *args, False)
-        want_last = jnp.stack([logits[i, lens[i] - 1] for i in live])
-        out["last_only"] = max(out["last_only"], worst(last, want_last))
+        out["last_only_rows"] += np.asarray(plain.rel_l2(last, pick(logits))).tolist()
         for i in live:
             skip = max(rows[i][3] - pos[i], 0)
             if skip < lens[i]:
@@ -204,6 +231,7 @@ def readings(config: dict, traffic: dict, seed: int, rows: list) -> dict:
         out["steps"] += 1
         del logits, last
     eng.cache = None
+    out["last_only"] = max(out["last_only_rows"])
 
     ref_rows = [(toks[i], p, first) for i, (p, _, _, first) in enumerate(rows)]
     ref = [logits for logits, _ in serve_open_loop.reference_logits(config, eng.params, ref_rows)]
@@ -228,6 +256,8 @@ def report(out: dict, rows: list) -> tuple:
         print(f"phi4flash_check: zeroed={kind} " + " ".join(
             f"slot{slot}:p10={np.percentile(e, 10):.6f},p50={np.median(e):.6f}" for (_, _, slot, _), e in zip(rows, per_row)),
               flush=True)
-    print(f"phi4flash_check: last_only={out['last_only']:.3e} bucket={out['bucket']:.3e} steps={out['steps']}", flush=True)
+    apart = [d for d in out["last_only_rows"] if d > 0]
+    print(f"phi4flash_check: last_only={out['last_only']:.3e} rows={len(out['last_only_rows'])} rows_apart={len(apart)} "
+          f"last_only_exact={out['last_only_exact']:.3e} bucket={out['bucket']:.3e} steps={out['steps']}", flush=True)
     return [(float(np.percentile(errs, 90)), {kind: float(np.percentile(per_row[i], 10)) for kind, per_row in out["zeroed"].items()})
             for i, errs in enumerate(out["program"])]

@@ -22,3 +22,10 @@ def test_check_in_real_slots_under_the_published_initialisation_at_the_rehearsal
     per_row = granite_hybrid_check.report(out, rows)
     assert out["steps"] == 7 + 8 and out["kernel_steps"] == 8
     assert all(program < 0.03 and all(change > 3 * program for change in zeroed.values()) for program, zeroed in per_row), per_row
+    # the same engine's two-group programs against the rectangle (tests/tpu/row_groups_check.py), two rows decoding
+    # beside one and two that prefill
+    import row_groups_check
+    out = row_groups_check.readings(config, traffic, 3000032601,
+                                    lambda abstract: granite_hybrid_check.check_init(abstract, 3000032601, "bfloat16"),
+                                    granite_hybrid_check.REAL_FROM, rungs=(1, 2), decode_rows=2)
+    assert row_groups_check.report("granite_hybrid_check", out) < 0.03, out

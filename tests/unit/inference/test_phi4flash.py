@@ -34,8 +34,9 @@ from deepspeed_tpu.inference.v2.scheduler import SchedulerConfig
 from deepspeed_tpu.inference.v2.spec import SpecConfig
 from deepspeed_tpu.models.cache_zoo import cache_geometry, cache_twin
 from deepspeed_tpu.models.llama_cache import PagedKVConfig
-from deepspeed_tpu.models.phi4flash import Phi4FlashConfig, Phi4FlashForCausalLM
-from deepspeed_tpu.models.phi4flash_cache import Phi4FlashForCausalLMWithCache, init_cache, page_heads, ring_pages
+from deepspeed_tpu.models.phi4flash import Phi4FlashConfig, Phi4FlashForCausalLM, Phi4FlashLayer
+from deepspeed_tpu.models.phi4flash_cache import (Phi4FlashForCausalLMWithCache, _apply_layer, _memory_mix, init_cache,
+                                                  layer_traced_once, page_heads, ring_pages)
 from deepspeed_tpu.telemetry.step_anatomy import StepAnatomy
 
 sys.path.insert(0, os.path.join(os.path.dirname(__file__), "..", "..", "..", "benchmark"))
@@ -381,6 +382,42 @@ def test_registry_names_the_twin_and_its_geometry():
     assert geometry.state_slots and geometry.window == WINDOW and cache_geometry(Phi4FlashConfig(), 16).window == 512
 
 
+class _TwoLayersOfOneConfiguration(nn.Module):
+    """Two gated memory units that differ by name and parameters alone."""
+    traced: bool
+
+    @nn.compact
+    def __call__(self, x, memory):
+        out = []
+        for name in ("first", "second"):
+            layer = Phi4FlashLayer(CFG, "gmu", name=name)
+            if self.traced:
+                out.append(layer_traced_once(layer, _memory_mix, (), x, memory)[0])
+            else:
+                out.append(layer(x, lambda mixer, h: _memory_mix(mixer, h, memory))[0])
+        return out
+
+
+def test_layers_of_one_configuration_share_a_trace_and_not_their_parameters():
+    """``layer_traced_once`` keys its jitted function on the layer without its
+    name, so two layers of one configuration are traced once between them;
+    their parameters are arguments of that function, and each gives what it
+    gives when called as it is."""
+    x, memory = (jax.random.normal(jax.random.PRNGKey(i), (5, width)) for i, width in ((1, CFG.hidden_size), (2, CFG.d_inner)))
+    variables = _TwoLayersOfOneConfiguration(True).init(jax.random.PRNGKey(0), x, memory)
+    assert set(variables["params"]) == {"first", "second"}                   # made under the layers' own names
+    before = _apply_layer._cache_size()
+    first, second = _TwoLayersOfOneConfiguration(True).apply(variables, x, memory)
+    assert _apply_layer._cache_size() == before + 1
+    want_first, want_second = _TwoLayersOfOneConfiguration(False).apply(variables, x, memory)
+    np.testing.assert_allclose(first, want_first, rtol=1e-6, atol=1e-6)
+    np.testing.assert_allclose(second, want_second, rtol=1e-6, atol=1e-6)
+    assert float(jnp.max(jnp.abs(first - second))) > 1e-2                    # and they are two layers
+    swapped = {"params": {"first": variables["params"]["second"], "second": variables["params"]["first"]}}
+    np.testing.assert_allclose(_TwoLayersOfOneConfiguration(True).apply(swapped, x, memory)[0], want_second, rtol=1e-6,
+                               atol=1e-6)
+
+
 # ------------------------------------------- (f) the chip's check of the twin, at the rehearsal size
 
 
@@ -401,6 +438,6 @@ def test_check_in_real_slots_under_weights_for_every_mixer_at_the_rehearsal_size
     rows = [(200, 8, 4, 136), (70, 8, 1, 0), (33, 8, 3, 0)]
     out = phi4flash_check.readings(config, traffic, 3000003601, rows)
     per_row = phi4flash_check.report(out, rows)
-    assert out["steps"] == 7 + 8 and out["last_only"] < 1e-5 and out["bucket"] < 0.03
+    assert out["steps"] == 7 + 8 and out["last_only"] < 1e-5 and out["last_only_exact"] < 1e-5 and out["bucket"] < 0.03
     assert all(program < 0.02 and all(zeroed[kind] > 3 * program for kind in ("mamba", "window", "full", "cross"))
                for program, zeroed in per_row)

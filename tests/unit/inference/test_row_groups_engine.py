@@ -4,10 +4,14 @@ prefill group of a rung of rows at the chunk.  Held here: the token streams
 (against a row-at-a-time reference that knows no batching), the closure of
 ``step_shape_set`` over every plan the scheduler can make under the benchmark
 cells' scheduler configurations, no compile after ``warm_all``, and what the
-step record of a two-group step holds.
+step record of a two-group step holds.  The families: the three softmax
+twins and the two that hold a state slot a sequence (Phi-4-mini-flash,
+Granite 4.0-H), whose row-at-a-time reference runs each prompt in a slot of
+its own.
 """
 
 import dataclasses
+import functools
 import json
 import os
 import sys
@@ -23,11 +27,14 @@ from deepspeed_tpu.inference.v2 import RaggedInferenceEngineConfig, build_engine
 from deepspeed_tpu.inference.v2.engine_v2 import build_cache_model
 from deepspeed_tpu.inference.v2.ragged import SequenceDescriptor
 from deepspeed_tpu.inference.v2.scheduler import SchedulerConfig
+from deepspeed_tpu.models.cache_zoo import cache_geometry, cache_twin
 from deepspeed_tpu.models.evabyte import EvaByteConfig
 from deepspeed_tpu.models.falcon import FalconConfig
+from deepspeed_tpu.models.granite_hybrid import GraniteHybridConfig
 from deepspeed_tpu.models.llama import LlamaConfig
-from deepspeed_tpu.models.llama_cache import PagedKVConfig, init_kv_cache
+from deepspeed_tpu.models.llama_cache import PagedKVConfig
 from deepspeed_tpu.models.mixtral import PRESETS as MIXTRAL_PRESETS
+from deepspeed_tpu.models.phi4flash import Phi4FlashConfig
 from deepspeed_tpu.serving import RequestState, ServingConfig, ServingEngine, VirtualClock
 from deepspeed_tpu.telemetry import StepAnatomy
 
@@ -45,6 +52,15 @@ CONFIGS = {
     "evabyte": EvaByteConfig(hidden_size=64, intermediate_size=128, num_hidden_layers=2, num_attention_heads=4,
                              num_key_value_heads=4, max_position_embeddings=2048, window_size=256,
                              chunk_size=PAGE, dtype=jnp.float32, param_dtype=jnp.float32),
+    # the slot-holding twins, at the fewest layers their patterns allow (tests/unit/inference/test_row_groups.py)
+    "phi4flash": Phi4FlashConfig(vocab_size=128, hidden_size=32, intermediate_size=64, num_hidden_layers=8,
+                                 num_attention_heads=4, num_key_value_heads=2, sliding_window=32,
+                                 max_position_embeddings=512, dtype=jnp.float32, param_dtype=jnp.float32),
+    "granitehybrid": GraniteHybridConfig(vocab_size=128, hidden_size=32, intermediate_size=64,
+                                         shared_intermediate_size=64, num_hidden_layers=2,
+                                         layer_types=("mamba", "attention"), num_attention_heads=4,
+                                         num_key_value_heads=2, mamba_n_heads=4, mamba_d_head=16, mamba_d_state=16,
+                                         max_position_embeddings=512, dtype=jnp.float32, param_dtype=jnp.float32),
 }
 #: a twin whose blocks take rectangles only
 FALCON = FalconConfig(vocab_size=128, hidden_size=64, num_hidden_layers=2, num_attention_heads=4, num_kv_heads=4,
@@ -52,22 +68,33 @@ FALCON = FalconConfig(vocab_size=128, hidden_size=64, num_hidden_layers=2, num_a
                       remat=False)
 KV = PagedKVConfig(num_pages=160, page_size=PAGE, max_pages_per_seq=24)
 SCHED = SchedulerConfig(token_budget=40, max_seqs=8, prefill_chunk=CHUNK, decode_bucket=4)
+#: the slot-holding twins' cells keep one batch bucket, all their rows: six programs to warm where SCHED has twelve
+ONE_BUCKET = dataclasses.replace(SCHED, decode_bucket=SCHED.max_seqs)
 NEW = 6
 
 
+def _cache(cfg):
+    """Pages, and for a twin that holds state slots the scratch slot and one more."""
+    return cache_twin(cfg).init_cache(cfg, KV, jnp.float32, 2, CHUNK)
+
+
+def _sched(cfg):
+    return ONE_BUCKET if cache_geometry(cfg, PAGE).state_slots else SCHED
+
+
+@functools.lru_cache(maxsize=None)   # a configuration's twin and weights, once a module
 def _params(cfg):
     set_global_mesh(create_mesh(MeshSpec(), devices=jax.devices()[:1]))
     twin = build_cache_model(cfg, PAGE)
-    cache = init_kv_cache(cfg, KV, jnp.float32)
     table = jnp.zeros((1, KV.max_pages_per_seq), jnp.int32)
     one = jnp.zeros((1, ), jnp.int32)
-    return twin, nn.meta.unbox(twin.init(jax.random.PRNGKey(0), jnp.zeros((1, 1), jnp.int32), one, table, cache,
-                                         jnp.ones((1, ), jnp.int32)))
+    return twin, nn.meta.unbox(jax.jit(twin.init)(jax.random.PRNGKey(0), jnp.zeros((1, 1), jnp.int32), one, table,
+                                                  _cache(cfg), jnp.ones((1, ), jnp.int32)))
 
 
-def _engine(cfg, params, k=1, sched=SCHED, kv=KV):
+def _engine(cfg, params, k=1, sched=None, kv=KV):
     return build_engine(cfg, params, RaggedInferenceEngineConfig(
-        kv=kv, scheduler=sched, kv_dtype=jnp.float32, decode_steps_per_dispatch=k, max_new_tokens=NEW,
+        kv=kv, scheduler=sched or _sched(cfg), kv_dtype=jnp.float32, decode_steps_per_dispatch=k, max_new_tokens=NEW,
         enable_prefix_cache=False))
 
 
@@ -81,12 +108,16 @@ def _prompts(cfg, seed=3):
 
 def _row_at_a_time(cfg, twin, params, prompts, new=NEW):
     """Greedy streams from the twin fed one sequence at a time as rectangles
-    of one row: the prompt in chunks, then one token a step."""
-    table = jnp.asarray(1 + np.arange(KV.max_pages_per_seq, dtype=np.int32)[None])
+    of one row: the prompt in chunks, then one token a step; where a sequence
+    holds a state slot, in slot 1 (the row's last column)."""
+    table = 1 + np.arange(KV.max_pages_per_seq, dtype=np.int32)[None]
+    if cache_geometry(cfg, PAGE).state_slots:
+        table[0, -1] = 1
+    table = jnp.asarray(table)
     step = jax.jit(lambda c, t, s, n: twin.apply(params, t, s, table, c, n, True))
     out = []
     for prompt in prompts:
-        cache, pos, toks = init_kv_cache(cfg, KV, jnp.float32), 0, list(prompt)
+        cache, pos, toks = _cache(cfg), 0, list(prompt)
         while len(toks) < len(prompt) + new:
             n = min(CHUNK, len(prompt) - pos) if pos < len(prompt) else 1
             ids = np.zeros((1, CHUNK if n > 1 or pos < len(prompt) else 1), np.int32)
@@ -149,19 +180,39 @@ def test_the_step_record_of_a_two_group_step(family):
     assert len(plan.decode) == 2 and [n for _, n in plan.prefill] == [CHUNK]
     eng.step(plan)
     row = eng.anatomy.last_step.to_row()
-    assert row["key"] == "step:b4:c1:b1:c16" and row["path"] == "mixed"
-    assert row["slots"] == 4 + 1 * CHUNK and row["tokens_real"] == plan.planned_tokens == 2 + CHUNK
+    bucket = _sched(cfg).decode_bucket
+    assert row["key"] == f"step:b{bucket}:c1:b1:c16" and row["path"] == "mixed"
+    assert row["slots"] == bucket + 1 * CHUNK and row["tokens_real"] == plan.planned_tokens == 2 + CHUNK
     assert (row["rows_decode"], row["rows_prefill"]) == (2, 1)
     # the key survives _named -> the lowered module's name -> benchmark/step_trace.program_key
-    module = eng._aot_lower(((4, 1), (1, CHUNK))).as_text()[:400]
-    assert "jit_ds_step_b4_c1_b1_c16" in module
-    assert step_trace.program_key("jit_ds_step_b4_c1_b1_c16(1234)") == row["key"]
+    module = eng._aot_lower(((bucket, 1), (1, CHUNK))).as_text()[:400]
+    assert f"jit_ds_step_b{bucket}_c1_b1_c16" in module
+    assert step_trace.program_key(f"jit_ds_step_b{bucket}_c1_b1_c16(1234)") == row["key"]
     for _ in range(7):                               # the prompt's other four chunks, then one-token steps
         eng.step()
     rows = [s.to_row() for s in eng.anatomy.steps]
-    mixed = sum(1 for r in rows if r["key"] != "step:b4:c1")
+    mixed = sum(1 for r in rows if r["key"] != f"step:b{bucket}:c1")
     assert step_trace.mixed_step_share(rows) == pytest.approx(mixed / len(rows)) and 3 <= mixed < len(rows)
-    assert step_trace.slot_fill_share([row]) == pytest.approx((2 + CHUNK) / (4 + CHUNK))
+    assert step_trace.slot_fill_share([row]) == pytest.approx((2 + CHUNK) / (bucket + CHUNK))
+
+
+@pytest.mark.parametrize("name", ["phi4flash", "granitehybrid"])
+def test_a_slot_holding_twin_takes_row_groups_and_its_cell_warms_seven_programs(name):
+    """Under the scheduler of its benchmark cell (one bucket of 32 rows,
+    chunks of 128, eight fused steps) the twin's engine reaches the decode
+    step, the decode bucket beside 1, 4 and 32 prefill rows, and three fused
+    rungs: no rectangle of 32 x 128."""
+    cell = {"phi4flash": "phi4-mini-flash-serve-1chip", "granitehybrid": "granite-4.0-h-micro-serve-1chip"}[name]
+    with open(os.path.join(BENCHMARK, "configs", cell + ".json")) as f:
+        engine = json.load(f)["engine"]
+    cfg = CONFIGS[name]
+    _, params = _params(cfg)
+    eng = _engine(cfg, params, k=engine["decode_steps_per_dispatch"], sched=SchedulerConfig(**engine["scheduler"]),
+                  kv=dataclasses.replace(KV, num_pages=32))
+    assert eng._row_groups and build_cache_model(cfg, PAGE).takes_row_groups
+    assert {eng._key_label(k) for k in eng.step_shape_set()} == {
+        "step:b32:c1", "step:b32:c1:b1:c128", "step:b32:c1:b4:c128", "step:b32:c1:b32:c128",
+        "multi:b32:k8", "multi:b32:k4", "multi:b32:k2"}
 
 
 def test_a_twin_that_does_not_take_row_groups_keeps_the_rectangle():

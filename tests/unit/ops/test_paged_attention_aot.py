@@ -186,6 +186,65 @@ def test_scanned_twin_holds_no_second_arena(described_chip, no_compile_cache, pr
     assert ("ds_gmm" in compiled.as_text()) == (program == "step_c128")
 
 
+def _slot_twin(family):
+    """A small twin of a slot-holding family at its published head sizes, so
+    that both take their kernels, whose states, rings and pages are 148 to 268
+    MB each beside a few MB of weights (Phi-4's state 32 times the published
+    one, its window four times: an arena of 100 MB the compiler moves into a
+    faster memory whole, as it does the convolution tails)."""
+    if family == "phi4flash":
+        from deepspeed_tpu.models.phi4flash import Phi4FlashConfig
+        return Phi4FlashConfig(vocab_size=512, hidden_size=512, intermediate_size=512, num_hidden_layers=8,
+                               num_attention_heads=8, num_key_value_heads=4, sliding_window=2048, d_state=512,
+                               max_position_embeddings=4096, dtype=jnp.bfloat16, param_dtype=jnp.bfloat16,
+                               attention_impl="flash")
+    from deepspeed_tpu.models.granite_hybrid import GraniteHybridConfig
+    return GraniteHybridConfig(vocab_size=512, hidden_size=512, intermediate_size=512, shared_intermediate_size=512,
+                               num_hidden_layers=4, layer_types=("mamba", "mamba", "attention", "mamba"),
+                               num_attention_heads=4, num_key_value_heads=2, mamba_n_heads=16, mamba_d_head=64,
+                               mamba_d_state=512, max_position_embeddings=4096, dtype=jnp.bfloat16,
+                               param_dtype=jnp.bfloat16, attention_impl="flash")
+
+
+@pytest.mark.parametrize("prefill_rows", [1, 4])
+@pytest.mark.parametrize("family", ["phi4flash", "granitehybrid"])
+def test_slot_twins_two_group_step_copies_no_arena(described_chip, no_compile_cache, family, prefill_rows):
+    """The mixed step of ``phi4flash_reason`` and ``granite4h_sessions``
+    (``step:b32:c1:b1:c128`` and ``b4``), donated and compiled for one chip:
+    every array of the cache is the result's buffer, and no ``copy`` has the
+    shape of the recurrent states, the rings or the pages, which are threaded
+    through two row groups and the layer scan and updated where they lie (at
+    the benchmark's size a copy of Granite's states is 2.49 GB, 6 ms).  The
+    convolution tails are left out: the compiler moves that arena into a
+    faster memory around the layer loop, 9 and 31 MB at the benchmark's size,
+    in the one-group program and the rectangle's too."""
+    import re
+
+    from deepspeed_tpu.comm.mesh import MeshSpec, create_mesh
+    from deepspeed_tpu.inference.v2 import RaggedInferenceEngineConfig
+    from deepspeed_tpu.inference.v2.engine_v2 import _init_cache, compile_aot_serving
+    from deepspeed_tpu.inference.v2.scheduler import SchedulerConfig
+    from deepspeed_tpu.models.llama_cache import PagedKVConfig
+    cfg = _slot_twin(family)
+    econf = RaggedInferenceEngineConfig(kv=PagedKVConfig(num_pages=16384, page_size=16, max_pages_per_seq=64),
+                                        scheduler=SchedulerConfig(token_budget=4096, max_seqs=32, prefill_chunk=128,
+                                                                  decode_bucket=32))
+    arenas = jax.eval_shape(lambda: _init_cache(cfg, econf))
+    mesh = create_mesh(MeshSpec(), devices=[described_chip])
+    compiled, _ = compile_aot_serving(cfg, mesh, econf, groups=((32, 1), (prefill_rows, 128)))
+    text, mem = compiled.as_text(), compiled.memory_analysis()
+    assert "tpu_custom_call" in text
+    held = sum(a.size * a.dtype.itemsize for a in arenas.values())
+    assert mem.alias_size_in_bytes >= held, "a donated array of the cache is not the result's buffer"
+    for name in set(arenas) - {"conv"}:
+        a = arenas[name]
+        shape = {"bfloat16": "bf16", "float32": "f32"}[a.dtype.name] + "[" + ",".join(map(str, a.shape)) + "]"
+        assert shape in text, (name, shape)                                  # the shape is spelled as the text spells it
+        copies = re.findall(r"= " + re.escape(shape) + r"(?:\{[^}]*\})? copy(?:-done)?\(", text)
+        assert not copies, (name, shape, len(copies))
+        assert a.size * a.dtype.itemsize > 140e6, (name, a.size * a.dtype.itemsize)   # too large to be moved whole
+
+
 #: the grouped product's operands in the benchmark's cells: (rows, contraction, columns, groups)
 GROUPED = {
     "mixtral_gate_and_up_in_a_stack_of_3_layers": (4096, 4096, 14336, 24),
