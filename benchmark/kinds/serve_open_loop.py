@@ -293,6 +293,11 @@ def in_system(records, t):
     return n
 
 
+def in_system_most(records):
+    """The most requests in the system at any request's due time."""
+    return max(in_system(records, r.due) for r in records)
+
+
 def summarise(records):
     """(attempted, failed, per-request samples in ms) of the measured requests."""
     from deepspeed_tpu.serving.request import RequestState
@@ -342,28 +347,55 @@ def attention_work(ctx, records, tracer, chunk):
     return {"flops": flops * layers, "bytes": nbytes * layers}
 
 
-def sweep(ctx, serve, clock):
+#: a request that waited this long to be admitted waited for a slot (an
+#: admission with a slot free takes a fraction of a millisecond)
+SLOT_WAIT_MS = 1000.0
+
+
+def sweep(ctx, serve, clock, devices):
     """Several rates in one process after one set-up: a line per rate with
     the requests in the system when the window opens and closes, finished
-    and failed.  Used once to find a mix's knee; never a measured run."""
+    and failed, the longest wait for admission and the cell's latencies.
+    Each window opens behind a lead-in taken from its rate: the stay of a
+    median request under the readings of the window before it (the file's
+    ``at_rate`` for the first), so that a rate under the knee does not grow
+    inside its window for want of a lead-in.  A rate at which a request
+    waited for a slot ends the sweep: the rates above it queue too.  Used to
+    find a mix's knee and to read a rate's spread; never a measured run."""
     traffic, seconds = ctx["traffic"], ctx["seconds"]
+    readings = traffic.get("at_rate")
     for n, rate in enumerate(ctx["sweep"]):
-        schedule = traffic_gen.serving_schedule(traffic, seconds, ctx["seed"] + n,
-                                                ctx["config"]["vocab_size"], rate_per_s=rate)
-        t_open = clock.now() + traffic["lead_in_s"]
+        lead_in_s = traffic_gen.lead_in_rule(traffic, readings) if readings else float(traffic["lead_in_s"])
+        schedule = traffic_gen.serving_schedule(traffic, seconds, ctx["seed"] + n, ctx["config"]["vocab_size"],
+                                                rate_per_s=rate, lead_in_s=lead_in_s)
+        t_open = clock.now() + lead_in_s
         records, ticks = drive(serve, clock, schedule, t_open, seconds, traffic["drain_cap_s"])
         attempted, failed, samples = summarise(records)
-        row = {"rate_per_s": rate, "attempted": attempted, "finished": attempted - failed, "failed": failed,
-               "in_system_at_open": in_system(records, t_open),
+        row = {"rate_per_s": rate, "lead_in_s": lead_in_s, "attempted": attempted, "finished": attempted - failed,
+               "failed": failed, "in_system_at_open": in_system(records, t_open),
                "in_system_at_close": in_system(records, t_open + seconds),
+               "in_system_most": in_system_most(records),
                "drained_s": round(clock.now() - t_open - seconds, 2)}
-        for name, q in (("ttft_ms", 50), ("ttft_ms", 90), ("tpot_ms", 50)):
-            if samples[name]:
-                row[f"{name[:-3]}_p{q}_ms"] = round(percentile(samples[name], q), 2)
+        if samples["ttft_ms"] and samples["tpot_ms"]:
+            readings = {"ttft_mean_ms": sum(samples["ttft_ms"]) / len(samples["ttft_ms"]),
+                        "tpot_p50_ms": percentile(samples["tpot_ms"], 50)}
+            row.update(ttft_mean_ms=round(readings["ttft_mean_ms"], 2),
+                       ttft_p50_ms=round(percentile(samples["ttft_ms"], 50), 2),
+                       ttft_p90_ms=round(percentile(samples["ttft_ms"], 90), 2),
+                       tpot_p50_ms=round(readings["tpot_p50_ms"], 3),
+                       queue_wait_max_ms=round(max(samples["queue_wait_ms"]), 2))
         say("sweep", **row)
         t_cap = clock.now() + traffic["drain_cap_s"]
         while clock.now() < t_cap and (serve.load_stats()["active"] or serve.load_stats()["queue_depth"]):
             serve.tick()
+        # a window opens on the arena a run's would: none of the windows' before it in the prefix cache
+        prefix_cache = serve.engine.kv.prefix_cache
+        if prefix_cache is not None:
+            prefix_cache.evict(prefix_cache.cached_pages)
+        if failed or row.get("queue_wait_max_ms", 0.0) > SLOT_WAIT_MS:
+            say("sweep_ends", at_rate_per_s=rate, why="a request failed or waited for a slot")
+            break
+    say("sweep_device", hbm_peak_bytes=max(harness.hbm_bytes(devices)))
     return None
 
 
@@ -378,7 +410,7 @@ def run(ctx):
     mono = time.monotonic() - clock.now()  # clock time + mono = time.monotonic()
     serve = ServingEngine(eng, clock=clock)
     if ctx["sweep"]:
-        return sweep(ctx, serve, clock)
+        return sweep(ctx, serve, clock, devices)
 
     schedule = traffic_gen.serving_schedule(traffic, seconds, ctx["seed"], ctx["config"]["vocab_size"])
     parts.mark("schedule")
@@ -394,7 +426,7 @@ def run(ctx):
     n_compiles = compiles.since(t_open + mono)
     say("window", attempted=attempted, failed=failed,
         in_system_at_open=in_system(records, t_open), in_system_at_close=in_system(records, t_open + seconds),
-        in_system_most=max(in_system(records, r.due) for r in records),
+        in_system_most=in_system_most(records),
         drained_s=round(clock.now() - t_open - seconds, 3),
         **{f"{name}_{k}": round(v, 2) for name in ("ttft_ms", "tpot_ms", "queue_wait_ms", "gen_late_ms")
            if samples[name] for k, v in (("mean", sum(samples[name]) / len(samples[name])),

@@ -14,8 +14,12 @@ The last line of standard output is the result, one JSON object.  A run that
 finds no TPU, too few chips, or a ``device_kind`` that ``peaks.py`` does not
 hold exits non-zero and prints no result.
 
-One builder's mode, never used by the driver: ``--sweep r1,r2,...`` (several
-offered rates after one set-up, to find a serving mix's knee).  The CPU
+Two builder's modes, never used by the driver: ``--sweep r1,r2,...`` (several
+offered rates after one set-up, to find a serving mix's knee or, with one
+rate several times, its spread) and ``--set path=json`` (a value of the
+cell's files replaced for this process, as ``config.engine.scheduler.max_seqs=64``
+or ``traffic.rate_per_s=1.2``: how a setting is read before a file takes
+it; the result line then names what was set under ``set``).  The CPU
 rehearsal and the readings behind the limits of ``correct`` are modes of
 ``selfcheck.py``, which calls ``open_cell`` and ``execute`` below.
 """
@@ -48,6 +52,23 @@ def merge(base: dict, over: dict) -> dict:
     return out
 
 
+def assign(files: dict, assignments: list) -> None:
+    """``--set``: each ``path=json`` replaces one value of ``files``
+    (``config`` or ``traffic`` first, then the keys down to it)."""
+    for item in assignments:
+        path, value = item.split("=", 1)
+        *parents, leaf = path.split(".")
+        node = files
+        for key in parents:
+            node = node[key]
+        node[leaf] = json.loads(value)
+
+
+def rates(text) -> list:
+    """``--sweep``'s comma-separated rates, or None."""
+    return [float(r) for r in text.split(",")] if text else None
+
+
 def reader(folder: str, name: str):
     """The ``read`` function of ``<folder>/<name>.py`` (a name may hold dots)."""
     path = os.path.join(HERE, folder, name + ".py")
@@ -62,7 +83,8 @@ def metrics_of(cell: dict, entries: list) -> list:
     return [m for m in entries if "workloads" not in m or cell["name"] in m["workloads"]]
 
 
-def open_cell(workload: str, seed: int, seconds: float, trace: bool, sweep=None, rehearse=False, chips=None):
+def open_cell(workload: str, seed: int, seconds: float, trace: bool, sweep=None, rehearse=False, chips=None,
+              assignments=()):
     """(BENCHMARK.json, the run's context, the device as JAX reports it).
     ``rehearse`` (selfcheck only) takes the files' ``rehearsal`` sizes on
     virtual CPU devices: control flow, never a device metric."""
@@ -75,13 +97,15 @@ def open_cell(workload: str, seed: int, seconds: float, trace: bool, sweep=None,
     traffic = load_json("traffic", cell["traffic"] + ".json")
     if rehearse:
         config, traffic = merge(config, config["rehearsal"]), merge(traffic, traffic["rehearsal"])
+    assign({"config": config, "traffic": traffic}, assignments)
 
     import harness
     parts = harness.SetupParts(T_START)
     device = harness.open_device(chips or cell["chips"], rehearse)
     parts.mark("start_and_imports")
     ctx = {"cell": cell, "config": config, "traffic": traffic, "chips": cell["chips"], "seed": seed,
-           "seconds": seconds, "trace": trace, "parts": parts, "sweep": sweep, "rehearse": rehearse}
+           "seconds": seconds, "trace": trace, "parts": parts, "sweep": sweep, "rehearse": rehearse,
+           "set": list(assignments)}
     return bench, ctx, device
 
 
@@ -113,6 +137,8 @@ def execute(bench: dict, ctx: dict, device: dict) -> None:
         device["busy_s"], device["window_s"] = reduced["busy_s"], reduced["window_s"]
         result["breakdown"] = {"device_ops": [list(kv) for kv in reduced["device_ops"][:10]],
                                "idle_gaps": [list(kv) for kv in reduced["idle_gaps"][:10]]}
+    if ctx["set"]:
+        result["set"] = ctx["set"]  # not the cell as its files have it
     if rehearse:
         # a CPU run shows control flow: the readers ran, their numbers are not device metrics
         result.update(rehearsal=True, metrics={}, metrics_read=sorted(metrics))
@@ -126,9 +152,11 @@ def main():
     ap.add_argument("--seconds", type=float, required=True)
     ap.add_argument("--trace", type=int, choices=(0, 1), default=0)
     ap.add_argument("--sweep", help="builder's mode: comma-separated offered rates")
+    ap.add_argument("--set", action="append", default=[], metavar="PATH=JSON",
+                    help="builder's mode: replace one value of the cell's files for this process")
     args = ap.parse_args()
-    sweep = [float(r) for r in args.sweep.split(",")] if args.sweep else None
-    execute(*open_cell(args.workload, args.seed, args.seconds, bool(args.trace), sweep))
+    execute(*open_cell(args.workload, args.seed, args.seconds, bool(args.trace), rates(args.sweep),
+                       assignments=args.set))
 
 
 if __name__ == "__main__":

@@ -6,6 +6,7 @@ nothing as a run.
     python3 benchmark/selfcheck.py            # checks (a)-(d), a minute or two
     python3 benchmark/selfcheck.py --aot      # offline compiles for v5e:2x2, minutes
     python3 benchmark/selfcheck.py --rehearse <cell> [--trace 1]   # one cell of (a)
+    python3 benchmark/selfcheck.py --rehearse <cell> --sweep 2,2,4 [--set PATH=JSON]   # run.py's builder's modes
     python3 benchmark/selfcheck.py --limits <cell> --seeds 12 --dump chiprun_out   # on the chip
 
  (a) every cell of BENCHMARK.json end to end at its files' ``rehearsal``
@@ -281,6 +282,8 @@ def main():
     ap.add_argument("--seed", type=int, default=0, help="with --rehearse and --limits: the (first) seed")
     ap.add_argument("--seeds", type=int, default=12, help="with --limits: how many seeds")
     ap.add_argument("--trace", type=int, choices=(0, 1), default=0, help="with --rehearse")
+    ap.add_argument("--sweep", help="with --rehearse: run.py's --sweep at the rehearsal sizes")
+    ap.add_argument("--set", action="append", default=[], metavar="PATH=JSON", help="with --rehearse: run.py's --set")
     ap.add_argument("--dump", metavar="DIR", help="with --limits: keep the per-position readings there")
     args = ap.parse_args()
     sys.path.insert(0, HERE)
@@ -288,7 +291,8 @@ def main():
         return aot()
     if args.rehearse:
         import run
-        return run.execute(*run.open_cell(args.rehearse, args.seed, 4.0, bool(args.trace), rehearse=True))
+        return run.execute(*run.open_cell(args.rehearse, args.seed, 4.0, bool(args.trace), run.rates(args.sweep),
+                                          rehearse=True, assignments=args.set))
     if args.limits:
         import importlib
 

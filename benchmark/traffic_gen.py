@@ -15,9 +15,11 @@ inside the block, sorted: a Poisson process given its count, so arrivals
 bunch and thin out inside a block as a chat API's do.  Due times, pairing
 and order come from the file's ``mix_seed``, not from ``--seed``: they are
 part of the mix.  A lead-in of ``lead_in_s`` seconds of the same process
-comes before the window, with negative due times.  An open loop: a request
-is offered when it is due, whatever the system has done with the ones
-before.
+comes before the window, with negative due times: the stay of a median
+request at the mix's rate (``median_stay_s`` of the file's ``at_rate``
+readings), so that the window opens on a system about as full as it will
+stay.  An open loop: a request is offered when it is due, whatever the
+system has done with the ones before.
 
 Why the schedule is the mix's and not the seed's (my chip runs, PR 23, six
 seeds at 51 s, spread = distance between quartiles over the median): PR 22
@@ -102,19 +104,35 @@ def _stretch(mix, traffic: dict, rate: float, t0: float, length: float) -> list:
 
 
 def serving_schedule(traffic: dict, seconds: float, seed: int, vocab: int,
-                     rate_per_s: float = None) -> list:
+                     rate_per_s: float = None, lead_in_s: float = None) -> list:
     """The requests of one run, sorted by due time: dicts with ``due``
     (seconds from the window's opening; negative in the lead-in), ``prompt``
-    (token ids), ``max_new_tokens`` and ``measured``."""
+    (token ids), ``max_new_tokens`` and ``measured``.  ``rate_per_s`` and
+    ``lead_in_s`` take the file's place in a sweep."""
     rate = traffic["rate_per_s"] if rate_per_s is None else rate_per_s
     rng = np.random.default_rng(int(seed))
     mix = np.random.default_rng(int(traffic["mix_seed"]))
-    lead = float(traffic["lead_in_s"])
+    lead = float(traffic["lead_in_s"] if lead_in_s is None else lead_in_s)
     rows = [(False, r) for r in _stretch(mix, traffic, rate, -lead, lead)] + \
         [(True, r) for r in _stretch(mix, traffic, rate, 0.0, float(seconds))]
     return [{"due": float(due), "measured": measured, "max_new_tokens": int(o_len),
              "prompt": rng.integers(1, vocab, int(p_len)).tolist()}
             for measured, (due, p_len, o_len) in rows]
+
+
+def median_stay_s(traffic: dict, ttft_mean_ms: float, tpot_p50_ms: float) -> float:
+    """Seconds a median request stays in the system where a first token
+    takes ``ttft_mean_ms`` and each later one ``tpot_p50_ms``: the rule behind
+    a mix's ``lead_in_s`` (whole seconds of it) and a sweep's lead-ins."""
+    return (ttft_mean_ms + (quantile(traffic["output"], 0.5) - 1) * tpot_p50_ms) / 1e3
+
+
+def lead_in_rule(traffic: dict, readings: dict = None) -> int:
+    """``lead_in_s`` by the rule: whole seconds of a median request's stay
+    under ``readings`` (``ttft_mean_ms``, ``tpot_p50_ms``); the file's
+    ``at_rate`` readings, taken at its own rate, where none are given."""
+    readings = readings or traffic["at_rate"]
+    return max(1, round(median_stay_s(traffic, readings["ttft_mean_ms"], readings["tpot_p50_ms"])))
 
 
 def longest_request(traffic: dict) -> tuple:
