@@ -40,7 +40,7 @@ from ...telemetry.step_anatomy import NULL_ANATOMY, StepAnatomy
 from ...utils.logging import logger
 from ...utils.nvtx import profiler_range
 from .geometry import LinearGeometry
-from .ragged import BlockedKVCache, RaggedBatch, StateManager
+from .ragged import BlockedAllocator, BlockedKVCache, RaggedBatch, SequenceImage, StateManager, image_digest
 from .scheduler import SchedulerConfig, SplitFuseScheduler, StepPlan
 from .spec import SpecConfig, SpecStats, make_drafter
 
@@ -104,22 +104,28 @@ class RaggedInferenceEngineConfig:
     spec: Optional[SpecConfig] = None
 
 
-def _make_step_fn(model, qparams, greedy: bool, temperature: float, groups):
+def _make_step_fn(model, qparams, greedy: bool, temperature: float, groups, image_rows: bool = False):
     """The unified SplitFuse step program: one chunked forward serving
     prefill, continuation and decode, then per-row last-token sampling.
     ``groups`` is the step's static list of row groups ``(rows, width)``; the
     tokens are their one flat axis, the other batch arrays one entry a row
     (models/llama_cache.py "Row groups").  Pure in (params, cache, batch
     arrays) so both the live engine and the AOT serving-budget path
-    (compile_aot_serving) jit the same function."""
+    (compile_aot_serving) jit the same function.  ``image_rows``: the program
+    of a twin with a vision tower whose step holds a prefill group takes two
+    arguments more, ``mm_index`` (one entry a slot: its row of ``mm_rows``, -1
+    the token's own embedding) and ``mm_rows`` (the engine's image rows)."""
     groups = tuple(groups)
 
-    def step(params, cache, tokens, start_pos, block_tables, chunk_lens, rng):
+    def step(params, cache, tokens, start_pos, block_tables, chunk_lens, rng, *image_args):
         if qparams is not None:
             params = {"params": qparams.dequantize(params["params"])}
         # logits of each row's LAST real token alone: the twin takes those rows
         # out before its final norm and head (models/llama_cache.sampled_rows)
-        if len(groups) == 1:  # the rectangle of every twin's contract
+        if image_rows:
+            logits, cache = model.apply(params, tokens, start_pos, block_tables, cache, chunk_lens, True, groups,
+                                        *image_args)
+        elif len(groups) == 1:  # the rectangle of every twin's contract
             logits, cache = model.apply(params, tokens.reshape(groups[0]), start_pos, block_tables, cache,
                                         chunk_lens, True)
         else:  # to a twin that takes them (``takes_row_groups``): flat, with their list
@@ -369,6 +375,24 @@ class InferenceEngineV2:
         self._step_fns: Dict[tuple, callable] = {}
         #: whether the twin's blocks take a step of more than one row group
         self._row_groups = bool(getattr(self.model, "takes_row_groups", False))
+        #: a twin with a vision tower: the buffer of image rows [units, rows a
+        #: unit, hidden] the sequences' images own units of from their encode
+        #: until prefill has passed them (unit 0 is scratch, where an encode's
+        #: padding lands), and the buckets of patches an encode program takes
+        self.mm_rows = self.mm_alloc = None
+        self._image_rows = bool(getattr(self.model, "takes_image_rows", False))
+        if self._image_rows:
+            sched = self.econfig.scheduler
+            merge = cfg.vision.merge
+            if not sched.vision_patch_buckets or sched.vision_patch_buckets[0] % merge:
+                raise ValueError("a model with a vision tower needs scheduler.vision_patch_buckets, whole merge blocks")
+            self.mm_unit = sched.vision_patch_buckets[0] // merge
+            if any(b % (self.mm_unit * merge) for b in sched.vision_patch_buckets):
+                raise ValueError(f"vision_patch_buckets {sched.vision_patch_buckets}: each must be whole units of "
+                                 f"the smallest's {self.mm_unit} rows")
+            n_units = 1 + max(sched.vision_rows, sched.vision_patch_buckets[-1] // merge) // self.mm_unit
+            self.mm_rows = jnp.zeros((n_units, self.mm_unit, cfg.hidden_size), cfg.dtype)
+            self.mm_alloc = BlockedAllocator(n_units, "image rows")
         # per-step anatomy (telemetry/step_anatomy.py): every engine records
         # its steps (a ring of the last 8,192, drawn into a running profile
         # too); set_anatomy(None) switches to the NULL recorder, one
@@ -463,10 +487,61 @@ class InferenceEngineV2:
 
     # ---------------------------------------------------------------- put
 
+    def _placeholder_runs(self, tokens: Sequence[int]):
+        """(first position, length) of each run of the placeholder id in ``tokens``."""
+        at = np.flatnonzero(np.asarray(tokens) == self.cfg.media_placeholder_token_id)
+        starts = np.flatnonzero(np.diff(at, prepend=-2) > 1)
+        return at[starts], np.diff(np.append(starts, at.size))
+
+    def check_images(self, tokens: Sequence[int], images, strict: bool = True) -> Optional[str]:
+        """Why ``images`` ([(pixels [h w, 3, p, p] or [h w, 3 p p], (h, w)),
+        ...]) cannot go with ``tokens``, or None: a grid is odd or empty, an
+        image is over the largest bucket, the pixels are not the grid's, or
+        the runs of the placeholder id are not one run of ``h w / merge`` an
+        image, in order.  ``strict`` off: runs behind the images' own are
+        let be (a resumed sequence's tokens hold what it generated, which may
+        carry the placeholder's id and is text)."""
+        if not self._image_rows:
+            return "no_vision_tower"
+        vc = self.cfg.vision
+        want = []
+        for pixels, (h, w) in images:
+            if h <= 0 or w <= 0 or h % vc.merge_kernel_size[0] or w % vc.merge_kernel_size[1]:
+                return "image_grid_odd"
+            if h * w > self.econfig.scheduler.vision_patch_buckets[-1]:
+                return "image_over_largest_bucket"
+            if np.shape(pixels)[0] != h * w or int(np.prod(np.shape(pixels)[1:])) != vc.patch_dim:
+                return "image_pixels_mismatch"
+            want.append(h * w // vc.merge)
+        lengths = self._placeholder_runs(tokens)[1].tolist()
+        if (lengths if strict else lengths[:len(want)]) != want:
+            return "image_placeholders_mismatch"
+        return None
+
+    def _sequence_images(self, tokens: Sequence[int], images, reencode: bool = False) -> List[SequenceImage]:
+        reason = self.check_images(tokens, images, strict=False)
+        if reason is not None:
+            raise ValueError(f"images rejected: {reason}")
+        vc = self.cfg.vision
+        buckets = self.econfig.scheduler.vision_patch_buckets
+        out = []
+        for (pixels, (h, w)), start in zip(images, self._placeholder_runs(tokens)[0]):
+            pixels = np.asarray(pixels).reshape(h * w, vc.patch_dim)
+            out.append(SequenceImage(pixels=pixels, grid=(int(h), int(w)), start=int(start), rows=h * w // vc.merge,
+                                     bucket=next(b for b in buckets if b >= h * w),
+                                     digest=image_digest(pixels, (h, w)), reencoded=reencode))
+        return out
+
     def put(self, batch_uids: Sequence[int], batch_tokens: Sequence[Sequence[int]],
-            max_new_tokens: Optional[int] = None) -> None:
-        """Admit new sequences (ref: engine_v2.py:124 put)."""
+            max_new_tokens: Optional[int] = None, images: Optional[Sequence] = None, reencode: bool = False) -> None:
+        """Admit new sequences (ref: engine_v2.py:124 put).  ``images``: per
+        sequence its images, ``[(pixels, (h, w)), ...]`` or None, for a model
+        with a vision tower (``check_images`` says what is refused);
+        ``reencode``: they were encoded before (a preempted request resumes),
+        which the encode records count."""
         max_pos = getattr(self.cfg, "max_position_embeddings", None)
+        images = list(images) if images is not None else [None] * len(batch_uids)
+        seq_images = [self._sequence_images(t, i, reencode) if i else None for t, i in zip(batch_tokens, images)]
         # validate ALL before admitting ANY — a partial put would leave
         # earlier sequences admitted when a later one raises
         for uid, tokens in zip(batch_uids, batch_tokens):
@@ -476,11 +551,108 @@ class InferenceEngineV2:
                 # would silently produce degraded logits (e.g. OPT's table)
                 raise ValueError(f"sequence {uid}: prompt+max_new_tokens = {need} exceeds the "
                                  f"model's max_position_embeddings = {max_pos}")
-        for uid, tokens in zip(batch_uids, batch_tokens):
-            self.state.get_or_create(uid, list(tokens))
+        for uid, tokens, imgs in zip(batch_uids, batch_tokens, seq_images):
+            self.state.get_or_create(uid, list(tokens), imgs)
             self._max_new[uid] = max_new_tokens or self.econfig.max_new_tokens
 
+    # ------------------------------------------------------- the vision tower
+
+    def _release_images(self, seq, passed_only: bool = False) -> None:
+        """Give back the units of ``seq``'s images (those prefill has passed, or all)."""
+        for img in seq.images:
+            if img.units and (not passed_only or img.end <= seq.seen_tokens):
+                self.mm_alloc.free(img.units)
+                img.units, img.passed, img.pixels = [], True, None
+
+    def _build_vit_jit(self, bucket: int):
+        """The tower's program on a bucket of ``bucket`` patches: tower,
+        merger and projector on one image, its rows written into the units
+        given (``units`` [bucket / merge / unit]; padding's are unit 0)."""
+        model, unit = self.model, self.mm_unit
+
+        def encode(params, mm_rows, patches, grid, units):
+            rows = model.apply(params, patches, grid, method="encode_images")
+            return mm_rows.at[units].set(rows.reshape(units.shape[0], unit, rows.shape[-1]).astype(mm_rows.dtype))
+
+        return jax.jit(_named(encode, self._key_label(("vit", bucket))), donate_argnums=(1, ))
+
+    def _compiled_vit(self, bucket: int):
+        key = ("vit", bucket)
+        if key not in self._step_fns:
+            logger.info(f"InferenceEngineV2: compiling the vision tower's program on {bucket} patches")
+            self._step_fns[key] = self._build_vit_jit(bucket)
+            self._note_compile(self._key_label(key))
+        return self._step_fns[key]
+
+    def _image_units(self, img: SequenceImage) -> int:
+        """Units of the row buffer an image's bucket fills."""
+        return img.bucket // self.cfg.vision.merge // self.mm_unit
+
+    def image_row_index(self, img: SequenceImage) -> np.ndarray:
+        """[rows] int32: the row of the (flattened) buffer each of the image's rows lies in, by its units."""
+        k = np.arange(img.rows)
+        return (np.asarray(img.units)[k // self.mm_unit] * self.mm_unit + k % self.mm_unit).astype(np.int32)
+
+    def dispatch_encode(self, img: SequenceImage) -> None:
+        """Enqueue the tower's program for one image that holds its units:
+        the patches padded to the bucket (the copy to the device is made
+        here), the units padded with the scratch unit 0."""
+        h, w = img.grid
+        patches = np.zeros((img.bucket, img.pixels.shape[1]), img.pixels.dtype)
+        patches[:h * w] = img.pixels
+        units = np.zeros((self._image_units(img), ), np.int32)
+        units[:len(img.units)] = img.units
+        self.mm_rows = self._compiled_vit(img.bucket)(self.params, self.mm_rows, jnp.asarray(patches),
+                                                      jnp.asarray(img.grid, jnp.int32), jnp.asarray(units))
+
+    def encode_images(self) -> List[dict]:
+        """``iter_encode_images`` run to its end: the records of its dispatches."""
+        return list(self.iter_encode_images())
+
+    def iter_encode_images(self):
+        """Dispatch the tower for images that wait, sequences in scheduling
+        order and a sequence's images in prompt order, until the scheduler's
+        ``vision_patches_per_tick`` padded patches are spent (0: no bound; the
+        first image always goes).  A sequence's images take their units
+        of the row buffer together or not at all, so two half-encoded
+        sequences never wait for each other; one that finds no room waits for
+        prefill to pass earlier images, and those behind it wait with it.
+        A generator: one record after each dispatch (uid, key, bucket,
+        patches_real, patches_padded, reencoded, done: the sequence's last
+        image), so that a caller with a clock can time them one by one."""
+        if not self._image_rows:
+            return
+        budget = self.econfig.scheduler.vision_patches_per_tick
+        waiting = [s for s in self.state.seqs.values() if s.images_pending and not s.done]
+        if self.scheduler.order_key is not None:
+            waiting.sort(key=self.scheduler.order_key)
+        spent = 0
+        for seq in waiting:
+            todo = [img for img in seq.images if not (img.encoded or img.passed)]
+            if not todo[0].units:
+                need = sum(self._image_units(img) for img in todo)
+                if need > self.mm_alloc.free_pages:
+                    break
+                index = np.full((len(seq.tokens), ), -1, np.int32) if seq.mm_index is None else seq.mm_index
+                for img in todo:
+                    img.units = self.mm_alloc.allocate(self._image_units(img))
+                    index[img.start:img.end] = self.image_row_index(img)
+                seq.mm_index = index
+            for img in todo:
+                if budget and spent and spent + img.bucket > budget:
+                    return
+                h, w = img.grid
+                self.dispatch_encode(img)
+                img.encoded, img.pixels = True, None
+                spent += img.bucket
+                key = self._key_label(("vit", img.bucket))
+                self.anatomy.note_encode(key, h * w, img.bucket, img.reencoded)
+                yield {"uid": seq.uid, "key": key, "bucket": img.bucket, "patches_real": h * w,
+                       "patches_padded": img.bucket, "reencoded": img.reencoded, "done": img is todo[-1]}
+
     def flush(self, uid: int) -> None:
+        if self._image_rows and uid in self.state.seqs:
+            self._release_images(self.state.seqs[uid])
         self.state.flush(uid)
         self._max_new.pop(uid, None)
         self._spec_on.pop(uid, None)
@@ -494,6 +666,8 @@ class InferenceEngineV2:
         self._max_new.pop(uid, None)
         self._spec_on.pop(uid, None)
         self.last_spec_round.pop(uid, None)
+        if self._image_rows:
+            self._release_images(self.state.seqs[uid])   # a resumed sequence encodes again
         return self.state.preempt(uid)
 
     def set_spec(self, uid: int, enabled: bool) -> None:
@@ -544,9 +718,14 @@ class InferenceEngineV2:
         path, so the two can never trace different computations for the
         same key."""
         step = _make_step_fn(self.model, self._qparams, self.econfig.greedy,
-                             self.econfig.temperature, groups)
+                             self.econfig.temperature, groups, self._takes_image_rows(groups))
         return jax.jit(_named(step, self._key_label(groups)),
                        donate_argnums=(1, ), **self._jit_kwargs())
+
+    def _takes_image_rows(self, groups: tuple) -> bool:
+        """Whether the step program of ``groups`` takes ``mm_index`` and
+        ``mm_rows``: a twin with a tower, a group wider than one token."""
+        return self._image_rows and any(width > 1 for _, width in groups)
 
     def _build_multi_jit(self, batch: int, k: int):
         """The fused k-round decode program (shapes close over batch/k)."""
@@ -612,6 +791,8 @@ class InferenceEngineV2:
             return f"multi:b{key[1]}:k{key[2]}"
         if key[0] == "verify":
             return f"verify:b{key[1]}:w{key[2]}"
+        if key[0] == "vit":  # the vision tower on a bucket of patches
+            return f"vit:p{key[1]}"
         # a step's row groups: ((16, 128), ) -> step:b16:c128, a mixed step's
         # ((16, 1), (1, 128)) -> step:b16:c1:b1:c128 (no "_": _named turns ":" into it)
         return "step:" + ":".join(f"b{rows}:c{width}" for rows, width in key)
@@ -651,6 +832,8 @@ class InferenceEngineV2:
         if self.drafter is not None:
             width = self.econfig.spec.max_draft + 1
             keys += [("verify", b, width) for b in batches]
+        if self._image_rows:
+            keys += [("vit", p) for p in sched.vision_patch_buckets]
         return keys
 
     def _aot_lower(self, key):
@@ -679,10 +862,19 @@ class InferenceEngineV2:
             _, b, w = key
             jitted = self._build_verify_jit(b, w)
             args = (params_abs, cache_abs) + batch_args(b, w)
+        elif key[0] == "vit":
+            _, p = key
+            jitted = self._build_vit_jit(p)
+            args = (params_abs, sds(self.mm_rows.shape, self.mm_rows.dtype),
+                    sds((p, self.cfg.vision.patch_dim), self.cfg.dtype), sds((2, ), jnp.int32),
+                    sds((p // self.cfg.vision.merge // self.mm_unit, ), jnp.int32))
         else:
             jitted = self._build_step_jit(key)
-            args = (params_abs, cache_abs, sds((sum(rows * width for rows, width in key), ), jnp.int32)) + \
+            slots = sum(rows * width for rows, width in key)
+            args = (params_abs, cache_abs, sds((slots, ), jnp.int32)) + \
                 batch_args(sum(rows for rows, _ in key), 1)[1:] + (rng_abs, )
+            if self._takes_image_rows(key):
+                args += (sds((slots, ), jnp.int32), sds(self.mm_rows.shape, self.mm_rows.dtype))
         if self.mesh is None:
             return jitted.lower(*args)
         from ...comm.mesh import trace_mesh
@@ -1054,12 +1246,18 @@ class InferenceEngineV2:
         decode = [(s, 1) for s in plan.decode]
         work = decode + list(plan.prefill)
         chunk = self.econfig.scheduler.prefill_chunk
-        if all(n == 1 for _, n in work):  # a prompt's last token is a row of one token too
+        # a prompt's last token is a row of one token too, unless an image's row takes its place
+        if all(n == 1 for _, n in work) and not any(self._image_slot(seq, n) for seq, n in plan.prefill):
             return [(work, self._bucket_batch(len(work)), 1)]
         if not self._row_groups:
             return [(work, self._bucket_batch(len(work)), chunk)]
         rows = next(p for p in self._prefill_rungs() if p >= len(plan.prefill))
         return [(decode, self._bucket_batch(max(len(decode), 1)), 1), (list(plan.prefill), rows, chunk)]
+
+    @staticmethod
+    def _image_slot(seq, n: int) -> bool:
+        """Whether one of the next ``n`` tokens of ``seq`` takes an image's row."""
+        return seq.mm_index is not None and bool((seq.mm_index[seq.seen_tokens:seq.seen_tokens + n] >= 0).any())
 
     def step(self, plan: Optional[StepPlan] = None) -> Dict[int, List[int]]:
         """Run one scheduled step; returns {uid: [new tokens]} for
@@ -1099,6 +1297,8 @@ class InferenceEngineV2:
         inflight = None
         try:
             if plan is None:
+                if self._image_rows:   # who plans here encodes here (a serving frontend does both itself)
+                    self.encode_images()
                 plan = self.scheduler.plan(self.state)
                 if anat.enabled:
                     anat.mark("schedule")
@@ -1169,7 +1369,8 @@ class InferenceEngineV2:
             return None
         packed = self._step_groups(plan)
         groups = tuple((rows, width) for _, rows, width in packed)
-        rb: RaggedBatch = self.state.pack_groups(packed)
+        image_rows = self._takes_image_rows(groups)
+        rb: RaggedBatch = self.state.pack_groups(packed, mm=image_rows)
 
         self.rng, sub = jax.random.split(self.rng)
         fn = self._compiled_step(groups)
@@ -1180,13 +1381,17 @@ class InferenceEngineV2:
             anat.note_program(self._key_label(groups), path,
                               rows_decode=len(plan.decode), rows_prefill=len(plan.prefill),
                               tokens_real=tokens_real, slots=rb.tokens.size)
+        image_args = (jnp.asarray(rb.mm_index), self.mm_rows) if image_rows else ()
         next_tok, self.cache = self._invoke(fn, self.params, self.cache, jnp.asarray(rb.tokens),
                                             jnp.asarray(rb.start_pos), jnp.asarray(rb.block_tables),
-                                            jnp.asarray(rb.chunk_lens), sub)
+                                            jnp.asarray(rb.chunk_lens), sub, *image_args)
         if anat.enabled:
             # the passes over the rows run with the program already enqueued
+            state_counts = self._state_counts(work)
+            if image_rows:
+                state_counts["mm_tokens"] = int((rb.mm_index >= 0).sum())
             anat.note_counts(**self._expert_rows(tokens_real, rb.tokens.size),
-                             cache_counts=self._cache_counts(work), state_counts=self._state_counts(work))
+                             cache_counts=self._cache_counts(work), state_counts=state_counts)
             anat.mark("compile_wait" if self._fresh_compile else "dispatch")
         inf = InFlightStep("single")
         inf.tokens = next_tok
@@ -1206,6 +1411,8 @@ class InferenceEngineV2:
                 continue  # flushed while in flight (pipelined tick)
             seq.seen_tokens += n
             self.state.note_progress(seq)
+            if seq.images:
+                self._release_images(seq, passed_only=True)
             if seq.in_prefill:
                 continue  # mid-prompt chunk: logits not used
             tok = int(next_tok[i])

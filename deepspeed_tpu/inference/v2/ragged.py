@@ -72,7 +72,18 @@ class BlockedAllocator:
 PREFIX_CHAIN_SEED = 0x9E3779B9
 
 
-def iter_prefix_chain_hashes(tokens: Sequence[int], page_size: int):
+def page_key(tokens: Sequence[int], page: int, page_size: int, page_digests=None) -> tuple:
+    """What a full page is hashed and verified by: its token ids and, where
+    image rows lie in it, the digest of those images (``page_digests``: page
+    index -> digest).  Every image is the same run of one placeholder id, so
+    the ids alone would give two requests with different images and the same
+    text the same pages."""
+    toks = tuple(tokens[page * page_size:(page + 1) * page_size])
+    digest = page_digests.get(page) if page_digests else None
+    return toks if digest is None else toks + (("image", digest), )
+
+
+def iter_prefix_chain_hashes(tokens: Sequence[int], page_size: int, page_digests=None):
     """Lazily yield the chain hash of each FULL page of ``tokens``:
     ``h_k = hash(h_{k-1}, tokens[k*P:(k+1)*P])`` from
     :data:`PREFIX_CHAIN_SEED`, so a match on ``h_k`` transitively pins
@@ -80,16 +91,57 @@ def iter_prefix_chain_hashes(tokens: Sequence[int], page_size: int):
     pages by and the fleet prefix directory routes on — one rule, two
     consumers, no way to drift.  A generator so hot-path walkers that
     stop at the first miss stop HASHING there too.  Deterministic across
-    processes for integer tokens (int/tuple hashing is not salted)."""
+    processes for integer tokens (int/tuple hashing is not salted).
+    ``page_digests`` (page index -> digest of the images whose rows lie in
+    that page; None for text, whose hashes are what they were) goes into the
+    page's link of the chain (:func:`page_key`)."""
     h = PREFIX_CHAIN_SEED
     for i in range(len(tokens) // page_size):
-        h = hash((h, tuple(tokens[i * page_size:(i + 1) * page_size])))
+        h = hash((h, page_key(tokens, i, page_size, page_digests)))
         yield h
 
 
 def prefix_chain_hashes(tokens: Sequence[int], page_size: int) -> List[int]:
     """Materialized form of :func:`iter_prefix_chain_hashes`."""
     return list(iter_prefix_chain_hashes(tokens, page_size))
+
+
+@dataclasses.dataclass
+class SequenceImage:
+    """One image of a sequence: its patches on the host until the tower has
+    encoded them, and the units of the engine's image-row buffer that hold
+    its rows from then until prefill has passed them."""
+    pixels: np.ndarray                     # [h * w, 3 p p] patches, row-major
+    grid: Tuple[int, int]                  # (h, w) patches
+    start: int                             # position of its first placeholder in the sequence
+    rows: int                              # placeholders it fills: h w / merge
+    bucket: int                            # patches its encode program takes
+    digest: int                            # of its pixels and grid
+    units: List[int] = dataclasses.field(default_factory=list)
+    encoded: bool = False                  # its encode is dispatched (the device runs programs in order)
+    passed: bool = False                   # prefill is behind its last row: nothing of it is needed again
+    reencoded: bool = False                # a preempted request's, through the tower a second time
+
+    @property
+    def end(self) -> int:
+        return self.start + self.rows
+
+
+def image_digest(pixels: np.ndarray, grid) -> int:
+    """64 bits of an image's pixels and grid."""
+    import hashlib
+    h = hashlib.blake2b(np.ascontiguousarray(pixels).view(np.uint8).data, digest_size=8)
+    h.update(np.asarray(grid, np.int64).tobytes())
+    return int.from_bytes(h.digest(), "little")
+
+
+def image_page_digests(images: Sequence["SequenceImage"], page_size: int) -> Dict[int, int]:
+    """Page index -> digest of the images whose rows lie in that page."""
+    pages: Dict[int, list] = {}
+    for img in images:
+        for page in range(img.start // page_size, (img.end - 1) // page_size + 1):
+            pages.setdefault(page, []).append(img.digest)
+    return {page: hash(tuple(digests)) for page, digests in pages.items()}
 
 
 @dataclasses.dataclass
@@ -113,6 +165,18 @@ class SequenceDescriptor:
     # token instead of rehashing the whole history every step)
     pc_pages: int = 0
     pc_hash: int = 0
+    # a sequence with images (a model with a vision tower): the images, the
+    # digest of those in each page (what the prefix hash takes in) and, once
+    # their rows have units of the engine's buffer, the row each position of
+    # the prompt takes in an embedding's place (-1: the token's own)
+    images: List[SequenceImage] = dataclasses.field(default_factory=list)
+    page_digests: Optional[Dict[int, int]] = None
+    mm_index: Optional[np.ndarray] = None
+
+    @property
+    def images_pending(self) -> bool:
+        """An image still waits for the tower: no step may carry the sequence."""
+        return any(not (img.encoded or img.passed) for img in self.images)
 
     @property
     def remaining_prefill(self) -> int:
@@ -179,19 +243,19 @@ class PrefixCacheManager:
         self.hits = 0
         self.misses = 0
 
-    def _chain(self, tokens: Sequence[int]):
+    def _chain(self, tokens: Sequence[int], page_digests=None):
         """Yield (chain_hash, page_index) for each FULL page of ``tokens``
         (delegates to :func:`iter_prefix_chain_hashes` — the one digest
         rule the fleet prefix directory shares; lazy, so a walker that
         stops at the first miss stops hashing there too)."""
-        for i, h in enumerate(iter_prefix_chain_hashes(tokens, self.page_size)):
+        for i, h in enumerate(iter_prefix_chain_hashes(tokens, self.page_size, page_digests)):
             yield h, i
 
     def _notify(self, event: str, h: int) -> None:
         if self.listener is not None:
             self.listener(event, h)
 
-    def _walk(self, tokens: Sequence[int]):
+    def _walk(self, tokens: Sequence[int], page_digests=None):
         """Yield ``(chain_hash, page_id)`` for the longest run of cached
         full pages covering a prefix of ``tokens`` — the ONE matching rule
         (chain walk, token verification, last-token cap) shared by the
@@ -201,22 +265,24 @@ class PrefixCacheManager:
         still compute at least one prompt token (its logits seed
         generation)."""
         usable = len(tokens) - 1
-        for h, i in self._chain(tokens):
+        for h, i in self._chain(tokens, page_digests):
             if (i + 1) * self.page_size > usable:
                 return
             entry = self._pages.get(h)
-            if entry is None or entry[1] != tuple(tokens[i * self.page_size:(i + 1) * self.page_size]):
+            if entry is None or entry[1] != page_key(tokens, i, self.page_size, page_digests):
                 return
             yield h, entry[0]
 
-    def match(self, tokens: Sequence[int]) -> Tuple[List[int], int]:
+    def match(self, tokens: Sequence[int], page_digests=None) -> Tuple[List[int], int]:
         """Longest run of cached pages covering a prefix of ``tokens``,
         plus the chain hash at the match boundary (the caller seeds the
         sequence's register() cursor with it).  Returned pages are retained
-        on behalf of the caller."""
+        on behalf of the caller.  ``page_digests``: the sequence's images by
+        page (``image_page_digests``), so that a page of image rows matches
+        the same image's alone."""
         matched: List[int] = []
         h_end = self._SEED
-        for h, page in self._walk(tokens):
+        for h, page in self._walk(tokens, page_digests):
             matched.append(page)
             h_end = h
             self._lru.move_to_end(h)  # whole chain refreshed root→leaf
@@ -247,7 +313,7 @@ class PrefixCacheManager:
         h = seq.pc_hash if seq.pc_pages else self._SEED
         for i in range(seq.pc_pages, full):
             parent = h if i else None
-            page_toks = tuple(seq.tokens[i * self.page_size:(i + 1) * self.page_size])
+            page_toks = page_key(seq.tokens, i, self.page_size, seq.page_digests)
             h = hash((h, page_toks))
             if h not in self._pages:
                 self._pages[h] = (seq.pages[i], page_toks, parent)
@@ -362,6 +428,8 @@ class PrefixCacheManager:
             if entry is None:
                 return None
             _pid, toks, parent = entry
+            if toks and not isinstance(toks[-1], int):
+                return None   # a page of image rows: its tokens alone do not rebuild it
             parts.append(toks)
             h = parent
         return [t for part in reversed(parts) for t in part]
@@ -549,6 +617,7 @@ class RaggedBatch:
     block_tables: np.ndarray  # [B, max_pages] int32 (null page 0 padded)
     chunk_lens: np.ndarray    # [B] int32 — real tokens this step (0 = padding row)
     uids: List[int]           # row → uid (len B; padding rows map to -1)
+    mm_index: Optional[np.ndarray] = None   # [T] int32: a slot's row of the image-row buffer (-1: none)
 
     @property
     def batch(self) -> int:
@@ -563,9 +632,13 @@ class StateManager:
         self.max_batch = max_batch
         self.seqs: Dict[int, SequenceDescriptor] = {}
 
-    def get_or_create(self, uid: int, tokens: Optional[Sequence[int]] = None) -> SequenceDescriptor:
+    def get_or_create(self, uid: int, tokens: Optional[Sequence[int]] = None,
+                      images: Optional[List[SequenceImage]] = None) -> SequenceDescriptor:
         if uid not in self.seqs:
             seq = SequenceDescriptor(uid=uid, tokens=list(tokens or []))
+            if images:
+                seq.images = list(images)
+                seq.page_digests = image_page_digests(images, self.kv.page_size)
             if self.kv.slot_allocator is not None:
                 # with the sequence, released with it (flush, preempt); the
                 # admission controller counts free slots, so none is a caller's bug
@@ -574,9 +647,11 @@ class StateManager:
             if pc is not None and seq.tokens:
                 # reuse cached KV pages for the shared prompt prefix: the
                 # matched run is attached read-only and prefill starts after it
-                seq.pages, seq.pc_hash = pc.match(seq.tokens)
+                seq.pages, seq.pc_hash = pc.match(seq.tokens, seq.page_digests)
                 seq.pc_pages = len(seq.pages)
                 seq.seen_tokens = len(seq.pages) * self.kv.page_size
+                for img in seq.images:   # rows the matched pages hold need no tower
+                    img.passed = img.end <= seq.seen_tokens
             self.seqs[uid] = seq
         elif tokens:
             self.seqs[uid].tokens.extend(tokens)
@@ -642,15 +717,18 @@ class StateManager:
         rb.tokens = rb.tokens.reshape(b, chunk)
         return rb
 
-    def pack_groups(self, groups: List[Tuple[List[Tuple[SequenceDescriptor, int]], int, int]]) -> RaggedBatch:
+    def pack_groups(self, groups: List[Tuple[List[Tuple[SequenceDescriptor, int]], int, int]],
+                    mm: bool = False) -> RaggedBatch:
         """Pack a step's row groups, each (work, rows, width): the tokens on
         one flat axis of ``sum(rows x width)`` slots, group after group and a
         row's ``width`` slots together; ``start_pos``, the block tables,
         ``chunk_lens`` and ``uids`` one entry a row, the groups' rows
         concatenated.  A group's work fills its first rows; the rest are
-        padding rows as in ``pack``."""
+        padding rows as in ``pack``.  ``mm``: also ``mm_index``, the slots'
+        rows of the engine's image-row buffer (``SequenceDescriptor.mm_index``)."""
         n_rows = sum(rows for _, rows, _ in groups)
         tokens = np.zeros((sum(rows * width for _, rows, width in groups), ), np.int32)
+        mm_index = np.full(tokens.shape, -1, np.int32) if mm else None
         start_pos = np.zeros((n_rows, ), np.int32)
         block_tables = np.zeros((n_rows, self.kv.table_width), np.int32)
         chunk_lens = np.zeros((n_rows, ), np.int32)
@@ -663,6 +741,9 @@ class StateManager:
                 sl = seq.tokens[seq.seen_tokens:seq.seen_tokens + n]
                 at = t0 + (i - r0) * width
                 tokens[at:at + len(sl)] = sl
+                if mm and seq.mm_index is not None:
+                    rows_of = seq.mm_index[seq.seen_tokens:seq.seen_tokens + n]
+                    mm_index[at:at + len(rows_of)] = rows_of
                 start_pos[i] = seq.seen_tokens
                 block_tables[i, self.kv.geometry.slots(len(seq.pages))] = seq.pages
                 if seq.slot:
@@ -671,4 +752,4 @@ class StateManager:
                 uids[i] = seq.uid
             r0, t0 = r0 + rows, t0 + rows * width
         return RaggedBatch(tokens=tokens, start_pos=start_pos, block_tables=block_tables,
-                           chunk_lens=chunk_lens, uids=uids)
+                           chunk_lens=chunk_lens, uids=uids, mm_index=mm_index)

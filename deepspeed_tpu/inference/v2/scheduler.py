@@ -34,6 +34,17 @@ class SchedulerConfig:
     # shrinks the round until its total fed tokens (1 + draft per row) fit
     # token_budget.
     spec_verify_tokens: int = 0
+    # a model with a vision tower (models/kimi_vl.py): the buckets of patches
+    # an image is padded to (one encode program each), the padded patches the
+    # encode dispatches of one serving tick may carry (0: no bound; a tick's
+    # decode rows wait behind them), and the rows of image embeddings the
+    # engine's buffer holds for the sequences whose prefill has not passed them
+    vision_patch_buckets: Tuple[int, ...] = ()
+    vision_patches_per_tick: int = 0
+    vision_rows: int = 0
+
+    def __post_init__(self):
+        object.__setattr__(self, "vision_patch_buckets", tuple(sorted(int(b) for b in self.vision_patch_buckets)))
 
 
 @dataclasses.dataclass
@@ -66,7 +77,8 @@ class SplitFuseScheduler:
         # paused sequences (mid-KV-migration — serving/kvtransfer) keep
         # their state and pages but take no step work: their pages must stay
         # byte-stable while export chunks overlap the other sequences' steps
-        running = [s for s in manager.seqs.values() if not s.done and not s.paused]
+        # nor does a sequence whose images the tower has yet to encode
+        running = [s for s in manager.seqs.values() if not s.done and not s.paused and not s.images_pending]
         if self.order_key is not None:
             running.sort(key=self.order_key)
         decodes = [s for s in running if s.in_decode]

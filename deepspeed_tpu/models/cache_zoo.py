@@ -28,6 +28,8 @@ from .falcon import FalconConfig
 from .granite_hybrid import GraniteHybridConfig
 from .granite_hybrid_cache import GraniteHybridForCausalLMWithCache, slot_state_bytes
 from .granite_hybrid_cache import init_cache as init_granite_hybrid_cache
+from .kimi_vl import KimiVLConfig
+from .kimi_vl_cache import KimiVLForCausalLMWithCache
 from .mixtral import MixtralConfig
 from .mixtral_cache import MixtralForCausalLMWithCache
 from .opt import OPTConfig
@@ -428,11 +430,16 @@ CACHE_MODEL_REGISTRY = {
                                    init_granite_hybrid_cache, lambda cache: cache["pages"]),
     Xing4Config: CacheTwin(Xing4ForCausalLMWithCache, lambda cfg, page_size: LatentPagesGeometry(page_size),
                            init_xing4_cache, walk_rows=xing4_walk_rows),
+    # the same latent pages under the same kernel; a subclass of Xing4Config, found by its own type first
+    KimiVLConfig: CacheTwin(KimiVLForCausalLMWithCache, lambda cfg, page_size: LatentPagesGeometry(page_size),
+                            init_xing4_cache, walk_rows=xing4_walk_rows),
 }
 
 
 def cache_twin(cfg) -> CacheTwin:
-    """The registry's entry for ``cfg``."""
+    """The registry's entry for ``cfg``: its own type's, else that of a type it derives from."""
+    if type(cfg) in CACHE_MODEL_REGISTRY:
+        return CACHE_MODEL_REGISTRY[type(cfg)]
     for cfg_cls, twin in CACHE_MODEL_REGISTRY.items():
         if isinstance(cfg, cfg_cls):
             return twin

@@ -21,7 +21,8 @@ compute dtype.  After the last layer the streams are added, normed, and go
 to the head.
 
 **Latent attention.**  ``c_q = RMSNorm(x W_qa)``; ``[q_nope | q_pe] = c_q
-W_qb`` a head; ``[c_kv | k_pe] = x W_kva``, ``c_kv <- RMSNorm(c_kv)``; rotary
+W_qb`` a head (``q_lora_rank`` None: ``[q_nope | q_pe] = x W_q``, no
+bottleneck and no query norm, as ``models/kimi_vl.py`` has it); ``[c_kv | k_pe] = x W_kva``, ``c_kv <- RMSNorm(c_kv)``; rotary
 (YaRN, interleaved pairs) on ``q_pe`` and on the one ``k_pe`` all heads
 share; ``[k_nope | v] = c_kv W_kvb`` a head; ``s = (q_nope . k_nope + q_pe .
 k_pe) * softmax_scale``.  What a cache has to hold of a token is ``[c_kv |
@@ -71,7 +72,7 @@ class Xing4Config:
     num_hidden_layers: int = 40
     first_k_dense_replace: int = 2
     num_attention_heads: int = 32
-    q_lora_rank: int = 768
+    q_lora_rank: Optional[int] = 768          # None: no query bottleneck, one ``q_proj`` and no query norm
     kv_lora_rank: int = 512
     qk_nope_head_dim: int = 128
     qk_rope_head_dim: int = 64
@@ -281,8 +282,11 @@ class Xing4Attention(nn.Module):
         def norm(name):
             return RMSNorm(cfg.rms_norm_eps, cfg.dtype, cfg.param_dtype, name=name)
 
-        c_q = norm("q_a_layernorm")(dense(cfg.q_lora_rank, (EMBED, None), "q_a_proj")(x))
-        q = dense((heads, nope + rope), (None, HEADS, HEAD_DIM), "q_b_proj")(c_q)
+        if cfg.q_lora_rank:
+            c_q = norm("q_a_layernorm")(dense(cfg.q_lora_rank, (EMBED, None), "q_a_proj")(x))
+            q = dense((heads, nope + rope), (None, HEADS, HEAD_DIM), "q_b_proj")(c_q)
+        else:
+            q = dense((heads, nope + rope), (EMBED, HEADS, HEAD_DIM), "q_proj")(x)
         kv_a = dense(cfg.kv_lora_rank + rope, (EMBED, None), "kv_a_proj_with_mqa")(x)
         c_kv = norm("kv_a_layernorm")(kv_a[..., :cfg.kv_lora_rank])
         w_kvb = self.param("kv_b_proj", _logical(nn.initializers.lecun_normal(), (None, HEADS, HEAD_DIM)),

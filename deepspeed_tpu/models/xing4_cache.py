@@ -37,7 +37,7 @@ the expert layers are one ``scan_blocks`` over their indices and read their
 expert banks in place (``MixtralForCausalLMWithCache._stacked_banks``).
 """
 
-from typing import Tuple
+from typing import Callable, Tuple
 
 import jax.numpy as jnp
 from flax import linen as nn
@@ -104,16 +104,19 @@ def absorbed_attend(cfg: Xing4Config, page_size, groups, pages, layer, block_tab
 
 
 class _DenseLayerCache(nn.Module):
+    """``forward`` is the family's layer (``xing4.layer_forward``; ``kimi_vl``'s
+    plain residual), which names the parameters."""
     cfg: Xing4Config
     page_size: int
     groups: Tuple[Tuple[int, int], ...]
+    forward: Callable = layer_forward
 
     @nn.compact
     def __call__(self, carry, layer, positions, block_table, start_pos, chunk_lens):
         x, pages = carry
         attend = absorbed_attend(self.cfg, self.page_size, self.groups, pages, layer, block_table, start_pos,
                                  chunk_lens)
-        return layer_forward(self.cfg, False, x, positions, attend)
+        return self.forward(self.cfg, False, x, positions, attend)
 
 
 class _SparseLayerCache(nn.Module):
@@ -122,6 +125,7 @@ class _SparseLayerCache(nn.Module):
     cfg: Xing4Config
     page_size: int
     groups: Tuple[Tuple[int, int], ...]
+    forward: Callable = layer_forward
 
     @nn.compact
     def __call__(self, carry, index, positions, block_table, start_pos, chunk_lens, stacked_banks=None):
@@ -130,8 +134,20 @@ class _SparseLayerCache(nn.Module):
         attend = absorbed_attend(cfg, self.page_size, self.groups, pages, cfg.first_k_dense_replace + index,
                                  block_table, start_pos, chunk_lens)
         # a chunk's padding goes to no routed expert (the mask the page write uses)
-        return layer_forward(cfg, True, x, positions, attend, live_slots(self.groups, chunk_lens),
-                             None if stacked_banks is None else (stacked_banks, index)), None
+        return self.forward(cfg, True, x, positions, attend, live_slots(self.groups, chunk_lens),
+                            None if stacked_banks is None else (stacked_banks, index)), None
+
+
+def stacked_banks(module, cfg):
+    """The expert layers' banks of ``module``'s parameters as the scan holds
+    them, [L, E, ...], for the blocks to read in place
+    (``MixtralForCausalLMWithCache._stacked_banks``); None where they are not
+    held in the compute dtype."""
+    experts = module.variables.get("params", {}).get("layers", {}).get("mlp", {}).get("experts")
+    if experts is None:
+        return None
+    banks = tuple(nn.meta.unbox(experts[name]) for name in ("w_gate", "w_up", "w_down"))
+    return banks if all(w.dtype == cfg.dtype for w in banks) else None
 
 
 class Xing4ForCausalLMWithCache(nn.Module):
@@ -140,15 +156,6 @@ class Xing4ForCausalLMWithCache(nn.Module):
     cfg: Xing4Config
     page_size: int = 16
     takes_row_groups = True
-
-    def _stacked_banks(self):
-        """The expert layers' banks as the scan holds them, [L, E, ...], for
-        the blocks to read in place (``MixtralForCausalLMWithCache._stacked_banks``)."""
-        experts = self.variables.get("params", {}).get("layers", {}).get("mlp", {}).get("experts")
-        if experts is None:
-            return None
-        banks = tuple(nn.meta.unbox(experts[name]) for name in ("w_gate", "w_up", "w_down"))
-        return banks if all(w.dtype == self.cfg.dtype for w in banks) else None
 
     @nn.compact
     def __call__(self, input_ids, start_pos, block_table, cache, chunk_lens=None, last_only=False, groups=None):
@@ -162,6 +169,6 @@ class Xing4ForCausalLMWithCache(nn.Module):
         if cfg.num_sparse_layers:
             (x, cache), _ = scan_blocks(_SparseLayerCache, cfg.num_sparse_layers, n_broadcast=5)(
                 cfg, self.page_size, groups, name="layers")((x, cache), jnp.arange(cfg.num_sparse_layers), positions,
-                                                            block_table, start_pos, chunk_lens, self._stacked_banks())
+                                                            block_table, start_pos, chunk_lens, stacked_banks(self, cfg))
         x = sampled_rows(x, chunk_lens, last_only, groups)
         return logits_as(head_logits(cfg, x), input_ids, last_only), cache

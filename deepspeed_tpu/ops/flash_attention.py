@@ -409,6 +409,97 @@ def _flash_bwd2(q, k, v, o, lse, do, *, h, hk, causal, block_q, block_k, interpr
     return dq, dk, dv
 
 
+# ---------------------------------------------------------------------------
+# Forward only, not causal, keys masked at a length a batch row: what an
+# encoder over a padded bucket needs (an image's patches padded to a bucket of
+# a vision tower: models/kimi_vl.py).  Same packed layout and the same
+# ``_pack_width`` as above, so heads of a width that is no multiple or divisor
+# of the lane width (72) go through it sixteen to a block, each cut out of the
+# block at an offset that is no multiple of 128.  The grid is every (query
+# block, key block) pair: a block of keys wholly behind the length is masked
+# like the rest (the buckets are chosen so that there are few).
+# ---------------------------------------------------------------------------
+
+
+def _fwd_keylen_kernel(len_ref, q_ref, k_ref, v_ref, o_ref, *scr, scale, bk, P, d):
+    ms, ls, accs = scr[:P], scr[P:2 * P], scr[2 * P:3 * P]
+    b, ik = pl.program_id(0), pl.program_id(3)
+
+    @pl.when(ik == 0)
+    def _init():
+        for p in range(P):
+            ms[p][:] = jnp.full_like(ms[p], -jnp.inf)
+            ls[p][:] = jnp.zeros_like(ls[p])
+            accs[p][:] = jnp.zeros_like(accs[p])
+
+    n_keys = len_ref[b]
+    for p in range(P):
+        q = q_ref[0, :, p * d:(p + 1) * d]
+        k = k_ref[0, :, p * d:(p + 1) * d]
+        v = v_ref[0, :, p * d:(p + 1) * d]
+        s = jax.lax.dot_general(q, k, (((1, ), (1, )), ((), ())), preferred_element_type=jnp.float32) * scale
+        kpos = ik * bk + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
+        s = jnp.where(kpos < n_keys, s, DEFAULT_MASK_VALUE)
+        m_prev, l_prev = ms[p][:], ls[p][:]
+        m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
+        pr = jnp.exp(s - m_new)
+        alpha = jnp.exp(m_prev - m_new)
+        ls[p][:] = alpha * l_prev + jnp.sum(pr, axis=1, keepdims=True)
+        accs[p][:] = accs[p][:] * alpha + jax.lax.dot_general(
+            pr.astype(v.dtype), v, (((1, ), (0, )), ((), ())), preferred_element_type=jnp.float32)
+        ms[p][:] = m_new
+
+    @pl.when(ik == pl.num_programs(3) - 1)
+    def _finalize():
+        for p in range(P):
+            o_ref[0, :, p * d:(p + 1) * d] = (accs[p][:] / jnp.maximum(ls[p][:], 1e-30)).astype(o_ref.dtype)
+
+
+def _interpret_here() -> bool:
+    """Whether the kernels are interpreted: resolved against the GOVERNING
+    mesh, not the local devices: an AOT compile for an offline TPU topology
+    from a CPU-only host must lower the real kernels, not interpret mode."""
+    from ..comm.mesh import get_trace_mesh
+    tm = get_trace_mesh()
+    dev = tm.devices.flat[0] if tm is not None else jax.devices()[0]
+    return getattr(dev, "platform", "") != "tpu"
+
+
+def flash_attention_keylen(q, k, v, kv_len, *, block_q: int = 256, block_k: int = 512,
+                           interpret: Optional[bool] = None):
+    """Non-causal attention of q, k, v [B, S, H, D] in which row ``b`` sees its
+    first ``kv_len[b]`` keys (>= 1) and no other: a padded bucket.  S is a
+    multiple of 128.  Forward only.  Query rows behind the length are
+    computed like the rest and are the caller's to drop."""
+    b, s, h, d = q.shape
+    if s % LANE:
+        raise ValueError(f"flash_attention_keylen: {s} positions are no multiple of {LANE}")
+    if interpret is None:
+        interpret = _interpret_here()
+    P = _pack_width(d, h)
+    bq, bk = math.gcd(min(block_q, s), s), math.gcd(min(block_k, s), s)
+    spec_q = pl.BlockSpec((1, bq, P * d), lambda b, hh, iq, ik, n: (b, iq, hh))
+    spec_k = pl.BlockSpec((1, bk, P * d), lambda b, hh, iq, ik, n: (b, ik, hh))
+    out = pl.pallas_call(
+        functools.partial(_fwd_keylen_kernel, scale=1.0 / (d**0.5), bk=bk, P=P, d=d),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1,
+            grid=(b, h // P, s // bq, s // bk),
+            in_specs=[spec_q, spec_k, spec_k],
+            out_specs=spec_q,
+            scratch_shapes=([pltpu.VMEM((bq, 1), jnp.float32)] * 2 * P + [pltpu.VMEM((bq, d), jnp.float32)] * P),
+        ),
+        out_shape=jax.ShapeDtypeStruct((b, s, h * d), q.dtype),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel", "parallel", "arbitrary"),
+            # sixteen heads a block at d = 72: 32 lane-padded [bq, 1] carries and 16 accumulators beside the blocks
+            vmem_limit_bytes=48 * 1024 * 1024),
+        interpret=interpret,
+        name="ds_flash_fwd_keylen",
+    )(jnp.asarray(kv_len, jnp.int32), q.reshape(b, s, h * d), k.reshape(b, s, h * d), v.reshape(b, s, h * d))
+    return out.reshape(b, s, h, d)
+
+
 @functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7))
 def _flash_attention(q, k, v, causal, block_q, block_k, interpret, q_offset=0):
     out, _ = _fwd(q, k, v, causal, block_q, block_k, interpret, q_offset, emit_lse=False)
@@ -537,12 +628,7 @@ def flash_attention(q,
                                  sliding_window=sliding_window)
     from ..comm.mesh import get_trace_mesh, in_manual_mesh
     if interpret is None:
-        # resolve against the GOVERNING mesh, not the local devices: an AOT
-        # compile for an offline TPU topology from a CPU-only host must
-        # lower the real kernels, not interpret mode
-        tm = get_trace_mesh()
-        dev = tm.devices.flat[0] if tm is not None else jax.devices()[0]
-        interpret = getattr(dev, "platform", "") != "tpu"
+        interpret = _interpret_here()
     if isinstance(q, jax.core.Tracer) and not in_manual_mesh():
         mesh = get_trace_mesh()
         if mesh is not None and mesh.size > 1:

@@ -180,7 +180,7 @@ class ServingEngine:
                trace_id: Optional[int] = None,
                parent_span_id: Optional[int] = None,
                spec: Optional[bool] = None,
-               kv_snapshot=None) -> ServingRequest:
+               kv_snapshot=None, images: Optional[Sequence] = None) -> ServingRequest:
         """Enqueue one request.  NEVER raises on overload: the returned
         request's state is REJECTED (with ``reject_reason``) when admission
         refuses it — callers inspect, the serving loop keeps running.
@@ -216,6 +216,15 @@ class ServingEngine:
         ``migration/import_fallback`` metric.  Either way the snapshot is
         consumed at first admission (a preemption AFTER import resumes by
         recompute, as always).
+
+        ``images``: ``[(pixels [h w, 3, p, p], (h, w)), ...]``, what the
+        prompt's runs of the placeholder id stand for, in order (a model
+        with a vision tower).  A request whose runs disagree with its grids,
+        whose grid is odd, or whose image is over the configuration's
+        largest bucket of patches is REJECTED with that reason
+        (``InferenceEngineV2.check_images``), as is one with images for a
+        model without a tower.  The tower runs inside ``tick()``, and the
+        request is planned for prefill once its images are through it.
 
         ``retry_policy`` (a resilience ``RetryPolicy``): re-probe admission
         while the rejection is TRANSIENT (``queue_full`` — pressure that
@@ -256,6 +265,7 @@ class ServingEngine:
                     "request has nothing to resume")
             req.tokens.extend(int(t) for t in resume_tokens)
         req.kv_snapshot = kv_snapshot
+        req.images = list(images) if images else None
         self._requests[req.uid] = req
         self.stats.submitted += 1
         if self.tracer.enabled:
@@ -268,7 +278,8 @@ class ServingEngine:
                 self.clock.now() if parent_span_id is not None else None)
         if self.metrics is not None:
             self.metrics.counter("serving/submitted").inc()
-        ok, reason = self.admission.submit_ok(req, len(self._queue))
+        reason = self.engine.check_images(req.prompt, req.images) if req.images else None
+        ok, reason = (False, reason) if reason else self.admission.submit_ok(req, len(self._queue))
         if not ok and reason == "queue_full" and retry_policy is not None:
             from ..resilience.retry import backoff_until
 
@@ -388,6 +399,7 @@ class ServingEngine:
             anat.mark("admit")
         if not self._active:
             return {}
+        self._encode_images(anat)
         evicted, plan = self.kvp.resolve()
         for seq in evicted:
             self._on_preempted(seq, now)
@@ -490,6 +502,7 @@ class ServingEngine:
         if anat.enabled:
             anat.step_begin()      # open step g+1's window for its planning
         try:
+            self._encode_images(anat)
             evicted, plan = self.kvp.resolve()
             for seq in evicted:
                 self._on_preempted(seq, now)
@@ -517,6 +530,42 @@ class ServingEngine:
             if anat.enabled:
                 anat.mark("deliver")
         return out
+
+    def _encode_images(self, anat) -> None:
+        """The vision tower's part of a tick, before the step is planned: the
+        images of admitted requests in scheduling order, a dispatch each, at
+        most ``scheduler.vision_patches_per_tick`` padded patches, so that
+        the tick's decode rows wait for a bounded time
+        (``InferenceEngineV2.iter_encode_images``).  The programs are
+        enqueued and not waited for: the device runs them before the step
+        that reads their rows.  Each dispatch is a ``serving/vision_encode``
+        span; a request whose last image went out is planned from this tick
+        on, and the wait since its admission is its ``phase/vision_encode``."""
+        if getattr(self.engine, "mm_rows", None) is None:
+            return
+        t0 = self.clock.now()
+        n = 0
+        for rec in self.engine.iter_encode_images():
+            t1 = self.clock.now()
+            n += 1
+            req = self._active.get(rec["uid"])
+            if self.tracer.enabled and rec["uid"] in self._trace_ctx:
+                self.tracer.add_span("serving/vision_encode", self._trace_ctx[rec["uid"]][0], t0, t1,
+                                     track=self.trace_track,
+                                     attrs={"uid": rec["uid"], "images": 1, "bucket": rec["bucket"],
+                                            "patches_real": rec["patches_real"],
+                                            "patches_padded": rec["patches_padded"]})
+            if self.metrics is not None:
+                self.metrics.counter("serving/vision_images").inc()
+                self.metrics.counter("serving/vision_patches_real").inc(rec["patches_real"])
+                self.metrics.counter("serving/vision_patches_padded").inc(rec["patches_padded"])
+                if rec["reencoded"]:
+                    self.metrics.counter("serving/vision_reencoded").inc()
+            if req is not None and rec["done"]:
+                req.encode_windows.append((req.history[-1][1], t1))
+            t0 = t1
+        if n and anat.enabled:
+            anat.mark("vision_encode")
 
     def _fold_compiles(self, anat) -> None:
         """Bridge the recorder's compile tracker into the serving
@@ -627,8 +676,12 @@ class ServingEngine:
                     # chain tail for this prompt device-side first, so the
                     # prefill below skips it via the ordinary match()
                     self._promote_prefix_for(req)
-                self.engine.put([req.uid], [req.engine_tokens()],
-                                max_new_tokens=req.remaining_new_tokens)
+                if req.images:
+                    self.engine.put([req.uid], [req.engine_tokens()], max_new_tokens=req.remaining_new_tokens,
+                                    images=[req.images], reencode=req.preemptions > 0)
+                else:
+                    self.engine.put([req.uid], [req.engine_tokens()],
+                                    max_new_tokens=req.remaining_new_tokens)
             if req.spec is not None:
                 # re-applied on every (re)admission: preemption/flush
                 # cleared the engine's per-uid opt-out

@@ -143,6 +143,15 @@ class ServingRequest:
     #: state — the PARKED machinery (demote/promote/resume ladder) is
     #: identical; only span/why_slow attribution differs.
     park_phase: str = "parked"
+    #: images the prompt's placeholder runs stand for, ``[(pixels [h w, 3,
+    #: p, p], (h, w)), ...]`` in prompt order (a model with a vision tower;
+    #: None: text alone).  Kept for the request's life: a preempted request
+    #: goes through the tower again.
+    images: Optional[list] = None
+    #: ``(t_admitted, t_encoded)`` of each admission that waited for the
+    #: tower: telemetry carves them out of the PREFILL interval as
+    #: ``phase/vision_encode`` spans
+    encode_windows: List[Tuple[float, float]] = dataclasses.field(default_factory=list)
 
     def __post_init__(self):
         self.prompt = list(self.prompt)

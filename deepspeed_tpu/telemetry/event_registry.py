@@ -267,6 +267,20 @@ EVENTS = {
                                       "a step program compiled AFTER the "
                                       "warm-up boundary — the AOT "
                                       "serving-step regression guard"),
+    # ---- the vision tower's part of a serving tick (serving/engine.py
+    #      _encode_images; docs/OBSERVABILITY.md "Vision tower")
+    "serving/vision_encode": ("span", "serving/engine.py",
+                              "one dispatch of the vision tower's program "
+                              "(images, bucket, patches real and padded)"),
+    "serving/vision_images": ("counter", "serving/engine.py",
+                      "images dispatched to the vision tower"),
+    "serving/vision_patches_real": ("counter", "serving/engine.py",
+                            "patches of those images"),
+    "serving/vision_patches_padded": ("counter", "serving/engine.py",
+                              "patches of the buckets they were padded to"),
+    "serving/vision_reencoded": ("counter", "serving/engine.py",
+                         "images of preempted requests through the tower "
+                         "again (recompute-on-resume)"),
     # ---- engine-step tracer spans (runtime/engine.py set_telemetry)
     "engine/step": ("span", "runtime/engine.py",
                     "one train_batch trace root on the engine track"),

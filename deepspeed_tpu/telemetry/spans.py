@@ -11,6 +11,8 @@ its history is folded into contiguous **phase spans** here:
     decode    — DECODE
     migrating — MIGRATING (paused for chunked KV export — the per-request
                 migration cost of disaggregated serving)
+    vision_encode — the part of PREFILL before the request's images were
+                through the vision tower (``ServingRequest.encode_windows``)
     pending   — fleet-level router queue time (before dispatch, between
                 failover displacement and re-dispatch)
 
@@ -50,12 +52,15 @@ PHASE_OF_STATE = {
 }
 
 
-def _carve_promote(intervals: List[Tuple[str, float, float]],
-                   windows: List[Tuple[float, float]]
-                   ) -> List[Tuple[str, float, float]]:
+def _carve(intervals: List[Tuple[str, float, float]],
+           windows: List[Tuple[float, float]], name: str = "promote",
+           out_of: Tuple[str, ...] = ("parked", "tool_stall", "queued")
+           ) -> List[Tuple[str, float, float]]:
     """Carve h2d promotion transfer windows (``ServingRequest.
     promote_windows``) out of the ``parked``/``queued`` intervals they
-    overlap, as ``promote`` pieces.  The pieces PARTITION each original
+    overlap, as ``promote`` pieces; or, with ``name`` and ``out_of``, other
+    windows out of other phases (a request's wait for the vision tower,
+    ``encode_windows``, out of ``prefill`` as ``vision_encode``).  The pieces PARTITION each original
     interval (tiling preserved exactly): a resume's TTFT then splits into
     genuine queue wait vs promotion transfer instead of lumping both into
     ``queued``.  Windows never overlap other phases — the engine stalls
@@ -71,7 +76,7 @@ def _carve_promote(intervals: List[Tuple[str, float, float]],
             merged.append([w0, w1])
     out: List[Tuple[str, float, float]] = []
     for phase, t0, t1 in intervals:
-        if phase not in ("parked", "tool_stall", "queued"):
+        if phase not in out_of:
             out.append((phase, t0, t1))
             continue
         cur = t0
@@ -81,7 +86,7 @@ def _carve_promote(intervals: List[Tuple[str, float, float]],
                 continue
             if lo > cur:
                 out.append((phase, cur, lo))
-            out.append(("promote", lo, hi))
+            out.append((name, lo, hi))
             cur = hi
         if t1 > cur:
             out.append((phase, cur, t1))
@@ -156,8 +161,8 @@ def emit_attempt_spans(tracer: Tracer, req: ServingRequest, trace_id: int,
                                 tail_phase=tail_phase,
                                 park_phase=getattr(req, "park_phase",
                                                    "parked"))
-    intervals = _carve_promote(intervals,
-                               getattr(req, "promote_windows", None) or [])
+    intervals = _carve(intervals, getattr(req, "promote_windows", None) or [])
+    intervals = _carve(intervals, getattr(req, "encode_windows", None) or [], "vision_encode", ("prefill", ))
     for phase, t0, t1 in intervals:
         spans.append(tracer.add_span(f"phase/{phase}", trace_id, t0, t1,
                                      parent_id=parent_id, track=track))

@@ -140,19 +140,22 @@ from typing import Dict, List, Optional
 from ..utils.logging import logger
 from .trace import PerfClock
 
-__all__ = ["HOST_SEGMENTS", "COUNTS", "StepAnatomy", "NullStepAnatomy", "NULL_ANATOMY",
+__all__ = ["HOST_SEGMENTS", "COUNTS", "ENCODE_COUNTS", "StepAnatomy", "NullStepAnatomy", "NULL_ANATOMY",
            "StepRecord", "CompileRecord", "recorders"]
 
 #: the closed host-segment vocabulary; every step exports all of them
 #: (zero-filled) so the per-step table has one fixed shape
 HOST_SEGMENTS = ("admit", "schedule", "draft_plan", "verify_plan",
                  "aot_compile", "compile_wait", "dispatch", "sample_accept",
-                 "deliver", "overlap", "bookkeeping", "promote_wait")
+                 "deliver", "overlap", "bookkeeping", "promote_wait", "vision_encode")
 
 #: what a step carried; zero until the engine notes them
 COUNTS = ("rows_decode", "rows_prefill", "tokens_real", "slots", "tokens_out",
           "tokens_discarded", "expert_rows", "expert_rows_kernel", "attn_rows_visible", "attn_rows_walked",
-          "ssm_rows", "window_rows_visible", "ssd_state_bytes", "mla_rows_read")
+          "ssm_rows", "window_rows_visible", "ssd_state_bytes", "mla_rows_read", "mm_tokens")
+
+#: what an encode dispatch of a vision tower carried (``StepAnatomy.encodes``, beside the step records)
+ENCODE_COUNTS = ("vit_images", "vit_patches_real", "vit_patches_padded", "vit_pairs", "vit_reencoded")
 
 #: names of the instant profiler events, built once (a mark allocates no string)
 _MARK_NAMES = {s: "ds.mark." + s for s in HOST_SEGMENTS + ("device_wait", )}
@@ -211,7 +214,7 @@ class StepRecord:
         self.tokens_out = self.tokens_discarded = 0
         self.expert_rows = self.expert_rows_kernel = 0
         self.attn_rows_visible = self.attn_rows_walked = 0
-        self.ssm_rows = self.window_rows_visible = self.ssd_state_bytes = self.mla_rows_read = 0
+        self.ssm_rows = self.window_rows_visible = self.ssd_state_bytes = self.mla_rows_read = self.mm_tokens = 0
 
     def host_s(self) -> float:
         return sum(self.segments.values())
@@ -290,6 +293,9 @@ class StepAnatomy:
         self._slow_logged: Optional[float] = None   # clock time of the last ds.slow_step line
         self.compiles: List[CompileRecord] = []
         self.steady_state_recompiles = 0
+        #: one row a dispatch of a vision tower's program: ``ts`` (this clock),
+        #: ``key`` (``vit:p4096``) and ``ENCODE_COUNTS``
+        self.encodes = deque(maxlen=int(max_steps))
         #: monotonic count of CLOSED steps (deque eviction never rewinds it)
         self.total_steps = 0
         # lifetime totals (survive deque eviction; the cheap gauge inputs)
@@ -396,6 +402,14 @@ class StepAnatomy:
             cur.key, cur.path = key, path
             cur.rows_decode, cur.rows_prefill = int(rows_decode), int(rows_prefill)
             cur.tokens_real, cur.slots = int(tokens_real), int(slots)
+
+    def note_encode(self, key: str, patches_real: int, patches_padded: int, reencoded: bool = False) -> None:
+        """One image through the vision tower's program ``key``: a row of
+        ``encodes``.  ``vit_pairs`` is the (query, key) pairs a layer's
+        attention needs, ``patches_real`` squared."""
+        self.encodes.append({"ts": self.clock.now(), "key": key, "vit_images": 1,
+                             "vit_patches_real": int(patches_real), "vit_patches_padded": int(patches_padded),
+                             "vit_pairs": int(patches_real)**2, "vit_reencoded": int(bool(reencoded))})
 
     def note_counts(self, expert_rows: int = 0, expert_rows_kernel: int = 0,
                     cache_counts: tuple = (0, 0), state_counts: Optional[dict] = None) -> None:
@@ -702,6 +716,9 @@ class NullStepAnatomy:
         pass
 
     def note_program(self, key, path, rows_decode=0, rows_prefill=0, tokens_real=0, slots=0) -> None:
+        pass
+
+    def note_encode(self, key, patches_real, patches_padded, reencoded=False) -> None:
         pass
 
     def note_counts(self, expert_rows=0, expert_rows_kernel=0, cache_counts=(0, 0), state_counts=None) -> None:

@@ -53,10 +53,12 @@ from deepspeed_tpu.serving.metrics import percentile_summary  # noqa: E402
 #: ``tool_stall`` is a PARKED interval relabeled by its session park
 #: phase (serving/sessions): a mid-generation wait for an agentic tool
 #: result; ``think_time`` is the session-level between-turn gap (only in
-#: session-root traces, which fold() skips — named for completeness)
+#: session-root traces, which fold() skips — named for completeness);
+#: ``vision_encode`` is the part of a request's prefill before its images
+#: were through the vision tower (telemetry/spans.py carves it out of prefill)
 PHASES = ("pending", "queued", "prefill", "decode", "migrating", "evicted",
           "fenced", "host_gap", "compile_wait", "parked", "tool_stall",
-          "think_time", "promote")
+          "think_time", "promote", "vision_encode")
 _US = 1e6
 
 

@@ -85,7 +85,9 @@ _DIRECT = {"prefill": "prefill", "decode": "decode",
            "migrating": "migration_pause", "fenced": "fenced",
            "evicted": "eviction", "host_gap": "host_gap",
            "compile_wait": "compile_wait", "parked": "parked",
-           "tool_stall": "tool_stall", "promote": "promote"}
+           "tool_stall": "tool_stall", "promote": "promote",
+           # the wait for the vision tower is the prompt's processing: prefill
+           "vision_encode": "prefill"}
 
 
 def _overlap(t0, t1, w0, w1):

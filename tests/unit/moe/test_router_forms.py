@@ -95,3 +95,32 @@ def test_the_bias_changes_the_choice_and_not_the_weight():
     np.testing.assert_allclose(np.asarray(doubled), 2.0 * np.asarray(lifted[0]), rtol=1e-6, atol=1e-6)
     with pytest.raises(ValueError, match="unknown router scoring"):
         dropless_moe(x, logits, bank, 4, scoring="tanh")
+
+
+def test_six_of_sixty_four_at_scale_2_446_beside_two_shared_experts():
+    """Kimi-VL's expert block (``models/kimi_vl.py`` through ``Xing4MoE``): 6
+    of 64 by sigmoid score plus a selection bias, weights renormalised and
+    times 2.446, beside one ungated SwiGLU of ``2 x moe_intermediate_size``."""
+    from flax import linen as nn
+
+    from deepspeed_tpu.models.kimi_vl import KimiVLConfig
+    from deepspeed_tpu.models.xing4 import Xing4MoE
+    cfg = KimiVLConfig(hidden_size=32, moe_intermediate_size=24, dtype=jnp.float32, param_dtype=jnp.float32)
+    assert (cfg.n_routed_experts, cfg.num_experts_per_tok, cfg.n_shared_experts) == (64, 6, 2)
+    assert cfg.routed_scaling_factor == 2.446 and cfg.scoring_func == "sigmoid" and cfg.topk_method == "noaux_tc"
+    x = jax.random.normal(jax.random.PRNGKey(0), (1, 20, 32))
+    block = Xing4MoE(cfg)
+    params = nn.meta.unbox(block.init(jax.random.PRNGKey(1), x))
+    p = params["params"]
+    p["e_score_correction_bias"] = 0.2 * jax.random.normal(jax.random.PRNGKey(2), (64, ))
+    assert p["shared_experts"]["gate_proj"]["kernel"].shape == (32, 48)            # two shared experts, one block
+    with jax.default_matmul_precision("highest"):
+        out = block.apply(params, x)[0]
+    bank = tuple(p["experts"][n] for n in ("w_gate", "w_up", "w_down"))
+    logits = np.asarray(x[0]) @ np.asarray(p["gate"]["kernel"])
+    routed = _by_hand(x[0], logits, bank, 6, p["e_score_correction_bias"], 2.446, True)
+    h = np.asarray(x[0], np.float64)
+    sh = {n: np.asarray(p["shared_experts"][n]["kernel"], np.float64) for n in ("gate_proj", "up_proj", "down_proj")}
+    g = h @ sh["gate_proj"]
+    shared = (g / (1.0 + np.exp(-g)) * (h @ sh["up_proj"])) @ sh["down_proj"]
+    np.testing.assert_allclose(np.asarray(out), routed + shared, atol=2e-4)
