@@ -277,7 +277,7 @@ class InFlightStep:
     def __init__(self, kind: str):
         self.kind = kind          # "single" | "multi" | "spec"
         self.tokens = None        # device array: sampled tokens / argmax
-        self.rows = None          # single: [(uid, n, seq, row_index)]
+        self.rows = None          # single: [(uid, n, seq, row_index)]: a run's tokens, its last row
         self.seqs = None          # multi/spec: descriptor list at dispatch
         self.drafts = None        # spec: per-row draft token lists
         self.base_len = None      # spec: pre-splice history lengths
@@ -375,6 +375,13 @@ class InferenceEngineV2:
         self._step_fns: Dict[tuple, callable] = {}
         #: whether the twin's blocks take a step of more than one row group
         self._row_groups = bool(getattr(self.model, "takes_row_groups", False))
+        if self._row_groups:
+            # a prompt alone in prefill may fill the rung its steps have for a
+            # burst of arrivals with its own consecutive chunks, and no wider
+            # one: what the rung of ``max_seqs`` rows costs the decode rows
+            # that ride it is not measured (PERF.md section 7)
+            rungs = self._prefill_rungs()
+            self.scheduler.run_rows = rungs[-2] if len(rungs) > 1 else 1
         #: a twin with a vision tower: the buffer of image rows [units, rows a
         #: unit, hidden] the sequences' images own units of from their encode
         #: until prefill has passed them (unit 0 is scratch, where an encode's
@@ -1200,13 +1207,24 @@ class InferenceEngineV2:
         return self.kv.page_size * walk_block(self.kv.page_size, self.kv.table_width, n_kv // tp, d,
                                               pages.dtype.itemsize)
 
+    def _kernel_rows(self, work, calls: int = 1):
+        """(first position, tokens) of each row a step's (seq, tokens) work
+        takes: a run of chunks in a single step is a row a chunk
+        (``pack_groups``), and each row walks the cache to its own end; the
+        fused rung's tokens (``calls`` of one each) are one row's."""
+        if calls > 1:
+            return [(s.seen_tokens, n) for s, n in work]
+        chunk = self.econfig.scheduler.prefill_chunk
+        return [(s.seen_tokens + at, min(chunk, n - at)) for s, n in work for at in range(0, max(n, 1), chunk)]
+
     def _cache_counts(self, work, calls: int = 1) -> tuple:
-        """The geometry's ``step_counts`` summed over a step's (seq, tokens)
-        rows, each row's tokens going through the paged kernel in ``calls``
-        calls, by blocks of the rows its walk takes at a step (``walked`` is
-        0 where no kernel walks)."""
+        """The geometry's ``step_counts`` summed over the rows of a step's
+        (seq, tokens) work (``_kernel_rows``), each row's tokens going through
+        the paged kernel in ``calls`` calls, by blocks of the rows its walk
+        takes at a step (``walked`` is 0 where no kernel walks)."""
         block_rows = self._walk_rows()
-        counts = [self.kv.geometry.step_counts(s.seen_tokens, n, block_rows, calls) for s, n in work]
+        counts = [self.kv.geometry.step_counts(start, n, block_rows, calls)
+                  for start, n in self._kernel_rows(work, calls)]
         return tuple(sum(c) for c in zip(*counts))
 
     def _state_counts(self, work, calls: int = 1) -> dict:
@@ -1216,8 +1234,8 @@ class InferenceEngineV2:
         if type(geometry).state_counts is LinearGeometry.state_counts:  # none to add
             return {}
         total = {}
-        for s, n in work:
-            for name, count in geometry.state_counts(s.seen_tokens, n, calls).items():
+        for start, n in self._kernel_rows(work, calls):
+            for name, count in geometry.state_counts(start, n, calls).items():
                 total[name] = total.get(name, 0) + count
         return total
 
@@ -1251,7 +1269,7 @@ class InferenceEngineV2:
             return [(work, self._bucket_batch(len(work)), 1)]
         if not self._row_groups:
             return [(work, self._bucket_batch(len(work)), chunk)]
-        rows = next(p for p in self._prefill_rungs() if p >= len(plan.prefill))
+        rows = next(p for p in self._prefill_rungs() if p >= len(self._kernel_rows(plan.prefill)))
         return [(decode, self._bucket_batch(max(len(decode), 1)), 1), (list(plan.prefill), rows, chunk)]
 
     @staticmethod
@@ -1379,8 +1397,8 @@ class InferenceEngineV2:
                     else "prefill" if plan.prefill else "decode")
             tokens_real = plan.planned_tokens
             anat.note_program(self._key_label(groups), path,
-                              rows_decode=len(plan.decode), rows_prefill=len(plan.prefill),
-                              tokens_real=tokens_real, slots=rb.tokens.size)
+                              rows_decode=len(plan.decode), rows_prefill=len(self._kernel_rows(plan.prefill)),
+                              seqs_prefill=len(plan.prefill), tokens_real=tokens_real, slots=rb.tokens.size)
         image_args = (jnp.asarray(rb.mm_index), self.mm_rows) if image_rows else ()
         next_tok, self.cache = self._invoke(fn, self.params, self.cache, jnp.asarray(rb.tokens),
                                             jnp.asarray(rb.start_pos), jnp.asarray(rb.block_tables),
@@ -1395,8 +1413,14 @@ class InferenceEngineV2:
             anat.mark("compile_wait" if self._fresh_compile else "dispatch")
         inf = InFlightStep("single")
         inf.tokens = next_tok
-        inf.rows = [(int(uid), int(rb.chunk_lens[i]), self.state.seqs[uid], i)
-                    for i, uid in enumerate(rb.uids) if uid >= 0]
+        inf.rows = []
+        for i, uid in enumerate(rb.uids):
+            if uid < 0:
+                continue
+            n = int(rb.chunk_lens[i])
+            if inf.rows and inf.rows[-1][0] == uid:   # a run's next row: the run is folded as one, at its last row
+                n += inf.rows.pop()[1]
+            inf.rows.append((int(uid), n, self.state.seqs[uid], i))
         return inf
 
     def _complete_single(self, inf: InFlightStep) -> Dict[int, List[int]]:

@@ -42,6 +42,11 @@ class LinearGeometry:
     pages_immutable = True
     #: whether a sequence also holds a state slot (``SlotPagesGeometry``)
     state_slots = False
+    #: whether consecutive chunks of one sequence may be rows of one step (a
+    #: run, ``SplitFuseScheduler.run_rows``): a step's rows are all written to
+    #: the pages before any of them attends, each up to its own last token, so
+    #: a row finds the rows before it there as it finds the steps before it
+    chunk_runs = True
 
     def __init__(self, page_size: int):
         self.page_size = int(page_size)
@@ -71,7 +76,8 @@ class LinearGeometry:
         return {}
 
     def chunk_limit(self, start: int, n_tokens: int) -> int:
-        """How many of ``n_tokens`` one chunk starting at ``start`` may carry."""
+        """How many of ``n_tokens`` one chunk starting at ``start`` may carry
+        (or one run of chunks, where ``chunk_runs``)."""
         return n_tokens
 
     def step_counts(self, start: int, n_tokens: int, block_rows: int = 0, calls: int = 1) -> tuple:
@@ -97,6 +103,11 @@ class RingSummaryGeometry:
 
     pages_immutable = False
     state_slots = False
+    #: inside one window: the rows of a step all write their ring rows before
+    #: any attends, and a summary row is read from the next window on only;
+    #: ``chunk_limit`` ends a run, as it ends a chunk, where the window ends
+    #: (a row of the next window would overwrite ring rows this one's read)
+    chunk_runs = True
     token_capacity = LinearGeometry.token_capacity
     state_counts = LinearGeometry.state_counts
 
@@ -138,7 +149,7 @@ class RingSummaryGeometry:
     def chunk_limit(self, start: int, n_tokens: int) -> int:
         """A chunk ends where its window ends: inside a chunk every query
         sees the same summary rows, so the twin can hand the paged kernel one
-        start position a row."""
+        start position a row.  So does a run of chunks: its rows share the ring."""
         return min(n_tokens, self.window - start % self.window)
 
     def step_counts(self, start: int, n_tokens: int, block_rows: int = 0, calls: int = 1) -> tuple:
@@ -168,6 +179,10 @@ class SlotPagesGeometry(LinearGeometry):
     #: and is not kept by position: nothing of it can be shared or rewound to
     pages_immutable = False
     state_slots = True
+    #: a row's scan and convolution start from the slot's state and leave
+    #: theirs there: two rows of one sequence in a step would start from the
+    #: same state, and the second's would be the one kept
+    chunk_runs = False
 
     def __init__(self, page_size: int, window: int = None, state_bytes: int = 0):
         super().__init__(page_size)

@@ -480,9 +480,16 @@ class BlockedKVCache:
         if n:
             if len(seq.pages) + n > self.max_pages_per_seq:
                 raise RuntimeError(f"sequence {seq.uid} exceeds max_pages_per_seq={self.max_pages_per_seq}")
-            if self.prefix_cache is not None and n > self.allocator.free_pages:
-                self.prefix_cache.evict(n - self.allocator.free_pages)
+            self.free_pages_on_demand(n)
             seq.pages.extend(self.allocator.allocate(n))
+
+    def free_pages_on_demand(self, n: int) -> int:
+        """The allocator's free pages once the prefix cache has given up what
+        it can of the ``n`` asked for (cold pages that no sequence holds):
+        what can be allocated without preempting anybody."""
+        if self.prefix_cache is not None and n > self.allocator.free_pages:
+            self.prefix_cache.evict(n - self.allocator.free_pages)
+        return self.allocator.free_pages
 
     def release(self, seq: SequenceDescriptor) -> None:
         self.allocator.free(seq.pages)
@@ -723,8 +730,10 @@ class StateManager:
         one flat axis of ``sum(rows x width)`` slots, group after group and a
         row's ``width`` slots together; ``start_pos``, the block tables,
         ``chunk_lens`` and ``uids`` one entry a row, the groups' rows
-        concatenated.  A group's work fills its first rows; the rest are
-        padding rows as in ``pack``.  ``mm``: also ``mm_index``, the slots'
+        concatenated.  A group's work fills its first rows, an item of more
+        than ``width`` tokens as many consecutive rows as it has chunks of
+        ``width`` (a run: the plan's ``(seq, n)`` of ``SplitFuseScheduler``);
+        the rest are padding rows as in ``pack``.  ``mm``: also ``mm_index``, the slots'
         rows of the engine's image-row buffer (``SequenceDescriptor.mm_index``)."""
         n_rows = sum(rows for _, rows, _ in groups)
         tokens = np.zeros((sum(rows * width for _, rows, width in groups), ), np.int32)
@@ -735,8 +744,8 @@ class StateManager:
         uids = [-1] * n_rows
         r0 = t0 = 0
         for work, rows, width in groups:
-            assert len(work) <= rows, f"{len(work)} work items exceed batch capacity {rows}"
-            for i, (seq, n) in enumerate(work, start=r0):
+            i = r0
+            for seq, n in work:
                 self.kv.ensure_capacity(seq, n)
                 sl = seq.tokens[seq.seen_tokens:seq.seen_tokens + n]
                 at = t0 + (i - r0) * width
@@ -744,12 +753,19 @@ class StateManager:
                 if mm and seq.mm_index is not None:
                     rows_of = seq.mm_index[seq.seen_tokens:seq.seen_tokens + n]
                     mm_index[at:at + len(rows_of)] = rows_of
-                start_pos[i] = seq.seen_tokens
-                block_tables[i, self.kv.geometry.slots(len(seq.pages))] = seq.pages
+                # more than ``width`` tokens are a run of consecutive chunks: a row
+                # each, adjacent (so the flat slices above are theirs already),
+                # through the same pages, each from where the one before it ends
+                run = slice(i, i + max(1, -(-n // width)))
+                assert run.stop <= r0 + rows, f"work of {len(work)} items exceeds the group's {rows} rows"
+                ahead = width * np.arange(run.stop - i)
+                start_pos[run] = seq.seen_tokens + ahead
+                block_tables[run, self.kv.geometry.slots(len(seq.pages))] = seq.pages
                 if seq.slot:
-                    block_tables[i, -1] = seq.slot     # the row's last column (geometry.SlotPagesGeometry)
-                chunk_lens[i] = n
-                uids[i] = seq.uid
+                    block_tables[run, -1] = seq.slot     # the row's last column (geometry.SlotPagesGeometry)
+                chunk_lens[run] = np.minimum(n - ahead, width)
+                uids[run] = [seq.uid] * (run.stop - i)
+                i = run.stop
             r0, t0 = r0 + rows, t0 + rows * width
         return RaggedBatch(tokens=tokens, start_pos=start_pos, block_tables=block_tables,
                            chunk_lens=chunk_lens, uids=uids, mm_index=mm_index)

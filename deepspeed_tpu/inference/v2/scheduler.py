@@ -49,7 +49,9 @@ class SchedulerConfig:
 
 @dataclasses.dataclass
 class StepPlan:
-    """One engine step = one decode batch + up to one prefill chunk batch."""
+    """One engine step = one decode batch + one batch of prefill work: a
+    chunk a prefilling sequence, or a run of consecutive chunks of it
+    (``n_tokens`` over ``prefill_chunk``: ``SplitFuseScheduler.run_rows``)."""
     decode: List[SequenceDescriptor]
     prefill: List[Tuple[SequenceDescriptor, int]]   # (seq, n_tokens)
 
@@ -71,6 +73,12 @@ class SplitFuseScheduler:
         # None keeps dict-insertion (put) order — the historical behaviour
         # for direct engine users.
         self.order_key = None
+        # rows of ``prefill_chunk`` a step's prefill work may take where a
+        # sequence's consecutive chunks may be rows of one step (a run): the
+        # engine sets it to the rung of prefill rows its step programs have
+        # for a burst (``InferenceEngineV2._prefill_rungs``).  1: a chunk a
+        # sequence and no more, whatever the budget has left.
+        self.run_rows = 1
 
     def plan(self, manager: StateManager) -> StepPlan:
         cfg = self.config
@@ -111,4 +119,38 @@ class SplitFuseScheduler:
                 continue
             plan_prefill.append((seq, n))
             budget -= n
+        if budget > 0 and 0 < len(plan_prefill) < self.run_rows and manager.kv.geometry.chunk_runs:
+            self._run_ahead(manager.kv, decodes, plan_prefill, budget)
         return StepPlan(decode=decodes, prefill=plan_prefill)
+
+    def _run_ahead(self, kv, decodes, plan_prefill, budget: int) -> None:
+        """Give the rows that ``run_rows`` has spare to the planned sequences,
+        in their order: each takes further whole chunks behind its first, as
+        far as its prompt, the geometry (``chunk_limit``: where a run must
+        end), the token budget and the pages go.  Pages: what the allocator
+        gives without a preemption beyond what the plan needs as it stands
+        (free pages, and the prefix cache's cold ones, evicted here as
+        ``ensure_capacity`` would evict them when the step is packed), so a
+        run is never what makes a plan not fit (``serving/kv_pressure``)."""
+        chunk = self.config.prefill_chunk
+        spare = self.run_rows - len(plan_prefill)
+        held = sum(kv.pages_needed(s, 1) for s in decodes) + sum(kv.pages_needed(s, n) for s, n in plan_prefill)
+        for i, (seq, n) in enumerate(plan_prefill):
+            if spare <= 0 or budget <= 0:
+                break
+            if n < chunk:   # its prompt, its window or the budget ends inside the first chunk
+                continue
+            more = kv.geometry.chunk_limit(seq.seen_tokens,
+                                           min(seq.remaining_prefill, (1 + spare) * chunk, n + budget)) - n
+            first = kv.pages_needed(seq, n)
+            while more > 0:
+                pages = kv.pages_needed(seq, n + more) - first
+                if held + pages <= kv.free_pages_on_demand(held + pages):
+                    break
+                more -= (more - 1) % chunk + 1    # a row less
+            if more <= 0:
+                continue
+            plan_prefill[i] = (seq, n + more)
+            held += pages
+            budget -= more
+            spare -= -(-more // chunk)

@@ -53,7 +53,10 @@ to kill.  This module decomposes EVERY engine step into:
 * **counts** of what the step carried, noted where the engine packs the
   batch and folds the tokens: the program's key (``step:b16:c1:b1:c128``:
   a step's row groups, rows and width each; ``multi:b16:k8``),
-  ``rows_decode``/``rows_prefill`` (sequences),
+  ``rows_decode``/``rows_prefill`` (rows of the batch: a decoding sequence
+  each, a chunk of a prefilling one each) and ``seqs_prefill`` (prefilling
+  sequences: fewer than their rows where one's consecutive chunks ran ahead
+  as a run in the spare rows of its group),
   ``tokens_real`` (token positions computed for a live sequence),
   ``slots`` (positions the program computed, padding included),
   ``tokens_out`` (tokens that reached a sequence),
@@ -150,7 +153,7 @@ HOST_SEGMENTS = ("admit", "schedule", "draft_plan", "verify_plan",
                  "deliver", "overlap", "bookkeeping", "promote_wait", "vision_encode")
 
 #: what a step carried; zero until the engine notes them
-COUNTS = ("rows_decode", "rows_prefill", "tokens_real", "slots", "tokens_out",
+COUNTS = ("rows_decode", "rows_prefill", "seqs_prefill", "tokens_real", "slots", "tokens_out",
           "tokens_discarded", "expert_rows", "expert_rows_kernel", "attn_rows_visible", "attn_rows_walked",
           "ssm_rows", "window_rows_visible", "ssd_state_bytes", "mla_rows_read", "mm_tokens")
 
@@ -209,7 +212,7 @@ class StepRecord:
         self.end_ts = 0.0                    # recorder-clock time at step end
         self.cpu_s = 0.0                     # real clock: CPU the stepping thread burned in the step
         self.gc_s = 0.0                      # real clock: seconds inside Python's collector in the step
-        self.rows_decode = self.rows_prefill = 0
+        self.rows_decode = self.rows_prefill = self.seqs_prefill = 0
         self.tokens_real = self.slots = 0
         self.tokens_out = self.tokens_discarded = 0
         self.expert_rows = self.expert_rows_kernel = 0
@@ -389,8 +392,8 @@ class StepAnatomy:
         if self._cur is not None:
             self._cur.device_s += self._advance("device_wait")
 
-    def note_program(self, key: str, path: str, rows_decode: int = 0,
-                     rows_prefill: int = 0, tokens_real: int = 0, slots: int = 0) -> None:
+    def note_program(self, key: str, path: str, rows_decode: int = 0, rows_prefill: int = 0,
+                     seqs_prefill: int = 0, tokens_real: int = 0, slots: int = 0) -> None:
         """Tag the open step with the program it dispatches (``key``, as
         ``InferenceEngineV2._key_label`` prints it: the attribution key)
         and the rows and positions of the packed batch.  A step that never
@@ -401,6 +404,7 @@ class StepAnatomy:
         if cur is not None:
             cur.key, cur.path = key, path
             cur.rows_decode, cur.rows_prefill = int(rows_decode), int(rows_prefill)
+            cur.seqs_prefill = int(seqs_prefill)
             cur.tokens_real, cur.slots = int(tokens_real), int(slots)
 
     def note_encode(self, key: str, patches_real: int, patches_padded: int, reencoded: bool = False) -> None:
@@ -530,7 +534,7 @@ class StepAnatomy:
             f"host_gap_s={rec.host_gap_s:.6f} device_wait_s={rec.device_s:.6f} "
             + " ".join(f"{s}={rec.segments[s]:.6f}" for s in top) +
             f" cpu_s={rec.cpu_s:.6f} gc_s={rec.gc_s:.6f} compiles={rec.compiles} "
-            f"rows_decode={rec.rows_decode} rows_prefill={rec.rows_prefill} "
+            f"rows_decode={rec.rows_decode} rows_prefill={rec.rows_prefill} seqs_prefill={rec.seqs_prefill} "
             f"tokens_real={rec.tokens_real} slots={rec.slots}")
 
     def charge_last_step(self, dt: float) -> Optional[StepRecord]:
@@ -715,7 +719,7 @@ class NullStepAnatomy:
     def device_mark(self) -> None:
         pass
 
-    def note_program(self, key, path, rows_decode=0, rows_prefill=0, tokens_real=0, slots=0) -> None:
+    def note_program(self, key, path, rows_decode=0, rows_prefill=0, seqs_prefill=0, tokens_real=0, slots=0) -> None:
         pass
 
     def note_encode(self, key, patches_real, patches_padded, reencoded=False) -> None:

@@ -630,3 +630,101 @@ def _two_groups_against_the_rectangle(config, cfg, traffic):
     assert np.percentile(errs, 90) <= limit, errs
     assert _rel(pages_got, pages_want) <= limit
     assert groups_ms < rect_ms
+
+
+@pytest.mark.parametrize("config,traffic", [("mixtral-8x7b-serve-1chip", "long_prompt_short_answer"),
+                                            ("evabyte-6.5b-serve-1chip", "bytes_doc")])
+def test_a_run_of_four_chunks_matches_a_chunk_a_step_at_cell_widths_on_chip(config, traffic):
+    """One prompt's four consecutive chunks (128, 128, 128, 100 tokens behind
+    2,048: inside one window of EvaByte's ring) as the four rows of
+    ``((16, 1), (4, 128))`` in one step, beside eight rows that decode, against
+    the same chunks fed one a step through ``((16, 1), (1, 128))``: the logits
+    of every position of the run and of the decode rows within the cell's
+    check limit (the 90th percentile, as the check holds its positions: a
+    router near a tie picks another expert under any rounding, and a step of
+    528 slots takes the sorted form of the experts where one of 144 takes the
+    dense one), the prompt's pages the same, and the time of each program a
+    step, printed (t4 and t1 of PERF.md section 6, PR 42)."""
+    import jax
+    import jax.numpy as jnp
+    cfg, traffic = _load_cell(config, traffic)
+    sys.path.insert(0, os.path.join(os.path.dirname(__file__), "..", "..", "benchmark"))
+    import harness
+    from kinds import serve_open_loop
+
+    from deepspeed_tpu.inference.v2.engine_v2 import _table_width, build_cache_model
+    from deepspeed_tpu.models.cache_zoo import cache_geometry, cache_twin
+
+    econf = serve_open_loop.engine_config(cfg, traffic)
+    pcfg = harness.program_config(cfg)
+    _, params = harness.seeded_params(cfg, pcfg, 42, jax.devices()[:1])
+    page, chunk, width = econf.kv.page_size, econf.scheduler.prefill_chunk, _table_width(pcfg, econf.kv)
+    twin = build_cache_model(pcfg, page)
+    assert cache_geometry(pcfg, page).chunk_runs
+    longest = (width - 2) * page if cache_geometry(pcfg, page).pages_immutable else 24000
+    decoding = [(int(300 + (longest - 300) * i / 7), 1 + i * width + np.arange(width)) for i in range(8)]
+    start0, lens_run = 16 * chunk, [chunk, chunk, chunk, chunk - 28]
+    prompt_table = 1 + 8 * width + np.arange(width)
+    kv = econf.kv.__class__(num_pages=1 + 9 * width, page_size=page, max_pages_per_seq=econf.kv.max_pages_per_seq)
+    shape = jax.eval_shape(lambda: cache_twin(pcfg).init_cache(pcfg, kv, econf.kv_dtype, 17, chunk))
+    fresh = jax.jit(lambda: (0.5 * jax.random.normal(jax.random.PRNGKey(1), shape.shape, shape.dtype)).at[:, 0].set(0))
+    rng = np.random.default_rng(42)
+    ids_decode, ids_prompt = rng.integers(1, cfg["vocab_size"], 8), rng.integers(1, cfg["vocab_size"], sum(lens_run))
+    prompt_pages = prompt_table[:(start0 + sum(lens_run)) // page + 1]
+
+    def batch(rows, chunks):
+        """The arrays of ``((16, 1), (rows, chunk))`` with the prompt's ``chunks`` (indices of ``lens_run``) in its rows."""
+        toks, start = np.zeros((16 + rows * chunk, ), np.int32), np.zeros((16 + rows, ), np.int32)
+        tables, lens = np.zeros((16 + rows, width), np.int32), np.zeros((16 + rows, ), np.int32)
+        for i, (ctx, pages) in enumerate(decoding):
+            toks[i], start[i], lens[i], tables[i] = ids_decode[i], ctx, 1, pages
+        for j, c in enumerate(chunks):
+            at = sum(lens_run[:c])
+            toks[16 + j * chunk:16 + j * chunk + lens_run[c]] = ids_prompt[at:at + lens_run[c]]
+            start[16 + j], lens[16 + j], tables[16 + j] = start0 + at, lens_run[c], prompt_table
+        return tuple(map(jnp.asarray, (toks, start, tables, lens)))
+
+    def program(rows):
+        groups = ((16, 1), (rows, chunk))
+        return jax.jit(lambda p, c, toks, start, tables, lens: twin.apply(p, toks, start, tables, c, lens, False, groups),
+                       donate_argnums=1)
+
+    def timed(fn, args, arena):
+        _, arena = fn(params, arena, *args)           # the arena a program hands back: a second trace (as above)
+        jax.block_until_ready(arena)
+        t0 = time.perf_counter()
+        for _ in range(10):
+            out, arena = fn(params, arena, *args)
+        jax.block_until_ready(out)
+        return (time.perf_counter() - t0) / 10 * 1e3
+
+    def live(logits, chunks):
+        """[positions, vocab]: the decode rows' logits, then those of the chunks' tokens."""
+        logits = np.asarray(logits, np.float32)
+        return np.concatenate([logits[:8]] + [logits[16 + j * chunk:16 + j * chunk + lens_run[c]]
+                                              for j, c in enumerate(chunks)])
+
+    four, one = program(4), program(1)
+    logits, arena = four(params, fresh(), *batch(4, [0, 1, 2, 3]))
+    got = live(logits, [0, 1, 2, 3])
+    pages_got = np.asarray(arena[:, prompt_pages], np.float32)
+    t4 = timed(four, batch(4, [0, 1, 2, 3]), arena)
+    arena, want = fresh(), []
+    for c in range(4):
+        logits, arena = one(params, arena, *batch(1, [c]))
+        want.append(live(logits, [c])[0 if c == 0 else 8:])
+    want = np.concatenate(want)
+    pages_want = np.asarray(arena[:, prompt_pages], np.float32)
+    t1 = timed(one, batch(1, [3]), arena)
+    del logits
+    errs = np.asarray([_rel(g, w) for g, w in zip(got, want)])
+    limit = min(cfg["check"]["limits"].values())
+    print(f"\n{config}: a chunk a step ((16, 1), (1, {chunk})) t1 {t1:.2f} ms, a run of four ((16, 1), (4, {chunk})) t4 "
+          f"{t4:.2f} ms; logits run against chunk a step, ||d|| / ||ref||: decode rows p90 "
+          f"{np.percentile(errs[:8], 90):.5f} max {errs[:8].max():.5f}, the run's {errs.size - 8} positions p50 "
+          f"{np.median(errs[8:]):.5f} p90 {np.percentile(errs[8:], 90):.5f} max {errs[8:].max():.5f}, its last "
+          f"{errs[-1]:.5f} (limit {limit}); pages {_rel(pages_got, pages_want):.5f}")
+    assert np.isfinite(got).all() and got.shape == want.shape
+    assert np.percentile(errs[8:], 90) <= limit and np.percentile(errs[:8], 90) <= limit, errs
+    assert _rel(pages_got, pages_want) <= limit
+    assert t4 < 4 * t1

@@ -13,6 +13,8 @@ import json
 import os
 import sys
 
+import pytest
+
 sys.path.insert(0, os.path.dirname(__file__))
 import xing4_check  # noqa: E402
 
@@ -32,12 +34,19 @@ def _load(folder, name):
         return json.load(f)
 
 
-def test_the_stream_mix_the_selection_bias_and_the_rotary_score_are_held_at_8k_on_scattered_pages():
+@pytest.mark.parametrize("runs", [True, False], ids=["runs_of_four_chunks", "a_chunk_a_step"])
+def test_the_stream_mix_the_selection_bias_and_the_rotary_score_are_held_at_8k_on_scattered_pages(runs):
+    """``runs``: the long prompt's consecutive chunks in the spare rows of the
+    rung of four (three beside the short prompt's row, four once that
+    decodes), as the engine feeds a prompt since PR 42; else a chunk a step."""
     config, traffic = _load("configs", "xing4.0-29b-a4b-serve-1chip"), _load("traffic", "doc_8k_32k_short_answer")
-    out = xing4_check.readings(config, traffic, int(os.environ.get("DS_CHECK_SEED", 3000037001)), ROWS)
+    out = xing4_check.readings(config, traffic, int(os.environ.get("DS_CHECK_SEED", 3000037001)), ROWS, runs=runs)
     per_row = xing4_check.report(out, ROWS, MARGIN_MIN)
     limit = min(config["check"]["limits"].values())
-    assert out["mixed_steps"] >= 60 and all(clear >= 10 for _, clear, _ in per_row), per_row
+    # a chunk a step: the short row decodes beside 61 of the long prompt's 67 chunks; with runs the long prompt takes
+    # three rows a step beside the short prompt's six chunks and four a step beside its decode steps: 6 + 13 steps
+    assert out["mixed_steps"] >= (12 if runs else 60) and all(clear >= 10 for _, clear, _ in per_row), per_row
+    assert (out["run_steps"] >= 18) == runs, out["run_steps"]
     # at 8k of context the program is inside the limit the cell holds it to, and that limit calls every mutilated reference
     program, _, changed = per_row[0]
     assert program < limit and all(change > limit for change in changed.values()), per_row

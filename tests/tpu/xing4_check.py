@@ -93,13 +93,15 @@ def without(params, kind: str, config: dict):
     return jax.tree_util.tree_map_with_path(change, params)
 
 
-def readings(config: dict, traffic: dict, seed: int, rows: list) -> dict:
+def readings(config: dict, traffic: dict, seed: int, rows: list, runs: bool = False) -> dict:
     """``rows``: (prompt tokens, decode tokens, first position compared) a
     sequence.  Every row goes through the engine's own twin, weights and arena
     in one batch on pages drawn at random: SplitFuse chunks, then one token a
     step; a step that carries both is two row groups on one flat axis, the
     rows of one token at most at one slot each beside the chunks, as the
-    engine lays a mixed step out.  Returns ``program``: per row ``||logits -
+    engine lays a mixed step out; with ``runs``, a prompt's consecutive chunks
+    in the spare rows of the rung of four, as the scheduler hands them out
+    (``run_steps`` counts the steps that held one).  Returns ``program``: per row ``||logits -
     ref|| / ||ref||`` of the positions compared, against the float32 reference
     on the same weights; ``changed``: per kind and row, the same distance
     between the mutilated reference and the whole one; ``mixed_steps``."""
@@ -132,30 +134,44 @@ def readings(config: dict, traffic: dict, seed: int, rows: list) -> dict:
                    static_argnames="groups")
 
     pos, got = [0] * len(rows), [[] for _ in rows]
-    out = {"steps": 0, "mixed_steps": 0}
+    out = {"steps": 0, "mixed_steps": 0, "run_steps": 0}
+    run_rows = eng.scheduler.run_rows if runs else 1
     while any(pos[i] < len(toks[i]) for i in range(len(rows))):
-        lens = [min(chunk, p - pos[i]) if pos[i] < p else int(pos[i] < p + d) for i, (p, d, _) in enumerate(rows)]
-        wide = [i for i, n in enumerate(lens) if n > 1]
-        one = [i for i, n in enumerate(lens) if n <= 1]
+        # a chunk a prefilling row, then (``runs``) the spare rows of the rung in order, as the scheduler plans
+        fed = [min(chunk, p - pos[i]) if pos[i] < p else int(pos[i] < p + d) for i, (p, d, _) in enumerate(rows)]
+        spare = run_rows - sum(n > 1 for n in fed)
+        for i, (p, _, _) in enumerate(rows):
+            if fed[i] == chunk and spare > 0:
+                fed[i] = min(p - pos[i], (1 + spare) * chunk)
+                spare -= -(-fed[i] // chunk) - 1
+        # (row, first position, tokens) of each row of the step: a run is a row a chunk through the same pages
+        wide = [(i, pos[i] + at, min(chunk, fed[i] - at)) for i in range(len(rows)) if fed[i] > 1
+                for at in range(0, fed[i], chunk)]
+        one = [(i, pos[i], fed[i]) for i in range(len(rows)) if fed[i] <= 1]
+        if len(wide) > len({i for i, _, _ in wide}):
+            wide += [(None, 0, 0)] * (run_rows - len(wide))       # the rung's padding rows
+            out["run_steps"] += 1
         layout = [(one, 1), (wide, chunk)] if wide and one else [(wide or one, chunk if wide else 1)]
-        order = [i for members, _ in layout for i in members]
-        flat = []
+        flat, order = [], [entry for members, _ in layout for entry in members]
         for members, width in layout:
             rect = np.zeros((len(members), width), np.int32)
-            for j, i in enumerate(members):
-                rect[j, :lens[i]] = toks[i][pos[i]:pos[i] + lens[i]]
+            for j, (i, start, n) in enumerate(members):
+                if n:
+                    rect[j, :n] = toks[i][start:start + n]
             flat.append(rect.reshape(-1))
         groups = tuple((len(members), width) for members, width in layout)
         logits, eng.cache = step(eng.params, eng.cache, jnp.asarray(np.concatenate(flat)),
-                                 jnp.asarray([pos[i] for i in order], jnp.int32), jnp.asarray(tables[order]),
-                                 jnp.asarray([lens[i] for i in order], jnp.int32), groups=groups)
+                                 jnp.asarray([start for _, start, _ in order], jnp.int32),
+                                 jnp.asarray(np.stack([tables[i] if i is not None else 0 * tables[0] for i, _, _ in order])),
+                                 jnp.asarray([n for _, _, n in order], jnp.int32), groups=groups)
         t0 = 0
         for members, width in layout:
-            for i in members:
-                skip = max(rows[i][2] - pos[i], 0)
-                if skip < lens[i]:
-                    got[i].append(logits[t0 + skip:t0 + lens[i]].astype(jnp.float32))
-                pos[i] += lens[i]
+            for i, start, n in members:
+                if i is not None:
+                    skip = max(rows[i][2] - start, 0)
+                    if skip < n:
+                        got[i].append(logits[t0 + skip:t0 + n].astype(jnp.float32))
+                    pos[i] += n
                 t0 += width
         out["steps"] += 1
         out["mixed_steps"] += len(layout) == 2
@@ -193,5 +209,6 @@ def report(out: dict, rows: list, margin_min: float) -> list:
     for kind, changed in out["changed"].items():
         print(f"xing4_check: without={kind} " + " ".join(
             f"row{i}:p10={np.percentile(e, 10):.6f},p50={np.median(e):.6f}" for i, e in enumerate(changed)), flush=True)
-    print(f"xing4_check: steps={out['steps']} mixed_steps={out['mixed_steps']}", flush=True)
+    print(f"xing4_check: steps={out['steps']} mixed_steps={out['mixed_steps']} run_steps={out.get('run_steps', 0)}",
+          flush=True)
     return per_row

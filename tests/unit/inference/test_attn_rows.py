@@ -82,6 +82,41 @@ def test_a_linear_engines_records_fill_both_counts(attention_impl):
             assert r["attn_rows_walked"] == 0 < r["attn_rows_visible"], r
 
 
+def test_a_run_is_counted_row_by_row():
+    """A prompt of 150 tokens in chunks of 32 goes as a run of four rows and
+    one of 22 tokens where the engine has a rung of four prefill rows
+    (``max_seqs`` 8).  Each row walks the cache to its own end, so the steps'
+    ``attn_rows_walked`` and ``attn_rows_visible`` are the sums over the same
+    five chunks fed one a step (a run counted as one call would have every
+    token walk to the run's end; ``test_xing4_twin.py`` has the case where that
+    shows).  The records alone: no program runs (``_invoke`` hands back zeros)."""
+    cfg = LlamaConfig(vocab_size=128, hidden_size=64, intermediate_size=128, num_hidden_layers=2,
+                      num_attention_heads=4, num_key_value_heads=2, max_position_embeddings=512,
+                      dtype=jnp.float32, scan_layers=True, remat=False, attention_impl="flash")
+    params = nn.meta.unbox(LlamaForCausalLM(cfg).init(jax.random.PRNGKey(0), jnp.zeros((1, 8), jnp.int32)))
+    totals = {}
+    for run_rows in (4, 1):
+        eng = InferenceEngineV2(cfg, params, RaggedInferenceEngineConfig(
+            kv=PagedKVConfig(num_pages=64, page_size=8, max_pages_per_seq=24),
+            scheduler=SchedulerConfig(token_budget=256, max_seqs=8, prefill_chunk=32, decode_bucket=8),
+            max_new_tokens=1, enable_prefix_cache=False, decode_steps_per_dispatch=1, kv_dtype=jnp.float32))
+        assert eng.scheduler.run_rows == 4 and eng._walk_rows() == 128
+        eng.scheduler.run_rows = run_rows
+        eng._compiled_step = lambda groups: None
+        eng._invoke = lambda fn, params, cache, tokens, start_pos, *rest: (np.zeros(start_pos.shape, np.int32), cache)
+        eng.put([0], [np.arange(1, 151).tolist()])
+        while not eng.state.seqs[0].done:
+            eng.step()
+        rows = [r.to_row() for r in eng.anatomy.steps]
+        assert [(r["rows_prefill"], r["tokens_real"]) for r in rows] == (
+            [(4, 128), (1, 22)] if run_rows == 4 else [(1, 32)] * 4 + [(1, 22)])
+        totals[run_rows] = (sum(r["attn_rows_visible"] for r in rows), sum(r["attn_rows_walked"] for r in rows))
+    chunks = [(0, 32), (32, 32), (64, 32), (96, 32), (128, 22)]
+    by_hand = [LinearGeometry(8).step_counts(start, n, 128) for start, n in chunks]
+    assert totals[4] == totals[1] == (150 * 151 // 2, sum(walked for _, walked in by_hand))
+    assert totals[4][1] == 32 * 128 * 4 + 22 * 256
+
+
 @pytest.mark.parametrize("k", [1, 4])
 def test_the_counts_are_noted_after_the_enqueue_and_read_the_same(k):
     """The program's key and rows are on the open step before ``_invoke``

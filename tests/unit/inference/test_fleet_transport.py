@@ -301,7 +301,7 @@ def test_publish_gap_detected_and_resynced(trained_params):
         [dict(prompt=prefix + list(range(60, 60 + PAGE)) + [99],
               max_new_tokens=4, arrival_ts=0.0),
          dict(prompt=prefix + list(range(70, 70 + PAGE)) + [88],
-              max_new_tokens=4, arrival_ts=8.0)])
+              max_new_tokens=6, arrival_ts=8.0)])    # long enough for the gap's timeout to pass in the run
     tr.send = real_send
     assert all(r.state is FleetState.DONE for r in reqs2)
     assert eaten, "the drop hook never fired"

@@ -27,8 +27,8 @@ PH = 500
 BUCKETS = (16, 32, 64)
 
 
-def engine(cfg, params, per_tick=64, rows=64, **over):
-    sched = SchedulerConfig(token_budget=64, max_seqs=4, prefill_chunk=16, decode_bucket=4,
+def engine(cfg, params, per_tick=64, rows=64, max_seqs=4, **over):
+    sched = SchedulerConfig(token_budget=64, max_seqs=max_seqs, prefill_chunk=16, decode_bucket=max_seqs,
                             vision_patch_buckets=list(BUCKETS), vision_patches_per_tick=per_tick, vision_rows=rows)
     econf = RaggedInferenceEngineConfig(kv=PagedKVConfig(num_pages=64, page_size=16, max_pages_per_seq=8),
                                         scheduler=sched, kv_dtype=jnp.float32, decode_steps_per_dispatch=1, **over)
@@ -92,6 +92,35 @@ def test_images_through_submit_and_tick_give_the_full_models_tokens(kimi):
     names = [s.name for s in tracer.finished()]
     assert names.count("serving/vision_encode") == 3 and names.count("phase/vision_encode") == 2
     assert metrics.counter("serving/vision_images").value == 3 and metrics.counter("serving/vision_patches_padded").value == 112
+
+
+def test_an_image_whose_rows_straddle_two_rows_of_a_run(kimi):
+    """A prompt of 47 positions alone in prefill goes in one step, as three
+    rows of the rung of four (``max_seqs`` 8: rungs 1, 4, 8); its image's 12
+    rows lie at positions 5-16, the end of the run's first row and the start
+    of its second.  The tokens are the full model's and those of a chunk a
+    step, and the units go back when the run has passed the image."""
+    cfg, _, model, params = kimi
+    prompt, images = request(np.random.default_rng(5), [(8, 6)], text=(5, 3, 30))
+    assert len(prompt) == 47 and prompt[5:17] == [PH] * 12
+    served = {}
+    eng = engine(cfg, params, max_seqs=8, enable_prefix_cache=False)       # the second pass feeds the prompt again
+    assert eng.scheduler.run_rows == 4
+    serve = ServingEngine(eng)
+    for run_rows in (4, 1):
+        eng.scheduler.run_rows = run_rows
+        first = len(eng.anatomy.steps)
+        req = serve.submit(prompt, max_new_tokens=4, images=images)
+        serve.drain(max_ticks=50)
+        assert req.state is RequestState.DONE
+        rows = [r.to_row() for r in list(eng.anatomy.steps)[first:] if r.rows_prefill]
+        assert [(r["key"], r["rows_prefill"], r["seqs_prefill"], r["tokens_real"], r["mm_tokens"]) for r in rows] == (
+            [("step:b8:c1:b4:c16", 3, 1, 47, 12)] if run_rows == 4 else
+            [("step:b8:c1:b1:c16", 1, 1, n, mm) for n, mm in ((16, 11), (16, 1), (15, 0))])
+        assert eng.mm_alloc.free_pages == eng.mm_alloc.num_pages - 1
+        served[run_rows] = list(req.tokens)
+    # the token behind the run's last row is the full model's (the other test holds four tokens of a chunk a step)
+    assert served[4] == served[1] and served[4][:1] == greedy(model, params, prompt, images, 1)
 
 
 def test_the_three_rejections_and_a_model_without_a_tower(kimi):

@@ -106,7 +106,7 @@ def test_export_import_pages_roundtrip_and_validation(trained_params):
 def test_snapshot_crc_and_completeness(trained_params):
     eng = _factory(trained_params)()
     eng.put([0], [PROMPTS[2]], max_new_tokens=6)
-    for _ in range(6):
+    for _ in range(5):                               # the prompt in one step (a run of two chunks), four tokens more
         eng.step()
     seq = eng.state.seqs[0]
     seq.paused = True
@@ -125,7 +125,7 @@ def test_snapshot_crc_and_completeness(trained_params):
 def test_exporter_aborts_when_source_changes(trained_params):
     eng = _factory(trained_params)()
     eng.put([0], [PROMPTS[2]], max_new_tokens=6)
-    for _ in range(6):
+    for _ in range(5):                               # the prompt in one step (a run of two chunks), four tokens more
         eng.step()
     eng.state.seqs[0].paused = True
     exporter = KVExporter(eng, 0, chunk_pages=1)
@@ -138,7 +138,7 @@ def test_exporter_aborts_when_source_changes(trained_params):
 def test_import_rejections_leak_nothing(trained_params):
     src = _factory(trained_params)()
     src.put([0], [PROMPTS[2]], max_new_tokens=6)
-    for _ in range(6):
+    for _ in range(5):
         src.step()
     seq = src.state.seqs[0]
     seq.paused = True
@@ -269,9 +269,9 @@ def test_paused_sequence_takes_no_steps_and_pages_stay_stable(trained_params):
 def test_begin_migration_windows(trained_params):
     a = _serve(trained_params, prefill_chunk=8)
     assert a.begin_migration(999) is None            # unknown uid
-    long_prompt = [int(x) for x in np.random.default_rng(3).integers(1, 100, 40)]
+    long_prompt = [int(x) for x in np.random.default_rng(3).integers(1, 100, 72)]
     req = a.submit(long_prompt, max_new_tokens=6)
-    a.tick()                                          # admit + first chunk
+    a.tick()                                          # admit + the first run of four chunks
     seq = a.engine.state.seqs[req.uid]
     assert req.state is RequestState.PREFILL
     # too early: more than one chunk of prefill remains

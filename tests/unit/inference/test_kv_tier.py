@@ -220,10 +220,12 @@ def test_parked_resume_cheaper_than_evicted_recompute(trained_params):
     p2 = [int(x) for x in rng.integers(1, 100, 9)]
 
     def run(with_tier):
+        # max_seqs 4: no rung of four prefill rows under max_seqs, so a resumed
+        # prompt is recomputed a chunk a step (a run would feed it in one)
         if with_tier:
-            serve, tier = _serve(trained_params, num_pages=8)
+            serve, tier = _serve(trained_params, num_pages=8, max_seqs=4)
         else:
-            serve = ServingEngine(_engine(trained_params, num_pages=8),
+            serve = ServingEngine(_engine(trained_params, num_pages=8, max_seqs=4),
                                   clock=VirtualClock(), config=ServingConfig())
             tier = None
         a = serve.submit(p1, max_new_tokens=20)
@@ -422,7 +424,7 @@ def test_device_watermark_demotes_cold_prefix_with_hysteresis(trained_params):
     so back-to-back sweeps cannot thrash."""
     cfg = TierConfig(host_capacity_pages=64,
                      device_watermark_hi=0.08, device_watermark_lo=0.03)
-    serve, tier = _serve(trained_params, tier_config=cfg)
+    serve, tier = _serve(trained_params, tier_config=cfg, max_seqs=4)   # a chunk a sequence and step
     # three finished prompts leave ~6 cold prefix pages device-side
     for i in range(3):
         serve.submit(list(range(10 * i + 1, 10 * i + 2 * PAGE + 1)),

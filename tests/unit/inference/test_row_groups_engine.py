@@ -148,6 +148,33 @@ def test_generate_emits_the_row_at_a_time_streams(family):
     assert eng.generate(prompts) == want
     rows = _two_group_rows(eng.anatomy)
     assert rows and any(r["rows_decode"] and r["rows_prefill"] for r in rows), "no mixed step ran in two groups"
+    # once fewer than four prompts are left in prefill, the long ones run ahead where the geometry lets them
+    assert any(r["rows_prefill"] > r["seqs_prefill"] for r in rows) == eng.kv.geometry.chunk_runs
+
+
+@pytest.mark.parametrize("name", ["mixtral", "evabyte"])
+def test_runs_give_the_streams_of_a_chunk_a_step_in_fewer_steps(name):
+    """A prompt of 300 tokens (over EvaByte's window of 256: a run ends where
+    the window ends and the next starts behind it) and one of 41, fed in runs
+    and, with the scheduler's ``run_rows`` at 1, a chunk a sequence and step
+    as every engine fed them: both give the row-at-a-time streams, runs in
+    fewer steps, and every token sees the rows it saw (``attn_rows_visible``)."""
+    cfg = CONFIGS[name]
+    twin, params = _params(cfg)
+    prompts = [np.random.default_rng(5).integers(1, cfg.vocab_size, n).tolist() for n in (300, 41)]
+    want = _row_at_a_time(cfg, twin, params, prompts)
+    steps, visible = {}, {}
+    for run_rows in (4, 1):
+        eng = _engine(cfg, params)
+        assert eng.scheduler.run_rows == 4 and eng.kv.geometry.chunk_runs
+        eng.scheduler.run_rows = run_rows
+        assert eng.generate(prompts) == want
+        rows = [s.to_row() for s in eng.anatomy.steps]
+        assert all(r["rows_prefill"] <= 4 for r in rows)
+        assert any(r["rows_prefill"] > r["seqs_prefill"] for r in rows) == (run_rows == 4)
+        steps[run_rows] = sum(1 for r in rows if r["rows_prefill"])
+        visible[run_rows] = sum(r["attn_rows_visible"] for r in rows)
+    assert visible[4] == visible[1] and steps[4] < steps[1]
 
 
 @pytest.mark.parametrize("async_dispatch", [False, True])
@@ -167,6 +194,8 @@ def test_both_serving_ticks_emit_the_row_at_a_time_streams_and_compile_nothing(f
     assert set(eng._step_fns) == programs and anat.steady_state_recompiles == 0
     assert sum(r.compiles for r in anat.steps) == 0
     assert _two_group_rows(anat)
+    # runs among them: a program of the step set each, the rung of four
+    assert any(r.rows_prefill > r.seqs_prefill for r in anat.steps) == eng.kv.geometry.chunk_runs
 
 
 def test_the_step_record_of_a_two_group_step(family):
@@ -177,23 +206,29 @@ def test_the_step_record_of_a_two_group_step(family):
         eng.step()
     eng.put([2], [prompts[1]])                      # 70 tokens: chunks of 16 beside two decoding rows
     plan = eng.scheduler.plan(eng.state)
-    assert len(plan.decode) == 2 and [n for _, n in plan.prefill] == [CHUNK]
-    eng.step(plan)
-    row = eng.anatomy.last_step.to_row()
     bucket = _sched(cfg).decode_bucket
-    assert row["key"] == f"step:b{bucket}:c1:b1:c16" and row["path"] == "mixed"
-    assert row["slots"] == bucket + 1 * CHUNK and row["tokens_real"] == plan.planned_tokens == 2 + CHUNK
-    assert (row["rows_decode"], row["rows_prefill"]) == (2, 1)
+    # where the geometry lets a sequence's chunks share a step, what the budget has left
+    # of the rung of four rows: 16 + 16 + 4 of 40 - 4; a slot-holding twin's one chunk
+    runs = eng.kv.geometry.chunk_runs
+    fed, rows = (SCHED.token_budget - bucket, 3) if runs else (CHUNK, 1)
+    rung = 4 if runs else 1
+    assert len(plan.decode) == 2 and [n for _, n in plan.prefill] == [fed]
+    eng.step(plan)
+    assert eng.state.seqs[2].seen_tokens == fed and not eng.state.seqs[2].generated
+    row = eng.anatomy.last_step.to_row()
+    assert row["key"] == f"step:b{bucket}:c1:b{rung}:c16" and row["path"] == "mixed"
+    assert row["slots"] == bucket + rung * CHUNK and row["tokens_real"] == plan.planned_tokens == 2 + fed
+    assert (row["rows_decode"], row["rows_prefill"], row["seqs_prefill"]) == (2, rows, 1)
     # the key survives _named -> the lowered module's name -> benchmark/step_trace.program_key
-    module = eng._aot_lower(((bucket, 1), (1, CHUNK))).as_text()[:400]
-    assert f"jit_ds_step_b{bucket}_c1_b1_c16" in module
-    assert step_trace.program_key(f"jit_ds_step_b{bucket}_c1_b1_c16(1234)") == row["key"]
+    module = eng._aot_lower(((bucket, 1), (rung, CHUNK))).as_text()[:400]
+    assert f"jit_ds_step_b{bucket}_c1_b{rung}_c16" in module
+    assert step_trace.program_key(f"jit_ds_step_b{bucket}_c1_b{rung}_c16(1234)") == row["key"]
     for _ in range(7):                               # the prompt's other four chunks, then one-token steps
         eng.step()
     rows = [s.to_row() for s in eng.anatomy.steps]
     mixed = sum(1 for r in rows if r["key"] != f"step:b{bucket}:c1")
     assert step_trace.mixed_step_share(rows) == pytest.approx(mixed / len(rows)) and 3 <= mixed < len(rows)
-    assert step_trace.slot_fill_share([row]) == pytest.approx((2 + CHUNK) / (bucket + CHUNK))
+    assert step_trace.slot_fill_share([row]) == pytest.approx((2 + fed) / (bucket + rung * CHUNK))
 
 
 @pytest.mark.parametrize("name", ["phi4flash", "granitehybrid"])
@@ -275,7 +310,9 @@ def test_every_plan_of_a_cells_scheduler_maps_to_a_key_of_the_step_set(cell, twi
         packed = eng._step_groups(plan)
         groups = tuple((rows, width) for _, rows, width in packed)
         assert groups in keys, (groups, len(plan.decode), plan.prefill)
-        assert all(len(work) <= rows and all(n <= width for _, n in work) for work, rows, width in packed)
+        # a row a chunk: a run of one sequence's chunks takes several, and only inside the rung of four
+        assert all(sum(-(-n // width) for _, n in work) <= rows for work, rows, width in packed)
+        assert all(n <= width for work, _, width in packed for _, n in work) or packed[-1][1] == 4
         assert sum(len(work) for work, _, _ in packed) == len(plan.decode) + len(plan.prefill)
         assert sum(n for work, _, _ in packed for _, n in work) == plan.planned_tokens
         seen.add(groups)

@@ -295,8 +295,7 @@ def test_multi_decode_capacity_capped_at_remaining(trained_params):
                   enable_prefix_cache=False, decode_steps_per_dispatch=8)
     prompt = [5, 9, 2, 7, 1, 3, 3, 8, 4, 2, 6, 1]        # 12 tokens
     eng.put([0], [prompt], max_new_tokens=2)
-    eng.step()                                           # prefill chunk 1 (8 tokens)
-    eng.step()                                           # prefill tail, emits token 1
+    eng.step()                                           # the prompt's two chunks as a run: emits token 1
     seq = eng.state.seqs[0]
     assert not seq.done and len(seq.generated) == 1
     eng.step()                                           # fused rung, remaining=1

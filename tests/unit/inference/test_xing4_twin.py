@@ -26,7 +26,8 @@ def _feed(params, rows, steps, tables, attention_impl="reference"):
     """Feed ``rows`` through the twin.  A step is a list of groups, a group a
     list of ``(row, tokens)`` fed as one rectangle, ``CHUNK`` wide or, where
     no row carries more than a token, 1; a step of more than one group goes
-    as row groups on one flat axis.  Per row the logits of every position
+    as row groups on one flat axis; a row named more than once in a group is
+    a run, its chunks one behind the other through the same block-table row.  Per row the logits of every position
     fed, and the cache."""
     twin = Xing4ForCausalLMWithCache(dataclasses.replace(CFG, attention_impl=attention_impl), page_size=PAGE)
     cache = init_cache(CFG, KV, jnp.float32)
@@ -36,16 +37,18 @@ def _feed(params, rows, steps, tables, attention_impl="reference"):
     with jax.default_matmul_precision("highest"):
         for step in steps:
             groups = tuple((len(g), 1 if max(n for _, n in g) <= 1 else CHUNK) for g in step)
-            toks, order = [], [r for g in step for r, _ in g]
+            toks, order, starts, at = [], [r for g in step for r, _ in g], [], list(pos)
             for g, (_, width) in zip(step, groups):
                 rect = np.zeros((len(g), width), np.int32)
-                for j, (r, n) in enumerate(g):
-                    rect[j, :n] = rows[r][pos[r]:pos[r] + n]
+                for j, (r, n) in enumerate(g):      # a row named again is the next chunk of its run
+                    rect[j, :n] = rows[r][at[r]:at[r] + n]
+                    starts.append(at[r])
+                    at[r] += n
                 toks.append(rect.reshape(-1))
             lens = [n for g in step for _, n in g]
             flat = jnp.asarray(np.concatenate(toks))
             logits, cache = apply(cache, flat if len(groups) > 1 else flat.reshape(groups[0]),
-                                  jnp.asarray([pos[r] for r in order], jnp.int32), jnp.asarray(tables[order]),
+                                  jnp.asarray(starts, jnp.int32), jnp.asarray(tables[order]),
                                   jnp.asarray(lens, jnp.int32), groups=groups if len(groups) > 1 else None)
             logits, t0 = np.asarray(logits).reshape(-1, logits.shape[-1]), 0
             for g, (_, width) in zip(step, groups):
@@ -85,6 +88,22 @@ def test_a_mixed_step_in_two_row_groups_matches_reference(params, ids, want, att
         [[[(0, 1), (1, 1)], [(2, 13)]]] + [[[(0, 1), (1, 1), (2, 1)]]] * 4
     got, _ = _feed(params, ids, steps, _tables(3), attention_impl)
     assert [len(g) for g in got] == [41 + 8, 25 + 8, 96 + 13 + 4]
+    for i, g in enumerate(got):
+        np.testing.assert_allclose(g, want[i][:len(g)], atol=TOL)
+
+
+@pytest.mark.parametrize("attention_impl", ["reference"])   # the kernel under a run: tests/tpu/test_xing4_on_chip.py
+def test_a_run_of_one_prompts_chunks_in_one_step_matches_reference(params, ids, want, attention_impl):
+    """Row 2's prompt as consecutive chunks in the rows of one prefill group,
+    beside two rows that decode: ``((2, 1), (4, 32))`` with row 2 in three of
+    the four rows (the last ends inside a page and inside its chunk), then in
+    one row more.  Every position's logits are the reference's, the last
+    prompt position's among them, and so are those of the decode steps that
+    read the pages the run wrote."""
+    steps = [[[(0, 32), (1, 25)]], [[(0, 9), (1, 0)]], [[(0, 1), (1, 1)], [(2, 32), (2, 32), (2, 27), (1, 0)]],
+             [[(0, 1), (1, 1)], [(2, 18)]]] + [[[(0, 1), (1, 1), (2, 1)]]] * 2
+    got, _ = _feed(params, ids, steps, _tables(3), attention_impl)
+    assert [len(g) for g in got] == [41 + 4, 25 + 4, 109 + 2]
     for i, g in enumerate(got):
         np.testing.assert_allclose(g, want[i][:len(g)], atol=TOL)
 
@@ -151,6 +170,36 @@ def test_engine_serves_two_row_groups_with_the_prefix_cache_on(params, ids):
             assert r["mla_rows_read"] == r["attn_rows_visible"]
     # through the kernel the walk is the latent kernel's own, not ds_paged_attention's reading of the arena's shape
     assert _engine(params, attention_impl="flash")._walk_rows() == KV.max_pages_per_seq * PAGE == walk_rows(PAGE, 13)
+
+
+def test_engine_feeds_a_prompt_in_runs_and_counts_its_rows_one_by_one(params, ids):
+    """``max_seqs`` 8: rungs of 1, 4 and 8 prefill rows, so a prompt alone in
+    prefill takes the rung of four.  100 tokens are one step of four rows (32,
+    32, 32, 4) where they were four steps; the tokens are the full-sequence
+    model's, ``mla_rows_read`` and ``attn_rows_visible`` are the sums over the
+    same chunks fed one a step (each row reads to its own end, not the run's),
+    and the prefix cache holds the same pages."""
+    sched = SchedulerConfig(token_budget=160, max_seqs=8, prefill_chunk=CHUNK, decode_bucket=8)
+    prompt = ids[0, :100].tolist()
+    seen = {}
+    for run_rows in (4, 1):
+        eng = _engine(params, scheduler=sched)
+        assert eng.scheduler.run_rows == 4 and eng.kv.geometry.chunk_runs
+        eng.scheduler.run_rows = run_rows
+        with jax.default_matmul_precision("highest"):
+            eng.put([0], [prompt], max_new_tokens=6)
+            while not eng.state.seqs[0].done:
+                eng.step()
+        rows = [r.to_row() for r in eng.anatomy.steps if r.rows_prefill]
+        assert [(r["key"], r["rows_prefill"], r["seqs_prefill"], r["tokens_real"]) for r in rows] == (
+            [(f"step:b8:c1:b4:c{CHUNK}", 4, 1, 100)] if run_rows == 4 else
+            [(f"step:b8:c1:b1:c{CHUNK}", 1, 1, n) for n in (32, 32, 32, 4)])
+        assert sum(r["mla_rows_read"] for r in rows) == 32 + 64 + 96 + 100
+        assert sum(r["attn_rows_visible"] for r in rows) == 100 * 101 // 2
+        cache = eng.kv.prefix_cache
+        seen[run_rows] = (list(eng.state.seqs[0].generated), cache.held_digests(), eng.state.seqs[0].pages[:6])
+    assert seen[4] == seen[1] and seen[4][0] == _greedy(params, prompt, 6, width=112)
+    assert len(seen[4][1]) == (100 + 5) // PAGE
 
 
 def test_step_records_count_the_latent_rows_a_call_reads():

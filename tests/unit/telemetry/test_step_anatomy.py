@@ -409,7 +409,7 @@ def test_annotate_factory_sees_step_and_marks_nested_with_counts():
         ("open", "ds.mark.sample_accept"), ("close", "ds.mark.sample_accept"),
         ("close", "ds.step")]
     assert log[-1][2] == {"index": 0, "key": "multi:b4:k8", "rows_decode": 3,
-                          "rows_prefill": 0, "tokens_real": 24, "slots": 32,
+                          "rows_prefill": 0, "seqs_prefill": 0, "tokens_real": 24, "slots": 32,
                           "tokens_out": 20, "tokens_discarded": 4,
                           "expert_rows": 50, "expert_rows_kernel": 42,
                           "attn_rows_visible": 0, "attn_rows_walked": 0,
