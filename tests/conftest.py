@@ -9,13 +9,29 @@ settings are made here, before anything uses JAX.
 """
 
 import os
+import shutil
+import tempfile
+import time
 
-import jax
+# One compile cache a run.  Every engine jits closures of its own, so JAX's
+# in-memory cache (keyed by function) compiles the same HLO again for each;
+# the persistent cache is keyed by the HLO and finds it, in this process and
+# in the other workers.  The controller makes the directory, empty, before it
+# spawns the workers (they inherit the variable) and removes it at the end,
+# so no run reads what another wrote.  Set above ``import jax``, which reads
+# the variable.  A directory given from outside is used as it is, and the
+# real-chip leg has no cache unless it is given one.
+_ON_CHIP = os.environ.get("DS_TPU_TESTS") == "1"
+_OWN_CACHE_DIR = None
+if not _ON_CHIP and "PYTEST_XDIST_WORKER" not in os.environ and not os.environ.get("JAX_COMPILATION_CACHE_DIR"):
+    _OWN_CACHE_DIR = os.environ["JAX_COMPILATION_CACHE_DIR"] = tempfile.mkdtemp(prefix="ds_tpu_tests_jax_cache_")
+
+import jax  # noqa: E402
 
 # DS_TPU_TESTS=1 leaves the real accelerator in place (for tests/tpu — the
 # marker-gated real-chip leg of the harness, SURVEY §4).  A missing chip is
 # then a FAILURE: a real-chip run that skips every test proves nothing.
-if os.environ.get("DS_TPU_TESTS") == "1":
+if _ON_CHIP:
     if jax.devices()[0].platform != "tpu":
         raise RuntimeError(
             f"DS_TPU_TESTS=1 but JAX found no TPU chip (jax.devices()[0].platform == "
@@ -24,8 +40,12 @@ else:
     jax.config.update("jax_platforms", "cpu")
     jax.config.update("jax_num_cpu_devices", 8)
     assert jax.device_count() == 8, f"expected 8 CPU devices, got {jax.devices()}"
+    from deepspeed_tpu.utils import compile_cache
+    compile_cache.enable()
 
 import pytest  # noqa: E402
+
+_STARTED = time.time()
 
 
 def pytest_collection_modifyitems(config, items):
@@ -52,6 +72,38 @@ def _reset_global_mesh():
     mesh_lib._GLOBAL_MESH = None
     from deepspeed_tpu.comm import comm as comm_lib
     comm_lib._COMMS_LOGGER = None
+
+
+@pytest.fixture
+def no_compile_cache():
+    """For a test whose compile cannot be read back (a described device's),
+    or that counts an event a cache read does not raise."""
+    cache_was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    yield
+    jax.config.update("jax_enable_compilation_cache", cache_was)
+
+
+@pytest.hookimpl(trylast=True)   # behind xdist's own, which waits for the workers to end
+def pytest_sessionfinish(session):
+    if _OWN_CACHE_DIR is not None:
+        shutil.rmtree(_OWN_CACHE_DIR, ignore_errors=True)
+
+
+def pytest_terminal_summary(terminalreporter):
+    """Where the run's seconds went: the ten longest files (set-up, call and
+    tear-down summed over a file's tests; under ``--dist loadfile`` a file is
+    one worker's) and the wall seconds.  It prints, and fails nothing."""
+    seconds = {}
+    for reports in terminalreporter.stats.values():
+        for report in reports:
+            if hasattr(report, "duration") and hasattr(report, "nodeid"):
+                name = report.nodeid.split("::")[0]
+                seconds[name] = seconds.get(name, 0.0) + report.duration
+    terminalreporter.write_sep("=", "seconds a file (the ten longest)")
+    for name, spent in sorted(seconds.items(), key=lambda kv: -kv[1])[:10]:
+        terminalreporter.write_line(f"{spent:8.1f} s  {name}")
+    terminalreporter.write_line(f"{sum(seconds.values()):8.1f} s  in all files; {time.time() - _STARTED:.1f} s of wall clock")
 
 
 # ---------------------------------------------------------------- test tiers

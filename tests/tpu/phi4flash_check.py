@@ -12,7 +12,7 @@ harness's ``program_logits`` passes no slot, so its one row runs in slot 0.
 
 Used at the cell's own size on the chip (``test_phi4flash_on_chip.py``) and at
 the configuration file's rehearsal size on the CPU
-(``tests/unit/inference/test_phi4flash.py``).  Both also hold the branch that
+(``tests/unit/inference/test_phi4flash_check.py``).  Both also hold the branch that
 the engine's step programs take and the harness's check does not: the head
 over each row's last real token alone (``last_only``), at the batch the
 scheduler's decode bucket gives the timed programs.

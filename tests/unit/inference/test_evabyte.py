@@ -13,6 +13,7 @@ two layers (observed 1e-5 to 4e-5 on logits of size 2): 2e-4.
 """
 
 import dataclasses
+import functools
 import os
 import sys
 
@@ -35,6 +36,8 @@ from deepspeed_tpu.ops.paged_attention import walk_block
 
 sys.path.insert(0, os.path.join(os.path.dirname(__file__), "..", "..", "..", "benchmark"))
 from refs import evabyte as ref  # noqa: E402
+
+from reference_greedy import greedy  # noqa: E402
 
 WINDOW, PAGE = 256, 16
 CFG = EvaByteConfig(hidden_size=64, intermediate_size=128, num_hidden_layers=2, num_attention_heads=4,
@@ -76,9 +79,14 @@ def want(params, ids):
 # ---------------------------------------------------------------- (a) the model
 
 
+def _full(params, tokens):
+    """The full-sequence model; jitted where it is called, one program a length."""
+    return EvaByteForCausalLM(CFG).apply(params, tokens)
+
+
 @pytest.mark.parametrize("length", [10, WINDOW - 1, WINDOW, WINDOW + 1, 3 * WINDOW + 40])
 def test_full_sequence_model_matches_reference_on_all_heads(params, ids, length):
-    got = EvaByteForCausalLM(CFG).apply(params, jnp.asarray(ids[None, :length]))[0]
+    got = jax.jit(_full)(params, jnp.asarray(ids[None, :length]))[0]
     ref_all = ref.forward_all_heads(params, jnp.asarray(ids[:length]), REF_CFG)
     assert got.shape == (length, CFG.num_pred_heads, CFG.vocab_size)
     np.testing.assert_allclose(got, ref_all, atol=TOL)
@@ -90,7 +98,7 @@ def test_dropping_a_summary_vector_fails_the_comparison(params, ids, drop):
     comparison above would fail by two orders of magnitude."""
     broken = jax.tree_util.tree_map_with_path(
         lambda path, x: jnp.zeros_like(x) if drop in jax.tree_util.keystr(path) else x, params)
-    got = EvaByteForCausalLM(CFG).apply(broken, jnp.asarray(ids[None]))[0]
+    got = jax.jit(_full)(broken, jnp.asarray(ids[None]))[0]
     ref_all = ref.forward_all_heads(params, jnp.asarray(ids), REF_CFG)
     assert float(jnp.max(jnp.abs(got - ref_all)[WINDOW:])) > 100 * TOL     # from the second window on
     np.testing.assert_allclose(got[:WINDOW], ref_all[:WINDOW], atol=TOL)    # no summary is visible before
@@ -179,17 +187,8 @@ def _engine(params, cfg=CFG, **over):
     return InferenceEngineV2(cfg, params, RaggedInferenceEngineConfig(**econf))
 
 
-def _greedy(params, prompt, n):
-    """Greedy continuation by the full-sequence model, head 0.  One program:
-    the sequence is padded to a fixed length, and what lies behind a position
-    changes nothing before it."""
-    toks = list(prompt)
-    fwd = jax.jit(lambda ids: EvaByteForCausalLM(CFG).apply(params, ids)[0, :, 0])
-    for _ in range(n):
-        padded = np.zeros((1, len(prompt) + n), np.int32)
-        padded[0, :len(toks)] = toks
-        toks.append(int(jnp.argmax(fwd(jnp.asarray(padded))[len(toks) - 1])))
-    return toks[len(prompt):]
+#: greedy continuation by the full-sequence model, head 0: ``_greedy(params, prompt, n)``
+_greedy = functools.partial(greedy, lambda p, t: _full(p, t)[:, :, 0], width=300 + 48)
 
 
 def test_engine_generate_matches_full_sequence_model_over_two_windows(params, ids):

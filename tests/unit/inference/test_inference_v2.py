@@ -2,6 +2,7 @@
 scheduler, engine generate correctness vs the cache-free reference path)."""
 
 import dataclasses
+import functools
 
 import jax
 import jax.numpy as jnp
@@ -14,6 +15,8 @@ from deepspeed_tpu.inference.v2.ragged import BlockedKVCache, StateManager
 from deepspeed_tpu.inference.v2.scheduler import SchedulerConfig, SplitFuseScheduler
 from deepspeed_tpu.models.llama import LlamaConfig, LlamaForCausalLM
 from deepspeed_tpu.models.llama_cache import PagedKVConfig
+
+from reference_greedy import greedy
 
 
 CFG = LlamaConfig(vocab_size=128, hidden_size=64, intermediate_size=128, num_hidden_layers=2,
@@ -36,15 +39,8 @@ def _engine(trained_params, cfg=CFG, **overrides):
     return build_engine(cfg, trained_params, eng_cfg)
 
 
-def _reference_greedy(params, prompt, n_new, model=None):
-    """Cache-free greedy decode via the training model (golden)."""
-    model = model or LlamaForCausalLM(CFG)
-    ids = jnp.asarray([prompt], jnp.int32)
-    for _ in range(n_new):
-        logits = model.apply(params, ids)
-        nxt = jnp.argmax(logits[:, -1], axis=-1).astype(jnp.int32)
-        ids = jnp.concatenate([ids, nxt[:, None]], axis=1)
-    return list(np.asarray(ids[0, len(prompt):]))
+#: cache-free greedy decode via the training model (golden): ``_reference_greedy(params, prompt, n_new)``
+_reference_greedy = functools.partial(greedy, LlamaForCausalLM(CFG).apply, width=32)
 
 
 def test_generate_matches_cachefree_reference(trained_params):
@@ -80,7 +76,7 @@ def test_unscanned_checkpoint_served_through_the_scanned_twin():
     eng = _engine(params, cfg)
     assert eng.cache.ndim == 6
     prompt = [5, 9, 2, 7, 1]
-    assert eng.generate([prompt], max_new_tokens=6) == [_reference_greedy(params, prompt, 6, model)]
+    assert eng.generate([prompt], max_new_tokens=6) == [greedy(model.apply, params, prompt, 6, 32)]
 
 
 def test_mixed_dense_sparse_stack_served_in_the_one_arena():
@@ -104,7 +100,7 @@ def test_mixed_dense_sparse_stack_served_in_the_one_arena():
     sched = SchedulerConfig(token_budget=32, max_seqs=4, prefill_chunk=8, decode_bucket=4)
     eng = InferenceEngineV2(cfg, params, RaggedInferenceEngineConfig(kv=kv, scheduler=sched, kv_dtype=jnp.float32))
     prompt = [5, 9, 2, 7, 1]
-    assert eng.generate([prompt], max_new_tokens=5) == [_reference_greedy(params, prompt, 5, Qwen2MoeForCausalLM(cfg))]
+    assert eng.generate([prompt], max_new_tokens=5) == [greedy(Qwen2MoeForCausalLM(cfg).apply, params, prompt, 5, 32)]
     assert eng.cache.ndim == 6
 
 

@@ -21,6 +21,7 @@ from deepspeed_tpu.serving.request import RequestState
 from deepspeed_tpu.telemetry import MetricsRegistry, Tracer
 
 sys.path.insert(0, os.path.dirname(os.path.abspath(__file__)))
+import reference_greedy  # noqa: E402
 from test_kimi_vl import small  # noqa: E402
 
 PH = 500
@@ -45,18 +46,16 @@ def request(rng, grids, text=(5, 3, 7)):
 
 
 def greedy(model, params, prompt, images, n):
-    """``n`` greedy tokens of the full-sequence model."""
+    """``n`` greedy tokens of the full-sequence model: ``mm_index`` names an
+    image row at the prompt's placeholders and -1 behind them, as wide as the
+    padded tokens (80: the longest prompt here has 63)."""
+    width = 80
     rows = jnp.concatenate([model.apply(params, jnp.asarray(px.reshape(len(px), -1)), jnp.asarray(g),
                                         method="encode_images") for px, g in images])
-    index = np.full(len(prompt), -1)
-    index[np.flatnonzero(np.asarray(prompt) == PH)] = np.arange(rows.shape[0])
-    toks, out = list(prompt), []
-    for _ in range(n):
-        full = np.concatenate([index, np.full(len(toks) - len(index), -1)])
-        logits = model.apply(params, jnp.asarray(toks)[None], mm_index=jnp.asarray(full)[None], mm_rows=rows)
-        out.append(int(jnp.argmax(logits[0, -1])))
-        toks.append(out[-1])
-    return out
+    index = np.full((1, width), -1)
+    index[0, np.flatnonzero(np.asarray(prompt) == PH)] = np.arange(rows.shape[0])
+    return reference_greedy.greedy(lambda a, t: model.apply(a[0], t, mm_index=a[1], mm_rows=a[2]),
+                                   (params, index, rows), prompt, n, width)
 
 
 @pytest.fixture(scope="module")

@@ -5,9 +5,9 @@ prefill group of a rung of rows at the chunk.  Held here: the token streams
 ``step_shape_set`` over every plan the scheduler can make under the benchmark
 cells' scheduler configurations, no compile after ``warm_all``, and what the
 step record of a two-group step holds.  The families: the three softmax
-twins and the two that hold a state slot a sequence (Phi-4-mini-flash,
-Granite 4.0-H), whose row-at-a-time reference runs each prompt in a slot of
-its own.
+twins here and, in ``test_row_groups_engine_slots.py`` (a worker of their
+own), the two that hold a state slot a sequence (Phi-4-mini-flash, Granite
+4.0-H), whose row-at-a-time reference runs each prompt in a slot of its own.
 """
 
 import dataclasses
@@ -130,12 +130,23 @@ def _row_at_a_time(cfg, twin, params, prompts, new=NEW):
     return out
 
 
-@pytest.fixture(scope="module", params=sorted(CONFIGS))
-def family(request):
-    cfg = CONFIGS[request.param]
-    twin, params = _params(cfg)
-    prompts = _prompts(cfg)
-    return cfg, params, prompts, _row_at_a_time(cfg, twin, params, prompts)
+SLOT_HOLDING = [name for name in sorted(CONFIGS) if cache_geometry(CONFIGS[name], PAGE).state_slots]
+
+
+def families(names):
+    """The fixture ``family`` over ``names`` of ``CONFIGS``."""
+
+    @pytest.fixture(scope="module", params=names)
+    def family(request):
+        cfg = CONFIGS[request.param]
+        twin, params = _params(cfg)
+        prompts = _prompts(cfg)
+        return cfg, params, prompts, _row_at_a_time(cfg, twin, params, prompts)
+
+    return family
+
+
+family = families([name for name in sorted(CONFIGS) if name not in SLOT_HOLDING])
 
 
 def _two_group_rows(anat):

@@ -5,6 +5,8 @@ integration (per-request control + acceptance accounting), and a seeded
 admit/speculate/reject/preempt/resume property audit — all on the tiny CPU
 model with deterministic clocks."""
 
+import functools
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -18,6 +20,8 @@ from deepspeed_tpu.models.llama import LlamaConfig, LlamaForCausalLM
 from deepspeed_tpu.models.llama_cache import PagedKVConfig
 from deepspeed_tpu.serving import (RequestState, ServingConfig, ServingEngine,
                                    VirtualClock)
+
+from reference_greedy import greedy
 
 CFG = LlamaConfig(vocab_size=128, hidden_size=64, intermediate_size=128, num_hidden_layers=2,
                   num_attention_heads=4, num_key_value_heads=2, max_position_embeddings=128,
@@ -40,14 +44,8 @@ def _engine(trained_params, num_pages=64, max_pages=8, spec=SpecConfig(max_draft
         kv=kv, scheduler=sched, kv_dtype=jnp.float32, **overrides, spec=spec))
 
 
-def _reference_greedy(params, prompt, n_new):
-    model = LlamaForCausalLM(CFG)
-    ids = jnp.asarray([prompt], jnp.int32)
-    for _ in range(n_new):
-        logits = model.apply(params, ids)
-        nxt = jnp.argmax(logits[:, -1], axis=-1).astype(jnp.int32)
-        ids = jnp.concatenate([ids, nxt[:, None]], axis=1)
-    return list(np.asarray(ids[0, len(prompt):]))
+#: cache-free greedy decode via the training model: ``_reference_greedy(params, prompt, n_new)``
+_reference_greedy = functools.partial(greedy, LlamaForCausalLM(CFG).apply, width=32)
 
 
 PROMPTS = [[5, 9, 2, 7, 1], [3, 3, 8], [1, 2, 3, 1, 2, 3, 1, 2], [11, 4, 6, 2]]

@@ -2,7 +2,7 @@
 what a sequence of ``n`` tokens holds, the slot's life with its sequence in
 ``StateManager`` (allocate, exhaust, release on flush and on preempt), the
 slot in the packed batch's rows, and admission by free slots.  Host code only:
-no model runs here (``test_phi4flash.py`` serves one through them).
+no model runs here (``test_phi4flash_engine.py`` serves one through them).
 """
 
 import types

@@ -117,10 +117,15 @@ def test_yarn_blends_the_frequencies_between_the_published_dimensions():
     assert abs(full.softmax_scale - 192**-0.5 * (0.1 * np.log(64) + 1)**2) < 1e-9
 
 
+def _full(params, tokens):
+    """The full-sequence model; jitted where it is called, one program a shape."""
+    return Xing4ForCausalLM(CFG).apply(params, tokens)
+
+
 @pytest.mark.parametrize("length", [97])
 def test_full_sequence_model_matches_reference(params, ids, want, length):
     with jax.default_matmul_precision("highest"):
-        got = Xing4ForCausalLM(CFG).apply(params, jnp.asarray(ids[:2, :length]))
+        got = jax.jit(_full)(params, jnp.asarray(ids[:2, :length]))
     for i in range(2):
         np.testing.assert_allclose(np.asarray(got[i]), want[i][:length], atol=TOL)
 
@@ -132,7 +137,7 @@ def test_every_part_matters_under_these_weights(params, ids, want, zeroed):
     cut = jax.tree_util.tree_map_with_path(
         lambda path, x: jnp.zeros_like(x) if zeroed in jax.tree_util.keystr(path) else x, params)
     with jax.default_matmul_precision("highest"):
-        got = np.asarray(Xing4ForCausalLM(CFG).apply(cut, jnp.asarray(ids[:1, :64])))[0]
+        got = np.asarray(jax.jit(_full)(cut, jnp.asarray(ids[:1, :64])))[0]
     assert np.abs(got - want[0][:64]).max() > 50 * TOL
 
 

@@ -3,6 +3,7 @@ groups, and the engine over it, against the plain reference at the small size
 and under the weights of ``test_xing4.py`` (float32, 2e-4)."""
 
 import dataclasses
+import functools
 
 import jax
 import jax.numpy as jnp
@@ -13,10 +14,11 @@ from deepspeed_tpu.inference.v2 import InferenceEngineV2, RaggedInferenceEngineC
 from deepspeed_tpu.inference.v2.scheduler import SchedulerConfig
 from deepspeed_tpu.models.cache_zoo import cache_geometry, cache_twin
 from deepspeed_tpu.models.llama_cache import PagedKVConfig
-from deepspeed_tpu.models.xing4 import Xing4Config, Xing4ForCausalLM
+from deepspeed_tpu.models.xing4 import Xing4Config
 from deepspeed_tpu.models.xing4_cache import LatentPagesGeometry, Xing4ForCausalLMWithCache, init_cache, walk_rows
 
-from test_xing4 import CFG, CHUNK, KV, PAGE, TOL, ids, params, want  # noqa: F401 (the fixtures are this module's too)
+from reference_greedy import greedy
+from test_xing4 import CFG, CHUNK, KV, PAGE, TOL, _full, ids, params, want  # noqa: F401 (the fixtures are this module's too)
 
 
 # ------------------------------------------------------- (d) the twin, through pages
@@ -129,15 +131,8 @@ def _engine(params, max_seqs=2, attention_impl="reference", **over):
     return InferenceEngineV2(cfg, params, RaggedInferenceEngineConfig(**{**fields, **over}))
 
 
-def _greedy(params, prompt, n, width=96):
-    """Greedy continuation by the full-sequence model (causal: the padding behind the tokens changes nothing)."""
-    full = jax.jit(lambda p, t: Xing4ForCausalLM(CFG).apply(p, t))
-    toks = list(prompt)
-    for _ in range(n):
-        with jax.default_matmul_precision("highest"):
-            logits = full(params, jnp.asarray([toks + [0] * (width - len(toks))]))[0, len(toks) - 1]
-        toks.append(int(jnp.argmax(logits)))
-    return toks[len(prompt):]
+#: greedy continuation by the full-sequence model: ``_greedy(params, prompt, n)``
+_greedy = functools.partial(greedy, _full, width=96, precision="highest")
 
 
 def test_engine_serves_two_row_groups_with_the_prefix_cache_on(params, ids):

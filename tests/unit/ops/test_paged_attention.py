@@ -1,5 +1,9 @@
 """Pallas paged decode attention vs the jnp golden (interpret mode on CPU),
-mirroring the reference's kernel-vs-torch numeric tests (tests/unit/ops)."""
+mirroring the reference's kernel-vs-torch numeric tests (tests/unit/ops).
+Tracing the interpreted kernel takes six seconds a case, so the cases run in
+three files, a worker each: here those whose pages the kernel copies itself,
+in ``test_paged_attention_pipelined.py`` those the pipeline brings, in
+``test_paged_attention_window.py`` the window bound over cases of both."""
 
 import jax
 import jax.numpy as jnp
@@ -101,7 +105,7 @@ def _eva_view():
     return q, arena, view, vstart, jnp.asarray(lens), page, 1
 
 
-CASES = {
+COPIED = {
     # Heads of 128 lanes in whole tiles (every cell's): the kernel copies a block's pages itself, 64 of them (512 rows),
     # and takes a head's rows by strided load.
     # the three rows of old: a prefill from nothing, a chunk short of one token, a decode row deep in a chunk program
@@ -119,6 +123,8 @@ CASES = {
     "evabyte_view_32_key_heads_third_window": _eva_view,
     # one key head: whole tiles in float32; in bfloat16 half a 32-bit sublane, a page the tiling pads (see below)
     "one_key_head": lambda: _rows([(3, 4), (21, 1), (300, 2)], c=4, h=4, n_kv=1),
+}
+PIPELINED = {
     # Pages the chip's tiling pads, or heads no strided load takes: the pipeline brings a block's pages, 16 of them
     # (128 rows), and a head's rows are a load a page.
     "heads_of_32_lanes": lambda: _rows([(0, 4), (5, 3), (13, 1)], c=4, h=8, n_kv=4, d=32),
@@ -130,20 +136,10 @@ CASES = {
     "three_key_heads": lambda: _rows([(0, 4), (5, 3), (140, 1)], c=4, h=6, n_kv=3),
     "heads_of_256_lanes": lambda: _rows([(0, 4), (130, 1)], c=4, h=4, n_kv=2, d=256),
 }
+CASES = {**COPIED, **PIPELINED}
 
 
-def test_the_cases_take_both_ways_a_block_arrives():
-    """Which way is a matter of the page's shape alone: the cases above are
-    on both sides of it, in both types."""
-    from deepspeed_tpu.ops.paged_attention import _copies_pages
-    ways = {(case, size): _copies_pages(*make()[1].shape[-2:], size) for case, make in CASES.items() for size in (4, 2)}
-    assert sum(ways.values()) >= 18 and sum(not w for w in ways.values()) >= 10
-    assert ways["one_key_head", 4] and not ways["one_key_head", 2]
-
-
-@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16], ids=["float32", "bfloat16"])
-@pytest.mark.parametrize("case", list(CASES))
-def test_pallas_matches_jnp_golden(case, dtype):
+def matches_jnp_golden(case, dtype):
     """The kernel in interpret mode against the jnp golden: same values where
     a row carries a token, exactly zero where it does not.  In bfloat16 (two
     heads a 32-bit sublane: the kernel's other way to take a head's rows out
@@ -160,53 +156,7 @@ def test_pallas_matches_jnp_golden(case, dtype):
     np.testing.assert_array_equal(np.asarray(as32(got))[past], 0)
 
 
-def test_pallas_decode_single_token():
-    """C=1 pure-decode step (the FastGen hot path)."""
-    q, pages, bt, sp, cl, ps = _setup(c=1, h=4, n_kv=2)
-    expected = paged_attention(q, pages, bt, sp, cl, ps)
-    got = paged_attention_pallas(q, pages, bt, sp, cl, ps, interpret=True)
-    np.testing.assert_allclose(np.asarray(got), np.asarray(expected), atol=2e-5)
-
-
-def test_padding_rows_zeroed():
-    q, pages, bt, sp, cl, ps = _setup()
-    cl = cl.at[1].set(0)  # make row 1 a padding row
-    got = paged_attention_pallas(q, pages, bt, sp, cl, ps, interpret=True)
-    np.testing.assert_array_equal(np.asarray(got[1]), 0)
-
-
-WINDOW_CASES = {
-    # the kernel copies its pages (blocks of 64 pages = 512 rows): a window inside the first block, one that starts
-    # the walk at the second block, a chunk whose first and last query see different first blocks, a decode row
-    "window_inside_the_first_block": ("heads_of_128_lanes", 6),
-    "walk_starts_at_a_later_block": ("table_no_multiple_of_the_block", 40),
-    "a_chunk_across_the_window_s_edge": ("decode_row_in_a_chunk_of_32", 20),
-    "more_than_one_query_tile": ("more_than_one_query_tile", 9),
-    "layer_named_in_the_whole_arena": ("layer_named_in_the_whole_arena", 300),
-    # the pipeline brings the pages (blocks of 16 pages = 128 rows): steps before the first block are skipped
-    "pipelined_walk_starts_at_a_later_block": ("heads_of_32_lanes_table_no_multiple_of_the_block", 40),
-    "pipelined_heads_of_64_lanes": ("heads_of_64_lanes_decode_row_in_a_chunk_of_32", 150),
-    "three_key_heads": ("three_key_heads", 3),
-}
-
-
 @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16], ids=["float32", "bfloat16"])
-@pytest.mark.parametrize("case", list(WINDOW_CASES))
-def test_window_bound_matches_jnp_form(case, dtype):
-    """A first visible row as well as a last: the query at ``t`` sees keys
-    ``t - window + 1 .. t``, as ``paged_attention(sliding_window=)`` has it,
-    and a scale of the caller's in place of ``1 / sqrt(D)``."""
-    base, window = WINDOW_CASES[case]
-    q, pages, table, start, lens, page_size, layer = CASES[base]()
-    q, pages = q.astype(dtype), pages.astype(dtype)
-    as32 = lambda x: x.astype(jnp.float32)  # noqa: E731
-    expected = paged_attention(as32(q), as32(pages if layer is None else pages[layer]), table, start, lens, page_size,
-                               sliding_window=window, scale=0.125)
-    unbounded = paged_attention(as32(q), as32(pages if layer is None else pages[layer]), table, start, lens, page_size,
-                                scale=0.125)
-    assert float(jnp.abs(expected - unbounded).max()) > 1e-3          # the window hides something
-    got = jax.jit(lambda q, pages: paged_attention_pallas(q, pages, table, start, lens, page_size, layer=layer,
-                                                          window=window, scale=0.125, interpret=True))(q, pages)
-    np.testing.assert_allclose(np.asarray(as32(got)), np.asarray(expected), atol=2e-5 if dtype == jnp.float32 else 3e-2)
-    past = np.arange(q.shape[1])[None, :] >= np.asarray(lens)[:, None]
-    np.testing.assert_array_equal(np.asarray(as32(got))[past], 0)
+@pytest.mark.parametrize("case", list(COPIED))
+def test_pallas_matches_jnp_golden(case, dtype):
+    matches_jnp_golden(case, dtype)

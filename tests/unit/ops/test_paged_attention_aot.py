@@ -37,15 +37,6 @@ def one_chip(described_chip):
     return SingleDeviceSharding(described_chip)
 
 
-@pytest.fixture
-def no_compile_cache():
-    """A described device's compile cannot be read back."""
-    cache_was = jax.config.jax_enable_compilation_cache
-    jax.config.update("jax_enable_compilation_cache", False)
-    yield
-    jax.config.update("jax_enable_compilation_cache", cache_was)
-
-
 def _compile(one_chip, chunk, n_q, n_kv, table_width, layers=None, d=128, dtype=jnp.bfloat16, batch=16, **bounds):
     """The kernel alone, lowered and compiled: the program's text.  Under the
     ``no_compile_cache`` fixture."""
