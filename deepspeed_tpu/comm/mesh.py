@@ -169,6 +169,16 @@ def get_trace_mesh() -> Optional[Mesh]:
     return _TRACE_MESH
 
 
+def traced_for_tpu() -> bool:
+    """Whether the computation being traced is for a TPU: asked of the
+    governing mesh, not of the local devices, so that a compile for an
+    offline TPU topology from a CPU-only host lowers the real kernels and not
+    their interpreted form.  Every Pallas kernel's ``interpret=None`` is this
+    rule."""
+    dev = _TRACE_MESH.devices.flat[0] if _TRACE_MESH is not None else jax.devices()[0]
+    return dev.platform == "tpu"
+
+
 def in_manual_mesh() -> bool:
     """True inside a shard_map body: GSPMD-level sharding constraints are
     meaningless/illegal there, and shard_map-wrapping kernels must not

@@ -13,11 +13,9 @@ build_hf_engine``.  The serving loop composes:
 TPU specifics vs the reference:
   * ONE compiled step program per list of row groups ``((rows, width), ...)``:
     the decode bucket at one slot a row, and in a mixed step a prefill group
-    of a few rows (a ladder of three) at a chunk a row beside it, where the
-    twin's blocks take more than one group (``takes_row_groups``), else the
-    one rectangle of all rows at the chunk — the scheduler quantises both, so
-    steady-state serving reuses a handful of programs instead of the
-    reference's per-shape CUDA kernel launches.
+    of a few rows (a ladder of three) at a chunk a row beside it — the
+    scheduler quantises both, so steady-state serving reuses a handful of
+    programs instead of the reference's per-shape CUDA kernel launches.
   * the KV arena is donated through the jitted step, so XLA updates pages
     in place (the reference's global InferenceContext arena, inference_context.h).
   * sampling is greedy or categorical on-device; logits for each row are
@@ -104,17 +102,18 @@ class RaggedInferenceEngineConfig:
     spec: Optional[SpecConfig] = None
 
 
-def _make_step_fn(model, qparams, greedy: bool, temperature: float, groups, image_rows: bool = False):
+def _make_step_fn(model, qparams, greedy: bool, temperature: float, groups):
     """The unified SplitFuse step program: one chunked forward serving
     prefill, continuation and decode, then per-row last-token sampling.
     ``groups`` is the step's static list of row groups ``(rows, width)``; the
     tokens are their one flat axis, the other batch arrays one entry a row
     (models/llama_cache.py "Row groups").  Pure in (params, cache, batch
     arrays) so both the live engine and the AOT serving-budget path
-    (compile_aot_serving) jit the same function.  ``image_rows``: the program
-    of a twin with a vision tower whose step holds a prefill group takes two
-    arguments more, ``mm_index`` (one entry a slot: its row of ``mm_rows``, -1
-    the token's own embedding) and ``mm_rows`` (the engine's image rows)."""
+    (compile_aot_serving) jit the same function.  ``image_args``: the program
+    of a twin with a vision tower whose step holds a prefill group is called
+    with two arguments more (``_takes_image_rows``), ``mm_index`` (one entry a
+    slot: its row of ``mm_rows``, -1 the token's own embedding) and
+    ``mm_rows`` (the engine's image rows)."""
     groups = tuple(groups)
 
     def step(params, cache, tokens, start_pos, block_tables, chunk_lens, rng, *image_args):
@@ -122,14 +121,8 @@ def _make_step_fn(model, qparams, greedy: bool, temperature: float, groups, imag
             params = {"params": qparams.dequantize(params["params"])}
         # logits of each row's LAST real token alone: the twin takes those rows
         # out before its final norm and head (models/llama_cache.sampled_rows)
-        if image_rows:
-            logits, cache = model.apply(params, tokens, start_pos, block_tables, cache, chunk_lens, True, groups,
-                                        *image_args)
-        elif len(groups) == 1:  # the rectangle of every twin's contract
-            logits, cache = model.apply(params, tokens.reshape(groups[0]), start_pos, block_tables, cache,
-                                        chunk_lens, True)
-        else:  # to a twin that takes them (``takes_row_groups``): flat, with their list
-            logits, cache = model.apply(params, tokens, start_pos, block_tables, cache, chunk_lens, True, groups)
+        logits, cache = model.apply(params, tokens, start_pos, block_tables, cache, chunk_lens, True, groups,
+                                    *image_args)
         row_logits = logits[:, 0]                                                      # [R, V]
         if greedy:
             next_tok = jnp.argmax(row_logits, axis=-1)
@@ -373,15 +366,14 @@ class InferenceEngineV2:
         #: program key -> program: a step's row groups ``((rows, width), ...)``,
         #: ``("multi", batch, k)`` or ``("verify", batch, width)``
         self._step_fns: Dict[tuple, callable] = {}
-        #: whether the twin's blocks take a step of more than one row group
-        self._row_groups = bool(getattr(self.model, "takes_row_groups", False))
-        if self._row_groups:
-            # a prompt alone in prefill may fill the rung its steps have for a
-            # burst of arrivals with its own consecutive chunks, and no wider
-            # one: what the rung of ``max_seqs`` rows costs the decode rows
-            # that ride it is not measured (PERF.md section 7)
-            rungs = self._prefill_rungs()
-            self.scheduler.run_rows = rungs[-2] if len(rungs) > 1 else 1
+        # a prompt alone in prefill may fill the rung its steps have for a
+        # burst of arrivals with its own consecutive chunks, and no wider
+        # one: what the rung of ``max_seqs`` rows costs the decode rows
+        # that ride it is not measured (PERF.md section 7).  Where a
+        # sequence's chunks cannot share a step the geometry says so
+        # (``chunk_runs``) and the scheduler plans no run.
+        rungs = self._prefill_rungs()
+        self.scheduler.run_rows = rungs[-2] if len(rungs) > 1 else 1
         #: a twin with a vision tower: the buffer of image rows [units, rows a
         #: unit, hidden] the sequences' images own units of from their encode
         #: until prefill has passed them (unit 0 is scratch, where an encode's
@@ -690,7 +682,7 @@ class InferenceEngineV2:
         the guaranteed-progress rung (decode k=1 — the fused multi-decode
         path already self-shrinks k under pressure in ``step``).  The
         serving frontend preflights this against ``allocator.free_pages``
-        and preempts until the step fits, instead of letting ``pack`` raise
+        and preempts until the step fits, instead of letting ``pack_groups`` raise
         mid-step."""
         if plan is None:
             plan = self.scheduler.plan(self.state)
@@ -724,8 +716,7 @@ class InferenceEngineV2:
         builder shared by the lazy per-shape cache and the AOT ``warm_all``
         path, so the two can never trace different computations for the
         same key."""
-        step = _make_step_fn(self.model, self._qparams, self.econfig.greedy,
-                             self.econfig.temperature, groups, self._takes_image_rows(groups))
+        step = _make_step_fn(self.model, self._qparams, self.econfig.greedy, self.econfig.temperature, groups)
         return jax.jit(_named(step, self._key_label(groups)),
                        donate_argnums=(1, ), **self._jit_kwargs())
 
@@ -810,11 +801,10 @@ class InferenceEngineV2:
         ``decode_bucket`` multiples up to ``max_seqs``; a step is the decode
         bucket at one token a row and, with a prefill row in it, what
         ``_step_groups`` makes of the plan (the decode bucket beside each
-        rung of ``_prefill_rungs``, or the rectangle of a bucket of rows at
-        ``prefill_chunk``); the fused-decode rung adds its halving ladder
-        (k_cfg, k_cfg/2, ..., 2 — exactly the pressure fallbacks
-        ``_dispatch_inner`` walks); a drafter adds one verify width
-        (``max_draft + 1``).
+        rung of ``_prefill_rungs`` at ``prefill_chunk``); the fused-decode
+        rung adds its halving ladder (k_cfg, k_cfg/2, ..., 2 — exactly the
+        pressure fallbacks ``_dispatch_inner`` walks); a drafter adds one
+        verify width (``max_draft + 1``).
         This closure is what makes ``warm_all`` a guarantee rather than a
         heuristic: a steady-state dispatch outside this set would be an
         engine bug, and the ``engine/recompile_steady_state`` guard would
@@ -824,10 +814,8 @@ class InferenceEngineV2:
         maxb = self.state.max_batch
         batches = sorted({min(maxb, m * q) for m in range(1, -(-maxb // q) + 1)})
         keys: List[tuple] = [((b, 1), ) for b in batches]
-        if sched.prefill_chunk > 1 and self._row_groups:
+        if sched.prefill_chunk > 1:
             keys += [((b, 1), (p, sched.prefill_chunk)) for b in batches for p in self._prefill_rungs()]
-        elif sched.prefill_chunk > 1:
-            keys += [((b, sched.prefill_chunk), ) for b in batches]
         k_cfg = self.econfig.decode_steps_per_dispatch
         if k_cfg > 1:
             ks = set()
@@ -1010,13 +998,12 @@ class InferenceEngineV2:
         width = self.econfig.spec.max_draft + 1
         batch = self._bucket_batch(len(seqs))
         base_len = [len(s.tokens) for s in seqs]
-        # drafts ride in the token history for pack() (sliced back out
+        # drafts ride in the token history for pack_groups() (sliced back out
         # in the fold — they are verify INPUTS, not accepted output)
         for s, d in zip(seqs, drafts):
             s.tokens.extend(d)
         try:
-            rb: RaggedBatch = self.state.pack([(s, 1 + len(d)) for s, d in zip(seqs, drafts)],
-                                              width, pad_to=batch)
+            rb: RaggedBatch = self.state.pack_groups([([(s, 1 + len(d)) for s, d in zip(seqs, drafts)], batch, width)])
             if anat.enabled:
                 anat.mark("verify_plan")
             fn = self._compiled_verify(batch, width)
@@ -1026,8 +1013,8 @@ class InferenceEngineV2:
                                   rows_decode=len(seqs), slots=batch * width)
             _fi.check("engine.verify_step")  # chaos site: device loss mid-verify
             argmax, self.cache = self._invoke(fn, self.params, self.cache,
-                                              jnp.asarray(rb.tokens), jnp.asarray(rb.start_pos),
-                                              jnp.asarray(rb.block_tables),
+                                              jnp.asarray(rb.tokens.reshape(batch, width)),
+                                              jnp.asarray(rb.start_pos), jnp.asarray(rb.block_tables),
                                               jnp.asarray(rb.chunk_lens))
             if anat.enabled:
                 anat.mark("compile_wait" if self._fresh_compile else "dispatch")
@@ -1036,7 +1023,7 @@ class InferenceEngineV2:
             # into the history: restore every row's token list so a caller
             # that survives the error (chaos drill, retry layer) decodes
             # from exactly the pre-round state.  seen_tokens/pages were not
-            # advanced yet; extra pages pack() allocated are plain capacity
+            # advanced yet; extra pages pack_groups() allocated are plain capacity
             # the next round reuses.
             for s, L in zip(seqs, base_len):
                 del s.tokens[L:]
@@ -1116,7 +1103,7 @@ class InferenceEngineV2:
         """Enqueue ``k`` fused decode rounds for a pure-decode batch."""
         batch = self._bucket_batch(len(seqs))
         for s in seqs:
-            # capacity for the WHOLE block up front; pack()'s per-token
+            # capacity for the WHOLE block up front; pack_groups()'s per-token
             # ensure_capacity then finds nothing left to allocate.  Capped
             # at the row's remaining max_new budget: a short-tail row keeps
             # at most `remaining` of the k tokens, and KV writes past its
@@ -1125,7 +1112,7 @@ class InferenceEngineV2:
             remaining = self._max_new.get(s.uid, self.econfig.max_new_tokens) \
                 - len(s.generated)
             self.kv.ensure_capacity(s, min(k, remaining))
-        rb: RaggedBatch = self.state.pack([(s, 1) for s in seqs], 1, pad_to=batch)
+        rb: RaggedBatch = self.state.pack_groups([([(s, 1) for s in seqs], batch, 1)])
 
         anat = self.anatomy
         self.rng, sub = jax.random.split(self.rng)
@@ -1133,7 +1120,7 @@ class InferenceEngineV2:
         if anat.enabled:
             anat.note_program(self._key_label(("multi", batch, k)), "multi_decode",
                               rows_decode=len(seqs), tokens_real=len(seqs) * k, slots=batch * k)
-        toks, self.cache = self._invoke(fn, self.params, self.cache, jnp.asarray(rb.tokens[:, 0]),
+        toks, self.cache = self._invoke(fn, self.params, self.cache, jnp.asarray(rb.tokens),
                                         jnp.asarray(rb.start_pos), jnp.asarray(rb.block_tables),
                                         jnp.asarray(rb.chunk_lens), sub)
         if anat.enabled:
@@ -1256,19 +1243,16 @@ class InferenceEngineV2:
     def _step_groups(self, plan: StepPlan):
         """A single step's layout: its row groups as ``pack_groups`` takes them,
         [(work, rows, width)].  Rows of one token each are one group at one
-        token a row.  With a wider prefill row in the plan, a twin whose blocks
-        take more than one group gets the decode bucket at one token a row
-        (all padding where nothing decodes: dead slots that save a program)
-        and the prefill rows in a group of their own at the chunk; any other
-        gets the one rectangle, every row at the chunk."""
+        token a row.  With a wider prefill row in the plan: the decode bucket
+        at one token a row (all padding where nothing decodes: dead slots that
+        save a program) and the prefill rows in a group of their own at the
+        chunk."""
         decode = [(s, 1) for s in plan.decode]
         work = decode + list(plan.prefill)
         chunk = self.econfig.scheduler.prefill_chunk
         # a prompt's last token is a row of one token too, unless an image's row takes its place
         if all(n == 1 for _, n in work) and not any(self._image_slot(seq, n) for seq, n in plan.prefill):
             return [(work, self._bucket_batch(len(work)), 1)]
-        if not self._row_groups:
-            return [(work, self._bucket_batch(len(work)), chunk)]
         rows = next(p for p in self._prefill_rungs() if p >= len(self._kernel_rows(plan.prefill)))
         return [(decode, self._bucket_batch(max(len(decode), 1)), 1), (list(plan.prefill), rows, chunk)]
 

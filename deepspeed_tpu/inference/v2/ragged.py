@@ -619,7 +619,7 @@ class RaggedBatch:
     """One step's packed device inputs (ref: RaggedBatchWrapper) — fixed
     max shapes so the compiled program is reused across steps.  The rows of a
     step's row groups are concatenated, B rows in all."""
-    tokens: np.ndarray        # [B, C] int32 (padded); [T] flat where packed by groups
+    tokens: np.ndarray        # [T] int32: the groups' slots on one flat axis (padded)
     start_pos: np.ndarray     # [B] int32 — context length before this chunk
     block_tables: np.ndarray  # [B, max_pages] int32 (null page 0 padded)
     chunk_lens: np.ndarray    # [B] int32 — real tokens this step (0 = padding row)
@@ -711,19 +711,6 @@ class StateManager:
         self.kv.release(seq)
         return seq
 
-    def pack(self, work: List[Tuple[SequenceDescriptor, int]], chunk: int,
-             pad_to: Optional[int] = None) -> RaggedBatch:
-        """Pack (seq, n_tokens) work items into fixed [B, chunk] buffers: the
-        one-group case of ``pack_groups``, its tokens as the rectangle.
-
-        B is padded to ``pad_to`` (default ``max_batch``) so the compiled
-        step program keeps ONE shape across scheduler decisions — padding
-        rows have uid -1, chunk_len 0, and an all-null block table."""
-        b = pad_to if pad_to is not None else self.max_batch
-        rb = self.pack_groups([(work, b, chunk)])
-        rb.tokens = rb.tokens.reshape(b, chunk)
-        return rb
-
     def pack_groups(self, groups: List[Tuple[List[Tuple[SequenceDescriptor, int]], int, int]],
                     mm: bool = False) -> RaggedBatch:
         """Pack a step's row groups, each (work, rows, width): the tokens on
@@ -733,8 +720,10 @@ class StateManager:
         concatenated.  A group's work fills its first rows, an item of more
         than ``width`` tokens as many consecutive rows as it has chunks of
         ``width`` (a run: the plan's ``(seq, n)`` of ``SplitFuseScheduler``);
-        the rest are padding rows as in ``pack``.  ``mm``: also ``mm_index``, the slots'
-        rows of the engine's image-row buffer (``SequenceDescriptor.mm_index``)."""
+        the rest are padding rows (uid -1, chunk_len 0, an all-null block
+        table), so that a compiled program keeps one shape whatever the
+        scheduler decides.  ``mm``: also ``mm_index``, the slots' rows of the
+        engine's image-row buffer (``SequenceDescriptor.mm_index``)."""
         n_rows = sum(rows for _, rows, _ in groups)
         tokens = np.zeros((sum(rows * width for _, rows, width in groups), ), np.int32)
         mm_index = np.full(tokens.shape, -1, np.int32) if mm else None

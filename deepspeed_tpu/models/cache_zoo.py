@@ -6,12 +6,15 @@ container classes; here each gets a cache twin whose param tree mirrors its
 training model exactly (so converted HF checkpoints apply unchanged) and
 whose attention goes through the shared ``paged_attention_core``
 (models/llama_cache.py): chunked forward, KV arena threaded through, one
-program for prefill / continuation / decode.
+program for prefill / continuation / decode.  Like every twin they take a
+step's row groups (models/llama_cache.py "Row groups"): norms, projections,
+rope, OPT's position table, the MLPs and the experts run on the flat axis
+[T, hidden], the page writes and the attention group by group.
 """
 
 import dataclasses
 from functools import partial
-from typing import Callable
+from typing import Callable, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -20,8 +23,8 @@ from flax import linen as nn
 from ..inference.v2.geometry import LinearGeometry, RingSummaryGeometry, SlotPagesGeometry
 from .llama import (EMBED, HEAD_DIM, HEADS, KV_HEADS, MLP, VOCAB, LlamaConfig, RMSNorm, _logical, apply_rope,
                     rotary_embedding)
-from .llama_cache import (LlamaForCausalLMWithCache, init_kv_cache, paged_attention_core, sampled_rows,
-                          scan_blocks)
+from .llama_cache import (LlamaAttentionCache, LlamaForCausalLMWithCache, flat_positions, flat_step, init_kv_cache,
+                          lm_head, logits_as, paged_attention_core, sampled_rows, scan_blocks)
 from .evabyte import EvaByteConfig
 from .evabyte_cache import EvaByteForCausalLMWithCache
 from .falcon import FalconConfig
@@ -37,7 +40,7 @@ from .phi import PhiConfig, apply_partial_rope
 from .phi4flash import Phi4FlashConfig
 from .phi4flash_cache import Phi4FlashForCausalLMWithCache
 from .phi4flash_cache import init_cache as init_phi4flash_cache
-from .qwen2_moe import Qwen2MoeConfig, Qwen2MoeSparseMLP
+from .qwen2_moe import Qwen2MoeConfig, Qwen2MoeDenseMLP, Qwen2MoeSparseMLP
 from .xing4 import Xing4Config
 from .xing4_cache import LatentPagesGeometry, Xing4ForCausalLMWithCache
 from .xing4_cache import init_cache as init_xing4_cache, walk_rows as xing4_walk_rows
@@ -48,10 +51,11 @@ from .xing4_cache import init_cache as init_xing4_cache, walk_rows as xing4_walk
 
 class FalconAttentionCache(nn.Module):
     cfg: FalconConfig
-    page_size: int = 16
+    page_size: int
+    groups: Tuple[Tuple[int, int], ...]
 
     @nn.compact
-    def __call__(self, x, positions, pages, block_table, start_pos, chunk_lens=None, layer=None):
+    def __call__(self, x, positions, pages, block_table, start_pos, chunk_lens, layer):
         cfg = self.cfg
         H, KV = cfg.num_attention_heads, cfg.num_kv_heads
         D = cfg.hidden_size // H
@@ -72,8 +76,8 @@ class FalconAttentionCache(nn.Module):
             cos, sin = rotary_embedding(positions, D, cfg.rope_theta)
             q = apply_rope(q, cos, sin)
             k = apply_rope(k, cos, sin)
-        out, pages = paged_attention_core(q, k, v, pages, block_table, start_pos, chunk_lens, self.page_size,
-                                          attention_impl=cfg.attention_impl, alibi_slopes=slopes, layer=layer)
+        out, pages = paged_attention_core(self.groups, q, k, v, pages, layer, block_table, start_pos, chunk_lens,
+                                          self.page_size, attention_impl=cfg.attention_impl, alibi_slopes=slopes)
         out = nn.DenseGeneral(features=cfg.hidden_size, axis=(-2, -1), use_bias=cfg.bias,
                               dtype=cfg.dtype, param_dtype=cfg.param_dtype,
                               kernel_init=_logical(nn.initializers.lecun_normal(), (HEADS, HEAD_DIM, EMBED)),
@@ -83,10 +87,11 @@ class FalconAttentionCache(nn.Module):
 
 class FalconBlockCache(nn.Module):
     cfg: FalconConfig
-    page_size: int = 16
+    page_size: int
+    groups: Tuple[Tuple[int, int], ...]
 
     @nn.compact
-    def __call__(self, carry, layer, positions, block_table, start_pos, chunk_lens=None):
+    def __call__(self, carry, layer, positions, block_table, start_pos, chunk_lens):
         cfg = self.cfg
         x, pages = carry
         ln = partial(nn.LayerNorm, epsilon=cfg.layer_norm_epsilon, dtype=cfg.dtype,
@@ -104,7 +109,7 @@ class FalconBlockCache(nn.Module):
         if not cfg.parallel_attn:
             # falcon-rw sequential residual: ln1 → attn → add; ln2 → mlp → add
             attn_in = ln(name="input_layernorm")(x)
-            attn_out, pages = FalconAttentionCache(cfg, self.page_size, name="self_attention")(
+            attn_out, pages = FalconAttentionCache(cfg, self.page_size, self.groups, name="self_attention")(
                 attn_in, positions, pages, block_table, start_pos, chunk_lens, layer)
             h = x + attn_out
             return (h + mlp(ln(name="post_attention_layernorm")(h)), pages), None
@@ -115,7 +120,7 @@ class FalconBlockCache(nn.Module):
         else:
             attn_in = ln(name="input_layernorm")(x)
             mlp_in = attn_in
-        attn_out, pages = FalconAttentionCache(cfg, self.page_size, name="self_attention")(
+        attn_out, pages = FalconAttentionCache(cfg, self.page_size, self.groups, name="self_attention")(
             attn_in, positions, pages, block_table, start_pos, chunk_lens, layer)
         return (x + attn_out + mlp(mlp_in), pages), None
 
@@ -125,25 +130,20 @@ class FalconForCausalLMWithCache(nn.Module):
     page_size: int = 16
 
     @nn.compact
-    def __call__(self, input_ids, start_pos, block_table, cache, chunk_lens=None, last_only=False):
+    def __call__(self, input_ids, start_pos, block_table, cache, chunk_lens=None, last_only=False, groups=None):
         cfg = self.cfg
-        positions = start_pos[:, None] + jnp.arange(input_ids.shape[1])[None, :]
+        tokens, groups, chunk_lens = flat_step(input_ids, chunk_lens, groups)
+        positions = flat_positions(groups, start_pos)
         embed = nn.Embed(cfg.vocab_size, cfg.hidden_size, dtype=cfg.dtype, param_dtype=cfg.param_dtype,
                          embedding_init=_logical(nn.initializers.normal(0.02), (VOCAB, EMBED)),
                          name="word_embeddings")
-        x = embed(input_ids)
-        (x, cache), _ = scan_blocks(FalconBlockCache, cfg.num_hidden_layers)(cfg, self.page_size, name="h")(
+        x = embed(tokens)
+        (x, cache), _ = scan_blocks(FalconBlockCache, cfg.num_hidden_layers)(cfg, self.page_size, groups, name="h")(
             (x, cache), jnp.arange(cfg.num_hidden_layers), positions, block_table, start_pos, chunk_lens)
-        x = sampled_rows(x, chunk_lens, last_only)
+        x = sampled_rows(x, chunk_lens, last_only, groups)
         x = nn.LayerNorm(epsilon=cfg.layer_norm_epsilon, dtype=cfg.dtype, param_dtype=cfg.param_dtype,
                          name="ln_f")(x)
-        if cfg.tie_word_embeddings:
-            return embed.attend(x), cache
-        logits = nn.DenseGeneral(features=cfg.vocab_size, use_bias=False, dtype=cfg.dtype,
-                                 param_dtype=cfg.param_dtype,
-                                 kernel_init=_logical(nn.initializers.lecun_normal(), (EMBED, VOCAB)),
-                                 name="lm_head")(x)
-        return logits, cache
+        return logits_as(lm_head(cfg, embed, x), input_ids, last_only), cache
 
 
 # ---------------------------------------------------------------------- opt
@@ -151,10 +151,11 @@ class FalconForCausalLMWithCache(nn.Module):
 
 class OPTAttentionCache(nn.Module):
     cfg: OPTConfig
-    page_size: int = 16
+    page_size: int
+    groups: Tuple[Tuple[int, int], ...]
 
     @nn.compact
-    def __call__(self, x, pages, block_table, start_pos, chunk_lens=None, layer=None):
+    def __call__(self, x, pages, block_table, start_pos, chunk_lens, layer):
         cfg = self.cfg
         H = cfg.num_attention_heads
         D = cfg.hidden_size // H
@@ -165,8 +166,8 @@ class OPTAttentionCache(nn.Module):
                   name="k_proj")(x)
         v = dense(features=(H, D), kernel_init=_logical(nn.initializers.lecun_normal(), (EMBED, KV_HEADS, HEAD_DIM)),
                   name="v_proj")(x)
-        out, pages = paged_attention_core(q, k, v, pages, block_table, start_pos, chunk_lens, self.page_size,
-                                          attention_impl=cfg.attention_impl, layer=layer)
+        out, pages = paged_attention_core(self.groups, q, k, v, pages, layer, block_table, start_pos, chunk_lens,
+                                          self.page_size, attention_impl=cfg.attention_impl)
         out = nn.DenseGeneral(features=cfg.hidden_size, axis=(-2, -1), use_bias=True,
                               dtype=cfg.dtype, param_dtype=cfg.param_dtype,
                               kernel_init=_logical(nn.initializers.lecun_normal(), (HEADS, HEAD_DIM, EMBED)),
@@ -176,15 +177,16 @@ class OPTAttentionCache(nn.Module):
 
 class OPTBlockCache(nn.Module):
     cfg: OPTConfig
-    page_size: int = 16
+    page_size: int
+    groups: Tuple[Tuple[int, int], ...]
 
     @nn.compact
-    def __call__(self, carry, layer, positions, block_table, start_pos, chunk_lens=None):
+    def __call__(self, carry, layer, positions, block_table, start_pos, chunk_lens):
         cfg = self.cfg
         x, pages = carry
         ln = partial(nn.LayerNorm, epsilon=1e-5, dtype=cfg.dtype, param_dtype=cfg.param_dtype)
         a_in = ln(name="self_attn_layer_norm")(x) if cfg.do_layer_norm_before else x
-        a, pages = OPTAttentionCache(cfg, self.page_size, name="self_attn")(
+        a, pages = OPTAttentionCache(cfg, self.page_size, self.groups, name="self_attn")(
             a_in, pages, block_table, start_pos, chunk_lens, layer)
         h = x + a
         if not cfg.do_layer_norm_before:
@@ -206,9 +208,10 @@ class OPTForCausalLMWithCache(nn.Module):
     page_size: int = 16
 
     @nn.compact
-    def __call__(self, input_ids, start_pos, block_table, cache, chunk_lens=None, last_only=False):
+    def __call__(self, input_ids, start_pos, block_table, cache, chunk_lens=None, last_only=False, groups=None):
         cfg = self.cfg
-        positions = start_pos[:, None] + jnp.arange(input_ids.shape[1])[None, :]
+        tokens, groups, chunk_lens = flat_step(input_ids, chunk_lens, groups)
+        positions = flat_positions(groups, start_pos)
         proj_dim = cfg.word_embed_proj_dim or cfg.hidden_size
         embed = nn.Embed(cfg.vocab_size, proj_dim, dtype=cfg.dtype, param_dtype=cfg.param_dtype,
                          embedding_init=_logical(nn.initializers.normal(0.02), (VOCAB, EMBED)),
@@ -219,27 +222,21 @@ class OPTForCausalLMWithCache(nn.Module):
         # pad-region positions can exceed the learned table (prefill chunk >
         # max_position): clamp — jnp.take would otherwise FILL (NaN)
         safe_pos = jnp.minimum(positions, cfg.max_position_embeddings - 1)
-        x = embed(input_ids)
+        x = embed(tokens)
         if proj_dim != cfg.hidden_size:
             x = nn.Dense(cfg.hidden_size, use_bias=False, dtype=cfg.dtype,
                          param_dtype=cfg.param_dtype, name="project_in")(x)
         x = x + pos_embed(safe_pos + 2)
-        (x, cache), _ = scan_blocks(OPTBlockCache, cfg.num_hidden_layers)(cfg, self.page_size, name="layers")(
+        (x, cache), _ = scan_blocks(OPTBlockCache, cfg.num_hidden_layers)(cfg, self.page_size, groups, name="layers")(
             (x, cache), jnp.arange(cfg.num_hidden_layers), positions, block_table, start_pos, chunk_lens)
-        x = sampled_rows(x, chunk_lens, last_only)
+        x = sampled_rows(x, chunk_lens, last_only, groups)
         if cfg.do_layer_norm_before:
             x = nn.LayerNorm(epsilon=1e-5, dtype=cfg.dtype, param_dtype=cfg.param_dtype,
                              name="final_layer_norm")(x)
         if proj_dim != cfg.hidden_size:
             x = nn.Dense(proj_dim, use_bias=False, dtype=cfg.dtype,
                          param_dtype=cfg.param_dtype, name="project_out")(x)
-        if cfg.tie_word_embeddings:
-            return embed.attend(x), cache
-        logits = nn.DenseGeneral(features=cfg.vocab_size, use_bias=False, dtype=cfg.dtype,
-                                 param_dtype=cfg.param_dtype,
-                                 kernel_init=_logical(nn.initializers.lecun_normal(), (EMBED, VOCAB)),
-                                 name="lm_head")(x)
-        return logits, cache
+        return logits_as(lm_head(cfg, embed, x), input_ids, last_only), cache
 
 
 # ---------------------------------------------------------------------- phi
@@ -247,10 +244,11 @@ class OPTForCausalLMWithCache(nn.Module):
 
 class PhiAttentionCache(nn.Module):
     cfg: PhiConfig
-    page_size: int = 16
+    page_size: int
+    groups: Tuple[Tuple[int, int], ...]
 
     @nn.compact
-    def __call__(self, x, positions, pages, block_table, start_pos, chunk_lens=None, layer=None):
+    def __call__(self, x, positions, pages, block_table, start_pos, chunk_lens, layer):
         cfg = self.cfg
         H, KV = cfg.num_attention_heads, cfg.num_key_value_heads
         D = cfg.hidden_size // H
@@ -270,8 +268,8 @@ class PhiAttentionCache(nn.Module):
         cos, sin = rotary_embedding(positions, rot_dim, cfg.rope_theta)
         q = apply_partial_rope(q, cos, sin, rot_dim)
         k = apply_partial_rope(k, cos, sin, rot_dim)
-        out, pages = paged_attention_core(q, k, v, pages, block_table, start_pos, chunk_lens, self.page_size,
-                                          attention_impl=cfg.attention_impl, layer=layer)
+        out, pages = paged_attention_core(self.groups, q, k, v, pages, layer, block_table, start_pos, chunk_lens,
+                                          self.page_size, attention_impl=cfg.attention_impl)
         out = nn.DenseGeneral(features=cfg.hidden_size, axis=(-2, -1), use_bias=True,
                               dtype=cfg.dtype, param_dtype=cfg.param_dtype,
                               kernel_init=_logical(nn.initializers.lecun_normal(), (HEADS, HEAD_DIM, EMBED)),
@@ -281,15 +279,16 @@ class PhiAttentionCache(nn.Module):
 
 class PhiBlockCache(nn.Module):
     cfg: PhiConfig
-    page_size: int = 16
+    page_size: int
+    groups: Tuple[Tuple[int, int], ...]
 
     @nn.compact
-    def __call__(self, carry, layer, positions, block_table, start_pos, chunk_lens=None):
+    def __call__(self, carry, layer, positions, block_table, start_pos, chunk_lens):
         cfg = self.cfg
         x, pages = carry
         h = nn.LayerNorm(epsilon=cfg.layer_norm_eps, dtype=cfg.dtype, param_dtype=cfg.param_dtype,
                          name="input_layernorm")(x)
-        attn_out, pages = PhiAttentionCache(cfg, self.page_size, name="self_attn")(
+        attn_out, pages = PhiAttentionCache(cfg, self.page_size, self.groups, name="self_attn")(
             h, positions, pages, block_table, start_pos, chunk_lens, layer)
         m = nn.Dense(cfg.intermediate_size, use_bias=True, dtype=cfg.dtype, param_dtype=cfg.param_dtype,
                      kernel_init=_logical(nn.initializers.lecun_normal(), (EMBED, MLP)), name="fc1")(h)
@@ -304,22 +303,23 @@ class PhiForCausalLMWithCache(nn.Module):
     page_size: int = 16
 
     @nn.compact
-    def __call__(self, input_ids, start_pos, block_table, cache, chunk_lens=None, last_only=False):
+    def __call__(self, input_ids, start_pos, block_table, cache, chunk_lens=None, last_only=False, groups=None):
         cfg = self.cfg
-        positions = start_pos[:, None] + jnp.arange(input_ids.shape[1])[None, :]
+        tokens, groups, chunk_lens = flat_step(input_ids, chunk_lens, groups)
+        positions = flat_positions(groups, start_pos)
         embed = nn.Embed(cfg.vocab_size, cfg.hidden_size, dtype=cfg.dtype, param_dtype=cfg.param_dtype,
                          embedding_init=_logical(nn.initializers.normal(0.02), (VOCAB, EMBED)),
                          name="embed_tokens")
-        x = embed(input_ids)
-        (x, cache), _ = scan_blocks(PhiBlockCache, cfg.num_hidden_layers)(cfg, self.page_size, name="layers")(
+        x = embed(tokens)
+        (x, cache), _ = scan_blocks(PhiBlockCache, cfg.num_hidden_layers)(cfg, self.page_size, groups, name="layers")(
             (x, cache), jnp.arange(cfg.num_hidden_layers), positions, block_table, start_pos, chunk_lens)
-        x = sampled_rows(x, chunk_lens, last_only)
+        x = sampled_rows(x, chunk_lens, last_only, groups)
         x = nn.LayerNorm(epsilon=cfg.layer_norm_eps, dtype=cfg.dtype, param_dtype=cfg.param_dtype,
                          name="final_layernorm")(x)
         logits = nn.Dense(cfg.vocab_size, use_bias=True, dtype=cfg.dtype, param_dtype=cfg.param_dtype,
                           kernel_init=_logical(nn.initializers.lecun_normal(), (EMBED, VOCAB)),
                           name="lm_head")(x)
-        return logits, cache
+        return logits_as(logits, input_ids, last_only), cache
 
 
 # ---------------------------------------------------------------- qwen2-moe
@@ -327,22 +327,22 @@ class PhiForCausalLMWithCache(nn.Module):
 
 class Qwen2MoeBlockCache(nn.Module):
     cfg: Qwen2MoeConfig
-    page_size: int = 16
+    page_size: int
+    groups: Tuple[Tuple[int, int], ...]
     sparse: bool = True   # mixed stacks: dense SwiGLU for mlp_only/off-step layers
 
     @nn.compact
-    def __call__(self, carry, layer, positions, block_table, start_pos, chunk_lens=None):
-        from .llama_cache import LlamaAttentionCache
-        from .qwen2_moe import Qwen2MoeDenseMLP
+    def __call__(self, carry, layer, positions, block_table, start_pos, chunk_lens):
         cfg = self.cfg
         x, pages = carry
-        attn_out, pages = LlamaAttentionCache(cfg.as_llama(), self.page_size, name="self_attn")(
+        attn_out, pages = LlamaAttentionCache(cfg.as_llama(), self.page_size, self.groups, name="self_attn")(
             RMSNorm(cfg.rms_norm_eps, cfg.dtype, cfg.param_dtype, name="input_layernorm")(x), positions,
             pages, block_table, start_pos, chunk_lens, layer)
         h = x + attn_out
         mlp = Qwen2MoeSparseMLP(cfg, name="mlp") if self.sparse else Qwen2MoeDenseMLP(cfg, name="mlp")
+        # the step is one group of T tokens to the router and the sort
         out = h + mlp(
-            RMSNorm(cfg.rms_norm_eps, cfg.dtype, cfg.param_dtype, name="post_attention_layernorm")(h))
+            RMSNorm(cfg.rms_norm_eps, cfg.dtype, cfg.param_dtype, name="post_attention_layernorm")(h)[None])[0]
         return (out, pages), None
 
 
@@ -351,35 +351,30 @@ class Qwen2MoeForCausalLMWithCache(nn.Module):
     page_size: int = 16
 
     @nn.compact
-    def __call__(self, input_ids, start_pos, block_table, cache, chunk_lens=None, last_only=False):
+    def __call__(self, input_ids, start_pos, block_table, cache, chunk_lens=None, last_only=False, groups=None):
         cfg = self.cfg
-        positions = start_pos[:, None] + jnp.arange(input_ids.shape[1])[None, :]
+        tokens, groups, chunk_lens = flat_step(input_ids, chunk_lens, groups)
+        positions = flat_positions(groups, start_pos)
         embed = nn.Embed(cfg.vocab_size, cfg.hidden_size, dtype=cfg.dtype, param_dtype=cfg.param_dtype,
                          embedding_init=_logical(nn.initializers.normal(0.02), (VOCAB, EMBED)),
                          name="embed_tokens")
-        x = embed(input_ids)
+        x = embed(tokens)
         if cfg.mixed_stack:
             # dense/sparse layers can't share one scanned body — unroll with
             # per-layer dispatch, mirroring the training model's layers_{i}
             # naming so converted checkpoints apply unchanged; the arena stays
             # whole here too, each block naming its layer in it
             for i in range(cfg.num_hidden_layers):
-                (x, cache), _ = Qwen2MoeBlockCache(cfg, self.page_size, sparse=cfg.layer_is_sparse(i),
+                (x, cache), _ = Qwen2MoeBlockCache(cfg, self.page_size, groups, sparse=cfg.layer_is_sparse(i),
                                                    name=f"layers_{i}")((x, cache), i, positions, block_table,
                                                                        start_pos, chunk_lens)
         else:
             (x, cache), _ = scan_blocks(Qwen2MoeBlockCache, cfg.num_hidden_layers)(
-                cfg, self.page_size, name="layers")((x, cache), jnp.arange(cfg.num_hidden_layers), positions,
-                                                    block_table, start_pos, chunk_lens)
-        x = sampled_rows(x, chunk_lens, last_only)
+                cfg, self.page_size, groups, name="layers")((x, cache), jnp.arange(cfg.num_hidden_layers), positions,
+                                                            block_table, start_pos, chunk_lens)
+        x = sampled_rows(x, chunk_lens, last_only, groups)
         x = RMSNorm(cfg.rms_norm_eps, cfg.dtype, cfg.param_dtype, name="norm")(x)
-        if cfg.tie_word_embeddings:
-            return embed.attend(x), cache
-        logits = nn.DenseGeneral(features=cfg.vocab_size, use_bias=False, dtype=cfg.dtype,
-                                 param_dtype=cfg.param_dtype,
-                                 kernel_init=_logical(nn.initializers.lecun_normal(), (EMBED, VOCAB)),
-                                 name="lm_head")(x)
-        return logits, cache
+        return logits_as(lm_head(cfg, embed, x), input_ids, last_only), cache
 
 
 @dataclasses.dataclass(frozen=True)

@@ -36,7 +36,7 @@ scan that takes a layer's pages in and hands them out stacks a second arena,
 which an arena of half the chip's memory has no room for.)
 """
 
-from typing import Optional, Tuple
+from typing import Tuple
 
 import jax
 import jax.numpy as jnp
@@ -86,8 +86,8 @@ class EvaByteBlockCache(nn.Module):
     projections, rope and the MLP run there; the ring's writes, the summaries,
     the kernel's view and the attention are a row's, group by group."""
     cfg: EvaByteConfig
-    page_size: int = 16
-    groups: Optional[Tuple[Tuple[int, int], ...]] = None
+    page_size: int
+    groups: Tuple[Tuple[int, int], ...]
 
     @nn.compact
     def __call__(self, carry, layer, positions, block_table, start_pos, chunk_lens):
@@ -123,7 +123,6 @@ class EvaByteForCausalLMWithCache(nn.Module):
     (``LlamaForCausalLMWithCache``)."""
     cfg: EvaByteConfig
     page_size: int = 16
-    takes_row_groups = True
 
     @nn.compact
     def __call__(self, input_ids, start_pos, block_table, cache, chunk_lens=None, last_only=False, groups=None):

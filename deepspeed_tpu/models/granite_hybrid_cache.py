@@ -189,7 +189,6 @@ class GraniteHybridForCausalLMWithCache(nn.Module):
     (``LlamaForCausalLMWithCache``)."""
     cfg: GraniteHybridConfig
     page_size: int = 16
-    takes_row_groups = True
 
     @nn.compact
     def __call__(self, input_ids, start_pos, block_table, cache, chunk_lens=None, last_only=False, groups=None):

@@ -55,7 +55,6 @@ class KimiVLForCausalLMWithCache(VisionFront):
     """The twin: every twin's ``apply``, with ``mm_index`` and ``mm_rows``
     behind it, and ``encode_images``."""
     page_size: int = 16
-    takes_row_groups = True
     #: a prefill group's step program takes an index a slot into the engine's image rows
     takes_image_rows = True
 

@@ -41,21 +41,18 @@ rectangle has 2,048.  Whatever is a function of a token alone (embedding,
 norms, projections, rope, the MLP or the experts, the residual) runs on the
 flat axis, ``[T, hidden]``; what needs a row's sequence (the page writes, the
 paged attention) runs group by group at the group's own rectangle
-(``over_row_groups``).  A twin whose blocks are written so says
-``takes_row_groups = True``, and the engine hands it a mixed step in two
-groups; any other gets rectangles only.  The twins of this file, of
-``mixtral_cache.py``, ``evabyte_cache.py``, ``xing4_cache.py``,
-``phi4flash_cache.py`` and ``granite_hybrid_cache.py`` take groups; the other
-families of ``cache_zoo.py`` do not.  A twin that holds a state slot a
-sequence (the last two) also runs group by group what reads or writes the
-slot: the causal convolution with the slot's tail, the recurrence with the
-slot's state (in the form the group's width asks for: one position a row, or
-a chunk), a window layer's ring; its ``arena`` is the dict of its cache's
-arrays, threaded through the groups whole as a single arena is.
+(``over_row_groups``).  Every twin is written so, and the engine hands each
+a mixed step in two groups.  A twin that holds a state slot a sequence
+(``phi4flash_cache.py``, ``granite_hybrid_cache.py``) also runs group by
+group what reads or writes the slot: the causal convolution with the slot's
+tail, the recurrence with the slot's state (in the form the group's width
+asks for: one position a row, or a chunk), a window layer's ring; its
+``arena`` is the dict of its cache's arrays, threaded through the groups
+whole as a single arena is.
 """
 
 import dataclasses
-from typing import Any, Optional, Tuple
+from typing import Tuple
 
 import jax
 import jax.numpy as jnp
@@ -90,21 +87,17 @@ def init_kv_cache(cfg, kv: PagedKVConfig, dtype=jnp.bfloat16):
                      dtype)
 
 
-def _write_pages(pages, k_new, v_new, block_table, start_pos, page_size, chunk_lens=None, layer=None):
-    """Scatter a chunk's K/V into the arena pages.
+def _write_pages(pages, k_new, v_new, block_table, start_pos, page_size, chunk_lens, layer):
+    """Scatter a chunk's K/V into layer ``layer`` of the arena.
 
-    pages: [P, page, 2, n_kv, hd] (one layer)   k/v_new: [B, C, n_kv, hd]
-    block_table: [B, max_pages]  start_pos: [B]  chunk_lens: [B] or None —
+    pages: the whole arena [L, P, page, 2, n_kv, hd]   k/v_new: [B, C, n_kv, hd]
+    block_table: [B, max_pages]  start_pos: [B]  chunk_lens: [B] —
     positions at/after a row's chunk_len are padding; their writes are
-    redirected to the reserved null page 0.  With ``layer`` (an index, traced
-    in a scanned trunk) ``pages`` is the whole arena [L, P, page, 2, n_kv, hd]
-    and the rows go into that layer of it.  This is the form that updates in
-    place: the arena is the layer loop's carry, so the scatter's operand and
-    result are one buffer and only the chunk's B*C rows move.  Without
-    ``layer`` the scatter is into one layer's pages, in place only where that
-    layer is a buffer of its own (the unrolled trunk's tuple); a layer sliced
-    out of a stacked arena by a scan is a copy, and so is the stack it goes
-    back into.
+    redirected to the reserved null page 0.  ``layer`` is an index (traced in
+    a scanned trunk).  The arena is the layer loop's carry, so the scatter's
+    operand and result are one buffer and only the chunk's B*C rows move; a
+    layer sliced out of a stacked arena by a scan would be a copy, and so
+    would the stack it goes back into.
     """
     b, c = k_new.shape[0], k_new.shape[1]
     positions = start_pos[:, None] + jnp.arange(c)[None, :]          # [B, C]
@@ -113,18 +106,16 @@ def _write_pages(pages, k_new, v_new, block_table, start_pos, page_size, chunk_l
     page_slot = jnp.minimum(positions // page_size, block_table.shape[1] - 1)
     page_idx = jnp.take_along_axis(block_table, page_slot, axis=1)   # [B, C]
     kv_chunk = jnp.stack([k_new, v_new], axis=2)                      # [B, C, 2, n_kv, hd]
-    if chunk_lens is not None:
-        valid = jnp.arange(c)[None, :] < chunk_lens[:, None]          # [B, C]
-        page_idx = jnp.where(valid, page_idx, 0)
-        # ALSO zero the redirected values: pad-region activations can be
-        # non-finite (e.g. out-of-range learned-position lookups fill NaN),
-        # and a NaN-poisoned null page turns masked attention into NaN via
-        # 0 * NaN in the probs @ V matmul
-        kv_chunk = jnp.where(valid[:, :, None, None, None], kv_chunk, 0)
+    valid = jnp.arange(c)[None, :] < chunk_lens[:, None]              # [B, C]
+    page_idx = jnp.where(valid, page_idx, 0)
+    # ALSO zero the redirected values: pad-region activations can be
+    # non-finite (e.g. out-of-range learned-position lookups fill NaN),
+    # and a NaN-poisoned null page turns masked attention into NaN via
+    # 0 * NaN in the probs @ V matmul
+    kv_chunk = jnp.where(valid[:, :, None, None, None], kv_chunk, 0)
     slot_idx = positions % page_size                                  # [B, C]
     flat_kv = kv_chunk.reshape((-1, ) + kv_chunk.shape[2:])           # [B*C, 2, n_kv, hd]
-    where = (page_idx.reshape(-1), slot_idx.reshape(-1))
-    return pages.at[where if layer is None else (layer, ) + where].set(flat_kv)
+    return pages.at[layer, page_idx.reshape(-1), slot_idx.reshape(-1)].set(flat_kv)
 
 
 def paged_attention(q, pages, block_table, start_pos, chunk_lens, page_size, sliding_window=0,
@@ -181,24 +172,28 @@ def reads_through_kernel(attention_impl, alibi=False) -> bool:
     return attention_impl == "flash" and not alibi
 
 
-def paged_attention_core(q, k, v, pages, block_table, start_pos, chunk_lens, page_size,
-                         attention_impl="reference", sliding_window=0, alibi_slopes=None, layer=None):
-    """Shared paged-KV attention core for every model family's cache twin:
-    write this chunk's K/V into the arena, then attend the chunk's queries
-    against (history + chunk).  q/k/v are post-projection, post-RoPE
-    [B, C, N(H|KV), D].  ``pages`` is one layer's pages, or with ``layer``
-    the whole arena (``_write_pages``).  Returns (out [B, C, H, D], new_pages)."""
-    pages = _write_pages(pages, k.astype(pages.dtype), v.astype(pages.dtype), block_table,
-                         start_pos, page_size, chunk_lens, layer=layer)
-    if reads_through_kernel(attention_impl, alibi_slopes is not None):
-        from ..ops.paged_attention import paged_attention_pallas
-        out = paged_attention_pallas(q, pages, block_table, start_pos, chunk_lens, page_size, layer=layer,
-                                     window=sliding_window)
-    else:
+def paged_attention_core(groups, q, k, v, pages, layer, block_table, start_pos, chunk_lens, page_size,
+                         attention_impl="reference", sliding_window=0, alibi_slopes=None):
+    """Shared paged-KV attention core of the softmax twins whose pages hold a
+    token's keys and values: group by group of the step's ``groups``, write
+    the chunk's K/V into layer ``layer`` of the arena ``pages``
+    (``_write_pages``), then attend the chunk's queries against (history +
+    chunk).  q/k/v are post-projection, post-RoPE, on the flat axis
+    [T, N(H|KV), D]; the block table, ``start_pos`` and ``chunk_lens`` one
+    entry a row.  Returns (out [T, H, D], the arena)."""
+
+    def attend(pages, q, k, v, block_table, start_pos, chunk_lens):
+        pages = _write_pages(pages, k.astype(pages.dtype), v.astype(pages.dtype), block_table, start_pos, page_size,
+                             chunk_lens, layer=layer)
+        if reads_through_kernel(attention_impl, alibi_slopes is not None):
+            from ..ops.paged_attention import paged_attention_pallas
+            return paged_attention_pallas(q, pages, block_table, start_pos, chunk_lens, page_size, layer=layer,
+                                          window=sliding_window), pages
         # out of the whole arena the jnp form reads a layer's slice, 1/L of an arena
-        out = paged_attention(q, pages if layer is None else pages[layer], block_table, start_pos, chunk_lens,
-                              page_size, sliding_window=sliding_window, alibi_slopes=alibi_slopes)
-    return out, pages
+        return paged_attention(q, pages[layer], block_table, start_pos, chunk_lens, page_size,
+                               sliding_window=sliding_window, alibi_slopes=alibi_slopes), pages
+
+    return over_row_groups(groups, attend, pages, (q, k, v), (block_table, start_pos, chunk_lens))
 
 
 def flat_step(input_ids, chunk_lens, groups):
@@ -255,26 +250,22 @@ def over_row_groups(groups, attend, arena, flat, per_row):
     return (out[0] if len(out) == 1 else jnp.concatenate(out)), arena
 
 
-def sampled_rows(x, chunk_lens, last_only, groups=None):
+def sampled_rows(x, chunk_lens, last_only, groups):
     """What of a trunk's output goes on to the final norm and the head: with
     ``last_only`` each row's last real token alone, [R, 1, H].  The engine's
     step programs sample from nothing else, and a head over every slot of a
     mixed step is its largest product and buffer where the vocabulary is
     large; the logits of every position are for who compares them (the verify
-    program, the benchmark's check, the tests).  ``x`` is a rectangle
-    [B, C, H], or with ``groups`` the flat axis [T, H], of which a row's last
-    real token is that of its chunk in its group."""
+    program, the benchmark's check, the tests).  ``x`` is the flat axis
+    [T, H] of ``groups``, of which a row's last real token is that of its
+    chunk in its group."""
     if not last_only:
         return x
-    if groups is not None:
-        first, t0 = [], 0
-        for rows, width in groups:
-            first.append(t0 + width * np.arange(rows, dtype=np.int32))
-            t0 += rows * width
-        return x[np.concatenate(first) + jnp.maximum(chunk_lens - 1, 0)][:, None]
-    if chunk_lens is None:
-        return x[:, -1:]
-    return jnp.take_along_axis(x, jnp.maximum(chunk_lens - 1, 0)[:, None, None], axis=1)
+    first, t0 = [], 0
+    for rows, width in groups:
+        first.append(t0 + width * np.arange(rows, dtype=np.int32))
+        t0 += rows * width
+    return x[np.concatenate(first) + jnp.maximum(chunk_lens - 1, 0)][:, None]
 
 
 def logits_as(logits, input_ids, last_only):
@@ -282,6 +273,15 @@ def logits_as(logits, input_ids, last_only):
     ([B, C, V] of a rectangle, [T, V] of flat tokens); ``last_only``'s
     [R, 1, V] as they are."""
     return logits if last_only else logits.reshape(input_ids.shape + logits.shape[-1:])
+
+
+def lm_head(cfg, embed, x):
+    """The logits of ``x``: the tied embedding's, else an ``lm_head`` without
+    bias, made in the caller's scope."""
+    if cfg.tie_word_embeddings:
+        return embed.attend(x)
+    return nn.DenseGeneral(features=cfg.vocab_size, use_bias=False, dtype=cfg.dtype, param_dtype=cfg.param_dtype,
+                           kernel_init=_logical(nn.initializers.lecun_normal(), (EMBED, VOCAB)), name="lm_head")(x)
 
 
 def stack_layer_params(variables, num_layers):
@@ -308,15 +308,15 @@ def stack_layer_params(variables, num_layers):
 
 
 class LlamaAttentionCache(nn.Module):
-    """``x`` and ``positions`` are a rectangle, [B, C, hidden] and [B, C], or
-    with ``groups`` the flat axis, [T, hidden] and [T]: the projections and
-    rope run there, and the page writes and the attention group by group."""
+    """``x`` and ``positions`` are the flat axis of ``groups``, [T, hidden]
+    and [T]: the projections and rope run there, and the page writes and the
+    attention group by group."""
     cfg: LlamaConfig
-    page_size: int = 16
-    groups: Optional[Tuple[Tuple[int, int], ...]] = None
+    page_size: int
+    groups: Tuple[Tuple[int, int], ...]
 
     @nn.compact
-    def __call__(self, x, positions, pages, block_table, start_pos, chunk_lens=None, layer=None):
+    def __call__(self, x, positions, pages, block_table, start_pos, chunk_lens, layer):
         cfg = self.cfg
         head_dim = cfg.hidden_size // cfg.num_attention_heads
         from functools import partial
@@ -334,16 +334,9 @@ class LlamaAttentionCache(nn.Module):
         cos, sin = rotary_embedding(positions, head_dim, cfg.rope_theta)
         q = apply_rope(q, cos, sin)
         k = apply_rope(k, cos, sin)
-
-        def attend(pages, q, k, v, block_table, start_pos, chunk_lens):
-            return paged_attention_core(q, k, v, pages, block_table, start_pos, chunk_lens, self.page_size,
-                                        attention_impl=cfg.attention_impl, sliding_window=cfg.sliding_window,
-                                        layer=layer)
-
-        if self.groups is None:
-            out, pages = attend(pages, q, k, v, block_table, start_pos, chunk_lens)
-        else:
-            out, pages = over_row_groups(self.groups, attend, pages, (q, k, v), (block_table, start_pos, chunk_lens))
+        out, pages = paged_attention_core(self.groups, q, k, v, pages, layer, block_table, start_pos, chunk_lens,
+                                          self.page_size, attention_impl=cfg.attention_impl,
+                                          sliding_window=cfg.sliding_window)
         out = nn.DenseGeneral(features=cfg.hidden_size,
                               axis=(-2, -1),
                               use_bias=False,
@@ -360,11 +353,11 @@ class LlamaBlockCache(nn.Module):
     arena and scans over the layers' indices.  Every softmax twin's block has
     this form.  ``x`` is the flat axis [T, hidden] of ``groups``."""
     cfg: LlamaConfig
-    page_size: int = 16
-    groups: Optional[Tuple[Tuple[int, int], ...]] = None
+    page_size: int
+    groups: Tuple[Tuple[int, int], ...]
 
     @nn.compact
-    def __call__(self, carry, layer, positions, block_table, start_pos, chunk_lens=None):
+    def __call__(self, carry, layer, positions, block_table, start_pos, chunk_lens):
         cfg = self.cfg
         x, pages = carry
         attn_out, pages = LlamaAttentionCache(cfg, self.page_size, self.groups, name="self_attn")(
@@ -397,7 +390,6 @@ class LlamaForCausalLMWithCache(nn.Module):
     several, and the logits of every position have its shape."""
     cfg: LlamaConfig
     page_size: int = 16
-    takes_row_groups = True
 
     @nn.compact
     def __call__(self, input_ids, start_pos, block_table, cache, chunk_lens=None, last_only=False, groups=None):
@@ -428,13 +420,4 @@ class LlamaForCausalLMWithCache(nn.Module):
         x, cache = _Trunk(cfg, self.page_size, name="model")(x, cache, positions, block_table, start_pos, chunk_lens)
         x = sampled_rows(x, chunk_lens, last_only, groups)
         x = RMSNorm(cfg.rms_norm_eps, cfg.dtype, cfg.param_dtype, name="norm")(x)
-        if cfg.tie_word_embeddings:
-            logits = embed.attend(x)
-        else:
-            logits = nn.DenseGeneral(features=cfg.vocab_size,
-                                     use_bias=False,
-                                     dtype=cfg.dtype,
-                                     param_dtype=cfg.param_dtype,
-                                     kernel_init=_logical(nn.initializers.lecun_normal(), (EMBED, VOCAB)),
-                                     name="lm_head")(x)
-        return logits_as(logits, input_ids, last_only), cache
+        return logits_as(lm_head(cfg, embed, x), input_ids, last_only), cache

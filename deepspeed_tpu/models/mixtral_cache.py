@@ -15,7 +15,7 @@ by MixtralPolicy.convert — or trained with the training model — apply
 unchanged.
 """
 
-from typing import Optional, Tuple
+from typing import Tuple
 
 import jax.numpy as jnp
 from flax import linen as nn
@@ -30,8 +30,8 @@ from .mixtral import MixtralConfig
 class MixtralBlockCache(nn.Module):
     """``x`` is the flat axis [T, hidden] of ``groups`` (models/llama_cache.py)."""
     cfg: MixtralConfig
-    page_size: int = 16
-    groups: Optional[Tuple[Tuple[int, int], ...]] = None
+    page_size: int
+    groups: Tuple[Tuple[int, int], ...]
 
     @nn.compact
     def __call__(self, carry, layer, positions, block_table, start_pos, chunk_lens, stacked_banks=None):
@@ -68,7 +68,6 @@ class MixtralForCausalLMWithCache(nn.Module):
     (``LlamaForCausalLMWithCache``)."""
     cfg: MixtralConfig
     page_size: int = 16
-    takes_row_groups = True
 
     def _stacked_banks(self):
         """The blocks' expert banks as the scan holds them, [L, E, ...], for the

@@ -61,11 +61,12 @@ class PhiConfig:
 def apply_partial_rope(x, cos, sin, rotary_dim):
     """Rotate only the first ``rotary_dim`` of each head (HF phi
     rotate_half convention), pass the rest through.
-    x: [B, S, N, D]; cos/sin: [B, S, rotary_dim/2]."""
+    x: [B, S, N, D]; cos/sin: [B, S, rotary_dim/2] (or [T, N, D] with
+    the tables of flat positions [T, rotary_dim/2])."""
     rot, keep = x[..., :rotary_dim].astype(jnp.float32), x[..., rotary_dim:]
     half = rotary_dim // 2
     r1, r2 = rot[..., :half], rot[..., half:]
-    c, s = cos[:, :, None, :], sin[:, :, None, :]
+    c, s = cos[..., None, :], sin[..., None, :]
     rotated = jnp.concatenate([r1 * c - r2 * s, r2 * c + r1 * s], axis=-1)
     return jnp.concatenate([rotated.astype(x.dtype), keep], axis=-1)
 

@@ -298,7 +298,6 @@ class Phi4FlashForCausalLMWithCache(nn.Module):
     (``LlamaForCausalLMWithCache``)."""
     cfg: Phi4FlashConfig
     page_size: int = 16
-    takes_row_groups = True
 
     @nn.compact
     def __call__(self, input_ids, start_pos, block_table, cache, chunk_lens=None, last_only=False, groups=None):

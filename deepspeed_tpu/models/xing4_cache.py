@@ -155,7 +155,6 @@ class Xing4ForCausalLMWithCache(nn.Module):
     last_only, groups)`` -> (logits, new cache): every twin's contract."""
     cfg: Xing4Config
     page_size: int = 16
-    takes_row_groups = True
 
     @nn.compact
     def __call__(self, input_ids, start_pos, block_table, cache, chunk_lens=None, last_only=False, groups=None):

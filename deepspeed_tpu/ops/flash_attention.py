@@ -33,6 +33,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from ..comm.mesh import get_trace_mesh, in_manual_mesh, traced_for_tpu
+
 DEFAULT_MASK_VALUE = -0.7 * float(jnp.finfo(jnp.float32).max)
 LANE = 128  # TPU lane width: per-row scalars are stored lane-broadcast
 
@@ -455,16 +457,6 @@ def _fwd_keylen_kernel(len_ref, q_ref, k_ref, v_ref, o_ref, *scr, scale, bk, P, 
             o_ref[0, :, p * d:(p + 1) * d] = (accs[p][:] / jnp.maximum(ls[p][:], 1e-30)).astype(o_ref.dtype)
 
 
-def _interpret_here() -> bool:
-    """Whether the kernels are interpreted: resolved against the GOVERNING
-    mesh, not the local devices: an AOT compile for an offline TPU topology
-    from a CPU-only host must lower the real kernels, not interpret mode."""
-    from ..comm.mesh import get_trace_mesh
-    tm = get_trace_mesh()
-    dev = tm.devices.flat[0] if tm is not None else jax.devices()[0]
-    return getattr(dev, "platform", "") != "tpu"
-
-
 def flash_attention_keylen(q, k, v, kv_len, *, block_q: int = 256, block_k: int = 512,
                            interpret: Optional[bool] = None):
     """Non-causal attention of q, k, v [B, S, H, D] in which row ``b`` sees its
@@ -475,7 +467,7 @@ def flash_attention_keylen(q, k, v, kv_len, *, block_q: int = 256, block_k: int 
     if s % LANE:
         raise ValueError(f"flash_attention_keylen: {s} positions are no multiple of {LANE}")
     if interpret is None:
-        interpret = _interpret_here()
+        interpret = not traced_for_tpu()
     P = _pack_width(d, h)
     bq, bk = math.gcd(min(block_q, s), s), math.gcd(min(block_k, s), s)
     spec_q = pl.BlockSpec((1, bq, P * d), lambda b, hh, iq, ik, n: (b, iq, hh))
@@ -626,9 +618,8 @@ def flash_attention(q,
         from ..models.llama import chunked_attention
         return chunked_attention(q, k, v, causal=causal, segment_ids=segment_ids,
                                  sliding_window=sliding_window)
-    from ..comm.mesh import get_trace_mesh, in_manual_mesh
     if interpret is None:
-        interpret = _interpret_here()
+        interpret = not traced_for_tpu()
     if isinstance(q, jax.core.Tracer) and not in_manual_mesh():
         mesh = get_trace_mesh()
         if mesh is not None and mesh.size > 1:

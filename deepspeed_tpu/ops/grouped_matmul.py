@@ -48,7 +48,7 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 from jax.sharding import get_abstract_mesh
 
-from ..comm.mesh import get_trace_mesh
+from ..comm.mesh import get_trace_mesh, traced_for_tpu
 
 LANE = 128
 #: what a kernel's blocks may take of VMEM before the compiler is asked for
@@ -60,15 +60,10 @@ _BANK_BLOCK = 16 * 2**20
 _ACC_ELEMENTS = 3 * 2**20
 
 
-def _traced_for_tpu() -> bool:
-    mesh = get_trace_mesh()  # the device the step is traced for (an offline compile's is described)
-    return (mesh.devices.flat[0] if mesh is not None else jax.devices()[0]).platform == "tpu"
-
-
 def takes_kernel() -> bool:
     """True where ``grouped_matmul`` is the Pallas kernel: traced for a TPU,
     on one device or with every mesh axis of more than one device manual."""
-    if not _traced_for_tpu():
+    if not traced_for_tpu():
         return False
     am = get_abstract_mesh()
     if am.manual_axes:

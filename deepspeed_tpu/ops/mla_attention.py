@@ -37,6 +37,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from ..comm.mesh import get_trace_mesh, traced_for_tpu
 from .paged_attention import DEFAULT_MASK_VALUE
 
 _LANES = 128
@@ -188,13 +189,11 @@ def mla_absorbed_pallas(q, pages, block_table, start_pos, chunk_lens, page_size,
     and the kernel reads that layer's pages where they lie.  One device: no
     head-sharded call is built, and the engine refuses tensor-parallel serving
     of latent pages before it gets here (``engine_v2._serving_shardings``)."""
-    from ..comm.mesh import get_trace_mesh
     tm = get_trace_mesh()
     if tm is not None and tm.size > 1:
         raise NotImplementedError("ds_mla_absorbed under a mesh of several devices: no head-sharded call is built")
     if interpret is None:
-        dev = tm.devices.flat[0] if tm is not None else jax.devices()[0]
-        interpret = getattr(dev, "platform", "") != "tpu"
+        interpret = not traced_for_tpu()
     return _mla_call(q, pages, block_table, start_pos, chunk_lens, layer, page_size=page_size, d_v=int(d_v),
                      scale=float(scale), interpret=interpret)
 

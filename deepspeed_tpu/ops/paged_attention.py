@@ -57,6 +57,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from ..comm.mesh import TENSOR_AXIS, get_trace_mesh, in_manual_mesh, traced_for_tpu
+
 DEFAULT_MASK_VALUE = -0.7 * float(jnp.finfo(jnp.float32).max)
 
 #: key rows one step of the walk takes where the kernel copies the pages
@@ -272,72 +274,65 @@ def _paged_kernel(bt_ref, sp_ref, cl_ref, ly_ref, q_ref, *refs, page_size, ppb, 
         jax.lax.fori_loop(0, n_tiles, finish, None)
 
 
-def _paged_sharded(q, pages, block_table, start_pos, chunk_lens, page_size, interpret, mesh, layer=None,
-                   window=0, scale=None):
+def _paged_sharded(q, pages, block_table, start_pos, chunk_lens, layer, page_size, interpret, mesh, window, scale):
     """Run the paged kernel inside shard_map over the governing (trace) mesh.
 
     Mosaic custom calls cannot be auto-partitioned by GSPMD — the TP-sharded
     serving engine (inference/v2) traces this under a tensor-axis mesh, so the
     kernel wraps itself the way ``flash_attention._flash_sharded`` does.
-    Attention is head-local: q shards on H, the page arena on its n_kv dim
-    (one layer's pages or, with ``layer``, the whole arena under one more
-    leading dimension), block tables, positions and the layer's index
-    replicate, and no collective is needed inside — the o_proj allreduce after
-    it is GSPMD's to insert.  A tensor degree that does not divide n_kv
-    replicates (correct, just not distributed)."""
+    Attention is head-local: q shards on H, the arena on its n_kv dim, block
+    tables, positions, chunk lengths and the layer's index replicate, and no
+    collective is needed inside — the o_proj allreduce after it is GSPMD's to
+    insert.  A tensor degree that does not divide n_kv replicates (correct,
+    just not distributed)."""
     from jax.sharding import PartitionSpec as P
 
-    from ..comm.mesh import TENSOR_AXIS
     h, n_kv = q.shape[2], pages.shape[-2]
     tp = mesh.shape.get(TENSOR_AXIS, 1)
     head_axes = (TENSOR_AXIS, ) if tp > 1 and n_kv % tp == 0 and h % tp == 0 else ()
     qspec = P(None, None, head_axes or None, None)
-    pspec = P(*(None, ) * (pages.ndim - 2), head_axes or None, None)
-    optional = {"chunk_lens": (chunk_lens, P(None)),
-                "layer": (None if layer is None else jnp.asarray(layer, jnp.int32), P())}
-    given = {name: arg_spec for name, arg_spec in optional.items() if arg_spec[0] is not None}
+    pspec = P(None, None, None, None, head_axes or None, None)
 
-    def local(q_, pg_, bt_, sp_, *rest):
-        kw = dict(zip(given, rest))
-        return paged_attention_pallas(q_, pg_, bt_, sp_, kw.get("chunk_lens"), page_size,
-                                      layer=kw.get("layer"), window=window, scale=scale, interpret=interpret)
+    def local(q_, pg_, bt_, sp_, cl_, ly_):
+        return paged_attention_pallas(q_, pg_, bt_, sp_, cl_, page_size, layer=ly_, window=window, scale=scale,
+                                      interpret=interpret)
 
     fn = jax.shard_map(
         local,
         mesh=mesh,
-        in_specs=(qspec, pspec, P(None, None), P(None), *(spec for _, spec in given.values())),
+        in_specs=(qspec, pspec, P(None, None), P(None), P(None), P()),
         out_specs=qspec,
         # pallas_call out_shapes carry no varying-mesh-axes annotation
         check_vma=False)
-    return fn(q, pages, block_table, start_pos, *(arg for arg, _ in given.values()))
+    return fn(q, pages, block_table, start_pos, chunk_lens, jnp.asarray(layer, jnp.int32))
 
 
 def paged_attention_pallas(q, pages, block_table, start_pos, chunk_lens, page_size,
-                           *, layer=None, window: int = 0, scale: Optional[float] = None,
+                           *, layer, window: int = 0, scale: Optional[float] = None,
                            interpret: Optional[bool] = None):
-    """Drop-in twin of ``models/llama_cache.paged_attention`` (jnp golden).
+    """The kernel's form of ``models/llama_cache.paged_attention`` (jnp
+    golden) over one layer of the arena.
 
-    q: [B, C, H, D]; pages: [P, page, 2, n_kv, D] (chunk K/V already
-    written); block_table: [B, max_pages]; start_pos/chunk_lens: [B]
-    (``chunk_lens`` None: every row carries its whole chunk).  With ``layer``
-    (an index, traced in a scanned trunk) ``pages`` is the whole arena
-    [L, P, page, 2, n_kv, D] and the kernel reads that layer's pages where
-    they lie: no layer of the arena is sliced out first.  Query rows at and
-    past a row's ``chunk_lens`` come out exactly zero.  With ``window`` (a
-    static count) the query at position ``t`` sees keys ``t - window + 1 ..
-    t`` and the walk starts at the block that holds the row's first visible
-    key; ``scale`` multiplies the scores in place of ``1 / sqrt(D)``.
+    q: [B, C, H, D]; pages: the whole arena [L, P, page, 2, n_kv, D] (chunk
+    K/V already written); ``layer``: an index into it (traced in a scanned
+    trunk), whose pages the kernel reads where they lie: no layer of the
+    arena is sliced out first; block_table: [B, max_pages];
+    start_pos/chunk_lens: [B] (``chunk_lens`` None: every row carries its
+    whole chunk).  Query rows at and past a row's ``chunk_lens`` come out
+    exactly zero.  With ``window`` (a static count) the query at position
+    ``t`` sees keys ``t - window + 1 .. t`` and the walk starts at the block
+    that holds the row's first visible key; ``scale`` multiplies the scores
+    in place of ``1 / sqrt(D)``.
     """
-    from ..comm.mesh import get_trace_mesh, in_manual_mesh
     if interpret is None:
-        tm = get_trace_mesh()
-        dev = tm.devices.flat[0] if tm is not None else jax.devices()[0]
-        interpret = getattr(dev, "platform", "") != "tpu"
+        interpret = not traced_for_tpu()
+    if chunk_lens is None:
+        chunk_lens = jnp.full(q.shape[:1], q.shape[1], jnp.int32)
     if isinstance(q, jax.core.Tracer) and not in_manual_mesh():
         mesh = get_trace_mesh()
         if mesh is not None and mesh.size > 1:
-            return _paged_sharded(q, pages, block_table, start_pos, chunk_lens, page_size,
-                                  interpret, mesh, layer, window, scale)
+            return _paged_sharded(q, pages, block_table, start_pos, chunk_lens, layer, page_size, interpret, mesh,
+                                  window, scale)
     return _paged_call(q, pages, block_table, start_pos, chunk_lens, layer, page_size=page_size,
                        window=int(window or 0), scale=None if scale is None else float(scale), interpret=interpret)
 
@@ -353,10 +348,6 @@ def _paged_call(q, pages, block_table, start_pos, chunk_lens, layer, *, page_siz
     b, c, h, d = q.shape
     n_kv = pages.shape[-2]
     rep = h // n_kv
-    if layer is None:
-        pages, layer = pages[None], 0          # one layer's pages are an arena of one layer
-    if chunk_lens is None:
-        chunk_lens = jnp.full((b, ), c, jnp.int32)
     # the block, how its pages arrive, the tile and the scratch follow from
     # the shapes: a chunk of up to _TILE_ROWS query rows a key head is one
     # tile; a longer one is cut into tiles of that many, padded with rows no

@@ -31,6 +31,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from ..comm.mesh import traced_for_tpu
+
 #: heads a block holds at most: a row's whole state at the published 64 heads, 2 MiB at 64 x 64 x 128 float32
 #: and 8 MiB of VMEM with both ways double-buffered (on a v5e 36 layers of 32 live rows took 11.5, 9.9, 9.3 and
 #: 9.1 ms at 8, 16, 32 and 64 heads a block)
@@ -70,9 +72,7 @@ def ssd_update(arena, layer, slot, flags, xdt, decay, b_mat, c_mat, *, interpret
     new states, zeros for a row without ``LIVE``; the arena, the same
     buffer)."""
     if interpret is None:
-        from ..comm.mesh import get_trace_mesh
-        mesh = get_trace_mesh()     # the device the step is traced for (an offline compile's is described)
-        interpret = (mesh.devices.flat[0] if mesh is not None else jax.devices()[0]).platform != "tpu"
+        interpret = not traced_for_tpu()
     return _ssd_update(arena, jnp.asarray(layer, jnp.int32), slot, flags, xdt, decay, b_mat, c_mat, block_heads(xdt.shape[1]),
                        bool(interpret))
 

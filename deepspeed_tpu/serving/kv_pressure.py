@@ -71,7 +71,7 @@ class KVPressureManager:
             victims += [s for s in engine.state.seqs.values()
                         if s.paused and not s.done]
             if not victims:
-                # nothing to shed — pack() would raise; surface a clear error
+                # nothing to shed — pack_groups() would raise; surface a clear error
                 raise RuntimeError(
                     f"KV pressure unresolvable: step needs {need} pages, "
                     f"{kv.allocator.free_pages} free, nothing preemptible")

@@ -205,18 +205,19 @@ def test_paged_kernel_bf16_on_chip(c, h, n_kv, d, page_size, starts):
         next_page += needed
     # every slot random: what lies past a row's length is masked by both
     # implementations, so only visible history has to be meaningful
-    pages = jnp.asarray(rng.normal(size=(num_pages, page_size, 2, n_kv, d)), jnp.bfloat16)
+    pages = jnp.asarray(rng.normal(size=(1, num_pages, page_size, 2, n_kv, d)), jnp.bfloat16)   # an arena of one layer
     q = jnp.asarray(rng.normal(size=(b, c, h, d)), jnp.bfloat16)
     k_new = jnp.asarray(rng.normal(size=(b, c, n_kv, d)), jnp.bfloat16)
     v_new = jnp.asarray(rng.normal(size=(b, c, n_kv, d)), jnp.bfloat16)
     bt, sp, cl = jnp.asarray(block_table), jnp.asarray(start_pos), jnp.asarray(chunk_lens)
 
     # write the chunk like the cache twin does, then attend both ways
-    pages = _write_pages(pages, k_new, v_new, bt, sp, page_size, cl)
+    pages = _write_pages(pages, k_new, v_new, bt, sp, page_size, cl, layer=0)
 
     gold = jax.jit(lambda q, pages: paged_attention(
-        q.astype(jnp.float32), pages.astype(jnp.float32), bt, sp, cl, page_size))(q, pages)
-    kernel = jax.jit(lambda q, pages: paged_attention_pallas(q, pages, bt, sp, cl, page_size, interpret=False))
+        q.astype(jnp.float32), pages[0].astype(jnp.float32), bt, sp, cl, page_size))(q, pages)
+    kernel = jax.jit(lambda q, pages: paged_attention_pallas(q, pages, bt, sp, cl, page_size, layer=0,
+                                                             interpret=False))
     # the kernel by its name: no other form of the same attention stands in
     assert "ds_paged_attention" in kernel.lower(q, pages).as_text()
     got = kernel(q, pages)
@@ -576,7 +577,6 @@ def _two_groups_against_the_rectangle(config, cfg, traffic):
     _, params = harness.seeded_params(cfg, pcfg, 35, jax.devices()[:1])
     page, chunk, width = econf.kv.page_size, econf.scheduler.prefill_chunk, _table_width(pcfg, econf.kv)
     twin = build_cache_model(pcfg, page)
-    assert twin.takes_row_groups
     # eight rows decoding and one prompt's chunk: (context, tokens in the step, the row's pages)
     longest = (width - 2) * page if cache_geometry(pcfg, page).pages_immutable else 24000
     live = [(int(300 + (longest - 300) * i / 7), 1) for i in range(8)] + [(16 * chunk, chunk - 28)]

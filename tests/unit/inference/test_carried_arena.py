@@ -2,8 +2,9 @@
 names its layer (``models/llama_cache.py``).  A prefill in ragged chunks then
 decode steps through the carried scan must read the full-sequence model's
 logits and leave the arena that the per-layer form leaves: the same blocks
-driven layer by layer here, each handed its own layer's pages and no index.
-Bit for bit: the rows written, every other page untouched, the null page zero.
+driven layer by layer here, each handed its own layer's pages as an arena of
+one layer.  Bit for bit: the rows written, every other page untouched, the
+null page zero.
 """
 
 import dataclasses
@@ -34,14 +35,14 @@ FALCON_ALIBI = FalconConfig(vocab_size=128, hidden_size=64, num_hidden_layers=3,
                             num_kv_heads=4, alibi=True, parallel_attn=False, bias=True,
                             max_position_embeddings=128, dtype=jnp.float32, remat=False)
 
-#: name -> (config, full-sequence model, block, where the stacked blocks and the embedding lie in the tree,
-#: whether the block takes the flat axis of row groups: here the rectangle as its one group)
+#: name -> (config, full-sequence model, block, where the stacked blocks and the embedding lie in the tree).
+#: A block takes the flat axis of row groups: here the rectangle as its one group
 FAMILIES = {
-    "llama": (LLAMA, LlamaForCausalLM, LlamaBlockCache, ("model", "layers"), "embed_tokens", True),
-    "mixtral": (MIXTRAL, MixtralForCausalLM, MixtralBlockCache, ("layers", ), "embed_tokens", True),
+    "llama": (LLAMA, LlamaForCausalLM, LlamaBlockCache, ("model", "layers"), "embed_tokens"),
+    "mixtral": (MIXTRAL, MixtralForCausalLM, MixtralBlockCache, ("layers", ), "embed_tokens"),
     "mistral_window": (dataclasses.replace(LLAMA, sliding_window=6), LlamaForCausalLM, LlamaBlockCache,
-                       ("model", "layers"), "embed_tokens", True),
-    "falcon_alibi": (FALCON_ALIBI, FalconForCausalLM, FalconBlockCache, ("h", ), "word_embeddings", False),
+                       ("model", "layers"), "embed_tokens"),
+    "falcon_alibi": (FALCON_ALIBI, FalconForCausalLM, FalconBlockCache, ("h", ), "word_embeddings"),
 }
 
 #: (chunk width, a row's real tokens in it) a step: ragged prefill chunks, then one-token steps
@@ -53,19 +54,19 @@ SCHEDULES = {
 
 def _per_layer_arena(one_layer, layers, x, arena, *batch):
     """The arena after one step of the per-layer form: block ``i`` with its own
-    parameters, layer ``i``'s pages and ``layer=None``."""
+    parameters and layer ``i``'s pages, an arena of one layer."""
     out = []
     for i in range(arena.shape[0]):
-        x, pages = one_layer(jax.tree.map(lambda w, i=i: w[i], layers), x, arena[i], *batch)
+        x, pages = one_layer(jax.tree.map(lambda w, i=i: w[i], layers), x, arena[i:i + 1], *batch)
         out.append(pages)
-    return jnp.stack(out)
+    return jnp.concatenate(out)
 
 
 def _check(family, impl, schedule):
     """Run ``schedule`` through the twin and, beside it, through the per-layer
     form; hold every step's logits to the full-sequence model's and its arena
     to the per-layer form's."""
-    cfg, full_cls, block_cls, layers_at, embed_at, flat = FAMILIES[family]
+    cfg, full_cls, block_cls, layers_at, embed_at = FAMILIES[family]
     cfg = dataclasses.replace(cfg, attention_impl=impl)
     page = KV.page_size
     set_global_mesh(create_mesh(MeshSpec(), devices=jax.devices()[:1]))
@@ -78,8 +79,8 @@ def _check(family, impl, schedule):
         layers = layers[key]
 
     def one_layer(width):
-        block = block_cls(cfg, page, ((ROWS, width), )) if flat else block_cls(cfg, page)
-        return jax.jit(lambda w, x, pages, *batch: block.apply({"params": w}, (x, pages), None, *batch)[0])
+        block = block_cls(cfg, page, ((ROWS, width), ))
+        return jax.jit(lambda w, x, pages, *batch: block.apply({"params": w}, (x, pages), 0, *batch)[0])
 
     twin = jax.jit(build_cache_model(cfg, page).apply)
     # an arena that is not blank, so that "untouched" says something; the null page is zero
@@ -92,9 +93,8 @@ def _check(family, impl, schedule):
         for r in range(ROWS):
             ids[r, :lens[r]] = tokens[r, start[r]:start[r] + lens[r]]
         positions = start[:, None] + np.arange(width)[None, :]
-        x = params["params"][embed_at]["embedding"][jnp.asarray(ids)]
-        if flat:
-            x, positions = x.reshape(ROWS * width, -1), positions.reshape(-1)
+        x = params["params"][embed_at]["embedding"][jnp.asarray(ids)].reshape(ROWS * width, -1)
+        positions = positions.reshape(-1)
         by_layer = np.asarray(_per_layer_arena(one_layer(width), layers, x, arena, jnp.asarray(positions),
                                                jnp.asarray(tables), jnp.asarray(start), jnp.asarray(lens)))
         logits, after = twin(params, jnp.asarray(ids), jnp.asarray(start), jnp.asarray(tables), arena,
@@ -160,8 +160,8 @@ def test_head_over_the_sampled_rows_equals_the_all_position_logits(family):
 
 @pytest.mark.parametrize("traced", [True, False], ids=["traced_index", "static_index"])
 def test_kernel_reads_a_layer_of_the_whole_arena_under_a_tensor_mesh(traced):
-    """``_paged_sharded(layer=)``: the arena sharded over its key heads under
-    one more leading dimension, the index replicated."""
+    """``_paged_sharded``: the arena sharded over its key heads, the layer's
+    index replicated."""
     from jax.sharding import NamedSharding, PartitionSpec as P
     mesh = create_mesh(MeshSpec(data=1, tensor=2), devices=jax.devices()[:2])
     layers, heads, n_kv, d, chunk = 3, 4, 2, 8, 4

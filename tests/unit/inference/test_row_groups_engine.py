@@ -1,4 +1,4 @@
-"""The engine hands a mixed step to a twin that takes row groups in two groups
+"""The engine hands every twin a mixed step in two row groups
 (``engine_v2._step_groups``): the decode bucket at one slot a row beside a
 prefill group of a rung of rows at the chunk.  Held here: the token streams
 (against a row-at-a-time reference that knows no batching), the closure of
@@ -62,7 +62,7 @@ CONFIGS = {
                                          num_key_value_heads=2, mamba_n_heads=4, mamba_d_head=16, mamba_d_state=16,
                                          max_position_embeddings=512, dtype=jnp.float32, param_dtype=jnp.float32),
 }
-#: a twin whose blocks take rectangles only
+#: a twin of ``cache_zoo.py`` that no benchmark cell serves
 FALCON = FalconConfig(vocab_size=128, hidden_size=64, num_hidden_layers=2, num_attention_heads=4, num_kv_heads=4,
                       alibi=False, parallel_attn=True, bias=False, max_position_embeddings=512, dtype=jnp.float32,
                       remat=False)
@@ -255,18 +255,16 @@ def test_a_slot_holding_twin_takes_row_groups_and_its_cell_warms_seven_programs(
     _, params = _params(cfg)
     eng = _engine(cfg, params, k=engine["decode_steps_per_dispatch"], sched=SchedulerConfig(**engine["scheduler"]),
                   kv=dataclasses.replace(KV, num_pages=32))
-    assert eng._row_groups and build_cache_model(cfg, PAGE).takes_row_groups
     assert {eng._key_label(k) for k in eng.step_shape_set()} == {
         "step:b32:c1", "step:b32:c1:b1:c128", "step:b32:c1:b4:c128", "step:b32:c1:b32:c128",
         "multi:b32:k8", "multi:b32:k4", "multi:b32:k2"}
 
 
-def test_a_twin_that_does_not_take_row_groups_keeps_the_rectangle():
+def test_a_zoo_twins_engine_reaches_the_decode_bucket_beside_each_prefill_rung_and_no_rectangle_at_the_chunk():
     _, params = _params(FALCON)
     eng = _engine(FALCON, params)
-    assert not eng._row_groups and build_cache_model(CONFIGS["llama"], PAGE).takes_row_groups
     keys = {eng._key_label(k) for k in eng.step_shape_set()}
-    assert keys == {"step:b4:c1", "step:b8:c1", "step:b4:c16", "step:b8:c16"}
+    assert keys == {"step:b4:c1", "step:b8:c1"} | {f"step:b{b}:c1:b{rung}:c16" for b in (4, 8) for rung in (1, 4, 8)}
     prompts = _prompts(FALCON)
     outs = eng.generate(prompts[:3])
     assert all(len(o) == NEW for o in outs)
@@ -289,14 +287,14 @@ def _cell_schedulers():
 CELL_SCHEDULERS = _cell_schedulers()
 
 
-@pytest.mark.parametrize("twin", ["row_groups", "rectangle"])
+@pytest.mark.parametrize("twin", ["llama", "zoo"])
 @pytest.mark.parametrize("cell", sorted(CELL_SCHEDULERS))
 def test_every_plan_of_a_cells_scheduler_maps_to_a_key_of_the_step_set(cell, twin):
     """Random populations of decoding and prefilling sequences, up to more
     than the scheduler admits: whatever ``plan`` returns, ``_step_groups``
     names a program of ``step_shape_set`` and holds every row of the plan."""
     sched = CELL_SCHEDULERS[cell]
-    cfg = CONFIGS["llama"] if twin == "row_groups" else FALCON
+    cfg = CONFIGS["llama"] if twin == "llama" else FALCON
     _, params = _params(cfg)
     eng = _engine(cfg, params, k=8, sched=sched, kv=dataclasses.replace(KV, num_pages=32))
     keys = set(eng.step_shape_set())

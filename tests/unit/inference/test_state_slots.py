@@ -114,7 +114,7 @@ def test_a_slot_comes_with_the_sequence_and_goes_with_it():
 def test_the_packed_rows_carry_pages_then_zeros_then_the_slot():
     kv, state = _manager(state_slots=4)
     a, b = state.get_or_create(1, [5] * 40), state.get_or_create(2, [6] * 20)
-    batch = state.pack([(a, 40), (b, 20)], 128, pad_to=4)
+    batch = state.pack_groups([([(a, 40), (b, 20)], 4, 128)])
     tables = batch.block_tables
     assert tables.shape == (4, kv.table_width)
     assert tables[0, :3].tolist() == a.pages and tables[1, :2].tolist() == b.pages

@@ -142,7 +142,7 @@ def test_engine_serves_two_row_groups_with_the_prefix_cache_on(params, ids):
     the prefix cache."""
     from deepspeed_tpu.serving import RequestState, ServingEngine, VirtualClock
     eng = _engine(params)
-    assert isinstance(eng.kv.geometry, LatentPagesGeometry) and eng.kv.geometry.pages_immutable and eng._row_groups
+    assert isinstance(eng.kv.geometry, LatentPagesGeometry) and eng.kv.geometry.pages_immutable
     anat = eng.anatomy
     prompts = [ids[0, :70].tolist(), ids[1, :45].tolist(), ids[0, :70].tolist()]
     with jax.default_matmul_precision("highest"):
@@ -226,7 +226,6 @@ def test_registry_names_the_twin_and_its_geometry():
     assert isinstance(twin.model(CFG, page_size=PAGE), Xing4ForCausalLMWithCache)
     assert isinstance(cache_geometry(CFG, PAGE), LatentPagesGeometry)
     assert twin.walk_rows(PAGE, 2066) == 512 and twin.pages("arena") == "arena"
-    assert twin.model(CFG, page_size=PAGE).takes_row_groups
 
 
 def test_tensor_parallel_serving_of_latent_pages_is_refused_in_words(params):

@@ -355,7 +355,7 @@ def test_grouped_product_takes_the_kernel_only_where_one_device_runs_it(mesh_axe
     from deepspeed_tpu.comm.mesh import trace_mesh
     from deepspeed_tpu.moe import sharded_moe
     from deepspeed_tpu.ops import grouped_matmul as gm
-    monkeypatch.setattr(gm, "_traced_for_tpu", lambda: True)
+    monkeypatch.setattr(gm, "traced_for_tpu", lambda: True)
     monkeypatch.setattr(sharded_moe, "DENSE_UP_TO_TOKENS", 0)
     mesh = None
     if mesh_axes is not None:

@@ -15,8 +15,8 @@ from deepspeed_tpu.ops.paged_attention import paged_attention_pallas
 
 
 def _setup(b=3, c=4, h=8, n_kv=4, d=32, page_size=8, max_pages=6, seed=0):
-    """Build an arena with randomized per-sequence histories, then write the
-    current chunk, exactly as LlamaAttentionCache does."""
+    """Build an arena of one layer with randomized per-sequence histories,
+    then write the current chunk, exactly as LlamaAttentionCache does."""
     rng = np.random.default_rng(seed)
     num_pages = 1 + b * max_pages
     pages = jnp.zeros((num_pages, page_size, 2, n_kv, d), jnp.float32)
@@ -48,15 +48,15 @@ def _setup(b=3, c=4, h=8, n_kv=4, d=32, page_size=8, max_pages=6, seed=0):
     bt = jnp.asarray(block_table)
     sp = jnp.asarray(start_pos)
     cl = jnp.asarray(chunk_lens)
-    pages = _write_pages(pages, k_new, v_new, bt, sp, page_size, cl)
+    pages = _write_pages(pages[None], k_new, v_new, bt, sp, page_size, cl, layer=0)
     return q, pages, bt, sp, cl, page_size
 
 
-def _rows(rows, c, h, n_kv, d=128, page_size=8, width=None, layers=None, seed=0):
-    """An arena (of ``layers`` layers, or one layer's pages) that holds the
-    history of ``rows`` = [(start, chunk_len), ...] and each row's chunk,
-    written as the twins write it; the table ``width`` columns wide.  Heads
-    of 128 lanes, as every cell's, unless ``d`` says otherwise."""
+def _rows(rows, c, h, n_kv, d=128, page_size=8, width=None, layers=1, seed=0):
+    """An arena of ``layers`` layers, one of which holds the history of
+    ``rows`` = [(start, chunk_len), ...] and each row's chunk, written as the
+    twins write it; the table ``width`` columns wide.  Heads of 128 lanes, as
+    every cell's, unless ``d`` says otherwise."""
     rng = np.random.default_rng(seed)
     b = len(rows)
     start = np.array([s for s, _ in rows], np.int32)
@@ -76,13 +76,11 @@ def _rows(rows, c, h, n_kv, d=128, page_size=8, width=None, layers=None, seed=0)
     q = jnp.asarray(rng.normal(size=(b, c, h, d)), jnp.float32)
     k_new, v_new = (jnp.asarray(rng.normal(size=(b, c, n_kv, d)), jnp.float32) for _ in range(2))
     table, start, lens = jnp.asarray(table), jnp.asarray(start), jnp.asarray(lens)
-    pages = _write_pages(jnp.asarray(pages), k_new, v_new, table, start, page_size, lens)
-    layer = None
-    if layers:
-        # the other layers hold other rows: a read of the wrong layer shows
-        layer = layers - 2
-        pages = jnp.stack([pages if i == layer else jnp.asarray(rng.normal(size=pages.shape), jnp.float32)
-                           for i in range(layers)])
+    # the other layers hold other rows: a read of the wrong layer shows
+    layer = max(layers - 2, 0)
+    pages = jnp.stack([jnp.asarray(pages if i == layer else rng.normal(size=pages.shape), jnp.float32)
+                       for i in range(layers)])
+    pages = _write_pages(pages, k_new, v_new, table, start, page_size, lens, layer=layer)
     return q, pages, table, start, lens, page_size, layer
 
 
@@ -147,7 +145,7 @@ def matches_jnp_golden(case, dtype):
     q, pages, table, start, lens, page_size, layer = CASES[case]()
     q, pages = q.astype(dtype), pages.astype(dtype)
     as32 = lambda x: x.astype(jnp.float32)  # noqa: E731
-    expected = paged_attention(as32(q), as32(pages if layer is None else pages[layer]), table, start, lens, page_size)
+    expected = paged_attention(as32(q), as32(pages[layer]), table, start, lens, page_size)
     got = jax.jit(lambda q, pages: paged_attention_pallas(q, pages, table, start, lens, page_size, layer=layer,
                                                           interpret=True))(q, pages)
     assert got.dtype == dtype

@@ -37,19 +37,18 @@ def one_chip(described_chip):
     return SingleDeviceSharding(described_chip)
 
 
-def _compile(one_chip, chunk, n_q, n_kv, table_width, layers=None, d=128, dtype=jnp.bfloat16, batch=16, **bounds):
-    """The kernel alone, lowered and compiled: the program's text.  Under the
+def _compile(one_chip, chunk, n_q, n_kv, table_width, layers=1, d=128, dtype=jnp.bfloat16, batch=16, **bounds):
+    """The kernel alone over an arena of ``layers`` layers, the layer a traced
+    index, lowered and compiled: the program's text.  Under the
     ``no_compile_cache`` fixture."""
     sds = lambda shape, dtype: jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)  # noqa: E731
-    arena = (64, 16, 2, n_kv, d) if layers is None else (layers, 64, 16, 2, n_kv, d)
-    args = [sds((batch, chunk, n_q, d), dtype), sds(arena, dtype), sds((batch, table_width), jnp.int32),
-            sds((batch, ), jnp.int32), sds((batch, ), jnp.int32)]
+    args = [sds((batch, chunk, n_q, d), dtype), sds((layers, 64, 16, 2, n_kv, d), dtype),
+            sds((batch, table_width), jnp.int32), sds((batch, ), jnp.int32), sds((batch, ), jnp.int32),
+            sds((), jnp.int32)]
 
-    def call(q, pages, table, start, lens, layer=None):
+    def call(q, pages, table, start, lens, layer):
         return paged_attention_pallas(q, pages, table, start, lens, 16, layer=layer, interpret=False, **bounds)
 
-    if layers is not None:
-        args.append(sds((), jnp.int32))
     lowered = jax.jit(call).lower(*args)
     # the kernel by its name, not another form of the same attention
     assert "ds_paged_attention" in lowered.as_text()
@@ -58,8 +57,8 @@ def _compile(one_chip, chunk, n_q, n_kv, table_width, layers=None, d=128, dtype=
 
 @pytest.mark.parametrize("chunk", [128, 1])
 def test_grouped_heads_one_layer_of_pages(one_chip, no_compile_cache, chunk):
-    """Mixtral's heads, 32 query heads over 8 key heads, out of one layer's
-    pages: the form the unrolled trunk still gives the kernel."""
+    """Mixtral's heads, 32 query heads over 8 key heads, out of an arena of
+    one layer."""
     assert "tpu_custom_call" in _compile(one_chip, chunk, 32, 8, 770)
 
 

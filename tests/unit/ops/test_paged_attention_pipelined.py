@@ -29,13 +29,13 @@ def test_pallas_matches_jnp_golden(case, dtype):
 def test_pallas_decode_single_token():
     """C=1 pure-decode step (the FastGen hot path)."""
     q, pages, bt, sp, cl, ps = _setup(c=1, h=4, n_kv=2)
-    expected = paged_attention(q, pages, bt, sp, cl, ps)
-    got = paged_attention_pallas(q, pages, bt, sp, cl, ps, interpret=True)
+    expected = paged_attention(q, pages[0], bt, sp, cl, ps)
+    got = paged_attention_pallas(q, pages, bt, sp, cl, ps, layer=0, interpret=True)
     np.testing.assert_allclose(np.asarray(got), np.asarray(expected), atol=2e-5)
 
 
 def test_padding_rows_zeroed():
     q, pages, bt, sp, cl, ps = _setup()
     cl = cl.at[1].set(0)  # make row 1 a padding row
-    got = paged_attention_pallas(q, pages, bt, sp, cl, ps, interpret=True)
+    got = paged_attention_pallas(q, pages, bt, sp, cl, ps, layer=0, interpret=True)
     np.testing.assert_array_equal(np.asarray(got[1]), 0)

@@ -35,9 +35,9 @@ def test_window_bound_matches_jnp_form(case, dtype):
     q, pages, table, start, lens, page_size, layer = CASES[base]()
     q, pages = q.astype(dtype), pages.astype(dtype)
     as32 = lambda x: x.astype(jnp.float32)  # noqa: E731
-    expected = paged_attention(as32(q), as32(pages if layer is None else pages[layer]), table, start, lens, page_size,
+    expected = paged_attention(as32(q), as32(pages[layer]), table, start, lens, page_size,
                                sliding_window=window, scale=0.125)
-    unbounded = paged_attention(as32(q), as32(pages if layer is None else pages[layer]), table, start, lens, page_size,
+    unbounded = paged_attention(as32(q), as32(pages[layer]), table, start, lens, page_size,
                                 scale=0.125)
     assert float(jnp.abs(expected - unbounded).max()) > 1e-3          # the window hides something
     got = jax.jit(lambda q, pages: paged_attention_pallas(q, pages, table, start, lens, page_size, layer=layer,

@@ -698,10 +698,10 @@ def test_pallas_kernels_carry_ds_names():
     text = str(jax.make_jaxpr(jax.grad(loss))(q))
     assert all(n in text for n in ("ds_flash_fwd", "ds_flash_dq", "ds_flash_dkv"))
 
-    pages = jnp.zeros((5, 8, 2, 2, 32), jnp.float32)
+    pages = jnp.zeros((1, 5, 8, 2, 2, 32), jnp.float32)
     qd = jnp.asarray(rng.normal(size=(2, 1, 4, 32)), jnp.float32)
     bt = jnp.asarray([[1, 2], [3, 4]], jnp.int32)
     sp, cl = jnp.asarray([3, 9], jnp.int32), jnp.asarray([1, 1], jnp.int32)
     text = str(jax.make_jaxpr(lambda q, p: paged_attention_pallas(
-        q, p, bt, sp, cl, 8, interpret=True))(qd, pages))
+        q, p, bt, sp, cl, 8, layer=0, interpret=True))(qd, pages))
     assert "ds_paged_attention" in text
