@@ -31,6 +31,10 @@ from .falcon import FalconConfig
 from .granite_hybrid import GraniteHybridConfig
 from .granite_hybrid_cache import GraniteHybridForCausalLMWithCache, slot_state_bytes
 from .granite_hybrid_cache import init_cache as init_granite_hybrid_cache
+from .solar_open2 import SolarOpen2Config
+from .solar_open2_cache import SolarOpen2ForCausalLMWithCache
+from .solar_open2_cache import init_cache as init_solar_open2_cache
+from .solar_open2_cache import slot_state_bytes as solar_open2_state_bytes
 from .kimi_vl import KimiVLConfig
 from .kimi_vl_cache import KimiVLForCausalLMWithCache
 from .mixtral import MixtralConfig
@@ -423,6 +427,10 @@ CACHE_MODEL_REGISTRY = {
                                    lambda cfg, page_size: SlotPagesGeometry(page_size,
                                                                             state_bytes=slot_state_bytes(cfg)),
                                    init_granite_hybrid_cache, lambda cache: cache["pages"]),
+    SolarOpen2Config: CacheTwin(SolarOpen2ForCausalLMWithCache,
+                                lambda cfg, page_size: SlotPagesGeometry(page_size,
+                                                                         state_bytes=solar_open2_state_bytes(cfg)),
+                                init_solar_open2_cache, lambda cache: cache["pages"]),
     Xing4Config: CacheTwin(Xing4ForCausalLMWithCache, lambda cfg, page_size: LatentPagesGeometry(page_size),
                            init_xing4_cache, walk_rows=xing4_walk_rows),
     # the same latent pages under the same kernel; a subclass of Xing4Config, found by its own type first

@@ -219,7 +219,7 @@ def _experts_dense(x, top_vals, expert, group_sizes, bank, layer):
 
 
 def dropless_moe(x, logits, bank, k: int, token_mask=None, noise=None, layer=None, normalize: bool = True,
-                 scoring: str = "softmax", select_bias=None, route_scale: float = 1.0):
+                 scoring: str = "softmax", select_bias=None, route_scale: float = 1.0, held=None):
     """Route one group with no capacity: every live token through its k
     highest experts.
 
@@ -248,6 +248,16 @@ def dropless_moe(x, logits, bank, k: int, token_mask=None, noise=None, layer=Non
     ``route_scale`` (``routed_scaling_factor``) multiplies the k weights
     after the renormalisation.  A token's k outputs are weighted and added in
     float32.  Returns (out [S, d] float32, l_aux, exp_counts [E] int32).
+
+    ``held = (first, count)``: the bank holds experts ``first .. first +
+    count - 1`` of the router's ``E`` alone, [count, ...] (one chip's share
+    of a layer whose experts are divided over several).  The router keeps its
+    ``E`` outputs and its k a token, and the weights are renormalised over
+    all k chosen, as published; the choices that fall on an expert held
+    elsewhere become dead rows (they go to no expert, as a padding row's do),
+    so ``out`` is this share's part of the layer's sum and the shares of all
+    the chips add up to the uncut layer's.  ``exp_counts`` [count] counts the
+    held experts' rows.  No exchange and nothing in the absent experts' place.
     """
     s, e = logits.shape
     if scoring not in ("softmax", "sigmoid"):
@@ -271,7 +281,15 @@ def dropless_moe(x, logits, bank, k: int, token_mask=None, noise=None, layer=Non
     # aux load-balancing loss on the top-1 mask (ref: l_aux = E * sum(me * ce))
     mask1 = _one_hot(top_idx[:, 0], e) * live[:, None]
     l_aux = jnp.sum(jnp.mean(gates, axis=0) * jnp.mean(mask1, axis=0)) * e
-    expert = jnp.where(live[:, None], top_idx, e)  # [S, k]; a dead row goes to expert id E: to none
+    if held is not None:
+        first, e = held
+        if bank[0].shape[-3] != e:
+            raise ValueError(f"held={held}: the bank holds {bank[0].shape[-3]} experts, not {e}")
+        top_idx = top_idx - first      # the bank's own numbering; outside [0, e): an expert held elsewhere
+        live = live[:, None] & (top_idx >= 0) & (top_idx < e)
+    else:
+        live = live[:, None]
+    expert = jnp.where(live, top_idx, e)  # [S, k]; a dead row goes to expert id E: to none
     group_sizes = jnp.bincount(expert.reshape(-1), length=e + 1)[:e].astype(jnp.int32)
 
     experts = _experts_dense if s <= DENSE_UP_TO_TOKENS else _experts_grouped
@@ -279,7 +297,7 @@ def dropless_moe(x, logits, bank, k: int, token_mask=None, noise=None, layer=Non
 
 
 def dropless_dispatch(x, logits, bank, k: int, token_mask=None, noise=None, layer=None, normalize: bool = True,
-                      scoring: str = "softmax", select_bias=None, route_scale: float = 1.0):
+                      scoring: str = "softmax", select_bias=None, route_scale: float = 1.0, held=None):
     """``dropless_moe`` over a batch [B, S, ...]: one group a data shard.
 
     Without capacity a token's output does not depend on its group, so the
@@ -301,7 +319,7 @@ def dropless_dispatch(x, logits, bank, k: int, token_mask=None, noise=None, laye
     def one_group(x, logits, token_mask, noise, bank, layer):
         flat = lambda a: None if a is None else a.reshape((-1, ) + a.shape[2:])
         out, l_aux, counts = dropless_moe(flat(x), flat(logits), bank, k, flat(token_mask), flat(noise), layer,
-                                          normalize, scoring, select_bias, route_scale)
+                                          normalize, scoring, select_bias, route_scale, held)
         return out.reshape(x.shape[:2] + out.shape[1:]), l_aux, counts
 
     mesh = get_trace_mesh()

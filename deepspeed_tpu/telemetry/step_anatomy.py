@@ -171,12 +171,18 @@ SLOW_RING = 256
 SLOW_LOG_EVERY_S = 1.0
 
 _RECORDERS: List[weakref.ref] = []
+#: the recorder that closed the process's newest step, held until another
+#: closes one: a reader that comes once the engine has gone (the benchmark's
+#: ``step_rows.window_rows``, after its run returned) finds the run's records
+#: whenever Python's collector ran, not only before it
+_newest: Optional["StepAnatomy"] = None
 
 
 def recorders() -> list:
     """The live recorders of this process, oldest first.  Weakly held: a
     recorder goes with its engine (or whoever else made it), and its
-    reference leaves the list with it."""
+    reference leaves the list with it; only the one that closed the newest
+    step stays until another recorder closes a step."""
     return [rec for ref in list(_RECORDERS) if (rec := ref()) is not None]
 
 
@@ -601,6 +607,8 @@ class StepAnatomy:
     # --------------------------------------------------------------- intake
 
     def _retain(self, rec: StepRecord) -> None:
+        global _newest
+        _newest = self
         if self.steps.maxlen is not None and len(self.steps) == self.steps.maxlen:
             self.dropped_steps += 1
         self.steps.append(rec)

@@ -256,3 +256,25 @@ def test_recorders_forget_a_collected_engine(tiny_serving):
     del eng, own
     gc.collect()
     assert marker not in [id(r) for r in recorders()]
+
+
+@pytest.mark.parametrize("collections", [1, 3])
+def test_the_recorder_of_the_newest_step_outlives_its_engine(tiny_serving, collections):
+    """The benchmark's readers come when the run's engine is unreferenced: what
+    closed the newest step is there however often the collector has run, and
+    goes when another recorder closes a step."""
+    eng = tiny_serving()
+    eng.generate([[1, 2, 3]], max_new_tokens=2)
+    marker, steps = id(eng.anatomy), eng.anatomy.total_steps
+    assert steps > 0
+    del eng
+    for _ in range(collections):
+        gc.collect()
+    kept = [r for r in recorders() if id(r) == marker]
+    assert len(kept) == 1 and kept[0].total_steps == steps
+    del kept
+    clock = VirtualClock()
+    other = StepAnatomy(clock=clock)
+    _step(other, clock)
+    gc.collect()
+    assert marker not in [id(r) for r in recorders()] and other in recorders()
