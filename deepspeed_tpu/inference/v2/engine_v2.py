@@ -332,10 +332,13 @@ class InferenceEngineV2:
                 raise NotImplementedError("a quantized scan_layers=False tree cannot be stacked: "
                                           "stack it (stack_layer_params), then quantize")
             params = stack_layer_params(params, cfg.num_hidden_layers)
-        # experts a token is routed to (0: no expert layer), and whether the
-        # grouped product of a step this engine traces is the kernel ds_gmm,
-        # for the step records' expert_rows and expert_rows_kernel
+        # experts a token is routed to (0: no expert layer) of how many the
+        # router chooses among, and whether the grouped product of a step this
+        # engine traces is the kernel ds_gmm, for the step records'
+        # expert_rows and expert_rows_kernel
         self._experts_per_tok = int(getattr(cfg, "num_experts_per_tok", 0) or 0)
+        self._router_experts = next((int(n) for n in (getattr(cfg, name, 0) for name in (
+            "router_width", "n_routed_experts", "num_local_experts", "num_experts")) if n), 0)
         with trace_mesh(self.mesh):
             self._experts_kernel = takes_kernel()
         # weight-only-quantized checkpoints: int8 stays in HBM, dequant is
@@ -429,8 +432,9 @@ class InferenceEngineV2:
         slots takes the sorted form (``moe/sharded_moe.dropless_moe``) and its
         products the kernel (``ops/grouped_matmul.takes_kernel``), else none."""
         rows = tokens * self._experts_per_tok
-        return {"expert_rows": rows,
-                "expert_rows_kernel": rows if self._experts_kernel and group > sharded_moe.DENSE_UP_TO_TOKENS else 0}
+        kernel = rows and self._experts_kernel and sharded_moe.takes_sorted(group, self._experts_per_tok,
+                                                                            self._router_experts)
+        return {"expert_rows": rows, "expert_rows_kernel": rows if kernel else 0}
 
     # ------------------------------------------------------------------ TP
 

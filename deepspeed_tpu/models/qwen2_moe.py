@@ -9,10 +9,12 @@ expert scaled by sigmoid(shared_expert_gate(x)).
 The routed experts go through ``moe.sharded_moe.dropless_dispatch``, the
 path served Mixtral takes: no capacity, no token dropped, a token's k
 outputs weighted and added in float32 (HF's gather-based math).  Above
-``DENSE_UP_TO_TOKENS`` tokens a data shard (a training step) the [S, k]
-choices are sorted by expert and the bank multiplies the routed rows,
-k a token, forward and backward; up to it (a decode step, a short forward)
-every expert multiplies every row, which costs the same read of the weights.
+``DENSE_UP_TO_TOKENS`` tokens a data shard (a training step), and wherever
+the rows are too few to reach most of the 60 experts (a decode step:
+``sharded_moe.takes_sorted``), the [S, k] choices are sorted by expert and
+the bank multiplies the routed rows, k a token, forward and backward; between
+the two (a short forward) every expert multiplies every row, which costs the
+same read of the weights.
 The bank [NE, ...] is whole on every data shard (ZeRO-3 gathers it a layer,
 an ``expert`` mesh axis too): for expert counts that need the experts kept
 apart over the mesh, use deepspeed_tpu.moe.MoE (capacity dispatch).

@@ -37,9 +37,14 @@ and there is no other knob:
   [S, E, C] tensor exists.
   Rows a token mask removes (the padding of a serving step) sort behind the
   last group, are in no group, cost no product and come out as exact zeros.
-  Up to ``DENSE_UP_TO_TOKENS`` tokens a group (a decode step) every expert
-  multiplies every row instead: there the weights' read is the whole cost
-  either way, and the sort and the grouped kernel's fixed cost are not paid.
+  Which form a group takes is ``takes_sorted``'s rule of the layer's own
+  shapes (rows, choices a row, the router's experts): every expert multiplies
+  every row instead (``_experts_dense``) where the rows are few enough to be
+  bound by the weights' read and still choose nearly every expert (16 rows of
+  2 over 8): there the bank's read is the whole cost either way, and the sort
+  and the grouped kernel's fixed cost are not paid.  Where they cannot touch
+  most of the bank (12 rows of 4 over 64) the sorted form reads the chosen
+  experts alone, and a padding row costs nothing.
   A scanned trunk may hand the layer the banks of all its layers, still
   stacked, and the layer's index (``layer``): the grouped product is a
   custom call on the TPU and would copy the slice the scan gives it.
@@ -170,14 +175,31 @@ def dispatch_combine(x_grouped, combine, dispatch, expert_fn):
     return out
 
 
-#: Tokens up to which the dropless path lets every expert multiply every row.
-#: An expert's product over r rows does 2*r*d*f operations on the 2*d*f bytes
-#: of its bf16 weights, r operations a byte, so it is bound by reading the
-#: weights while r is under the chip's operations a byte (197e12 / 819e9 = 240
-#: on a v5e).  Up to there the dense form costs the weight read the routed
-#: rows would cost too, without the sort, the gathers and the grouped
-#: kernel's fixed cost (a decode step's 16 tokens: PERF.md section 6, PR 25).
+#: Rows up to which an expert's product is bound by the read of its weights:
+#: over r rows it does 2*r*d*f operations on the 2*d*f bytes of its bf16
+#: weights, r operations a byte, under the chip's operations a byte (197e12 /
+#: 819e9 = 240 on a v5e).  Beyond it every expert over every row is E/k times
+#: the work, and the group takes the sorted form whatever it touches.
 DENSE_UP_TO_TOKENS = 256
+#: Share of the bank from which the dense form may read all of it.  With
+#: every row live the sorted form (the sort, the gathers, three custom calls a
+#: layer, each expert's block read once) ties the dense one where the rows
+#: touch 0.95 of the bank and loses 2-12% where they touch all of it; under
+#: that it wins by the experts it does not read, and a padding row costs it
+#: nothing (the sweep on the chip: PERF.md section 5, PR 47).
+DENSE_FROM_BANK_SHARE = 0.95
+
+
+def takes_sorted(s: int, k: int, e: int) -> bool:
+    """Whether a group of ``s`` rows, ``k`` choices a row over a router of
+    ``e`` experts, takes the sorted form: above ``DENSE_UP_TO_TOKENS`` rows,
+    and wherever the rows cannot touch most of the bank.  ``s * k`` choices
+    spread evenly touch ``1 - (1 - 1/e)^(s k)`` of the experts, the held ones
+    of a share like all of them (16 rows of 2 over 8: 0.99, dense; 12 rows of
+    4 over 64: 0.53; 32 rows of 8 over 320: 0.55), and that with every row
+    live.  The one rule, for ``dropless_moe`` and for the engine's step
+    records (``engine_v2._expert_rows``): a function of the shapes alone."""
+    return s > DENSE_UP_TO_TOKENS or 1.0 - (1.0 - 1.0 / e)**(s * k) < DENSE_FROM_BANK_SHARE
 
 
 def _experts_grouped(x, top_vals, expert, group_sizes, bank, layer):
@@ -292,7 +314,7 @@ def dropless_moe(x, logits, bank, k: int, token_mask=None, noise=None, layer=Non
     expert = jnp.where(live, top_idx, e)  # [S, k]; a dead row goes to expert id E: to none
     group_sizes = jnp.bincount(expert.reshape(-1), length=e + 1)[:e].astype(jnp.int32)
 
-    experts = _experts_dense if s <= DENSE_UP_TO_TOKENS else _experts_grouped
+    experts = _experts_grouped if takes_sorted(s, k, logits.shape[1]) else _experts_dense
     return experts(x, top_vals, expert, group_sizes, bank, layer), l_aux, group_sizes
 
 

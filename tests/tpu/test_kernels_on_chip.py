@@ -430,6 +430,12 @@ GROUPED_ON_CHIP = {
     "mixtral_gate_and_up_full_step": (4096, 4096, 14336, 24, 16, 8, 4096),
     "qwen15_moe_gate_and_up": (16384, 2048, 1408, 60, 0, 60, 16384),
     "qwen15_moe_down": (16384, 1408, 2048, 60, 0, 60, 16384),
+    # a decode step in the sorted form (PR 47): Xing4's 12 rows of 4 over the fourth of six layers' 64 experts
+    # (some 34 touched), Solar-Open2's 32 rows of 8 of which 24 fall on the third of four layers' 40 held (some 18)
+    "xing4_gate_and_up_decode": (48, 3584, 1024, 384, 192, 64, 48),
+    "xing4_down_decode": (48, 1024, 3584, 384, 192, 64, 48),
+    "solar_gate_and_up_decode": (256, 4096, 1280, 160, 80, 40, 24),
+    "solar_down_decode": (256, 1280, 4096, 160, 80, 40, 24),
 }
 
 
@@ -533,6 +539,48 @@ def test_rows_in_no_group_do_not_reach_the_experts_output_on_chip():
     np.testing.assert_array_equal(np.asarray(dirty), np.asarray(clean))
     other, _, _ = run(x, stack, 0)
     assert _rel(other[np.asarray(mask)], clean[np.asarray(mask)]) > 0.5  # another layer's banks: the index is read
+
+
+#: a decode step's expert layer: (rows, experts a row, the router's experts, held, hidden, expert width, layers)
+DECODE_LAYERS = {"xing4_12_rows": (12, 4, 64, None, 3584, 1024, 6), "solar_32_rows": (32, 8, 320, (0, 40), 4096, 1280, 4)}
+
+
+@pytest.mark.parametrize("name", list(DECODE_LAYERS))
+def test_a_decode_steps_experts_take_the_sorted_form_on_chip(name, monkeypatch):
+    """The rows of a decode bucket cannot touch most of a bank of narrow
+    experts, so the layer takes the sorted form by ``takes_sorted``'s own
+    answer: ``ds_gmm`` in the program, the dense form's values, and its time
+    beside the dense form's with every row live and with two."""
+    import jax
+    import jax.numpy as jnp
+    from deepspeed_tpu.moe import sharded_moe
+    s, k, e, held, d, f, layers = DECODE_LAYERS[name]
+    count = e if held is None else held[1]
+    ks = jax.random.split(jax.random.PRNGKey(47), 5)
+    x = jax.random.normal(ks[0], (s, d), jnp.bfloat16)
+    logits = jax.random.normal(ks[1], (s, e), jnp.float32)
+    stack = tuple(jax.random.normal(kk, (layers, count) + shape, jnp.bfloat16) * shape[0]**-0.5
+                  for kk, shape in zip(ks[2:], ((d, f), (d, f), (f, d))))
+
+    def layer():    # the banks are arguments: see the test above
+        return jax.jit(lambda x, stack, mask: sharded_moe.dropless_moe(x, logits, stack, k, mask, None, layers // 2,
+                                                                       True, "sigmoid", None, 1.0, held))
+
+    assert sharded_moe.takes_sorted(s, k, e)
+    sorted_form = layer()
+    assert "ds_gmm" in sorted_form.lower(x, stack, jnp.ones((s, ), bool)).as_text()
+    monkeypatch.setattr(sharded_moe, "takes_sorted", lambda s, k, e: False)
+    dense_form = layer()
+    assert "ds_gmm" not in dense_form.lower(x, stack, jnp.ones((s, ), bool)).as_text()
+    for live in (s, 2):
+        mask = jnp.arange(s) < live
+        (got, _, counts), got_ms = _ms(sorted_form, x, stack, mask, n=20)
+        (want, _, want_counts), want_ms = _ms(dense_form, x, stack, mask, n=20)
+        print(f"\n{name}, {live} rows live, {int((np.asarray(counts) > 0).sum())} of {count} experts touched: "
+              f"sorted {got_ms:.3f} ms, dense {want_ms:.3f} ms")
+        np.testing.assert_array_equal(np.asarray(counts), np.asarray(want_counts))
+        assert not np.asarray(got)[live:].any() and np.isfinite(np.asarray(got)).all()
+        assert _rel(got[:live], want[:live]) < 1e-2
 
 
 # ------------------------------------------------- a mixed step in row groups

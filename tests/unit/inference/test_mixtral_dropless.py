@@ -28,7 +28,7 @@ def served(request):
     sorted dispatch, and both must hold what is tested here."""
     from deepspeed_tpu.moe import sharded_moe
     with pytest.MonkeyPatch.context() as mp:
-        mp.setattr(sharded_moe, "DENSE_UP_TO_TOKENS", 1 << 30 if request.param == "dense" else 0)
+        mp.setattr(sharded_moe, "takes_sorted", lambda s, k, e: request.param == "grouped")
         yield _served()
 
 
@@ -129,7 +129,10 @@ def test_step_records_count_the_rows_through_the_grouped_kernel(served, kernel_p
     params, _ = served
     if kernel_path:
         monkeypatch.setattr(engine_v2, "takes_kernel", lambda: True)
-    monkeypatch.setattr(sharded_moe, "DENSE_UP_TO_TOKENS", 4)  # a decode bucket's rows stay dense, a chunk's slots do not
+    # a decode bucket's rows stay dense, a chunk's slots do not (by the rule itself the toy's 8 experts, 2 a
+    # token, would turn sorted at 2 rows: tests/unit/moe/test_expert_form.py holds the rule, test_expert_form_records.py
+    # the records under it)
+    monkeypatch.setattr(sharded_moe, "takes_sorted", lambda s, k, e: s > 4)
     sched = SchedulerConfig(token_budget=64, max_seqs=4, prefill_chunk=CHUNK, decode_bucket=2)
     eng = build_engine(CFG, params, RaggedInferenceEngineConfig(
         kv=PagedKVConfig(num_pages=40, page_size=4, max_pages_per_seq=16), scheduler=sched,

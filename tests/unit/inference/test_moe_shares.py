@@ -23,7 +23,7 @@ from refs import solar_open2 as ref  # noqa: E402
 
 E, K, D, F, S = 16, 4, 32, 24, 40
 SHARES = [(0, 4), (4, 4), (8, 4), (12, 4)]
-FORMS = {"dense": 1 << 30, "grouped": 0}
+FORMS = {"dense": False, "grouped": True}
 
 
 @pytest.fixture(scope="module")
@@ -39,7 +39,7 @@ def layer():
 
 
 def _routed(layer, form, held, monkeypatch, mask=True):
-    monkeypatch.setattr(sharded_moe, "DENSE_UP_TO_TOKENS", FORMS[form])
+    monkeypatch.setattr(sharded_moe, "takes_sorted", lambda s, k, e: FORMS[form])
     bank = layer["bank"] if held is None else tuple(w[held[0]:held[0] + held[1]] for w in layer["bank"])
     with jax.default_matmul_precision("highest"):
         return dropless_moe(layer["x"], layer["x"] @ layer["gate"], bank, K, layer["mask"] if mask else None,

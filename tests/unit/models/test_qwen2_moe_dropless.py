@@ -1,6 +1,6 @@
 """Qwen2-MoE's sparse MLP through the dropless path (``moe/sharded_moe``):
-the layer against a per-token loop written here, under and over
-``DENSE_UP_TO_TOKENS``, and a ZeRO-3 step on the CPU's host devices against
+the layer against a per-token loop written here, in the dense and in the
+sorted form (``takes_sorted``), and a ZeRO-3 step on the CPU's host devices against
 the one-device run."""
 
 import dataclasses
@@ -14,7 +14,7 @@ from flax import linen as nn
 import deepspeed_tpu as ds
 from deepspeed_tpu.comm.mesh import MeshSpec, create_mesh
 from deepspeed_tpu.models.qwen2_moe import Qwen2MoeConfig, Qwen2MoeForCausalLM, Qwen2MoeSparseMLP
-from deepspeed_tpu.moe.sharded_moe import DENSE_UP_TO_TOKENS
+from deepspeed_tpu.moe.sharded_moe import DENSE_UP_TO_TOKENS, takes_sorted
 
 
 def _layer_cfg(norm_topk_prob):
@@ -49,7 +49,7 @@ def _per_token_mlp(p, x, k, norm_topk_prob):
 @pytest.mark.parametrize("seq", [64, 160], ids=["dense_form", "grouped_form"])
 def test_sparse_mlp_value_and_gradient_match_per_token_loop(seq, norm_topk_prob):
     """Value and gradient (router, bank, shared expert) of the layer equal the
-    per-token loop's in float32, on either side of ``DENSE_UP_TO_TOKENS``, and
+    per-token loop's in float32, on either side of ``takes_sorted``'s rule, and
     ``intermediates`` holds the rows each expert multiplied."""
     cfg = _layer_cfg(norm_topk_prob)
     layer = Qwen2MoeSparseMLP(cfg)
@@ -61,6 +61,7 @@ def test_sparse_mlp_value_and_gradient_match_per_token_loop(seq, norm_topk_prob)
     loss = lambda p: jnp.mean((layer.apply(p, x) - target)**2)
     want_loss = lambda p: jnp.mean((_per_token_mlp(p["params"], x, k, norm_topk_prob) - target)**2)
     # the form follows the tokens the layer is handed, and nothing else
+    assert takes_sorted(tokens, k, cfg.num_experts) == (tokens > DENSE_UP_TO_TOKENS)   # 128 rows of 4 reach all 8
     assert ("ragged_dot" in str(jax.make_jaxpr(loss)(params))) == (tokens > DENSE_UP_TO_TOKENS)
 
     np.testing.assert_allclose(np.asarray(layer.apply(params, x)),

@@ -189,7 +189,7 @@ def test_dropless_matches_per_token_loop(name, form, monkeypatch):
     from deepspeed_tpu.moe import sharded_moe
     x, logits, k, mask = _dropless_case(name)
     s, e = logits.shape
-    monkeypatch.setattr(sharded_moe, "DENSE_UP_TO_TOKENS", s if form == "dense" else 0)
+    monkeypatch.setattr(sharded_moe, "takes_sorted", lambda s, k, e: form == "grouped")
     rng = np.random.default_rng(11)
     bank = tuple(jnp.asarray(rng.normal(size=shape).astype(np.float32) * 0.3)
                  for shape in ((e, 16, 32), (e, 16, 32), (e, 32, 16)))
@@ -214,7 +214,7 @@ def test_dropless_matches_per_token_loop(name, form, monkeypatch):
 def dropless_form(request, monkeypatch):
     """Run a test of the whole layer under each form of the dropless path."""
     from deepspeed_tpu.moe import sharded_moe
-    monkeypatch.setattr(sharded_moe, "DENSE_UP_TO_TOKENS", 1 << 30 if request.param == "dense" else 0)
+    monkeypatch.setattr(sharded_moe, "takes_sorted", lambda s, k, e: request.param == "grouped")
     return request.param
 
 

@@ -35,7 +35,7 @@ def _as_before_pr37(x, logits, bank, k, token_mask, normalize):
     l_aux = jnp.sum(jnp.mean(gates, axis=0) * jnp.mean(mask1, axis=0)) * e
     expert = jnp.where(live[:, None], top_idx, e)
     group_sizes = jnp.bincount(expert.reshape(-1), length=e + 1)[:e].astype(jnp.int32)
-    experts = _experts_dense if s <= sharded_moe.DENSE_UP_TO_TOKENS else _experts_grouped
+    experts = _experts_grouped if sharded_moe.takes_sorted(s, k, e) else _experts_dense
     return experts(x, top_vals, expert, group_sizes, bank, None), l_aux, group_sizes
 
 
