@@ -425,15 +425,22 @@ class InferenceEngineV2:
         self._fresh_compile = True
         self.anatomy.note_compile(key)
 
-    def _expert_rows(self, tokens: int, group: int) -> Dict[str, int]:
+    def _expert_rows(self, tokens: int, group: int, live: Optional[int] = None) -> Dict[str, int]:
         """The step records' expert counts: the rows the routed experts
         multiplied for ``tokens`` real tokens, and how many of them went
-        through the kernel ``ds_gmm``: all, where a model step of ``group``
-        slots takes the sorted form (``moe/sharded_moe.dropless_moe``) and its
-        products the kernel (``ops/grouped_matmul.takes_kernel``), else none."""
-        rows = tokens * self._experts_per_tok
-        kernel = rows and self._experts_kernel and sharded_moe.takes_sorted(group, self._experts_per_tok,
-                                                                            self._router_experts)
+        through the kernel ``ds_gmm``: all, where the grouped product is the
+        kernel (``ops/grouped_matmul.takes_kernel``) and a model step of
+        ``group`` slots of which ``live`` carry a token (``tokens`` of them,
+        unless said: a round of a fused dispatch) takes the sorted form, else
+        none.  It asks what ``moe/sharded_moe.dropless_moe`` asks:
+        ``takes_sorted`` of the slots, as the traced program did, and where
+        they say "dense" whether the live rows are under
+        ``live_rows_sorted``, as the program does when it runs."""
+        k, e = self._experts_per_tok, self._router_experts
+        rows = tokens * k
+        kernel = rows and self._experts_kernel and (
+            sharded_moe.takes_sorted(group, k, e)
+            or (tokens if live is None else live) <= sharded_moe.live_rows_sorted(group, k, e))
         return {"expert_rows": rows, "expert_rows_kernel": rows if kernel else 0}
 
     # ------------------------------------------------------------------ TP
@@ -1097,9 +1104,9 @@ class InferenceEngineV2:
             # real: accepted + 1 a live row (last_spec_round holds this round
             # only); discarded: rejected drafts and rows flushed in flight
             n_real = sum(a + 1 for _, a, _ in self.last_spec_round.values())
-            anat.note_tokens(sum(len(v) for v in out.values()),
-                             sum(1 + len(d) for d in drafts) - n_real, real=n_real,
-                             **self._expert_rows(n_real, argmax.size))
+            verified = sum(1 + len(d) for d in drafts)      # the slots that carried a token through the program
+            anat.note_tokens(sum(len(v) for v in out.values()), verified - n_real, real=n_real,
+                             **self._expert_rows(n_real, argmax.size, live=verified))
             anat.mark("sample_accept")
         return out
 
@@ -1129,7 +1136,7 @@ class InferenceEngineV2:
                                         jnp.asarray(rb.chunk_lens), sub)
         if anat.enabled:
             # the passes over the rows run with the program already enqueued
-            anat.note_counts(**self._expert_rows(len(seqs) * k, batch),
+            anat.note_counts(**self._expert_rows(len(seqs) * k, batch, live=len(seqs)),
                              cache_counts=self._cache_counts([(s, k) for s in seqs], calls=k),
                              state_counts=self._state_counts([(s, k) for s in seqs], calls=k))
             anat.mark("compile_wait" if self._fresh_compile else "dispatch")

@@ -37,14 +37,22 @@ and there is no other knob:
   [S, E, C] tensor exists.
   Rows a token mask removes (the padding of a serving step) sort behind the
   last group, are in no group, cost no product and come out as exact zeros.
-  Which form a group takes is ``takes_sorted``'s rule of the layer's own
-  shapes (rows, choices a row, the router's experts): every expert multiplies
-  every row instead (``_experts_dense``) where the rows are few enough to be
-  bound by the weights' read and still choose nearly every expert (16 rows of
-  2 over 8): there the bank's read is the whole cost either way, and the sort
-  and the grouped kernel's fixed cost are not paid.  Where they cannot touch
-  most of the bank (12 rows of 4 over 64) the sorted form reads the chosen
-  experts alone, and a padding row costs nothing.
+  Which form a group takes is ``takes_sorted``'s rule of the rows, the
+  choices a row and the router's experts: every expert multiplies every row
+  instead (``_experts_dense``) where the rows are few enough to be bound by
+  the weights' read and still choose nearly every expert (16 rows of 2 over
+  8): there the bank's read is the whole cost either way, and the sort and
+  the grouped kernel's fixed cost are not paid.  Where they cannot touch most
+  of the bank (12 rows of 4 over 64) the sorted form reads the chosen experts
+  alone, and a padding row costs nothing.  The rule is asked of the group's
+  slots when the program is traced; where they say "dense" and a token mask
+  says which slots live, both forms are compiled and the program asks the
+  same rule of the live rows each time it runs (``sorted_up_to``: a decode
+  bucket of 16 with three rows live reads the experts those three chose).
+  That second lowering is held where the live rows can be under the bound as
+  often as not (``live_rows_sorted``: 16 slots against 11 rows, not 144), and
+  its dense branch holds the bank to the layout it lies in, or XLA would copy
+  the stack for it.
   A scanned trunk may hand the layer the banks of all its layers, still
   stacked, and the layer's index (``layer``): the grouped product is a
   custom call on the TPU and would copy the slice the scan gives it.
@@ -191,15 +199,55 @@ DENSE_FROM_BANK_SHARE = 0.95
 
 
 def takes_sorted(s: int, k: int, e: int) -> bool:
-    """Whether a group of ``s`` rows, ``k`` choices a row over a router of
-    ``e`` experts, takes the sorted form: above ``DENSE_UP_TO_TOKENS`` rows,
-    and wherever the rows cannot touch most of the bank.  ``s * k`` choices
-    spread evenly touch ``1 - (1 - 1/e)^(s k)`` of the experts, the held ones
-    of a share like all of them (16 rows of 2 over 8: 0.99, dense; 12 rows of
-    4 over 64: 0.53; 32 rows of 8 over 320: 0.55), and that with every row
-    live.  The one rule, for ``dropless_moe`` and for the engine's step
-    records (``engine_v2._expert_rows``): a function of the shapes alone."""
+    """Whether ``s`` rows, ``k`` choices a row over a router of ``e`` experts,
+    take the sorted form: above ``DENSE_UP_TO_TOKENS`` rows, and wherever the
+    rows cannot touch most of the bank.  ``s * k`` choices spread evenly touch
+    ``1 - (1 - 1/e)^(s k)`` of the experts, the held ones of a share like all
+    of them (16 rows of 2 over 8: 0.99, dense; 11 of them: 0.947; 12 rows of
+    4 over 64: 0.53; 32 rows of 8 over 320: 0.55).  The one rule, asked of two
+    numbers: of a group's slots, every one counted live, where
+    ``dropless_moe`` is traced, and, where the slots say "dense", of the rows
+    the token mask leaves live where the program runs (``live_rows_sorted``).
+    The engine's step records ask it of both as well
+    (``engine_v2._expert_rows``)."""
     return s > DENSE_UP_TO_TOKENS or 1.0 - (1.0 - 1.0 / e)**(s * k) < DENSE_FROM_BANK_SHARE
+
+
+def sorted_up_to(k: int, e: int) -> int:
+    """The most rows under ``DENSE_UP_TO_TOKENS`` of which ``takes_sorted``
+    says "sorted" (it is monotone there): 11 rows of 2 over 8, 47 of 4 over
+    64, 31 of 6 over 64, 119 of 8 over 320.  What a program that holds both
+    forms compares its live rows with; 0 where no row count is sorted."""
+    s = 0
+    while s < DENSE_UP_TO_TOKENS and takes_sorted(s + 1, k, e):
+        s += 1
+    return s
+
+
+def live_rows_sorted(s: int, k: int, e: int) -> int:
+    """For a group of ``s`` slots that ``takes_sorted`` calls dense and that
+    comes with a token mask: the live rows up to which its program takes the
+    sorted form all the same, both forms compiled (``sorted_up_to``), or 0
+    where it holds the dense form alone: where the sorted form would be taken
+    for fewer than half of the live counts the group can have.  A second
+    lowering costs every such program's set-up (0.4-0.9 s each on the chip's
+    host, PERF.md section 6, PR 48), a decode bucket of 16 slots is under its
+    11 rows most of the time, and a step of 144 slots only in a prompt's last
+    chunk.  ``dropless_moe`` compares its live tokens with this number where
+    it runs, and the engine's step records theirs (``engine_v2._expert_rows``)."""
+    rows = sorted_up_to(k, e)
+    return rows if 2 * rows >= s else 0
+
+
+def _experts_dense_in_place(x, top_vals, expert, group_sizes, bank, layer):
+    """``_experts_dense`` as a branch of a conditional, with the bank held to
+    the layout it lies in: XLA gives a branch's operands the layout its
+    products like best, and would copy a whole stack of banks a layer to hand
+    the dense form its matrices transposed (2.6 GB at Xing4's widths, 2.8 at
+    Mixtral's) while the grouped kernel beside it reads them as they are."""
+    from jax.experimental.layout import Layout, with_layout_constraint
+    bank = tuple(with_layout_constraint(w, Layout(major_to_minor=tuple(range(w.ndim)))) for w in bank)
+    return _experts_dense(x, top_vals, expert, group_sizes, bank, layer)
 
 
 def _experts_grouped(x, top_vals, expert, group_sizes, bank, layer):
@@ -250,8 +298,10 @@ def dropless_moe(x, logits, bank, k: int, token_mask=None, noise=None, layer=Non
     dtype — or, with ``layer`` (an index, traced or not), the banks of a
     whole scanned trunk [L, E, ...], of which layer ``layer``'s are read in
     place; token_mask: [S] bool or None — rows that carry no token go to no
-    expert and come out as zeros; noise: [S, E] or None, added to the logits
-    for the choice only (``top1_gating``'s RSample).
+    expert and come out as zeros, and where the slots alone would take the
+    dense form the rows it leaves live choose the form as the program runs
+    (``takes_sorted``); noise: [S, E] or None, added to the logits for the
+    choice only (``top1_gating``'s RSample).
 
     Three router forms, all in float32, chosen by what the model publishes:
 
@@ -314,8 +364,19 @@ def dropless_moe(x, logits, bank, k: int, token_mask=None, noise=None, layer=Non
     expert = jnp.where(live, top_idx, e)  # [S, k]; a dead row goes to expert id E: to none
     group_sizes = jnp.bincount(expert.reshape(-1), length=e + 1)[:e].astype(jnp.int32)
 
-    experts = _experts_grouped if takes_sorted(s, k, logits.shape[1]) else _experts_dense
-    return experts(x, top_vals, expert, group_sizes, bank, layer), l_aux, group_sizes
+    args = (x, top_vals, expert, group_sizes, bank, layer)
+    if takes_sorted(s, k, logits.shape[1]):
+        out = _experts_grouped(*args)
+    else:
+        # the slots say "dense".  With a mask both forms go into the program and it asks the rule of the live tokens
+        # where it runs, as the step records do (of the tokens, not of the rows that fall on a held expert: the
+        # held ones of a share are touched like all of them); with none every slot lives
+        live_up_to = 0 if token_mask is None else live_rows_sorted(s, k, logits.shape[1])
+        if live_up_to:
+            out = jax.lax.cond(jnp.sum(token_mask) <= live_up_to, _experts_grouped, _experts_dense_in_place, *args)
+        else:
+            out = _experts_dense(*args)
+    return out, l_aux, group_sizes
 
 
 def dropless_dispatch(x, logits, bank, k: int, token_mask=None, noise=None, layer=None, normalize: bool = True,

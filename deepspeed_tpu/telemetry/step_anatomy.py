@@ -64,10 +64,13 @@ to kill.  This module decomposes EVERY engine step into:
   ``expert_rows`` (rows a layer's routed experts multiplied:
   ``tokens_real`` x experts a token; 0 for a model with no expert layer),
   ``expert_rows_kernel`` (those of them that went through the grouped
-  kernel ``ds_gmm``: all of a step's that takes the sorted form
-  (``moe/sharded_moe.takes_sorted``) on a TPU of one device, none of a
-  step's where every expert multiplies every row, and none where the product
-  is ``jax.lax.ragged_dot``)
+  kernel ``ds_gmm``: all of a step's that takes the sorted form on a TPU of
+  one device, by ``moe/sharded_moe.takes_sorted`` asked of the step's slots
+  and, where they say "dense" and the program holds both forms
+  (``live_rows_sorted``), of the rows that live in it, a round's of a fused
+  dispatch, as the program asks it when it runs; none of a step's
+  where every expert multiplies every row, and none where the product is
+  ``jax.lax.ragged_dot``)
   and, under every cache geometry, ``attn_rows_visible`` (key rows a query
   could see: ring rows plus summary rows, or its whole history; summed over
   the step's token rows, one layer) and ``attn_rows_walked`` (key rows the

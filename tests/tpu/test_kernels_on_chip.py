@@ -583,6 +583,49 @@ def test_a_decode_steps_experts_take_the_sorted_form_on_chip(name, monkeypatch):
         assert _rel(got[:live], want[:live]) < 1e-2
 
 
+def test_the_live_rows_choose_the_experts_form_in_mixtrals_decode_bucket_on_chip(monkeypatch):
+    """Mixtral's layer read out of a stack of three at the decode bucket's 16
+    slots, whose slots say "dense": with a mask the program holds both forms
+    under one conditional, and at 1, 4, 8, 12 and 16 live rows it costs what
+    the better forced form costs (within 3%: the conditional itself is lost
+    in a branch of 1-4 ms, and no bank is copied) and gives the values of the
+    form the rule names for those rows."""
+    import jax
+    import jax.numpy as jnp
+    from deepspeed_tpu.moe import sharded_moe
+    s, k, e, d, f, layers = 16, 2, 8, 4096, 14336, 3
+    ks = jax.random.split(jax.random.PRNGKey(48), 5)
+    x = jax.random.normal(ks[0], (s, d), jnp.bfloat16)
+    logits = jax.random.normal(ks[1], (s, e), jnp.float32)
+    stack = tuple(jax.random.normal(kk, (layers, e) + shape, jnp.bfloat16) * shape[0]**-0.5
+                  for kk, shape in zip(ks[2:], ((d, f), (d, f), (f, d))))
+    rule = sharded_moe.takes_sorted
+
+    def layer():    # the banks are arguments: a closed-over constant would be written into the program's text
+        return jax.jit(lambda x, stack, mask: sharded_moe.dropless_moe(x, logits, stack, k, mask, None, 1))
+
+    assert not rule(s, k, e) and sharded_moe.sorted_up_to(k, e) == 11
+    forms = {"rule": layer()}
+    text = forms["rule"].lower(x, stack, jnp.ones((s, ), bool)).compile().as_text()
+    assert "ds_gmm" in text and " conditional(" in text
+    for name in ("sorted", "dense"):
+        monkeypatch.setattr(sharded_moe, "takes_sorted", lambda s, k, e: name == "sorted")
+        forms[name] = layer()
+        assert ("ds_gmm" in forms[name].lower(x, stack, jnp.ones((s, ), bool)).as_text()) == (name == "sorted")
+    for live in (1, 4, 8, 12, 16):
+        mask = jnp.arange(s) < live
+        out, ms = {}, {}
+        for name, fn in forms.items():
+            runs = [_ms(fn, x, stack, mask, n=20) for _ in range(3)]
+            (out[name], _, counts), ms[name] = runs[0][0], min(t for _, t in runs)
+        named = "sorted" if rule(live, k, e) else "dense"
+        print(f"\nmixtral 16 slots, {live} live, {int((np.asarray(counts) > 0).sum())} of 8 experts touched: "
+              f"rule {ms['rule']:.3f} ms ({named}), sorted {ms['sorted']:.3f}, dense {ms['dense']:.3f}")
+        np.testing.assert_array_equal(np.asarray(out["rule"]), np.asarray(out[named]))
+        assert not np.asarray(out["rule"])[live:].any() and _rel(out["rule"][:live], out["dense"][:live]) < 1e-2
+        assert ms["rule"] <= 1.03 * min(ms["sorted"], ms["dense"]), (live, ms)
+
+
 # ------------------------------------------------- a mixed step in row groups
 
 

@@ -171,9 +171,48 @@ def test_scanned_twin_holds_no_second_arena(described_chip, no_compile_cache, pr
     mem = compiled.memory_analysis()
     assert mem.alias_size_in_bytes >= arena_bytes, "the donated arena is not the result's buffer"
     assert mem.temp_size_in_bytes < arena_bytes // 2, (mem.temp_size_in_bytes, arena_bytes)
-    assert "tpu_custom_call" in compiled.as_text()
-    # the mixed step's 1,024 slots take the sorted form and its products the grouped kernel; a decode step's 8 do not
-    assert ("ds_gmm" in compiled.as_text()) == (program == "step_c128")
+    text = compiled.as_text()
+    assert "tpu_custom_call" in text
+    # the mixed step's 1,024 slots take the sorted form and its products the grouped kernel; a decode step's 8 say
+    # "dense" and hold both forms under one conditional on the rows that live (PR 48), which copies no bank either
+    assert "ds_gmm" in text and (" conditional(" in text) == (program != "step_c128")
+
+
+@pytest.mark.parametrize("pinned", [True, False], ids=["as_served", "control_without_the_layout"])
+def test_both_forms_of_the_experts_copy_no_bank(described_chip, one_chip, no_compile_cache, pinned, monkeypatch):
+    """An expert layer that holds both forms under one conditional (PR 48),
+    scanned over a stack of four layers' banks at Solar-Open2's widths (40
+    held of 320, 8 a token), 128 slots (the benchmark check's chunk): compiled
+    for one chip it holds a few MB beside its arguments.  The control shows
+    what the dense branch's layout constraint is for: without it XLA hands
+    that branch a transposed copy of a whole stack, 1.7 GB made anew every
+    layer (at 128 slots of Xing4's and of Mixtral's banks 2.8 GB, when their
+    programs still held both forms there: ``xing4_longdoc``'s check did not
+    fit the chip and Mixtral's ran three times as long)."""
+    from jax.sharding import Mesh
+    import numpy as np
+    from deepspeed_tpu.comm.mesh import trace_mesh
+    from deepspeed_tpu.moe import sharded_moe
+    if not pinned:
+        monkeypatch.setattr(sharded_moe, "_experts_dense_in_place", sharded_moe._experts_dense)
+    layers, e, held, d, f, k, s = 4, 320, (0, 40), 4096, 1280, 8, 128
+    assert sharded_moe.live_rows_sorted(s, k, e) == 119
+    sds = lambda shape, dtype: jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)  # noqa: E731
+    bank = tuple(sds((layers, held[1]) + shape, jnp.bfloat16) for shape in ((d, f), (d, f), (f, d)))
+
+    def trunk(x, w_router, bank, mask):
+        def layer(x, index):
+            out, _, _ = sharded_moe.dropless_moe(x, x.astype(jnp.float32) @ w_router, bank, k, mask, None, index,
+                                                 True, "sigmoid", held=held)
+            return x + out.astype(x.dtype), None
+        return jax.lax.scan(layer, x, jnp.arange(layers))[0]
+
+    with trace_mesh(Mesh(np.array([described_chip]), ("data", ))):
+        compiled = jax.jit(trunk).lower(sds((s, d), jnp.bfloat16), sds((d, e), jnp.float32), bank,
+                                        sds((s, ), jnp.bool_)).compile()
+    text, temp = compiled.as_text(), compiled.memory_analysis().temp_size_in_bytes
+    assert " conditional(" in text and "ds_gmm" in text
+    assert (temp < 64 * 2**20) if pinned else (temp > 2**30), temp
 
 
 def _slot_twin(family):
