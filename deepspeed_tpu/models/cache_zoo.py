@@ -35,6 +35,9 @@ from .solar_open2 import SolarOpen2Config
 from .solar_open2_cache import SolarOpen2ForCausalLMWithCache
 from .solar_open2_cache import init_cache as init_solar_open2_cache
 from .solar_open2_cache import slot_state_bytes as solar_open2_state_bytes
+from .minicpm_sala import MiniCPMSALAConfig
+from .minicpm_sala_cache import MiniCPMSALAForCausalLMWithCache, SparseSlotPagesGeometry
+from .minicpm_sala_cache import init_cache as init_minicpm_sala_cache
 from .kimi_vl import KimiVLConfig
 from .kimi_vl_cache import KimiVLForCausalLMWithCache
 from .mixtral import MixtralConfig
@@ -431,6 +434,9 @@ CACHE_MODEL_REGISTRY = {
                                 lambda cfg, page_size: SlotPagesGeometry(page_size,
                                                                          state_bytes=solar_open2_state_bytes(cfg)),
                                 init_solar_open2_cache, lambda cache: cache["pages"]),
+    # the sparse layers read their pages through a list walk of their own, which no contiguous walk's count fits
+    MiniCPMSALAConfig: CacheTwin(MiniCPMSALAForCausalLMWithCache, SparseSlotPagesGeometry, init_minicpm_sala_cache,
+                                 lambda cache: cache["pages"], walk_rows=lambda page_size, table_width: 0),
     Xing4Config: CacheTwin(Xing4ForCausalLMWithCache, lambda cfg, page_size: LatentPagesGeometry(page_size),
                            init_xing4_cache, walk_rows=xing4_walk_rows),
     # the same latent pages under the same kernel; a subclass of Xing4Config, found by its own type first

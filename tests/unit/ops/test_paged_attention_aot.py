@@ -300,3 +300,33 @@ def test_grouped_product_at_the_cells_operands(one_chip, no_compile_cache, opera
     lowered = jax.jit(product).lower(sds((m, k)), sds((g, k, n)), sds((g, ), jnp.int32))
     assert ("ds_gmm" in lowered.as_text()) and (("ds_tgmm" in lowered.as_text()) == backward)
     assert "tpu_custom_call" in lowered.compile().as_text()
+
+
+@pytest.mark.parametrize("kernel", ["list_walk", "gather_pages", "lightning_update"])
+def test_minicpm_salas_kernels_at_the_cells_shapes(one_chip, no_compile_cache, kernel):
+    """The three kernels ``models/minicpm_sala_cache.py`` brings, at the
+    shapes of ``minicpmsala_longctx``: 32 one-token rows over lists of 512
+    pages a key head out of an arena of two key heads of 128; a step's 32
+    pages a row of a prefill group; 32 rows' states of 32 x 128 x 128 float32
+    on the slot arena in place."""
+    from deepspeed_tpu.ops.lightning_update import lightning_update
+    from deepspeed_tpu.ops.sparse_paged_attention import _gather_pages, sparse_paged_decode
+    sds = lambda shape, dtype: jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)  # noqa: E731
+    bf16, i32, f32 = jnp.bfloat16, jnp.int32, jnp.float32
+    arena = sds((2, 64, 16, 2, 2, 128), bf16)
+    if kernel == "list_walk":
+        args = [sds((32, 32, 128), bf16), arena, sds((), i32), sds((32, 2, 512), i32), sds((32, ), i32), sds((32, ), i32)]
+        call, name = lambda q, p, l, lists, n, pos: sparse_paged_decode(q, p, l, lists, n, pos, 16, interpret=False), \
+            "ds_sparse_paged_attention"
+    elif kernel == "gather_pages":
+        args = [arena, sds((), i32), sds((4, 32), i32)]
+        call, name = lambda p, l, ids: _gather_pages(p, l, ids, False), "ds_gather_pages"
+    else:
+        row = sds((32, 32, 128), f32)
+        args = [sds((6, 33, 32, 128, 128), f32), sds((), i32), sds((32, ), i32), sds((32, ), i32), row, row, row,
+                sds((32, ), f32)]
+        call, name = lambda a, l, s, f, q, k, v, d: lightning_update(a, l, s, f, q, k, v, d, interpret=False), \
+            "ds_lightning_update"
+    lowered = jax.jit(call).lower(*args)
+    assert name in lowered.as_text()
+    assert "tpu_custom_call" in lowered.compile().as_text()

@@ -414,7 +414,8 @@ def test_annotate_factory_sees_step_and_marks_nested_with_counts():
                           "expert_rows": 50, "expert_rows_kernel": 42,
                           "attn_rows_visible": 0, "attn_rows_walked": 0,
                           "ssm_rows": 0, "window_rows_visible": 0, "ssd_state_bytes": 0,
-                          "mla_rows_read": 0, "mm_tokens": 0}
+                          "mla_rows_read": 0, "mm_tokens": 0,
+                          "sparse_decode_rows_read": 0, "lightning_state_bytes": 0}
     # the counts ride the row and the per-program fold too
     row = anat.last_step.to_row()
     assert (row["tokens_real"], row["slots"], row["tokens_out"],
