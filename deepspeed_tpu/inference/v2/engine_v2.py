@@ -842,13 +842,10 @@ class InferenceEngineV2:
             keys += [("vit", p) for p in sched.vision_patch_buckets]
         return keys
 
-    def _aot_lower(self, key):
-        """Lower one program key against abstract params/cache (the
-        ``compile_aot_serving`` machinery, aimed at the LIVE engine's
-        shapes): nothing executes, no engine state moves — unlike
-        ``warm_verify``'s all-padding dispatches.  The Lowered's text is
-        what an integrity check reads to see which kernels the step
-        really contains (``chip_smoke.py``)."""
+    def _aot_program(self, key):
+        """(the jitted program of one key, its arguments as abstract
+        params/cache/inputs of the LIVE engine's shapes): what ``_aot_lower``
+        lowers, and what a test traces to hold a program's jaxpr."""
         sds = jax.ShapeDtypeStruct
         params_abs = jax.tree.map(lambda x: sds(x.shape, x.dtype), self.params)
         cache_abs = jax.tree.map(lambda x: sds(x.shape, x.dtype), self.cache)
@@ -881,6 +878,16 @@ class InferenceEngineV2:
                 batch_args(sum(rows for rows, _ in key), 1)[1:] + (rng_abs, )
             if self._takes_image_rows(key):
                 args += (sds((slots, ), jnp.int32), sds(self.mm_rows.shape, self.mm_rows.dtype))
+        return jitted, args
+
+    def _aot_lower(self, key):
+        """Lower one program key against abstract params/cache (the
+        ``compile_aot_serving`` machinery, aimed at the LIVE engine's
+        shapes): nothing executes, no engine state moves — unlike
+        ``warm_verify``'s all-padding dispatches.  The Lowered's text is
+        what an integrity check reads to see which kernels the step
+        really contains (``chip_smoke.py``)."""
+        jitted, args = self._aot_program(key)
         if self.mesh is None:
             return jitted.lower(*args)
         from ...comm.mesh import trace_mesh

@@ -179,15 +179,22 @@ class SlotPagesGeometry(LinearGeometry):
     #: and is not kept by position: nothing of it can be shared or rewound to
     pages_immutable = False
     state_slots = True
-    #: a row's scan and convolution start from the slot's state and leave
-    #: theirs there: two rows of one sequence in a step would start from the
-    #: same state, and the second's would be the one kept
-    chunk_runs = False
 
-    def __init__(self, page_size: int, window: int = None, state_bytes: int = 0):
+    def __init__(self, page_size: int, window: int = None, state_bytes: int = 0, chunk_runs: bool = False):
         super().__init__(page_size)
         self.window = None if window is None else int(window)
         self.state_bytes = int(state_bytes)
+        #: a row's scan and convolution start from the slot's state and leave
+        #: theirs there: two rows of one sequence in a step would start from
+        #: the same state, and the second's would be the one kept.  True where
+        #: the twin says it hands the state on inside the program (its entry
+        #: in ``models/cache_zoo.CACHE_MODEL_REGISTRY``): a row that holds the
+        #: slot of the row before it and starts where that row, a full chunk,
+        #: ends is a *continuing row*: it starts from the state and the
+        #: convolution's inputs that row leaves, not from the slot's, and the
+        #: slot receives what the last row of the run leaves, once
+        #: (``models/solar_open2_cache.continuing_rows``)
+        self.chunk_runs = bool(chunk_runs)
 
     def token_capacity(self, max_tokens: int) -> int:
         return (self.table_width(max_tokens) - 1) * self.page_size

@@ -431,7 +431,8 @@ CACHE_MODEL_REGISTRY = {
                                                                             state_bytes=slot_state_bytes(cfg)),
                                    init_granite_hybrid_cache, lambda cache: cache["pages"]),
     SolarOpen2Config: CacheTwin(SolarOpen2ForCausalLMWithCache,
-                                lambda cfg, page_size: SlotPagesGeometry(page_size,
+                                # the one slot-holding twin that hands a slot's state from row to row: runs
+                                lambda cfg, page_size: SlotPagesGeometry(page_size, chunk_runs=True,
                                                                          state_bytes=solar_open2_state_bytes(cfg)),
                                 init_solar_open2_cache, lambda cache: cache["pages"]),
     # the sparse layers read their pages through a list walk of their own, which no contiguous walk's count fits

@@ -29,7 +29,8 @@ gated delta-rule linear attention whose state is a matrix a head.
 The recurrence has three forms that ``tests/unit/inference/test_solar_open2.py``
 ties together: position by position (``kda_recurrent``), a chunk of positions
 at a time with the state touched once a sub-block (``kda_chunk``, the WY / UT
-transform) and one position on the slot arena in place
+transform; with ``continues``, a row of the batch going on from the row
+before it) and one position on the slot arena in place
 (``ops/kda_update.py``, the serving twin's decode rows).
 
 **A chip's share.**  ``n_routed_experts`` is what the bank holds; where
@@ -218,18 +219,18 @@ def kda_update_reference(q, k, v, g, beta, state):
     return jnp.sum(q[..., None] * state, axis=-2), state
 
 
-def _kda_sub_block(state, q, k, v, g, beta):
-    """One sub-block of ``c`` positions, heads leading: ``q``, ``k``, ``g``
-    [B, H, c, K], ``v`` [B, H, c, V], ``beta`` [B, H, c], ``state`` [B, H, K,
-    V].  With ``G`` the running sum of ``g`` inside the sub-block (the WY / UT
-    transform of the delta rule):
+def _kda_sub_block_terms(q, k, v, g, beta):
+    """What of a sub-block of ``c`` positions does not ask for the state it
+    starts from, heads leading: ``q``, ``k``, ``g`` [B, H, c, K], ``v`` [B, H,
+    c, V], ``beta`` [B, H, c].  With ``G`` the running sum of ``g`` inside the
+    sub-block (the WY / UT transform of the delta rule):
 
       A = strict_lower(beta_t (k_t * exp(G_t - G_s)) . k_s);   (I + A) [W | U] = diag(beta) [k * exp(G) | v]
-      U' = U - W S_0;   o = (q * exp(G)) S_0 + lower((q_t * exp(G_t - G_s)) . k_s) U'
-      S_c = diag(exp(G_c)) S_0 + (k * exp(G_c - G))^T U'
 
-    Decays enter as differences ``G_t - G_s <= 0`` and as ``exp(G) <= 1``
-    alone, so nothing overflows however fast a channel forgets."""
+    -> (``W``, ``U``, ``q * exp(G)``, ``lower((q_t * exp(G_t - G_s)) . k_s)``,
+    ``k * exp(G_c - G)``, ``exp(G_c)``).  Decays enter as differences ``G_t -
+    G_s <= 0`` and as ``exp(G) <= 1`` alone, so nothing overflows however
+    fast a channel forgets."""
     c, dk = q.shape[-2], q.shape[-1]
     cum = jnp.cumsum(g, axis=-2)                                            # G  [B, H, c, K]
     seen = jnp.arange(c)[:, None] >= jnp.arange(c)[None, :]
@@ -240,33 +241,85 @@ def _kda_sub_block(state, q, k, v, g, beta):
     into = jnp.exp(cum)                                                     # exp(G_t): the start's state seen from t
     rhs = beta[..., None] * jnp.concatenate([k * into, v], axis=-1)
     wu = jax.scipy.linalg.solve_triangular(a + jnp.eye(c, dtype=a.dtype), rhs, lower=True, unit_diagonal=True)
-    mm = lambda eq, x, y: jnp.einsum(eq, x, y, precision=HIGHEST, preferred_element_type=jnp.float32)  # noqa: E731
-    u = wu[..., dk:] - mm("bhck,bhkv->bhcv", wu[..., :dk], state)
-    o = mm("bhck,bhkv->bhcv", q * into, state) + mm("bhts,bhsv->bhtv", qk, u)
     to_end = jnp.exp(cum[..., -1:, :] - cum)                                # exp(G_c - G_s)
-    state = jnp.exp(cum[..., -1, :])[..., None] * state + mm("bhck,bhcv->bhkv", k * to_end, u)
-    return state, o
+    return wu[..., :dk], wu[..., dk:], q * into, qk, k * to_end, jnp.exp(cum[..., -1, :])
 
 
-def kda_chunk(q, k, v, g, beta, state, sub=KDA_SUB):
+def _kda_sub_block_from(state, w, u, q_into, qk, k_to_end, decay):
+    """A sub-block from the ``state`` [B, H, K, V] it starts from, of what
+    ``_kda_sub_block_terms`` gave: (the state after it, ``o`` [B, H, c, V]).
+
+      U' = U - W S_0;   o = (q * exp(G)) S_0 + lower((q_t * exp(G_t - G_s)) . k_s) U'
+      S_c = diag(exp(G_c)) S_0 + (k * exp(G_c - G))^T U'"""
+    mm = lambda eq, x, y: jnp.einsum(eq, x, y, precision=HIGHEST, preferred_element_type=jnp.float32)  # noqa: E731
+    u = u - mm("bhck,bhkv->bhcv", w, state)
+    o = mm("bhck,bhkv->bhcv", q_into, state) + mm("bhts,bhsv->bhtv", qk, u)
+    return decay[..., None] * state + mm("bhck,bhcv->bhkv", k_to_end, u), o
+
+
+def _kda_sub_block(state, q, k, v, g, beta):
+    """One sub-block of ``c`` positions, heads leading: (the state after it, ``o``)."""
+    return _kda_sub_block_from(state, *_kda_sub_block_terms(q, k, v, g, beta))
+
+
+def _kda_blocks(t, sub):
+    """[B, C, H, ...] -> [n, B, H, sub, ...] float32, ``C`` padded to ``n`` whole sub-blocks."""
+    b, c = t.shape[:2]
+    n = -(-c // sub)
+    t = jnp.pad(t.astype(jnp.float32), ((0, 0), (0, n * sub - c)) + ((0, 0), ) * (t.ndim - 2))
+    t = t.reshape((b, n, sub) + t.shape[2:])
+    return jnp.moveaxis(jnp.moveaxis(t, 1, 0), 3, 2)
+
+
+def _kda_unblock(o, c):
+    """``o`` [n, B, H, sub, V] of the sub-blocks -> [B, C, H, V]."""
+    o = jnp.moveaxis(jnp.moveaxis(o, 2, 3), 0, 1)                           # [B, n, sub, H, V]
+    return o.reshape((o.shape[0], -1) + o.shape[3:])[:, :c]
+
+
+def _kda_rows_in_turn(blocks, state, continues):
+    """The sub-blocks of a batch whose row ``i`` starts from the state row ``i
+    - 1`` leaves where ``continues[i]`` (its ``state[i]`` is not read):
+    ``blocks`` of ``[n, B, H, sub, ...]`` each, ``state`` [B, H, K, V] ->
+    (the state every row leaves, ``o`` [n, B, H, sub, V]).  The rows go one
+    after another; of a row, what its sub-blocks do not ask the state for is
+    taken for all of them at once, and the state then passes through them in
+    order."""
+
+    def row(handed, at):
+        start, goes_on, blocks = at
+        terms = tuple(t[:, None] for t in _kda_sub_block_terms(*blocks))                     # [n, 1, H, ...]
+        first = jnp.where(goes_on, handed, start)[None]
+        last, o = jax.lax.scan(lambda s, at: _kda_sub_block_from(s, *at), first, terms)
+        return last[0], (last[0], o[:, 0])
+
+    by_row = tuple(jnp.moveaxis(t, 1, 0) for t in blocks)                                    # [B, n, H, sub, ...]
+    _, (state, o) = jax.lax.scan(row, jnp.zeros_like(state[0]), (state, continues, by_row))
+    return state, jnp.moveaxis(o, 0, 1)
+
+
+def kda_chunk(q, k, v, g, beta, state, sub=KDA_SUB, continues=None):
     """A chunk of positions (of any length) with no loop over positions:
     ``kda_recurrent``'s arguments and results.  A position that carries no
     token has ``g`` 0, ``beta`` 0 and ``k`` 0 and leaves the state alone.
     The chunk goes ``sub`` positions at a time (``_kda_sub_block``), the state
-    carried from one sub-block to the next."""
+    carried from one sub-block to the next, the rows of the batch side by
+    side.
+
+    ``continues`` [B] bool: row ``i`` of the batch goes on from row ``i - 1``
+    where it is set, its ``state[i]`` not read; ``state`` comes back a row
+    each, a continued row's being the one it handed on.  The rows then go one
+    after another (``_kda_rows_in_turn``: the same sub-blocks, solves and
+    order along a sequence), whether any continues another or none does: at
+    four rows of 128 that costs 0.4 ms of a step of 34 where none does
+    (PERF.md section 6, PR 50), less than a ``cond`` between the two forms."""
     with jax.named_scope("ds_kda_chunk"):
-        b, c = q.shape[:2]
-        n = -(-c // sub)
-
-        def blocks(t):   # [B, C, H, ...] -> [n, B, H, sub, ...]
-            t = jnp.pad(t.astype(jnp.float32), ((0, 0), (0, n * sub - c)) + ((0, 0), ) * (t.ndim - 2))
-            t = t.reshape((b, n, sub) + t.shape[2:])
-            return jnp.moveaxis(jnp.moveaxis(t, 1, 0), 3, 2)
-
-        args = tuple(blocks(t) for t in (q, k, v, g, beta))
-        state, o = jax.lax.scan(lambda s, at: _kda_sub_block(s, *at), state, args)
-        o = jnp.moveaxis(jnp.moveaxis(o, 2, 3), 0, 1)                       # [B, n, sub, H, V]
-        return o.reshape((b, n * sub) + o.shape[3:])[:, :c], state
+        blocks = tuple(_kda_blocks(t, sub) for t in (q, k, v, g, beta))
+        if continues is None:
+            state, o = jax.lax.scan(lambda s, at: _kda_sub_block(s, *at), state, blocks)
+        else:
+            state, o = _kda_rows_in_turn(blocks, state, continues)
+        return _kda_unblock(o, q.shape[1]), state
 
 
 def _a_log(key, shape, dtype):

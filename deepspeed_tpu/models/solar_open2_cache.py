@@ -33,6 +33,24 @@ prefill rows) gathers its rows' states, takes the chunked form
 (``solar_open2.kda_chunk``) and scatters them back.  The expert banks of a
 period's layers are read in place out of the periods' stack
 (``MixtralForCausalLMWithCache._stacked_banks``).
+
+**A continuing row.**  The rows of a prefill group may be consecutive chunks
+of one sequence (a run: ``SplitFuseScheduler.run_rows``, laid out by
+``ragged.pack_groups``).  A row that carries tokens, holds the slot of the row
+before it and starts where that row, a full chunk, ends *continues* it
+(``continuing_rows``, read from the step's own ``slot``, ``start_pos`` and
+``chunk_lens``): its recurrent state is the one that row leaves and its
+convolution's tail that row's last ``conv - 1`` inputs, not the slot's; a row
+that continues nothing reads its slot as ever, and the slot receives the
+state and the tail of the run's last row, once (a row that hands on writes
+nothing).  A group of one row, or of one token a row, is as it was; a wider
+group of several rows takes them row after row
+(``solar_open2.kda_chunk(continues=)``), whether one continues another or
+none does: one form, which costs four rows of different prompts 0.4 ms of a
+step of 34 (PERF.md section 6, PR 50).  The
+attention layers need nothing: a step's rows are all written to the pages
+before any attends.  This is what the twin's entry in
+``cache_zoo.CACHE_MODEL_REGISTRY`` says with ``chunk_runs=True``.
 """
 
 import functools
@@ -68,6 +86,18 @@ def slot_state_bytes(cfg: SolarOpen2Config) -> int:
     return 4 * cfg.count("kda") * cfg.kda_heads * cfg.kda_head_dim**2
 
 
+def continuing_rows(slot, start_pos, chunk_lens, width):
+    """Which rows of a group ``width`` wide go on from the row before them
+    (``goes_on`` [R]) and which are gone on from (``handed_on`` [R]): a row
+    continues the row before it where it carries tokens, holds that row's
+    slot and starts where that row, a full one, ends.  Read from the step's
+    own inputs: the engine packs a run's chunks so (``ragged.pack_groups``)."""
+    goes_on = (chunk_lens[1:] > 0) & (slot[1:] == slot[:-1]) & (chunk_lens[:-1] == width) & \
+        (start_pos[1:] == start_pos[:-1] + width)
+    no = jnp.zeros((1, ), bool)
+    return jnp.concatenate([no, goes_on]), jnp.concatenate([goes_on, no])
+
+
 def _kda_mix(mixer, h, groups, cache, index, slot, start_pos, chunk_lens, live):
     """A KDA layer's mixer through its slots, ``index`` among the cache's KDA
     layers: (mixed, cache).  ``h`` is the flat axis [T, hidden] of ``groups``."""
@@ -75,10 +105,22 @@ def _kda_mix(mixer, h, groups, cache, index, slot, start_pos, chunk_lens, live):
     def fresh_rows(start_pos, chunk_lens):
         return (start_pos == 0) & (chunk_lens > 0)       # a row that carries no token changes nothing
 
+    def continuing(slot, start_pos, chunk_lens, rows, width):
+        """(``goes_on``, the slots to write: none for a row that hands its state on), or None where a
+        group's shape lets no row continue another."""
+        if rows == 1 or width == 1:
+            return None, slot
+        goes_on, handed_on = continuing_rows(slot, start_pos, chunk_lens, width)
+        return goes_on, jnp.where(handed_on, cache["kda"].shape[1], slot)     # past the arena: dropped
+
     def convolve(cache, qkv, slot, start_pos, chunk_lens):
         tail = jnp.where(fresh_rows(start_pos, chunk_lens)[:, None, None], 0, cache["conv"][index, slot])
+        goes_on, put = continuing(slot, start_pos, chunk_lens, *qkv.shape[:2])
+        if goes_on is not None:     # the inputs before a continuing row's first are the last of the row before it
+            before = jnp.roll(qkv[:, qkv.shape[1] - tail.shape[1]:], 1, axis=0).astype(tail.dtype)
+            tail = jnp.where(goes_on[:, None, None], before, tail)
         qkv, tail = mixer.convolve(qkv, tail, chunk_lens)
-        return qkv, dict(cache, conv=cache["conv"].at[index, slot].set(tail.astype(cache["conv"].dtype)))
+        return qkv, dict(cache, conv=cache["conv"].at[index, put].set(tail.astype(cache["conv"].dtype), mode="drop"))
 
     def recur(cache, q, k, v, g, beta, slot, start_pos, chunk_lens):
         fresh = fresh_rows(start_pos, chunk_lens)
@@ -87,8 +129,9 @@ def _kda_mix(mixer, h, groups, cache, index, slot, start_pos, chunk_lens, live):
             o, kda = kda_update(cache["kda"], index, slot, flags, q[:, 0], k[:, 0], v[:, 0], g[:, 0], beta[:, 0])
             return o[:, None], dict(cache, kda=kda)
         state = jnp.where(fresh[:, None, None, None], 0.0, cache["kda"][index, slot])
-        o, state = kda_chunk(q, k, v, g, beta, state)
-        return o, dict(cache, kda=cache["kda"].at[index, slot].set(state))
+        goes_on, put = continuing(slot, start_pos, chunk_lens, *q.shape[:2])
+        o, state = kda_chunk(q, k, v, g, beta, state, continues=goes_on)
+        return o, dict(cache, kda=cache["kda"].at[index, put].set(state, mode="drop"))
 
     rows = (slot, start_pos, chunk_lens)
     qkv, g, beta, gate = mixer.in_project(h, live)

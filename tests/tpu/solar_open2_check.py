@@ -99,6 +99,23 @@ def reference_logits(config: dict, params, rows, without=()):
     return out
 
 
+def _engine(config: dict, traffic: dict, seed: int):
+    """The cell's engine under ``check_init``'s weights: its twin, weights and cache are what is compared."""
+    import jax
+    import jax.numpy as jnp
+
+    import harness
+    from deepspeed_tpu.inference.v2 import InferenceEngineV2
+    from flax import linen as nn
+    from kinds import serve_open_loop
+
+    pcfg = harness.program_config(config)
+    model = harness.load_symbol(config["program"]["model"])(pcfg)
+    abstract = nn.meta.unbox(jax.eval_shape(model.init, jax.random.PRNGKey(0), jnp.zeros((1, 128), jnp.int32)))
+    return InferenceEngineV2(pcfg, check_init(abstract, seed, jnp.bfloat16, config),
+                             serve_open_loop.engine_config(config, traffic))
+
+
 def readings(config: dict, traffic: dict, seed: int, rows: list) -> dict:
     """``rows``: (prompt tokens, decode tokens, state slot, first position
     compared) a sequence.  Every row goes through the engine's own twin,
@@ -113,18 +130,9 @@ def readings(config: dict, traffic: dict, seed: int, rows: list) -> dict:
     import jax
     import jax.numpy as jnp
 
-    import harness
-    from deepspeed_tpu.inference.v2 import InferenceEngineV2
-    from flax import linen as nn
-    from kinds import serve_open_loop
     from refs import plain
 
-    pcfg = harness.program_config(config)
-    model = harness.load_symbol(config["program"]["model"])(pcfg)
-    abstract = nn.meta.unbox(jax.eval_shape(model.init, jax.random.PRNGKey(0), jnp.zeros((1, 128), jnp.int32)))
-    params = check_init(abstract, seed, jnp.bfloat16, config)
-    eng = InferenceEngineV2(pcfg, params, serve_open_loop.engine_config(config, traffic))
-    del params                                                               # the engine's are the ones compared
+    eng = _engine(config, traffic, seed)
     kv, sched = eng.kv, eng.econfig.scheduler
     chunk, page = sched.prefill_chunk, kv.page_size
 
@@ -170,6 +178,91 @@ def readings(config: dict, traffic: dict, seed: int, rows: list) -> dict:
         out["zeroed"][kind] = [np.asarray(plain.rel_l2(c, r)) for c, r in zip(changed, ref)]
         del changed
     return out
+
+
+def run_readings(config: dict, traffic: dict, seed: int, prompt: int, decode: int, first: int, slots=(5, 9),
+                 run_rows: int = 4) -> dict:
+    """One sequence of ``prompt`` tokens twice through the engine's own twin,
+    weights and cache, on scattered pages: in ``slots[0]`` as **runs**, its
+    consecutive chunks the rows of one rectangle of ``run_rows`` rows (each
+    row behind the first continues the row before it: the state and the
+    convolution's tail are handed on inside the program), and in ``slots[1]``
+    a chunk a step; then ``decode`` steps of one token each way.  Returns, of
+    the positions from ``first`` on: ``run`` and ``a_chunk_a_step``, each
+    ``||logits - ref|| / ||ref||`` against the float32 reference;
+    ``between``, the same distance of the two from one another;
+    ``without_state``, of the reference that reads an empty state from the
+    whole one (what a state that is not handed on would look like); and
+    ``kda`` and ``conv``, the relative distance of what the two slots hold at
+    the end."""
+    import jax
+    import jax.numpy as jnp
+
+    from refs import plain
+
+    eng = _engine(config, traffic, seed)
+    kv, chunk = eng.kv, eng.econfig.scheduler.prefill_chunk
+    rng = np.random.default_rng(int(seed) + 1)
+    toks = rng.integers(1, config["vocab_size"], prompt + decode).tolist()
+    free = rng.permutation(np.arange(1, eng.econfig.kv.num_pages)).tolist()
+    n_pages = math.ceil(len(toks) / kv.page_size)
+    assert n_pages < kv.table_width
+    step = jax.jit(lambda p, c, t, s, b, ln: eng.model.apply(p, t, s, b, c, ln), donate_argnums=1)
+
+    def serve(slot, rows):
+        """The sequence in ``slot``, ``rows`` consecutive chunks a step: the logits from ``first`` on."""
+        table = np.zeros((rows, kv.table_width), np.int32)
+        table[:, :n_pages] = [free.pop() for _ in range(n_pages)]
+        table[:, -1] = slot
+        pos, got, steps = 0, [], 0
+        while pos < len(toks):
+            width = chunk if pos < prompt else 1
+            end = prompt if pos < prompt else len(toks)
+            starts = [min(pos + r * width, end) for r in range(rows if width > 1 else 1)]
+            lens = [min(width, end - at) for at in starts]
+            t, s, n = np.zeros((rows, width), np.int32), np.zeros(rows, np.int32), np.zeros(rows, np.int32)
+            for r, (at, ln) in enumerate(zip(starts, lens)):
+                t[r, :ln], s[r], n[r] = toks[at:at + ln], at, ln
+            # a row that carries nothing is a padding row: no pages, the scratch slot
+            tables = np.where((n > 0)[:, None], table, 0)
+            logits, eng.cache = step(eng.params, eng.cache, jnp.asarray(t), jnp.asarray(s), jnp.asarray(tables),
+                                     jnp.asarray(n))
+            for r, (at, ln) in enumerate(zip(starts, lens)):
+                skip = max(first - at, 0)
+                if skip < ln:
+                    got.append(logits[r, skip:ln].astype(jnp.float32))
+            pos += sum(lens)
+            steps += 1
+            jax.block_until_ready(logits)       # a step at a time, as the engine reads its tokens back
+            del logits
+        return jnp.concatenate(got), steps
+
+    out = {}
+    run, out["run_steps"] = serve(slots[0], run_rows)
+    each, out["a_chunk_a_step_steps"] = serve(slots[1], 1)
+    for name in ("kda", "conv"):
+        a, b = (np.asarray(eng.cache[name][:, slot], np.float32) for slot in slots)
+        out[name] = float(np.linalg.norm(a - b) / np.linalg.norm(b))
+    eng.cache = None
+    ref, = reference_logits(config, eng.params, [(toks, first)])
+    out["run"], out["a_chunk_a_step"] = (np.asarray(plain.rel_l2(g, ref)) for g in (run, each))
+    out["between"] = np.asarray(plain.rel_l2(run, each))
+    del run, each
+    changed, = reference_logits(config, eng.params, [(toks, first)], without=("state", ))
+    out["without_state"] = np.asarray(plain.rel_l2(changed, ref))
+    return out
+
+
+def report_run(out: dict) -> dict:
+    """Print ``run_readings``; the 90th percentiles (the 10th of ``without_state``), and the states' distances."""
+    read = {name: float(np.percentile(out[name], 10 if name == "without_state" else 90))
+            for name in ("run", "a_chunk_a_step", "between", "without_state")}
+    read.update(kda=out["kda"], conv=out["conv"])
+    print(f"solar_open2_check: run steps={out['run_steps']} a_chunk_a_step_steps={out['a_chunk_a_step_steps']} "
+          f"positions={len(out['run'])} " + " ".join(f"{k}={v:.6f}" for k, v in read.items()) +
+          f" run_p50={np.median(out['run']):.6f} a_chunk_a_step_p50={np.median(out['a_chunk_a_step']):.6f} "
+          f"between_p50={np.median(out['between']):.6f}", flush=True)
+    return read
 
 
 def report(out: dict, rows: list) -> list:

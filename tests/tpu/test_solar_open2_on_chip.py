@@ -53,3 +53,22 @@ def test_the_cells_two_group_programs_give_what_the_rectangle_gives_in_real_slot
                                     lambda abstract: solar_open2_check.check_init(abstract, seed, "bfloat16", config),
                                     solar_open2_check.REAL_FROM)
     assert row_groups_check.report("solar_open2_check", out) < config["check"]["limits"]["long"], out
+
+
+def test_a_prompt_fed_as_runs_of_four_rows_is_the_prompt_fed_a_chunk_a_step():
+    """A run in the state slots (PR 50): 8,576 tokens as 16 rectangles of four
+    consecutive chunks and one of three, every row behind a rectangle's first
+    starting from the state and the convolution's tail the row before it
+    leaves, against the same tokens a chunk a step in another slot: the last
+    256 prompt positions (two continuing rows) and 64 decode steps against the
+    float32 reference, the two ways against one another, and what the two
+    slots hold at the end."""
+    config, traffic = _load("configs", "solar-open2-250b-serve-1chip"), _load("traffic", "ctx_8k_32k_long_answer")
+    seed = int(os.environ.get("DS_CHECK_SEED", 3000050701))
+    out = solar_open2_check.run_readings(config, traffic, seed, 8576, 64, 8320)
+    read = solar_open2_check.report_run(out)
+    assert (out["run_steps"], out["a_chunk_a_step_steps"]) == (17 + 64, 67 + 64)
+    limit = config["check"]["limits"]["long"]
+    assert read["run"] < limit and read["a_chunk_a_step"] < limit and read["between"] < limit, read
+    # a state that is not handed on reads as the reference without its state term does: far over the program's error
+    assert read["without_state"] > 3 * read["run"] and read["kda"] < limit and read["conv"] < limit, read

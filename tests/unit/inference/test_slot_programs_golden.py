@@ -1,0 +1,107 @@
+"""The programs of the slot-holding twins that PR 50 (a run in a state-slot
+geometry: Solar-Open2's prefill rows may continue one another) was to leave
+word for word as they were: Solar-Open2's decode-bucket step and fused
+program, and the one-row and four-row step programs of Phi-4-mini-flash,
+Granite 4.0-H and MiniCPM-SALA, whose twins start every row from its slot.
+Each is traced through the engine's own builder at a small size
+(``InferenceEngineV2._aot_program``) and its jaxpr's digest held to
+``slot_programs_golden.json``, which this file wrote on the parent of PR 50
+(with ``_aot_program`` split out of ``_aot_lower`` there too, which touches no
+program):
+
+    PYTHONPATH=<a checkout> JAX_PLATFORMS=cpu python tests/unit/inference/test_slot_programs_golden.py \\
+        > tests/unit/inference/slot_programs_golden.json
+
+A PR that means to change one of these programs writes the file again and
+says so; one that does not finds here that it did.
+"""
+
+import hashlib
+import json
+import os
+import re
+
+import jax
+import jax.numpy as jnp
+import pytest
+
+from deepspeed_tpu.inference.v2 import InferenceEngineV2, RaggedInferenceEngineConfig
+from deepspeed_tpu.inference.v2.engine_v2 import build_cache_model
+from deepspeed_tpu.inference.v2.scheduler import SchedulerConfig
+from deepspeed_tpu.models.cache_zoo import cache_twin
+from deepspeed_tpu.models.llama_cache import PagedKVConfig
+
+from test_minicpm_sala import CFG as SALA_CFG
+from test_slot_twins_golden import FAMILIES
+from test_solar_open2 import CFG as SOLAR_CFG
+
+CHUNK, BUCKET, FUSED = 32, 8, 4
+DECODE, ONE_ROW, FOUR_ROWS = ((BUCKET, 1), ), ((BUCKET, 1), (1, CHUNK)), ((BUCKET, 1), (4, CHUNK))
+#: twin -> (its small configuration, its page, the programs held)
+HELD = {
+    "solar_open2": (SOLAR_CFG, 16, (DECODE, ("multi", BUCKET, FUSED))),
+    "phi4flash": (FAMILIES["phi4flash"][1], 16, (ONE_ROW, FOUR_ROWS)),
+    "granitehybrid": (FAMILIES["granitehybrid"][1], 16, (ONE_ROW, FOUR_ROWS)),
+    "minicpm_sala": (SALA_CFG, 8, (ONE_ROW, FOUR_ROWS)),       # a compressed key a page: the selection's stride
+}
+CASES = [(twin, key) for twin, (_, _, keys) in HELD.items() for key in keys]
+#: the digest of Solar-Open2's ``step:b8:c1:b4:c32`` on the parent of PR 50, which that PR meant to change
+PARENT_SOLAR_FOUR_ROWS = "c878025b545252141364706ac50b7c3739ac39472c70a4afd135ef27b118a894"
+
+
+@pytest.fixture(scope="module")
+def engines():
+    made = {}
+
+    def engine(twin):
+        if twin not in made:
+            made[twin] = _engine(*HELD[twin][:2])
+        return made[twin]
+
+    return engine
+
+
+def _engine(cfg, page):
+    """An engine over ``cfg`` under its twin's own initialisation: the
+    programs are functions of shapes, the values are never read."""
+    kv = PagedKVConfig(num_pages=64, page_size=page, max_pages_per_seq=20)
+    model = build_cache_model(cfg, page)
+    cache = cache_twin(cfg).init_cache(cfg, kv, jnp.float32, 2, CHUNK)
+    params = jax.jit(model.init)(jax.random.PRNGKey(0), jnp.zeros((1, 1), jnp.int32), jnp.zeros((1, ), jnp.int32),
+                                 jnp.zeros((1, kv.max_pages_per_seq), jnp.int32), cache, jnp.ones((1, ), jnp.int32))
+    sched = SchedulerConfig(token_budget=BUCKET + 4 * CHUNK, max_seqs=BUCKET, prefill_chunk=CHUNK, decode_bucket=BUCKET)
+    return InferenceEngineV2(cfg, params, RaggedInferenceEngineConfig(
+        kv=kv, scheduler=sched, max_new_tokens=8, decode_steps_per_dispatch=FUSED, enable_prefix_cache=False,
+        kv_dtype=jnp.float32))
+
+
+def digest(eng, key) -> str:
+    """The program's jaxpr as text (an object's address, if one is printed, left out), hashed."""
+    jitted, args = eng._aot_program(key)
+    return hashlib.sha256(re.sub(r"0x[0-9a-f]+", "0x", str(jitted.trace(*args).jaxpr)).encode()).hexdigest()
+
+
+def _golden():
+    with open(os.path.join(os.path.dirname(__file__), "slot_programs_golden.json")) as f:
+        return json.load(f)
+
+
+@pytest.mark.parametrize("twin, key", CASES, ids=[f"{twin}-{InferenceEngineV2._key_label(key)}" for twin, key in CASES])
+def test_the_program_is_word_for_word_the_parents(engines, twin, key):
+    eng = engines(twin)
+    assert key in eng.step_shape_set()
+    assert digest(eng, key) == _golden()[twin][InferenceEngineV2._key_label(key)]
+
+
+def test_the_program_that_takes_a_run_is_not_among_them(engines):
+    """The guard of the guard: Solar-Open2's four-row step is the program the
+    PR changed (its rows go one after another, the state handed on), and the
+    digest tells it from the parent's."""
+    eng = engines("solar_open2")
+    assert digest(eng, FOUR_ROWS) != PARENT_SOLAR_FOUR_ROWS
+    assert digest(eng, FOUR_ROWS) != digest(eng, ONE_ROW)
+
+
+if __name__ == "__main__":
+    print(json.dumps({twin: {InferenceEngineV2._key_label(key): digest(_engine(cfg, page), key) for key in keys}
+                      for twin, (cfg, page, keys) in HELD.items()}, indent=1))
