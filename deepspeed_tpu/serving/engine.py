@@ -123,8 +123,17 @@ class ServingEngine:
         self._ewma_step_s: Optional[float] = None
         # async double-buffered dispatch (config.async_dispatch): the
         # step enqueued last tick, completed at the NEXT tick's readback —
-        # (InFlightStep, charged_cost, dispatch_ts) or None
+        # (InFlightStep, charged_cost, dispatch_ts, the prefilling requests
+        # it carries as ``_carried`` gives them) or None
         self._inflight = None
+        # the way to a first token (docs/OBSERVABILITY.md): the seconds of
+        # the steps this frontend has run, on its clock (a step's charge on
+        # a clock that charges), and the window of the newest of them.  A
+        # request is stamped with the sum when a stretch of its PREFILL
+        # begins (``_run_position``), so what ran and did not carry it takes
+        # no list
+        self._run_s = 0.0
+        self._run_last = (0.0, 0.0)
         # a fleet ReplicaClockView over a shared VirtualClock quantizes
         # latencies exactly like a bare VirtualClock — unwrap it so the
         # warning below fires for fleet replicas too
@@ -256,7 +265,8 @@ class ServingEngine:
         req = ServingRequest(
             uid=uid, prompt=list(prompt), arrival_ts=now,
             max_new_tokens=max_new_tokens,
-            deadline=deadline, priority=priority, stream=stream, spec=spec)
+            deadline=deadline, priority=priority, stream=stream, spec=spec,
+            submit_ts=now if arrival_ts is None else self.clock.now())
         if resume_tokens:
             if len(resume_tokens) >= max_new_tokens:
                 raise ValueError(
@@ -425,6 +435,7 @@ class ServingEngine:
             dt = charged if charged is not None else self.clock.now() - t_step
             self._ewma_step_s = dt if self._ewma_step_s is None \
                 else 0.8 * self._ewma_step_s + 0.2 * dt
+            self._note_step(self._carried(plan, anat), t_step, t_step + dt)
             if anat.enabled:
                 if charged is not None:
                     anat.charge_last_step(charged)
@@ -479,13 +490,14 @@ class ServingEngine:
             anat.mark("admit")
         out: Dict[int, List[int]] = {}
         if self._inflight is not None:
-            inf, charged, t_dispatch = self._inflight
+            inf, charged, t_dispatch, carried = self._inflight
             self._inflight = None
             out = self.engine.complete_step(inf)
             dt = charged if charged is not None \
                 else self.clock.now() - t_dispatch
             self._ewma_step_s = dt if self._ewma_step_s is None \
                 else 0.8 * self._ewma_step_s + 0.2 * dt
+            self._note_step(carried, t_dispatch, t_dispatch + dt)
             if anat.enabled:
                 self._fold_compiles(anat)
             # fold BEFORE the next dispatch (it clears last_spec_round)
@@ -524,12 +536,84 @@ class ServingEngine:
                         # claim it now so the next overlap window cannot
                         # absorb it as host work
                         anat.device_mark()
-                    self._inflight = (inf, charged, t_dispatch)
+                    # looked up with the program already enqueued; their
+                    # windows are stored when the step completes
+                    self._inflight = (inf, charged, t_dispatch, self._carried(plan, anat))
         finally:
             self._deliver(out, t_deliver)
             if anat.enabled:
                 anat.mark("deliver")
         return out
+
+    # ------------------------------------------- the way to a first token
+
+    def _carried(self, plan, anat) -> list:
+        """(request, tokens) of the prefill work of ``plan``: whom the step
+        carries a chunk (or a run of chunks) of.  The recorder's open step
+        gets their uids (``prefill_uids`` of its ``ds.step`` range)."""
+        active = self._active
+        carried = [(req, n) for seq, n in plan.prefill if (req := active.get(seq.uid)) is not None]
+        if carried and anat.enabled:
+            anat.note_prefill_uids(tuple(req.uid for req, _ in carried))
+        return carried
+
+    def _note_step(self, carried: list, t0: float, t1: float) -> None:
+        """A step ran from ``t0`` to ``t1`` on this frontend's clock and
+        carried chunks of ``carried`` (``_carried``): the window joins the
+        running sum and the ``carry_windows`` of each of them still in
+        PREFILL (not one that expired or began to migrate while the step was
+        in flight), with its counts up to the first token.  For the traced
+        requests it passed by it is a ``bypass_windows`` entry: their spans
+        need to know where; the rows take the running sum."""
+        self._run_s += t1 - t0
+        self._run_last = window = (t0, t1)
+        for req, n in carried:
+            if req.state is not RequestState.PREFILL:
+                continue
+            req.carry_windows.append(window)
+            if req.first_token_ts is None:
+                if req.first_dispatch_ts is None:
+                    req.first_dispatch_ts = t0
+                req.prefill_steps += 1
+                req.prefill_tokens += n
+        if self._trace_ctx:
+            for uid, req in self._active.items():
+                if req.state is RequestState.PREFILL and uid in self._trace_ctx \
+                        and not (req.carry_windows and req.carry_windows[-1] is window):
+                    req.bypass_windows.append(window)
+
+    def _run_position(self, ts: float) -> float:
+        """The step seconds this frontend's engine had run by ``ts``, a
+        reading of its clock at about now: the running sum, less what the
+        newest window has beyond ``ts`` (a state change stamped with its
+        tick's start; a charge on a clock that the fleet's round advances),
+        plus the part of a dispatch in flight that has elapsed (the
+        pipelined tick).  A stretch of PREFILL from ``a`` to ``b`` had
+        ``_run_position(b) - _run_position(a)`` step seconds in it."""
+        t0, t1 = self._run_last
+        pos = self._run_s - min(max(0.0, t1 - ts), t1 - t0)
+        if self._inflight is not None:
+            _, charged, t0, _ = self._inflight
+            elapsed = ts - t0 if charged is None else min(ts - t0, charged)
+            pos += max(0.0, elapsed)
+        return pos
+
+    def _leave_prefill(self, req: ServingRequest, ts: float) -> None:
+        """A stretch of PREFILL ends at ``ts`` before the first token (a
+        preemption, a migration): the step seconds in it join ``ran_s``."""
+        if req.state is RequestState.PREFILL and req.first_token_ts is None:
+            req.ran_s += self._run_position(ts) - req.run_mark
+
+    def _note_first_token(self, req: ServingRequest, anat) -> None:
+        """The first token of ``req`` was just delivered: its way here as one
+        row (``telemetry.spans.first_token_row``), kept on the request for
+        the terminal metrics and handed to the engine's step recorder
+        (``first_tokens``).  Called where a recorder or a registry reads it."""
+        from ..telemetry.spans import first_token_row
+        if req.state is RequestState.PREFILL:   # the stretch the token ends, as ``_leave_prefill`` folds the others
+            req.ran_s += self._run_position(req.first_token_ts) - req.run_mark
+        req.ttft_row = first_token_row(req, req.ran_s)
+        anat.note_first_token(req.ttft_row)
 
     def _encode_images(self, anat) -> None:
         """The vision tower's part of a tick, before the step is planned: the
@@ -563,6 +647,7 @@ class ServingEngine:
                     self.metrics.counter("serving/vision_reencoded").inc()
             if req is not None and rec["done"]:
                 req.encode_windows.append((req.history[-1][1], t1))
+                req.run_mark = self._run_position(t1)   # what ran before is the tower's wait
             t0 = t1
         if n and anat.enabled:
             anat.mark("vision_encode")
@@ -693,6 +778,7 @@ class ServingEngine:
             if req.admitted_ts is None:
                 req.admitted_ts = adm_now
             req.to(RequestState.PREFILL, adm_now)
+            req.run_mark = self._run_position(adm_now)
             self._active[req.uid] = req
             reserved += self.admission._start_pages(req)
 
@@ -923,7 +1009,9 @@ class ServingEngine:
         except Exception:
             seq.paused = False
             raise
-        req.to(RequestState.MIGRATING, self.clock.now())
+        now = self.clock.now()
+        self._leave_prefill(req, now)
+        req.to(RequestState.MIGRATING, now)
         return exporter
 
     def abort_migration(self, uid: int) -> None:
@@ -939,7 +1027,9 @@ class ServingEngine:
             seq.paused = False
         back = RequestState.DECODE if seq is not None and seq.in_decode \
             else RequestState.PREFILL
-        req.to(back, self.clock.now())
+        now = self.clock.now()
+        req.to(back, now)
+        req.run_mark = self._run_position(now)
 
     def complete_migration(self, uid: int) -> ServingRequest:
         """Close out a MIGRATING request whose snapshot fully exported: the
@@ -977,6 +1067,7 @@ class ServingEngine:
         # every token the evicted sequence generated was already delivered to
         # req.tokens at the tick it was sampled — the descriptor can be
         # dropped without losing output
+        self._leave_prefill(req, now)
         req.to(RequestState.EVICTED, now)
         req.preemptions += 1
         self.stats.preemptions += 1
@@ -1004,6 +1095,9 @@ class ServingEngine:
                 continue
             if req.first_token_ts is None:
                 req.first_token_ts = now
+                anat = getattr(self.engine, "anatomy", NULL_ANATOMY)
+                if anat.enabled or self.metrics is not None:
+                    self._note_first_token(req, anat)
             if req.state is RequestState.PREFILL:
                 req.to(RequestState.DECODE, now)
             req.tokens.extend(int(t) for t in toks)
@@ -1060,6 +1154,13 @@ class ServingEngine:
         if state is RequestState.DONE:
             if req.ttft is not None:
                 self.metrics.histogram("serving/ttft_s").record(req.ttft)
+            if req.ttft_row is not None:
+                # what of it a step that carried the request took, a step
+                # that passed it by, and no step at all
+                row = req.ttft_row
+                self.metrics.histogram("serving/ttft_carried_s").record(row["carried_s"])
+                self.metrics.histogram("serving/ttft_bypassed_s").record(row["bypassed_s"])
+                self.metrics.histogram("serving/ttft_wait_s").record(row["wait_s"])
             if req.tpot is not None:
                 self.metrics.histogram("serving/tpot_s").record(req.tpot)
             if req.queue_wait is not None:
@@ -1196,7 +1297,7 @@ class ServingEngine:
             # async mode with a step in flight: block on its readback and
             # discard the fold output — fenced work is dropped WHOLE (the
             # flushes below release its sequences), never half-applied
-            inf, _, _ = self._inflight
+            inf = self._inflight[0]
             self._inflight = None
             try:
                 self.engine.complete_step(inf)

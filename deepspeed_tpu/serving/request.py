@@ -152,6 +152,38 @@ class ServingRequest:
     #: tower: telemetry carves them out of the PREFILL interval as
     #: ``phase/vision_encode`` spans
     encode_windows: List[Tuple[float, float]] = dataclasses.field(default_factory=list)
+    # ---- the way to the first token (docs/OBSERVABILITY.md "The way to a
+    # first token"): what the frontend notes a step for each prefilling
+    # sequence, folded into one row of ``StepAnatomy.first_tokens`` when the
+    # first token is delivered (``first_token_row``)
+    #: the clock at the ``submit()`` call; ``arrival_ts`` may be backdated by
+    #: the caller (a load generator that shares the serving thread, a router)
+    submit_ts: Optional[float] = None
+    #: dispatch of the first step that carried a chunk of this request
+    first_dispatch_ts: Optional[float] = None
+    #: steps that carried it, and token positions they computed for it, before
+    #: its first token: fewer than the prompt after a prefix-cache hit, more
+    #: after a preemption
+    prefill_steps: int = 0
+    prefill_tokens: int = 0
+    #: ``(t0, t1)`` of every step that carried a chunk of it while in PREFILL:
+    #: telemetry keeps them ``phase/prefill`` and carves the rest of the
+    #: PREFILL interval into ``phase/prefill_bypassed`` and ``phase/prefill_wait``
+    carry_windows: List[Tuple[float, float]] = dataclasses.field(default_factory=list)
+    #: ``(t0, t1)`` of the steps that ran while it was in PREFILL and carried
+    #: none of it; kept only for a request that is traced (the spans need
+    #: where, the row only how long: ``run_mark`` and ``ran_s``)
+    bypass_windows: List[Tuple[float, float]] = dataclasses.field(default_factory=list)
+    #: the frontend's running sum of step seconds when this stretch of PREFILL
+    #: began (admission, the tower's last image, an aborted migration), and
+    #: the step seconds of the stretches of PREFILL that ended before the
+    #: first token (a preemption, a migration): what ran, carried or not
+    run_mark: float = 0.0
+    ran_s: float = 0.0
+    #: the row ``StepAnatomy.first_tokens`` got when the first token was
+    #: delivered (``telemetry.spans.first_token_row``); None before, and
+    #: where neither a step recorder nor a metrics registry would read it
+    ttft_row: Optional[dict] = None
 
     def __post_init__(self):
         self.prompt = list(self.prompt)

@@ -82,6 +82,12 @@ EVENTS = {
                        "time per output token, DONE requests"),
     "serving/queue_wait_s": ("histogram", "serving/engine.py",
                              "admission-queue wait, DONE requests"),
+    "serving/ttft_carried_s": ("histogram", "serving/engine.py",
+                               "the part of the TTFT in which a step that carried the request ran, DONE requests"),
+    "serving/ttft_bypassed_s": ("histogram", "serving/engine.py",
+                                "the part of the TTFT in prefill in which a step ran and carried none of it, DONE requests"),
+    "serving/ttft_wait_s": ("histogram", "serving/engine.py",
+                            "the part of the TTFT in prefill in which no step of the engine ran, DONE requests"),
     # ---- speculative decoding (serving/engine.py folding
     #      inference/v2/engine_v2.py last_spec_round)
     "spec/proposed": ("counter", "serving/engine.py",

@@ -7,12 +7,22 @@ path.  Instead, when a request (or a failed-over replica attempt) ends,
 its history is folded into contiguous **phase spans** here:
 
     queued    — QUEUED (admission queue, preemption requeue, backoff)
-    prefill   — PREFILL (prompt + recompute-on-resume KV build)
+    prefill   — the part of PREFILL in which a step that carried a chunk of
+                the request was running (``ServingRequest.carry_windows``:
+                prompt + recompute-on-resume KV build); all of PREFILL for
+                a request nobody noted steps for
     decode    — DECODE
     migrating — MIGRATING (paused for chunked KV export — the per-request
                 migration cost of disaggregated serving)
     vision_encode — the part of PREFILL before the request's images were
                 through the vision tower (``ServingRequest.encode_windows``)
+    prefill_bypassed — the part of PREFILL in which a step of the engine ran
+                and carried no chunk of the request (the token budget went
+                to another prompt, a step was in flight when it was
+                admitted; ``ServingRequest.bypass_windows``)
+    prefill_wait — the part of PREFILL in which no step ran: admission and
+                planning before a dispatch, delivery after it, the caller's
+                loop between ticks
     pending   — fleet-level router queue time (before dispatch, between
                 failover displacement and re-dispatch)
 
@@ -31,7 +41,12 @@ from typing import List, Optional, Tuple
 from ..serving.request import RequestState, ServingRequest
 from .trace import Span, Tracer
 
-__all__ = ["PHASE_OF_STATE", "phase_intervals", "emit_attempt_spans"]
+__all__ = ["PHASE_OF_STATE", "FIRST_TOKEN_PARTS", "phase_intervals", "attempt_intervals", "emit_attempt_spans",
+           "first_token_row"]
+
+#: the parts of a first token's time, in the row's order: they are
+#: non-negative and sum to ``first_token_ts - arrival_ts``
+FIRST_TOKEN_PARTS = ("late_s", "queued_s", "carried_s", "bypassed_s", "vision_encode_s", "wait_s", "other_s")
 
 # RequestState -> phase name; EVICTED is transient (the requeue lands at
 # the same timestamp) but named so a non-zero-length eviction window —
@@ -145,6 +160,75 @@ def phase_intervals(history: List[Tuple[RequestState, float]],
     return out
 
 
+#: once the carried pieces of PREFILL have a name of their own, what is left
+#: of it is the wait, and the carried pieces take the phase's name
+_AFTER_CARRY = {"prefill": "prefill_wait", "prefill_carried": "prefill"}
+
+
+def attempt_intervals(req: ServingRequest, end_ts: Optional[float] = None,
+                      clamp_start: Optional[float] = None,
+                      tail_phase: Optional[str] = None) -> List[Tuple[str, float, float]]:
+    """One serving attempt's ``(phase, t0, t1)`` pieces: the state history
+    folded (:func:`phase_intervals`) and carved by the windows the request
+    carries: ``promote`` out of the queue, ``vision_encode`` out of PREFILL,
+    then what is left of PREFILL by the steps that ran in it: ``prefill``
+    stays the name of the pieces under ``carry_windows``, ``prefill_bypassed``
+    are those under ``bypass_windows`` and ``prefill_wait`` is the rest.  A
+    request nobody noted steps for (no ``carry_windows`` attribute) keeps
+    its PREFILL in one ``prefill`` piece."""
+    intervals = phase_intervals(req.history, end_ts=end_ts,
+                                clamp_start=clamp_start,
+                                tail_phase=tail_phase,
+                                park_phase=getattr(req, "park_phase",
+                                                   "parked"))
+    intervals = _carve(intervals, getattr(req, "promote_windows", None) or [])
+    intervals = _carve(intervals, getattr(req, "encode_windows", None) or [], "vision_encode", ("prefill", ))
+    carry = getattr(req, "carry_windows", None)
+    if carry is None:
+        return intervals
+    intervals = _carve(intervals, carry, "prefill_carried", ("prefill", ))
+    intervals = _carve(intervals, getattr(req, "bypass_windows", None) or [], "prefill_bypassed", ("prefill", ))
+    return [(_AFTER_CARRY.get(phase, phase), t0, t1) for phase, t0, t1 in intervals]
+
+
+def first_token_row(req: ServingRequest, ran_s: float) -> dict:
+    """A request's way to its first token as one row (``StepAnatomy.
+    first_tokens``; docs/OBSERVABILITY.md "The way to a first token"): the
+    pieces of :func:`attempt_intervals` up to ``first_token_ts`` summed into
+    ``FIRST_TOKEN_PARTS``.  The queue splits at ``submit_ts`` into ``late_s``
+    (the caller held the request) and ``queued_s``; ``ran_s``, the step
+    seconds inside the request's PREFILL as the frontend's running sum has
+    them, splits what no carrying step covered into ``bypassed_s`` (a step
+    ran) and ``wait_s`` (none did), so the row needs no list of the steps
+    that passed the request by.  Raw clock differences: ``StepAnatomy.
+    to_doc`` rounds them."""
+    first = req.first_token_ts
+    submit = req.submit_ts if req.submit_ts is not None else req.arrival_ts
+    parts = dict.fromkeys(FIRST_TOKEN_PARTS, 0.0)
+    uncarried = 0.0
+    for phase, t0, t1 in attempt_intervals(req, end_ts=first):   # the history ends here: no piece lies behind
+        if phase == "queued":
+            late = max(0.0, min(t1, submit) - t0)
+            parts["late_s"] += late
+            parts["queued_s"] += (t1 - t0) - late
+        elif phase == "prefill":
+            parts["carried_s"] += t1 - t0
+        elif phase == "vision_encode":
+            parts["vision_encode_s"] += t1 - t0
+        elif phase in ("prefill_wait", "prefill_bypassed"):
+            uncarried += t1 - t0
+        else:
+            parts["other_s"] += t1 - t0
+    parts["bypassed_s"] = min(max(ran_s - parts["carried_s"], 0.0), uncarried)
+    parts["wait_s"] = uncarried - parts["bypassed_s"]
+    carried = req.carry_windows[-1][1] if req.carry_windows else None
+    return {"uid": req.uid, "arrival_ts": req.arrival_ts, "submit_ts": submit, "admitted_ts": req.admitted_ts,
+            "first_dispatch_ts": req.first_dispatch_ts, "last_carried_ts": carried, "first_token_ts": first,
+            "ttft_s": first - req.arrival_ts, **parts,
+            "prompt_tokens": len(req.prompt), "prefill_tokens": req.prefill_tokens,
+            "prefill_steps": req.prefill_steps, "preemptions": req.preemptions}
+
+
 def emit_attempt_spans(tracer: Tracer, req: ServingRequest, trace_id: int,
                        parent_id: Optional[int], track: str,
                        end_ts: Optional[float] = None,
@@ -156,14 +240,7 @@ def emit_attempt_spans(tracer: Tracer, req: ServingRequest, trace_id: int,
     attempt a replica death (or lease expiry — ``tail_phase="fenced"``)
     displaced."""
     spans = []
-    intervals = phase_intervals(req.history, end_ts=end_ts,
-                                clamp_start=clamp_start,
-                                tail_phase=tail_phase,
-                                park_phase=getattr(req, "park_phase",
-                                                   "parked"))
-    intervals = _carve(intervals, getattr(req, "promote_windows", None) or [])
-    intervals = _carve(intervals, getattr(req, "encode_windows", None) or [], "vision_encode", ("prefill", ))
-    for phase, t0, t1 in intervals:
+    for phase, t0, t1 in attempt_intervals(req, end_ts=end_ts, clamp_start=clamp_start, tail_phase=tail_phase):
         spans.append(tracer.add_span(f"phase/{phase}", trace_id, t0, t1,
                                      parent_id=parent_id, track=track))
     return spans

@@ -120,6 +120,18 @@ the readback, the two largest host segments, ``cpu_s``, ``gc_s`` and what
 the step carried.  ``host_gap_s`` is printed and left out of the rule: a
 caller's idle waits lie there too.
 
+A request's **way to its first token** rides along: the serving frontend
+notes a step for every prefilling sequence it carried and folds them, when
+the first token is delivered, into one row of ``first_tokens`` (a ring of
+4,096 beside ``steps`` and ``encodes``; :meth:`StepAnatomy.note_first_token`):
+the request's timestamps and counts and the parts of its TTFT
+(``telemetry.spans.FIRST_TOKEN_PARTS``: the caller held it, it queued, a step
+that carried it ran, a step that passed it by ran, the vision tower, no step
+ran), which sum to it.  With a factory the row is also the instant
+``ds.first_token`` of the profile, and a step's ``ds.step`` range names the
+prefilling requests it carried (``prefill_uids``), so one request's steps can
+be followed in a trace of the chip.
+
 Every recorder is reachable in its process: :func:`recorders` gives the
 live ones, weakly held, oldest first (what the benchmark's readers of the
 step records call, and what a server's debug endpoint would).
@@ -173,6 +185,8 @@ _MARK_NAMES = {s: "ds.mark." + s for s in HOST_SEGMENTS + ("device_wait", )}
 SLOW_HISTORY, SLOW_MIN_STEPS = 64, 16
 SLOW_FACTOR, SLOW_OVER_S = 4.0, 0.25
 SLOW_RING = 256
+#: rows of ``first_tokens``: a request each, whatever ``max_steps`` is
+FIRST_TOKEN_RING = 4096
 SLOW_LOG_EVERY_S = 1.0
 
 _RECORDERS: List[weakref.ref] = []
@@ -208,7 +222,7 @@ class StepRecord:
     """One recorded engine step (mutable only via the recorder)."""
 
     __slots__ = ("index", "key", "path", "segments", "device_s", "host_gap_s",
-                 "wall_s", "after_idle", "compiles", "end_ts", "cpu_s", "gc_s") + COUNTS
+                 "wall_s", "after_idle", "compiles", "end_ts", "cpu_s", "gc_s", "prefill_uids") + COUNTS
 
     def __init__(self, index: int):
         self.index = index
@@ -223,6 +237,7 @@ class StepRecord:
         self.end_ts = 0.0                    # recorder-clock time at step end
         self.cpu_s = 0.0                     # real clock: CPU the stepping thread burned in the step
         self.gc_s = 0.0                      # real clock: seconds inside Python's collector in the step
+        self.prefill_uids: tuple = ()        # the prefilling requests it carried (the range's metadata, no column)
         self.rows_decode = self.rows_prefill = self.seqs_prefill = 0
         self.tokens_real = self.slots = 0
         self.tokens_out = self.tokens_discarded = 0
@@ -311,6 +326,9 @@ class StepAnatomy:
         #: one row a dispatch of a vision tower's program: ``ts`` (this clock),
         #: ``key`` (``vit:p4096``) and ``ENCODE_COUNTS``
         self.encodes = deque(maxlen=int(max_steps))
+        #: one row a request that reached its first token under a serving
+        #: frontend (``telemetry.spans.first_token_row``), raw clock differences
+        self.first_tokens = deque(maxlen=FIRST_TOKEN_RING)
         #: monotonic count of CLOSED steps (deque eviction never rewinds it)
         self.total_steps = 0
         # lifetime totals (survive deque eviction; the cheap gauge inputs)
@@ -427,6 +445,22 @@ class StepAnatomy:
                              "vit_patches_real": int(patches_real), "vit_patches_padded": int(patches_padded),
                              "vit_pairs": int(patches_real)**2, "vit_reencoded": int(bool(reencoded))})
 
+    def note_prefill_uids(self, uids: tuple) -> None:
+        """The prefilling requests the open step carries, as the serving
+        frontend knows them: ``prefill_uids`` of the ``ds.step`` range."""
+        if self._cur is not None:
+            self._cur.prefill_uids = uids
+
+    def note_first_token(self, row: dict) -> None:
+        """A request's first token was delivered: ``row`` (``telemetry.spans.
+        first_token_row``) joins ``first_tokens``, and with a factory it is
+        the instant ``ds.first_token`` of the profile, with the request and
+        the parts of its TTFT as metadata."""
+        self.first_tokens.append(row)
+        if self._annotate is not None:
+            with self._annotate("ds.first_token") as instant:
+                instant.set_metadata(**{k: v for k, v in row.items() if k == "uid" or k.endswith("_s")})
+
     def note_counts(self, expert_rows: int = 0, expert_rows_kernel: int = 0,
                     cache_counts: tuple = (0, 0), state_counts: Optional[dict] = None) -> None:
         """What the packed batch carries beyond its rows, noted once the
@@ -507,6 +541,8 @@ class StepAnatomy:
         if rng is not None:
             if cur.path is not None:
                 rng.set_metadata(index=cur.index, key=cur.key, **cur.counts())
+                if cur.prefill_uids:   # "+" between them: a comma ends a value of the profile's metadata
+                    rng.set_metadata(prefill_uids="+".join(map(str, cur.prefill_uids)))
             rng.__exit__(None, None, None)
         if cur.path is None:
             # planned-but-empty step: keep the gap origin where it was so
@@ -597,6 +633,7 @@ class StepAnatomy:
         record, they are what 'steady state' is defined against)."""
         self.steps.clear()
         self.slow_steps.clear()
+        self.first_tokens.clear()
         self._own_by_key.clear()
         self.dropped_steps = 0
         self.total_steps = 0
@@ -701,13 +738,17 @@ class StepAnatomy:
         per-step table, compile log, per-program fold, summary.  Pure
         data, 9-dp rounding, sorted keys downstream.  Schema 3 = the
         program key as a step's identity, the counts, and the ``admit``
-        and ``deliver`` segments."""
+        and ``deliver`` segments; ``first_tokens`` (the requests' ways to
+        their first tokens, in the rows' own key order) came later and
+        changed no other key."""
         return {
             "schema": 3,
             "summary": self.summary(),
             "by_shape": self.by_shape(),
             "steps": [rec.to_row() for rec in self.steps],
             "compiles": [c.to_row() for c in self.compiles],
+            "first_tokens": [{k: round(v, 9) if isinstance(v, float) else v for k, v in row.items()}
+                             for row in self.first_tokens],
         }
 
 
@@ -719,6 +760,7 @@ class NullStepAnatomy:
     enabled = False
     steps: tuple = ()
     compiles: tuple = ()
+    first_tokens: tuple = ()
     dropped_steps = 0
     total_steps = 0
     steady_state_recompiles = 0
@@ -737,6 +779,12 @@ class NullStepAnatomy:
         pass
 
     def note_encode(self, key, patches_real, patches_padded, reencoded=False) -> None:
+        pass
+
+    def note_prefill_uids(self, uids) -> None:
+        pass
+
+    def note_first_token(self, row) -> None:
         pass
 
     def note_counts(self, expert_rows=0, expert_rows_kernel=0, cache_counts=(0, 0), state_counts=None) -> None:
@@ -778,7 +826,7 @@ class NullStepAnatomy:
 
     def to_doc(self) -> dict:
         return {"schema": 3, "summary": {}, "by_shape": {}, "steps": [],
-                "compiles": []}
+                "compiles": [], "first_tokens": []}
 
 
 NULL_ANATOMY = NullStepAnatomy()

@@ -55,10 +55,17 @@ from deepspeed_tpu.serving.metrics import percentile_summary  # noqa: E402
 #: result; ``think_time`` is the session-level between-turn gap (only in
 #: session-root traces, which fold() skips — named for completeness);
 #: ``vision_encode`` is the part of a request's prefill before its images
-#: were through the vision tower (telemetry/spans.py carves it out of prefill)
+#: were through the vision tower (telemetry/spans.py carves it out of prefill);
+#: ``prefill`` is the part of PREFILL in which a step that carried the request
+#: ran, ``prefill_bypassed`` the part in which a step ran and carried none of
+#: it, ``prefill_wait`` the part in which no step ran (carved by the windows
+#: the serving frontend notes a step)
 PHASES = ("pending", "queued", "prefill", "decode", "migrating", "evicted",
           "fenced", "host_gap", "compile_wait", "parked", "tool_stall",
-          "think_time", "promote", "vision_encode")
+          "think_time", "promote", "vision_encode", "prefill_bypassed",
+          "prefill_wait")
+#: the phases a request is in while its state is PREFILL
+_PREFILLING = ("prefill", "prefill_bypassed", "prefill_wait", "vision_encode")
 _US = 1e6
 
 
@@ -91,7 +98,7 @@ def fold(doc: dict, tol: float = 1e-6) -> dict:
         for segs in by_parent.values():
             segs.sort()
             for prev, cur in zip(segs, segs[1:]):
-                if cur[1] == "queued" and prev[1] in ("prefill", "decode"):
+                if cur[1] == "queued" and prev[1] in _PREFILLING + ("decode", ):
                     preemptions += 1
         attempts = [e for e in evs if e["name"] == "attempt"]
         span_sum = sum(phases.values())

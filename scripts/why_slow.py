@@ -16,7 +16,14 @@ named-cause breakdown of its end-to-end latency:
                      flash crowd) — the trace's ``otherData`` carries
                      ``degradation_t0``/``degradation_t1`` (the exporter's
                      ``meta``; ``--window t0:t1`` overrides)
-    prefill          prompt processing (incl. recompute-on-resume)
+    prefill          prompt processing (incl. recompute-on-resume): a step
+                     that carried a chunk of the request was running
+    prefill_bypassed a step ran and carried none of it: the token budget
+                     went to another prompt, a dispatch was in flight when
+                     it was admitted (``phase/prefill_bypassed``)
+    prefill_wait     admitted, and no step of the engine running: planning
+                     before a dispatch, delivery after it, the caller's loop
+                     between ticks (``phase/prefill_wait``)
     decode           token generation
     migration_pause  paused for chunked KV export (``phase/migrating``)
     lease_expiry     re-home wait after a lease-expiry/fencing
@@ -66,7 +73,8 @@ _US = 1e6
 #: pauses — named slowdowns, not baseline compute
 CAUSES = ("queue_wait", "partition_delay", "prefill", "decode",
           "migration_pause", "lease_expiry", "fenced", "eviction",
-          "host_gap", "compile_wait", "parked", "tool_stall", "promote")
+          "host_gap", "compile_wait", "parked", "tool_stall", "promote",
+          "prefill_bypassed", "prefill_wait")
 
 #: causes that are NOT baseline compute — the named slowdowns the tail
 #: receipt attributes the p99-p50 gap to.  ``parked`` is deliberate idle
@@ -75,10 +83,13 @@ CAUSES = ("queue_wait", "partition_delay", "prefill", "decode",
 #: tool result (serving/sessions — the agent's latency, parked through
 #: the same host tier), and ``promote`` is the h2d transfer a resume
 #: could not hide — the receipt separates resume-TTFT paid to the tier
-#: from recompute it avoided
+#: from recompute it avoided.  ``prefill_bypassed`` and ``prefill_wait``
+#: are the parts of a prompt's processing in which no step worked on it: a
+#: prompt that waited behind another is not baseline compute
 SLOWDOWN_CAUSES = ("queue_wait", "partition_delay", "migration_pause",
                    "lease_expiry", "fenced", "eviction", "host_gap",
-                   "compile_wait", "parked", "tool_stall", "promote")
+                   "compile_wait", "parked", "tool_stall", "promote",
+                   "prefill_bypassed", "prefill_wait")
 
 #: phase -> cause for the phases that map 1:1
 _DIRECT = {"prefill": "prefill", "decode": "decode",
@@ -86,6 +97,8 @@ _DIRECT = {"prefill": "prefill", "decode": "decode",
            "evicted": "eviction", "host_gap": "host_gap",
            "compile_wait": "compile_wait", "parked": "parked",
            "tool_stall": "tool_stall", "promote": "promote",
+           "prefill_bypassed": "prefill_bypassed",
+           "prefill_wait": "prefill_wait",
            # the wait for the vision tower is the prompt's processing: prefill
            "vision_encode": "prefill"}
 

@@ -217,15 +217,19 @@ def test_compile_tracker_warmup_vs_steady_and_reset():
 
 
 def test_null_anatomy_allocates_nothing():
+    row, uids = {"uid": 0, "ttft_s": 1.0}, (0, )
+
     def loop(n):
         for _ in range(n):
             NULL_ANATOMY.step_begin()
             NULL_ANATOMY.mark("schedule")
             NULL_ANATOMY.note_program("step:b4:c1", "decode")
+            NULL_ANATOMY.note_prefill_uids(uids)
             NULL_ANATOMY.device_mark()
             NULL_ANATOMY.note_compile("k")
             NULL_ANATOMY.step_end()
             NULL_ANATOMY.charge_last_step(1.0)
+            NULL_ANATOMY.note_first_token(row)
 
     loop(10)
     tracemalloc.start()
@@ -240,7 +244,8 @@ def test_null_anatomy_allocates_nothing():
               if d.size_diff > 0 and any(pkg in (f.filename or "")
                                          for f in d.traceback)]
     assert sum(d.size_diff for d in allocs) < 8192, allocs
-    assert NULL_ANATOMY.to_doc()["steps"] == []
+    assert NULL_ANATOMY.to_doc()["steps"] == [] and NULL_ANATOMY.to_doc()["first_tokens"] == []
+    assert NULL_ANATOMY.first_tokens == ()
 
 
 # ------------------------------------------------- report CLI + sabotage
