@@ -173,17 +173,24 @@ class SlotPagesGeometry(LinearGeometry):
     layout alone reads as a valid one.  The slot is allocated with the
     sequence and released with it (``ragged.StateManager``).
     ``state_bytes``: what one sequence's recurrent states take, every layer,
-    where the step records are to count the bytes a step moves of them."""
+    where the step records are to count the bytes a step moves of them.
+    ``run_tokens``: with a window whose rings the twin sizes for it, the most
+    tokens one sequence may feed in a step (``chunk_limit``); ``ring_rows``:
+    the rows one window layer's ring holds in a slot, where the step records
+    are to count them (``models/trinity_cache.py`` gives both)."""
 
     #: the pages never change, but a slot's state belongs to one sequence
     #: and is not kept by position: nothing of it can be shared or rewound to
     pages_immutable = False
     state_slots = True
 
-    def __init__(self, page_size: int, window: int = None, state_bytes: int = 0, chunk_runs: bool = False):
+    def __init__(self, page_size: int, window: int = None, state_bytes: int = 0, chunk_runs: bool = False,
+                 run_tokens: int = None, ring_rows: int = 0):
         super().__init__(page_size)
         self.window = None if window is None else int(window)
         self.state_bytes = int(state_bytes)
+        self.run_tokens = None if run_tokens is None else int(run_tokens)
+        self.ring_rows = int(ring_rows)
         #: a row's scan and convolution start from the slot's state and leave
         #: theirs there: two rows of one sequence in a step would start from
         #: the same state, and the second's would be the one kept.  True where
@@ -193,7 +200,11 @@ class SlotPagesGeometry(LinearGeometry):
         #: ends is a *continuing row*: it starts from the state and the
         #: convolution's inputs that row leaves, not from the slot's, and the
         #: slot receives what the last row of the run leaves, once
-        #: (``models/solar_open2_cache.continuing_rows``)
+        #: (``models/solar_open2_cache.continuing_rows``).  A ring needs no
+        #: handing on: a step's rows all write their ring rows before any
+        #: attends, so a run is sound as far as the ring's slack behind its
+        #: window goes (``run_tokens``): a row further on would overwrite
+        #: rows an earlier row of the run still sees
         self.chunk_runs = bool(chunk_runs)
 
     def token_capacity(self, max_tokens: int) -> int:
@@ -203,16 +214,29 @@ class SlotPagesGeometry(LinearGeometry):
         """A recurrent state cannot be rewound at all."""
         return int(seen_tokens)
 
+    def chunk_limit(self, start: int, n_tokens: int) -> int:
+        """A chunk, and a run of chunks, ends where the rings' slack ends."""
+        return n_tokens if self.run_tokens is None else min(n_tokens, self.run_tokens)
+
     def state_counts(self, start: int, n_tokens: int, calls: int = 1) -> dict:
         """``ssm_rows``: token rows the recurrence advanced; with a window,
         ``window_rows_visible``: key rows a window layer's queries could see,
         ``min(t + 1, window)`` summed over them (one layer each); with
         ``state_bytes``, ``ssd_state_bytes``: the row's states read and
-        written once in each of the ``calls`` calls its tokens go through."""
+        written once in each of the ``calls`` calls its tokens go through;
+        with ``ring_rows``, a call each: ``ring_rows_held``, the rows the
+        sequence's ring holds (one layer's), ``ring_rows_seen``, those of
+        them the call's last query can see, and ``full_rows_seen``, the rows
+        it sees in a layer with pages (what a call reads of each at the least)."""
         counts = {"ssm_rows": int(n_tokens)}
         if self.window is not None:
             t = np.arange(start, start + n_tokens)
             counts["window_rows_visible"] = int(np.minimum(t + 1, self.window).sum())
+            if self.ring_rows and n_tokens:
+                last = t[np.minimum((np.arange(calls) + 1) * -(-int(n_tokens) // calls), int(n_tokens)) - 1]
+                counts["ring_rows_held"] = self.ring_rows * int(calls)
+                counts["ring_rows_seen"] = int(np.minimum(last + 1, self.window).sum())
+                counts["full_rows_seen"] = int((last + 1).sum())
         if self.state_bytes:
             counts["ssd_state_bytes"] = 2 * self.state_bytes * int(calls)
         return counts

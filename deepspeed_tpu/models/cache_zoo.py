@@ -48,6 +48,9 @@ from .phi4flash import Phi4FlashConfig
 from .phi4flash_cache import Phi4FlashForCausalLMWithCache
 from .phi4flash_cache import init_cache as init_phi4flash_cache
 from .qwen2_moe import Qwen2MoeConfig, Qwen2MoeDenseMLP, Qwen2MoeSparseMLP
+from .trinity import TrinityConfig
+from .trinity_cache import TrinityForCausalLMWithCache
+from .trinity_cache import geometry as trinity_geometry, init_cache as init_trinity_cache
 from .xing4 import Xing4Config
 from .xing4_cache import LatentPagesGeometry, Xing4ForCausalLMWithCache
 from .xing4_cache import init_cache as init_xing4_cache, walk_rows as xing4_walk_rows
@@ -438,6 +441,9 @@ CACHE_MODEL_REGISTRY = {
     # the sparse layers read their pages through a list walk of their own, which no contiguous walk's count fits
     MiniCPMSALAConfig: CacheTwin(MiniCPMSALAForCausalLMWithCache, SparseSlotPagesGeometry, init_minicpm_sala_cache,
                                  lambda cache: cache["pages"], walk_rows=lambda page_size, table_width: 0),
+    # window layers in rings in the slot beside full layers in pages; a ring needs no handing on: runs
+    TrinityConfig: CacheTwin(TrinityForCausalLMWithCache, trinity_geometry, init_trinity_cache,
+                             lambda cache: cache["pages"]),
     Xing4Config: CacheTwin(Xing4ForCausalLMWithCache, lambda cfg, page_size: LatentPagesGeometry(page_size),
                            init_xing4_cache, walk_rows=xing4_walk_rows),
     # the same latent pages under the same kernel; a subclass of Xing4Config, found by its own type first

@@ -172,7 +172,8 @@ HOST_SEGMENTS = ("admit", "schedule", "draft_plan", "verify_plan",
 COUNTS = ("rows_decode", "rows_prefill", "seqs_prefill", "tokens_real", "slots", "tokens_out",
           "tokens_discarded", "expert_rows", "expert_rows_kernel", "attn_rows_visible", "attn_rows_walked",
           "ssm_rows", "window_rows_visible", "ssd_state_bytes", "mla_rows_read", "mm_tokens",
-          "sparse_decode_rows_read", "lightning_state_bytes")
+          "sparse_decode_rows_read", "lightning_state_bytes", "ring_rows_held", "ring_rows_seen",
+          "full_rows_seen")
 
 #: what an encode dispatch of a vision tower carried (``StepAnatomy.encodes``, beside the step records)
 ENCODE_COUNTS = ("vit_images", "vit_patches_real", "vit_patches_padded", "vit_pairs", "vit_reencoded")
@@ -245,6 +246,7 @@ class StepRecord:
         self.attn_rows_visible = self.attn_rows_walked = 0
         self.ssm_rows = self.window_rows_visible = self.ssd_state_bytes = self.mla_rows_read = self.mm_tokens = 0
         self.sparse_decode_rows_read = self.lightning_state_bytes = 0
+        self.ring_rows_held = self.ring_rows_seen = self.full_rows_seen = 0
 
     def host_s(self) -> float:
         return sum(self.segments.values())

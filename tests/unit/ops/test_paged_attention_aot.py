@@ -330,3 +330,15 @@ def test_minicpm_salas_kernels_at_the_cells_shapes(one_chip, no_compile_cache, k
     lowered = jax.jit(call).lower(*args)
     assert name in lowered.as_text()
     assert "tpu_custom_call" in lowered.compile().as_text()
+
+
+@pytest.mark.parametrize("rows, chunk", [(32, 1), (4, 256)], ids=["decode_bucket", "a_run_of_four"])
+@pytest.mark.parametrize("kind", ["window", "full"])
+def test_whole_tile_heads_under_a_window_of_4096(one_chip, no_compile_cache, kind, rows, chunk):
+    """The two calls of ``models/trinity_cache.py`` at the shapes of
+    ``trinity_mixed_queue``: 48 query heads over 8 key heads of 128; a window
+    layer over its ring's view of 321 pages out of an arena of four layers
+    with the window's bound, 4,096 rows; the full layer over a table of 2,081
+    pages out of an arena of one with none."""
+    width, layers, bounds = (321, 4, {"window": 4096}) if kind == "window" else (2081, 1, {})
+    assert "tpu_custom_call" in _compile(one_chip, chunk, 48, 8, width, layers=layers, batch=rows, **bounds)

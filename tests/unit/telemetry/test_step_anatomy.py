@@ -420,7 +420,8 @@ def test_annotate_factory_sees_step_and_marks_nested_with_counts():
                           "attn_rows_visible": 0, "attn_rows_walked": 0,
                           "ssm_rows": 0, "window_rows_visible": 0, "ssd_state_bytes": 0,
                           "mla_rows_read": 0, "mm_tokens": 0,
-                          "sparse_decode_rows_read": 0, "lightning_state_bytes": 0}
+                          "sparse_decode_rows_read": 0, "lightning_state_bytes": 0,
+                          "ring_rows_held": 0, "ring_rows_seen": 0, "full_rows_seen": 0}
     # the counts ride the row and the per-program fold too
     row = anat.last_step.to_row()
     assert (row["tokens_real"], row["slots"], row["tokens_out"],
@@ -711,3 +712,35 @@ def test_pallas_kernels_carry_ds_names():
     text = str(jax.make_jaxpr(lambda q, p: paged_attention_pallas(
         q, p, bt, sp, cl, 8, layer=0, interpret=True))(qd, pages))
     assert "ds_paged_attention" in text
+
+
+def test_the_rings_counts_ride_the_step_record_by_name():
+    """What a geometry with rings counts (``SlotPagesGeometry(ring_rows=)``:
+    the rows a window layer's ring holds a call, those of them and of a layer
+    with pages the call's last query sees) reaches the record, its row and the
+    per-program fold by name, as every named count does; a step that notes
+    none reads 0."""
+    from deepspeed_tpu.inference.v2.geometry import SlotPagesGeometry
+    from deepspeed_tpu.telemetry.step_anatomy import COUNTS
+    assert {"ring_rows_held", "ring_rows_seen", "full_rows_seen"} <= set(COUNTS)
+    geometry = SlotPagesGeometry(16, window=4096, chunk_runs=True, run_tokens=1024, ring_rows=5136)
+    clock = VirtualClock()
+    anat = StepAnatomy(clock=clock)
+    for start, n, calls in ((8192, 128, 1), (100, 8, 8)):      # a chunk past two windows; eight fused rounds at 100
+        anat.step_begin()
+        key, path = ("step:b32:c1:b1:c128", "mixed") if calls == 1 else ("multi:b32:k8", "multi_decode")
+        anat.note_program(key, path, rows_decode=int(calls > 1), rows_prefill=int(calls == 1), tokens_real=n, slots=n)
+        anat.note_counts(state_counts=geometry.state_counts(start, n, calls))
+        clock.advance(0.01)
+        anat.step_end()
+    chunk, fused = (r.to_row() for r in anat.steps)
+    assert (chunk["ring_rows_held"], chunk["ring_rows_seen"], chunk["full_rows_seen"]) == (5136, 4096, 8320)
+    assert chunk["window_rows_visible"] == 128 * 4096
+    assert (fused["ring_rows_held"], fused["ring_rows_seen"]) == (8 * 5136, sum(range(101, 109)))
+    assert fused["full_rows_seen"] == fused["ring_rows_seen"] == fused["window_rows_visible"]
+    assert anat.by_shape()["multi:b32:k8"]["ring_rows_held"] == 8 * 5136
+    anat.step_begin()
+    anat.note_program("step:b32:c1", "decode", rows_decode=1, tokens_real=1, slots=32)
+    clock.advance(0.01)
+    anat.step_end()
+    assert anat.last_step.ring_rows_held == 0 and anat.last_step.to_row()["full_rows_seen"] == 0
