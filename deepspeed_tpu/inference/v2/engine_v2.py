@@ -33,7 +33,7 @@ from ...models.llama_cache import PagedKVConfig, reads_through_kernel, stack_lay
 from ...comm.mesh import trace_mesh
 from ...moe import sharded_moe
 from ...ops.grouped_matmul import takes_kernel
-from ...ops.paged_attention import walk_block
+from ...ops.paged_attention import takes_decode_form, walk_block
 from ...telemetry.step_anatomy import NULL_ANATOMY, StepAnatomy
 from ...utils.logging import logger
 from ...utils.nvtx import profiler_range
@@ -1143,9 +1143,11 @@ class InferenceEngineV2:
                                         jnp.asarray(rb.chunk_lens), sub)
         if anat.enabled:
             # the passes over the rows run with the program already enqueued
+            groups = [([(s, k) for s in seqs], batch, 1)]
             anat.note_counts(**self._expert_rows(len(seqs) * k, batch, live=len(seqs)),
-                             cache_counts=self._cache_counts([(s, k) for s in seqs], calls=k),
-                             state_counts=self._state_counts([(s, k) for s in seqs], calls=k))
+                             cache_counts=self._cache_counts(groups, calls=k),
+                             state_counts={**self._state_counts(groups[0][0], calls=k),
+                                           **self._decode_form_counts(groups, calls=k)})
             anat.mark("compile_wait" if self._fresh_compile else "dispatch")
         inf = InFlightStep("multi")
         inf.tokens = toks
@@ -1194,23 +1196,36 @@ class InferenceEngineV2:
         from ...models.cache_zoo import cache_twin
         return cache_twin(self.cfg).pages(self.cache)
 
-    def _walk_rows(self) -> int:
+    def _walk_rows(self, width: int = 0) -> int:
         """Key rows a block of the paged kernel's walk holds, as the kernel
         chooses it for this engine's pages (a tensor-parallel shard's key
-        heads); 0 where the twin's attention does not read through it."""
-        cfg = self.cfg
-        if not reads_through_kernel(getattr(cfg, "attention_impl", None), getattr(cfg, "alibi", False)):
+        heads) and a group of rows ``width`` tokens wide (1: the granule of
+        the kernel's decode form); 0 where the twin's attention does not read
+        through it."""
+        shape = self._kernel_page_shape()
+        if shape is None:
             return 0
-        from ...models.cache_zoo import cache_twin
-        own_walk = cache_twin(cfg).walk_rows
+        own_walk = self._own_walk()
         if own_walk is not None:  # a kernel of the twin's own over pages of another shape
             return own_walk(self.kv.page_size, self.kv.table_width)
+        return self.kv.page_size * walk_block(self.kv.page_size, self.kv.table_width, *shape, chunk=width)
+
+    def _own_walk(self):
+        from ...models.cache_zoo import cache_twin
+        return cache_twin(self.cfg).walk_rows
+
+    def _kernel_page_shape(self):
+        """(key heads of a tensor-parallel shard, lanes, bytes an element) of
+        the pages the paged kernel reads; None where the twin's attention does
+        not read through a kernel."""
+        cfg = self.cfg
+        if not reads_through_kernel(getattr(cfg, "attention_impl", None), getattr(cfg, "alibi", False)):
+            return None
         from ...comm.mesh import TENSOR_AXIS
         pages = self._pages()
         *_, n_kv, d = pages.shape
         tp = 1 if self.mesh is None else self.mesh.shape.get(TENSOR_AXIS, 1)
-        return self.kv.page_size * walk_block(self.kv.page_size, self.kv.table_width, n_kv // tp, d,
-                                              pages.dtype.itemsize)
+        return n_kv // tp, d, pages.dtype.itemsize
 
     def _kernel_rows(self, work, calls: int = 1):
         """(first position, tokens) of each row a step's (seq, tokens) work
@@ -1222,15 +1237,40 @@ class InferenceEngineV2:
         chunk = self.econfig.scheduler.prefill_chunk
         return [(s.seen_tokens + at, min(chunk, n - at)) for s, n in work for at in range(0, max(n, 1), chunk)]
 
-    def _cache_counts(self, work, calls: int = 1) -> tuple:
-        """The geometry's ``step_counts`` summed over the rows of a step's
-        (seq, tokens) work (``_kernel_rows``), each row's tokens going through
-        the paged kernel in ``calls`` calls, by blocks of the rows its walk
-        takes at a step (``walked`` is 0 where no kernel walks)."""
-        block_rows = self._walk_rows()
-        counts = [self.kv.geometry.step_counts(start, n, block_rows, calls)
-                  for start, n in self._kernel_rows(work, calls)]
-        return tuple(sum(c) for c in zip(*counts))
+    def _cache_counts(self, groups, calls: int = 1) -> tuple:
+        """The geometry's ``step_counts`` summed over the rows of a step's row
+        groups [(work, rows, width)] (``_kernel_rows`` of each group's (seq,
+        tokens) work), each row's tokens going through the paged kernel in
+        ``calls`` calls, by blocks of the rows its walk takes at a step for a
+        group of that width (``walked`` is 0 where no kernel walks)."""
+        visible = walked = 0
+        geometry = self.kv.geometry
+        # a window that bounds every layer's walk: a Llama twin's over the linear geometry (a slot-holding twin's
+        # window layers keep rings, which the records count by other names)
+        window = int(getattr(self.cfg, "sliding_window", 0) or 0) if type(geometry) is LinearGeometry else 0
+        bounds = {"window": window} if window else {}
+        for work, _, width in groups:
+            block_rows = self._walk_rows(width)
+            for start, n in self._kernel_rows(work, calls):
+                seen, covered = geometry.step_counts(start, n, block_rows, calls, **bounds)
+                visible, walked = visible + seen, walked + covered
+        return visible, walked
+
+    def _decode_form_counts(self, groups, calls: int = 1) -> dict:
+        """The step records' counts of the rows that went through the paged
+        kernel's decode form: the rows of the step's groups of one position a
+        row, a call (``attn_decode_rows``: what the program holds, padding
+        included; a twin may hand the kernel each as several rows, a group of
+        key heads each), and those of them that carried a token
+        (``attn_decode_rows_live``: the others cost the form nothing).  None
+        where the kernel takes its general form for every group, or no kernel
+        of this module walks."""
+        shape = self._kernel_page_shape()
+        if shape is None or self._own_walk() is not None:
+            return {}
+        ones = [(work, rows) for work, rows, width in groups if takes_decode_form(width, *shape)]
+        return {"attn_decode_rows": calls * sum(rows for _, rows in ones),
+                "attn_decode_rows_live": calls * sum(len(work) for work, _ in ones)} if ones else {}
 
     def _state_counts(self, work, calls: int = 1) -> dict:
         """The step records' named counts of a geometry that has some (state
@@ -1407,11 +1447,11 @@ class InferenceEngineV2:
                                             jnp.asarray(rb.chunk_lens), sub, *image_args)
         if anat.enabled:
             # the passes over the rows run with the program already enqueued
-            state_counts = self._state_counts(work)
+            state_counts = {**self._state_counts(work), **self._decode_form_counts(packed)}
             if image_rows:
                 state_counts["mm_tokens"] = int((rb.mm_index >= 0).sum())
             anat.note_counts(**self._expert_rows(tokens_real, rb.tokens.size),
-                             cache_counts=self._cache_counts(work), state_counts=state_counts)
+                             cache_counts=self._cache_counts(packed), state_counts=state_counts)
             anat.mark("compile_wait" if self._fresh_compile else "dispatch")
         inf = InFlightStep("single")
         inf.tokens = next_tok

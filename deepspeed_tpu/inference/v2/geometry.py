@@ -20,16 +20,20 @@ Stdlib + numpy only: the admission controller and the scheduler import it.
 import numpy as np
 
 
-def _rows_walked(seen, block_rows, calls):
+def _rows_walked(seen, block_rows, calls, window=0):
     """Key rows the paged kernel's walk covers for consecutive tokens that
     see ``seen[i] + 1`` rows each and go through it in ``calls`` calls of
     equal length: every token of a call walks whole blocks up to the call's
-    last token's last row.  ``block_rows`` 0: no kernel walks."""
+    last token's last row, from the block of the first row the call's first
+    token sees (block 0 unless a ``window`` bounds what a token sees).
+    ``block_rows`` 0: no kernel walks."""
     if not seen.size or not block_rows:
         return 0
     a_call = -(-seen.size // calls)
-    last = np.minimum((np.arange(seen.size) // a_call + 1) * a_call, seen.size) - 1     # its call's last token
-    return int((-(-(seen[last] + 1) // block_rows) * block_rows).sum())
+    first = np.arange(seen.size) // a_call * a_call                     # its call's first token
+    last = np.minimum(first + a_call, seen.size) - 1                    # ... and last
+    begin = np.maximum(seen[first] - window + 1, 0) // block_rows if window else 0
+    return int(((-(-(seen[last] + 1) // block_rows) - begin) * block_rows).sum())
 
 
 class LinearGeometry:
@@ -80,16 +84,18 @@ class LinearGeometry:
         (or one run of chunks, where ``chunk_runs``)."""
         return n_tokens
 
-    def step_counts(self, start: int, n_tokens: int, block_rows: int = 0, calls: int = 1) -> tuple:
+    def step_counts(self, start: int, n_tokens: int, block_rows: int = 0, calls: int = 1, window: int = 0) -> tuple:
         """What feeding tokens ``start .. start + n_tokens - 1`` does to the
         cache, for the step records: (``attn_rows_visible``,
-        ``attn_rows_walked``).  Token ``t`` sees rows ``0 .. t``.  The
+        ``attn_rows_walked``).  Token ``t`` sees rows ``0 .. t``, the last
+        ``window`` of them where every layer's attention has one.  The
         tokens go through the paged kernel in ``calls`` calls of equal length
         (one chunk, or the fused rung's one token a step), and a call walks
-        whole blocks of ``block_rows`` key rows up to its last token's last
-        visible row, for every one of its tokens."""
+        whole blocks of ``block_rows`` key rows from its first token's first
+        visible row up to its last token's last, for every one of its tokens."""
         t = np.arange(start, start + n_tokens)
-        return int((t + 1).sum()), _rows_walked(t, block_rows, calls)
+        visible = np.minimum(t + 1, window) if window else t + 1
+        return int(visible.sum()), _rows_walked(t, block_rows, calls, window)
 
 
 class RingSummaryGeometry:

@@ -76,7 +76,11 @@ to kill.  This module decomposes EVERY engine step into:
   the step's token rows, one layer) and ``attn_rows_walked`` (key rows the
   paged kernel's walk covered for them: whole blocks up to the last row its
   call could see, 0 where the attention does not read through the kernel;
-  ``visible / walked`` is the kernel's tightness).  The engine notes the
+  ``visible / walked`` is the kernel's tightness), ``attn_decode_rows`` (rows
+  of the step's groups of one query position a row, a call of the kernel,
+  that went through its decode form, padding included) and
+  ``attn_decode_rows_live`` (those of them that carried a token: the form
+  walks these alone).  The engine notes the
   program's key and its rows before it enqueues the program and the counts
   that take a pass over the rows (``note_counts``) after, under the device's
   busy time.
@@ -173,7 +177,7 @@ COUNTS = ("rows_decode", "rows_prefill", "seqs_prefill", "tokens_real", "slots",
           "tokens_discarded", "expert_rows", "expert_rows_kernel", "attn_rows_visible", "attn_rows_walked",
           "ssm_rows", "window_rows_visible", "ssd_state_bytes", "mla_rows_read", "mm_tokens",
           "sparse_decode_rows_read", "lightning_state_bytes", "ring_rows_held", "ring_rows_seen",
-          "full_rows_seen")
+          "full_rows_seen", "attn_decode_rows", "attn_decode_rows_live")
 
 #: what an encode dispatch of a vision tower carried (``StepAnatomy.encodes``, beside the step records)
 ENCODE_COUNTS = ("vit_images", "vit_patches_real", "vit_patches_padded", "vit_pairs", "vit_reencoded")
@@ -247,6 +251,7 @@ class StepRecord:
         self.ssm_rows = self.window_rows_visible = self.ssd_state_bytes = self.mla_rows_read = self.mm_tokens = 0
         self.sparse_decode_rows_read = self.lightning_state_bytes = 0
         self.ring_rows_held = self.ring_rows_seen = self.full_rows_seen = 0
+        self.attn_decode_rows = self.attn_decode_rows_live = 0
 
     def host_s(self) -> float:
         return sum(self.segments.values())

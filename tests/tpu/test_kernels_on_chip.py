@@ -228,6 +228,39 @@ def test_paged_kernel_bf16_on_chip(c, h, n_kv, d, page_size, starts):
     assert err < 4e-2, f"paged kernel bf16 deviates by {err}"
 
 
+@pytest.mark.parametrize("window", [0, 512], ids=["shared_pages", "window_ring"])
+def test_paged_kernel_decode_form_20_live_rows_of_160_on_chip(window):
+    """The kernel's decode form at ``phi4flash_reason``'s shape (160 rows of 2
+    key heads and 4 queries each, one position a row), 20 rows live in runs of
+    five (four slots' groups of key pairs) and 140 dead: one stream of page
+    copies over the live rows, against the jnp golden on the same chip, under
+    the window layers' bound and under none."""
+    import jax
+    import jax.numpy as jnp
+    from deepspeed_tpu.models.llama_cache import paged_attention
+    from deepspeed_tpu.ops.paged_attention import paged_attention_pallas, takes_decode_form
+
+    rng = np.random.default_rng(55)
+    rows, n_q, n_kv, d, page, width = 160, 8, 2, 128, 16, 41 if window else 257
+    assert takes_decode_form(1, n_kv, d, 2)
+    start, lens = np.zeros(rows, np.int32), np.zeros(rows, np.int32)
+    for slot, at in zip((0, 7, 8, 31), (526, 511, 640, 513) if window else (0, 127, 1537, 4100)):
+        start[5 * slot:5 * slot + 5], lens[5 * slot:5 * slot + 5] = at, 1
+    table = rng.integers(1, 4096, (rows, width)).astype(np.int32)
+    pages = jnp.asarray(rng.normal(size=(2, 4096, page, 2, n_kv, d)), jnp.bfloat16)
+    q = jnp.asarray(rng.normal(size=(rows, 1, n_q, d)), jnp.bfloat16)
+    bt, sp, cl = jnp.asarray(table), jnp.asarray(start), jnp.asarray(lens)
+    gold = jax.jit(lambda q, pages: paged_attention(q.astype(jnp.float32), pages[1].astype(jnp.float32), bt, sp, cl,
+                                                    page, sliding_window=window, scale=0.125))(q, pages)
+    kernel = jax.jit(lambda q, pages: paged_attention_pallas(q, pages, bt, sp, cl, page, layer=1, window=window,
+                                                             scale=0.125, interpret=False))
+    assert "ds_paged_attention" in kernel.lower(q, pages).as_text()
+    got = kernel(q, pages).astype(jnp.float32)
+    assert bool(jnp.isfinite(got).all())
+    assert float(jnp.max(jnp.abs(got - gold))) < 4e-2
+    np.testing.assert_array_equal(np.asarray(got)[lens == 0], 0)
+
+
 # ----------------------------------------------------------------- quant
 
 

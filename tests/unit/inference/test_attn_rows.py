@@ -30,6 +30,13 @@ HAND_WORKED = {
     "linear_fused_rung": (LinearGeometry(16), (126, 4, 128, 4), (127 + 128 + 129 + 130, 128 + 128 + 256 + 256)),
     # a prefill from nothing, blocks of 32: 1 + 2 + ... + 40 seen, 40 queries walk 64 rows each
     "linear_first_chunk": (LinearGeometry(8), (0, 40, 32, 1), (820, 40 * 64)),
+    # a window of 200 on every layer: the decode row at 299 sees rows 100..299 and its walk starts at block 0 all
+    # the same; at 399 it sees 200..399 and starts at block 1: three blocks of the four
+    "linear_window_decode_row": (LinearGeometry(16), (299, 1, 128, 1, 200), (200, 384)),
+    "linear_window_walk_starts_later": (LinearGeometry(16), (399, 1, 128, 1, 200), (200, 384)),
+    # a chunk of 4 from 126 under it: 127, 128, 129, 130 rows seen; from 400: four times 200, and blocks 1..3 of 128
+    "linear_window_wider_than_the_context": (LinearGeometry(16), (126, 4, 128, 1, 200), (127 + 128 + 129 + 130, 4 * 256)),
+    "linear_window_chunk": (LinearGeometry(16), (400, 4, 128, 1, 200), (800, 4 * 384)),
     # no token, no rows
     "linear_empty_row": (LinearGeometry(16), (77, 0, 128, 1), (0, 0)),
     # window 256, pages of 16: token 600 lies 88 into the third window behind 2 x 16 summary rows: it sees 32 + 89
@@ -117,6 +124,72 @@ def test_a_run_is_counted_row_by_row():
     assert totals[4][1] == 32 * 128 * 4 + 22 * 256
 
 
+@pytest.mark.parametrize("window", [0, 24], ids=["full_layers", "window_layers"])
+def test_a_decode_steps_walk_is_the_decode_forms(window):
+    """Heads of 128 lanes, pages the kernel copies: a group of one position a
+    row takes the kernel's decode form, whose walk goes by granules of 128 key
+    rows from the first a token sees to the last (``walk_block(chunk=1)``, the
+    function the kernel cuts its blocks by), a prompt's chunk the general
+    form's blocks (here the table's 24 pages: 192 rows).  ``attn_rows_walked``
+    of every step is that, row by row, with a window on every layer and with
+    none; ``attn_decode_rows`` are the rows of the step's groups of one
+    position, a call, ``attn_decode_rows_live`` those that carried a token,
+    and with the prefill group's rows they are the step's kernel rows.  The
+    records alone: no program runs (``_invoke`` hands back zeros)."""
+    cfg = LlamaConfig(vocab_size=128, hidden_size=256, intermediate_size=128, num_hidden_layers=2,
+                      num_attention_heads=2, num_key_value_heads=2, max_position_embeddings=512, sliding_window=window,
+                      dtype=jnp.float32, scan_layers=True, remat=False, attention_impl="flash")
+    params = nn.meta.unbox(LlamaForCausalLM(cfg).init(jax.random.PRNGKey(0), jnp.zeros((1, 8), jnp.int32)))
+    k = 4
+    eng = InferenceEngineV2(cfg, params, RaggedInferenceEngineConfig(
+        kv=PagedKVConfig(num_pages=96, page_size=8, max_pages_per_seq=24),
+        scheduler=SchedulerConfig(token_budget=64, max_seqs=4, prefill_chunk=32, decode_bucket=4),
+        max_new_tokens=12, enable_prefix_cache=False, decode_steps_per_dispatch=k, kv_dtype=jnp.float32))
+    granule, block = eng._walk_rows(1), eng._walk_rows(32)
+    assert granule == 128 == walk_block(8, 24, 2, 128, 4, chunk=1) * 8 and block == 192 == eng._walk_rows()
+    eng._compiled_step = eng._compiled_multi_step = lambda *key: None
+
+    def no_program(fn, params, cache, tokens, start_pos, *rest):
+        fused = tokens.size == start_pos.size and eng.anatomy._cur.key.startswith("multi:")
+        return np.zeros(start_pos.shape + ((k, ) if fused else ()), np.int32), cache
+
+    eng._invoke = no_program
+    fed = {}
+
+    def spy(groups, calls=1):                                  # (first position, tokens, width, calls) of every kernel row
+        fed[len(eng.anatomy.steps)] = [(start, n, width, calls) for work, _, width in groups
+                                       for start, n in eng._kernel_rows(work, calls)]
+        return counts(groups, calls)
+
+    counts, eng._cache_counts = eng._cache_counts, spy
+    eng.put([0, 1, 2], [np.arange(1, 151).tolist(), np.arange(1, 38).tolist(), np.arange(1, 131).tolist()])
+    while not all(s.done for s in eng.state.seqs.values()):
+        eng.step()
+    rows = [r.to_row() for r in eng.anatomy.steps]
+    assert any(r["key"].startswith("multi:") for r in rows) and any(":c32" in r["key"] for r in rows)
+
+    def walked(start, n, width, calls):                        # by hand: every token of a call walks the call's span
+        total, a_call, unit = 0, -(-n // calls), granule if width == 1 else block
+        for at in range(start, start + n, a_call):
+            end = min(at + a_call, start + n)
+            first = max(at - window + 1, 0) // unit if window else 0
+            total += (end - at) * (-(-end // unit) - first) * unit
+        return total
+
+    for i, r in enumerate(rows):
+        assert r["attn_rows_walked"] == sum(walked(*row) for row in fed[i]), r
+        calls = k if r["key"].startswith("multi:") else 1
+        groups = [tuple(int(x[1:]) for x in g) for g in zip(*[iter(r["key"].split(":")[1:])] * 2)]
+        groups = [(groups[0][0], 1)] if calls > 1 else groups                    # multi:b4:k4 is four rows of one position
+        ones = sum(b for b, c in groups if c == 1)
+        assert r["attn_decode_rows"] == calls * ones
+        assert r["attn_decode_rows_live"] == sum(1 for row in fed[i] if row[2] == 1) * calls <= r["attn_decode_rows"]
+        assert r["attn_decode_rows"] // calls + sum(b for b, c in groups if c > 1) == sum(b for b, _ in groups)
+    assert sum(r["attn_decode_rows_live"] for r in rows) >= 3 * 11
+    if window:                                                   # a decode row whose walk left its first granule out
+        assert any(width == 1 and start - window + 1 >= granule for f in fed.values() for start, _, width, _ in f)
+
+
 @pytest.mark.parametrize("k", [1, 4])
 def test_the_counts_are_noted_after_the_enqueue_and_read_the_same(k):
     """The program's key and rows are on the open step before ``_invoke``
@@ -140,9 +213,9 @@ def test_the_counts_are_noted_after_the_enqueue_and_read_the_same(k):
         events.append(("invoke", {uid: s.seen_tokens for uid, s in eng.state.seqs.items()}))
         return invoke(fn, *args)
 
-    def spy_counts(work, calls=1):
-        got = counts(work, calls)
-        events.append(("counts", {s.uid: s.seen_tokens for s, _ in work}, got))
+    def spy_counts(groups, calls=1):
+        got = counts(groups, calls)
+        events.append(("counts", {s.uid: s.seen_tokens for work, _, _ in groups for s, _ in work}, got))
         return got
 
     eng._invoke, eng._cache_counts = spy_invoke, spy_counts

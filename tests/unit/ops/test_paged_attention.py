@@ -122,6 +122,36 @@ COPIED = {
     # one key head: whole tiles in float32; in bfloat16 half a 32-bit sublane, a page the tiling pads (see below)
     "one_key_head": lambda: _rows([(3, 4), (21, 1), (300, 2)], c=4, h=4, n_kv=1),
 }
+#: a decode call's rows as (context, in keys; 0: a row with no token): dead rows first, last and in runs between
+#: live ones, contexts round the granule's edges (128) and the block's (512)
+_DECODE_ROWS = (0, 1, 127, 128, 0, 0, 129, 511, 512, 513, 0)
+
+
+def _decode(contexts, h, n_kv, **kw):
+    """A call of one query position a row (the kernel's decode form), a row a context."""
+    return _rows([(max(n - 1, 0), int(n > 0)) for n in contexts], c=1, h=h, n_kv=n_kv, **kw)
+
+
+DECODE = {
+    # One query position a row over pages the kernel copies: the decode form, one stream of page copies over the
+    # rows that carry a token.  Each cell's decode shape, fewer rows:
+    # Phi-4-mini-flash [160, 2, 4, 128]: runs of dead rows, every edge of a granule and of a block
+    "decode_two_key_heads_four_queries_each": lambda: _decode(_DECODE_ROWS, h=8, n_kv=2),
+    # Mixtral [16, 8, 4, 128], the last row ending on the table's last page (65 pages of 8 hold 520 keys)
+    "decode_eight_key_heads_ends_on_the_last_page": lambda: _decode((0, 300, 0, 520, 77), h=32, n_kv=8, width=65),
+    # EvaByte [16, 32, 1, 128]: one query a key head, the scratch pads the heads
+    "decode_32_key_heads_one_query_each": lambda: _decode((200, 0, 0, 515, 1), h=32, n_kv=32),
+    # Granite [32, 4, 8, 128] and Solar-Open2 [32, 8, 8, 128]: eight queries a key head; a traced layer of three
+    "decode_four_key_heads_eight_queries_each": lambda: _decode((640, 0, 130, 0), h=32, n_kv=4, layers=3),
+    "decode_eight_key_heads_eight_queries_each": lambda: _decode((0, 0, 385, 1030), h=64, n_kv=8),
+    # Trinity 48q/8kv: six queries a key head
+    "decode_eight_key_heads_six_queries_each": lambda: _decode((257, 0, 512), h=48, n_kv=8),
+    "decode_a_lone_live_row": lambda: _decode((0, 0, 0, 700, 0, 0), h=8, n_kv=2),
+    "decode_no_live_row": lambda: _decode((0, 0, 0), h=8, n_kv=2),
+    # more rows than one grid step holds (see ``test_decode_rows_beyond_one_grid_step``)
+    "decode_every_row_live": lambda: _decode((5, 140, 260, 390), h=8, n_kv=2),
+}
+COPIED.update(DECODE)
 PIPELINED = {
     # Pages the chip's tiling pads, or heads no strided load takes: the pipeline brings a block's pages, 16 of them
     # (128 rows), and a head's rows are a load a page.
@@ -152,9 +182,46 @@ def matches_jnp_golden(case, dtype):
     np.testing.assert_allclose(np.asarray(as32(got)), np.asarray(expected), atol=2e-5 if dtype == jnp.float32 else 3e-2)
     past = np.arange(q.shape[1])[None, :] >= np.asarray(lens)[:, None]
     np.testing.assert_array_equal(np.asarray(as32(got))[past], 0)
+    if case in DECODE:
+        # the same rows through the general form: the chunk of 1 padded into a chunk of 2
+        wide = jax.jit(lambda q, pages: paged_attention_pallas(jnp.pad(q, ((0, 0), (0, 1), (0, 0), (0, 0))), pages, table,
+                                                               start, lens, page_size, layer=layer, interpret=True))(q, pages)
+        np.testing.assert_allclose(np.asarray(as32(wide[:, :1])), np.asarray(expected),
+                                   atol=2e-5 if dtype == jnp.float32 else 3e-2)
+        np.testing.assert_array_equal(np.asarray(as32(wide[:, 1:])), 0)
 
 
 @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16], ids=["float32", "bfloat16"])
 @pytest.mark.parametrize("case", list(COPIED))
 def test_pallas_matches_jnp_golden(case, dtype):
     matches_jnp_golden(case, dtype)
+
+
+def test_the_decode_form_is_taken_from_the_shape_alone():
+    """One query position a row over pages the kernel copies takes the decode
+    form, and the walk's granule the engine counts by follows it; a chunk, or
+    pages the pipeline brings, the general one."""
+    from deepspeed_tpu.ops.paged_attention import takes_decode_form, walk_block
+    assert takes_decode_form(1, 2, 128, 2) and takes_decode_form(1, 32, 128, 2) and takes_decode_form(1, 2, 128, 4)
+    assert not takes_decode_form(2, 2, 128, 2) and not takes_decode_form(128, 8, 128, 2)
+    assert not takes_decode_form(1, 1, 128, 2) and not takes_decode_form(1, 4, 64, 2)        # pages the tiling pads
+    assert walk_block(16, 257, 2, 128, 2, chunk=1) == 8 and walk_block(16, 257, 2, 128, 2, chunk=128) == 32
+    assert walk_block(16, 257, 2, 128, 2) == 32 and walk_block(16, 5, 2, 128, 2, chunk=1) == 5
+    assert walk_block(16, 257, 4, 64, 2, chunk=1) == walk_block(16, 257, 4, 64, 2) == 8
+
+
+@pytest.mark.parametrize("case, fit", [("decode_two_key_heads_four_queries_each", 3), ("decode_every_row_live", 2)])
+def test_decode_rows_beyond_one_grid_step(monkeypatch, case, fit):
+    """A call whose queries pass the VMEM one grid step may give them is cut
+    into groups of rows, a stream each, and gives the same values: 11 rows
+    where 3 fit go one a step, 4 where 2 fit two a step."""
+    from deepspeed_tpu.ops import paged_attention as kernel
+    q, pages, table, start, lens, page_size, layer = DECODE[case]()
+    monkeypatch.setattr(kernel, "_DECODE_ROWS_BYTES", fit * 2 * 8 * 128 * 4)     # a row: 2 key heads of 8 x 128 float32
+    kernel._paged_call.clear_cache()                     # the budget is no part of the jitted call's key
+    try:
+        got = kernel.paged_attention_pallas(q, pages, table, start, lens, page_size, layer=layer, interpret=True)
+    finally:
+        kernel._paged_call.clear_cache()
+    expected = paged_attention(q, pages[layer], table, start, lens, page_size)
+    np.testing.assert_allclose(np.asarray(got), np.asarray(expected), atol=2e-5)

@@ -37,10 +37,9 @@ def one_chip(described_chip):
     return SingleDeviceSharding(described_chip)
 
 
-def _compile(one_chip, chunk, n_q, n_kv, table_width, layers=1, d=128, dtype=jnp.bfloat16, batch=16, **bounds):
+def _lower(one_chip, chunk, n_q, n_kv, table_width, layers=1, d=128, dtype=jnp.bfloat16, batch=16, **bounds):
     """The kernel alone over an arena of ``layers`` layers, the layer a traced
-    index, lowered and compiled: the program's text.  Under the
-    ``no_compile_cache`` fixture."""
+    index, lowered for the described chip."""
     sds = lambda shape, dtype: jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)  # noqa: E731
     args = [sds((batch, chunk, n_q, d), dtype), sds((layers, 64, 16, 2, n_kv, d), dtype),
             sds((batch, table_width), jnp.int32), sds((batch, ), jnp.int32), sds((batch, ), jnp.int32),
@@ -52,7 +51,13 @@ def _compile(one_chip, chunk, n_q, n_kv, table_width, layers=1, d=128, dtype=jnp
     lowered = jax.jit(call).lower(*args)
     # the kernel by its name, not another form of the same attention
     assert "ds_paged_attention" in lowered.as_text()
-    return lowered.compile().as_text()
+    return lowered
+
+
+def _compile(*args, **kwargs):
+    """... and compiled: the program's text.  Under the ``no_compile_cache``
+    fixture."""
+    return _lower(*args, **kwargs).compile().as_text()
 
 
 @pytest.mark.parametrize("chunk", [128, 1])
@@ -99,6 +104,50 @@ def test_two_key_pairs_a_row_with_the_windows_bound(one_chip, no_compile_cache, 
                                          scale=0.125)
 
 
+#: the decode shape of each serving cell that reads through the kernel: (rows, query heads, key heads, table width,
+#: the window its window layers bound the walk by or, for a cell that has none, one to compile it under)
+DECODE_SHAPES = {
+    "mixtral_16x8x4": (16, 32, 8, 776, 4096),
+    "evabyte_16x32x1": (16, 32, 32, 248, 2048),
+    "phi4flash_160x2x4": (160, 8, 2, 257, 512),
+    "granite_32x4x8": (32, 32, 4, 257, 1024),
+    "solar_open2_32x8x8": (32, 64, 8, 2177, 8192),
+    "trinity_32x8x6": (32, 48, 8, 2081, 4096),
+}
+
+
+@pytest.mark.parametrize("windowed", [False, True], ids=["full", "window"])
+@pytest.mark.parametrize("cell", list(DECODE_SHAPES))
+def test_the_decode_form_at_each_cells_decode_shape(one_chip, no_compile_cache, cell, windowed):
+    """One query position a row: the decode form (``takes_decode_form``), all
+    the call's rows one grid step, with a window's two bounds and with one."""
+    from deepspeed_tpu.ops.paged_attention import takes_decode_form
+    batch, n_q, n_kv, width, window = DECODE_SHAPES[cell]
+    assert takes_decode_form(1, n_kv, 128, 2)
+    assert "tpu_custom_call" in _compile(one_chip, 1, n_q, n_kv, width, layers=8, batch=batch,
+                                         window=window if windowed else 0, scale=0.125)
+
+
+#: the length of the lowered module's text at three cells' decode shapes on the parent of PR 55 (a grid step a row,
+#: 96 page copies unrolled in its body), read there with this file's ``_lower``; the Mosaic module rides in that
+#: text, so its length follows the size of the kernel's body, which every program pays to trace and to lower at
+#: every start, compile cache or none
+#: (without a window, with the cell's)
+PARENT_TEXT = {"phi4flash_160x2x4": (49916, 51748), "solar_open2_32x8x8": (49896, 51744), "granite_32x4x8": (49892, 51736)}
+
+
+@pytest.mark.parametrize("cell", list(PARENT_TEXT))
+def test_the_decode_forms_body_stays_in_its_budget(one_chip, cell):
+    """The set-up budget of ISSUE 55: the decode form's lowered text is at
+    most 1.1 times as long as the kernel's was at the same shape before it
+    had the form (PR 53 did the same work in a body that cost three serving
+    cells 7 to 16 s of ``setup_s`` and was refused for it).  No clock."""
+    batch, n_q, n_kv, width, window = DECODE_SHAPES[cell]
+    for w, parent in zip((0, window), PARENT_TEXT[cell]):
+        text = _lower(one_chip, 1, n_q, n_kv, width, layers=8, batch=batch, window=w, scale=0.125).as_text()
+        assert len(text) <= 1.1 * parent, (w, len(text), parent)
+
+
 #: pages the chip's tiling pads, or heads no strided load takes: (query heads, key heads, lanes, dtype)
 PIPELINED = {
     "falcon_7b_one_key_head_of_64_lanes": (71, 1, 64, jnp.bfloat16),
@@ -136,9 +185,12 @@ def test_pages_the_kernel_copies_itself(one_chip, no_compile_cache, n_kv, dtype)
 def test_the_blocks_scratch_stays_in_its_budget(n_kv, width):
     """The two slots of the scratch, with the heads it pads, at the cells'
     shapes: within the budget the block was chosen under (a count, no compile)."""
-    ppb = walk_block(16, width, n_kv, 128, 2)
-    assert ppb * 16 >= 128
-    assert 2 * ppb * 16 * 2 * _padded_heads(n_kv, 2) * 128 * 2 <= _BLOCK_BYTES
+    from deepspeed_tpu.ops.paged_attention import _decode_block
+    for ppb in (walk_block(16, width, n_kv, 128, 2), _decode_block(16, width, n_kv, 128, 2)):
+        assert ppb * 16 >= 128
+        assert 2 * ppb * 16 * 2 * _padded_heads(n_kv, 2) * 128 * 2 <= _BLOCK_BYTES
+    # the decode form's block: 1,024 key rows of 8 or 2 key heads, 512 of EvaByte's 32 (padded to 40) and of 64
+    assert _decode_block(16, width, n_kv, 128, 2) == (64 if n_kv <= 8 else 32 if n_kv == 32 else 16)
 
 
 @pytest.mark.parametrize("program", ["step_c128", "step_c1", "fused_2"])

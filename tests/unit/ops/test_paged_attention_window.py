@@ -18,6 +18,12 @@ WINDOW_CASES = {
     "a_chunk_across_the_window_s_edge": ("decode_row_in_a_chunk_of_32", 20),
     "more_than_one_query_tile": ("more_than_one_query_tile", 9),
     "layer_named_in_the_whole_arena": ("layer_named_in_the_whole_arena", 300),
+    # the decode form (one query position a row): a window smaller than a row's context, equal to it (the row of 128
+    # keys) and larger; one that starts the walk at a later granule of 128 keys; one wider than every context but one
+    "decode_window_of_a_granule": ("decode_two_key_heads_four_queries_each", 128),
+    "decode_walk_starts_at_a_later_granule": ("decode_32_key_heads_one_query_each", 300),
+    "decode_window_of_a_block": ("decode_four_key_heads_eight_queries_each", 512),
+    "decode_window_in_a_traced_layer_s_lone_row": ("decode_a_lone_live_row", 200),
     # the pipeline brings the pages (blocks of 16 pages = 128 rows): steps before the first block are skipped
     "pipelined_walk_starts_at_a_later_block": ("heads_of_32_lanes_table_no_multiple_of_the_block", 40),
     "pipelined_heads_of_64_lanes": ("heads_of_64_lanes_decode_row_in_a_chunk_of_32", 150),

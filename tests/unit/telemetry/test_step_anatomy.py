@@ -421,7 +421,8 @@ def test_annotate_factory_sees_step_and_marks_nested_with_counts():
                           "ssm_rows": 0, "window_rows_visible": 0, "ssd_state_bytes": 0,
                           "mla_rows_read": 0, "mm_tokens": 0,
                           "sparse_decode_rows_read": 0, "lightning_state_bytes": 0,
-                          "ring_rows_held": 0, "ring_rows_seen": 0, "full_rows_seen": 0}
+                          "ring_rows_held": 0, "ring_rows_seen": 0, "full_rows_seen": 0,
+                          "attn_decode_rows": 0, "attn_decode_rows_live": 0}
     # the counts ride the row and the per-program fold too
     row = anat.last_step.to_row()
     assert (row["tokens_real"], row["slots"], row["tokens_out"],
