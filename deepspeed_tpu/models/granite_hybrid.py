@@ -1,15 +1,32 @@
 """Granite 4.0-H (ref: https://huggingface.co/ibm-granite/granite-4.0-h-micro
 ``config.json``, ``model_type`` ``granitemoehybrid``): Mamba-2 layers with a
 few grouped-query attention layers between them, no positional encoding
-(``position_embedding_type: "nope"``), muP multipliers.  Dense siblings only
-(``num_local_experts`` 0: the shared MLP is the layer's MLP).
+(``position_embedding_type: "nope"``), muP multipliers; the dense siblings
+(``num_local_experts`` 0: the shared MLP is the layer's MLP, granite-4.0-h-micro)
+and the siblings with routed experts beside the shared MLP (granite-4.0-h-small:
+72 experts, ten a token; transformers ``modeling_granitemoehybrid.py``).
 
-``x = embedding_multiplier * E[ids]``.  Layer ``i``:
+``x = embedding_multiplier * E[ids]``.  Layer ``i``, with ``u = RMSNorm(h)``:
 
   h = x + residual_multiplier * mixer_i(RMSNorm(x))
-  x = h + residual_multiplier * W_out(silu(a) * b),   [a | b] = W_in RMSNorm(h)
+  shared = W_out(silu(a) * b),   [a | b] = W_in u          (2 x shared_intermediate_size)
+  x = h + residual_multiplier * (routed + shared)          (routed = 0 in a dense sibling)
 
 and logits ``RMSNorm(x) E^T / logits_scaling`` with the tied embedding.
+
+* routed experts (``num_local_experts`` > 0): ``l = W_r u`` in float32, no
+  bias; the ``num_experts_per_tok`` largest of ``l``; ``g`` = the softmax over
+  those logits alone (= the softmax over all, the chosen renormalised, which
+  is what ``moe/sharded_moe.dropless_dispatch`` computes); ``routed = sum_e
+  g_e W2_e(silu(a_e) * b_e)``, ``[a_e | b_e] = W1_e u`` of ``2 x
+  intermediate_size``.  Departures from the published code, in layout alone:
+  it holds ``W1_e`` as one matrix ``[2 f, hidden]`` (``input_linear``) whose
+  **first** half goes through the activation; here the halves are the bank's
+  ``w_gate`` (the first, through ``silu``) and ``w_up`` (the second), ``[E,
+  hidden, f]`` each, and ``W2_e`` is ``w_down`` ``[E, f, hidden]``
+  (``moe/experts.ExpertsFFN``); the router is ``block_sparse_moe/router``.
+  The shared MLP keeps the published fused ``input_linear``, first half
+  through the activation.
 
 * attention (``layer_types[i] == "attention"``): no bias, no rotary, causal
   ``softmax(attention_multiplier * q k^T) v``, grouped heads.
@@ -25,6 +42,16 @@ The recurrence is computed a block of positions at a time (``ssd_chunk``, the
 state-space-duality form: inside a block matrix products, the state touched
 once) or one position at a time (``ssd_update_reference``; on the serving
 path the kernel ``ops/ssd_update.py``).
+
+**A chip's share** (as ``models/solar_open2.py`` and ``models/trinity.py``).
+``num_local_experts`` is what the bank holds; where ``router_experts`` (the
+published count) is larger the layer holds experts ``first_expert ..
+first_expert + num_local_experts - 1`` of a router that wide
+(``dropless_dispatch(held=)``): the router keeps its outputs and its choices
+a token, the weights are over all the chosen, the choices that fall on an
+expert held elsewhere reach no expert, the shared MLP is computed here in
+full, and the layer's output is this chip's part of the sum.  ``vocab_size``
+is the rows of the tied vocabulary held: a sliced vocabulary is a smaller one.
 
 The layer pattern has a period (10 at the published sizes: five Mamba
 layers, attention, four Mamba layers), so the trunk scans the periods and
@@ -45,7 +72,10 @@ import jax
 import jax.numpy as jnp
 from flax import linen as nn
 
-from .llama import RMSNorm
+from ..axes import EMBED
+from ..moe.experts import ExpertsFFN
+from ..moe.sharded_moe import dropless_dispatch
+from .llama import RMSNorm, _logical
 from .llama_cache import scan_blocks
 from .phi4flash import _Weight, dense_attention, embed_tokens
 
@@ -56,9 +86,9 @@ SSD_BLOCK = 128
 @dataclasses.dataclass(frozen=True)
 class GraniteHybridConfig:
     """Fields carry the published key names."""
-    vocab_size: int = 100352
+    vocab_size: int = 100352                          # rows of the tied vocabulary held
     hidden_size: int = 2048
-    intermediate_size: int = 8192
+    intermediate_size: int = 8192                     # a routed expert's width; a dense sibling's MLP
     shared_intermediate_size: int = 8192
     num_hidden_layers: int = 40
     num_attention_heads: int = 32
@@ -83,8 +113,11 @@ class GraniteHybridConfig:
     normalization_function: str = "rmsnorm"
     hidden_act: str = "silu"
     tie_word_embeddings: bool = True
-    num_local_experts: int = 0
+    num_local_experts: int = 0                        # experts the bank holds; 0: a dense sibling
     num_experts_per_tok: int = 0
+    #: the router's width where the bank holds a share of it, and the first expert held
+    router_experts: Optional[int] = None
+    first_expert: int = 0
     max_position_embeddings: int = 131072
     dtype: Any = jnp.bfloat16
     param_dtype: Any = jnp.float32
@@ -97,9 +130,16 @@ class GraniteHybridConfig:
         object.__setattr__(self, "layer_types", tuple(kinds))
         if len(self.layer_types) != self.num_hidden_layers or set(self.layer_types) - {"mamba", "attention"}:
             raise ValueError("layer_types names 'mamba' or 'attention' for each of num_hidden_layers layers")
-        if self.num_local_experts or self.num_experts_per_tok:
-            raise NotImplementedError("GraniteHybrid with routed experts (the family's larger siblings) is not "
-                                      "implemented: num_local_experts and num_experts_per_tok must be 0")
+        if self.num_local_experts:
+            if not 0 < self.num_experts_per_tok <= self.router_width:
+                raise ValueError("num_experts_per_tok must lie in 1 .. the router's width")
+            if self.router_width % self.num_local_experts or \
+                    not 0 <= self.first_expert <= self.router_width - self.num_local_experts:
+                raise ValueError("the experts held, first_expert .. first_expert + num_local_experts - 1, must lie "
+                                 "inside the router's router_experts and divide them")
+        elif self.num_experts_per_tok or self.router_experts:
+            raise ValueError("num_experts_per_tok and router_experts belong to routed experts: num_local_experts "
+                             "is 0")
         if self.position_embedding_type != "nope" or self.normalization_function != "rmsnorm" or \
                 self.hidden_act != "silu" or not self.tie_word_embeddings or self.attention_bias or \
                 self.mamba_proj_bias or self.mamba_n_groups != 1:
@@ -108,7 +148,7 @@ class GraniteHybridConfig:
                                       "attention's or the Mamba projections, one group")
         if self.mamba_n_heads * self.mamba_d_head != self.mamba_expand * self.hidden_size:
             raise ValueError("mamba_n_heads * mamba_d_head must be mamba_expand * hidden_size")
-        if self.shared_intermediate_size != self.intermediate_size:
+        if not self.num_local_experts and self.shared_intermediate_size != self.intermediate_size:
             raise ValueError("a dense GraniteHybrid's MLP is the shared MLP: shared_intermediate_size must equal "
                              "intermediate_size")
 
@@ -123,6 +163,15 @@ class GraniteHybridConfig:
     @property
     def conv_dim(self) -> int:
         return self.d_inner + 2 * self.mamba_n_groups * self.mamba_d_state
+
+    @property
+    def router_width(self) -> int:
+        return self.router_experts or self.num_local_experts
+
+    @property
+    def held(self) -> Optional[Tuple[int, int]]:
+        """``dropless_dispatch``'s ``held``: None where the bank holds every expert."""
+        return None if self.router_width == self.num_local_experts else (self.first_expert, self.num_local_experts)
 
     @property
     def period(self) -> int:
@@ -148,13 +197,41 @@ def _dense(cfg, features, name):
 
 
 class GraniteMLP(nn.Module):
+    """The shared MLP (a dense sibling's only one)."""
     cfg: GraniteHybridConfig
 
     @nn.compact
     def __call__(self, x):
         cfg = self.cfg
-        a, b = jnp.split(_dense(cfg, 2 * cfg.intermediate_size, "input_linear")(x), 2, axis=-1)
+        a, b = jnp.split(_dense(cfg, 2 * cfg.shared_intermediate_size, "input_linear")(x), 2, axis=-1)
         return _dense(cfg, cfg.hidden_size, "output_linear")(nn.silu(a) * b)
+
+
+class GraniteMoE(nn.Module):
+    """The routed experts over a batch ``x`` [B, S, C]: a softmax router of
+    ``router_width`` outputs, the experts held here through the dropless
+    dispatch -> [B, S, C] float32, this share's part of the routed sum.
+    ``token_mask`` [B, S]: slots that carry no token go to no expert.
+    ``stacked_banks``: (the banks of a scanned trunk [L, E, ...], the layer's
+    index), read in place (``moe.layer.MoE``'s)."""
+    cfg: GraniteHybridConfig
+
+    @nn.compact
+    def __call__(self, x, token_mask=None, stacked_banks=None):
+        cfg = self.cfg
+        with jax.named_scope("ds_moe_router"):
+            logits = nn.Dense(cfg.router_width, use_bias=False, dtype=jnp.float32, param_dtype=cfg.param_dtype,
+                              kernel_init=_logical(nn.initializers.lecun_normal(), (EMBED, "experts_gate")),
+                              name="router")(x.astype(jnp.float32))
+        experts = ExpertsFFN(num_experts=cfg.num_local_experts, hidden_size=cfg.hidden_size,
+                             intermediate_size=cfg.intermediate_size, dtype=cfg.dtype, param_dtype=cfg.param_dtype,
+                             name="experts")
+        bank, layer = (experts.bank(), None) if stacked_banks is None else stacked_banks
+        with jax.named_scope("ds_moe_grouped"):
+            out, _, exp_counts = dropless_dispatch(x.astype(cfg.dtype), logits, bank, cfg.num_experts_per_tok,
+                                                   token_mask, None, layer, True, "softmax", None, 1.0, cfg.held)
+        self.sow("intermediates", "exp_counts", exp_counts)
+        return out
 
 
 # ------------------------------------------------------------------ Mamba-2
@@ -346,9 +423,11 @@ class GraniteAttention(nn.Module):
 
 
 class GraniteHybridLayer(nn.Module):
-    """One layer around its mixer: ``layer(x, mix) -> (out, aux)`` where
-    ``mix(mixer, RMSNorm(x)) -> (mixed, aux)`` runs the mixer as the caller's
-    trunk needs it."""
+    """One layer around its mixer: ``layer(x, mix, token_mask, stacked_banks)
+    -> (out, aux)`` where ``mix(mixer, RMSNorm(x)) -> (mixed, aux)`` runs the
+    mixer as the caller's trunk needs it.  ``x`` [B, S, C] or the flat axis
+    [T, C] of a serving step (one group to the router); the last two
+    arguments are the routed experts' (``GraniteMoE``)."""
     cfg: GraniteHybridConfig
     kind: str   # mamba | attention
 
@@ -357,13 +436,21 @@ class GraniteHybridLayer(nn.Module):
         self.input_layernorm = _norm(cfg, "input_layernorm")
         self.post_attention_layernorm = _norm(cfg, "post_attention_layernorm")
         self.shared_mlp = GraniteMLP(cfg, name="shared_mlp")
+        if cfg.num_local_experts:
+            self.block_sparse_moe = GraniteMoE(cfg, name="block_sparse_moe")
         self.mixer = {"mamba": Mamba2Mixer, "attention": GraniteAttention}[self.kind](cfg, name="mixer")
 
-    def __call__(self, x, mix):
+    def __call__(self, x, mix, token_mask=None, stacked_banks=None):
         cfg = self.cfg
         mixed, aux = mix(self.mixer, self.input_layernorm(x))
         h = x + (cfg.residual_multiplier * mixed).astype(x.dtype)
-        return h + (cfg.residual_multiplier * self.shared_mlp(self.post_attention_layernorm(h))).astype(x.dtype), aux
+        u = self.post_attention_layernorm(h)
+        if not cfg.num_local_experts:
+            return h + (cfg.residual_multiplier * self.shared_mlp(u)).astype(x.dtype), aux
+        u3 = u if u.ndim == 3 else u[None]
+        mask = None if token_mask is None else token_mask.reshape(u3.shape[:2])
+        m = self.block_sparse_moe(u3, mask, stacked_banks).reshape(u.shape) + self.shared_mlp(u).astype(jnp.float32)
+        return h + (cfg.residual_multiplier * m).astype(x.dtype), aux
 
 
 def _whole_mamba(mixer, h):

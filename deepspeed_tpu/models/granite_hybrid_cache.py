@@ -43,6 +43,15 @@ beside a prefilling prompt alike) advances the states where they lie,
 ``ops/ssd_update.ds_ssd_update``; a wider group (the prefill rows) gathers
 its rows' states, takes the block form (``granite_hybrid.ssd_chunk``) and
 scatters them back.
+
+A sibling with routed experts (``num_local_experts`` > 0; granite-4.0-h-small)
+runs them on the flat axis beside the shared MLP, one group to the router:
+the step's live-slot mask goes into the block, so a padded slot of the decode
+bucket reaches no expert, and the periods' stacks of the banks are handed to
+the blocks whole with the period's index (``solar_open2_cache.stacked_banks``:
+the grouped product reads its layer of a stack in place).  Heads of 128 fill
+a page head alone (``kv_pack`` 1).  A dense sibling takes the path it took
+before the experts came, with the same parameter tree and the same programs.
 """
 
 from typing import Tuple
@@ -57,6 +66,8 @@ from .granite_hybrid import (GraniteHybridConfig, GraniteHybridLayer, _norm, emb
 from .llama_cache import (PagedKVConfig, _write_pages, flat_step, live_slots, logits_as, over_row_groups,
                           paged_attention, reads_through_kernel, sampled_rows, scan_blocks)
 from .phi4flash_cache import layer_traced_once
+from .solar_open2_cache import _layer_traced_once as layer_with_experts_traced_once
+from .solar_open2_cache import stacked_banks
 
 _LANES = 128
 
@@ -165,18 +176,25 @@ class _CachePeriod(nn.Module):
     groups: Tuple[Tuple[int, int], ...]
 
     @nn.compact
-    def __call__(self, carry, period, slot, table, start_pos, chunk_lens, live):
+    def __call__(self, carry, period, slot, table, start_pos, chunk_lens, live, banks=None):
         cfg = self.cfg
         x, cache = carry
         for j, kind in enumerate(cfg.layer_types[:cfg.period]):
             index = period * cfg.per_period(kind) + cfg.per_period(kind, before=j)
             layer = GraniteHybridLayer(cfg, kind, name=layer_name(j))
+
+            def traced(mix, static, *arrays):
+                if not cfg.num_local_experts:
+                    return layer_traced_once(layer, mix, static, x, *arrays)
+                # the layer's expert block also takes the live slots and its layer of the periods' stack of banks
+                stacked = None if banks is None else (banks[j], period)
+                return layer_with_experts_traced_once(layer, mix, static, x, arrays, live, stacked)
+
             if kind == "mamba":
-                x, cache = layer_traced_once(layer, _mamba_mix, (cfg, self.groups), x, cache, index, slot, start_pos,
-                                             chunk_lens, live)
+                x, cache = traced(_mamba_mix, (cfg, self.groups), cache, index, slot, start_pos, chunk_lens, live)
             else:
-                x, pages = layer_traced_once(layer, _attention_mix, (cfg, self.groups, self.page_size), x,
-                                             cache["pages"], index, table, start_pos, chunk_lens)
+                x, pages = traced(_attention_mix, (cfg, self.groups, self.page_size), cache["pages"], index, table,
+                                  start_pos, chunk_lens)
                 cache = dict(cache, pages=pages)
         return (x, cache), None
 
@@ -198,7 +216,10 @@ class GraniteHybridForCausalLMWithCache(nn.Module):
         slot, table = block_table[:, -1], block_table[:, :-1]
         embed = embed_tokens(cfg)
         x = (cfg.embedding_multiplier * embed(tokens)).astype(cfg.dtype)
-        (x, cache), _ = scan_blocks(_CachePeriod, n_periods, 5)(cfg, self.page_size, groups, name="periods")(
-            (x, cache), jnp.arange(n_periods), slot, table, start_pos, chunk_lens, live_slots(groups, chunk_lens))
+        banks = (stacked_banks(self, cfg, "block_sparse_moe"), ) if cfg.num_local_experts else ()
+        (x, cache), _ = scan_blocks(_CachePeriod, n_periods, 5 + len(banks))(
+            cfg, self.page_size, groups, name="periods")(
+                (x, cache), jnp.arange(n_periods), slot, table, start_pos, chunk_lens, live_slots(groups, chunk_lens),
+                *banks)
         x = sampled_rows(x, chunk_lens, last_only, groups)
         return logits_as(scaled_logits(cfg, embed, _norm(cfg, "norm")(x)), input_ids, last_only), cache

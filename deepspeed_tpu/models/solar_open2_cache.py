@@ -197,14 +197,15 @@ class _CachePeriod(nn.Module):
         return (x, cache), None
 
 
-def stacked_banks(module, cfg):
+def stacked_banks(module, cfg, block="mlp"):
     """Per layer of a period, the periods' stack of its expert bank as the
     scan holds it, [periods, E, ...], for the blocks to read in place; None
-    where the banks are not held in the compute dtype (or not made yet)."""
+    where the banks are not held in the compute dtype (or not made yet).
+    ``block``: the name of a layer's expert block."""
     periods = module.variables.get("params", {}).get("periods")
     if periods is None:
         return None
-    banks = tuple(tuple(nn.meta.unbox(periods[layer_name(j)]["mlp"]["experts"][name])
+    banks = tuple(tuple(nn.meta.unbox(periods[layer_name(j)][block]["experts"][name])
                         for name in ("w_gate", "w_up", "w_down")) for j in range(cfg.period))
     return banks if all(w.dtype == cfg.dtype for bank in banks for w in bank) else None
 

@@ -277,15 +277,19 @@ def _experts_grouped(x, top_vals, expert, group_sizes, bank, layer):
 
 def _experts_dense(x, top_vals, expert, group_sizes, bank, layer):
     """Every expert multiplies every row; a row's k choices are picked by
-    the weights [S, E], zero elsewhere and on dead rows (expert id E)."""
+    the weights [S, E], zero elsewhere and on dead rows (expert id E).  The
+    products lie under the scope ``ds_experts_dense``, the dense form's name
+    beside the grouped kernel's ``ds_gmm`` (docs/OBSERVABILITY.md "The
+    experts' products in a device trace")."""
     e = group_sizes.shape[0]
-    if layer is not None:  # an einsum reads its slice of the stack in place
-        bank = tuple(jax.lax.dynamic_index_in_dim(w, layer, 0, keepdims=False) for w in bank)
-    w_gate, w_up, w_down = bank
-    h = jax.nn.silu(jnp.einsum("sd,edf->esf", x, w_gate)) * jnp.einsum("sd,edf->esf", x, w_up)
-    y = jnp.einsum("esf,efd->esd", h, w_down).astype(jnp.float32)
-    weights = jnp.sum(_one_hot(expert, e) * top_vals[:, :, None], axis=1)
-    return jnp.einsum("se,esd->sd", weights, y)
+    with jax.named_scope("ds_experts_dense"):
+        if layer is not None:  # an einsum reads its slice of the stack in place
+            bank = tuple(jax.lax.dynamic_index_in_dim(w, layer, 0, keepdims=False) for w in bank)
+        w_gate, w_up, w_down = bank
+        h = jax.nn.silu(jnp.einsum("sd,edf->esf", x, w_gate)) * jnp.einsum("sd,edf->esf", x, w_up)
+        y = jnp.einsum("esf,efd->esd", h, w_down).astype(jnp.float32)
+        weights = jnp.sum(_one_hot(expert, e) * top_vals[:, :, None], axis=1)
+        return jnp.einsum("se,esd->sd", weights, y)
 
 
 def dropless_moe(x, logits, bank, k: int, token_mask=None, noise=None, layer=None, normalize: bool = True,

@@ -70,7 +70,12 @@ to kill.  This module decomposes EVERY engine step into:
   (``live_rows_sorted``), of the rows that live in it, a round's of a fused
   dispatch, as the program asks it when it runs; none of a step's
   where every expert multiplies every row, and none where the product is
-  ``jax.lax.ragged_dot``)
+  ``jax.lax.ragged_dot``; in a device trace the dense form's products lie
+  under the scope ``ds_experts_dense``.  There is no ``expert_rows_held``
+  (of a share's ``expert_rows``, those that fell on an expert held here): a
+  step brings back one array, its tokens, and the layers' ``exp_counts``
+  would be a second transfer a dispatch; a reader takes ``held / router``
+  of the rows, their expectation: ``benchmark/roofline_experts.py``)
   and, under every cache geometry, ``attn_rows_visible`` (key rows a query
   could see: ring rows plus summary rows, or its whole history; summed over
   the step's token rows, one layer) and ``attn_rows_walked`` (key rows the

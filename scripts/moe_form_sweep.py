@@ -16,7 +16,10 @@ experts the live rows touched, the reads of those and of the whole bank at the
 chip's 819 GB/s, the forms' distance from the dense one (relative l2), and
 what each compiled program holds beside its arguments (``temp_mb``: a copy of
 a bank would show there).  ``--trace bank:slots:live`` prints that point's
-longest device operations a call, form by form.  ``--tiny`` cuts the widths
+longest device operations a call, form by form, and with ``--raw`` one event's
+whole HLO text and profiler statistics an operation (what the profiler keeps
+of a ``jax.named_scope``: ``docs/OBSERVABILITY.md`` "The experts' products in
+a device trace").  ``--tiny`` cuts the widths
 by 16 for a rehearsal on the CPU (counts and values, never a time).
 """
 
@@ -42,6 +45,7 @@ BANKS = {
     "xing4": (64, 64, 3584, 1024, 4, 6, "sigmoid"),
     "kimivl": (64, 64, 2048, 1408, 6, 7, "sigmoid"),
     "solar": (320, 40, 4096, 1280, 8, 4, "sigmoid"),
+    "granite4hs": (72, 36, 4096, 768, 10, 1, "softmax"),
 }
 FORMS = ("dense", "sorted", "rule")
 HBM_BYTES_S = 819e9
@@ -76,7 +80,7 @@ def timed(fn, args, calls, rounds=3):
     return sorted(ms)[len(ms) // 2], out
 
 
-def traced(tag, fn, args, calls):
+def traced(tag, fn, args, calls, raw=False):
     """The point's longest device operations, microseconds a call."""
     import trace_reduce
     tdir = tempfile.mkdtemp(prefix="moe_form_sweep_")
@@ -92,6 +96,10 @@ def traced(tag, fn, args, calls):
     for key, seconds in red["device_ops"][:12]:
         times = red["op_counts"][key] / calls
         print(f"sweep_op: {tag} {1e6 * seconds / calls:9.1f} us x{times:<4.1f} {key}", flush=True)
+        if raw:
+            event = next(e for e in red["events"] if trace_reduce.display_name(e) == key)
+            print(f"sweep_raw: {tag} {event[0][:700]!r} stats={ {k: str(v)[:200] for k, v in event[3].items()} }",
+                  flush=True)
 
 
 def main():
@@ -103,6 +111,7 @@ def main():
     ap.add_argument("--forms", default=",".join(FORMS))
     ap.add_argument("--calls", type=int, default=20)
     ap.add_argument("--trace", default="", help="points bank:slots:live whose device operations are printed")
+    ap.add_argument("--raw", action="store_true", help="with --trace: an event's HLO text and statistics an operation")
     ap.add_argument("--tiny", action="store_true")
     ap.add_argument("--out", default=os.path.join(ROOT, "chiprun_out", "moe_form_sweep.json"))
     a = ap.parse_args()
@@ -157,7 +166,7 @@ def main():
                 print("sweep_row:", json.dumps(row), flush=True)
                 if f"{name}:{s}:{live}" in points:
                     for form in forms:
-                        traced(f"{name}:{s}:{live}:{form}", fns[form], args, a.calls)
+                        traced(f"{name}:{s}:{live}:{form}", fns[form], args, a.calls, a.raw)
         del bank, fns, args, x, outs, out, counts
     os.makedirs(os.path.dirname(a.out), exist_ok=True)
     with open(a.out, "w") as fh:

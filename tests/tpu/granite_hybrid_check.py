@@ -89,18 +89,17 @@ def without(params, kind: str, config: dict):
     return jax.tree_util.tree_map_with_path(zero, params)
 
 
-def readings(config: dict, traffic: dict, seed: int, rows: list) -> dict:
+def fed_in_slots(config: dict, traffic: dict, seed: int, rows: list):
     """``rows``: (prompt tokens, decode tokens, state slot, first position
     compared) a sequence.  Every row goes through the engine's own twin,
-    weights and cache in one batch, each in its slot and on pages drawn at
-    random: SplitFuse chunks (the block form), then one token a step
-    (``ds_ssd_update``) beside the rows still in their prompts, which a mixed
-    step carries as chunks of one token.  Returns ``program``: per row
-    ``||logits - ref|| / ||ref||`` of the positions compared, against the
-    float32 reference on the same weights; ``zeroed``: per kind and row, the
-    same distance between the reference without that kind and the whole
-    reference; ``kernel_steps``: the steps whose every row carried one token
-    at most, which went through the kernel."""
+    weights (``check_init``) and cache in one batch, each in its slot and on
+    pages drawn at random, a padding row behind them: SplitFuse chunks (the
+    block form), then one token a step (``ds_ssd_update``) beside the rows
+    still in their prompts, which a mixed step carries as chunks of one token.
+    Returns (the engine, its cache dropped; the rows' token ids; per row the
+    logits of the positions compared; ``steps`` and ``kernel_steps``, the
+    steps whose every row carried one token at most, which went through the
+    kernel)."""
     import jax
     import jax.numpy as jnp
 
@@ -108,7 +107,6 @@ def readings(config: dict, traffic: dict, seed: int, rows: list) -> dict:
     from deepspeed_tpu.inference.v2 import InferenceEngineV2
     from flax import linen as nn
     from kinds import serve_open_loop
-    from refs import plain
 
     pcfg = harness.program_config(config)
     model = harness.load_symbol(config["program"]["model"])(pcfg)
@@ -132,7 +130,7 @@ def readings(config: dict, traffic: dict, seed: int, rows: list) -> dict:
     step = jax.jit(lambda p, c, t, s, b, ln: eng.model.apply(p, t, s, b, c, ln), donate_argnums=1)
 
     pos, got = [0] * len(rows), [[] for _ in rows]
-    out = {"steps": 0, "kernel_steps": 0}
+    counts = {"steps": 0, "kernel_steps": 0}
     while any(pos[i] < len(toks[i]) for i in range(len(rows))):
         lens = [min(chunk, p - pos[i]) if pos[i] < p else int(pos[i] < p + d) for i, (p, d, _, _) in enumerate(rows)]
         width = chunk if max(lens) > 1 else 1
@@ -145,16 +143,28 @@ def readings(config: dict, traffic: dict, seed: int, rows: list) -> dict:
             if skip < ln:
                 got[i].append(logits[i, skip:ln].astype(jnp.float32))
             pos[i] += ln
-        out["steps"] += 1
-        out["kernel_steps"] += width == 1
+        counts["steps"] += 1
+        counts["kernel_steps"] += width == 1
         del logits
     scratch = float(jnp.max(jnp.abs(eng.cache["ssm"][:, [r[2] for r in rows]])))
     assert scratch > 0                                                        # the rows' slots hold their states
     eng.cache = None
+    return eng, toks, [jnp.concatenate(g) for g in got], counts
 
+
+def readings(config: dict, traffic: dict, seed: int, rows: list) -> dict:
+    """``fed_in_slots`` against the float32 reference on the same weights.
+    Returns ``program``: per row ``||logits - ref|| / ||ref||`` of the
+    positions compared; ``zeroed``: per kind and row, the same distance
+    between the reference without that kind and the whole reference;
+    ``steps`` and ``kernel_steps``."""
+    from kinds import serve_open_loop
+    from refs import plain
+
+    eng, toks, got, out = fed_in_slots(config, traffic, seed, rows)
     ref_rows = [(toks[i], p, first) for i, (p, _, _, first) in enumerate(rows)]
     ref = [logits for logits, _ in serve_open_loop.reference_logits(config, eng.params, ref_rows)]
-    out["program"] = [np.asarray(plain.rel_l2(jnp.concatenate(g), r)) for g, r in zip(got, ref)]
+    out["program"] = [np.asarray(plain.rel_l2(g, r)) for g, r in zip(got, ref)]
     del got
     out["zeroed"] = {}
     for kind in KINDS:

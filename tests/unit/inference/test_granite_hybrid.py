@@ -113,9 +113,9 @@ def test_the_mamba_parameters_are_initialised_as_published(params):
     assert 1e-3 <= dt.min() * 1.001 and dt.max() <= 0.1001 and np.all(np.asarray(mixer["D"]) == 1.0)
 
 
-def test_a_config_with_routed_experts_is_refused():
-    with pytest.raises(NotImplementedError, match="routed experts"):
-        GraniteHybridConfig(num_local_experts=32, num_experts_per_tok=4)
+def test_a_config_with_routed_experts_is_taken_and_one_with_positions_refused():
+    """Since PR 56 the siblings with routed experts are built (``test_granite_moe_hybrid.py``)."""
+    assert GraniteHybridConfig(num_local_experts=32, num_experts_per_tok=4).router_width == 32
     with pytest.raises(NotImplementedError, match="no\\s+positional encoding"):
         GraniteHybridConfig(position_embedding_type="rope")
 

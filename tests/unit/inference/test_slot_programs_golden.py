@@ -13,7 +13,11 @@ program):
         > tests/unit/inference/slot_programs_golden.json
 
 A PR that means to change one of these programs writes the file again and
-says so; one that does not finds here that it did.
+says so; one that does not finds here that it did.  PR 56 added the last
+entry, ``granitemoehybrid``: the decode-bucket step, the one-row mixed step
+and the fused program of Granite 4.0-H with routed experts (the cell
+``granite4hs_agent_turns`` at a small size), as that PR handed them in; the
+dense sibling's two stood as they were.
 
 The digests are taken in a process of their own (``python <this file>
 --all``, once a module): a jaxpr's text names the jaxprs of the jitted
@@ -40,6 +44,7 @@ from deepspeed_tpu.inference.v2.scheduler import SchedulerConfig
 from deepspeed_tpu.models.cache_zoo import cache_twin
 from deepspeed_tpu.models.llama_cache import PagedKVConfig
 
+from test_granite_moe_hybrid import CFG as GRANITE_MOE_CFG
 from test_minicpm_sala import CFG as SALA_CFG
 from test_slot_twins_golden import FAMILIES
 from test_solar_open2 import CFG as SOLAR_CFG
@@ -52,6 +57,7 @@ HELD = {
     "phi4flash": (FAMILIES["phi4flash"][1], 16, (ONE_ROW, FOUR_ROWS)),
     "granitehybrid": (FAMILIES["granitehybrid"][1], 16, (ONE_ROW, FOUR_ROWS)),
     "minicpm_sala": (SALA_CFG, 8, (ONE_ROW, FOUR_ROWS)),       # a compressed key a page: the selection's stride
+    "granitemoehybrid": (GRANITE_MOE_CFG, 16, (DECODE, ONE_ROW, ("multi", BUCKET, FUSED))),
 }
 CASES = [(twin, key) for twin, (_, _, keys) in HELD.items() for key in keys]
 #: the digest of Solar-Open2's ``step:b8:c1:b4:c32`` on the parent of PR 50, which that PR meant to change
